@@ -44,12 +44,43 @@ REFERENCE_DOCS_PER_MIN = 3.01
 E2E_DOCS = 16
 E2E_WORDS_PER_DOC = 37_000  # reference's average_words_per_file
 
-# bench chip: TPU v5e ("TPU v5 lite") — bf16 MXU peak and HBM bandwidth used
-# for the MFU / roofline fields (VERDICT r3 #6). The weights are int8 but
-# the matmuls accumulate from bf16 activations, so bf16 peak is the honest
-# denominator.
-PEAK_FLOPS_BF16 = 197e12
-HBM_BYTES_PER_S = 819e9
+# Published per-chip peaks, keyed by the device_kind JAX reports, used for
+# the MFU / roofline fields. A device that is not in the table is an error,
+# not a default.
+DEVICE_PEAKS = {
+    # Google Cloud documentation, "TPU v5e"
+    "TPU v5 lite": {
+        "flops_bf16": 197e12, "ops_int8": 393e12, "hbm_bytes_per_s": 819e9,
+    },
+}
+
+
+def require_chip() -> dict:
+    """The device this run times, as JAX reports it — or no run at all: a
+    benchmark that finds no chip fails instead of timing the CPU."""
+    import jax
+
+    d = jax.devices()
+    if d[0].platform != "tpu":
+        raise SystemExit(
+            f"bench.py times a TPU; JAX found platform {d[0].platform!r} "
+            f"({d[0].device_kind} x{len(d)}) — nothing was run"
+        )
+    device_peaks()  # an unknown device kind fails here, not after the run
+    return {"platform": d[0].platform, "device_kind": d[0].device_kind,
+            "count": len(d)}
+
+
+def device_peaks() -> dict:
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    if kind not in DEVICE_PEAKS:
+        raise SystemExit(
+            f"no published peaks for device kind {kind!r}: add it to "
+            "bench.DEVICE_PEAKS with its source"
+        )
+    return DEVICE_PEAKS[kind]
 
 
 def run_map_step_bench(backend) -> dict:
@@ -430,7 +461,10 @@ def run_device_budget(params, root: str, tok_spec, eos) -> dict:
         + d["B"] * 2 * ahd * d["S"] ** 2
         for d in st.dispatches
     )
-    mfu_prefill = pre_flops / (pre * PEAK_FLOPS_BF16) if pre else 0.0
+    # the weights are int8 but the default-exact matmuls accumulate from
+    # bf16 activations, so the bf16 peak is this field's denominator
+    peaks = device_peaks()
+    mfu_prefill = pre_flops / (pre * peaks["flops_bf16"]) if pre else 0.0
 
     # decode is HBM-bound: every step streams the full weight set plus each
     # row's valid KV cache (int8 + per-(token, head) f32 scales when the
@@ -447,7 +481,7 @@ def run_device_budget(params, root: str, tok_spec, eos) -> dict:
         )
         for d in st.dispatches
     )
-    roofline = dec_bytes / (dec * HBM_BYTES_PER_S) if dec else 0.0
+    roofline = dec_bytes / (dec * peaks["hbm_bytes_per_s"]) if dec else 0.0
 
     # one-shot comparison pass (VERDICT r4 weak #5): the SAME 4 docs through
     # a production (instrument=False) engine sharing these weights, so the
@@ -481,8 +515,8 @@ def run_device_budget(params, root: str, tok_spec, eos) -> dict:
         "dispatches": st.dispatches,
         "mfu_prefill": round(mfu_prefill, 4),
         "decode_roofline_frac": round(roofline, 4),
-        "peak_flops_bf16": PEAK_FLOPS_BF16,
-        "hbm_bytes_per_s": HBM_BYTES_PER_S,
+        "peak_flops_bf16": peaks["flops_bf16"],
+        "hbm_bytes_per_s": peaks["hbm_bytes_per_s"],
     }
     print(f"device budget: {out}", file=sys.stderr)
     return out
@@ -537,6 +571,9 @@ def run_strategy_bench(backend, approach: str, root: str, tok_spec) -> dict:
 
 
 def main() -> int:
+    device = require_chip()
+    print(f"bench device: {device}", file=sys.stderr)
+
     from vnsum_tpu.backend.engine import TpuBackend
     from vnsum_tpu.models import llama32_3b
 
@@ -606,6 +643,7 @@ def main() -> int:
         json.dumps(
             {
                 "metric": "map_step_chunks_per_sec_per_chip_llama32_3b",
+                "device": device,
                 "value": round(chunks_per_sec, 4),
                 "unit": "chunks/s",
                 "vs_baseline": round(chunks_per_sec / REFERENCE_CHUNKS_PER_SEC, 2),

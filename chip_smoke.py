@@ -1,0 +1,1061 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — does today's code still start, compile and answer on the chip?
+
+Drives the system's main paths once, at the full width of Llama-3.2-3B with
+random weights made from a seed, through the entry points a user calls:
+
+    device   jax.devices()[0].platform must be "tpu"; versions, cache dir
+    kernels  every Pallas kernel, compiled (never interpreted), at the head
+             geometry of every lane-aligned family, against the dense
+             reference attention of models/llama.py
+    offline  PipelineRunner (what `python -m vnsum_tpu.pipeline.cli
+             --backend tpu` and bench.py run): VN-LongSum-length documents,
+             mapreduce, a full-batch S=8192 dispatch, a reduce, evaluation
+    serve    `python -m vnsum_tpu.serve.server --backend tpu --inflight
+             --fused-segments N --journal-dir ...` on a cold program cache:
+             /v1/generate (shared prefix, stream), /v1/summarize, /metrics,
+             SIGTERM drain
+    mesh     the generate step under TpuBackend(mesh=) at model=4 and
+             data=4 — only with >= 4 devices, otherwise reported as skipped
+
+One process holds the chip at a time: this parent never imports JAX and runs
+each phase in its own child. It exits non-zero if any phase failed or ran on
+a platform other than tpu, writes the full report to
+chiprun_out/chip_smoke.json, and prints as the last line of its standard
+output one JSON object: {"ok": true, "device": {"platform", "kind", "count"}}.
+
+`--rehearsal` is the debugging aid, never what the plain command does: tiny
+model, CPU, interpret-mode kernels, report marked "rehearsal": true.
+"""
+from __future__ import annotations
+
+import argparse
+import http.client
+import json
+import os
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import traceback
+from pathlib import Path
+
+HERE = Path(__file__).resolve().parent
+PHASES = ("device", "kernels", "offline", "serve", "mesh")
+# the whole run, compilation included, must end inside 1200 s
+TOTAL_BUDGET_S = 1140
+PHASE_TIMEOUT_S = {
+    "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
+}
+
+
+def sizes(rehearsal: bool) -> dict:
+    """Every size the phases use, in one place: the full-width run and the
+    tiny CPU rehearsal differ here and nowhere else."""
+    if rehearsal:
+        return dict(
+            kernel_geometries=[("tiny-g2", 4, 2, 16, 24), ("tiny-g3", 6, 2, 16, 24)],
+            kernel_S=64, kernel_off=128, kernel_C=232, kernel_B=8,
+            docs=2, words_per_doc=2400, bpe_vocab=512, chunk_size=420,
+            token_max=300, offline_batch=4, offline_max_new=8,  # rehearsal engine
+            offline_seq=640, offline_prefill_chunk=128, probe_tokens=380,
+            serve_model="tiny", serve_slots=2, serve_slot_tokens=192,
+            serve_max_new=24, serve_fused=2, serve_block_tokens=16,
+            serve_prefix_bytes=120, serve_doc_bytes=26_000,
+            mesh_prompt_bytes=200, mesh_batch=8, mesh_max_new=4,
+            mesh_seq=512,
+        )
+    return dict(
+        kernel_geometries=None,  # derived from MODEL_REGISTRY
+        # C leaves a partial tail block in every kernel (3208 % 128 == 8): past
+        # the cache's end a block holds stale VMEM, NaN patterns included
+        kernel_S=1024, kernel_off=2048, kernel_C=3208, kernel_B=8,
+        # offline: bench.py's e2e shape — chunk_size 7800 BPE tokens lands
+        # map prompts in the S=8192 bucket at B=16
+        docs=4, words_per_doc=37_000, bpe_vocab=4096, chunk_size=7_800,
+        token_max=6_000,  # batch and decode budget: bench.e2e_engine_kwargs
+        offline_seq=8448, offline_prefill_chunk=2048, probe_tokens=7_300,
+        # serve: bf16 weights (6.4 GB, the server has no --quantize) leave
+        # room for two slots whose prompt bucket holds a 12k-token map chunk
+        serve_model="llama3.2:3b", serve_slots=2, serve_slot_tokens=12_800,
+        serve_max_new=640, serve_fused=4, serve_block_tokens=64,
+        serve_prefix_bytes=6_000, serve_doc_bytes=26_000,
+        mesh_prompt_bytes=3_500, mesh_batch=8, mesh_max_new=32,
+        mesh_seq=4352,
+    )
+
+
+# ---------------------------------------------------------------------------
+# children (these import JAX; the parent below never does)
+# ---------------------------------------------------------------------------
+
+
+class Checks:
+    """Named pass/fail checks; a phase is ok when every check is."""
+
+    def __init__(self) -> None:
+        self.results: dict[str, bool] = {}
+        self.details: dict[str, object] = {}
+
+    def check(self, name: str, ok, detail=None) -> bool:
+        self.results[name] = bool(ok)
+        if detail is not None:
+            self.details[name] = detail
+        print(f"  [{'ok' if ok else 'FAIL'}] {name}"
+              + (f": {detail}" if detail is not None and not ok else ""),
+              flush=True)
+        return bool(ok)
+
+    def report(self) -> dict:
+        return {"ok": all(self.results.values()), "checks": self.results,
+                "check_details": self.details}
+
+
+def _device_report() -> dict:
+    import jax
+
+    d = jax.devices()
+    return {"platform": d[0].platform, "device_kind": d[0].device_kind,
+            "count": len(d)}
+
+
+def _memory() -> list[dict]:
+    import jax
+
+    keep = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")
+    return [
+        {"id": d.id, **{k: int(v) for k, v in (d.memory_stats() or {}).items()
+                        if k in keep}}
+        for d in jax.local_devices()
+    ]
+
+
+def _watch_compiles() -> dict:
+    """Sum XLA backend-compile seconds and count persistent-cache hits and
+    misses, from JAX's own monitoring events."""
+    import jax.monitoring as mon
+
+    seen = {"backend_compile_s": 0.0, "cache_hits": 0, "cache_misses": 0}
+
+    def on_duration(event: str, seconds: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            seen["backend_compile_s"] += seconds
+
+    def on_event(event: str, **_kw) -> None:
+        if event == "/jax/compilation_cache/cache_hits":
+            seen["cache_hits"] += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            seen["cache_misses"] += 1
+
+    mon.register_event_duration_secs_listener(on_duration)
+    mon.register_event_listener(on_event)
+    return seen
+
+
+def phase_device(args) -> dict:
+    import importlib.metadata as md
+
+    import jax
+
+    from vnsum_tpu import native
+    from vnsum_tpu.core.jax_cache import enable_compilation_cache
+
+    c = Checks()
+    dev = _device_report()
+    c.check("platform_is_tpu", dev["platform"] == "tpu" or args.rehearsal,
+            dev["platform"])
+    cache_dir = enable_compilation_cache()
+    entries = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+    def version(pkg: str) -> str:
+        try:
+            return md.version(pkg)
+        except md.PackageNotFoundError:
+            return "not installed"
+
+    return {
+        **c.report(),
+        "versions": {"python": sys.version.split()[0], "jax": jax.__version__,
+                     "jaxlib": version("jaxlib"), "libtpu": version("libtpu")},
+        "compile_cache_dir": cache_dir,
+        "compile_cache_from_env": bool(
+            os.environ.get("JAX_COMPILATION_CACHE_DIR")),
+        "compile_cache_entries_at_start": entries,
+        # the C++ host core is built with `make` on first use; without it
+        # ROUGE and the byte splitter run their slower Python twins
+        "native_available": bool(native.available()),
+    }
+
+
+def phase_kernels(args) -> dict:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from vnsum_tpu.core.jax_cache import enable_compilation_cache
+    from vnsum_tpu.models import MODEL_REGISTRY
+    from vnsum_tpu.models.llama import (
+        _attention,
+        _quantize_kv,
+        decode_attention_mask,
+        dequantize_cache_layer,
+        prefill_attention_mask,
+        verify_attention_mask,
+    )
+    from vnsum_tpu.ops.decode_attention import (
+        flash_decode_attention,
+        flash_spec_verify_attention,
+    )
+    from vnsum_tpu.ops.flash_attention import flash_prefill_attention
+
+    enable_compilation_cache()
+    sz = sizes(args.rehearsal)
+    interpret = args.rehearsal
+    c = Checks()
+    geoms = sz["kernel_geometries"]
+    if geoms is None:
+        # one entry per distinct head geometry among the lane-aligned
+        # families (head_dim % 128 — the others take the dense path by rule)
+        seen: dict = {}
+        for name, factory in MODEL_REGISTRY.items():
+            cfg = factory()
+            key = (cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
+            if cfg.head_dim % 128 == 0 and key not in seen:
+                seen[key] = (name, *key, cfg.sliding_window or 1024)
+        geoms = list(seen.values())
+    S, OFF, C, B = (sz["kernel_S"], sz["kernel_off"], sz["kernel_C"],
+                    sz["kernel_B"])
+    L, LAYER, BP = 2, 1, 2  # two-layer cache, read layer 1; prefill rows
+    rng = np.random.default_rng(0)
+    pads_np = rng.integers(1, S // 2, size=B).astype(np.int32)
+    pads_np[0] = 0                                  # one row with no padding
+    pads = jnp.asarray(pads_np)
+    fill = C - 9                                    # decode: last valid slot
+    fills = jnp.asarray(
+        rng.integers(OFF, C - 8, size=B).astype(np.int32), jnp.int32)
+    TOL = 2e-2  # max abs error over the reference's max abs value
+    rows: list[dict] = []
+
+    # the reference: models.llama's dense attention over the dequantized
+    # layer, with the slot-space window of models.llama._block. One program
+    # per query shape — the bf16 and int8 caches share it, as do decode and
+    # the Sq=1 verify — so compiling references does not dwarf the kernels
+    @jax.jit
+    def dense(q, k, v, mask, q_slots, win):
+        k_slot = jnp.arange(C)[None, None, :]
+        mask = mask & ((win == 0) | (k_slot > q_slots[:, :, None] - win))
+        return _attention(q, k, v, mask, q.shape[2] // k.shape[1])
+
+    @jax.jit
+    def measure(got, want, valid):
+        got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+        got = jnp.where(valid, got, 0.0)
+        return (jnp.max(jnp.abs(got - jnp.where(valid, want, 0.0))),
+                jnp.max(jnp.abs(want)), jnp.all(jnp.isfinite(got)))
+
+    def compare(label, kernel, want, valid):
+        t0 = time.perf_counter()
+        try:
+            got = jax.block_until_ready(kernel())
+        except Exception as e:  # a compiler refusal is that case's finding
+            c.check(label, False, f"{type(e).__name__}: {str(e)[:1500]}")
+            return
+        first_call_s = time.perf_counter() - t0
+        err, scale, finite = (
+            x.item() for x in measure(got, want, jnp.asarray(valid)))
+        rows.append({"case": label, "max_abs_err": round(err, 5),
+                     "ref_max": round(scale, 3),
+                     "first_call_s": round(first_call_s, 2)})
+        c.check(label, finite and err <= TOL * scale,
+                {"err": err, "ref_max": scale})
+
+    for name, H, KV, hd, win in geoms:
+        G = H // KV
+        for quantized in (False, True):
+            tag = f"{name}/G{G}hd{hd}/{'int8' if quantized else 'bf16'}"
+            t0 = time.time()
+            kk, kv_, kq = jax.random.split(jax.random.key(H * 1000 + hd), 3)
+            k = jax.random.normal(kk, (L, B, KV, C, hd), jnp.bfloat16)
+            v = jax.random.normal(kv_, (L, B, KV, C, hd), jnp.bfloat16)
+            if quantized:
+                k8, ks = _quantize_kv(k)
+                v8, vs = _quantize_kv(v)
+                cache = {"k": k8, "v": v8, "ks": ks, "vs": vs}
+            else:
+                cache = {"k": k, "v": v}
+            cache_p = {n: a[:, :BP] for n, a in cache.items()}
+            pads_p = pads[:BP]
+            kd, vd = (a.astype(jnp.bfloat16)
+                      for a in dequantize_cache_layer(cache, LAYER))
+            # peaked softmax (score std ~3) so a masking slip moves outputs
+            # by far more than bf16 rounding does
+            qs = jax.random.normal(kq, (B, S, H, hd), jnp.bfloat16) * 3.0
+            qp, q1, q5 = qs[:BP], qs[:, :1], qs[:, :5]
+            for wname, w in (("global", 0), (f"win{win}", win)):
+                wj = jnp.int32(w)
+                # prefill, whole prompt and at a q_offset chunk (one
+                # compiled kernel: the offset is a runtime scalar)
+                for off in (0, OFF):
+                    q_slots = off + jnp.broadcast_to(
+                        jnp.arange(S)[None, :], (BP, S))
+                    mask = prefill_attention_mask(pads_p, off + S, C)[:, off:]
+                    want = dense(qp, kd[:BP], vd[:BP], mask, q_slots, wj)
+                    valid = (q_slots >= pads_p[:, None])[:, :, None, None]
+                    compare(
+                        f"{tag}/{wname}/prefill@{off}",
+                        lambda: flash_prefill_attention(
+                            qp, cache_p, LAYER, pads_p, G, wj,
+                            jnp.int32(off), interpret=interpret),
+                        want, valid)
+                # decode (one query at a shared fill)
+                mask = decode_attention_mask(pads, fill, C)
+                q_slots = jnp.full((B, 1), fill)
+                want = dense(q1, kd, vd, mask, q_slots, wj)
+                compare(
+                    f"{tag}/{wname}/decode",
+                    lambda: flash_decode_attention(
+                        q1, cache, LAYER, pads, fill, G, wj,
+                        interpret=interpret),
+                    want, True)
+                # verify: Sq=1 is the slot loop's every decode step,
+                # Sq=5 the speculative step (k=4), per-row fills
+                for Sq, q in ((1, q1), (5, q5)):
+                    mask = verify_attention_mask(pads, fills, Sq, C)
+                    q_slots = fills[:, None] + jnp.arange(Sq)[None, :]
+                    want = dense(q, kd, vd, mask, q_slots, wj)
+                    compare(
+                        f"{tag}/{wname}/verify_sq{Sq}",
+                        lambda q=q: flash_spec_verify_attention(
+                            q, cache, LAYER, pads, fills, G, wj,
+                            interpret=interpret),
+                        want, True)
+            print(f"  {tag}: {time.time() - t0:.1f}s", flush=True)
+
+    # does block_until_ready wait for the device? (dispatch returns early;
+    # a fetch after the block must find the work already done)
+    n, reps = (256, 4) if args.rehearsal else (8192, 200)
+    x = jnp.full((n, n), 1.0 / n, jnp.bfloat16)
+
+    @jax.jit
+    def chain(a):
+        return jax.lax.fori_loop(0, reps, lambda _i, y: (y @ a) * 1.0, a)
+
+    float(chain(x)[0, 0])  # compile + warm, the fetch's slice program too
+    t0 = time.perf_counter()
+    y = chain(x)
+    t1 = time.perf_counter()
+    y.block_until_ready()
+    t2 = time.perf_counter()
+    float(y[0, 0])
+    t3 = time.perf_counter()
+    sync = {"dispatch_s": round(t1 - t0, 4), "block_s": round(t2 - t1, 4),
+            "fetch_after_block_s": round(t3 - t2, 4),
+            "flops": 2.0 * n ** 3 * reps}
+    c.check("block_until_ready_waits",
+            args.rehearsal or (t3 - t2) < max(0.05, 0.2 * (t2 - t1)), sync)
+    return {**c.report(), "cases": rows, "tolerance": TOL,
+            "geometries": [list(g) for g in geoms], "sync_probe": sync}
+
+
+def _offline_engine(args, sz, tok_spec):
+    """The e2e engine of bench.py (one copy of that configuration), or its
+    tiny interpret-mode stand-in for the rehearsal."""
+    from vnsum_tpu.backend.engine import TpuBackend
+
+    if not args.rehearsal:
+        import bench
+
+        return TpuBackend(**bench.e2e_engine_kwargs(tok_spec, None))
+    from vnsum_tpu.models import tiny_llama
+
+    return TpuBackend(
+        model_config=tiny_llama(
+            vocab_size=sz["bpe_vocab"] + 64, max_seq_len=sz["offline_seq"]),
+        tokenizer=tok_spec, batch_size=sz["offline_batch"],
+        max_new_tokens=sz["offline_max_new"], quantize=True,
+        quantize_act=True, prefill_chunk_tokens=sz["offline_prefill_chunk"],
+        interpret=True,
+    )
+
+
+def phase_offline(args) -> dict:
+    import jax
+
+    import bench
+    from vnsum_tpu.core.config import GenerationConfig, PipelineConfig
+    from vnsum_tpu.data.synthesize import synthesize_corpus
+    from vnsum_tpu.models.fixtures import train_bpe_tokenizer
+    from vnsum_tpu.pipeline.cli import failures
+    from vnsum_tpu.pipeline.runner import PipelineRunner
+
+    sz = sizes(args.rehearsal)
+    c = Checks()
+    root = Path(args.work) / "offline"
+    corpus = synthesize_corpus(
+        root / "corpus", n_docs=sz["docs"], tokens_per_doc=sz["words_per_doc"],
+        summary_tokens=714, seed=7, ragged=0.2,
+    )
+    doc_paths = sorted((root / "corpus/doc").glob("*.txt"))
+    # the quality-run configuration tokenizes with the checkpoint's BPE, not
+    # raw bytes; train one on the corpus as bench.py does
+    hf_tok = train_bpe_tokenizer(
+        (p.read_text(encoding="utf-8") for p in doc_paths),
+        vocab_size=sz["bpe_vocab"],
+    )
+    hf_tok.save_pretrained(str(root / "tok"))
+    tok_spec = f"hf:{root / 'tok'}"
+    sample = doc_paths[0].read_text(encoding="utf-8")
+    bytes_per_tok = len(sample.encode()) / len(hf_tok.encode(sample))
+
+    t0 = time.time()
+    backend = _offline_engine(args, sz, tok_spec)
+    init_s = time.time() - t0
+    c.check("engine_platform_is_tpu",
+            backend.platform == "tpu" or args.rehearsal, backend.platform)
+
+    # random-init weights under greedy decode can emit EOS at step 0; use
+    # bench.py's sampled ragged-EOS recipe, at full batch so the dominant
+    # (B, S=8192) program is the one the probe compiles
+    raw = b" ".join(p.read_text(encoding="utf-8").encode() for p in doc_paths)
+    step = int(sz["probe_tokens"] * bytes_per_tok)
+    nb = backend.batch_size
+    if len(raw) < nb * step:
+        raise RuntimeError(f"corpus too small for the probe: {len(raw)} < "
+                           f"{nb * step}")
+    probe = backend.generate(
+        ["Tóm tắt: " + raw[i * step:(i + 1) * step].decode("utf-8", "ignore")
+         for i in range(nb)],
+        config=GenerationConfig(temperature=1.0, seed=11),
+    )
+    max_new = backend.max_new_tokens
+    eos = bench._pick_ragged_eos(probe, backend.tok, max_new)
+    backend.gen_cfg = GenerationConfig(
+        max_new_tokens=max_new, temperature=1.0, seed=11,
+        eos_ids=eos,
+    )
+
+    model = "llama3.2-3b"
+    cfg = PipelineConfig(
+        approach="mapreduce", models=[model], backend="tpu",
+        docs_dir=str(root / "corpus/doc"),
+        summary_dir=str(root / "corpus/summary"),
+        generated_summaries_dir=str(root / "gen"),
+        results_dir=str(root / "results"), logs_dir=str(root / "logs"),
+        chunk_size=sz["chunk_size"], chunk_overlap=sz["chunk_size"] // 39,
+        token_max=sz["token_max"], max_new_tokens=max_new,
+        batch_size=backend.batch_size, tokenizer=tok_spec,
+    )
+    t0 = time.time()
+    results = PipelineRunner(cfg, backend_factory=lambda _m: backend).run()
+    pipeline_s = time.time() - t0
+
+    rec = results.summarization[model]
+    details = rec["processing_details"]
+    st = backend.stats
+    c.check("no_pipeline_failures", not failures(results), failures(results))
+    c.check("every_document_success",
+            rec["successful"] == sz["docs"] and rec["failed"] == 0
+            and all(d["status"] == "success" for d in details),
+            {"successful": rec["successful"], "failed": rec["failed"]})
+    c.check("generated_tokens_positive", st.generated_tokens > 0,
+            st.generated_tokens)
+    S_big = 8192 if not args.rehearsal else max(s for _b, s in st.by_bucket)
+    full = {f"B={b},S={s}": n for (b, s), n in st.by_bucket.items()}
+    c.check("full_batch_dispatch_in_top_bucket",
+            args.rehearsal
+            or st.by_bucket.get((backend.batch_size, S_big), 0) >= 1, full)
+    c.check("reduce_dispatch_ran",
+            sum(d["llm_calls"] for d in details) > rec["total_chunks"],
+            {"llm_calls": sum(d["llm_calls"] for d in details),
+             "chunks": rec["total_chunks"]})
+    ev = results.evaluation.get(model, {})
+    c.check("evaluation_present",
+            "rouge_scores" in ev and "bert_scores" in ev
+            and "semantic_similarity" in ev, sorted(ev))
+    paths = st.attention_paths
+    c.check("attention_path_kernel",
+            bool(paths) and all(p == "kernel" for prog in paths.values()
+                                for p in prog.values()), paths)
+    if not args.rehearsal:
+        # flash resolved on: the generate program carries Mosaic custom calls
+        B, S = backend.batch_size, 8192
+        lowered = backend._make_fn(
+            B, S, max_new, backend.gen_cfg
+        ).lower(
+            backend.params, jax.ShapeDtypeStruct((B, S), "int32"),
+            jax.ShapeDtypeStruct((B,), "int32"), 0,
+        ).as_text()
+        c.check("mosaic_custom_calls_in_generate_program",
+                "tpu_custom_call" in lowered,
+                lowered.count("tpu_custom_call"))
+    return {
+        **c.report(),
+        "corpus": {"docs": sz["docs"],
+                   "avg_words": corpus["documents"]["avg_tokens_per_file"],
+                   "bytes_per_bpe_token": round(bytes_per_tok, 2)},
+        "engine_init_s": round(init_s, 1),
+        "pipeline_s": round(pipeline_s, 1),
+        "engine_first_call_s": round(st.compile_seconds, 1),
+        "dispatches": full, "attention_paths": paths,
+        "documents": [{k: d[k] for k in ("filename", "num_chunks", "llm_calls",
+                                         "summary_length_chars", "status")}
+                      for d in details],
+        "generated_tokens": st.generated_tokens,
+        "prompt_tokens": st.prompt_tokens,
+        "eos_ids": list(eos),
+        "evaluation_keys": sorted(ev),
+    }
+
+
+def phase_mesh(args) -> dict:
+    import jax
+
+    n = jax.device_count()
+    if n < 4:
+        return {"ok": True, "skipped": f"{n} devices", "checks": {}}
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models import llama32_3b, tiny_llama
+    from vnsum_tpu.parallel import make_mesh
+
+    sz = sizes(args.rehearsal)
+    c = Checks()
+    runs = {}
+    filler = "Quốc hội đã thông qua nghị quyết về phát triển kinh tế xã hội. "
+    prompts = [
+        (filler * (sz["mesh_prompt_bytes"] // len(filler.encode()) + 1))
+        + f"(tài liệu {i})" for i in range(sz["mesh_batch"])
+    ]
+    for spec in ({"model": 4}, {"data": 4}):
+        tag = ",".join(f"{k}={v}" for k, v in spec.items())
+        mesh = make_mesh(spec)
+        model_cfg = (
+            tiny_llama(max_seq_len=sz["mesh_seq"], n_heads=8, n_kv_heads=4)
+            if args.rehearsal else llama32_3b(max_seq_len=sz["mesh_seq"])
+        )
+        t0 = time.time()
+        be = TpuBackend(
+            model_config=model_cfg, mesh=mesh, batch_size=sz["mesh_batch"],
+            max_new_tokens=sz["mesh_max_new"], quantize=True,
+            quantize_act=True, interpret=args.rehearsal,
+            generation=GenerationConfig(temperature=1.0, seed=5),
+            # the prefix pool keeps KV blocks after the call: cache shards
+            # that can be inspected, not only a program's temporaries
+            cache_blocks=128,
+        )
+        before = _memory()
+        outs = be.generate(prompts)
+        wall = time.time() - t0
+        c.check(f"{tag}/outputs", len(outs) == len(prompts)
+                and be.stats.generated_tokens > 0, be.stats.generated_tokens)
+        c.check(f"{tag}/attention_path_kernel",
+                all(p == "kernel" for prog in be.stats.attention_paths.values()
+                    for p in prog.values()), be.stats.attention_paths)
+        # every device holds parameter shards, not device 0 alone
+        leaves = jax.tree.leaves(be.params)
+        holders = {s.device.id for leaf in leaves
+                   for s in leaf.addressable_shards}
+        c.check(f"{tag}/params_on_every_device", len(holders) == 4,
+                sorted(holders))
+        if "model" in spec:
+            wq = be.params["layers"]["wq"]
+            wq = wq["q"] if isinstance(wq, dict) else wq
+            shard = wq.addressable_shards[0].data.shape
+            c.check(f"{tag}/wq_is_sharded", shard != wq.shape,
+                    {"global": list(wq.shape), "shard": list(shard)})
+        pool_k = be.prefix_cache.store.pool["k"]  # [N, L, KV, BLK, hd]
+        pool_holders = {s.device.id for s in pool_k.addressable_shards}
+        kv_per_shard = pool_k.addressable_shards[0].data.shape[2]
+        c.check(f"{tag}/cache_blocks_on_every_device",
+                len(pool_holders) == 4
+                and be.prefix_cache.stats_dict()["blocks_used"] > 0
+                and kv_per_shard == pool_k.shape[2] // spec.get("model", 1),
+                {"holders": sorted(pool_holders),
+                 "kv_heads_per_shard": kv_per_shard,
+                 "stats": be.prefix_cache.stats_dict()})
+        mem = _memory()
+        held = [m.get("bytes_in_use", 0) for m in mem]
+        # resident bytes are parameter + pool shards: even, not device 0's
+        c.check(f"{tag}/resident_bytes_balanced",
+                args.rehearsal or min(held) > 0.5 * max(held), held)
+        runs[tag] = {"wall_s": round(wall, 1),
+                     "first_call_s": round(be.stats.compile_seconds, 1),
+                     "memory_before_generate": before, "memory": mem,
+                     "attention_paths": be.stats.attention_paths}
+        del be
+    return {**c.report(), "runs": runs}
+
+
+def _rehearsal_server(argv: list[str]) -> int:
+    """The rehearsal's server child: the real serve.server.main, with the
+    engine's kernels emulated (the product has no such flag, on purpose)."""
+    from vnsum_tpu.backend import engine
+
+    class InterpretedBackend(engine.TpuBackend):
+        def __init__(self, *a, **kw):
+            kw.setdefault("interpret", True)
+            super().__init__(*a, **kw)
+
+    engine.TpuBackend = InterpretedBackend
+    from vnsum_tpu.serve.server import main as server_main
+
+    return server_main(argv)
+
+
+def _child(args) -> int:
+    phase = args.phase
+    if phase == "rehearsal-server":
+        return _rehearsal_server(args.rest)
+    t0 = time.time()
+    rep: dict = {"phase": phase, "ok": False}
+    try:
+        compiles = _watch_compiles()
+        rep.update({"device": phase_device, "kernels": phase_kernels,
+                    "offline": phase_offline, "mesh": phase_mesh}[phase](args))
+        rep["device"] = _device_report()
+        rep["memory"] = _memory()
+        rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v
+                          for k, v in compiles.items()}
+        if rep["device"]["platform"] != "tpu" and not args.rehearsal:
+            rep["ok"] = False
+            rep["error"] = f"ran on platform {rep['device']['platform']!r}"
+    except Exception:
+        rep["ok"] = False
+        rep["error"] = traceback.format_exc()[-6000:]
+        traceback.print_exc()
+    rep["wall_s"] = round(time.time() - t0, 1)
+    Path(args.report).write_text(json.dumps(rep, indent=1, default=str))
+    return 0 if rep["ok"] else 1
+
+
+# ---------------------------------------------------------------------------
+# parent: no JAX from here down
+# ---------------------------------------------------------------------------
+
+
+def _kill(proc: subprocess.Popen) -> None:
+    if proc.poll() is None:
+        try:
+            os.killpg(proc.pid, signal.SIGKILL)
+        except ProcessLookupError:
+            pass
+        proc.wait()
+
+
+def _tail(path: Path, n: int = 4000) -> str:
+    try:
+        return path.read_text(errors="replace")[-n:]
+    except OSError:
+        return ""
+
+
+def run_child(phase: str, env: dict, work: Path, logs: Path,
+              timeout_s: float, rehearsal: bool) -> dict:
+    out = work / f"{phase}.json"
+    log = logs / f"chip_smoke_{phase}.log"
+    cmd = [sys.executable, str(HERE / "chip_smoke.py"), "--phase", phase,
+           "--report", str(out), "--work", str(work)]
+    if rehearsal:
+        cmd.append("--rehearsal")
+    with open(log, "w") as fh:
+        proc = subprocess.Popen(cmd, env=env, cwd=HERE, stdout=fh,
+                                stderr=subprocess.STDOUT,
+                                start_new_session=True)
+        try:
+            rc = proc.wait(timeout=timeout_s)
+        except subprocess.TimeoutExpired:
+            _kill(proc)
+            return {"phase": phase, "ok": False,
+                    "error": f"timed out after {timeout_s:.0f}s",
+                    "log_tail": _tail(log)}
+        finally:
+            _kill(proc)
+    if out.is_file():
+        rep = json.loads(out.read_text())
+    else:
+        rep = {"phase": phase, "ok": False,
+               "error": f"child exited {rc} without a report"}
+    if not rep.get("ok"):
+        rep["log_tail"] = _tail(log)
+    return rep
+
+
+def _http(port: int, method: str, path: str, body: dict | None = None,
+          timeout: float = 600.0):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        data = None if body is None else json.dumps(body).encode()
+        conn.request(method, path, body=data,
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        raw = resp.read()
+        return resp.status, raw.decode("utf-8", "replace")
+    finally:
+        conn.close()
+
+
+def _sse(port: int, path: str, body: dict, timeout: float = 600.0):
+    """POST and read a text/event-stream to its end: (status, events)."""
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=timeout)
+    try:
+        conn.request("POST", path, body=json.dumps(body).encode(),
+                     headers={"Content-Type": "application/json"})
+        resp = conn.getresponse()
+        text = resp.read().decode("utf-8", "replace")
+    finally:
+        conn.close()
+    events = []
+    for frame in text.split("\n\n"):
+        name, data = None, None
+        for line in frame.splitlines():
+            if line.startswith("event: "):
+                name = line[7:]
+            elif line.startswith("data: "):
+                data = line[6:]
+        if name and data is not None:
+            events.append((name, json.loads(data)))
+    return resp.status, events
+
+
+def _metric(text: str, name: str) -> float | None:
+    """Sum of one Prometheus family's samples (labels folded)."""
+    total, found = 0.0, False
+    for line in text.splitlines():
+        if line.startswith("#") or not line.startswith(name):
+            continue
+        head, _, val = line.rpartition(" ")
+        if head == name or head.startswith(name + "{"):
+            total += float(val)
+            found = True
+    return total if found else None
+
+
+def _vn_text(n_bytes: int, salt: str) -> str:
+    base = ("Quốc hội đã thông qua nghị quyết về phát triển kinh tế xã hội "
+            f"giai đoạn mới ({salt}). Các đại biểu đề nghị tiếp tục hoàn "
+            "thiện thể chế, nâng cao chất lượng nguồn nhân lực và bảo vệ "
+            "môi trường.\n\n")
+    out = base * (n_bytes // len(base.encode()) + 1)
+    return out.encode()[:n_bytes].decode("utf-8", "ignore")
+
+
+def phase_serve(env: dict, work: Path, logs: Path, timeout_s: float,
+                rehearsal: bool) -> dict:
+    sz = sizes(rehearsal)
+    c = Checks()
+    t_start = time.time()
+    deadline = t_start + timeout_s
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        port = s.getsockname()[1]
+    journal, flight = work / "journal", work / "flight"
+    server_args = [
+        "--backend", "tpu", "--model", sz["serve_model"], "--inflight",
+        "--fused-segments", str(sz["serve_fused"]),
+        "--slots", str(sz["serve_slots"]),
+        "--max-batch", str(sz["serve_slots"]),
+        "--slot-prompt-tokens", str(sz["serve_slot_tokens"]),
+        "--max-new-tokens", str(sz["serve_max_new"]),
+        "--cache-block-tokens", str(sz["serve_block_tokens"]),
+        "--journal-dir", str(journal), "--flight-dir", str(flight),
+        "--port", str(port),
+    ]
+    if rehearsal:
+        cmd = [sys.executable, str(HERE / "chip_smoke.py"), "--phase",
+               "rehearsal-server", "--", *server_args]
+    else:
+        cmd = [sys.executable, "-m", "vnsum_tpu.serve.server", *server_args]
+    log = logs / "chip_smoke_serve.log"
+    rep: dict = {"phase": "serve", "command": " ".join(cmd[1:]),
+                 "sanitizers": env.get("VNSUM_SANITIZERS", "")}
+    MN = sz["serve_max_new"]
+    fh = open(log, "w")
+    proc = subprocess.Popen(cmd, env=env, cwd=HERE, stdout=fh,
+                            stderr=subprocess.STDOUT, start_new_session=True)
+    try:
+        # -- wait for /readyz ------------------------------------------------
+        ready = False
+        while time.time() < deadline and proc.poll() is None:
+            try:
+                status, _ = _http(port, "GET", "/readyz", timeout=2.0)
+                if status == 200:
+                    ready = True
+                    break
+            except OSError:
+                pass
+            time.sleep(0.5)
+        rep["ready_s"] = round(time.time() - t_start, 1)
+        if not c.check("server_ready", ready,
+                       f"exit={proc.poll()} after {rep['ready_s']}s"):
+            return {**rep, **c.report(), "log_tail": _tail(log)}
+        left = lambda: max(deadline - time.time(), 5.0)  # noqa: E731
+
+        def generate(prompt: str, rid: str) -> dict:
+            status, body = _http(port, "POST", "/v1/generate", {
+                "prompt": prompt, "max_new_tokens": MN, "request_id": rid,
+            }, timeout=left())
+            out = {"rid": rid, "status": status}
+            if status == 200:
+                rec = json.loads(body)["completions"][0]["record"]
+                out.update(generated_tokens=rec["generated_tokens"],
+                           cached_prompt_tokens=rec["cached_prompt_tokens"],
+                           total_s=round(rec["total_s"], 2),
+                           ttft_s=round(rec["ttft_s"], 2))
+            else:
+                out["body"] = body[:400]
+            return out
+
+        prefix = _vn_text(sz["serve_prefix_bytes"], "phần chung")
+        # -- the first request, on a cold program cache ----------------------
+        t0 = time.time()
+        first = generate(prefix + " Hãy tóm tắt ý chính thứ nhất.", "smoke-a")
+        first["wall_s"] = round(time.time() - t0, 1)
+        c.check("first_request_200", first["status"] == 200, first)
+        # -- concurrent: one sharing smoke-a's prefix, one plain, one stream -
+        replies: dict = {}
+
+        def run(name, fn):
+            try:
+                replies[name] = fn()
+            except Exception as e:
+                replies[name] = {"status": -1, "error": repr(e)}
+
+        def stream() -> dict:
+            status, events = _sse(port, "/v1/generate", {
+                "prompt": "Viết một câu ngắn về biến đổi khí hậu.",
+                "max_new_tokens": MN, "request_id": "smoke-stream",
+                "stream": True,
+            }, timeout=left())
+            deltas = "".join(p.get("text", "") for n, p in events
+                             if n == "delta")
+            done = [p for n, p in events if n == "done"]
+            final = done[0]["completions"][0] if done else None
+            return {
+                "status": status, "events": len(events),
+                "delta_events": sum(n == "delta" for n, _ in events),
+                "deltas_equal_done": final is not None
+                and deltas == final["text"],
+                "generated_tokens": final["record"]["generated_tokens"]
+                if final else 0,
+            }
+
+        threads = [
+            threading.Thread(target=run, args=("shared", lambda: generate(
+                prefix + " Hãy nêu ý chính thứ hai, thật ngắn gọn.",
+                "smoke-b"))),
+            threading.Thread(target=run, args=("plain", lambda: generate(
+                "Tóm tắt: giáo dục phổ thông đang đổi mới.", "smoke-c"))),
+            threading.Thread(target=run, args=("stream", stream)),
+        ]
+        for t in threads:
+            t.start()
+            time.sleep(0.05)  # arrival order: shared, plain, stream
+        for t in threads:
+            t.join()
+        rep["requests"] = {"first": first, **replies}
+        gens = [first, replies["shared"], replies["plain"]]
+        c.check("generate_all_200", all(r.get("status") == 200 for r in gens),
+                gens)
+        c.check("generate_tokens_positive",
+                all(r.get("generated_tokens", 0) > 0 for r in gens),
+                [r.get("generated_tokens") for r in gens])
+        c.check("stream_200_and_deltas_equal_done",
+                replies["stream"].get("status") == 200
+                and replies["stream"].get("deltas_equal_done")
+                and replies["stream"].get("generated_tokens", 0) > 0,
+                replies["stream"])
+        # -- /v1/summarize, mapreduce over a multi-chunk text ----------------
+        t0 = time.time()
+        status, body = _http(port, "POST", "/v1/summarize", {
+            "text": _vn_text(sz["serve_doc_bytes"], "văn bản dài"),
+            "approach": "mapreduce", "max_new_tokens": MN,
+            "request_id": "smoke-sum",
+        }, timeout=left())
+        summ = {"status": status, "wall_s": round(time.time() - t0, 1)}
+        if status == 200:
+            payload = json.loads(body)
+            summ.update(num_chunks=payload["num_chunks"],
+                        llm_calls=payload["llm_calls"],
+                        generated_tokens=payload["serving"]["generated_tokens"])
+        else:
+            summ["body"] = body[:400]
+        rep["summarize"] = summ
+        c.check("summarize_200_multi_chunk",
+                status == 200 and summ.get("num_chunks", 0) >= 2
+                and summ.get("generated_tokens", 0) > 0, summ)
+        # -- /metrics and /healthz -------------------------------------------
+        _, metrics = _http(port, "GET", "/metrics", timeout=30.0)
+        m = {name: _metric(metrics, f"vnsum_serve_{name}") for name in (
+            "watchdog_stalls_total", "watchdog_hung_dispatches_total",
+            "fault_failures_total", "fault_retries_total",
+            "degraded_steps_total", "degraded_rung",
+            "inflight_fused_dispatches_total", "inflight_segments_total",
+            "cache_hit_tokens_total", "requests_total",
+        )}
+        rep["metrics"] = m
+        for name in ("watchdog_stalls_total", "watchdog_hung_dispatches_total",
+                     "fault_failures_total", "fault_retries_total",
+                     "degraded_steps_total", "degraded_rung"):
+            c.check(f"{name}_is_0", not m[name], m[name])
+        c.check("inflight_fused_dispatches_positive",
+                (m["inflight_fused_dispatches_total"] or 0) > 0,
+                m["inflight_fused_dispatches_total"])
+        c.check("cache_hit_tokens_positive",
+                (m["cache_hit_tokens_total"] or 0) > 0,
+                m["cache_hit_tokens_total"])
+        _, health = _http(port, "GET", "/healthz", timeout=30.0)
+        engine = json.loads(health).get("engine", {})
+        rep["engine"] = engine
+        rep["device"] = {"platform": engine.get("platform"),
+                         "device_kind": engine.get("device_kind"),
+                         "count": engine.get("device_count")}
+        rep["memory"] = engine.get("memory")
+        c.check("engine_platform_is_tpu",
+                engine.get("platform") == "tpu" or rehearsal,
+                engine.get("platform"))
+        paths = engine.get("attention_paths", {})
+        c.check("attention_path_kernel",
+                bool(paths) and all(p == "kernel" for prog in paths.values()
+                                    for p in prog.values()), paths)
+        # -- SIGTERM drains, seals and exits 0 -------------------------------
+        proc.send_signal(signal.SIGTERM)
+        try:
+            rc = proc.wait(timeout=60.0)
+        except subprocess.TimeoutExpired:
+            rc = None
+        c.check("sigterm_exit_0", rc == 0, rc)
+        ledger = subprocess.run(
+            [sys.executable, "-m", "vnsum_tpu.serve.journal", str(journal)],
+            env={**env, "JAX_PLATFORMS": "cpu"}, cwd=HERE,
+            capture_output=True, text=True, timeout=120,
+        )
+        try:
+            led = json.loads(ledger.stdout)
+            rep["journal"] = {k: led[k] for k in (
+                "sealed", "torn_records", "entries", "live", "by_status")}
+            c.check("journal_sealed_nothing_owed",
+                    led["sealed"] and led["live"] == 0, rep["journal"])
+        except (ValueError, KeyError):
+            c.check("journal_sealed_nothing_owed", False,
+                    (ledger.stdout + ledger.stderr)[-400:])
+    finally:
+        _kill(proc)
+        fh.close()
+    rep.update(c.report())
+    rep["wall_s"] = round(time.time() - t_start, 1)
+    if not rep["ok"]:
+        rep["log_tail"] = _tail(log)
+    return rep
+
+
+def main(argv: list[str] | None = None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--rehearsal", action="store_true",
+                    help="tiny model on the CPU with interpret-mode kernels; "
+                         "for debugging this script, never a result")
+    ap.add_argument("--phases", default=",".join(PHASES),
+                    help="comma-separated subset of " + ",".join(PHASES))
+    ap.add_argument("--out", default=str(HERE / "chiprun_out/chip_smoke.json"))
+    # child plumbing
+    ap.add_argument("--phase", help=argparse.SUPPRESS)
+    ap.add_argument("--report", help=argparse.SUPPRESS)
+    ap.add_argument("--work", help=argparse.SUPPRESS)
+    ap.add_argument("rest", nargs="*", help=argparse.SUPPRESS)
+    args = ap.parse_args(argv)
+
+    if not (HERE / "vnsum_tpu").is_dir():
+        print("chip_smoke: no vnsum_tpu package next to this script — it "
+              "drives the repository, it is not the program", file=sys.stderr)
+        return 2
+    if args.phase:
+        return _child(args)
+
+    t_start = time.time()
+    phases = [p for p in args.phases.split(",") if p]
+    unknown = [p for p in phases if p not in PHASES]
+    if unknown:
+        ap.error(f"unknown phase(s) {unknown}")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = f"{HERE}{os.pathsep}{env.get('PYTHONPATH', '')}"
+    if args.rehearsal:
+        env["JAX_PLATFORMS"] = "cpu"
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
+                            + " --xla_force_host_platform_device_count=8")
+    out = Path(args.out)
+    out.parent.mkdir(parents=True, exist_ok=True)
+    work = Path(tempfile.mkdtemp(prefix="chip_smoke_"))
+    report: dict = {"rehearsal": args.rehearsal, "phases": {},
+                    "started_unix": round(t_start)}
+
+    def remaining() -> float:
+        return TOTAL_BUDGET_S - (time.time() - t_start)
+
+    # the device comes first, always: nothing is timed or served off-chip
+    dev = run_child("device", env, work, out.parent,
+                    min(PHASE_TIMEOUT_S["device"], remaining()),
+                    args.rehearsal)
+    report["phases"]["device"] = dev
+    device = dev.get("device") or {}
+    platform = device.get("platform")
+    if platform != "tpu" and not args.rehearsal:
+        out.write_text(json.dumps(report, indent=1))
+        print(f"chip_smoke: JAX found platform {platform!r}, not 'tpu' — "
+              f"nothing was run. {dev.get('error', '')[-800:]}"
+              f"{dev.get('log_tail', '')[-800:]}", file=sys.stderr)
+        return 1
+    print(f"device: {device}  versions: {dev.get('versions')}  cache: "
+          f"{dev.get('compile_cache_dir')} "
+          f"({dev.get('compile_cache_entries_at_start')} entries)", flush=True)
+
+    for phase in phases:
+        if phase == "device":
+            continue
+        budget = min(PHASE_TIMEOUT_S[phase], remaining())
+        if budget <= 10:
+            report["phases"][phase] = {
+                "ok": False, "error": "no time left inside the 1200 s limit"}
+            continue
+        t0 = time.time()
+        if phase == "serve":
+            rep = phase_serve(env, work, out.parent, budget, args.rehearsal)
+        else:
+            rep = run_child(phase, env, work, out.parent, budget,
+                            args.rehearsal)
+        report["phases"][phase] = rep
+        tag = ("skipped: " + rep["skipped"] if rep.get("skipped")
+               else "ok" if rep.get("ok") else "FAILED")
+        print(f"{phase}: {tag} in {time.time() - t0:.0f}s", flush=True)
+        if not rep.get("ok"):
+            bad = [k for k, v in rep.get("checks", {}).items() if not v]
+            print(f"  failed checks: {bad}\n  {rep.get('error', '')[-1500:]}",
+                  file=sys.stderr, flush=True)
+
+    ran = report["phases"]
+    off_chip = [p for p, r in ran.items()
+                if (r.get("device") or {}).get("platform") not in ("tpu", None)]
+    ok = all(r.get("ok") for r in ran.values()) and (
+        args.rehearsal or not off_chip)
+    report["ok"] = ok
+    report["wall_s"] = round(time.time() - t_start, 1)
+    out.write_text(json.dumps(report, indent=1))
+    shutil.rmtree(work, ignore_errors=True)
+    result = {
+        "ok": ok,
+        "device": {"platform": platform, "kind": device.get("device_kind"),
+                   "count": device.get("count")},
+        "phases": {p: ("skipped" if r.get("skipped") else bool(r.get("ok")))
+                   for p, r in ran.items()},
+        "seconds": report["wall_s"], "report": str(out),
+    }
+    if args.rehearsal:
+        result["rehearsal"] = True
+    print(json.dumps(result))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    raise SystemExit(main())

@@ -38,9 +38,11 @@ _FILLER = (
 )
 
 
-def probe_real_shape(label: str, cfg_factory, ladder, max_new: int = 64) -> dict:
+def probe_real_shape(label: str, cfg_factory, ladder, max_new: int = 64,
+                     **engine_kw) -> dict:
     """Try (B, S) shapes big-to-small; return a perf row for the first that
-    runs plus the failure trail (the OOM boundary is data, not an error)."""
+    runs plus the failure trail (the OOM boundary is data, not an error).
+    ``engine_kw`` reaches TpuBackend (off-chip tests name the dense path)."""
     import jax
 
     from vnsum_tpu.backend.engine import EngineStats, TpuBackend
@@ -63,6 +65,7 @@ def probe_real_shape(label: str, cfg_factory, ladder, max_new: int = 64) -> dict
             be = TpuBackend(
                 model_config=cfg, params=params, tokenizer="byte",
                 batch_size=B, max_new_tokens=max_new, instrument=True,
+                **engine_kw,
             )
             body = (_FILLER * (S // len(_FILLER.encode()) + 1)).encode()
             prompts = [

@@ -36,7 +36,12 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-HBM_BYTES_PER_S = 819e9  # bench.py v5e-1 number
+
+
+def hbm_bytes_per_s() -> float:
+    import bench
+
+    return bench.device_peaks()["hbm_bytes_per_s"]
 
 
 def weight_bytes(params) -> int:
@@ -85,7 +90,7 @@ def run_arm(label: str, cfg, tok_spec, gen_cfg, prompts, max_new: int) -> dict:
     cb = cache_bytes(cfg, st.dispatches[0]["B"] if st.dispatches else 8,
                      fill, be.quantize_kv)
     mandatory = wb + cb
-    roofline_ms = mandatory / HBM_BYTES_PER_S * 1e3
+    roofline_ms = mandatory / hbm_bytes_per_s() * 1e3
     row = {
         "label": label,
         "compile_and_warm_s": round(compile_s, 1),
@@ -159,7 +164,7 @@ def run_kernel_direct(cfg, B: int, C: int, steps: int = 32) -> dict:
         "seconds": round(dt, 3),
         "bytes_per_step_one_layer": per_step,
         "achieved_gb_per_s": round(bw / 1e9, 1),
-        "frac_of_819": round(bw / HBM_BYTES_PER_S, 4),
+        "frac_of_819": round(bw / hbm_bytes_per_s(), 4),
     }
 
 
@@ -240,7 +245,7 @@ def main() -> int:
             kernel_row = prev.get("kernel_direct")
     rec = {
         "what": "decode roofline gap decomposition at the e2e shape",
-        "hbm_bytes_per_s_assumed": HBM_BYTES_PER_S,
+        "hbm_bytes_per_s_assumed": hbm_bytes_per_s(),
         "arms": rows,
         "kernel_direct": kernel_row,
         "timestamp": time.strftime("%Y-%m-%dT%H:%M:%S"),

@@ -36,7 +36,6 @@ from pathlib import Path
 REPO = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(REPO))
 
-BF16_PEAK = 197e12
 S8_MEASURED_CEILING = 132.7e12  # chained-matmul microbench, PERF finding 18
 
 
@@ -135,6 +134,9 @@ def main() -> int:
     # QK^T and PV are 2*B*H*S*S*hd FLOPs EACH (mult+add); causal halves
     # the S^2 → per-layer total 2*B*H*S^2*hd
     attn_flops = cfg.n_layers * 2 * B * cfg.n_heads * S * S * cfg.head_dim
+    import bench
+
+    BF16_PEAK = bench.device_peaks()["flops_bf16"]
     analytic = {
         "B": B, "S": S,
         "proj_flops": proj_flops,

@@ -144,16 +144,16 @@ def test_transfer_guard_green_over_engine_decode_prefill(tiny, monkeypatch):
 
     monkeypatch.delenv("VNSUM_SANITIZERS", raising=False)
     base = TpuBackend(model_config=cfg, params=params, batch_size=4,
-                      max_new_tokens=8)
+                      max_new_tokens=8, flash=False)
     want = base.generate(prompts)
 
     monkeypatch.setenv("VNSUM_SANITIZERS", "transfer")
     one_shot = TpuBackend(model_config=cfg, params=params, batch_size=4,
-                          max_new_tokens=8)
+                          max_new_tokens=8, flash=False)
     assert one_shot.generate(prompts) == want
     segmented = TpuBackend(model_config=cfg, params=params, batch_size=4,
                            max_new_tokens=8, continuous=True,
-                           segment_tokens=4)
+                           segment_tokens=4, flash=False)
     assert segmented.generate(prompts) == want
 
 

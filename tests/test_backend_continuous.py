@@ -13,6 +13,8 @@ from vnsum_tpu.models import tiny_llama
 
 
 def make_backend(continuous, **kw):
+    if not kw.get("interpret"):
+        kw.setdefault("flash", False)  # off-chip: the dense path, by name
     return TpuBackend(
         model_config=tiny_llama(max_seq_len=128),
         tokenizer="byte",
@@ -99,11 +101,13 @@ def test_continuous_auto_policy_is_oneshot():
     auto = TpuBackend(
         model_config=tiny_llama(max_seq_len=128), batch_size=32,
         max_new_tokens=8,
+        flash=False,
     )
     assert auto.continuous is False
     forced = TpuBackend(
         model_config=tiny_llama(max_seq_len=128), batch_size=4,
         max_new_tokens=8, continuous=True,
+        flash=False,
     )
     assert forced.continuous is True
 

@@ -14,6 +14,7 @@ def engine():
         model_config=tiny_llama(max_seq_len=128),
         batch_size=4,
         max_new_tokens=8,
+        flash=False,
     )
 
 
@@ -89,10 +90,11 @@ def test_mesh_sharded_generation_matches_single_device():
     from vnsum_tpu.parallel import make_mesh
 
     cfg = tiny_llama(max_seq_len=128)
-    plain = TpuBackend(model_config=cfg, batch_size=4, max_new_tokens=6, seed=3)
+    plain = TpuBackend(model_config=cfg, batch_size=4, max_new_tokens=6, seed=3, flash=False)
     mesh = make_mesh({"data": 2, "model": 2, "seq": 1}, platform="cpu")
     sharded = TpuBackend(
-        model_config=cfg, batch_size=4, max_new_tokens=6, mesh=mesh, seed=3
+        model_config=cfg, batch_size=4, max_new_tokens=6, mesh=mesh, seed=3,
+        flash=False,
     )
     prompts = ["văn bản một", "văn bản thứ hai dài hơn", "ba", "bốn bốn bốn"]
     np.testing.assert_array_equal(
@@ -108,7 +110,8 @@ def test_mesh_sharded_quantized_generation_matches_single_device():
 
     cfg = tiny_llama(max_seq_len=128)
     plain = TpuBackend(
-        model_config=cfg, batch_size=4, max_new_tokens=6, seed=3, quantize=True
+        model_config=cfg, batch_size=4, max_new_tokens=6, seed=3, quantize=True,
+        flash=False,
     )
     mesh = make_mesh({"data": 2, "model": 2, "seq": 1}, platform="cpu")
     sharded = TpuBackend(
@@ -118,6 +121,7 @@ def test_mesh_sharded_quantized_generation_matches_single_device():
         mesh=mesh,
         seed=3,
         quantize=True,
+        flash=False,
     )
     prompts = ["văn bản một", "văn bản thứ hai dài hơn", "ba", "bốn bốn bốn"]
     np.testing.assert_array_equal(
@@ -295,6 +299,7 @@ def test_sampled_batches_draw_fresh_randomness():
         return TpuBackend(
             model_config=tiny_llama(max_seq_len=128),
             batch_size=4, max_new_tokens=16, seed=5, continuous=False,
+            flash=False,
         )
 
     gen = GenerationConfig(temperature=1.0, seed=11, max_new_tokens=16)
@@ -319,7 +324,8 @@ def test_instrument_mode_matches_oneshot_and_records_budget():
     from vnsum_tpu.backend.engine import TpuBackend
 
     cfg = tiny_llama(max_seq_len=128)
-    kw = dict(model_config=cfg, batch_size=4, max_new_tokens=8, seed=3)
+    kw = dict(model_config=cfg, batch_size=4, max_new_tokens=8, seed=3,
+              flash=False)
     plain = TpuBackend(**kw)
     inst = TpuBackend(instrument=True, **kw)
     prompts = ["văn bản một", "hai dài hơn một chút", "ba", "bốn"]
@@ -398,6 +404,7 @@ def test_native_eos_terminates_sampled_decode():
     be = TpuBackend(
         model_config=tiny_llama(max_seq_len=256), tokenizer="byte",
         batch_size=8, max_new_tokens=128, seed=0, continuous=False,
+        flash=False,
     )
     # near-uniform random-init logits give p(EOS) ~ 1/258 per draw; over
     # 16 rows x 128 steps the no-early-stop probability is ~3e-4, and the
@@ -428,6 +435,7 @@ def test_sampling_restricted_to_tokenizer_vocab():
     be = TpuBackend(
         model_config=cfg, tokenizer="byte", batch_size=2, max_new_tokens=16,
         seed=0, continuous=False,
+        flash=False,
     )
     gen = GenerationConfig(temperature=1.0, seed=9)
     encoded = [be.tok.encode(p, add_bos=True) for p in ["văn bản", "hai"]]
@@ -531,8 +539,8 @@ def test_chunked_prefill_matches_whole_prompt():
     ]
     outs = {}
     for tag, kw in {
-        "whole": dict(),
-        "chunked": dict(prefill_chunk_tokens=128),
+        "whole": dict(flash=False),
+        "chunked": dict(prefill_chunk_tokens=128, flash=False),
         "chunked_flash": dict(
             prefill_chunk_tokens=128, flash=True, interpret=True
         ),
@@ -550,4 +558,4 @@ def test_chunked_prefill_rejects_bad_multiple():
     from vnsum_tpu.backend.engine import TpuBackend
 
     with pytest.raises(ValueError):
-        TpuBackend(model_config=tiny_llama(), prefill_chunk_tokens=100)
+        TpuBackend(model_config=tiny_llama(), prefill_chunk_tokens=100, flash=False)

@@ -39,10 +39,12 @@ def setup(mesh):
     dense = TpuBackend(
         model_config=cfg, params=params, batch_size=4, max_new_tokens=16,
         continuous=False,
+        flash=False,
     )
     long = LongContextBackend(
         model_config=cfg, mesh=mesh, params=params, max_new_tokens=16,
         max_total_tokens=2048,
+        decode_kernel=False,
     )
     return dense, long
 
@@ -104,10 +106,12 @@ def test_exceeds_single_chip_ceiling(mesh):
     long = LongContextBackend(
         model_config=small_cfg, mesh=mesh, params=params, max_new_tokens=12,
         max_total_tokens=2048,
+        decode_kernel=False,
     )
     oracle = TpuBackend(
         model_config=big_cfg, params=params, batch_size=2, max_new_tokens=12,
         continuous=False,
+        flash=False,
     )
     got = long.generate([long_doc])
     expect = oracle.generate([long_doc])
@@ -126,6 +130,7 @@ def test_truncated_strategy_untruncated_via_long_backend(mesh):
     long = LongContextBackend(
         model_config=cfg, mesh=mesh, params=params, max_new_tokens=8,
         max_total_tokens=4096,
+        decode_kernel=False,
     )
     st = TruncatedStrategy(long, max_context=4096, max_new_tokens=8)
     doc = "Báo cáo kinh tế xã hội sáu tháng đầu năm cho thấy nhiều tín hiệu tích cực. " * 10
@@ -145,6 +150,7 @@ def test_batch_grouping_and_config_max_new(mesh):
     be = LongContextBackend(
         model_config=cfg, mesh=mesh, params=params, batch_size=2,
         max_new_tokens=16, max_total_tokens=2048,
+        decode_kernel=False,
     )
     prompts = ["a " * n for n in (4, 300, 8, 280, 2)]
     outs = be.generate(prompts)
@@ -174,6 +180,7 @@ def test_long_backend_sampled_seed_replay(mesh):
         return LongContextBackend(
             model_config=cfg, mesh=mesh, params=params, batch_size=2,
             max_new_tokens=8, max_total_tokens=512,
+            decode_kernel=False,
         )
 
     gen = GenerationConfig(temperature=1.0, seed=4, max_new_tokens=8)
@@ -204,7 +211,6 @@ def test_pipeline_long_context_truncated_untruncated(tmp_path):
         backend="tpu",
         long_context=True,
         mesh_shape={"data": 2, "seq": 4},
-        allow_cpu_mesh=True,  # 8-way mesh on a host whose default is 1 chip
         max_context=2048,
         max_new_tokens=8,
         batch_size=2,
@@ -214,7 +220,13 @@ def test_pipeline_long_context_truncated_untruncated(tmp_path):
         results_dir=str(tmp_path / "results"),
         logs_dir=str(tmp_path / "logs"),
     )
-    results = PipelineRunner(cfg).run()
+    runner = PipelineRunner(cfg)
+    # off-chip: the dense decode partial, by name (the engine refuses a
+    # platform other than tpu otherwise)
+    runner.backend_factory = lambda model: runner._default_backend_factory(
+        model, decode_kernel=False
+    )
+    results = runner.run()
     rec = results.summarization["tiny"]
     assert rec["successful"] == 2 and rec["failed"] == 0
     # docs really exceeded the one-chip limit
@@ -275,6 +287,7 @@ def test_long_context_int8_weights_and_cache(mesh):
         model_config=cfg, mesh=mesh, params=params, batch_size=2,
         max_new_tokens=12, max_total_tokens=2048,
         quantize=True, quantize_kv=True,
+        decode_kernel=False,
     )
     doc = "Hội nghị thường niên về chuyển đổi năng lượng tái tạo. " * 9
     outs = q8.generate([doc])
@@ -290,6 +303,7 @@ def test_decode_kernel_path_greedy_parity(mesh):
     dense = TpuBackend(
         model_config=cfg, params=params, batch_size=4, max_new_tokens=16,
         continuous=False,
+        flash=False,
     )
     kernel_long = LongContextBackend(
         model_config=cfg, mesh=mesh, params=params, max_new_tokens=16,
@@ -346,10 +360,12 @@ def test_long_backend_rejects_budget_exceeding_context(mesh):
         LongContextBackend(
             model_config=cfg, mesh=mesh, params=params,
             max_new_tokens=512, max_total_tokens=512,
+            decode_kernel=False,
         )
     be = LongContextBackend(
         model_config=cfg, mesh=mesh, params=params,
         max_new_tokens=8, max_total_tokens=512,
+        decode_kernel=False,
     )
     with pytest.raises(ValueError, match="max_new_tokens"):
         be.generate(["x"], max_new_tokens=600)
@@ -364,9 +380,11 @@ def test_greedy_parity_with_model_axis_active():
     dense = TpuBackend(
         model_config=cfg, params=params, batch_size=2, max_new_tokens=12,
         continuous=False,
+        flash=False,
     )
     long = LongContextBackend(
         model_config=cfg, mesh=mesh, params=params, batch_size=2,
         max_new_tokens=12, max_total_tokens=2048,
+        decode_kernel=False,
     )
     assert long.generate(PROMPTS) == dense.generate(PROMPTS)

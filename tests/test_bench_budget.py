@@ -21,9 +21,23 @@ def test_run_device_budget_tiny(tmp_path, monkeypatch):
     monkeypatch.setattr(
         models, "llama32_3b", lambda **kw: tiny_llama(max_seq_len=512)
     )
+    # off-chip: the dense path by name, and the v5e row of the peaks table
+    # (bench.py itself refuses both a CPU engine and an unknown device kind)
+    e2e_kwargs = bench_mod.e2e_engine_kwargs
+    monkeypatch.setattr(
+        bench_mod, "e2e_engine_kwargs",
+        lambda *a: {**e2e_kwargs(*a), "flash": False},
+    )
+    monkeypatch.setattr(
+        bench_mod, "device_peaks",
+        lambda: bench_mod.DEVICE_PEAKS["TPU v5 lite"],
+    )
     out = bench_mod.run_device_budget(None, root, "byte", (10,))
     assert out["docs"] == 2 and out["chunks"] >= 2
-    assert out["prefill_s"] > 0 and out["decode_s"] > 0
+    # phase totals are rounded to 0.1 s and a tiny model's prefill can land
+    # under that on a fast host; the per-dispatch records keep milliseconds
+    assert sum(d["prefill_s"] for d in out["dispatches"]) > 0
+    assert out["decode_s"] > 0
     assert out["dispatches"] and all(
         d["steps"] <= 128 for d in out["dispatches"]
     )

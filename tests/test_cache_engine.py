@@ -38,7 +38,8 @@ def params(cfg):
 @pytest.fixture(scope="module")
 def reference_outputs(cfg, params):
     base = TpuBackend(
-        model_config=cfg, params=params, batch_size=4, max_new_tokens=16
+        model_config=cfg, params=params, batch_size=4, max_new_tokens=16,
+        flash=False,
     )
     return base.generate(PROMPTS)
 
@@ -46,6 +47,8 @@ def reference_outputs(cfg, params):
 def make_backend(cfg, params, **kw):
     kw.setdefault("cache_blocks", 32)
     kw.setdefault("cache_block_tokens", 64)
+    if not kw.get("interpret"):
+        kw.setdefault("flash", False)  # off-chip: the dense path, by name
     return TpuBackend(
         model_config=cfg, params=params, batch_size=4, max_new_tokens=16, **kw
     )
@@ -105,7 +108,8 @@ def test_mixed_lengths_group_by_suffix(cfg, params):
     the same mixed workload)."""
     mixed = PROMPTS + ["Cau hoi ngan."] * 2
     base = TpuBackend(
-        model_config=cfg, params=params, batch_size=4, max_new_tokens=16
+        model_config=cfg, params=params, batch_size=4, max_new_tokens=16,
+        flash=False,
     )
     want = base.generate(mixed)
     b = make_backend(cfg, params)
@@ -124,6 +128,7 @@ def test_cache_pool_requires_tp_divisible_kv_heads(cfg, params):
         TpuBackend(
             model_config=cfg, params=params, mesh=mesh,
             max_new_tokens=16, cache_blocks=8,
+            flash=False,
         )
 
 

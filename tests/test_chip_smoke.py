@@ -1,0 +1,117 @@
+"""chip_smoke.py off the chip: it must fail, say which platform it found, and
+print no result — and nothing else may time or serve from the CPU either
+(TpuBackend at defaults, bench.py, a tpu worker's environment, a fleet of
+tpu workers on one chip)."""
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+REPO = Path(__file__).resolve().parent.parent
+
+
+def _run(cmd, **kw):
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    return subprocess.run(
+        cmd, env=env, capture_output=True, text=True, timeout=300, **kw
+    )
+
+
+def test_chip_smoke_fails_on_cpu_and_names_the_platform(tmp_path):
+    proc = _run([sys.executable, str(REPO / "chip_smoke.py"),
+                 "--out", str(tmp_path / "report.json")])
+    assert proc.returncode != 0
+    assert "platform 'cpu'" in proc.stderr
+    # no result line: the last line of stdout is not an {"ok": ...} object
+    assert '"ok"' not in proc.stdout
+
+
+def test_chip_smoke_alone_is_not_the_program(tmp_path):
+    lone = tmp_path / "chip_smoke.py"
+    lone.write_text((REPO / "chip_smoke.py").read_text())
+    proc = _run([sys.executable, str(lone)], cwd=tmp_path)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+
+
+def test_parent_module_imports_without_jax():
+    code = (
+        "import sys; sys.path.insert(0, %r); import chip_smoke; "
+        "assert 'jax' not in sys.modules, 'parent imported jax'; "
+        "assert not any(m.startswith('vnsum_tpu') for m in sys.modules)"
+        % str(REPO)
+    )
+    proc = _run([sys.executable, "-c", code])
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_bench_refuses_to_time_the_cpu():
+    proc = _run([sys.executable, str(REPO / "bench.py")])
+    assert proc.returncode != 0
+    assert "platform 'cpu'" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
+def test_bench_peaks_table_is_keyed_by_device_kind():
+    sys.path.insert(0, str(REPO))
+    try:
+        import bench
+    finally:
+        sys.path.remove(str(REPO))
+    v5e = bench.DEVICE_PEAKS["TPU v5 lite"]
+    assert v5e == {"flops_bf16": 197e12, "ops_int8": 393e12,
+                   "hbm_bytes_per_s": 819e9}
+    with pytest.raises(SystemExit, match="no published peaks.*'cpu'"):
+        bench.device_peaks()  # the CPU is not in the table, nor a default
+
+
+def test_tpu_backend_refuses_the_cpu_unless_told_how_to_run():
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.models import tiny_llama
+
+    cfg = tiny_llama()
+    with pytest.raises(RuntimeError, match="platform 'cpu'"):
+        TpuBackend(model_config=cfg, max_new_tokens=4)  # flash="auto"
+    emulated = TpuBackend(model_config=cfg, max_new_tokens=4, interpret=True)
+    assert emulated.flash and emulated.platform == "cpu"
+    dense = TpuBackend(model_config=cfg, max_new_tokens=4, flash=False)
+    assert not dense.flash
+    dense.generate(["xin chào"])
+    assert dense.stats.attention_paths == {
+        "generate[B=1,S=64]": {"prefill": "dense", "decode": "dense"}
+    }
+    assert dense.describe()["platform"] == "cpu"
+
+
+def test_worker_keeps_the_cpu_default_only_off_the_tpu_backend(monkeypatch):
+    from vnsum_tpu.serve import worker
+
+    seen = {}
+
+    class FakePopen:
+        pid = 1
+
+        def __init__(self, argv, env, **kw):
+            seen["env"] = env
+
+    monkeypatch.setattr(worker.subprocess, "Popen", FakePopen)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    worker.WorkerHandle("w", 1, journal_dir="j",
+                        extra_args=["--backend", "fake"]).start()
+    assert seen["env"]["JAX_PLATFORMS"] == "cpu"
+    worker.WorkerHandle("w", 1, journal_dir="j",
+                        extra_args=["--backend", "tpu"]).start()
+    assert "JAX_PLATFORMS" not in seen["env"]
+
+
+def test_router_refuses_to_spawn_tpu_workers_onto_one_chip(tmp_path, capsys):
+    from vnsum_tpu.serve import router
+
+    with pytest.raises(SystemExit):
+        router.main(["--spawn-workers", "2", "--backend", "tpu",
+                     "--fleet-dir", str(tmp_path)])
+    assert "a chip belongs to one process" in capsys.readouterr().err

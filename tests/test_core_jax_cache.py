@@ -1,52 +1,49 @@
-"""enable_compilation_cache: idempotent per directory, re-points on a new
-explicit directory, honors the "off"/""/"0" opt-outs, and explicit choices
-(enable OR disable) survive the library-internal no-arg ensure-enabled calls
-(ADVICE r3: first-call-wins previously swallowed later explicit config)."""
+"""enable_compilation_cache's two rules: JAX_COMPILATION_CACHE_DIR set → the
+module sets no directory; unset → <checkout>/.jax_cache, resolved from the
+package's own location."""
+from pathlib import Path
+
 import jax
 import pytest
 
+import vnsum_tpu
 from vnsum_tpu.core import jax_cache
+
+CHECKOUT_CACHE = str(Path(vnsum_tpu.__file__).resolve().parents[1] / ".jax_cache")
 
 
 @pytest.fixture()
 def _restore_cache_config():
-    before_state = jax_cache._state
-    before_cfg = jax.config.jax_compilation_cache_dir
+    before = jax.config.jax_compilation_cache_dir
     yield
-    jax_cache._state = before_state
-    jax.config.update("jax_compilation_cache_dir", before_cfg)
+    jax.config.update("jax_compilation_cache_dir", before)
 
 
-def test_repoints_on_new_explicit_dir(tmp_path, _restore_cache_config):
-    a, b = str(tmp_path / "a"), str(tmp_path / "b")
-    assert jax_cache.enable_compilation_cache(a) is True
-    assert jax.config.jax_compilation_cache_dir == a
-    # same dir: idempotent no-op
-    assert jax_cache.enable_compilation_cache(a) is True
-    # different explicit dir: re-points instead of being silently ignored
-    assert jax_cache.enable_compilation_cache(b) is True
-    assert jax.config.jax_compilation_cache_dir == b
-    # library-internal no-arg ensure-enabled calls must NOT re-point an
-    # active cache back to the env/default resolution
-    assert jax_cache.enable_compilation_cache() is True
-    assert jax.config.jax_compilation_cache_dir == b
+def test_env_set_means_module_sets_nothing(
+    tmp_path, monkeypatch, _restore_cache_config
+):
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+    jax.config.update("jax_compilation_cache_dir", "sentinel")
+    assert jax_cache.enable_compilation_cache() == placed
+    # JAX's own handling stands: the config was not touched, nothing created
+    assert jax.config.jax_compilation_cache_dir == "sentinel"
+    assert not Path(placed).exists()
 
 
-def test_explicit_disable_survives_no_arg_calls(tmp_path, _restore_cache_config):
-    a = str(tmp_path / "a")
-    assert jax_cache.enable_compilation_cache(a) is True
-    assert jax_cache.enable_compilation_cache("off") is False
-    assert jax.config.jax_compilation_cache_dir is None
-    # backend construction's ensure-enabled call must not undo the opt-out
-    assert jax_cache.enable_compilation_cache() is False
-    assert jax.config.jax_compilation_cache_dir is None
-    # a later explicit dir re-enables
-    assert jax_cache.enable_compilation_cache(a) is True
-    assert jax.config.jax_compilation_cache_dir == a
+def test_env_unset_means_checkout_dir(monkeypatch, _restore_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    jax.config.update("jax_compilation_cache_dir", None)
+    assert jax_cache.enable_compilation_cache() == CHECKOUT_CACHE
+    assert jax.config.jax_compilation_cache_dir == CHECKOUT_CACHE
+    assert Path(CHECKOUT_CACHE).is_dir()
+    # idempotent
+    assert jax_cache.enable_compilation_cache() == CHECKOUT_CACHE
 
 
-@pytest.mark.parametrize("val", ["", "0", "off"])
-def test_every_documented_disable_value_disables(val, _restore_cache_config):
-    jax_cache._state = None
-    assert jax_cache.enable_compilation_cache(val) is False
-    assert jax_cache.enable_compilation_cache() is False
+def test_default_never_leaves_the_checkout(monkeypatch):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert not jax_cache._DEFAULT_DIR.startswith(str(Path.home() / ".cache"))
+    assert Path(jax_cache._DEFAULT_DIR).parent == Path(
+        vnsum_tpu.__file__
+    ).resolve().parents[1]

@@ -36,6 +36,8 @@ def make_backend(mesh=None, **kw):
     kw.setdefault("max_new_tokens", 16)
     kw.setdefault("seed", 1)
     kw.setdefault("segment_tokens", 4)
+    if not kw.get("interpret"):
+        kw.setdefault("flash", False)  # off-chip: the dense path, by name
     return TpuBackend(mesh=mesh, **kw)
 
 

@@ -263,6 +263,7 @@ def test_trained_generation_string_parity(family, tmp_path):
     be = TpuBackend(
         model_config=cfg, params=params, tokenizer=f"hf:{out}",
         batch_size=1, max_new_tokens=24,
+        flash=False,
     )
 
     prompt = "Quốc hội đã thông qua nghị quyết"

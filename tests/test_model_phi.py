@@ -101,6 +101,7 @@ def test_phi_engine_generate(hf_checkpoint):
     be = TpuBackend(
         model_config=cfg, tokenizer="byte", params=params, batch_size=2,
         max_new_tokens=8, seed=0,
+        flash=False,
     )
     outs = be.generate(["văn bản một", "hai"])
     assert len(outs) == 2 and all(isinstance(o, str) for o in outs)

@@ -141,7 +141,8 @@ def test_init_params_quantized_runs_engine():
     assert is_quantized(params)
     assert params["layers"]["wq"]["q"].dtype == jax.numpy.int8
     be = TpuBackend(
-        model_config=cfg, params=params, batch_size=2, max_new_tokens=6
+        model_config=cfg, params=params, batch_size=2, max_new_tokens=6,
+        flash=False,
     )
     outs = be.generate(["văn bản", "hai"])
     assert len(outs) == 2 and all(isinstance(o, str) for o in outs)
@@ -195,7 +196,8 @@ def test_w8a8_engine_runs_and_rejects_without_int8_weights():
     from vnsum_tpu.models import tiny_llama
 
     cfg = tiny_llama(max_seq_len=128)
-    kw = dict(model_config=cfg, batch_size=2, max_new_tokens=8, seed=0)
+    kw = dict(model_config=cfg, batch_size=2, max_new_tokens=8, seed=0,
+              flash=False)
     with pytest.raises(ValueError, match="quantize_act"):
         TpuBackend(quantize_act=True, **kw)
     w8a8 = TpuBackend(quantize=True, quantize_act=True, **kw)
@@ -263,7 +265,7 @@ def test_w8a8_mesh_sharded_matches_single_device():
     cfg = tiny_llama(max_seq_len=128)
     kw = dict(
         model_config=cfg, batch_size=4, max_new_tokens=6, seed=3,
-        quantize=True, quantize_act=True,
+        quantize=True, quantize_act=True, flash=False,
     )
     plain = TpuBackend(**kw)
     mesh = make_mesh({"data": 2, "model": 2, "seq": 1}, platform="cpu")

@@ -124,6 +124,7 @@ def test_qwen3_engine_generate_and_registry():
     be = TpuBackend(
         model_config=tiny_q, tokenizer="byte", batch_size=2,
         max_new_tokens=8, seed=0,
+        flash=False,
     )
     outs = be.generate(["văn bản một", "hai"])
     assert len(outs) == 2 and all(isinstance(o, str) for o in outs)

@@ -395,3 +395,59 @@ def test_verify_kernel_windowed_matches_dense(win):
     np.testing.assert_allclose(
         np.asarray(dense), np.asarray(kernel), rtol=2e-5, atol=2e-5
     )
+
+
+# -- partial tail block (cache_len % block_k != 0) ---------------------------
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+def test_partial_tail_block_cannot_poison_the_output(quantized):
+    """Past the end of the cache a partial tail block holds whatever was in
+    VMEM (the interpreter fills it with NaN; the chip leaves stale bytes,
+    seen there as NaN rows). Scores of those slots are masked, but their
+    zero probability must not meet the garbage in a multiply. All three
+    kernels, float and int8 caches."""
+    from vnsum_tpu.models.llama import (
+        dequantize_cache_layer,
+        prefill_attention_mask,
+        verify_attention_mask,
+    )
+    from vnsum_tpu.ops.decode_attention import flash_spec_verify_attention
+    from vnsum_tpu.ops.flash_attention import flash_prefill_attention
+
+    L, B, KV, C, H, hd = 1, 2, 2, 40, 4, 128  # 40 % 16 == 8: a partial tail
+    q, cache = make_verify_case(L, B, KV, C, C, H, hd, seed=21)
+    if quantized:
+        cache = quantize_case(cache)
+    kd, vd = dequantize_cache_layer(cache, 0)
+    pad = jnp.asarray([0, 3], jnp.int32)
+    G = H // KV
+
+    def close(kernel, dense, rows=slice(None)):
+        kernel = np.asarray(kernel)
+        assert np.isfinite(kernel[:, rows]).all()
+        np.testing.assert_allclose(
+            np.asarray(dense)[:, rows], kernel[:, rows], rtol=2e-5, atol=2e-5
+        )
+
+    # decode at the last slot: the tail block is computed
+    close(
+        flash_decode_attention(q[:, :1], cache, 0, pad, C - 1, G,
+                               block_k=16, interpret=True),
+        _attention(q[:, :1], kd, vd, decode_attention_mask(pad, C - 1, C), G),
+    )
+    # verify with fills that reach into the tail block
+    fills = jnp.asarray([C - 3, C - 9], jnp.int32)
+    close(
+        flash_spec_verify_attention(q[:, :3], cache, 0, pad, fills, G,
+                                    block_k=16, interpret=True),
+        _attention(q[:, :3], kd, vd,
+                   verify_attention_mask(pad, fills, 3, C), G),
+    )
+    # prefill over the whole cache: the last query block sees the tail block
+    close(
+        flash_prefill_attention(q, cache, 0, pad, G, block_q=16, block_k=16,
+                                interpret=True),
+        _attention(q, kd, vd, prefill_attention_mask(pad, C, C), G),
+        rows=slice(3, None),  # pad rows are garbage on both paths
+    )

@@ -206,3 +206,57 @@ def test_cli_long_context_and_quantize_flags():
     assert cfg.long_context and cfg.quantize
     assert cfg.max_context == 65536
     assert cfg.mesh_shape == {"data": 2, "seq": 4}
+
+
+def _cli_argv(ws):
+    return [
+        "--backend", "fake", "--models", "fake-model",
+        "--docs-dir", str(ws / "doc"), "--summary-dir", str(ws / "summary"),
+        "--generated-summaries-dir", str(ws / "generated_summaries"),
+        "--results-dir", str(ws / "evaluation_results"),
+        "--chunk-size", "50", "--token-max", "60", "--batch-size", "4",
+    ]
+
+
+def test_cli_exit_code_zero_on_a_clean_run(workspace, monkeypatch):
+    from vnsum_tpu.pipeline import cli
+
+    monkeypatch.chdir(workspace)  # logs/ lands under the tmp dir
+    assert cli.main(_cli_argv(workspace)) == 0
+
+
+def test_cli_exit_code_nonzero_when_every_document_fails(
+    workspace, monkeypatch, capsys
+):
+    """The runner's per-batch catch keeps partial progress; the exit code
+    must still say that documents failed (it used to return 0 whatever
+    happened — a run whose every dispatch died exited clean)."""
+    from vnsum_tpu.pipeline import cli
+    from vnsum_tpu.testing.faults import FaultPlan, FaultSpec, injected
+
+    monkeypatch.chdir(workspace)
+    plan = FaultPlan([FaultSpec("fake.dispatch", kind="fatal", every_n=1)])
+    with injected(plan):
+        rc = cli.main(_cli_argv(workspace))
+    assert rc == 1
+    assert plan.fired
+    assert "fake-model: 3 document(s) failed" in capsys.readouterr().err
+
+
+def test_cli_exit_code_nonzero_when_the_model_fails(
+    workspace, monkeypatch, capsys
+):
+    from vnsum_tpu.pipeline import cli, runner
+
+    monkeypatch.chdir(workspace)
+
+    def broken(self, model, **kw):
+        raise RuntimeError("no such backend today")
+
+    monkeypatch.setattr(
+        runner.PipelineRunner, "_default_backend_factory", broken
+    )
+    assert cli.main(_cli_argv(workspace)) == 1
+    assert "summarization failed: no such backend today" in (
+        capsys.readouterr().err
+    )

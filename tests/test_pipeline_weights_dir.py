@@ -52,9 +52,19 @@ def _config(root, **kw):
     return PipelineConfig(**base)
 
 
+def _runner(cfg) -> PipelineRunner:
+    """The default tpu factory, off-chip: the dense path asked for by name
+    (the engine refuses a platform other than tpu otherwise)."""
+    runner = PipelineRunner(cfg)
+    runner.backend_factory = lambda model: runner._default_backend_factory(
+        model, flash=False
+    )
+    return runner
+
+
 def test_weights_dir_end_to_end_with_rouge(corpus_and_ckpt):
     root = corpus_and_ckpt
-    results = PipelineRunner(_config(root)).run()
+    results = _runner(_config(root)).run()
 
     rec = results.summarization["tiny-parity"]
     assert rec["successful"] == 3 and rec["failed"] == 0
@@ -72,7 +82,7 @@ def test_weights_dir_end_to_end_with_rouge(corpus_and_ckpt):
 
 def test_weights_dir_tokenizer_comes_from_checkpoint(corpus_and_ckpt):
     root = corpus_and_ckpt
-    runner = PipelineRunner(_config(root))
+    runner = _runner(_config(root))
     backend = runner.backend_factory("tiny-parity")
     # trained BPE vocab, not the byte fallback
     assert backend.tok.vocab_size <= 512
@@ -88,7 +98,7 @@ def test_weights_dir_resume_skips_existing(corpus_and_ckpt):
     # hermetic: pre-write all 3 outputs into a fresh dir; the run must skip
     # every doc (resume-by-file, ref run_full_evaluation_pipeline.py:422-431)
     cfg = _config(root, generated_summaries_dir=str(root / "gen_resume"))
-    runner = PipelineRunner(cfg)
+    runner = _runner(cfg)
     out_dir = runner._output_dir("tiny-parity")
     out_dir.mkdir(parents=True, exist_ok=True)
     for name in ("doc_000.txt", "doc_001.txt", "doc_002.txt"):

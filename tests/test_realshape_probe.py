@@ -21,7 +21,8 @@ spec.loader.exec_module(mm)
 @pytest.mark.slow
 def test_probe_real_shape_success_row():
     row = mm.probe_real_shape(
-        "tiny", lambda **kw: tiny_llama(**kw), ladder=[(2, 256)], max_new=8
+        "tiny", lambda **kw: tiny_llama(**kw), ladder=[(2, 256)], max_new=8,
+        flash=False,
     )
     assert row["status"] == "success"
     assert row["B"] == 2 and row["S"] == 256 and row["layers"] == 2
@@ -45,7 +46,8 @@ def test_probe_real_shape_ladder_steps_down_and_records_failures():
         return cfg
 
     row = mm.probe_real_shape(
-        "tiny", factory, ladder=[(4, 1024), (2, 256)], max_new=8
+        "tiny", factory, ladder=[(4, 1024), (2, 256)], max_new=8,
+        flash=False,
     )
     assert row["status"] == "success" and row["B"] == 2
     assert len(row["attempts"]) == 1

@@ -487,13 +487,18 @@ def test_queue_full_shed_has_retry_after_header():
     server = make_server(state, "127.0.0.1", 0)
     threading.Thread(target=server.serve_forever, daemon=True).start()
     base = f"http://127.0.0.1:{server.server_address[1]}"
+    stop = threading.Event()
     try:
         def fire():
-            # lint-allow[swallowed-exception]: background load may itself shed or race shutdown — only the foreground 429 below is asserted
-            try:
-                _post(base + "/v1/generate", {"prompt": "giu cho " * 4})
-            except Exception:
-                pass
+            # keeps posting until told to stop: a one-shot burst loses the
+            # race when the foreground post below gets queued first, and
+            # the queue is then never full again (a flake, 2 runs in 5)
+            while not stop.is_set():
+                # lint-allow[swallowed-exception]: background load may itself shed or race shutdown — only the foreground 429 below is asserted
+                try:
+                    _post(base + "/v1/generate", {"prompt": "giu cho " * 4})
+                except Exception:
+                    time.sleep(0.005)
         threads = [threading.Thread(target=fire) for _ in range(6)]
         for t in threads:
             t.start()
@@ -506,6 +511,7 @@ def test_queue_full_shed_has_retry_after_header():
                     saw_429 = e
                     break
             time.sleep(0.01)
+        stop.set()
         assert saw_429 is not None, "queue never filled"
         assert int(saw_429.headers["Retry-After"]) >= 1
         assert json.loads(saw_429.read())["reason"] in (
@@ -514,6 +520,7 @@ def test_queue_full_shed_has_retry_after_header():
         for t in threads:
             t.join(timeout=30)
     finally:
+        stop.set()
         server.shutdown()
         server.server_close()
         state.close()

@@ -106,6 +106,62 @@ def test_dispatch_budget_math_and_false_positive_immunity():
     assert [s.kind for s in stalls] == ["lock"]
 
 
+def test_compile_inside_a_ticket_is_not_dispatch_time():
+    """A cold program's first call compiles for longer than any dispatch
+    budget: the ticket's clock stops for that stretch (the engine enters
+    Watchdog.compiling around it), the budget still bounds the dispatch
+    itself afterwards, and a compile that never returns is still caught."""
+    from vnsum_tpu.serve.watchdog import COMPILE_BUDGET_S
+
+    clock = FakeClock()
+    wd = Watchdog(loop_deadline_s=2.0, dispatch_base_s=10.0, clock=clock)
+    wd.register("scheduler", kind="loop")
+    wd.begin_dispatch("scheduler", "slot_admit", 10.0)
+    clock.advance(4.0)
+    with wd.compiling("scheduler"):
+        clock.advance(120.0)            # twelve budgets of compiling
+        assert wd.check() == []
+    clock.advance(5.0)                  # 9 s of dispatch in all: inside
+    assert wd.check() == []
+    clock.advance(1.5)                  # 10.5 s of dispatch: hung
+    stalls = wd.check()
+    assert [(s.kind, s.detail["compiling"]) for s in stalls] == [
+        ("dispatch", False)
+    ]
+    assert stalls[0].stalled_for_s == pytest.approx(10.5)
+
+    # a compiler that never comes back trips the compile ceiling instead
+    wd.begin_dispatch("scheduler", "slot_admit", 10.0)
+    with wd.compiling("scheduler"):
+        clock.advance(COMPILE_BUDGET_S + 1.0)
+        stalls = wd.check()
+    assert [(s.kind, s.detail["compiling"]) for s in stalls] == [
+        ("dispatch", True)
+    ]
+    assert stalls[0].limit_s == COMPILE_BUDGET_S
+    # no ticket armed: the context is a no-op
+    with wd.compiling("scheduler"):
+        pass
+
+
+def test_engine_first_call_enters_the_schedulers_compile_scope():
+    """The scheduler installs the watchdog's compile pause on any backend
+    that exposes ``compile_scope`` (TpuBackend does; FakeBackend has no
+    programs to compile)."""
+
+    class Compiles(FakeBackend):
+        compile_scope = None
+
+    be = Compiles()
+    wd = Watchdog(clock=FakeClock())
+    sched = MicroBatchScheduler(be, watchdog=wd)
+    try:
+        assert be.compile_scope.func == wd.compiling
+        assert be.compile_scope.args == ("scheduler",)
+    finally:
+        sched.close()
+
+
 def test_dispatch_past_budget_is_hung_and_fires_once():
     clock = FakeClock()
     wd = Watchdog(loop_deadline_s=100.0, dispatch_base_s=5.0,

@@ -34,6 +34,7 @@ def engine():
         batch_size=4,
         max_new_tokens=12,
         seed=0,
+        flash=False,
     )
 
 
@@ -238,6 +239,7 @@ def test_w8a8_prefill_does_not_quantize_the_verify_forward():
     kw = dict(
         model_config=tiny_llama(max_seq_len=256), batch_size=4,
         max_new_tokens=10, seed=0, quantize=True, quantize_act=True,
+        flash=False,
     )
     be = TpuBackend(**kw)
     plain = be.generate(PROMPTS)
@@ -272,6 +274,7 @@ def test_all_refless_group_takes_the_plain_path():
     be = TpuBackend(
         model_config=tiny_llama(max_seq_len=256), batch_size=2,
         max_new_tokens=8, seed=0,
+        flash=False,
     )
     # two short refless prompts group together; two long ones carry refs
     prompts = ["a", "b", "một tài liệu dài " * 4, "văn bản nguồn khá dài " * 4]

@@ -358,11 +358,16 @@ class TpuSlotLoop:
         sleep until the fused dispatch retires. The host never blocks
         inside the runtime while the device is still looping — the poll
         is pure host time, and the later explicit ``device_get`` finds the
-        copies already landed. ``is_ready``/``copy_to_host_async`` perform
-        no implicit transfer, so the transfer-guard sanitizer stays green
-        on this path."""
-        for a in arrays:
-            a.copy_to_host_async()
+        copies already landed. On a TPU the transfer guard counts
+        ``copy_to_host_async`` as a device-to-host transfer it was not told
+        about (on the CPU it never fires, which is how this went unseen), so
+        the copies are started under an explicit allow: they ARE the
+        boundary fetch, begun early."""
+        import jax
+
+        with jax.transfer_guard_device_to_host("allow"):
+            for a in arrays:
+                a.copy_to_host_async()
         spin = 0.0001
         while not all(a.is_ready() for a in arrays):
             time.sleep(spin)

@@ -197,21 +197,26 @@ class BlockStore:
         fn = self._gather_fns.get(key)
         if fn is None:
 
-            def per_row(pool, row_cache, row_ids, start):
-                for i in range(NB):
-                    for name in row_cache:
-                        blk = pool[name][row_ids[i]]  # [L, KV, BLK(, hd)]
-                        nd = row_cache[name].ndim
-                        idx = (0, 0, start + i * BLK) + (0,) * (nd - 3)
-                        row_cache[name] = jax.lax.dynamic_update_slice(
-                            row_cache[name], blk, idx
-                        )
-                return row_cache
-
+            # in-place block writes on the cache in its own layout: a loop
+            # over block positions, rows unrolled inside. Not a vmap over
+            # the batch axis — that moves axis 1 to the front and back, and
+            # on the chip those are whole-cache transposes (see
+            # models.llama._cache_write)
             def gather_fn(pool, cache, ids, starts):
-                out = jax.vmap(
-                    per_row, in_axes=(None, 1, 0, 0), out_axes=1
-                )(pool, cache, ids, starts)
+                def write_position(i, cache):
+                    cache = dict(cache)
+                    for b in range(B):
+                        for name, buf in cache.items():
+                            blk = pool[name][ids[b, i]]  # [L, KV, BLK(, hd)]
+                            idx = (0, b, 0, starts[b] + i * BLK) + (0,) * (
+                                buf.ndim - 4
+                            )
+                            cache[name] = jax.lax.dynamic_update_slice(
+                                buf, blk[:, None], idx
+                            )
+                    return cache
+
+                out = jax.lax.fori_loop(0, NB, write_position, cache)
                 return self._constrain_batch_cache(out)
 
             fn = jax.jit(gather_fn, donate_argnums=(1,))

@@ -136,11 +136,6 @@ class PipelineConfig:
     doc_group_size: int = 0
     tokenizer: str = "byte"  # byte | hf:<name-or-path>
     mesh_shape: dict[str, int] = field(default_factory=dict)
-    # opt-in: when mesh_shape needs more devices than the default platform
-    # has, rebuild the mesh on host CPU devices (tests, dry runs, artifact
-    # scripts). Off by default so a production TPU run with an oversized
-    # --mesh fails loudly instead of silently running ~100x slower on CPU
-    allow_cpu_mesh: bool = False
     # ring-attention prefill + seq-sharded decode (backend/long_context.py):
     # prompts run UN-truncated up to seq_axis × the one-chip limit; requires
     # backend=tpu and a mesh with a seq axis > 1

@@ -1,73 +1,41 @@
 """Persistent XLA compilation cache.
 
-The engine's per-bucket programs cost 5-60s each to compile (a 3B prefill at
-S=8192 is the worst), and the reference has nothing comparable to pay — its
-"backend" is an HTTP call. Enabling JAX's persistent compilation cache makes
-every program a one-time cost per machine instead of per process: measured on
-the attached TPU, a cross-process recompile drops from seconds to ~20ms.
+The engine's per-bucket programs take seconds to minutes each to compile,
+and the reference has nothing comparable to pay — its "backend" is an HTTP
+call. JAX's persistent compilation cache makes every program a one-time cost
+per cache directory instead of per process.
 
-Opt-out via VNSUM_JAX_CACHE_DIR=off. Every device-touching entry point
-(TpuBackend, LongContextBackend, EmbeddingModel, Trainer, bench.py) calls
-:func:`enable_compilation_cache` before building programs.
+Two rules, and no third:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX's own handling of it stands and
+  this module sets no directory. Whoever runs the program (a driver, a CI
+  job) places the cache.
+- unset: the directory is ``<checkout>/.jax_cache``, resolved from this
+  package's own location — never ``$HOME``, a temp name, a pid or the time.
+  The path is part of the cache key, so a directory that moves never hits.
+
+Every device-touching entry point (TpuBackend, LongContextBackend,
+EmbeddingModel, Trainer, bench.py) calls :func:`enable_compilation_cache`
+before building programs.
 """
 from __future__ import annotations
 
 import os
+from pathlib import Path
 
-# None = never configured; "" = explicitly disabled; else the active dir.
-# The disabled sentinel matters: an explicit opt-out must survive the
-# library-internal no-arg ensure-enabled calls backends make.
-_state: str | None = None
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+_DEFAULT_DIR = str(Path(__file__).resolve().parents[2] / ".jax_cache")
 
 
-def _apply(directory: str | None) -> None:
+def enable_compilation_cache() -> str:
+    """Make sure compiled programs persist on disk; returns the directory
+    they land in. Idempotent."""
+    placed = os.environ.get(_ENV)
+    if placed:
+        return placed
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", directory)
-    if directory is not None:
-        # cache every program that takes meaningful compile time; the tiny
-        # eager helpers stay uncached to keep the directory small
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    # JAX binds its cache object at the FIRST cached compile and a run-once
-    # guard then ignores config changes — drop it so the new directory (or
-    # the disable) actually takes effect for subsequent compiles
-    from jax.experimental.compilation_cache import compilation_cache
-
-    compilation_cache.reset_cache()
-
-
-def enable_compilation_cache(cache_dir: str | None = None) -> bool:
-    """Point JAX at a persistent on-disk compilation cache.
-
-    Returns True when the cache is active. Resolution order: explicit
-    argument > $VNSUM_JAX_CACHE_DIR > ~/.cache/vnsum_jax. The values
-    "off"/"0"/"" disable it.
-
-    Calls are idempotent for the same resolved directory. A later call with
-    a DIFFERENT *explicit* cache_dir re-points JAX at it — programs compiled
-    under the old directory stay there, new compiles land in the new one —
-    and an explicit "off" disables it. No-arg calls (the library-internal
-    ensure-enabled calls every device-touching entry point makes) never
-    override an explicit earlier choice, enable or disable.
-    """
-    global _state
-    if cache_dir is None and _state is not None:
-        return _state != ""
-    resolved = (
-        cache_dir
-        if cache_dir is not None
-        else os.environ.get(
-            "VNSUM_JAX_CACHE_DIR", os.path.expanduser("~/.cache/vnsum_jax")
-        )
-    )
-    if resolved in ("", "0", "off"):
-        if _state not in (None, ""):
-            _apply(None)
-        _state = ""
-        return False
-    if resolved == _state:
-        return True
-    os.makedirs(resolved, exist_ok=True)
-    _apply(resolved)
-    _state = resolved
-    return True
+    if jax.config.jax_compilation_cache_dir != _DEFAULT_DIR:
+        os.makedirs(_DEFAULT_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
+    return _DEFAULT_DIR

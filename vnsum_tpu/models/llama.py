@@ -404,20 +404,24 @@ def _cache_write(buf, val, layer_idx, write_index):
     is the cache slot of val's first token — a scalar (prefill/decode: every
     row writes at the same slot) or a [B] vector (the speculative verify
     step: rows sit at different fills after ragged draft acceptance, so each
-    row writes at its own slot via a vmapped per-row update)."""
+    row writes at its own slot, one small in-place update per row).
+
+    The per-row form is a chain of dynamic_update_slices on the cache in its
+    own layout, not a vmap over the batch axis: vmapping moves axis 1 to the
+    front and back, and on the chip XLA materializes both whole-cache
+    transposes per layer per step — the slot loop's first B=2 segment ran
+    past 230 ms/step and was declared hung (B=1 hid it: a size-1 axis
+    transposes for free)."""
     tail = (0,) * (buf.ndim - 4)  # hd present on k/v, absent on ks/vs
     if jnp.ndim(write_index) == 0:
         return jax.lax.dynamic_update_slice(
             buf, val[None], (layer_idx, 0, 0, write_index) + tail
         )
-    return jax.vmap(
-        # per row: buf slice [L, KV, C(, hd)], update [1, KV, S(, hd)]
-        lambda c, u, w: jax.lax.dynamic_update_slice(
-            c, u[None], (layer_idx, 0, w) + tail
-        ),
-        in_axes=(1, 0, 0),
-        out_axes=1,
-    )(buf, val, write_index)
+    for b in range(val.shape[0]):
+        buf = jax.lax.dynamic_update_slice(
+            buf, val[None, b : b + 1], (layer_idx, b, 0, write_index[b]) + tail
+        )
+    return buf
 
 
 def _block(

@@ -46,7 +46,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _LANES, _NEG
+from .flash_attention import _LANES, _NEG, VMEM_LIMIT_BYTES
+
+
+def _zero_past_cache(vb, k_start, cache_len: int):
+    """Zero the value rows of a K/V block that lie past the end of the cache.
+
+    A partial tail block (cache_len % block_k != 0) is only DMA'd up to the
+    cache's end; the rest of its VMEM buffer holds whatever was there, NaN
+    bit patterns included. Those slots' scores are masked, but a probability
+    of 0 still meets the value side in a multiply, and 0 * NaN is NaN (see
+    ops/flash_attention._kernel). With an int8 cache the values are finite
+    and the kernels zero the f32 V-scale row instead. vb [BKV, BK, hd]."""
+    v_slot = k_start + jax.lax.broadcasted_iota(jnp.int32, vb.shape[:2] + (1,), 1)
+    return jnp.where(v_slot < cache_len, vb, 0.0)
 
 
 def _kernel(
@@ -57,6 +70,7 @@ def _kernel(
     block_b: int,
     block_k: int,
     n_kv: int,
+    cache_len: int,
     scale: float,
     quantized: bool,
     return_partials: bool = False,
@@ -125,6 +139,9 @@ def _kernel(
         k_pos = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (BKV, 1, block_k), 2
         )
+        in_cache = k_pos < cache_len
+        if not quantized:
+            vb = _zero_past_cache(vb, j * block_k, cache_len)
         mask = (k_pos >= pads_ref[0]) & (k_pos <= fill)  # [BKV, 1, BK]
         # window in slot space, matching the dense path's k_slot > fill - win
         mask = mask & ((win == 0) | (k_pos > fill - win))
@@ -141,7 +158,9 @@ def _kernel(
             l_ref.shape,
         )
         if quantized:
-            p = p * vs_ref[0].reshape(BKV, 1, block_k)
+            p = p * jnp.where(
+                in_cache, vs_ref[0].reshape(BKV, 1, block_k), 0.0
+            )
         pv = jax.lax.dot_general(
             p, vb, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -170,6 +189,7 @@ def _verify_kernel(
     block_k: int,
     n_kv: int,
     n_q: int,
+    cache_len: int,
     scale: float,
     quantized: bool,
 ):
@@ -231,6 +251,9 @@ def _verify_kernel(
         k_pos = j * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (BKV, 1, block_k), 2
         )
+        in_cache = k_pos < cache_len
+        if not quantized:
+            vb = _zero_past_cache(vb, j * block_k, cache_len)
         limit = lim_ref[0, :, :, :1]                     # [BKV, SG, 1]
         mask = (k_pos >= pads_ref[0]) & (k_pos <= limit)
         # window in slot space per query: k_slot > (fill_b + s) - win
@@ -248,7 +271,9 @@ def _verify_kernel(
             l_ref.shape,
         )
         if quantized:
-            p = p * vs_ref[0].reshape(BKV, 1, block_k)
+            p = p * jnp.where(
+                in_cache, vs_ref[0].reshape(BKV, 1, block_k), 0.0
+            )
         pv = jax.lax.dot_general(
             p, vb, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -356,8 +381,9 @@ def flash_decode_attention(
         operands += [cache["ks"], cache["vs"]]
 
     kernel = functools.partial(
-        _kernel, block_b=bb, block_k=bk, n_kv=KV, scale=1.0 / (hd ** 0.5),
-        quantized=quantized, return_partials=return_partials,
+        _kernel, block_b=bb, block_k=bk, n_kv=KV, cache_len=C,
+        scale=1.0 / (hd ** 0.5), quantized=quantized,
+        return_partials=return_partials,
     )
     out_block = lambda shape: pl.BlockSpec(  # noqa: E731
         (1, *shape), lambda b, j, lidx, fill, win: (b,) + (0,) * len(shape)
@@ -392,6 +418,9 @@ def flash_decode_attention(
             ],
         ),
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
         interpret=interpret,
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
@@ -504,7 +533,7 @@ def flash_spec_verify_attention(
         operands += [cache["ks"], cache["vs"]]
 
     kernel = functools.partial(
-        _verify_kernel, block_b=bb, block_k=bk, n_kv=KV, n_q=Sq,
+        _verify_kernel, block_b=bb, block_k=bk, n_kv=KV, n_q=Sq, cache_len=C,
         scale=1.0 / (hd ** 0.5), quantized=quantized,
     )
     out = pl.pallas_call(
@@ -521,6 +550,9 @@ def flash_spec_verify_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B // bb, bb * KV, SG, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
         interpret=interpret,
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),

@@ -25,7 +25,7 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   artifacts/prefill_gap.json). For the group-major grid the measured-best
   default is bq=512 / bk=2048 at hd=128, G≤3 (30.8 ms/layer at the worst
   e2e chunk vs the per-head kernel's best 37.5; map shape 19.4 vs 20.7 —
-  artifacts/flash_block_geometry.json holds the per-head history). bk
+  not measured on the current machine). bk
   shrinks with head_dim (hd=256 Gemma3 → 1024) AND with G (the unrolled
   per-head score temporaries stay live: G=4 at bk=2048 exceeds the 16 MB
   scoped-VMEM budget, so G·bk is capped at 3·2048 — phi-4's 4:1 groups
@@ -51,6 +51,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30  # python float: jnp constants would be captured by the kernel
 _LANES = 128
+# Scoped VMEM the attention kernels ask Mosaic for. The compiler's default is
+# 16 MiB, which the int8-cache variants of the block geometries below fit
+# and their bf16-cache twins do not: on libtpu 0.0.34 the prefill kernel at
+# G=3/bk=2048 needs 18.93 MiB and the verify kernel at KV=10/G=4/Sq=5 needs
+# 16.66 MiB (compile errors, chip_smoke.py kernels phase). 32 MiB of a v5e
+# core's 128 MiB covers both with room; block geometry is unchanged.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
 def _kernel(
@@ -62,6 +69,7 @@ def _kernel(
     block_q: int,
     block_k: int,
     seq_len: int,
+    cache_len: int,
     scale: float,
     quantized: bool,
     q_per_kv: int,
@@ -110,6 +118,24 @@ def _kernel(
         # dtype — see the dot comment below)
         kb = k_ref[0, 0, 0].astype(q_ref.dtype)
         vb = v_ref[0, 0, 0].astype(q_ref.dtype)
+        # A partial tail block (cache_len % block_k != 0) is only DMA'd up
+        # to the end of the cache; the rest of its VMEM buffer holds
+        # whatever was there, NaN bit patterns included. Scores of those
+        # slots are masked below, but their probability 0 still meets the
+        # value side in a multiply (0 * NaN = NaN — seen on the chip as NaN
+        # rows, int8 cache at C=3200/bk=2048), so the value side is zeroed
+        # past the end: the f32 scale row when quantized (int8 garbage is
+        # finite), else the value rows themselves.
+        if quantized:
+            in_cache = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_k), 1
+            ) < cache_len
+            v_scale = jnp.where(in_cache, vs_ref[0, 0, kv][None, :], 0.0)
+        else:
+            in_cache = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0
+            ) < cache_len
+            vb = jnp.where(in_cache, vb, jnp.zeros_like(vb))
 
         # mask depends on positions only, not the head — ONE copy serves
         # the whole GQA group (a third of the old per-head VPU bookkeeping)
@@ -161,7 +187,7 @@ def _kernel(
 
             l_new = l_ref[lo:hi, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
             if quantized:
-                p = p * vs_ref[0, 0, kv][None, :]
+                p = p * v_scale
             # probabilities drop to the query dtype for the PV dot (bf16
             # adds ~0.4% relative rounding — same class as the int8 V
             # scale already applied above); accumulation stays f32
@@ -231,10 +257,10 @@ def flash_prefill_attention(
         raise ValueError(f"q_per_kv={q_per_kv} inconsistent with H/KV={G}")
     # measured-best geometry for the GROUP-major grid (worst e2e chunk,
     # B=16/S=2048@off=6144/C=8320 int8: 512/2048 = 30.8 ms/layer vs the
-    # per-head kernel's best 37.5; map shape 19.4 vs 20.7). Two VMEM
-    # scaling rules keep the ~16 MB scoped budget at the measured G=3,
-    # hd=128 level: the K width shrinks with head_dim (hd=256 Gemma3 →
-    # bk 1024), AND with the group size — the per-head loop is a static
+    # per-head kernel's best 37.5; map shape 19.4 vs 20.7; not re-measured
+    # on the current machine). Two VMEM scaling rules hold the footprint at
+    # the measured G=3, hd=128 level: the K width shrinks with head_dim
+    # (hd=256 Gemma3 → bk 1024), AND with the group size — the per-head loop is a static
     # unroll whose [bq, bk] f32 score temporaries stay live per head, so
     # G=4 at bk=2048 exceeds scoped vmem by ~2 MB (measured compile OOM;
     # G*bk is held ≤ 3*2048). bq stays 512: the q tile already carries
@@ -261,7 +287,7 @@ def flash_prefill_attention(
         # geometry that will OOM in Mosaic — fail with the numbers instead
         # of a compile-time scoped-vmem error naming none of them
         raise ValueError(
-            f"flash prefill geometry exceeds the ~16 MB scoped-VMEM "
+            f"flash prefill geometry exceeds the scoped-VMEM "
             f"budget: G={G} (H={H}/KV={KV}), head_dim={hd}, bq={bq}, "
             f"bk={bk} (G*bq*bk={G * bq * bk} > {_VMEM_CELLS}) — pass a "
             f"smaller block_q/block_k or drop to the dense path"
@@ -307,8 +333,8 @@ def flash_prefill_attention(
 
     grid = (B, KV, pl.cdiv(S, bq), pl.cdiv(C, bk))
     kernel = functools.partial(
-        _kernel, block_q=bq, block_k=bk, seq_len=S, scale=1.0 / (hd ** 0.5),
-        quantized=quantized, q_per_kv=G,
+        _kernel, block_q=bq, block_k=bk, seq_len=S, cache_len=C,
+        scale=1.0 / (hd ** 0.5), quantized=quantized, q_per_kv=G,
     )
     out = pl.pallas_call(
         kernel,
@@ -327,6 +353,9 @@ def flash_prefill_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, S, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
         interpret=interpret,
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),

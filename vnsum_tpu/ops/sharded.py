@@ -14,10 +14,11 @@ kernel local, let the compiler move everything else.
 """
 from __future__ import annotations
 
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.mesh import AXES, shard_map
+from ..parallel.mesh import AXES
 from ..parallel.sharding import cache_specs
 from .decode_attention import flash_decode_attention
 from .flash_attention import flash_prefill_attention

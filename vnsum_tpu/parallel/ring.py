@@ -17,10 +17,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from .mesh import AXES, axis_size, shard_map
+from .mesh import AXES
 
 _NEG = jnp.float32(-1e30)
 
@@ -28,7 +29,7 @@ _NEG = jnp.float32(-1e30)
 def _ring_local(qb, kb, vb, pad_lens, q_per_kv: int, axis_name: str, causal: bool):
     """Per-device body. qb [B, Sq, H, hd], kb/vb [B, Sk, KV, hd] (local);
     pad_lens [B] (or None) masks out the left-padding slots of each row."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     B, Sq, H, hd = qb.shape
     KV = kb.shape[2]

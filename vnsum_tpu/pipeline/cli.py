@@ -5,6 +5,7 @@
 from __future__ import annotations
 
 import argparse
+import sys
 
 from ..core.config import APPROACHES, PipelineConfig, approach_defaults
 
@@ -34,12 +35,6 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--tokenizer", default="byte", help="byte or hf:<name-or-path>")
     p.add_argument(
         "--mesh", default="", help='device mesh, e.g. "data=2,model=4"'
-    )
-    p.add_argument(
-        "--allow-cpu-mesh", action="store_true",
-        help="when --mesh needs more devices than the default platform "
-        "has, rebuild it on host CPU devices instead of failing (tests / "
-        "dry runs; ~100x slower than TPU — never for production)",
     )
     p.add_argument(
         "--quantize", action="store_true",
@@ -151,7 +146,6 @@ def config_from_args(args: argparse.Namespace) -> PipelineConfig:
         batch_size=args.batch_size,
         tokenizer=args.tokenizer,
         mesh_shape=mesh_shape,
-        allow_cpu_mesh=args.allow_cpu_mesh,
         long_context=args.long_context,
         long_context_quantize_kv=args.quantize_kv_long,
         quantize=args.quantize,
@@ -174,13 +168,31 @@ def config_from_args(args: argparse.Namespace) -> PipelineConfig:
     return cfg
 
 
+def failures(results) -> list[str]:
+    """One line per model whose summarization or evaluation failed, or that
+    left failed documents behind. The runner's per-document and per-model
+    catches keep partial progress; the exit code still has to say so."""
+    out = []
+    for model, rec in results.summarization.items():
+        if rec.get("status") == "failed":
+            out.append(f"{model}: summarization failed: {rec.get('error')}")
+        elif rec.get("failed", 0):
+            out.append(f"{model}: {rec['failed']} document(s) failed")
+    for model, ev in results.evaluation.items():
+        if ev.get("status") == "failed":
+            out.append(f"{model}: evaluation failed: {ev.get('error')}")
+    return out
+
+
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
     from .runner import PipelineRunner
 
     runner = PipelineRunner(config_from_args(args))
-    runner.run()
-    return 0
+    failed = failures(runner.run())
+    for line in failed:
+        print(f"vnsum-pipeline: {line}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":

@@ -1581,6 +1581,13 @@ def main(argv: list[str] | None = None) -> int:
         p.error("exactly one of --workers / --spawn-workers is required")
     if args.spawn_workers and not args.fleet_dir:
         p.error("--spawn-workers requires --fleet-dir")
+    if args.spawn_workers > 1 and args.backend == "tpu":
+        p.error(
+            f"--spawn-workers {args.spawn_workers} --backend tpu: a chip "
+            "belongs to one process, and spawned workers cannot be given a "
+            "device each yet — they would all claim the same one. Spawn one "
+            "tpu worker, or place workers yourself and pass --workers"
+        )
 
     tenants = None
     if args.tenants:

@@ -45,6 +45,7 @@ import threading
 import time
 from collections import OrderedDict
 from concurrent.futures import InvalidStateError
+from functools import partial
 
 from ..analysis.sanitizers import make_lock
 from ..backend.base import Backend
@@ -205,6 +206,11 @@ class MicroBatchScheduler:
             self._hb = watchdog.register("scheduler", kind="loop")
             self.queue.heartbeat = self._hb.beat
             watchdog.on_hung_dispatch = self.recover_hung_dispatch
+            if hasattr(backend, "compile_scope"):
+                # a program's first call compiles; that is not dispatch time
+                backend.compile_scope = partial(
+                    watchdog.compiling, "scheduler"
+                )
         self._thread = threading.Thread(
             target=self._loop, name="vnsum-serve-scheduler", daemon=True
         )

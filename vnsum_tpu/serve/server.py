@@ -868,6 +868,11 @@ def make_handler(state: ServeState):
                     # echo the serving mesh so probes/load balancers can
                     # verify the topology a replica actually runs with
                     payload["mesh"] = mesh_state
+                describe = getattr(state.backend, "describe", None)
+                if callable(describe):
+                    # the device as JAX reports it, the attention path of
+                    # every built program, compile seconds, device memory
+                    payload["engine"] = describe()
                 if state.tenants is not None:
                     # echo the QoS table (name -> weight/rate/tier) so
                     # operators can verify what a replica actually enforces

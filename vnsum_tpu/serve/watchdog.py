@@ -21,7 +21,12 @@ with the work instead of a one-size timeout). While a ticket is armed the
 owner's heartbeat check is SUSPENDED — the loop can't beat mid-dispatch,
 and a slow-but-progressing dispatch inside its budget must never be
 flagged (the false-positive-immunity contract) — and a ticket past its
-budget is declared HUNG.
+budget is declared HUNG. Compiling is not dispatching: programs are built
+lazily, one XLA compile of a 3B program outlasts any sane dispatch budget,
+and a cold server would otherwise declare its first request hung while the
+abandoned thread is still compiling. The engine enters
+:meth:`Watchdog.compiling` around each program's first call; the ticket's
+clock stops for that stretch and ``COMPILE_BUDGET_S`` bounds it instead.
 
 On a stall the monitor thread:
 
@@ -57,6 +62,7 @@ sleeping.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import sys
 import threading
@@ -76,6 +82,11 @@ logger = get_logger("vnsum.serve.watchdog")
 # process manager / the chaos harness can tell "the watchdog gave up on
 # this process" from everything else
 WATCHDOG_EXIT_CODE = 86
+
+# ceiling on one program's first call (trace + XLA compile) inside a ticket:
+# generous next to the minutes a cold multi-billion-parameter program takes,
+# finite so a compiler that never returns is still a detected hang
+COMPILE_BUDGET_S = 900.0
 
 # classification vocabulary — the stable label set of
 # vnsum_serve_watchdog_stalls_total{kind}
@@ -137,9 +148,14 @@ class DispatchTicket:
     started_at: float
     riders: tuple = ()    # trace ids, for the stall report
     tokens: int = 0
+    # set while the owner compiles (Watchdog.compiling): the dispatch clock
+    # is stopped and COMPILE_BUDGET_S governs instead
+    compiling_since: float | None = None
 
-    def age(self, now: float) -> float:
-        return now - self.started_at
+    def over_budget(self, now: float) -> bool:
+        if self.compiling_since is not None:
+            return now - self.compiling_since > COMPILE_BUDGET_S
+        return now - self.started_at > self.budget_s
 
 
 @dataclass
@@ -274,6 +290,24 @@ class Watchdog:
             if self._tickets.get(ticket.owner) is ticket:
                 del self._tickets[ticket.owner]
 
+    @contextlib.contextmanager
+    def compiling(self, owner: str):
+        """Stop ``owner``'s dispatch clock while it compiles a program. On
+        exit the ticket's start moves forward by the compile time, so the
+        budget still bounds the dispatch itself. A no-op without an armed
+        ticket (and for a ticket already declared hung)."""
+        with self._lock:
+            ticket = self._tickets.get(owner)
+            if ticket is not None:
+                ticket.compiling_since = self._clock()
+        try:
+            yield
+        finally:
+            with self._lock:
+                if ticket is not None and ticket.compiling_since is not None:
+                    ticket.started_at += self._clock() - ticket.compiling_since
+                    ticket.compiling_since = None
+
     # -- detection --------------------------------------------------------
 
     def check(self, now: float | None = None) -> list[Stall]:
@@ -287,9 +321,10 @@ class Watchdog:
         with self._lock:
             hung_owners: set[str] = set()
             for owner, t in list(self._tickets.items()):
-                age = t.age(now)
-                if age <= t.budget_s:
+                if not t.over_budget(now):
                     continue
+                compiling = t.compiling_since is not None
+                age = now - (t.compiling_since if compiling else t.started_at)
                 # declared hung: remove it so end_dispatch from the
                 # abandoned thread no-ops and the next interval doesn't
                 # re-declare the same dispatch
@@ -305,8 +340,10 @@ class Watchdog:
                     hb.beat()
                 out.append(Stall(
                     kind="dispatch", name=owner, stalled_for_s=age,
-                    limit_s=t.budget_s, ticket=t,
+                    limit_s=COMPILE_BUDGET_S if compiling else t.budget_s,
+                    ticket=t,
                     detail={"dispatch_kind": t.kind, "tokens": t.tokens,
+                            "compiling": compiling,
                             "riders": list(t.riders)[:32]},
                 ))
             for name, hb in self._beats.items():

@@ -71,9 +71,19 @@ class WorkerHandle:
             *self.extra_args,
         ]
 
+    def _wants_chip(self) -> bool:
+        a = self.extra_args
+        return "--backend=tpu" in a or any(
+            x == "--backend" and y == "tpu" for x, y in zip(a, a[1:])
+        )
+
     def start(self) -> None:
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        if not self._wants_chip():
+            # fake/ollama/hf workers have no use for an accelerator: keep
+            # them off it, so a fleet of them never claims a chip. A tpu
+            # worker gets the platform JAX finds — never a quiet CPU
+            env.setdefault("JAX_PLATFORMS", "cpu")
         if self.env:
             env.update(self.env)
         self.generation += 1
