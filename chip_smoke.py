@@ -952,6 +952,18 @@ def phase_serve(env: dict, work: Path, logs: Path, timeout_s: float,
     return rep
 
 
+def result_line(ok: bool, device: dict) -> str:
+    """The last line of stdout: one JSON object with exactly the keys "ok"
+    and "device", the device with exactly "platform", "kind" and "count" as
+    the device phase read them from jax.devices()."""
+    return json.dumps({
+        "ok": bool(ok),
+        "device": {"platform": str(device.get("platform")),
+                   "kind": str(device.get("device_kind")),
+                   "count": int(device.get("count") or 0)},
+    })
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--rehearsal", action="store_true",
@@ -1043,17 +1055,13 @@ def main(argv: list[str] | None = None) -> int:
     report["wall_s"] = round(time.time() - t_start, 1)
     out.write_text(json.dumps(report, indent=1))
     shutil.rmtree(work, ignore_errors=True)
-    result = {
-        "ok": ok,
-        "device": {"platform": platform, "kind": device.get("device_kind"),
-                   "count": device.get("count")},
-        "phases": {p: ("skipped" if r.get("skipped") else bool(r.get("ok")))
-                   for p, r in ran.items()},
-        "seconds": report["wall_s"], "report": str(out),
-    }
-    if args.rehearsal:
-        result["rehearsal"] = True
-    print(json.dumps(result))
+    print(f"report: {out}  wall: {report['wall_s']}s"
+          + ("  (rehearsal: not a result)" if args.rehearsal else ""),
+          flush=True)
+    if not args.rehearsal:
+        # the contract line: exactly these keys, the device as JAX reports it,
+        # and nothing after it on stdout. A rehearsal never prints one
+        print(result_line(ok, device), flush=True)
     return 0 if ok else 1
 
 

@@ -4,6 +4,7 @@ print no result — and nothing else may time or serve from the CPU either
 tpu workers on one chip)."""
 from __future__ import annotations
 
+import json
 import os
 import subprocess
 import sys
@@ -47,6 +48,25 @@ def test_parent_module_imports_without_jax():
     )
     proc = _run([sys.executable, "-c", code])
     assert proc.returncode == 0, proc.stderr
+
+
+def test_result_line_has_exactly_the_contract_keys():
+    sys.path.insert(0, str(REPO))
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(str(REPO))
+    line = chip_smoke.result_line(
+        True, {"platform": "tpu", "device_kind": "TPU v5 lite", "count": 1})
+    assert "\n" not in line
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+    failed = json.loads(chip_smoke.result_line(False, {}))
+    assert set(failed) == {"ok", "device"} and failed["ok"] is False
+    assert set(failed["device"]) == {"platform", "kind", "count"}
+    assert isinstance(failed["device"]["count"], int)
 
 
 def test_bench_refuses_to_time_the_cpu():
