@@ -400,6 +400,100 @@ class TpuBackend:
             ],
         }
 
+    def scope_maps(self) -> list[dict]:
+        """For each generation program this engine has built, which
+        ``jax.named_scope`` every instruction was written under:
+        ``[{"program", "module", "compile_s", "scopes"}]`` with ``module``
+        the XLA module's name as the device trace has it (several programs
+        share one: every one-shot bucket is ``jit_generate``) and ``scopes``
+        ``core.profiling.hlo_scope_map`` of the compiled text. The device
+        trace carries no scope, so this is what lets a trace be read by
+        layer (``scripts/trace_by_scope.py``).
+
+        Each program is built and lowered again at the shapes it was built
+        for and compiled with the persistent compile cache off: the cache's
+        key leaves metadata out, so an executable found there may hold the
+        names of an older source. That is one full compile per program and
+        the cache is off for the whole process meanwhile: an operator's
+        call, outside any timed window, never the engine's own. The choice
+        and speculation programs are not covered. Under a mesh the
+        programs that take a cache argument are lowered with it unsharded.
+        """
+        from jax.experimental.compilation_cache import compilation_cache
+
+        from ..core.profiling import hlo_scope_map
+
+        i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)  # noqa: E731
+        done = lambda n: jax.ShapeDtypeStruct((n,), jnp.bool_)  # noqa: E731
+
+        def cache_of(B, C):
+            return jax.eval_shape(lambda: init_kv_cache(
+                self.cfg, B, C, quantized=self.quantize_kv))
+
+        programs = []   # (label, jitted function, arguments)
+        for key in self._fns:
+            if not isinstance(key[0], int):
+                continue   # ("choice", ...), ("spec", ...)
+            B, S, max_new, gen, K = key
+            programs.append((
+                f"generate[B={B},S={S},new={max_new},resume={K}]",
+                self._make_fn(B, S, max_new, gen, K),
+                (self.params, i32(B, S), i32(B), 0)
+                + ((cache_of(B, S + max_new),) if K else ())))
+        for kind, B, S, max_new, gen, K, fused in self._seg_fns:
+            C = S + max_new
+            label = f"{kind}[B={B},S={S},new={max_new},resume={K},fused={fused}]"
+            prompt = (self.params, i32(B, S), i32(B), 0)
+            resumed = (cache_of(B, C),) if K else ()
+            # a decode batch's carry after the step counter: cur, cache,
+            # done, uids, out, pads, seed
+            carry = (i32(B), cache_of(B, C), done(B), i32(B),
+                     i32(B, max_new), i32(B), 0)
+            if kind == "prefill":
+                fn = self._make_prefill_fn(B, S, max_new, gen, K)
+                args = prompt + resumed
+            elif kind == "slot_prefill":
+                fn = self._make_slot_prefill_fn(B, S, max_new, gen, K)
+                args = prompt + (i32(B),) + resumed
+            elif kind == "slot_seg":
+                fn = self._make_slot_segment_fn(B, S, max_new, gen, fused)
+                args = (self.params, i32(B)) + carry
+            elif kind == "adopt":
+                # the resident batch is not in an adopt key: it is the B of
+                # the slot segment built for the same loop
+                slots = {k[1] for k in self._seg_fns
+                         if k[0] == "slot_seg" and k[2:5] == (S, max_new, gen)}
+                fn = self._make_adopt_fn(B)
+                for n in sorted(slots):
+                    programs.append((f"{label[:-1]},slots={n}]", fn, (
+                        cache_of(n, C), i32(n), done(n), i32(n),
+                        i32(n, max_new), i32(n),
+                        cache_of(B, C), i32(B), done(B), i32(B), i32(B))))
+                continue
+            else:
+                fn = self._make_segment_fn(B, S, max_new, gen)
+                args = (self.params, i32()) + carry
+            programs.append((label, fn, args))
+
+        maps = []
+        cache_was_on = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()   # the switch is read once a process
+        try:
+            for label, fn, args in programs:
+                t0 = time.time()
+                text = fn.lower(*args).compile().as_text()
+                maps.append({
+                    "program": label,
+                    "module": text.split(None, 2)[1].rstrip(","),
+                    "compile_s": round(time.time() - t0, 3),
+                    "scopes": hlo_scope_map(text),
+                })
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_was_on)
+            compilation_cache.reset_cache()
+        return maps
+
     def _sampling_setup(self, gen: GenerationConfig):
         """(eos ids, vocab limit, restrict fn) — the ONE sampling restriction
         shared by the plain decode programs (_make_parts) and the spec verify
@@ -468,28 +562,32 @@ class TpuBackend:
         # (measured 1.36x decode vs 2x B=8 dispatches,
         # artifacts/b16_chunked_prefill.json); see _prefill_forward
         def prefill_part(params, tokens, pad_lens, seed, cache=None):
-            logits, cache = self._prefill_forward(
-                params, tokens, pad_lens, B, S, C, use_flash, layer_window,
-                cache=cache, start=resume_from,
-            )
-            base = jax.random.key(seed)
-            uids0 = jnp.arange(B, dtype=jnp.int32)
-            keys0 = jax.vmap(
-                lambda u: jax.random.fold_in(jax.random.fold_in(base, u), 0)
-            )(uids0)
-            first = sample_logits_rows(
-                restrict(logits[:, -1, :vocab_limit]), keys0,
-                gen.temperature, gen.top_k, gen.top_p,
-            )
-            # all-pad dummy rows (batch bucketing filler) start done, else
-            # their garbage decode would keep the early exit from firing
-            done0 = pad_lens == S
+            with jax.named_scope("prefill"):
+                logits, cache = self._prefill_forward(
+                    params, tokens, pad_lens, B, S, C, use_flash,
+                    layer_window, cache=cache, start=resume_from,
+                )
+                with jax.named_scope("sample"):
+                    base = jax.random.key(seed)
+                    uids0 = jnp.arange(B, dtype=jnp.int32)
+                    keys0 = jax.vmap(
+                        lambda u: jax.random.fold_in(
+                            jax.random.fold_in(base, u), 0
+                        )
+                    )(uids0)
+                    first = sample_logits_rows(
+                        restrict(logits[:, -1, :vocab_limit]), keys0,
+                        gen.temperature, gen.top_k, gen.top_p,
+                    )
+                # all-pad dummy rows (batch bucketing filler) start done,
+                # else their garbage decode would keep the early exit from
+                # firing
+                done0 = pad_lens == S
             return first, cache, done0
 
         def decode_part(
             params, t0, cur, cache, done, uids, out, pad_lens, t_end, seed
         ):
-            base = jax.random.key(seed)
             # decode loop with early exit: a while_loop instead of a fixed
             # lax.scan, so the program stops as soon as every row has hit
             # EOS (real summaries end far before the max_new budget)
@@ -504,7 +602,8 @@ class TpuBackend:
 
             def body(carry):
                 t, cur, cache, done, out = carry
-                out, done = emit_token(out, cur, done, t)
+                with jax.named_scope("emit"):
+                    out, done = emit_token(out, cur, done, t)
                 pos = (S - pad_lens) + t
                 mask_t = decode_attention_mask(pad_lens, S + t, C)
                 stacked_fn = None
@@ -531,23 +630,26 @@ class TpuBackend:
                     params, cfg, cur[:, None], pos[:, None], cache, S + t,
                     mask_t, stacked_attention_fn=stacked_fn,
                 )
-                step_keys = jax.vmap(
-                    lambda u: jax.random.fold_in(
-                        jax.random.fold_in(base, u), t + 1
+                with jax.named_scope("sample"):
+                    step_keys = jax.vmap(
+                        lambda u: jax.random.fold_in(
+                            jax.random.fold_in(base, u), t + 1
+                        )
+                    )(uids)
+                    nxt = sample_logits_rows(
+                        restrict(logits[:, -1, :vocab_limit]), step_keys,
+                        gen.temperature, gen.top_k, gen.top_p,
                     )
-                )(uids)
-                nxt = sample_logits_rows(
-                    restrict(logits[:, -1, :vocab_limit]), step_keys,
-                    gen.temperature, gen.top_k, gen.top_p,
-                )
                 return (t + 1, nxt, cache, done, out)
 
             # each iteration emits BEFORE sampling, so on exit (budget spent
             # or all rows done) every live slot is already written and the
             # rest remain pad from the init — identical to a full-length scan
-            return jax.lax.while_loop(
-                cond, body, (t0, cur, cache, done, out)
-            )
+            with jax.named_scope("decode"):
+                base = jax.random.key(seed)
+                return jax.lax.while_loop(
+                    cond, body, (t0, cur, cache, done, out)
+                )
 
         return prefill_part, decode_part
 
@@ -909,7 +1011,11 @@ class TpuBackend:
         _, decode_part = self._make_parts(B, S, max_new, gen)
         seg = self.segment_tokens
 
-        def segment(params, t0, cur, cache, done, uids, out, pad_lens, seed):
+        # not ``segment``: the slot loop's program owns the XLA module name
+        # jit_segment, which the benchmark's metrics read
+        def decode_segment(
+            params, t0, cur, cache, done, uids, out, pad_lens, seed
+        ):
             t_end = jnp.minimum(t0 + seg, max_new)
             t, cur, cache, done, out = decode_part(
                 params, t0, cur, cache, done, uids, out, pad_lens, t_end, seed
@@ -917,7 +1023,7 @@ class TpuBackend:
             return t, cur, cache, done, out
 
         # donate the cache and out buffers: segments overwrite them in place
-        return jax.jit(segment, donate_argnums=(3, 6))
+        return jax.jit(decode_segment, donate_argnums=(3, 6))
 
     def _make_compact_fn(self):
         def compact(cache, cur, done, out, pad_lens, idx):
@@ -947,20 +1053,24 @@ class TpuBackend:
         layer_window = self._layer_window_fn()
 
         def slot_prefill(params, tokens, pad_lens, seed, uids, cache=None):
-            logits, cache = self._prefill_forward(
-                params, tokens, pad_lens, B, S, C, use_flash, layer_window,
-                cache=cache, start=resume_from,
-            )
-            base = jax.random.key(seed)
-            keys0 = jax.vmap(
-                lambda u: jax.random.fold_in(jax.random.fold_in(base, u), 0)
-            )(uids)
-            first = sample_logits_rows(
-                restrict(logits[:, -1, :vocab_limit]), keys0,
-                gen.temperature, gen.top_k, gen.top_p,
-            )
-            # all-pad filler rows (join-batch bucketing) start done
-            done0 = pad_lens == S
+            with jax.named_scope("prefill"):
+                logits, cache = self._prefill_forward(
+                    params, tokens, pad_lens, B, S, C, use_flash,
+                    layer_window, cache=cache, start=resume_from,
+                )
+                with jax.named_scope("sample"):
+                    base = jax.random.key(seed)
+                    keys0 = jax.vmap(
+                        lambda u: jax.random.fold_in(
+                            jax.random.fold_in(base, u), 0
+                        )
+                    )(uids)
+                    first = sample_logits_rows(
+                        restrict(logits[:, -1, :vocab_limit]), keys0,
+                        gen.temperature, gen.top_k, gen.top_p,
+                    )
+                # all-pad filler rows (join-batch bucketing) start done
+                done0 = pad_lens == S
             return first, cache, done0
 
         if resume_from:
@@ -1012,8 +1122,6 @@ class TpuBackend:
         seg = self.segment_tokens * max(int(fused_segments), 1)
 
         def segment(params, t, cur, cache, done, uids, out, pads, seed):
-            base = jax.random.key(seed)
-
             def emit_row(o, c, tt, d):
                 # done rows hold a frozen cursor: an unguarded write would
                 # clobber the row's last real token with its stale cur
@@ -1028,8 +1136,9 @@ class TpuBackend:
                 k, t, cur, cache, done, out = carry
                 # emit BEFORE sampling, mirroring decode_part: on exit every
                 # live token is written and the rest stay pad from the init
-                out = jax.vmap(emit_row)(out, cur, t, done)
-                done = done | jnp.isin(cur, eos)
+                with jax.named_scope("emit"):
+                    out = jax.vmap(emit_row)(out, cur, t, done)
+                    done = done | jnp.isin(cur, eos)
                 fills = S + t                                   # [B]
                 positions = verify_positions(pads, fills, 1)
                 mask = verify_attention_mask(pads, fills, 1, C)
@@ -1050,15 +1159,16 @@ class TpuBackend:
                     params, cfg, cur[:, None], positions, cache, fills,
                     mask, stacked_attention_fn=stacked_fn,
                 )
-                step_keys = jax.vmap(
-                    lambda u, tt: jax.random.fold_in(
-                        jax.random.fold_in(base, u), tt + 1
+                with jax.named_scope("sample"):
+                    step_keys = jax.vmap(
+                        lambda u, tt: jax.random.fold_in(
+                            jax.random.fold_in(base, u), tt + 1
+                        )
+                    )(uids, t)
+                    nxt = sample_logits_rows(
+                        restrict(logits[:, -1, :vocab_limit]), step_keys,
+                        gen.temperature, gen.top_k, gen.top_p,
                     )
-                )(uids, t)
-                nxt = sample_logits_rows(
-                    restrict(logits[:, -1, :vocab_limit]), step_keys,
-                    gen.temperature, gen.top_k, gen.top_p,
-                )
                 # done rows freeze t (their out cursor) and cur; live rows
                 # advance exactly like decode_part's shared t
                 t = jnp.where(done, t, t + 1)
@@ -1066,9 +1176,11 @@ class TpuBackend:
                 cur = jnp.where(done, cur, nxt)
                 return (k + 1, t, cur, cache, done, out)
 
-            _, t, cur, cache, done, out = jax.lax.while_loop(
-                cond, body, (jnp.int32(0), t, cur, cache, done, out)
-            )
+            with jax.named_scope("decode"):
+                base = jax.random.key(seed)
+                _, t, cur, cache, done, out = jax.lax.while_loop(
+                    cond, body, (jnp.int32(0), t, cur, cache, done, out)
+                )
             return t, cur, cache, done, out
 
         # donate the resident cache and out buffers: segments overwrite
@@ -1087,15 +1199,16 @@ class TpuBackend:
 
         def adopt(cache, cur, done, t, out, pads,
                   join_cache, first, done0, join_pads, slot_idx):
-            cache = {
-                k: v.at[:, slot_idx].set(join_cache[k])
-                for k, v in cache.items()
-            }
-            cur = cur.at[slot_idx].set(first)
-            done = done.at[slot_idx].set(done0)
-            t = t.at[slot_idx].set(0)
-            out = out.at[slot_idx].set(pad_id)
-            pads = pads.at[slot_idx].set(join_pads)
+            with jax.named_scope("adopt"):
+                cache = {
+                    k: v.at[:, slot_idx].set(join_cache[k])
+                    for k, v in cache.items()
+                }
+                cur = cur.at[slot_idx].set(first)
+                done = done.at[slot_idx].set(done0)
+                t = t.at[slot_idx].set(0)
+                out = out.at[slot_idx].set(pad_id)
+                pads = pads.at[slot_idx].set(join_pads)
             return cache, cur, done, t, out, pads
 
         # donate the resident cache/out (overwritten in place); the join

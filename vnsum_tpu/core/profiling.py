@@ -21,13 +21,20 @@ those capabilities and makes them first-class:
   activation (...critique.py:22-23).
 - `annotate(name)` — `jax.profiler.TraceAnnotation` passthrough so host-side
   phases show up inside device traces.
+- `hlo_scope_map(hlo_text)` — the device half: which `jax.named_scope` each
+  instruction of a compiled program was written under. The device trace
+  names an operation by its HLO line alone, so the program hands this map
+  to whoever reads the trace (`TpuBackend.scope_maps`,
+  `scripts/trace_by_scope.py`).
 """
 from __future__ import annotations
 
 import contextlib
 import os
+import re
 import threading
 import time
+from collections import Counter
 from dataclasses import dataclass
 
 from ..obs.trace import Span, SpanRecorder
@@ -141,3 +148,57 @@ def annotate(name: str):
         cm = contextlib.nullcontext()
     with cm:
         yield
+
+
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+# what JAX puts into an op_name besides the scopes someone wrote: the
+# transformations (``jit(generate)``, ``vmap(...)``: anything called with
+# brackets) and the bodies of control flow and calls
+_OP_NAME_WRAPPERS = frozenset({
+    "while", "body", "cond", "closed_call", "core_call", "checkpoint",
+    "remat", "custom_jvp_call", "custom_vjp_call", "pjit",
+})
+
+
+def hlo_scope_map(hlo_text: str) -> dict[str, str]:
+    """{instruction name: scope path} for every instruction of a compiled
+    module's text (``compiled.as_text()``).
+
+    The scope path is the instruction's ``op_name`` without its last part
+    (the primitive) and without JAX's own wrappers, joined by ``/``:
+    ``jit(generate)/decode/while/body/mlp/dot_general`` gives ``decode/mlp``.
+    An instruction without metadata, or written under no scope, maps to
+    ``""``. A fusion carries its own metadata, that of one of the
+    instructions fused into it: a fusion that spans two scopes is booked
+    under one of them. A fusion the compiler left without metadata (a
+    multi-output fusion, whose root is the compiler's own tuple) takes the
+    scope most of the instructions fused into it were written under. Names
+    are as the device trace spells them (``fusion.989``, no ``%``).
+    """
+    scopes: dict[str, str] = {}
+    inside: dict[str, Counter] = {}      # computation -> its scopes, counted
+    bare_calls: dict[str, str] = {}      # instruction without metadata -> callee
+    computation = ""
+    for line in hlo_text.splitlines():
+        inst = _HLO_INSTRUCTION.match(line)
+        if inst is None:
+            header = _HLO_COMPUTATION.match(line)
+            if header:
+                computation = header.group(1)
+            continue
+        op_name = _HLO_OP_NAME.search(line)
+        parts = op_name.group(1).split("/")[:-1] if op_name else []
+        scope = "/".join(
+            p for p in parts if p not in _OP_NAME_WRAPPERS and "(" not in p)
+        scopes[inst.group(1)] = scope
+        if scope:
+            inside.setdefault(computation, Counter())[scope] += 1
+        elif op_name is None and (callee := _HLO_CALLS.search(line)):
+            bare_calls[inst.group(1)] = callee.group(1)
+    for name, callee in bare_calls.items():
+        if callee in inside:
+            scopes[name] = inside[callee].most_common(1)[0][0]
+    return scopes

@@ -437,7 +437,13 @@ def _block(
     decode HBM traffic at weights+cache-read — emitting per-layer caches as
     scan outputs would re-materialize the whole ~GB cache every decode
     step. ``write_index`` may be a [B] vector (see _cache_write) for the
-    speculative verify step's per-row fills."""
+    speculative verify step's per-row fills.
+
+    The ``jax.named_scope`` names here and in ``forward`` (embed, qkv,
+    kv_write, attn, attn_out, mlp, lm_head) are metadata of the compiled
+    program: a device trace is read by them
+    (``core.profiling.hlo_scope_map``, README "Device time by layer"), so
+    none carries a shape or a number."""
     P1 = cfg.norm_plus_one
     cos, sin = rope[0]
     if cfg.sliding_window:
@@ -471,69 +477,78 @@ def _block(
     # exact — speculation promises greedy outputs identical to plain
     # decode, and plain decode scores these positions unquantized
     aq = cfg.w8a8_prefill and x.shape[1] > 1 and jnp.ndim(write_index) == 0
-    h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps, P1)
-    q = _proj("bsd,dhk->bshk", h, lp["wq"], aq)
-    k = _proj("bsd,dhk->bshk", h, lp["wk"], aq)
-    v = _proj("bsd,dhk->bshk", h, lp["wv"], aq)
-    if cfg.qk_norm:
-        # Qwen3/Gemma3: RMSNorm over each head's hd dim before RoPE
-        q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps, P1)
-        k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps, P1)
-    if cfg.query_scale:
-        # fold a non-default score scale (Gemma's query_pre_attn_scalar)
-        # into q so every attention implementation (dense, ring, Pallas)
-        # keeps its built-in 1/sqrt(head_dim)
-        q = q * jnp.asarray(
-            (cfg.head_dim ** 0.5) / (cfg.query_scale ** 0.5), q.dtype
-        )
-    q = _apply_rope(q, cos, sin)
-    k = _apply_rope(k, cos, sin)
+    with jax.named_scope("qkv"):
+        h = _rmsnorm(x, lp["attn_norm"], cfg.norm_eps, P1)
+        q = _proj("bsd,dhk->bshk", h, lp["wq"], aq)
+        k = _proj("bsd,dhk->bshk", h, lp["wk"], aq)
+        v = _proj("bsd,dhk->bshk", h, lp["wv"], aq)
+        if cfg.qk_norm:
+            # Qwen3/Gemma3: RMSNorm over each head's hd dim before RoPE
+            q = _rmsnorm(q, lp["q_norm"], cfg.norm_eps, P1)
+            k = _rmsnorm(k, lp["k_norm"], cfg.norm_eps, P1)
+        if cfg.query_scale:
+            # fold a non-default score scale (Gemma's query_pre_attn_scalar)
+            # into q so every attention implementation (dense, ring, Pallas)
+            # keeps its built-in 1/sqrt(head_dim)
+            q = q * jnp.asarray(
+                (cfg.head_dim ** 0.5) / (cfg.query_scale ** 0.5), q.dtype
+            )
+        q = _apply_rope(q, cos, sin)
+        k = _apply_rope(k, cos, sin)
 
-    kt = k.transpose(0, 2, 1, 3)  # [B, KV, S, hd] — cache-native
-    vt = v.transpose(0, 2, 1, 3)
-    if is_quantized_cache(cache):
-        k8, ks = _quantize_kv(kt)
-        v8, vs = _quantize_kv(vt)
-        cache = dict(
-            cache,
-            k=_cache_write(cache["k"], k8, layer_idx, write_index),
-            v=_cache_write(cache["v"], v8, layer_idx, write_index),
-            ks=_cache_write(cache["ks"], ks, layer_idx, write_index),
-            vs=_cache_write(cache["vs"], vs, layer_idx, write_index),
-        )
-    else:
-        cache = dict(
-            cache,
-            k=_cache_write(cache["k"], kt, layer_idx, write_index),
-            v=_cache_write(cache["v"], vt, layer_idx, write_index),
-        )
-
-    if stacked_attention_fn is not None:
-        # reads the stacked cache in place (Pallas kernels): no per-layer
-        # extraction copy materializes
-        attn = stacked_attention_fn(q, cache, layer_idx)
-    else:
-        k_cache, v_cache = dequantize_cache_layer(cache, layer_idx)
-        k_cache = k_cache.astype(q.dtype)
-        v_cache = v_cache.astype(q.dtype)
-        if attention_fn is None:
-            attn = _attention(q, k_cache, v_cache, mask, cfg.q_per_kv)
+    with jax.named_scope("kv_write"):
+        kt = k.transpose(0, 2, 1, 3)  # [B, KV, S, hd] — cache-native
+        vt = v.transpose(0, 2, 1, 3)
+        if is_quantized_cache(cache):
+            k8, ks = _quantize_kv(kt)
+            v8, vs = _quantize_kv(vt)
+            cache = dict(
+                cache,
+                k=_cache_write(cache["k"], k8, layer_idx, write_index),
+                v=_cache_write(cache["v"], v8, layer_idx, write_index),
+                ks=_cache_write(cache["ks"], ks, layer_idx, write_index),
+                vs=_cache_write(cache["vs"], vs, layer_idx, write_index),
+            )
         else:
-            attn = attention_fn(q, k_cache, v_cache, mask, cfg.q_per_kv)
-    attn_out = _proj("bshk,hkd->bsd", attn, lp["wo"], aq)
-    if cfg.sandwich_norms:
-        attn_out = _rmsnorm(attn_out, lp["post_attn_norm"], cfg.norm_eps, P1)
-    x = x + attn_out
+            cache = dict(
+                cache,
+                k=_cache_write(cache["k"], kt, layer_idx, write_index),
+                v=_cache_write(cache["v"], vt, layer_idx, write_index),
+            )
 
-    h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, P1)
-    gate = _proj("bsd,di->bsi", h, lp["w_gate"], aq)
-    up = _proj("bsd,di->bsi", h, lp["w_up"], aq)
-    mlp_out = _proj(
-        "bsi,id->bsd", _mlp_act(gate, cfg.act) * up, lp["w_down"], aq
-    )
-    if cfg.sandwich_norms:
-        mlp_out = _rmsnorm(mlp_out, lp["post_ffw_norm"], cfg.norm_eps, P1)
-    return x + mlp_out, cache
+    with jax.named_scope("attn"):
+        if stacked_attention_fn is not None:
+            # reads the stacked cache in place (Pallas kernels): no
+            # per-layer extraction copy materializes
+            attn = stacked_attention_fn(q, cache, layer_idx)
+        else:
+            k_cache, v_cache = dequantize_cache_layer(cache, layer_idx)
+            k_cache = k_cache.astype(q.dtype)
+            v_cache = v_cache.astype(q.dtype)
+            if attention_fn is None:
+                attn = _attention(q, k_cache, v_cache, mask, cfg.q_per_kv)
+            else:
+                attn = attention_fn(q, k_cache, v_cache, mask, cfg.q_per_kv)
+    with jax.named_scope("attn_out"):
+        attn_out = _proj("bshk,hkd->bsd", attn, lp["wo"], aq)
+        if cfg.sandwich_norms:
+            attn_out = _rmsnorm(
+                attn_out, lp["post_attn_norm"], cfg.norm_eps, P1
+            )
+        x = x + attn_out
+
+    with jax.named_scope("mlp"):
+        h = _rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, P1)
+        gate = _proj("bsd,di->bsi", h, lp["w_gate"], aq)
+        up = _proj("bsd,di->bsi", h, lp["w_up"], aq)
+        mlp_out = _proj(
+            "bsi,id->bsd", _mlp_act(gate, cfg.act) * up, lp["w_down"], aq
+        )
+        if cfg.sandwich_norms:
+            mlp_out = _rmsnorm(
+                mlp_out, lp["post_ffw_norm"], cfg.norm_eps, P1
+            )
+        return x + mlp_out, cache
 
 
 def forward(
@@ -562,20 +577,22 @@ def forward(
     ``stacked_attention_fn(q, cache, layer_idx)`` overrides it with a
     consumer of the FULL stacked cache dict (the Pallas kernels) and takes
     precedence."""
-    x = _embed_lookup(params["embed"], tokens, cfg.dtype)
-    if cfg.embed_scale:
-        # Gemma scales hidden states by sqrt(dim), rounded through the
-        # model dtype like the HF implementation's normalizer
-        x = x * jnp.asarray(cfg.dim ** 0.5, cfg.dtype)
-    rope = (_rope_cos_sin(cfg, positions),)
-    if cfg.sliding_window:
-        import dataclasses as _dc
+    with jax.named_scope("embed"):
+        x = _embed_lookup(params["embed"], tokens, cfg.dtype)
+        if cfg.embed_scale:
+            # Gemma scales hidden states by sqrt(dim), rounded through the
+            # model dtype like the HF implementation's normalizer
+            x = x * jnp.asarray(cfg.dim ** 0.5, cfg.dtype)
+    with jax.named_scope("qkv"):  # the rope tables every layer's qkv reads
+        rope = (_rope_cos_sin(cfg, positions),)
+        if cfg.sliding_window:
+            import dataclasses as _dc
 
-        local_cfg = _dc.replace(
-            cfg, rope_theta=cfg.rope_local_theta,
-            use_llama3_rope_scaling=False, rope_linear_factor=0.0,
-        )
-        rope = rope + (_rope_cos_sin(local_cfg, positions),)
+            local_cfg = _dc.replace(
+                cfg, rope_theta=cfg.rope_local_theta,
+                use_llama3_rope_scaling=False, rope_linear_factor=0.0,
+            )
+            rope = rope + (_rope_cos_sin(local_cfg, positions),)
     flags = _layer_global_flags(cfg)
 
     block = _block
@@ -597,10 +614,11 @@ def forward(
         (params["layers"], jnp.arange(cfg.n_layers), flags),
     )
 
-    if last_only:
-        x = x[:, -1:, :]
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
-    logits = _lm_head_logits(x, params, cfg)
+    with jax.named_scope("lm_head"):
+        if last_only:
+            x = x[:, -1:, :]
+        x = _rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
+        logits = _lm_head_logits(x, params, cfg)
     return logits, new_cache
 
 
