@@ -554,6 +554,55 @@ def test_chunked_prefill_matches_whole_prompt():
     assert outs["chunked_flash"] == outs["whole_flash"]
 
 
+@pytest.mark.parametrize(
+    "kw",
+    [
+        dict(flash=True, interpret=True),
+        dict(flash=True, interpret=True, prefill_chunk_tokens=128),
+        dict(flash=True, interpret=True, window=24),
+        dict(flash=False),
+    ],
+    ids=["whole", "chunked", "sliding", "dense"],
+)
+def test_prefill_blocks_counts_what_each_dispatch_ran(kw):
+    """EngineStats.prefill_blocks: the kernel's grid cells by class for the
+    pads each one-shot dispatch was packed with, over every chunk and layer
+    (windowed layers with their window); nothing on the dense path."""
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.ops.flash_attention import prefill_block_classes
+
+    kw = dict(kw)
+    window = kw.pop("window", 0)
+    cfg = tiny_llama(
+        max_seq_len=256, n_layers=3, sliding_window=window,
+        layer_is_global=(False, True, False) if window else (),
+    )
+    be = TpuBackend(model_config=cfg, batch_size=4, max_new_tokens=12, **kw)
+    packed = []
+    pack = be._pack_group
+    be._pack_group = lambda *a: packed.append(pack(*a)) or packed[-1]
+    be.generate(["văn bản một " * 14, "hai " * 3, "một tài liệu dài hơn " * 7])
+    if not kw["flash"]:
+        assert be.stats.prefill_blocks == {}
+        return
+    (_, pad_lens, B, S), = packed
+    assert B == 4 and (pad_lens == S).sum() == 1   # one filler row
+    chunk = kw.get("prefill_chunk_tokens") or S
+    want = dict.fromkeys(be.stats.prefill_blocks, 0)
+    for win, n_layers in ((0, 1), (window, 2)) if window else ((0, 3),):
+        for lo in range(0, S, chunk):
+            for name, n in prefill_block_classes(
+                pad_lens, min(chunk, S - lo), S + 12, lo, win,
+                cfg.q_per_kv, cfg.head_dim,
+            ).items():
+                want[name] += n * n_layers
+    assert be.stats.prefill_blocks == want
+    assert S > chunk or "prefill_chunk_tokens" not in kw
+    # the filler row alone is a whole row of dead cells in every layer
+    assert want["dead_pad"] >= sum(want.values()) // B
+    assert want["interior"] + want["edge"] > 0
+
+
 def test_chunked_prefill_rejects_bad_multiple():
     from vnsum_tpu.backend.engine import TpuBackend
 

@@ -1,10 +1,21 @@
+import importlib.util
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from vnsum_tpu.models.llama import _attention, prefill_attention_mask
-from vnsum_tpu.ops.flash_attention import flash_prefill_attention, supports_flash
+from vnsum_tpu.models.llama import (
+    _attention,
+    _quantize_kv,
+    prefill_attention_mask,
+)
+from vnsum_tpu.ops import flash_attention
+from vnsum_tpu.ops.flash_attention import (
+    flash_prefill_attention,
+    prefill_block_classes,
+    supports_flash,
+)
 
 
 def make_case(L, B, S, C, H, KV, hd, seed=0):
@@ -250,3 +261,132 @@ def test_vmem_guard_rejects_explicit_overrides_with_geometry():
             q, cache, 0, jnp.zeros((B,), jnp.int32), H // KV,
             block_q=512, block_k=2048,
         )
+
+
+# -- block classes: dead / interior / edge ---------------------------------
+
+
+@pytest.fixture(scope="module")
+def all_edge_kernel():
+    """The kernel at the parent commit's semantics: a second instance of the
+    module in which every cell the causal/window rule lets through runs the
+    masked body (no pad-dead cell, no interior cell) — what the kernel did
+    before it classified its blocks. A second module instance, so no jit or
+    trace cache is shared with the kernel under test."""
+    spec = importlib.util.spec_from_file_location(
+        "_flash_attention_all_edge", flash_attention.__file__
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    rule = mod._block_class
+
+    def all_edge(*args):
+        seen, padded, interior = rule(*args)
+        return seen, padded & False, interior & False
+
+    mod._block_class = all_edge
+    return mod.flash_prefill_attention
+
+
+def _cache_as(cache, kind):
+    """The f32 test cache as the engine would hold it: f32, bf16 or int8
+    with per-(slot, head) scales, here rounded up to powers of two (see
+    _unoptimized)."""
+    if kind == "f32":
+        return cache
+    if kind == "bf16":
+        return {n: a.astype(jnp.bfloat16) for n, a in cache.items()}
+    out = {}
+    for n in ("k", "v"):
+        _, scales = _quantize_kv(cache[n])
+        scales = jnp.exp2(jnp.ceil(jnp.log2(scales)))
+        out[n] = jnp.clip(
+            jnp.round(cache[n] / scales[..., None]), -127, 127
+        ).astype(jnp.int8)
+        out[n + "s"] = scales
+    return out
+
+
+def _unoptimized(fn, q, cache, layer, pad, q_per_kv, win, off, **static):
+    """``fn`` compiled with the CPU backend's optimizer off. Optimized, the
+    backend compiles the masked and the unmasked body differently, which
+    moves a last bit in the INTERPRETED kernel and says nothing about the
+    kernel on the chip (bit-equal there against the parent commit's file;
+    PERF.md, PR 27). One difference survives the switch: a multiply and the
+    subtract after it become one FMA where no select sits between them.
+    The bit-identity cases therefore make ``dot * scale * ks`` exact — head
+    size 256 (scale 1/16) and power-of-two int8 scales — so that the FMA
+    rounds as the two operations do."""
+    compiled = fn.lower(
+        q, cache, layer, pad, q_per_kv, win, off, **static
+    ).compile(compiler_options={"xla_backend_optimization_level": 0})
+    return compiled(q, cache, layer, pad, win, off)
+
+
+# (S, C, q_offset, window, block_q, block_k, pads); the kernel gets the
+# queries [q_offset, q_offset + S) of a prompt whose cache is filled to there
+_CLASS_CASES = {
+    # pad under zero, one and several whole K blocks (and mid-block)
+    "pads_0_1_3_blocks": (64, 96, 0, 0, 16, 16, [0, 16 + 5, 48 + 9]),
+    # pad ends on a block's edge exactly: the next block is interior
+    "pad_on_block_edge": (64, 96, 0, 0, 16, 16, [16, 32, 48]),
+    # batch-bucketing filler rows (pad == S) beside a real one
+    "filler_rows": (64, 96, 0, 0, 16, 16, [64, 7, 64]),
+    # a later chunk wholly inside one row's pad, partly in another's
+    "chunk_in_pad": (32, 128, 32, 0, 16, 16, [80, 40, 0]),
+    "last_chunk_tail_rows": (32, 128, 64, 0, 16, 32, [90, 70, 3]),
+    # partial tail block: C % bk != 0, and S % bq != 0
+    "partial_tail_blocks": (45, 61, 0, 0, 16, 16, [0, 17, 45]),
+    "wide_k_partial_tail": (64, 80, 0, 0, 16, 32, [0, 33, 64]),
+    # window > 0 with a pad: the windowed layer keeps the edge path
+    "window_with_pad": (64, 96, 0, 24, 16, 16, [0, 20, 64]),
+    "window_chunk_with_pad": (32, 96, 32, 8, 16, 16, [0, 37, 64]),
+}
+
+
+@pytest.mark.parametrize("kind", ["f32", "bf16", "int8"])
+@pytest.mark.parametrize("case", sorted(_CLASS_CASES))
+def test_block_classes_bit_identical_to_masking_every_block(
+    case, kind, all_edge_kernel
+):
+    """Skipping the dead cells and dropping the mask in the interior ones
+    must not change one bit of the output, pad rows (zeros) included."""
+    S, C, off, win, bq, bk, pads = _CLASS_CASES[case]
+    L, B, H, KV, hd = 2, len(pads), 4, 2, 256
+    q, cache = make_case(L, B, off + S, C, H, KV, hd, seed=11)
+    q = q[:, off:]
+    pad = jnp.asarray(pads, jnp.int32)
+    classes = prefill_block_classes(
+        pads, S, C, off, win, H // KV, hd, block_q=bq, block_k=bk
+    )
+    assert classes["dead_pad"] > 0 or max(pads) == 0
+    assert (classes["interior"] > 0) == (win == 0)
+
+    cache = _cache_as(cache, kind)
+    if kind != "f32":
+        q = q.astype(jnp.bfloat16)
+    args = (q, cache, jnp.int32(1), pad, H // KV, jnp.int32(win), jnp.int32(off))
+    kw = dict(block_q=bq, block_k=bk, interpret=True)
+    got, want = (
+        np.asarray(_unoptimized(fn, *args, **kw).astype(jnp.float32))
+        for fn in (flash_prefill_attention, all_edge_kernel)
+    )
+    assert not np.isnan(got).any()
+    np.testing.assert_array_equal(got, want)
+    for b, p in enumerate(pads):  # pad query rows come back as zeros
+        assert not got[b, : max(0, min(S, p - off))].any()
+
+    if kind == "f32":  # and the real rows are still the dense path's
+        mask = prefill_attention_mask(pad, off + S, C)[:, off:]
+        if win:
+            mask = mask & (
+                jnp.arange(C)[None, :] > off + jnp.arange(S)[:, None] - win
+            )[None]
+        dense = np.asarray(
+            _attention(q, cache["k"][1], cache["v"][1], mask, H // KV)
+        )
+        for b, p in enumerate(pads):
+            lo = max(0, min(S, p - off))
+            np.testing.assert_allclose(
+                dense[b, lo:], got[b, lo:], rtol=2e-5, atol=2e-5
+            )

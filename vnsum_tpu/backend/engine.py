@@ -103,6 +103,12 @@ class EngineStats:
     compactions: int = 0
     compacted_batch_sizes: list = field(default_factory=list)
     by_bucket: dict = field(default_factory=dict)
+    # grid cells of the prefill attention kernel by class, per KV head,
+    # summed over layers, chunks and one-shot dispatches
+    # (ops.flash_attention.prefill_block_classes): dead_causal and dead_pad
+    # cells are neither fetched nor computed, interior cells run unmasked,
+    # edge cells masked — what the kernel skips, counted from the pads
+    prefill_blocks: dict = field(default_factory=dict)
     # which attention each built program got, keyed "program[B=..,S=..]" →
     # {"prefill"|"decode": "kernel"|"dense"}: a dense fallback (unaligned
     # head dim, the slot/verify kernel under a mesh) is visible here and in
@@ -829,35 +835,52 @@ class TpuBackend:
             cache = self._init_prefill_cache(B, C)
         positions = prefill_positions(pad_lens, S)
         mask = prefill_attention_mask(pad_lens, S, C)
-        CL = self.prefill_chunk_tokens
-        span = S - start
-        n_chunks = -(-span // CL) if CL and span > CL else 1
-        if n_chunks == 1:
-            if start:
-                tokens = tokens[:, start:]
-                positions = positions[:, start:]
-                mask = mask[:, start:, :]
-            return forward(
-                params, cfg, tokens, positions, cache, start, mask,
-                last_only=True,
-                stacked_attention_fn=self._prefill_stacked(
-                    use_flash, pad_lens, layer_window, q_offset=start
-                ),
-            )
         # chunked: transient activations scale with the CHUNK length, not
         # the full S — the kernel's q_offset places chunk c's queries at
         # cache slots [lo, hi) (see prefill_part's rationale comment)
-        for c in range(n_chunks):
-            lo, hi = start + c * CL, min(S, start + (c + 1) * CL)
+        for lo, hi in self._prefill_spans(S, start):
             logits, cache = forward(
                 params, cfg, tokens[:, lo:hi], positions[:, lo:hi],
                 cache, lo, mask[:, lo:hi, :],
-                last_only=(c == n_chunks - 1),
+                last_only=(hi == S),
                 stacked_attention_fn=self._prefill_stacked(
                     use_flash, pad_lens, layer_window, q_offset=lo
                 ),
             )
         return logits, cache
+
+    def _prefill_spans(self, S: int, start: int = 0) -> list[tuple[int, int]]:
+        """Query spans [lo, hi) one prefill forward over cache slots
+        [start, S) runs: the whole of it, or prefill_chunk_tokens-long
+        chunks when it is longer than one."""
+        CL = self.prefill_chunk_tokens
+        if not CL or S - start <= CL:
+            return [(start, S)]
+        return [(lo, min(S, lo + CL)) for lo in range(start, S, CL)]
+
+    def _count_prefill_blocks(self, pad_lens, S: int, C: int,
+                              start: int = 0) -> None:
+        """Add one dispatch's prefill-kernel cells, by class, to
+        ``stats.prefill_blocks``. Pure host arithmetic on the pads the
+        dispatch was packed with; nothing when its prefill is dense."""
+        if not self._decode_settings(S, C)[0]:
+            return
+        from ..ops.flash_attention import prefill_block_classes
+
+        cfg = self.cfg
+        layers = {0: cfg.n_layers}   # {window: layers that run with it}
+        if cfg.sliding_window:
+            n_global = sum(map(bool, cfg.layer_is_global))
+            layers = {0: n_global,
+                      cfg.sliding_window: cfg.n_layers - n_global}
+        total = self.stats.prefill_blocks
+        for window, n_layers in layers.items():
+            for lo, hi in self._prefill_spans(S, start):
+                for name, n in prefill_block_classes(
+                    pad_lens, hi - lo, C, lo, window, cfg.q_per_kv,
+                    cfg.head_dim,
+                ).items():
+                    total[name] = total.get(name, 0) + n * n_layers
 
     # -- constrained choice scoring --------------------------------------
 
@@ -2081,6 +2104,7 @@ class TpuBackend:
                     self.stats.by_bucket[(B, S)] = (
                         self.stats.by_bucket.get((B, S), 0) + 1
                     )
+                    self._count_prefill_blocks(pad_lens, S, S + max_new, K)
                     if insert_cb is not None:
                         insert_cb(final_cache)
                     t_detok = time.monotonic() if tracing else 0.0

@@ -20,12 +20,26 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   for ANY S/C. An earlier divisor-only picker collapsed to 32-wide
   K blocks at C=2080 (8 KB DMAs) and the kernel ran 60% of total profile
   time — tail masking costs one wasted partial block instead;
-- **wide K blocks, measured**: this kernel is DMA-granularity-bound, not
-  MXU-bound (switching the dots bf16 moved nothing —
-  artifacts/prefill_gap.json). For the group-major grid the measured-best
-  default is bq=512 / bk=2048 at hd=128, G≤3 (30.8 ms/layer at the worst
-  e2e chunk vs the per-head kernel's best 37.5; map shape 19.4 vs 20.7 —
-  not measured on the current machine). bk
+- **what binds it, measured on the v5e** (kernel alone, int8 cache, the
+  benchmark's shapes: four 2048-query chunks of an S=8192 dispatch over
+  C=8448, bq 512 / bk 1024; PERF.md, PR 27): a computed cell costs
+  11.0 us (Qwen3 KV=8/G=4, 8 rows: 50.7 ms a layer over 4,608 cells;
+  Phi-4 KV=10/G=4, 12 rows: 94.8 ms over 8,640) against 5.4 us for its
+  two matrix products at the bf16 peak and 0.3 us for its 262 KB of int8
+  K and V at the HBM peak. So neither the DMA nor the MXU sets the pace
+  (an earlier machine's reading, "DMA-granularity-bound", does not hold
+  here). Taking both selects and the mask build out of EVERY cell bought
+  9% (46.1 ms), folding ``scale`` into the ``ks`` row bought nothing:
+  it is not the count of vector operations per score either. What is
+  left is the [bq, bk] f32 score tile, 2 MB a head and far beyond the
+  vector registers, going through VMEM for the row max, the exp, the row
+  sum and the cast beside the MXU's own result traffic (inferred: no
+  bundle dump was read). The lever that pays is not running a cell:
+  with a group's four tail chunks in the dispatch, 50.2 -> 35.8 ms;
+- **block geometry**: for the group-major grid the default is bq=512 /
+  bk=2048 at hd=128, G≤3 (chosen on an earlier machine: 30.8 ms/layer at
+  the worst e2e chunk vs the per-head kernel's best 37.5 — not re-swept
+  on the current one). bk
   shrinks with head_dim (hd=256 Gemma3 → 1024) AND with G (the unrolled
   per-head score temporaries stay live: G=4 at bk=2048 exceeds the 16 MB
   scoped-VMEM budget, so G·bk is capped at 3·2048 — phi-4's 4:1 groups
@@ -36,7 +50,15 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   copies XLA otherwise materializes inside the layer scan;
 - causal + left-pad masking fused (same semantics as
   models.llama.prefill_attention_mask: pad_b <= j <= i);
-- blocks strictly above the causal diagonal skip their FLOPs entirely.
+- **each cell does its class's work** (_block_class, from the scalars the
+  cell already holds; splash attention's three-way split): *dead* cells —
+  above the causal diagonal, below the window floor, under the row's left
+  pad, or any cell of a query block that is all pad (a batch-bucketing
+  filler row is dead everywhere) — are neither fetched nor computed;
+  *interior* cells, whose mask would be all true, build no mask and run
+  no select; *edge* cells run the masked body. The output is the same
+  bit for bit as masking every cell. ``prefill_block_classes`` counts the
+  cells of a call on the host with the same rule.
 
 Inference-only (no VJP); training uses dense or ring attention.
 """
@@ -58,6 +80,36 @@ _LANES = 128
 # 16.66 MiB (compile errors, chip_smoke.py kernels phase). 32 MiB of a v5e
 # core's 128 MiB covers both with room; block geometry is unchanged.
 VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+
+
+def _block_class(q_start, k_start, pad, win, q_end, cache_len,
+                 block_q: int, block_k: int):
+    """What the (block_q x block_k) cell at query slot ``q_start`` / cache
+    slot ``k_start`` holds for a row with ``pad`` left-pad slots, as three
+    flags (seen, padded, interior):
+
+    - not ``seen``: wholly above the causal diagonal, or wholly below the
+      first query row's window floor — dead before the pad is looked at;
+    - ``padded``: every K slot is under the pad, or every query the block
+      really holds is (queries end at ``q_end`` = q_offset + S; a filler
+      row, pad == S, is padded everywhere) — dead;
+    - ``interior``: the per-element mask would be all true — wholly at or
+      under every query's diagonal, past the pad, every query real
+      (< ``q_end``), every slot in the cache, no window;
+    - the rest is edge.
+
+    Only comparisons and bit operators: the kernel calls it on SMEM
+    scalars, prefill_block_class_grid on numpy arrays — one rule, so the
+    host's count is the kernel's behaviour."""
+    q_last = q_start + (block_q - 1)
+    k_last = k_start + (block_k - 1)
+    seen = (k_start <= q_last) & ((win == 0) | (k_last >= q_start - win + 1))
+    padded = (k_last < pad) | (q_last < pad) | (q_end <= pad)
+    interior = (
+        (k_last <= q_start) & (k_start >= pad) & (q_last < q_end)
+        & (k_last < cache_len) & (win == 0)
+    )
+    return seen, padded, interior
 
 
 def _kernel(
@@ -96,6 +148,11 @@ def _kernel(
     q_start = off_ref[0] + i * block_q
     k_start = j * block_k
     win = win_ref[0]
+    pad = pad_ref[b]
+    seen, padded, interior = _block_class(
+        q_start, k_start, pad, win, off_ref[0] + seq_len, cache_len,
+        block_q, block_k,
+    )
 
     @pl.when(j == 0)
     def _init():
@@ -103,72 +160,62 @@ def _kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # blocks strictly above the causal diagonal contribute nothing; with a
-    # sliding window, neither do blocks wholly below the FIRST query row's
-    # window floor (q_start - win + 1 — the least restrictive floor in the
-    # block; later rows re-mask per element). Both sets were never DMA'd —
-    # the index_map clamps them onto an in-range block, see kv_index.
-    @pl.when(
-        (k_start <= q_start + block_q - 1)
-        & ((win == 0) | (k_start + block_k - 1 >= q_start - win + 1))
-    )
-    def _compute():
+    def _accumulate(masked: bool):
         # casts hoisted out of the G-unroll: one [BK, hd] conversion per
         # grid cell, not G (int8 cache values are exact in the query
         # dtype — see the dot comment below)
         kb = k_ref[0, 0, 0].astype(q_ref.dtype)
         vb = v_ref[0, 0, 0].astype(q_ref.dtype)
-        # A partial tail block (cache_len % block_k != 0) is only DMA'd up
-        # to the end of the cache; the rest of its VMEM buffer holds
-        # whatever was there, NaN bit patterns included. Scores of those
-        # slots are masked below, but their probability 0 still meets the
-        # value side in a multiply (0 * NaN = NaN — seen on the chip as NaN
-        # rows, int8 cache at C=3200/bk=2048), so the value side is zeroed
-        # past the end: the f32 scale row when quantized (int8 garbage is
-        # finite), else the value rows themselves.
-        if quantized:
-            in_cache = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block_k), 1
-            ) < cache_len
-            v_scale = jnp.where(in_cache, vs_ref[0, 0, kv][None, :], 0.0)
-        else:
-            in_cache = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, 1), 0
-            ) < cache_len
-            vb = jnp.where(in_cache, vb, jnp.zeros_like(vb))
+        v_scale = vs_ref[0, 0, kv][None, :] if quantized else None
+        mask = None
+        if masked:
+            # A partial tail block (cache_len % block_k != 0) is only DMA'd
+            # up to the end of the cache; the rest of its VMEM buffer holds
+            # whatever was there, NaN bit patterns included. Scores of those
+            # slots are masked below, but their probability 0 still meets
+            # the value side in a multiply (0 * NaN = NaN — seen on the chip
+            # as NaN rows, int8 cache at C=3200/bk=2048), so the value side
+            # is zeroed past the end: the f32 scale row when quantized (int8
+            # garbage is finite), else the value rows themselves.
+            if quantized:
+                in_cache = k_start + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, block_k), 1
+                ) < cache_len
+                v_scale = jnp.where(in_cache, v_scale, 0.0)
+            else:
+                in_cache = k_start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, 1), 0
+                ) < cache_len
+                vb = jnp.where(in_cache, vb, jnp.zeros_like(vb))
 
-        # mask depends on positions only, not the head — ONE copy serves
-        # the whole GQA group (a third of the old per-head VPU bookkeeping)
-        q_pos = q_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        )
-        k_pos = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        pad = pad_ref[b]
-        # k_pos <= q_pos also kills the masked tail of a partial K block
-        # (those slots have k_pos > any valid q_pos); q_pos of a partial
-        # Q-block tail produces garbage rows the caller never reads.
-        # Window semantics in SLOT space match the dense path
-        # (models.llama._block: k_slot > q_slot - window) — left pad shifts
-        # q and k slots identically, so the token-space window is preserved
-        mask = (
-            (k_pos <= q_pos) & (k_pos >= pad)
-            & (q_pos < off_ref[0] + seq_len)
-        )
-        mask = mask & ((win == 0) | (k_pos > q_pos - win))
+            # mask depends on positions only, not the head — ONE copy serves
+            # the whole GQA group
+            q_pos = q_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0
+            )
+            k_pos = k_start + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1
+            )
+            # k_pos <= q_pos also kills the masked tail of a partial K block
+            # (those slots have k_pos > any valid q_pos); q_pos of a partial
+            # Q-block tail produces garbage rows the caller never reads.
+            # Window semantics in SLOT space match the dense path
+            # (models.llama._block: k_slot > q_slot - window) — left pad
+            # shifts q and k slots identically, so the token-space window is
+            # preserved
+            mask = (
+                (k_pos <= q_pos) & (k_pos >= pad)
+                & (q_pos < off_ref[0] + seq_len)
+            )
+            mask = mask & ((win == 0) | (k_pos > q_pos - win))
 
         for g in range(q_per_kv):  # static unroll over the GQA group
             lo, hi = g * block_q, (g + 1) * block_q
             # MXU inputs stay in the QUERY dtype with f32 accumulation
             # (preferred_element_type): f32 parity tests keep exact f32
-            # dots, the engine's bf16 takes the native-rate MXU path.
-            # Measured NEUTRAL on wall (the kernel is DMA-bound — the
-            # block geometry and the once-per-group K/V stream are the
-            # wins); kept because f32 dots waste MXU headroom for nothing
-            # the f32 oracle tests need. int8 cache values (-128..127)
-            # are exactly representable in bf16, so the dequant algebra
-            # is unchanged.
+            # dots, the engine's bf16 takes the native-rate MXU path. int8
+            # cache values (-128..127) are exactly representable in bf16,
+            # so the dequant algebra is unchanged.
             qg = q_ref[0, 0, g]
             s = jax.lax.dot_general(
                 qg, kb, (((1,), (1,)), ((), ())),
@@ -176,14 +223,16 @@ def _kernel(
             ) * scale  # [BQ, BK] f32
             if quantized:
                 s = s * ks_ref[0, 0, kv][None, :]
-            s = jnp.where(mask, s, _NEG)
+            if masked:
+                s = jnp.where(mask, s, _NEG)
 
             m_prev = m_ref[lo:hi, :1]                   # [BQ, 1]
             m_cur = jnp.max(s, axis=1, keepdims=True)   # [BQ, 1]
             m_new = jnp.maximum(m_prev, m_cur)
             corr = jnp.exp(m_prev - m_new)
             p = jnp.exp(s - m_new)
-            p = jnp.where(mask, p, 0.0)                 # dead rows stay dead
+            if masked:
+                p = jnp.where(mask, p, 0.0)             # dead rows stay dead
 
             l_new = l_ref[lo:hi, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
             if quantized:
@@ -198,12 +247,71 @@ def _kernel(
             m_ref[lo:hi] = jnp.broadcast_to(m_new, (block_q, m_ref.shape[1]))
             l_ref[lo:hi] = jnp.broadcast_to(l_new, (block_q, l_ref.shape[1]))
 
+    # Each cell does its class's work and no more. Dead cells (nothing to
+    # see: above the diagonal, below the window floor, under the row's left
+    # pad) run neither body and were never DMA'd — the index_map clamps
+    # them onto a live block, see visible_j. An interior cell's mask would
+    # be all true, so it builds none; an edge cell runs the masked body.
+    @pl.when(interior)
+    def _interior():
+        _accumulate(masked=False)
+
+    @pl.when(seen & jnp.logical_not(padded) & jnp.logical_not(interior))
+    def _edge():
+        _accumulate(masked=True)
+
     @pl.when(j == nj - 1)
     def _finalize():
         for g in range(q_per_kv):
             lo, hi = g * block_q, (g + 1) * block_q
             l = jnp.maximum(l_ref[lo:hi, :1], 1e-30)
             o_ref[0, 0, g] = (acc_ref[lo:hi] / l).astype(o_ref.dtype)
+
+
+def _block_geometry(S: int, C: int, G: int, hd: int,
+                    block_q: int | None = None, block_k: int | None = None,
+                    interpret: bool = False) -> tuple[int, int]:
+    """(bq, bk) of the kernel's grid for S queries over a cache of C slots
+    with GQA group G and head size hd — the wrapper's rule, also the
+    counter's (prefill_block_classes)."""
+    # measured-best geometry for the GROUP-major grid (worst e2e chunk,
+    # B=16/S=2048@off=6144/C=8320 int8: 512/2048 = 30.8 ms/layer vs the
+    # per-head kernel's best 37.5; map shape 19.4 vs 20.7; not re-measured
+    # on the current machine). Two VMEM scaling rules hold the footprint at
+    # the measured G=3, hd=128 level: the K width shrinks with head_dim
+    # (hd=256 Gemma3 → bk 1024), AND with the group size — the per-head loop is a static
+    # unroll whose [bq, bk] f32 score temporaries stay live per head, so
+    # G=4 at bk=2048 exceeds scoped vmem by ~2 MB (measured compile OOM;
+    # G*bk is held ≤ 3*2048). bq stays 512: the q tile already carries
+    # G*512 rows, and bq=1024 geometries fail to compile at G=3.
+    default_bk = max(512, 2048 * _LANES // max(hd, 1))
+    while G * default_bk > 3 * 2048 and default_bk > 512:
+        default_bk //= 2
+    bq = min(block_q or 512, S)
+    # scratch is G-sliced at multiples of bq — keep the slice offsets
+    # sublane-aligned when S is small and not 8-divisible
+    bq = -(-bq // 8) * 8
+    bk = min(block_k or default_bk, C)
+    # the bk guard above bottoms out at 512; very wide GQA groups (G > 12)
+    # can still blow the scoped-VMEM score budget there, so continue the
+    # scaling on bq (the q tile and the per-head [bq, bk] f32 temporaries
+    # both shrink with it). G*bq*bk <= 3*2048*512 is the measured-working
+    # ceiling at the default geometry (G=3, bq=512, bk=2048).
+    _VMEM_CELLS = 3 * 2048 * 512
+    if block_q is None:
+        while G * bq * bk > _VMEM_CELLS and bq > 8:
+            bq = max(-(-(bq // 2) // 8) * 8, 8)
+    if G * bq * bk > _VMEM_CELLS and not interpret:
+        # an explicit block_q/block_k overrode the autoscaler into a
+        # geometry that will OOM in Mosaic — fail with the numbers instead
+        # of a compile-time scoped-vmem error naming none of them
+        raise ValueError(
+            f"flash prefill geometry exceeds the scoped-VMEM "
+            f"budget: G={G}, head_dim={hd}, bq={bq}, "
+            f"bk={bk} (G*bq*bk={G * bq * bk} > {_VMEM_CELLS}) — pass a "
+            f"smaller block_q/block_k or drop to the dense path"
+        )
+    return bq, bk
 
 
 def supports_flash(seq_len: int, cache_len: int, head_dim: int) -> bool:
@@ -239,11 +347,13 @@ def flash_prefill_attention(
     prefill_chunk_tokens path, which halves/quarters prefill transients so
     bigger decode batches fit); 0/None is the classic whole-prompt prefill.
 
-    K/V blocks a query block can never see — strictly above the causal
-    diagonal, or wholly below the window floor — are both compute-skipped
-    AND DMA-elided: the index_map clamps their block index onto the nearest
-    visible block, and Pallas skips the copy when consecutive grid steps
-    address the same block."""
+    K/V blocks a query block has nothing to see in — strictly above the
+    causal diagonal, wholly below the window floor, wholly under the row's
+    left pad, or any block at all when the query block itself is all pad —
+    are both compute-skipped AND DMA-elided: the index_map clamps their
+    block index onto the nearest live block, and Pallas skips the copy when
+    consecutive grid steps address the same block. Pad query rows still
+    come back as zeros (see _block_class for the cell classes)."""
     k_all, v_all = cache["k"], cache["v"]
     quantized = "ks" in cache
     B, S, H, hd = q.shape
@@ -255,65 +365,34 @@ def flash_prefill_attention(
         # the group-major grid derives G from the shapes; a mismatched
         # caller value would silently change the head->KV mapping
         raise ValueError(f"q_per_kv={q_per_kv} inconsistent with H/KV={G}")
-    # measured-best geometry for the GROUP-major grid (worst e2e chunk,
-    # B=16/S=2048@off=6144/C=8320 int8: 512/2048 = 30.8 ms/layer vs the
-    # per-head kernel's best 37.5; map shape 19.4 vs 20.7; not re-measured
-    # on the current machine). Two VMEM scaling rules hold the footprint at
-    # the measured G=3, hd=128 level: the K width shrinks with head_dim
-    # (hd=256 Gemma3 → bk 1024), AND with the group size — the per-head loop is a static
-    # unroll whose [bq, bk] f32 score temporaries stay live per head, so
-    # G=4 at bk=2048 exceeds scoped vmem by ~2 MB (measured compile OOM;
-    # G*bk is held ≤ 3*2048). bq stays 512: the q tile already carries
-    # G*512 rows, and bq=1024 geometries fail to compile at G=3.
-    default_bk = max(512, 2048 * _LANES // max(hd, 1))
-    while G * default_bk > 3 * 2048 and default_bk > 512:
-        default_bk //= 2
-    bq = min(block_q or 512, S)
-    # scratch is G-sliced at multiples of bq — keep the slice offsets
-    # sublane-aligned when S is small and not 8-divisible
-    bq = -(-bq // 8) * 8
-    bk = min(block_k or default_bk, C)
-    # the bk guard above bottoms out at 512; very wide GQA groups (G > 12)
-    # can still blow the scoped-VMEM score budget there, so continue the
-    # scaling on bq (the q tile and the per-head [bq, bk] f32 temporaries
-    # both shrink with it). G*bq*bk <= 3*2048*512 is the measured-working
-    # ceiling at the default geometry (G=3, bq=512, bk=2048).
-    _VMEM_CELLS = 3 * 2048 * 512
-    if block_q is None:
-        while G * bq * bk > _VMEM_CELLS and bq > 8:
-            bq = max(-(-(bq // 2) // 8) * 8, 8)
-    if G * bq * bk > _VMEM_CELLS and not interpret:
-        # an explicit block_q/block_k overrode the autoscaler into a
-        # geometry that will OOM in Mosaic — fail with the numbers instead
-        # of a compile-time scoped-vmem error naming none of them
-        raise ValueError(
-            f"flash prefill geometry exceeds the scoped-VMEM "
-            f"budget: G={G} (H={H}/KV={KV}), head_dim={hd}, bq={bq}, "
-            f"bk={bk} (G*bq*bk={G * bq * bk} > {_VMEM_CELLS}) — pass a "
-            f"smaller block_q/block_k or drop to the dense path"
-        )
+    bq, bk = _block_geometry(S, C, G, hd, block_q, block_k, interpret)
 
     # group-major query layout: [B, KV, G, S, hd] — the grid walks KV
     # heads, so one grid cell computes the whole GQA group against each
     # K/V block (DMA'd once, not G times)
     qt = q.transpose(0, 2, 1, 3).reshape(B, KV, G, S, hd)
 
-    def visible_j(i, j, win, off):
+    def visible_j(b, i, j, pad, win, off):
+        q_start = off[0] + i * bq
         # causal: last block any row sees (rows start at off + i*bq)
-        j_hi = (off[0] + i * bq + bq - 1) // bk
-        # window: first block any row sees — the FIRST query row's floor
-        lo = jnp.where(
-            win[0] > 0,
-            jnp.maximum(off[0] + i * bq - win[0] + 1, 0) // bk,
-            0,
+        j_hi = (q_start + bq - 1) // bk
+        # first block with anything to see: the one the row's pad ends in,
+        # or with a window the FIRST query row's floor, whichever is later
+        lo = jnp.maximum(
+            pad[b] // bk,
+            jnp.where(
+                win[0] > 0, jnp.maximum(q_start - win[0] + 1, 0) // bk, 0
+            ),
         )
-        return jnp.clip(j, lo, j_hi)
+        # the upper clamp goes last: a Q block wholly in the pad has
+        # lo > j_hi and parks every step on j_hi (always in range)
+        return jnp.minimum(jnp.maximum(j, lo), j_hi)
 
     def kv_index(b, kv, i, j, lidx, pad, win, off):
-        return (lidx[0], b, kv, visible_j(i, j, win, off), 0)
+        return (lidx[0], b, kv, visible_j(b, i, j, pad, win, off), 0)
 
     def scale_index(b, kv, i, j, lidx, pad, win, off):
-        return (lidx[0], b, 0, visible_j(i, j, win, off))
+        return (lidx[0], b, 0, visible_j(b, i, j, pad, win, off))
 
     in_specs = [
         pl.BlockSpec(
@@ -368,3 +447,48 @@ def flash_prefill_attention(
         *operands,
     )
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)
+
+
+BLOCK_CLASSES = ("dead_causal", "dead_pad", "interior", "edge")
+
+
+def prefill_block_class_grid(pad_lens, S: int, C: int, q_offset: int = 0,
+                             window: int = 0, G: int = 1, hd: int = _LANES,
+                             *, block_q: int | None = None,
+                             block_k: int | None = None):
+    """Per-cell class of one flash_prefill_attention call, as an int8 numpy
+    array [B, ceil(S/bq), ceil(C/bk)] of indices into BLOCK_CLASSES, with
+    the geometry (bq, bk). Pure host code: same geometry rule as the
+    wrapper, same class rule as the kernel (_block_class)."""
+    import numpy as np
+
+    bq, bk = _block_geometry(S, C, G, hd, block_q, block_k, interpret=True)
+    pad = np.asarray(pad_lens, np.int64).reshape(-1, 1, 1)
+    q_start = q_offset + bq * np.arange(-(-S // bq), dtype=np.int64)
+    k_start = bk * np.arange(-(-C // bk), dtype=np.int64)
+    seen, padded, interior = _block_class(
+        q_start[None, :, None], k_start[None, None, :], pad,
+        np.int64(window), q_offset + S, C, bq, bk,
+    )
+    # dead_causal is what the kernel skipped before it looked at the pad
+    grid = np.select([~seen, padded, interior], [0, 1, 2], default=3)
+    return grid.astype(np.int8), (bq, bk)
+
+
+def prefill_block_classes(pad_lens, S: int, C: int, q_offset: int = 0,
+                          window: int = 0, G: int = 1, hd: int = _LANES,
+                          *, block_q: int | None = None,
+                          block_k: int | None = None) -> dict[str, int]:
+    """How many grid cells of one flash_prefill_attention call (per layer
+    and KV head) fall in each class: ``dead_causal`` (above the diagonal or
+    below the window floor), ``dead_pad`` (under the row's left pad),
+    ``interior`` (no mask needed) and ``edge`` (the masked body). Only
+    interior and edge cells are fetched and computed."""
+    import numpy as np
+
+    grid, _ = prefill_block_class_grid(
+        pad_lens, S, C, q_offset, window, G, hd,
+        block_q=block_q, block_k=block_k,
+    )
+    counts = np.bincount(grid.ravel(), minlength=len(BLOCK_CLASSES))
+    return {name: int(n) for name, n in zip(BLOCK_CLASSES, counts)}
