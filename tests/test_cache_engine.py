@@ -117,6 +117,45 @@ def test_mixed_lengths_group_by_suffix(cfg, params):
     assert b.generate(mixed) == want
 
 
+@pytest.mark.parametrize("continuous", [False, True])
+def test_group_insert_is_one_write_dispatch(cfg, params, continuous):
+    """One _cache_insert of a multi-row group sends every row's new blocks
+    to the pool in ONE dispatch, and /metrics carries both counters."""
+    from vnsum_tpu.serve.metrics import ServeMetrics
+
+    kw = dict(continuous=True, segment_tokens=8) if continuous else {}
+    b = make_backend(cfg, params, **kw)
+    calls = []
+    cache_insert = b._cache_insert
+
+    def counted(*a, **k):
+        calls.append(cache_insert(*a, **k))
+        return calls[-1]
+
+    b._cache_insert = counted
+    mixed = PROMPTS[:3] + ["Mot van ban khac han, khong chung tieu de. " * 6]
+    b.generate(mixed)                       # one group of four rows
+    st = b.prefix_cache_stats()
+    assert len(calls) == 1 and calls[0] > 4  # several new blocks a row
+    assert st["write_dispatches"] == 1
+    assert st["inserted_blocks"] == calls[0] == st["blocks_used"]
+    b.generate(mixed)                       # all cached: nothing to write
+    st = b.prefix_cache_stats()
+    assert len(calls) == 2 and calls[1] == 0
+    assert st["write_dispatches"] == 1
+    text = ServeMetrics().render_prometheus(cache_stats=st)
+    assert "vnsum_serve_cache_write_dispatches_total 1" in text
+    assert f"vnsum_serve_cache_inserted_blocks_total {calls[0]}" in text
+    # an index without a device pool has blocks and no write program
+    fake = FakeBackend(prefix_cache_blocks=8, cache_block_tokens=2)
+    fake.generate(["mot hai ba bon nam sau bay"])
+    text = ServeMetrics().render_prometheus(
+        cache_stats=fake.prefix_cache_stats()
+    )
+    assert "vnsum_serve_cache_inserted_blocks_total 3" in text
+    assert "cache_write_dispatches_total" not in text
+
+
 def test_cache_pool_requires_tp_divisible_kv_heads(cfg, params):
     """The block pool shards KV heads over `model`; an indivisible config
     must fail loudly at construction (mirroring shard_params' check), not

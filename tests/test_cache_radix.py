@@ -171,8 +171,8 @@ def test_store_write_gather_roundtrip(jnp):
     )
     src = _fake_cache(jnp)
     # extract two consecutive blocks of row 1 starting at slot 8
-    store.write_block(src, row=1, slot=8, block_id=3)
-    store.write_block(src, row=1, slot=12, block_id=5)
+    store.write_blocks(src, [(1, 8, 3)])
+    store.write_blocks(src, [(1, 12, 5)])
     # gather them into row 0 and row 2 of a zero cache at different offsets
     dst = {k: jnp.zeros_like(v) for k, v in _fake_cache(jnp, seed=1).items()}
     ids = np.array([[3, 5], [store.scratch_id] * 2, [3, 5]], dtype=np.int32)
@@ -202,7 +202,7 @@ def test_store_quantized_leaves_roundtrip(jnp):
         "ks": jnp.asarray(rng.normal(size=(1, 2, 1, 16)).astype(np.float32)),
         "vs": jnp.asarray(rng.normal(size=(1, 2, 1, 16)).astype(np.float32)),
     }
-    store.write_block(src, row=0, slot=4, block_id=2)
+    store.write_blocks(src, [(0, 4, 2)])
     dst = {k: jnp.zeros_like(v) for k, v in src.items()}
     out = store.gather(dst, np.array([[2], [store.scratch_id]], np.int32),
                        np.array([8, 0], np.int32))
@@ -219,7 +219,7 @@ def test_prefix_cache_facade(jnp):
     )
     cache = _fake_cache(jnp)
     ids = seq(10)
-    n = pc.insert(cache, row=0, slot_base=2, ids=ids, upto=9)  # 2 whole blocks
+    n = pc.insert(cache, [(0, 2, ids, 9)])  # row 0 at slot 2: 2 whole blocks
     assert n == 2
     assert pc.probe(ids) == 8
     m = pc.match(ids, max_tokens=len(ids) - 1)
@@ -242,3 +242,183 @@ def test_prefix_cache_facade(jnp):
     st = pc.stats_dict()
     assert st["blocks_used"] == 2 and st["blocks_total"] == 8
     assert st["hbm_bytes"] > 0
+
+
+# -- batched insertion: one dispatch per list --------------------------------
+
+_GEOM = dict(L=2, B=3, KV=2, C=32, hd=4)
+_BLK = 4
+
+
+def _pool_kind_cache(jnp, kind, seed=0):
+    """A [L, B, KV, C(, hd)] batch cache of one pool kind: bf16 leaves, or
+    int8 leaves with their float32 scales."""
+    g = _GEOM
+    rng = np.random.default_rng(seed)
+    shape = (g["L"], g["B"], g["KV"], g["C"], g["hd"])
+    if kind == "bf16":
+        return {
+            n: jnp.asarray(rng.normal(size=shape), dtype=jnp.bfloat16)
+            for n in ("k", "v")
+        }
+    cache = {
+        n: jnp.asarray(rng.integers(-127, 128, size=shape, dtype=np.int8))
+        for n in ("k", "v")
+    }
+    for n in ("ks", "vs"):
+        cache[n] = jnp.asarray(rng.normal(size=shape[:-1]).astype(np.float32))
+    return cache
+
+
+def _pool_kind_store(jnp, kind, num_blocks=8):
+    return BlockStore(
+        num_blocks=num_blocks, block_tokens=_BLK, n_layers=_GEOM["L"],
+        n_kv_heads=_GEOM["KV"], head_dim=_GEOM["hd"], dtype=jnp.bfloat16,
+        quantized=kind == "int8",
+    )
+
+
+def _pool_arrays(store):
+    # bf16 has no numpy dtype of its own: compare the bits
+    import jax.numpy as jnp
+
+    return {
+        n: np.asarray(v.view(jnp.uint16) if v.dtype == jnp.bfloat16 else v)
+        for n, v in store.pool.items()
+    }
+
+
+def _assert_pools_equal(a, b):
+    pa, pb = _pool_arrays(a), _pool_arrays(b)
+    assert pa.keys() == pb.keys()
+    for name in pa:
+        np.testing.assert_array_equal(pa[name], pb[name], err_msg=name)
+
+
+# (row, slot, block_id) lists; the fixed vector of this cache shape holds
+# B * (C // BLK) = 24 entries
+_TRIPLE_LISTS = {
+    "one_entry": [(1, 8, 3)],
+    "ragged_short": [(0, 2, 5), (0, 6, 1), (2, 17, 7), (1, 0, 0)],
+    "full_length": [
+        (r, s, (r * 8 + s // _BLK) % 8) for r in range(3)
+        for s in range(0, 32, _BLK)
+    ],
+    # block 4 written twice from different slabs: the later write must win
+    "block_id_twice": [(0, 4, 4), (1, 12, 2), (2, 20, 4), (1, 28, 6)],
+    "last_slot_of_the_row": [(2, 28, 0), (0, 0, 7)],
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TRIPLE_LISTS))
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_write_blocks_equals_one_block_at_a_time(jnp, kind, case):
+    """The pool after ONE batched dispatch equals, array for array, the pool
+    after the same list one entry a dispatch — and both equal what plain
+    numpy slicing says the pool should hold."""
+    triples = _TRIPLE_LISTS[case]
+    cache = _pool_kind_cache(jnp, kind)
+    batched = _pool_kind_store(jnp, kind)
+    serial = _pool_kind_store(jnp, kind)
+    batched.write_blocks(cache, triples)
+    for row, slot, bid in triples:
+        serial.write_blocks(cache, [(row, slot, bid)])
+    assert batched.write_dispatches == 1
+    assert serial.write_dispatches == len(triples)
+    _assert_pools_equal(batched, serial)
+    got = _pool_arrays(batched)
+    for name, buf in cache.items():
+        want = np.zeros_like(got[name])
+        src = np.asarray(buf.view(jnp.uint16) if kind == "bf16" else buf)
+        for row, slot, bid in triples:
+            want[bid] = src[:, row, :, slot:slot + _BLK]
+        np.testing.assert_array_equal(got[name], want, err_msg=name)
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_write_blocks_empty_list_dispatches_nothing(jnp, kind):
+    store = _pool_kind_store(jnp, kind)
+    before = dict(store.pool)
+    store.write_blocks(_pool_kind_cache(jnp, kind), [])
+    assert store.write_dispatches == 0
+    assert not store._write_fns            # nothing was even traced
+    assert all(store.pool[n] is before[n] for n in before)
+
+
+def test_write_blocks_one_program_per_cache_shape(jnp):
+    """Lists of any length share the executable of their cache shape: the
+    vector's length is fixed by the shape and the trip count is an input."""
+    store = _pool_kind_store(jnp, "int8")
+    cache = _pool_kind_cache(jnp, "int8")
+    for case in sorted(_TRIPLE_LISTS):
+        store.write_blocks(cache, _TRIPLE_LISTS[case])
+    (fn,) = store._write_fns.values()
+    assert fn._cache_size() == 1
+    with pytest.raises(ValueError, match="at most 24"):
+        store.write_blocks(cache, [(0, 0, 0)] * 25)
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8"])
+def test_insert_second_row_evicts_first_rows_leaf(jnp, kind):
+    """A join of two long rows over a small pool: the second row's index
+    insert evicts leaves the first row allocated a moment before (its pins
+    are dropped when its own insert ends), so block ids occur twice in the
+    join's one list. The pool must equal the per-row, per-block path's."""
+    rows = [(0, 3, seq(24), 20), (2, 8, seq(24, base=100), 24)]
+
+    def build():
+        return PrefixCache(
+            num_blocks=8, block_tokens=_BLK, n_layers=_GEOM["L"],
+            n_kv_heads=_GEOM["KV"], head_dim=_GEOM["hd"], dtype=jnp.bfloat16,
+            quantized=kind == "int8",
+        )
+
+    cache = _pool_kind_cache(jnp, kind, seed=3)
+    batched, serial = build(), build()
+    seen = []
+    write_blocks = batched.store.write_blocks
+    batched.store.write_blocks = lambda c, t: (seen.extend(t), write_blocks(c, t))
+    assert batched.insert(cache, rows) == 11
+    for row, slot_base, ids, upto in rows:
+        for block, off in serial.index.insert(ids, upto):
+            serial.store.write_blocks(cache, [(row, slot_base + off, block)])
+    block_ids = [bid for _, _, bid in seen]
+    assert len(block_ids) == 11 and len(set(block_ids)) == 8  # 3 written twice
+    assert batched.store.write_dispatches == 1
+    assert serial.store.write_dispatches == 11
+    assert batched.stats_dict()["evictions"] == 3
+    _assert_pools_equal(batched.store, serial.store)
+    # and the index hands the same blocks out for the surviving chains
+    for _, _, ids, _ in rows:
+        a = batched.match(ids)
+        b = serial.match(ids)
+        assert a.blocks == b.blocks and a.tokens == b.tokens
+    # the second row is whole in the pool: gathering it returns its slabs
+    m = batched.match(rows[1][2])
+    assert m.tokens == 24
+    dst = {k: jnp.zeros_like(v) for k, v in cache.items()}
+    scratch = [batched.store.scratch_id] * len(m.blocks)
+    out = batched.gather(
+        dst, np.array([m.blocks, scratch, scratch], np.int32),
+        np.array([4, 0, 0], np.int32),
+    )
+    for name in cache:
+        np.testing.assert_array_equal(
+            np.asarray(out[name].astype(jnp.float32))[:, 0, :, 4:28],
+            np.asarray(cache[name].astype(jnp.float32))[:, 2, :, 8:32],
+        )
+
+
+def test_prefix_cache_stats_count_blocks_and_dispatches(jnp):
+    pc = PrefixCache(
+        num_blocks=8, block_tokens=4, n_layers=2, n_kv_heads=2,
+        head_dim=4, dtype=jnp.float32,
+    )
+    cache = _fake_cache(jnp)
+    assert pc.stats_dict()["write_dispatches"] == 0
+    assert pc.insert(cache, [(0, 2, seq(10), 9), (1, 0, seq(13, 50), 12)]) == 5
+    st = pc.stats_dict()
+    assert st["inserted_blocks"] == 5 and st["write_dispatches"] == 1
+    # nothing new to write: no dispatch
+    assert pc.insert(cache, [(0, 2, seq(10), 9)]) == 0
+    assert pc.stats_dict()["write_dispatches"] == 1

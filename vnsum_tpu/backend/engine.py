@@ -1800,7 +1800,7 @@ class TpuBackend:
         t0 = time.time()
         t0_m = time.monotonic() if tracing else 0.0
         evict0 = pc.index.stats.evictions
-        new_blocks = 0
+        rows = []
         for row, i in enumerate(group):
             ids = encoded[i]
             target = len(ids) - 1
@@ -1809,9 +1809,9 @@ class TpuBackend:
                 target = min(self._hint_prefix_len(hint, ids), target)
             upto = target // BLK * BLK
             if upto > matches[i].tokens:
-                new_blocks += pc.insert(
-                    cache, row, int(pad_lens[row]), ids, upto
-                )
+                rows.append((row, int(pad_lens[row]), ids, upto))
+        # every row's new blocks go to the pool in one dispatch
+        new_blocks = pc.insert(cache, rows)
         if tracing and (new_blocks or pc.index.stats.evictions != evict0):
             emit("cache_insert", t0_m, time.time() - t0, blocks=new_blocks,
                  evictions=pc.index.stats.evictions - evict0)

@@ -16,9 +16,15 @@ cross-request, cross-bucket reuse sound.
 
 Two device ops, both jitted per cache-shape bucket:
 
-- :meth:`BlockStore.write_block` — copy one block slab out of a batch row
-  into the pool (insertion after prefill); one dispatch per block keeps the
-  copies clamp-free for any slot alignment.
+- :meth:`BlockStore.write_blocks` — copy a list of block slabs out of a
+  batch cache into the pool (insertion after prefill): ONE dispatch per
+  call, whatever the list's length. The (row, slot, block) triples cross as
+  one fixed-length host vector with the true count beside it, and the
+  program copies slab by slab in a loop of that many trips, in list order,
+  with the donated pool as the carry — so there is one executable per cache
+  shape, no slab-sized temporary beside the pool, a block id that occurs
+  twice keeps its later write, and no copy can clamp onto neighbouring
+  slots.
 - :meth:`BlockStore.gather` — vmapped per-row ``dynamic_update_slice`` of up
   to NB blocks into a fresh batch cache at per-row slot offsets (the same
   per-row ragged-write shape as llama._cache_write's vector path). Rows
@@ -113,6 +119,9 @@ class BlockStore:
             }
         self._write_fns: dict = {}
         self._gather_fns: dict = {}
+        # dispatches of the write program; with the radix's inserted_blocks
+        # it says how many blocks one dispatch carries (/metrics)
+        self.write_dispatches = 0
 
     @property
     def hbm_bytes(self) -> int:
@@ -144,39 +153,55 @@ class BlockStore:
 
     # -- insertion -------------------------------------------------------
 
-    def write_block(self, cache: dict, row: int, slot: int, block_id: int) -> None:
-        """Copy the [slot, slot+BLK) slab of batch ``row`` into pool block
-        ``block_id``. One small device-to-device copy; per-block dispatch
-        means no padded slice can ever clamp onto neighbouring slots."""
+    def write_blocks(self, cache: dict, triples) -> None:
+        """Copy, for each (row, slot, block_id) of ``triples`` in order, the
+        [slot, slot+BLK) slab of batch ``row`` of ``cache`` ([L, B, KV,
+        C(, hd)] leaves) into pool block ``block_id``: one dispatch for the
+        whole list, none for an empty one. The list is padded to a length
+        fixed by the cache's shape (no row holds more than C // BLK whole
+        blocks) and the loop's trip count is an input, so every list of one
+        cache shape runs the same executable."""
         import jax
-        import jax.numpy as jnp
 
+        n = len(triples)
+        if n == 0:
+            return
         BLK = self.block_tokens
         key = self._shape_sig(cache)
+        _, B, _, C = next(iter(cache.values())).shape[:4]
+        idx = np.zeros((B * (C // BLK), 3), dtype=np.int32)
+        if n > len(idx):
+            raise ValueError(
+                f"{n} blocks to write from a cache of {B} rows x {C} slots "
+                f"(at most {len(idx)} whole {BLK}-token blocks)"
+            )
+        idx[:n] = triples
         fn = self._write_fns.get(key)
         if fn is None:
 
-            def write(pool, cache, row, slot, bid):
-                out = {}
-                for name, buf in cache.items():
-                    # [L, B, KV, C(, hd)] -> slab [L, KV, BLK(, hd)]
-                    L, _, KV = buf.shape[:3]
-                    tail = buf.shape[4:]
-                    sizes = (L, 1, KV, BLK) + tail
-                    starts = (0, row, 0, slot) + (0,) * len(tail)
-                    slab = jax.lax.dynamic_slice(buf, starts, sizes)[:, 0]
-                    out[name] = jax.lax.dynamic_update_slice(
-                        pool[name], slab[None],
-                        (bid,) + (0,) * (pool[name].ndim - 1),
-                    )
-                return out
+            def write(pool, cache, idx, n):
+                def write_one(i, pool):
+                    row, slot, bid = idx[i, 0], idx[i, 1], idx[i, 2]
+                    out = {}
+                    for name, buf in cache.items():
+                        # [L, B, KV, C(, hd)] -> slab [L, KV, BLK(, hd)]
+                        L, _, KV = buf.shape[:3]
+                        tail = buf.shape[4:]
+                        sizes = (L, 1, KV, BLK) + tail
+                        starts = (0, row, 0, slot) + (0,) * len(tail)
+                        slab = jax.lax.dynamic_slice(buf, starts, sizes)[:, 0]
+                        out[name] = jax.lax.dynamic_update_slice(
+                            pool[name], slab[None],
+                            (bid,) + (0,) * (pool[name].ndim - 1),
+                        )
+                    return out
+
+                return jax.lax.fori_loop(0, n, write_one, pool)
 
             fn = jax.jit(write, donate_argnums=(0,))
             self._write_fns[key] = fn
-        self.pool = fn(
-            self.pool, cache,
-            jnp.int32(row), jnp.int32(slot), jnp.int32(block_id),
-        )
+        self.pool = fn(self.pool, cache, idx, np.int32(n))
+        self.write_dispatches += 1
 
     # -- gather ----------------------------------------------------------
 
@@ -266,17 +291,25 @@ class PrefixCache:
     def gather(self, cache: dict, block_ids, starts) -> dict:
         return self.store.gather(cache, block_ids, starts)
 
-    def insert(self, cache: dict, row: int, slot_base: int, ids, upto: int) -> int:
-        """Index tokens[:upto] of a freshly prefilled row and copy the newly
-        allocated blocks' KV out of ``cache`` (whose row sits left-padded at
-        ``slot_base``). Returns the number of new blocks written."""
-        new = self.index.insert(ids, upto)
-        for block, off in new:
-            self.store.write_block(cache, row, slot_base + off, block)
-        return len(new)
+    def insert(self, cache: dict, rows) -> int:
+        """Index tokens[:upto] of each freshly prefilled row of ``rows`` —
+        (row, slot_base, ids, upto), the row sitting left-padded at
+        ``slot_base`` in ``cache`` — and copy all their newly allocated
+        blocks' KV out of ``cache`` in ONE dispatch, in the rows' order: a
+        later row's insert may evict a leaf an earlier row just allocated
+        (pins last only for a row's own insert), the block id then occurs
+        twice and the later write wins. Returns the number of new blocks."""
+        triples = [
+            (row, slot_base + off, block)
+            for row, slot_base, ids, upto in rows
+            for block, off in self.index.insert(ids, upto)
+        ]
+        self.store.write_blocks(cache, triples)
+        return len(triples)
 
     def stats_dict(self) -> dict:
         d = self.index.stats_dict()
         d["block_tokens"] = self.block_tokens
         d["hbm_bytes"] = self.store.hbm_bytes
+        d["write_dispatches"] = self.store.write_dispatches
         return d

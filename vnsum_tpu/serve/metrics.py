@@ -83,6 +83,11 @@ _reg("cache_hit_rate", "gauge",
      "cumulative cache-hit tokens / prompt tokens (0 when the cache is off)")
 _reg("cache_evictions_total", "counter",
      "prefix-cache blocks evicted (LRU under the block budget)")
+_reg("cache_inserted_blocks_total", "counter",
+     "prefix-cache blocks newly allocated by inserts and copied to the pool")
+_reg("cache_write_dispatches_total", "counter",
+     "dispatches of the pool's write program (one per insert call with new "
+     "blocks; inserted blocks / dispatches = blocks a dispatch carries)")
 _reg("cache_blocks_used", "gauge",
      "prefix-cache blocks currently allocated")
 _reg("cache_blocks_total", "gauge", "prefix-cache block budget")
@@ -1056,6 +1061,13 @@ class ServeMetrics:
             simple("journal_pending", journal_stats.get("pending", 0))
         if cache_stats is not None:
             simple("cache_evictions_total", cache_stats.get("evictions", 0))
+            simple("cache_inserted_blocks_total",
+                   cache_stats.get("inserted_blocks", 0))
+            if "write_dispatches" in cache_stats:
+                # a device pool's counter: a backend that keeps the index
+                # alone (FakeBackend) has no write program to count
+                simple("cache_write_dispatches_total",
+                       cache_stats["write_dispatches"])
             simple("cache_blocks_used", cache_stats.get("blocks_used", 0))
             simple("cache_blocks_total", cache_stats.get("blocks_total", 0))
             if "pinned_blocks" in cache_stats:
