@@ -9,7 +9,7 @@ random weights made from a seed, through the entry points a user calls:
              geometry of every lane-aligned family, against the dense
              reference attention of models/llama.py
     offline  PipelineRunner (what `python -m vnsum_tpu.pipeline.cli
-             --backend tpu` and bench.py run): VN-LongSum-length documents,
+             --backend tpu` runs): VN-LongSum-length documents,
              mapreduce, a full-batch S=8192 dispatch, a reduce, evaluation
     serve    `python -m vnsum_tpu.serve.server --backend tpu --inflight
              --fused-segments N --journal-dir ...` on a cold program cache:
@@ -74,10 +74,10 @@ def sizes(rehearsal: bool) -> dict:
         # C leaves a partial tail block in every kernel (3208 % 128 == 8): past
         # the cache's end a block holds stale VMEM, NaN patterns included
         kernel_S=1024, kernel_off=2048, kernel_C=3208, kernel_B=8,
-        # offline: bench.py's e2e shape — chunk_size 7800 BPE tokens lands
-        # map prompts in the S=8192 bucket at B=16
+        # offline: chunk_size 7800 BPE tokens lands map prompts in the
+        # S=8192 bucket at B=16
         docs=4, words_per_doc=37_000, bpe_vocab=4096, chunk_size=7_800,
-        token_max=6_000,  # batch and decode budget: bench.e2e_engine_kwargs
+        token_max=6_000,  # batch and decode budget: e2e_engine_kwargs
         offline_seq=8448, offline_prefill_chunk=2048, probe_tokens=7_300,
         # serve: bf16 weights (6.4 GB, the server has no --quantize) leave
         # room for two slots whose prompt bucket holds a 12k-token map chunk
@@ -361,15 +361,53 @@ def phase_kernels(args) -> dict:
             "geometries": [list(g) for g in geoms], "sync_probe": sync}
 
 
+def e2e_engine_kwargs(tok_spec) -> dict:
+    """The offline phase's engine configuration: chunk_size 7800 lands map
+    prompts in the S=8192 bucket, int8 weights, W8A8 prefill (lossy; its
+    quality cost is bounded in artifacts/quality_lossy_ab.json), and
+    prefill in 2048-token chunks, which caps the prefill transients so that
+    B=16 fits beside the int8 KV cache."""
+    from vnsum_tpu.models import llama32_3b
+
+    return dict(
+        model_config=llama32_3b(max_seq_len=8448),
+        tokenizer=tok_spec,
+        batch_size=16,
+        max_new_tokens=128,
+        quantize=True,
+        quantize_act=True,
+        prefill_chunk_tokens=2048,
+    )
+
+
+def _pick_ragged_eos(outs: list[str], tok, budget: int = 128) -> tuple[int, ...]:
+    """Pick the token id whose per-row frequency makes the EXPECTED
+    termination step ~budget/3 under sampled decode: with ~f occurrences per
+    ``budget``-token row, per-step hit probability is ~f/budget, so
+    E[termination] ~ budget/f. f~3 puts the average stop around step 40 of
+    128 — most rows finish well before the budget at scattered depths, the
+    shape real summaries produce."""
+    from collections import Counter
+
+    rows = [tok.encode(o) for o in outs if o]
+    rows = [r for r in rows if r]
+    if not rows:
+        return (10,)
+    counts: Counter = Counter()
+    for r in rows:
+        counts.update(r)
+    target = 3.0 * len(rows)  # ~3 occurrences per row on average
+    best = min(counts, key=lambda b: (abs(counts[b] - target), b))
+    return (int(best),)
+
+
 def _offline_engine(args, sz, tok_spec):
-    """The e2e engine of bench.py (one copy of that configuration), or its
-    tiny interpret-mode stand-in for the rehearsal."""
+    """The offline phase's engine (e2e_engine_kwargs), or its tiny
+    interpret-mode stand-in for the rehearsal."""
     from vnsum_tpu.backend.engine import TpuBackend
 
     if not args.rehearsal:
-        import bench
-
-        return TpuBackend(**bench.e2e_engine_kwargs(tok_spec, None))
+        return TpuBackend(**e2e_engine_kwargs(tok_spec))
     from vnsum_tpu.models import tiny_llama
 
     return TpuBackend(
@@ -385,7 +423,6 @@ def _offline_engine(args, sz, tok_spec):
 def phase_offline(args) -> dict:
     import jax
 
-    import bench
     from vnsum_tpu.core.config import GenerationConfig, PipelineConfig
     from vnsum_tpu.data.synthesize import synthesize_corpus
     from vnsum_tpu.models.fixtures import train_bpe_tokenizer
@@ -401,7 +438,7 @@ def phase_offline(args) -> dict:
     )
     doc_paths = sorted((root / "corpus/doc").glob("*.txt"))
     # the quality-run configuration tokenizes with the checkpoint's BPE, not
-    # raw bytes; train one on the corpus as bench.py does
+    # raw bytes; train one on the corpus
     hf_tok = train_bpe_tokenizer(
         (p.read_text(encoding="utf-8") for p in doc_paths),
         vocab_size=sz["bpe_vocab"],
@@ -418,7 +455,7 @@ def phase_offline(args) -> dict:
             backend.platform == "tpu" or args.rehearsal, backend.platform)
 
     # random-init weights under greedy decode can emit EOS at step 0; use
-    # bench.py's sampled ragged-EOS recipe, at full batch so the dominant
+    # the sampled ragged-EOS recipe, at full batch so the dominant
     # (B, S=8192) program is the one the probe compiles
     raw = b" ".join(p.read_text(encoding="utf-8").encode() for p in doc_paths)
     step = int(sz["probe_tokens"] * bytes_per_tok)
@@ -432,7 +469,7 @@ def phase_offline(args) -> dict:
         config=GenerationConfig(temperature=1.0, seed=11),
     )
     max_new = backend.max_new_tokens
-    eos = bench._pick_ragged_eos(probe, backend.tok, max_new)
+    eos = _pick_ragged_eos(probe, backend.tok, max_new)
     backend.gen_cfg = GenerationConfig(
         max_new_tokens=max_new, temperature=1.0, seed=11,
         eos_ids=eos,

@@ -1,6 +1,6 @@
 """Standalone flash-prefill kernel timing vs block geometry (DMA probe).
 
-artifacts/prefill_gap.json: attention costs 2.7 s of the 7.0 s e2e prefill
+An earlier machine's profile: attention costs 2.7 s of the 7.0 s e2e prefill
 dispatch (~39% of device time for ~18% of FLOPs), and switching the MXU
 dots to bf16 moved NOTHING — so the kernel is not compute-rate-bound.
 Prime suspect: K/V DMA redundancy. The grid (B, H, I, J) streams each K/V

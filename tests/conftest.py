@@ -22,7 +22,6 @@ import pytest  # noqa: E402
 #   python -m pytest -m "not slow"
 # The full hermetic suite stays the CI default (plain `pytest`).
 _SLOW_MODULES = {
-    "test_backend_continuous",
     "test_backend_engine",
     "test_backend_long_context",
     "test_graft_entry",

@@ -3,12 +3,12 @@
 - lock-order detector green (and actually watching) under concurrent
   scheduler traffic and under cache-eviction churn — the two paths ISSUE 7
   names as deadlock suspects;
-- transfer-guard mode green over a hermetic TpuBackend prefill/decode run
-  (one-shot AND continuous), with byte-identical outputs;
+- transfer-guard mode green over a hermetic TpuBackend one-shot
+  prefill/decode run, with byte-identical outputs;
 - the disabled-mode no-op guarantee: with sanitizers off the serve/cache
   locks are plain ``threading.Lock`` objects — no wrapper, zero extra
-  acquisitions on the scheduler hot path — so serving goodput
-  (BENCH_serving_r03) is untouched by this machinery existing.
+  acquisitions on the scheduler hot path — so serving goodput is
+  untouched by this machinery existing.
 
 CPU caveat (documented in analysis/sanitizers.py): device<->host on CPU JAX
 is zero-copy, so the transfer guard cannot fire there — these tests verify
@@ -134,9 +134,9 @@ def tiny():
 
 
 def test_transfer_guard_green_over_engine_decode_prefill(tiny, monkeypatch):
-    """Acceptance: sanitizer transfer mode passes over hermetic one-shot
-    AND continuous prefill/decode runs, byte-identical to unsanitized —
-    every hot-loop sync is an explicit (lint-acknowledged) device_get."""
+    """Acceptance: sanitizer transfer mode passes over a hermetic one-shot
+    prefill/decode run, byte-identical to unsanitized — every hot-loop
+    sync is an explicit (lint-acknowledged) device_get."""
     from vnsum_tpu.backend.engine import TpuBackend
 
     cfg, params = tiny
@@ -151,10 +151,6 @@ def test_transfer_guard_green_over_engine_decode_prefill(tiny, monkeypatch):
     one_shot = TpuBackend(model_config=cfg, params=params, batch_size=4,
                           max_new_tokens=8, flash=False)
     assert one_shot.generate(prompts) == want
-    segmented = TpuBackend(model_config=cfg, params=params, batch_size=4,
-                           max_new_tokens=8, continuous=True,
-                           segment_tokens=4, flash=False)
-    assert segmented.generate(prompts) == want
 
 
 def test_transfer_guard_context_selection(monkeypatch):

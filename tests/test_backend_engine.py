@@ -129,38 +129,17 @@ def test_mesh_sharded_quantized_generation_matches_single_device():
     )
 
 
-def test_mesh_flash_quantized_continuous_matches_single_device():
-    """The full fast path (Pallas prefill+decode kernels via shard_map, int8
-    KV cache, continuous scheduling) must emit the same tokens under a
-    (data, model) mesh as on a single device — the round-1 guards that
-    locked the kernels out of meshes are gone (VERDICT r1 'what's weak' #2)."""
-    from vnsum_tpu.backend.engine import TpuBackend
-    from vnsum_tpu.parallel import make_mesh
-
-    cfg = tiny_llama(max_seq_len=128)
-    kw = dict(
-        model_config=cfg, batch_size=4, max_new_tokens=6, seed=3,
-        flash=True, quantize_kv=True, interpret=True, continuous=True,
-        segment_tokens=2, min_batch=1,
-    )
-    plain = TpuBackend(**kw)
-    mesh = make_mesh({"data": 2, "model": 2, "seq": 1}, platform="cpu")
-    sharded = TpuBackend(mesh=mesh, **kw)
-    prompts = ["văn bản một", "văn bản thứ hai dài hơn", "ba", "bốn bốn bốn"]
-    np.testing.assert_array_equal(
-        plain.generate(prompts), sharded.generate(prompts)
-    )
-
-
 def test_mesh_flash_oneshot_matches_single_device():
-    """Same as above for the one-shot (non-continuous) program."""
+    """The full fast path (Pallas prefill+decode kernels via shard_map, int8
+    KV cache) must emit the same tokens under a (data, model) mesh as on a
+    single device."""
     from vnsum_tpu.backend.engine import TpuBackend
     from vnsum_tpu.parallel import make_mesh
 
     cfg = tiny_llama(max_seq_len=128)
     kw = dict(
         model_config=cfg, batch_size=4, max_new_tokens=6, seed=3,
-        flash=True, quantize_kv=True, interpret=True, continuous=False,
+        flash=True, quantize_kv=True, interpret=True,
     )
     plain = TpuBackend(**kw)
     mesh = make_mesh({"data": 2, "model": 2, "seq": 1}, platform="cpu")
@@ -169,41 +148,6 @@ def test_mesh_flash_oneshot_matches_single_device():
     np.testing.assert_array_equal(
         plain.generate(prompts), sharded.generate(prompts)
     )
-
-
-def test_mesh_continuous_compaction_fires_and_matches():
-    """Tail compaction under a mesh: when most rows finish early the batch
-    is halved (respecting data-axis divisibility) and outputs still match
-    the single-device engine."""
-    from vnsum_tpu.backend.engine import TpuBackend
-    from vnsum_tpu.parallel import make_mesh
-
-    cfg = tiny_llama(max_seq_len=128)
-    kw = dict(
-        model_config=cfg, batch_size=4, max_new_tokens=12, seed=3,
-        flash=True, quantize_kv=True, interpret=True, continuous=True,
-        segment_tokens=2, min_batch=1,
-    )
-    prompts = ["văn bản một", "văn bản thứ hai dài hơn", "ba", "bốn bốn bốn"]
-    probe = TpuBackend(**kw)
-    outs = probe.generate(prompts)
-    firsts = {probe.tok.encode(o)[0] for o in outs if o}
-    if len(firsts) < 2:
-        pytest.skip("random model gives <2 distinct first tokens")
-    # make all but one row stop at its first token -> compaction must fire
-    eos_ids = tuple(sorted(firsts))[:-1]
-    gen = GenerationConfig(temperature=0.0, eos_ids=eos_ids)
-
-    plain = TpuBackend(**kw)
-    mesh = make_mesh({"data": 2, "model": 2, "seq": 1}, platform="cpu")
-    sharded = TpuBackend(mesh=mesh, **kw)
-    a = plain.generate(prompts, max_new_tokens=12, config=gen)
-    b = sharded.generate(prompts, max_new_tokens=12, config=gen)
-    np.testing.assert_array_equal(a, b)
-    assert sharded.stats.compactions > 0
-    # divisibility: every post-compaction batch must still split over data=2
-    assert sharded.stats.compacted_batch_sizes
-    assert all(B % 2 == 0 for B in sharded.stats.compacted_batch_sizes)
 
 
 def test_early_exit_matches_reference_rollout(engine):
@@ -298,7 +242,7 @@ def test_sampled_batches_draw_fresh_randomness():
     def fresh():
         return TpuBackend(
             model_config=tiny_llama(max_seq_len=128),
-            batch_size=4, max_new_tokens=16, seed=5, continuous=False,
+            batch_size=4, max_new_tokens=16, seed=5,
             flash=False,
         )
 
@@ -315,28 +259,6 @@ def test_sampled_batches_draw_fresh_randomness():
     # a different GenerationConfig.seed changes the stream (knob is honored)
     c = fresh()
     assert c.generate(["một văn bản"], config=gen.with_(seed=99)) != first
-
-
-def test_instrument_mode_matches_oneshot_and_records_budget():
-    """instrument=True must be observability-only: identical outputs to the
-    one-shot program (same _make_parts bodies), with per-phase device times
-    and per-dispatch {B, S, steps} records filled in."""
-    from vnsum_tpu.backend.engine import TpuBackend
-
-    cfg = tiny_llama(max_seq_len=128)
-    kw = dict(model_config=cfg, batch_size=4, max_new_tokens=8, seed=3,
-              flash=False)
-    plain = TpuBackend(**kw)
-    inst = TpuBackend(instrument=True, **kw)
-    prompts = ["văn bản một", "hai dài hơn một chút", "ba", "bốn"]
-    assert plain.generate(prompts) == inst.generate(prompts)
-    st = inst.stats
-    assert st.phase_seconds.get("prefill", 0) > 0
-    assert st.phase_seconds.get("decode", 0) > 0
-    assert "tokenize_host" in st.phase_seconds
-    assert st.compactions == 0  # instrument pins the batch
-    (d,) = st.dispatches
-    assert d["B"] == 4 and d["steps"] <= 8 and d["decode_s"] >= 0
 
 
 def test_sampling_vocab_keeps_terminators_sampleable():
@@ -403,7 +325,7 @@ def test_native_eos_terminates_sampled_decode():
 
     be = TpuBackend(
         model_config=tiny_llama(max_seq_len=256), tokenizer="byte",
-        batch_size=8, max_new_tokens=128, seed=0, continuous=False,
+        batch_size=8, max_new_tokens=128, seed=0,
         flash=False,
     )
     # near-uniform random-init logits give p(EOS) ~ 1/258 per draw; over
@@ -434,7 +356,7 @@ def test_sampling_restricted_to_tokenizer_vocab():
     cfg = tiny_llama(vocab_size=2048)  # model vocab >> byte-tokenizer vocab
     be = TpuBackend(
         model_config=cfg, tokenizer="byte", batch_size=2, max_new_tokens=16,
-        seed=0, continuous=False,
+        seed=0,
         flash=False,
     )
     gen = GenerationConfig(temperature=1.0, seed=9)

@@ -38,7 +38,6 @@ def setup(mesh):
     params = init_params(jax.random.key(3), cfg)
     dense = TpuBackend(
         model_config=cfg, params=params, batch_size=4, max_new_tokens=16,
-        continuous=False,
         flash=False,
     )
     long = LongContextBackend(
@@ -110,7 +109,6 @@ def test_exceeds_single_chip_ceiling(mesh):
     )
     oracle = TpuBackend(
         model_config=big_cfg, params=params, batch_size=2, max_new_tokens=12,
-        continuous=False,
         flash=False,
     )
     got = long.generate([long_doc])
@@ -302,7 +300,6 @@ def test_decode_kernel_path_greedy_parity(mesh):
     params = init_params(jax.random.key(3), cfg)
     dense = TpuBackend(
         model_config=cfg, params=params, batch_size=4, max_new_tokens=16,
-        continuous=False,
         flash=False,
     )
     kernel_long = LongContextBackend(
@@ -379,7 +376,6 @@ def test_greedy_parity_with_model_axis_active():
     params = init_params(jax.random.key(13), cfg)
     dense = TpuBackend(
         model_config=cfg, params=params, batch_size=2, max_new_tokens=12,
-        continuous=False,
         flash=False,
     )
     long = LongContextBackend(

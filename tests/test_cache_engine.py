@@ -71,11 +71,28 @@ def test_resume_outputs_byte_identical(cfg, params, reference_outputs):
         assert r <= len(p.encode()) + 1
 
 
-def test_resume_identical_in_continuous_mode(cfg, params, reference_outputs):
-    b = make_backend(cfg, params, continuous=True, segment_tokens=8)
-    assert b.generate(PROMPTS) == reference_outputs
-    assert b.generate(PROMPTS) == reference_outputs
-    assert b.stats.cache_hit_tokens > 0
+@pytest.mark.parametrize("option", ["continuous", "min_batch", "instrument"])
+def test_engine_has_no_option_that_selects_another_decode_loop(
+    cfg, params, option
+):
+    with pytest.raises(TypeError, match=option):
+        TpuBackend(model_config=cfg, params=params, flash=False,
+                   **{option: True})
+
+
+def test_plain_generate_is_one_program_whatever_the_budget(cfg, params):
+    """A budget several ``segment_tokens`` long still runs the one-shot
+    program: one ``_fns`` entry, nothing among the split/slot programs."""
+    b = TpuBackend(
+        model_config=cfg, params=params, batch_size=4, max_new_tokens=16,
+        segment_tokens=4, flash=False,
+    )
+    outs = b.generate(PROMPTS)
+    assert len(outs) == len(PROMPTS) and all(isinstance(o, str) for o in outs)
+    assert len(b._fns) == 1 and not b._seg_fns
+    (key,) = b._fns
+    assert key[:3] == (4, 256, 16)   # (B, S bucket, max_new)
+    assert list(b.stats.attention_paths) == ["generate[B=4,S=256]"]
 
 
 def test_post_eviction_outputs_byte_identical(cfg, params, reference_outputs):
@@ -117,14 +134,12 @@ def test_mixed_lengths_group_by_suffix(cfg, params):
     assert b.generate(mixed) == want
 
 
-@pytest.mark.parametrize("continuous", [False, True])
-def test_group_insert_is_one_write_dispatch(cfg, params, continuous):
+def test_group_insert_is_one_write_dispatch(cfg, params):
     """One _cache_insert of a multi-row group sends every row's new blocks
     to the pool in ONE dispatch, and /metrics carries both counters."""
     from vnsum_tpu.serve.metrics import ServeMetrics
 
-    kw = dict(continuous=True, segment_tokens=8) if continuous else {}
-    b = make_backend(cfg, params, **kw)
+    b = make_backend(cfg, params)
     calls = []
     cache_insert = b._cache_insert
 

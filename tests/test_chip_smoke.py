@@ -1,7 +1,7 @@
 """chip_smoke.py off the chip: it must fail, say which platform it found, and
 print no result — and nothing else may time or serve from the CPU either
-(TpuBackend at defaults, bench.py, a tpu worker's environment, a fleet of
-tpu workers on one chip)."""
+(TpuBackend at defaults, a tpu worker's environment, a fleet of tpu workers
+on one chip)."""
 from __future__ import annotations
 
 import json
@@ -39,6 +39,15 @@ def test_chip_smoke_alone_is_not_the_program(tmp_path):
     assert proc.stdout.strip() == ""
 
 
+def _chip_smoke():
+    sys.path.insert(0, str(REPO))
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(str(REPO))
+    return chip_smoke
+
+
 def test_parent_module_imports_without_jax():
     code = (
         "import sys; sys.path.insert(0, %r); import chip_smoke; "
@@ -51,11 +60,7 @@ def test_parent_module_imports_without_jax():
 
 
 def test_result_line_has_exactly_the_contract_keys():
-    sys.path.insert(0, str(REPO))
-    try:
-        import chip_smoke
-    finally:
-        sys.path.remove(str(REPO))
+    chip_smoke = _chip_smoke()
     line = chip_smoke.result_line(
         True, {"platform": "tpu", "device_kind": "TPU v5 lite", "count": 1})
     assert "\n" not in line
@@ -69,24 +74,32 @@ def test_result_line_has_exactly_the_contract_keys():
     assert isinstance(failed["device"]["count"], int)
 
 
-def test_bench_refuses_to_time_the_cpu():
-    proc = _run([sys.executable, str(REPO / "bench.py")])
-    assert proc.returncode != 0
-    assert "platform 'cpu'" in proc.stderr
-    assert proc.stdout.strip() == ""
+def test_pick_ragged_eos_ends_some_rows_and_not_all_before_the_budget():
+    from vnsum_tpu.text.tokenizer import ByteTokenizer
+
+    chip_smoke = _chip_smoke()
+    tok = ByteTokenizer()
+    # 13 "e" in four rows and none in the third, against 26 "x", 5 "a",
+    # 5 "y", 1 "z": the recipe wants ~3 occurrences a row
+    outs = ["xaxexexexx" * 2, "xeyeyeaeyy", "xaxxxxxxxx", "xezeyeaxxx", ""]
+    (eos,) = chip_smoke._pick_ragged_eos(outs, tok, budget=20)
+    assert eos == tok.encode("e")[0]
+    rows = [tok.encode(o) for o in outs if o]
+    ended = [eos in r[:20] for r in rows]
+    assert any(ended) and not all(ended)
+    assert chip_smoke._pick_ragged_eos([], tok) == (10,)
 
 
-def test_bench_peaks_table_is_keyed_by_device_kind():
-    sys.path.insert(0, str(REPO))
-    try:
-        import bench
-    finally:
-        sys.path.remove(str(REPO))
-    v5e = bench.DEVICE_PEAKS["TPU v5 lite"]
-    assert v5e == {"flops_bf16": 197e12, "ops_int8": 393e12,
-                   "hbm_bytes_per_s": 819e9}
-    with pytest.raises(SystemExit, match="no published peaks.*'cpu'"):
-        bench.device_peaks()  # the CPU is not in the table, nor a default
+def test_e2e_engine_kwargs_are_all_engine_parameters():
+    import inspect
+
+    from vnsum_tpu.backend.engine import TpuBackend
+
+    kwargs = _chip_smoke().e2e_engine_kwargs("byte")
+    accepted = set(inspect.signature(TpuBackend.__init__).parameters)
+    assert set(kwargs) <= accepted - {"self"}
+    assert kwargs["tokenizer"] == "byte" and kwargs["batch_size"] == 16
+    assert kwargs["model_config"].max_seq_len == 8448
 
 
 def test_tpu_backend_refuses_the_cpu_unless_told_how_to_run():

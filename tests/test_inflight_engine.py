@@ -50,7 +50,7 @@ def drain(loop, outs, max_segments=64):
 def ragged_eos_config(max_new=24):
     """A GenerationConfig whose extra EOS fires at scattered depths, so
     rows FINISH at different segments and freed slots actually refill
-    mid-flight (the same probe trick as the continuous-scheduling tests)."""
+    mid-flight."""
     probe = make_backend()
     outs = probe.generate(PROMPTS)
     tok = probe.tok

@@ -186,10 +186,10 @@ def test_finished_trace_is_sealed_against_late_spans():
 
 
 def test_unsynced_prefill_does_not_anchor_ttft():
-    # TpuBackend without instrument=True returns from the prefill call at
-    # async DISPATCH — its emitted duration bounds submission, not device
-    # time, and must not become the TTFT anchor (synced=False); an
-    # instrumented (sync-bounded) prefill must
+    # TpuBackend returns from the spec prefill call at async DISPATCH —
+    # its emitted duration bounds submission, not device time, and must
+    # not become the TTFT anchor (synced=False); a sync-bounded prefill
+    # must
     bt = BatchTrace(batch_id=1, occupancy=2)
     t0 = time.monotonic()
     bt.event("prefill", t0, 0.0005, B=2, synced=False)

@@ -211,7 +211,7 @@ def test_flash_bf16_compute_dtype_close_to_f32():
     """The kernel computes its dots in the QUERY dtype (f32 tests exact;
     the engine's bf16 gets the MXU full-rate path — the f32 in-kernel dots
     previously made attention 39% of prefill device time for ~18% of its
-    FLOPs, artifacts/prefill_gap.json). bf16 inputs must stay within bf16
+    FLOPs, earlier machine). bf16 inputs must stay within bf16
     rounding of the f32 oracle: f32 accumulation bounds the error at the
     input-rounding level (~1e-2), not O(sqrt(K)) growth."""
     L, B, S, C, H, KV, hd = 2, 2, 32, 64, 4, 2, 128

@@ -43,12 +43,13 @@ def paths(scopes: dict, depth: int = 2) -> set[str]:
 @pytest.fixture(scope="module")
 def maps():
     """The engine's five kinds of program, built (not run), and their maps
-    by kind."""
+    by kind: the one-shot program, the spec path's split prefill, and the
+    slot loop's three."""
     b = make_backend()
     gen = b.gen_cfg
     b._get_fn(B, S, NEW, gen)
     for kind, batch in (("slot_prefill", 2), ("slot_seg", B), ("adopt", 2),
-                        ("segment", B)):
+                        ("prefill", B)):
         b._get_seg_fn(kind, batch, S, NEW, gen)
     return {m["program"].split("[")[0]: m for m in b.scope_maps()}
 
@@ -59,7 +60,7 @@ def maps():
     ("slot_prefill", "jit_slot_prefill", "prefill", MODEL + ("sample",)),
     ("slot_seg", "jit_segment", "decode", MODEL + ("sample", "emit")),
     ("adopt", "jit_adopt", "adopt", ()),
-    ("segment", "jit_decode_segment", "decode", MODEL + ("sample", "emit")),
+    ("prefill", "jit_prefill", "prefill", MODEL + ("sample",)),
 ])
 def test_program_carries_its_phase_and_component_scopes(
         maps, program, module, phase, components):
@@ -72,13 +73,15 @@ def test_program_carries_its_phase_and_component_scopes(
     assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
 
 
-def test_two_segment_programs_two_module_names(maps):
-    """The slot loop's segment keeps ``jit_segment`` (the benchmark's
-    ``segment_ms_per_step`` reads it); the one-shot path's segmented decode
-    is ``jit_decode_segment``."""
+def test_one_segment_program_and_no_other_kind(maps):
+    """The slot loop's segment is ``jit_segment`` (the benchmark's
+    ``segment_ms_per_step`` reads it) and nothing else decodes in segments:
+    the engine builds no program of the kind the continuous path had."""
     assert maps["slot_seg"]["module"] == "jit_segment"
-    assert maps["segment"]["module"] == "jit_decode_segment"
     assert maps["adopt"]["program"].endswith(f"slots={B}]")
+    b = make_backend()
+    with pytest.raises(ValueError, match="segment"):
+        b._get_seg_fn("segment", B, S, NEW, b.gen_cfg)
 
 
 def _pallas_names(jaxpr) -> list[str]:

@@ -302,6 +302,9 @@ def test_hung_dispatch_journals_typed_failed(tmp_path):
             fut = sched.submit("ket trong dong co", trace_id="hung-1")
             with pytest.raises(RequestFailed):
                 fut.result(timeout=10)
+        # the rider fails first; recovery ends when the successor loop
+        # thread has started, and only then may close() join it
+        _wait_until(lambda: wd.recoveries_total == 1)
         entries = journal.lookup("hung-1")
         assert entries and entries[0].status == "failed"
         assert entries[0].reason == "hung"

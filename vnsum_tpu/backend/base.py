@@ -11,7 +11,7 @@ Optional observability contract (vnsum_tpu.obs): backends MAY publish phase
 telemetry from inside generate() via ``obs.trace.emit(name, t0, dur, ...)``
 — host timestamps around already-dispatched device calls, never extra
 device syncs. emit() no-ops on a single contextvar read unless a caller
-(the serving scheduler, a bench) installed a collector, so backends wrap
+(the serving scheduler) installed a collector, so backends wrap
 their hot paths unconditionally. Recognized phase names: "tokenize",
 "prefill"/"spec_prefill" (their end is the TTFT anchor), "decode",
 "decode_seg", "spec_step", "dispatch" (fused one-shot program),

@@ -14,8 +14,8 @@ becomes length-bucketed, fixed-shape [B, S] device batches:
 - params and token batches carry NamedShardings over a (data, model) mesh, so
   the same program runs single-chip or TP/DP-sharded with GSPMD collectives.
 
-Telemetry: the host loops publish phase events (tokenize, prefill,
-dispatch, decode_seg, spec_step, detokenize) through obs.trace.emit() — host
+Telemetry: the host loops publish phase events (tokenize, dispatch,
+spec_prefill, spec_step, detokenize) through obs.trace.emit() — host
 timestamps around device calls whose sync the loop already paid (done-mask /
 result fetches), a no-op unless a collector is installed (the serving
 scheduler's BatchTrace; see backend/base.py for the contract). These feed
@@ -76,7 +76,7 @@ def _bucket_len(n: int, max_len: int) -> int:
 
 @dataclass
 class EngineStats:
-    """Wall-clock + token accounting for bench.py / run records."""
+    """Wall-clock + token accounting for run records."""
 
     calls: int = 0
     prompts: int = 0
@@ -100,8 +100,6 @@ class EngineStats:
     # from scratch — hit/(hit+miss) is the prefill-token reduction
     cache_hit_tokens: int = 0
     cache_miss_tokens: int = 0
-    compactions: int = 0
-    compacted_batch_sizes: list = field(default_factory=list)
     by_bucket: dict = field(default_factory=dict)
     # grid cells of the prefill attention kernel by class, per KV head,
     # summed over layers, chunks and one-shot dispatches
@@ -114,15 +112,8 @@ class EngineStats:
     # head dim, the slot/verify kernel under a mesh) is visible here and in
     # the log instead of only in the timings
     attention_paths: dict = field(default_factory=dict)
-    # host-phase wall clock (always on: the timers wrap pure-host work) plus,
-    # under instrument=True, the device phases "prefill"/"decode" measured by
-    # explicit result-fetch sync (every hot-path fetch is a
-    # lint-acknowledged device_get, see analysis/rules/host_sync)
+    # host-phase wall clock (always on: the timers wrap pure-host work)
     phase_seconds: dict = field(default_factory=dict)
-    # instrument=True: one record per device dispatch {B, S, steps,
-    # prefill_s, decode_s} — enough to reconstruct FLOP and HBM-byte budgets
-    # per batch shape without re-deriving them from logs
-    dispatches: list = field(default_factory=list)
 
     def add_phase(self, name: str, seconds: float) -> None:
         self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + seconds
@@ -150,11 +141,8 @@ class TpuBackend:
         quantize: bool = False,
         quantize_act: bool = False,
         quantize_kv: str | bool = "auto",
-        continuous: str | bool = "auto",
         segment_tokens: int = 128,
-        min_batch: int = 8,
         interpret: bool = False,
-        instrument: bool = False,
         prefill_chunk_tokens: int = 0,
         spec_max_ref_tokens: int = 4096,
         cache_blocks: int = 0,
@@ -220,39 +208,12 @@ class TpuBackend:
                 f"max_new_tokens={max_new_tokens} must be < "
                 f"max_seq_len={self.cfg.max_seq_len}"
             )
-        # continuous scheduling (segmented decode + tail compaction): decode
-        # runs in fixed segments; at segment boundaries finished rows are
-        # harvested and the survivors compacted into a half-size program, so
-        # ragged generation lengths don't pay full-batch decode for the tail.
-        # Streams are keyed per row (seed, uid, step) so compaction never
-        # changes which random draws a surviving row makes; across the
-        # batch-shape change, logits can still differ in the last bits
-        # (different matmul tilings accumulate in different orders), so
-        # outputs are bit-identical on same-shape replays and test-exact in
-        # CPU/interpret runs, but near-tie tokens can flip across a
-        # compaction on real hardware. Under a mesh, compaction only halves
-        # down to batch shapes that stay divisible by the data axis.
-        #
-        # "auto" policy, from the measured A/B (artifacts/compaction_ab.json,
-        # PERF.md finding 13): the segmented path LOST token-normalized at
-        # BOTH tested shapes (0.68x at B=8/S=8192, 0.82x at B=64/S=1024,
-        # compactions firing 6-8 times) — segment-boundary host syncs, the
-        # un-donated compaction gather, and the cross-dispatch resident
-        # carry outweigh the shed-row cache savings at summary-length decode
-        # budgets. One-shot (early-exit while_loop) is the default; the
-        # segmented scheduler remains available explicitly for workloads
-        # with long ragged tails (multi-hundred-token budgets where a few
-        # stragglers pin an otherwise-finished batch).
-        if continuous == "auto":
-            continuous = False
-        self.continuous = bool(continuous)
+        # decode steps per dispatch of the in-flight slot loop's segment
+        # program (backend/inflight.py)
         self.segment_tokens = max(segment_tokens, 1)
-        self.min_batch = max(min_batch, 1)
         # prefill in slices of this many tokens (0 = whole prompt): caps
         # prefill transients at CL tokens' worth so decode batches beyond
-        # the whole-prompt memory ceiling fit (B=16 at S=8192 on one v5e —
-        # measured 1.36x decode / 1.10x whole-dispatch vs 2x B=8,
-        # artifacts/b16_chunked_prefill.json)
+        # the whole-prompt memory ceiling fit (B=16 at S=8192 on one v5e)
         if prefill_chunk_tokens < 0 or (
             prefill_chunk_tokens and prefill_chunk_tokens % 128
         ):
@@ -260,18 +221,6 @@ class TpuBackend:
                 "prefill_chunk_tokens must be a non-negative multiple of 128"
             )
         self.prefill_chunk_tokens = int(prefill_chunk_tokens)
-        # instrument=True: run the SPLIT prefill + decode programs (same
-        # _make_parts bodies as the one-shot jit, so identical math) with a
-        # result-fetch sync between them, so stats.phase_seconds carries a
-        # real per-phase device-time budget. Decode runs as ONE full-length
-        # segment and compaction is disabled — the only deltas vs the
-        # one-shot program are the extra dispatch boundary and the done
-        # fetch, a few percent of wall clock (artifacts/compaction_ab.json).
-        self.instrument = bool(instrument)
-        if instrument:
-            self.continuous = True
-            self.segment_tokens = 1 << 30      # single full-length segment
-            self.min_batch = max(self.min_batch, batch_size)  # no compaction
         self.stats = EngineStats()
         # entered around each program's first call, the one that compiles.
         # The serving scheduler installs its watchdog's compile pause here so
@@ -279,7 +228,6 @@ class TpuBackend:
         self.compile_scope = contextlib.nullcontext
         self._fns: dict[tuple[int, int, int], callable] = {}
         self._seg_fns: dict = {}
-        self._compact_fn = None
         self._seed = seed
         self._dispatch = 0
         # reference-guided speculative decoding (vnsum_tpu.spec): cap on
@@ -464,9 +412,9 @@ class TpuBackend:
             elif kind == "slot_seg":
                 fn = self._make_slot_segment_fn(B, S, max_new, gen, fused)
                 args = (self.params, i32(B)) + carry
-            elif kind == "adopt":
-                # the resident batch is not in an adopt key: it is the B of
-                # the slot segment built for the same loop
+            else:
+                # adopt. The resident batch is not in its key: it is the B
+                # of the slot segment built for the same loop
                 slots = {k[1] for k in self._seg_fns
                          if k[0] == "slot_seg" and k[2:5] == (S, max_new, gen)}
                 fn = self._make_adopt_fn(B)
@@ -476,9 +424,6 @@ class TpuBackend:
                         i32(n, max_new), i32(n),
                         cache_of(B, C), i32(B), done(B), i32(B), i32(B))))
                 continue
-            else:
-                fn = self._make_segment_fn(B, S, max_new, gen)
-                args = (self.params, i32()) + carry
             programs.append((label, fn, args))
 
         maps = []
@@ -535,14 +480,11 @@ class TpuBackend:
 
         Sampling is counter-based per row: step t of row uid draws from
         fold_in(fold_in(key(seed), uid), t). A row's stream therefore
-        depends only on (seed, uid, t) — never on its batch position — so
-        the continuous scheduler can compact a sampled batch mid-decode
-        with bit-identical surviving outputs (greedy was always safe).
+        depends only on (seed, uid, t) — never on its batch position.
 
         The one-shot program is prefill + one decode to t_end=max_new in a
-        single jit; the continuous scheduler jits them separately and runs
-        decode in segments — ONE body definition serves both, so the paths
-        cannot drift.
+        single jit; the spec path jits prefill_part alone
+        (_make_prefill_fn) and decodes with its own verify step.
 
         ``resume_from=K`` (prefix KV cache, vnsum_tpu.cache) builds the
         resume-prefill variant: prefill_part takes a cache pre-seeded with
@@ -564,9 +506,8 @@ class TpuBackend:
 
         # prefill runs whole-prompt or in prefill_chunk_tokens slices —
         # chunking caps transient activations (q/k/v, MLP intermediates)
-        # at a chunk's worth, which is what lets B=16 decode fit at S=8192
-        # (measured 1.36x decode vs 2x B=8 dispatches,
-        # artifacts/b16_chunked_prefill.json); see _prefill_forward
+        # at a chunk's worth, which is what lets B=16 decode fit at S=8192;
+        # see _prefill_forward
         def prefill_part(params, tokens, pad_lens, seed, cache=None):
             with jax.named_scope("prefill"):
                 logits, cache = self._prefill_forward(
@@ -716,8 +657,8 @@ class TpuBackend:
 
     def _mesh_in_shardings(self):
         """in_shardings for (params, tokens, pad_lens, seed) — shared by the
-        one-shot and continuous prefill builders so the two paths cannot
-        compile against different input layouts."""
+        one-shot and split-prefill builders so the two cannot compile
+        against different input layouts."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..models.quant import is_quantized
@@ -979,15 +920,12 @@ class TpuBackend:
                         self._make_choice_fn(B, S, len(ids)),
                         f"choice[B={B},S={S}]",
                     )
-                t_disp = time.time()
                 with annotate(f"choice[B={B},S={S}]"):
                     idx = self._fns[key](
                         self.params, tokens, pad_lens, choice_dev
                     )
-                # lint-allow[host-sync-in-hot-path]: result fetch = the sync that makes the choice timing real
+                # lint-allow[host-sync-in-hot-path]: result fetch — the host needs the chosen indices
                 idx_h = jax.device_get(idx)
-                if self.instrument:
-                    self.stats.add_phase("choice", time.time() - t_disp)
                 self.stats.batches += 1
                 self.stats.by_bucket[(B, S)] = (
                     self.stats.by_bucket.get((B, S), 0) + 1
@@ -996,7 +934,7 @@ class TpuBackend:
                     results[i] = int(idx_h[row])
         return results
 
-    # -- continuous scheduling programs ---------------------------------
+    # -- split prefill program (spec path) ------------------------------
 
     def _decode_settings(self, S: int, C: int):
         use_flash = self.flash
@@ -1024,40 +962,6 @@ class TpuBackend:
         if self.mesh is not None:
             return jax.jit(prefill, in_shardings=self._mesh_in_shardings())
         return jax.jit(prefill)
-
-    def _make_segment_fn(self, B: int, S: int, max_new: int, gen):
-        """One decode segment: advance up to ``segment_tokens`` steps (early
-        exit on all-EOS), carrying (t, cur, cache, done, key, out) across
-        host boundaries so finished rows can be harvested and the batch
-        compacted between segments. Shares its loop body with the one-shot
-        program via _make_parts."""
-        _, decode_part = self._make_parts(B, S, max_new, gen)
-        seg = self.segment_tokens
-
-        # not ``segment``: the slot loop's program owns the XLA module name
-        # jit_segment, which the benchmark's metrics read
-        def decode_segment(
-            params, t0, cur, cache, done, uids, out, pad_lens, seed
-        ):
-            t_end = jnp.minimum(t0 + seg, max_new)
-            t, cur, cache, done, out = decode_part(
-                params, t0, cur, cache, done, uids, out, pad_lens, t_end, seed
-            )
-            return t, cur, cache, done, out
-
-        # donate the cache and out buffers: segments overwrite them in place
-        return jax.jit(decode_segment, donate_argnums=(3, 6))
-
-    def _make_compact_fn(self):
-        def compact(cache, cur, done, out, pad_lens, idx):
-            cache = {k: jnp.take(v, idx, axis=1) for k, v in cache.items()}
-            return (
-                cache, cur[idx], done[idx], out[idx], pad_lens[idx]
-            )
-
-        # no donation: the gathered outputs are smaller than the inputs, so
-        # the buffers can't be reused (donating only triggers warnings)
-        return jax.jit(compact)
 
     # -- in-flight slot serving programs (backend/inflight.py) -----------
 
@@ -1122,8 +1026,8 @@ class TpuBackend:
         becomes a [B] vector and masks/positions/cache-write slots ride the
         spec-verify machinery (verify_attention_mask + vector write_index,
         num_q=1). For any single row the emitted-token math is exactly
-        decode_part's, so greedy outputs match the one-shot path with the
-        same caveat class as compaction (batch-shape tiling last bits).
+        decode_part's, so greedy outputs match the one-shot path up to the
+        last bits that another batch shape's matmul tiling moves.
 
         ``fused_segments`` fuses N host boundaries into ONE dispatch
         (Kernel Looping, arXiv 2410.23668): the same while_loop simply runs
@@ -1207,7 +1111,7 @@ class TpuBackend:
             return t, cur, cache, done, out
 
         # donate the resident cache and out buffers: segments overwrite
-        # them in place, exactly like the continuous path's segment fn
+        # them in place
         return jax.jit(segment, donate_argnums=(3, 6))
 
     def _make_adopt_fn(self, Bj: int):
@@ -1251,16 +1155,15 @@ class TpuBackend:
         """Open a persistent in-flight serving loop: a fixed-shape decode
         batch of ``slots`` rows where finished rows are harvested at every
         segment boundary and freed slots are REFILLED from new prompts
-        (chunked prefill + adopt-scatter into the resident cache) instead of
-        only compacted — Orca-style iteration-level scheduling over the
-        segmented-decode machinery. Under a mesh the resident batch rows
-        shard over `data` and heads over `model` (the same layout every
-        other decode program uses), so the loop runs TP/DP-sharded; the
-        slot count must stay divisible by the data axis. ``prompt_tokens``
-        fixes the prompt bucket S (0 = the full context minus the decode
-        budget); prompts that don't fit are rejected at admit for the
-        caller to route through the one-shot path, which remains
-        generate()'s default. ``fused_segments`` fuses N decode segments
+        (chunked prefill + adopt-scatter into the resident cache) —
+        Orca-style iteration-level scheduling. Under a mesh the resident
+        batch rows shard over `data` and heads over `model` (the same
+        layout every other decode program uses), so the loop runs
+        TP/DP-sharded; the slot count must stay divisible by the data axis.
+        ``prompt_tokens`` fixes the prompt bucket S (0 = the full context
+        minus the decode budget); prompts that don't fit are rejected at
+        admit for the caller to route through the one-shot path, which is
+        generate()'s only program. ``fused_segments`` fuses N decode segments
         into one dispatch with async host polling (see TpuSlotLoop.step) —
         joins/cancels/preemption coarsen to the fused cadence while greedy
         outputs stay byte-identical to N=1."""
@@ -1317,7 +1220,7 @@ class TpuBackend:
             elif kind == "adopt":
                 fn = self._make_adopt_fn(B)
             else:
-                fn = self._make_segment_fn(B, S, max_new, gen)
+                raise ValueError(f"no program of kind {kind!r}")
             self._seg_fns[key] = self._timed_first_call(
                 fn,
                 f"{kind}[B={B},S={S},new={max_new},resume={resume_from},"
@@ -1329,165 +1232,6 @@ class TpuBackend:
         s = fold_seed(gen.seed, self._seed, self._dispatch)
         self._dispatch += 1
         return s
-
-    # hot path
-    def _run_group_continuous(
-        self, group, encoded, max_new: int, gen, results, seed: int,
-        packed=None, resume=None, insert_cb=None,
-    ) -> None:
-        """Generate one prompt group with segmented decode + tail compaction.
-
-        After each segment the done mask is fetched; when the live rows fit
-        a half-size (or smaller) program, finished rows are harvested and
-        the survivors gathered into it. Output is identical to the one-shot
-        path for greedy AND sampled decode — greedy depends only on the
-        row's own cache, and sampled streams are keyed by (seed, row uid,
-        step), not batch position.
-
-        ``resume`` = (K, seeded_cache) runs the resume-prefill variant over
-        [K, S) against prefix-cache blocks already gathered into the cache;
-        ``insert_cb(cache)`` fires right after prefill (the copies dispatch
-        before the first segment's donation can retire the buffer) so new
-        prefix blocks enter the pool."""
-        tokens, pads, B, S = (
-            packed if packed is not None
-            else self._pack_group(group, encoded, max_new)
-        )
-        rows: list[int | None] = [None] * B
-        for row, i in enumerate(group):
-            rows[row] = i
-
-        # telemetry gate (vnsum_tpu.obs): resolved ONCE per dispatch — the
-        # collector is installed around the whole generate() call, so inside
-        # it the answer cannot change, and per-segment emit bookkeeping
-        # (timestamps, mask reductions, kwargs) is skipped entirely when off
-        tracing = current_collector() is not None
-        K = resume[0] if resume else 0
-        prefill = self._get_seg_fn("prefill", B, S, max_new, gen, K)
-        t_pre = time.time()
-        t_pre_m = time.monotonic()
-        with annotate(f"prefill[B={B},S={S}]"):
-            if resume:
-                cur, cache, done = prefill(
-                    self.params, tokens, pads, seed, resume[1]
-                )
-            else:
-                cur, cache, done = prefill(self.params, tokens, pads, seed)
-            if self.instrument:
-                # fetch forces the dispatch to completion: [B] bools, the
-                # cheapest output — prefill device time is now bounded
-                # lint-allow[host-sync-in-hot-path]: instrument=True exists to bound prefill with exactly this sync
-                jax.device_get(done)
-        prefill_s = time.time() - t_pre
-        # engine step telemetry (vnsum_tpu.obs): host timestamps around the
-        # dispatched device call — no extra sync; without instrument=True the
-        # dispatch is async and this bounds submission, not device time
-        if tracing:
-            emit("prefill", t_pre_m, prefill_s, B=B, S=S,
-                 occupancy=len(group), synced=self.instrument)
-        if self.instrument:
-            self.stats.add_phase("prefill", prefill_s)
-        self.stats.batches += 1
-        self.stats.by_bucket[(B, S)] = self.stats.by_bucket.get((B, S), 0) + 1
-        if insert_cb is not None:
-            # prefix-cache insertion must read the cache BEFORE the first
-            # segment dispatch donates its buffer; the copies dispatch here,
-            # in stream order ahead of the donation
-            insert_cb(cache)
-
-        out = jnp.full((B, max_new), self.tok.pad_id, dtype=jnp.int32)
-        pad_dev = jnp.asarray(pads)
-        # per-row RNG identity: sampling keys fold in the row's INITIAL slot
-        # index, carried across compactions so surviving streams never change
-        uid_of_slot = list(range(B))
-        t = jnp.int32(0)
-        if self._compact_fn is None:
-            self._compact_fn = self._make_compact_fn()
-        compact = self._compact_fn
-
-        decode_s = 0.0
-        t_h = 0
-        while True:
-            t_seg = time.time()
-            t_seg_m = time.monotonic() if tracing else 0.0
-            segment = self._get_seg_fn("segment", B, S, max_new, gen)
-            # lint-allow[host-sync-in-hot-path]: host list -> host array for the uids argument, no device sync
-            uids_np = np.asarray(uid_of_slot, dtype=np.int32)
-            with annotate(f"decode_seg[B={B},S={S}]"):
-                t, cur, cache, done, out = segment(
-                    self.params, t, cur, cache, done, uids_np, out, pad_dev,
-                    seed,
-                )
-            # ONE explicit fetch for both control values: done gates the
-            # harvest/compaction decision and t bounds the budget — this
-            # sync IS the segment boundary (and makes its timing real)
-            # lint-allow[host-sync-in-hot-path]: segment-boundary done/t fetch is the scheduler's control dependency
-            done_h, t_h = jax.device_get((done, t))
-            t_h = int(t_h)
-            seg_s = time.time() - t_seg
-            decode_s += seg_s
-            # per-segment telemetry: the done fetch above already synced, so
-            # these are true device-step timings; kv_frac is the cache fill
-            # at segment end — the decode-attention byte budget driver. The
-            # mask reduction + kwargs are gated: untraced runs pay nothing
-            if tracing:
-                emit("decode_seg", t_seg_m, seg_s, B=B, S=S, steps=t_h,
-                     live=int((~done_h).sum()),
-                     kv_frac=round((S + t_h) / (S + max_new), 4))
-            live = [r for r, orig in enumerate(rows) if orig is not None]
-            active = [r for r in live if not done_h[r]]
-            if t_h >= max_new or not active:
-                break
-
-            # compact when the survivors fit a half-size program (under a
-            # mesh, only down to batches the data axis still divides)
-            data_size = (
-                self.mesh.shape.get("data", 1) if self.mesh is not None else 1
-            )
-            B_new = B
-            while (
-                B_new // 2 >= max(len(active), self.min_batch, 1)
-                and (B_new // 2) % data_size == 0
-            ):
-                B_new //= 2
-            if B_new < B:
-                # lint-allow[host-sync-in-hot-path]: harvesting finished rows' tokens before their slots are compacted away
-                out_h = jax.device_get(out)
-                for r in live:
-                    if done_h[r]:  # harvest leaving rows
-                        results[rows[r]] = self._detok(out_h[r], tuple(gen.eos_ids))
-                # pad the gather index with done slots (kept inert by done=True)
-                filler = [r for r in range(B) if r not in active]
-                idx = active + filler[: B_new - len(active)]
-                idx_dev = jnp.asarray(idx, dtype=jnp.int32)
-                cache, cur, done, out, pad_dev = compact(
-                    cache, cur, done, out, pad_dev, idx_dev
-                )
-                rows = [rows[r] if r in active else None for r in idx]
-                uid_of_slot = [uid_of_slot[r] for r in idx]
-                B = B_new
-                self.stats.compactions += 1
-                self.stats.compacted_batch_sizes.append(B_new)
-                logger.info(
-                    "compacted decode batch to B=%d (%d live, t=%d)",
-                    B, len(active), t_h,
-                )
-
-        if self.instrument:
-            self.stats.add_phase("decode", decode_s)
-            self.stats.dispatches.append(
-                {
-                    "B": B, "S": S, "steps": t_h,
-                    "prefill_s": round(prefill_s, 3),
-                    "decode_s": round(decode_s, 3),
-                }
-            )
-
-        # lint-allow[host-sync-in-hot-path]: final result fetch — the generation is over, detok needs the tokens
-        out_h = jax.device_get(out)
-        for r, orig in enumerate(rows):
-            if orig is not None and results[orig] is None:
-                results[orig] = self._detok(out_h[r], tuple(gen.eos_ids))
 
     # -- speculative decoding (reference-guided, vnsum_tpu.spec) ---------
 
@@ -1657,13 +1401,10 @@ class TpuBackend:
         t_pre_m = time.monotonic()
         with annotate(f"spec_prefill[B={B},S={S}]"):
             cur, cache, done = prefill(self.params, tokens, pads, seed)
-        if self.instrument:
-            # lint-allow[host-sync-in-hot-path]: instrument=True exists to bound prefill with exactly this sync
-            jax.device_get(done)
-            self.stats.add_phase("prefill", time.time() - t_pre)
+        # the dispatch is asynchronous: this bounds submission, not device time
         if tracing:
             emit("spec_prefill", t_pre_m, time.time() - t_pre, B=B, S=S,
-                 occupancy=len(group), synced=self.instrument)
+                 occupancy=len(group), synced=False)
         self.stats.batches += 1
         self.stats.by_bucket[(B, S)] = self.stats.by_bucket.get((B, S), 0) + 1
 
@@ -1679,7 +1420,6 @@ class TpuBackend:
         steps_live = np.zeros((B,), dtype=np.int64)
         # lint-allow[host-sync-in-hot-path]: prefill done mask seeds the host loop's exit condition
         prev_done = jax.device_get(done)
-        t_dec = time.time()
         while not prev_done.all():
             t_step = time.monotonic() if tracing else 0.0
             with annotate(f"spec_step[B={B},S={S},k={k}]"):
@@ -1704,8 +1444,6 @@ class TpuBackend:
                 emit("spec_step", t_step, time.monotonic() - t_step, B=B,
                      k=k, live=int((~prev_done).sum()),
                      drafted=int(nd_h.sum()), accepted=int(acc_h.sum()))
-        if self.instrument:
-            self.stats.add_phase("spec_decode", time.time() - t_dec)
         self.stats.spec_draft_tokens += int(drafted[: len(group)].sum())
         self.stats.spec_accepted_tokens += int(accepted[: len(group)].sum())
 
@@ -1885,8 +1623,8 @@ class TpuBackend:
     def _pack_group(self, group, encoded, max_new: int):
         """Pack one prompt group into a fixed-shape left-padded batch.
 
-        Shared by the one-shot and continuous paths — their greedy-parity
-        guarantee depends on identical bucketing and padding."""
+        Shared by the one-shot and spec paths and the choice scorer — their
+        greedy-parity guarantee depends on identical bucketing and padding."""
         t_pack = time.time()
         max_input = self.cfg.max_seq_len - max_new
         data_size = self.mesh.shape.get("data", 1) if self.mesh is not None else 1
@@ -2023,14 +1761,6 @@ class TpuBackend:
             order = sorted(range(len(encoded)), key=lambda i: len(encoded[i]))
         results: list[str | None] = [None] * len(encoded)
         t0 = time.time()
-        # the segmented path only pays off when the budget spans multiple
-        # segments (otherwise there is nothing to compact and the extra
-        # prefill/segment dispatches cost ~3% on a homogeneous batch).
-        # Sampling is compaction-safe: per-row counter-based keys (see
-        # _make_parts) make each row's stream independent of batch position
-        continuous = self.continuous and (
-            self.instrument or max_new > self.segment_tokens
-        )
         try:
             # sanitizer hook (analysis pkg): nullcontext in production;
             # under VNSUM_SANITIZERS=transfer any IMPLICIT device->host
@@ -2065,20 +1795,6 @@ class TpuBackend:
                     if resume is not None:
                         for row, i in enumerate(group):
                             cache_report[i] = resume[2][row]
-                    insert_cb = None
-                    if use_cache:
-                        def insert_cb(cache, _g=group, _p=pad_lens):
-                            self._cache_insert(
-                                cache, _g, encoded, matches, cache_hints, _p,
-                                tracing,
-                            )
-                    if continuous:
-                        self._run_group_continuous(
-                            group, encoded, max_new, gen, results, seed,
-                            packed=(tokens, pad_lens, B, S),
-                            resume=resume and resume[:2], insert_cb=insert_cb,
-                        )
-                        continue
                     K = resume[0] if resume else 0
                     fn = self._get_fn(B, S, max_new, gen, resume_from=K)
                     t_disp = time.monotonic() if tracing else 0.0
@@ -2105,8 +1821,11 @@ class TpuBackend:
                         self.stats.by_bucket.get((B, S), 0) + 1
                     )
                     self._count_prefill_blocks(pad_lens, S, S + max_new, K)
-                    if insert_cb is not None:
-                        insert_cb(final_cache)
+                    if use_cache:
+                        self._cache_insert(
+                            final_cache, group, encoded, matches,
+                            cache_hints, pad_lens, tracing,
+                        )
                     t_detok = time.monotonic() if tracing else 0.0
                     for row, i in enumerate(group):
                         results[i] = self._detok(out[row], tuple(gen.eos_ids))

@@ -10,10 +10,10 @@ Two modes:
 An optional latency model (``batch_overhead_s`` + ``per_prompt_s``) makes a
 generate() call sleep like a device dispatch: a fixed per-call cost plus a
 much smaller marginal per-row cost — the economics that make micro-batching
-win. The serving scheduler tests and scripts/bench_serving.py use it to
-measure batching effects hermetically; it defaults off so every existing
-test is unchanged. ``batch_sizes`` records the prompt count of each call
-(``calls`` flattens prompts, which hides batch boundaries).
+win. The serving scheduler tests use it to exercise batching effects
+hermetically; it defaults off so every existing test is unchanged.
+``batch_sizes`` records the prompt count of each call (``calls`` flattens
+prompts, which hides batch boundaries).
 
 Speculative-decoding plumbing (vnsum_tpu.spec) is mirrored synthetically:
 ``generate`` accepts per-prompt ``references`` (recorded in
@@ -30,8 +30,8 @@ device pool behind it. ``cache_hints`` bound insertion exactly like the
 engine, hit counts flow through ``take_cache_report()`` /
 ``cached_prefix_tokens()`` / ``prefix_cache_stats()``, and the optional
 ``per_token_s`` latency term scales the simulated prefill sleep with
-UNCACHED tokens only, so hermetic serving benches see real TTFT improvement
-from cache hits (scripts/bench_serving.py --shared-prefix arm).
+UNCACHED tokens only, so hermetic serving tests see a shorter simulated
+prefill on cache hits.
 """
 from __future__ import annotations
 
@@ -106,8 +106,7 @@ class FakeBackend:
         # slot loop pays only for the steps a segment actually runs. This
         # is the economics in-flight refill exploits, modeled symmetrically.
         self.per_step_s = per_step_s
-        # data-parallel replica model (the sharded-serving bench,
-        # scripts/bench_serving.py sharded phase): per-ROW marginal costs
+        # data-parallel replica model: per-ROW marginal costs
         # divide over replicas (rows spread across the data axis and run
         # concurrently) while per-dispatch overheads and per-STEP depth
         # costs don't — replication buys row throughput, not step latency.

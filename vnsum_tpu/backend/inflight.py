@@ -287,8 +287,8 @@ class TpuSlotLoop:
                     )
                 if pc is not None:
                     # prefix-cache insertion reads the join cache BEFORE the
-                    # adopt dispatch donates it (copies enter the stream
-                    # first, same ordering argument as the continuous path)
+                    # adopt dispatch donates it (the copies enter the stream
+                    # first)
                     b._cache_insert(
                         join_cache, list(range(len(take))), group_ids,
                         group_matches, group_hints, pad_lens, tracing,

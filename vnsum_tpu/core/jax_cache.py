@@ -15,7 +15,7 @@ Two rules, and no third:
   The path is part of the cache key, so a directory that moves never hits.
 
 Every device-touching entry point (TpuBackend, LongContextBackend,
-EmbeddingModel, Trainer, bench.py) calls :func:`enable_compilation_cache`
+EmbeddingModel, Trainer) calls :func:`enable_compilation_cache`
 before building programs.
 """
 from __future__ import annotations

@@ -77,9 +77,8 @@ class ServeRequestRecord:
     Shed requests never reach a batch and are counted per-reason in
     ServingStats.shed instead (their typed RequestShed carries the reason
     to the caller). The serving HTTP layer returns these inline with
-    responses and the load generator (scripts/bench_serving.py) aggregates
-    them, so the same fields serve live debugging and committed benchmark
-    evidence.
+    responses, so a load generator can aggregate the same fields that serve
+    live debugging.
 
     ``trace_id`` is the END-TO-END correlation id (vnsum_tpu.obs): the same
     string rides the X-Request-Id response header, the /debug/trace dump's

@@ -73,9 +73,9 @@ def sample_logits_rows(
 ) -> jax.Array:
     """Per-row-keyed sampling: row i draws only from keys[i], so a row's
     sampled stream is invariant to its position in the batch. This is what
-    lets the continuous scheduler compact a sampled batch mid-decode without
-    changing any surviving row's output (engine.py derives keys[i] from
-    (seed, row_uid, step) — counter-based, like per-request generators in
+    lets the slot loop seat a request in any row, beside any others, without
+    changing its output (engine.py derives keys[i] from (seed, row_uid,
+    step) — counter-based, like per-request generators in
     continuous-batching servers)."""
     if temperature <= 0.0:
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)

@@ -179,8 +179,8 @@ class Histogram:
         return lines
 
     def to_dict(self) -> dict:
-        """Snapshot for bench JSON: buckets plus derived p50/p95/p99 — the
-        quantiles BENCH_*.json files report instead of bare means."""
+        """Snapshot as JSON: buckets plus derived p50/p95/p99 — quantiles
+        to report instead of bare means."""
         return {
             "buckets": {
                 **{_fmt(ub): n for ub, n in zip(self.bounds, self.counts)},

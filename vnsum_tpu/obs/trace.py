@@ -206,10 +206,10 @@ class BatchTrace:
         self.events.append(Span(name, t0, dur, 0, args or None))
         # TTFT anchor: only a SYNC-BOUNDED prefill end qualifies. Backends
         # whose prefill call returns at async dispatch mark the event
-        # synced=False (TpuBackend without instrument=True) — anchoring on
-        # that would record near-zero prefill and poison the TTFT quantiles
-        # with queue-wait-only values. Absent flag = synchronous backend
-        # (FakeBackend's sleep, instrumented engine fetches).
+        # synced=False (TpuBackend's spec prefill) — anchoring on that would
+        # record near-zero prefill and poison the TTFT quantiles with
+        # queue-wait-only values. Absent flag = synchronous backend
+        # (FakeBackend's sleep).
         if (
             self.first_token_at is None
             and name in ("prefill", "spec_prefill")

@@ -78,7 +78,7 @@ class InflightScheduler(MicroBatchScheduler):
         # fused multi-step decode: the loop dispatches N on-device segments
         # per host round-trip, so joins, cancel/preempt polls, and stream
         # deltas run at the FUSED cadence — the TTFT/goodput trade knob
-        # (--fused-segments; bench_serving.py's fused phase sweeps it)
+        # (--fused-segments)
         self.fused_segments = max(int(fused_segments), 1)
         # preemption cap per request: a batch-tier request evicted this
         # many times becomes non-evictable — bounded interference instead

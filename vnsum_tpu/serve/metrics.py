@@ -10,8 +10,8 @@ Three consumption surfaces off one locked data structure:
 - snapshot() returns a core.results.ServingStats so run records and the
   serving benchmark embed the same numbers the scrape endpoint reports;
 - histograms_snapshot() exposes the bucket state with bucket-derived
-  p50/p95/p99, which `scripts/bench_serving.py` / `scripts/bench_spec_ab.py`
-  write into their BENCH_*.json instead of bare means.
+  p50/p95/p99, which `scripts/bench_spec_ab.py` writes into its record
+  instead of bare means.
 
 Metric registry: every exported metric is declared ONCE in the `_reg(...)`
 block below — rendering takes its HELP/TYPE text from the registry, and

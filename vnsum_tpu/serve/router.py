@@ -18,8 +18,7 @@ Routing — tenant-sticky with cache affinity::
 Rendezvous hashing ranks every worker per key, so a mark-down remaps only
 the dead worker's keys — the radix-cache hit rates that justify
 ``cache_hint`` routing survive both the split across workers and a
-failover (bench_serving's fleet phase holds the shared-prefix hit rate
-within 10% of single-process).
+failover.
 
 Health — probe loop with mark-down/mark-up hysteresis: every worker is
 probed on ``/readyz`` (routability: draining / browned-out / pre-replay

@@ -185,9 +185,8 @@ class MicroBatchScheduler:
         # cancelled by the sweep. None = disabled (library default; the
         # HTTP server arms it via --stream-idle-timeout-s)
         self.stream_idle_timeout_s: float | None = None
-        # bench-only A/B lever (scripts/bench_serving.py cancel phase):
-        # False skips the per-iteration cancel sweeps so the unused-path
-        # overhead is measurable against the same build. Never exposed as
+        # A/B lever: False skips the per-iteration cancel sweeps so the
+        # unused-path overhead is measurable against the same build. Never
         # an operator flag — cancellation is part of the serving contract
         self.cancellation_enabled = True
         self._closed = False
