@@ -413,3 +413,43 @@ def test_slot_count_must_divide_mesh_data_axis():
     b.mesh = FakeMesh()
     with pytest.raises(ValueError, match="divisible by the mesh data axis"):
         b.start_slot_loop(4)
+
+
+# -- a row's text ends at its cursor, not at its first pad id ---------------
+
+
+@pytest.mark.parametrize(
+    "row, t, text, counted",
+    [
+        # pad is an id the sampler can draw where the tokenizer's pad sits
+        # inside the decodable range (a trained BPE's id 0): drawn FIRST it
+        # used to empty the whole answer, drawn later to cut it short
+        ([0, 12, 401, 454, 0, 0, 0, 0], 4, "12 401 454", 3),
+        ([12, 0, 401, 454, 7, 0, 0, 0], 5, "12 401 454 7", 4),
+        # a terminator still ends the text, and is not part of it
+        ([12, 401, 2, 9, 0, 0, 0, 0], 4, "12 401", 4),
+        ([12, 401, 99, 9, 0, 0, 0, 0], 4, "12 401", 4),
+        # nothing emitted yet: nothing, whatever the buffer holds
+        ([0, 0, 0, 0, 0, 0, 0, 0], 0, "", 0),
+    ],
+)
+def test_row_text_is_cut_at_the_cursor_not_at_a_drawn_pad(row, t, text,
+                                                         counted):
+    from types import SimpleNamespace
+
+    from vnsum_tpu.backend.inflight import TpuSlotLoop
+
+    class Tok:
+        pad_id, bos_id, eos_id = 0, 1, 2
+
+        def decode(self, ids, skip_special_tokens=True):
+            return " ".join(str(i) for i in ids if i > 2)
+
+    stats = SimpleNamespace(generated_tokens=0)
+    loop = SimpleNamespace(
+        backend=SimpleNamespace(tok=Tok(), stats=stats),
+        gen=SimpleNamespace(eos_ids=(99,)),
+    )
+    got = TpuSlotLoop._row_text(loop, np.asarray(row, np.int32), t)
+    assert got == text
+    assert stats.generated_tokens == counted

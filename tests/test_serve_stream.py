@@ -112,6 +112,38 @@ def test_channel_detach_supersedes_stale_consumer():
     assert ch.pop(0.01, gen2)[1]["text"] == " hai"
 
 
+def test_channel_wake_cuts_an_empty_poll_short():
+    """The HTTP layer wakes the channel when the request's future
+    resolves: the handler is back inside an empty pop by then (the last
+    delta is pushed before the future resolves), and the terminal event
+    must not wait out the poll interval."""
+    import time
+
+    ch = StreamChannel("r4w")
+    # woken between pops: the next empty pop returns at once, once
+    ch.wake()
+    t0 = time.monotonic()
+    assert ch.pop(5.0) is None
+    assert time.monotonic() - t0 < 1.0
+    # woken inside a pop
+    got = []
+    t = threading.Thread(target=lambda: got.append(ch.pop(30.0)))
+    t.start()
+    time.sleep(0.05)
+    ch.wake()
+    t.join(timeout=5)
+    assert not t.is_alive() and got == [None]
+    # an event still comes before the wake's None
+    ch.push_text("mot")
+    ch.wake()
+    assert ch.pop(0.01)[0] == "delta"
+    assert ch.pop(5.0) is None
+    # and the flag does not stick: the next empty pop runs its timeout
+    t0 = time.monotonic()
+    assert ch.pop(0.05) is None
+    assert time.monotonic() - t0 >= 0.045
+
+
 def test_channel_resume_snapshot_folds_buffered_deltas():
     ch = StreamChannel("r5")
     ch.push_text("mot")

@@ -41,7 +41,7 @@ from ..analysis.sanitizers import hot_path_transfer_guard
 from ..core.logging import get_logger
 from ..obs.trace import current_collector, emit
 from ..testing.faults import fault
-from .base import left_pad_batch
+from .base import left_pad_batch, trim_to_eos
 
 # jax is imported lazily (TpuSlotLoop.__init__): the shared record types
 # below also serve FakeBackend's hermetic slot loop, which must not pay a
@@ -444,7 +444,7 @@ class TpuSlotLoop:
                 self._t_host[s] = int(t_h[s])
         self._out_snap = out_h
         for s in finished:
-            text = b._detok(out_h[s], tuple(self.gen.eos_ids))
+            text = self._row_text(out_h[s], int(t_h[s]))
             res.completions.append(SlotCompletion(
                 key=self._keys[s], text=text, slot=s,
                 gen_tokens=int(t_h[s]),
@@ -523,13 +523,25 @@ class TpuSlotLoop:
 
             # lint-allow[host-sync-in-hot-path]: cold fallback off the boundary cadence (post-admit, pre-step); the hot path serves the coalesced snapshot above
             out_h = jax.device_get(self._out)
-        eos = tuple(self.gen.eos_ids)
         return {
-            id(self._keys[s]): self.backend._detok(
-                out_h[s][: int(self._t_host[s])], eos
-            )
+            id(self._keys[s]): self._row_text(out_h[s], int(self._t_host[s]))
             for s in rows
         }
+
+    def _row_text(self, row: np.ndarray, t: int) -> str:
+        """A row's first ``t`` emitted ids as text, cut after a terminator.
+        The row's own cursor says where it ends, not its first pad id: pad
+        is an id like any other to the sampler (one draw in the decodable
+        vocabulary), and cutting at the first one emptied an answer whose
+        first token it was and shortened any other. The tokenizer drops a
+        drawn pad as it drops every special id."""
+        b = self.backend
+        emitted = row[:t]
+        b.stats.generated_tokens += int((emitted != b.tok.pad_id).sum())
+        ids = trim_to_eos(
+            emitted.tolist(), b.tok.eos_id, -1, tuple(self.gen.eos_ids)
+        )
+        return b.tok.decode(ids).strip()
 
     # -- lifecycle -------------------------------------------------------
 

@@ -159,6 +159,12 @@ class ServingStats:
     # segment boundary (0 for the batch-dispatch scheduler)
     segments: int = 0
     refills: int = 0
+    # the idle loop's coalescing window (RequestQueue.take_upto): takes
+    # that held it open, followers that arrived inside one and joined with
+    # its head, and the seconds held
+    windows: int = 0
+    window_joined: int = 0
+    window_wait_seconds: float = 0.0
     # fused multi-step decode: host dispatches of the slot loop (each
     # covers up to --fused-segments on-device segments; == segments at N=1)
     fused_dispatches: int = 0

@@ -15,6 +15,17 @@ strangers' decode.
 
 Policy notes:
 
+- **coalescing**: while rows decode, the segment cadence coalesces —
+  whatever arrived during a segment joins together at its boundary, and the
+  take there never waits (a wait would stall the resident rows). An IDLE
+  loop has no cadence, so its take holds the coalescing window
+  ``max_wait_s`` (the batch scheduler's knob and meaning: a lone request
+  waits about that long) before the join that follows: it ends the moment
+  the free slots are full, stays open while requests keep arriving or are
+  still being tokenized, and for as many followers as rows finished at the
+  boundary just passed, under a hard cap (``queue.COALESCE_CAP_S``).
+  Without it a burst into an idle loop splits: its first request gets a
+  join and a segment to itself and the rest wait out both.
 - **compatibility**: a loop serves ONE batch key (max_new_tokens +
   GenerationConfig — the same coalescing rule as batch dispatch). Requests
   with other keys wait; compatible later arrivals may leapfrog them into
@@ -51,6 +62,10 @@ from .queue import ServeRequest, ShedReason
 from .scheduler import MicroBatchScheduler, _Completion
 
 logger = get_logger("vnsum.serve.inflight")
+
+# how long an idle loop blocks in one queue wait before it comes round
+# again (heartbeat, cancel sweep, stale-thread check)
+IDLE_POLL_S = 0.05
 
 
 class InflightScheduler(MicroBatchScheduler):
@@ -98,6 +113,10 @@ class InflightScheduler(MicroBatchScheduler):
         # taken-but-not-yet-admitted requests (scheduler-thread state; an
         # instance attribute so close() can shed them on drain overrun)
         self._pending: list[ServeRequest] = []
+        # rows that completed at the segment boundary just passed
+        # (scheduler-thread state): their clients are presumably on their
+        # way back, so the next IDLE take's window expects that many
+        self._just_finished = 0
         super().__init__(backend, **kw)
 
     # -- scrape surface ---------------------------------------------------
@@ -315,11 +334,18 @@ class InflightScheduler(MicroBatchScheduler):
         return stranded
 
     def _take(self, loop, loop_key, active: int):
-        """One queue interaction: blocking for the head when idle,
-        non-blocking slot-feeding when decoding."""
+        """One queue interaction. Idle (no row decodes): block for the
+        head, then hold the coalescing window ``max_wait_s`` for company
+        before the join — it closes the moment the free slots are full,
+        and the rows that finished at the boundary just passed tell the
+        queue how many followers to expect. Decoding: non-blocking
+        slot-feeding, no wait — the segment cadence coalesces there, and
+        a wait would stall the resident rows."""
         if not active:
+            expect, self._just_finished = self._just_finished, 0
             return self.queue.take_upto(
-                self._take_limit(), wait_s=max(self.max_wait_s, 0.05)
+                self._take_limit(), wait_s=max(self.max_wait_s, IDLE_POLL_S),
+                window_s=self.max_wait_s, expect=expect,
             )
         if loop is None or not loop.free:
             return []
@@ -650,6 +676,7 @@ class InflightScheduler(MicroBatchScheduler):
             device_segments=getattr(res, "device_segments", 1),
         )
         now = time.monotonic()
+        self._just_finished = len(res.completions)
         self._emit_stream_deltas(loop)
         for c in res.completions:
             r: ServeRequest = c.key

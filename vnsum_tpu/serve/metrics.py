@@ -95,6 +95,14 @@ _reg("inflight_segments_total", "counter",
      "decode segments dispatched by the in-flight slot loop")
 _reg("inflight_refills_total", "counter",
      "requests admitted into a running decode batch at a segment boundary")
+_reg("inflight_windows_total", "counter",
+     "takes by an idle slot loop that held the coalescing window open "
+     "before a join (a take that was full or alone at once holds none)")
+_reg("inflight_window_joined_total", "counter",
+     "requests that arrived inside a held coalescing window and joined "
+     "in the same take as its head")
+_reg("inflight_window_wait_seconds_total", "counter",
+     "seconds idle slot loops held coalescing windows open, anchor to take")
 _reg("inflight_fused_dispatches_total", "counter",
      "fused slot-loop dispatches by the in-flight scheduler (each covers "
      "up to --fused-segments on-device decode segments; equals "
@@ -484,6 +492,15 @@ class ServeMetrics:
         with self._lock:
             self._stats.refills += n
 
+    def observe_window(self, held_s: float, joined: int) -> None:
+        """One coalescing window an idle slot loop held before a join
+        (the queue's on_window hook): how long, and how many followers it
+        caught."""
+        with self._lock:
+            self._stats.windows += 1
+            self._stats.window_joined += joined
+            self._stats.window_wait_seconds += held_s
+
     # -- fault-tolerance hooks (serve/supervisor.py consumers) -----------
 
     def observe_failure(self, failure_class: str) -> None:
@@ -835,6 +852,10 @@ class ServeMetrics:
         simple("cache_hit_rate", round(s.cache_hit_rate, 6))
         simple("inflight_segments_total", s.segments)
         simple("inflight_refills_total", s.refills)
+        simple("inflight_windows_total", s.windows)
+        simple("inflight_window_joined_total", s.window_joined)
+        simple("inflight_window_wait_seconds_total",
+               round(s.window_wait_seconds, 6))
         simple("inflight_fused_dispatches_total", s.fused_dispatches)
         typ, help_ = _METRICS["fault_failures_total"]
         lines.append(f"# HELP {_PREFIX}fault_failures_total {help_}")

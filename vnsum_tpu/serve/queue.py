@@ -17,6 +17,7 @@ owns the synchronization.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -79,6 +80,16 @@ class RequestCancelled(RuntimeError):
 
 
 _ids = itertools.count()
+
+# hard cap on the coalescing window an idle slot loop holds before a join
+# (take_upto): whatever keeps the window open — arrivals, requests being
+# tokenized, rows that have just finished — a waiting request is taken this
+# long after the window's anchor at the latest (or ``window_s`` after it,
+# where that is longer). A constant, not an option: 2% of the ~2.4 s an
+# 8k-token join and its segment take, what the idle poll already waits
+# between wake-ups, and 13 ms more than the widest spread of one boundary's
+# followers measured on the chip (PERF.md section 6, PR 32)
+COALESCE_CAP_S = 0.05
 
 
 @dataclass
@@ -220,6 +231,9 @@ class RequestQueue:
         self._items: list[ServeRequest] = []    # guarded by: _cond, _lock
         self._queued_tokens = 0                 # guarded by: _cond, _lock
         self._closed = False                    # guarded by: _cond, _lock
+        # requests a submitter has announced but not yet enqueued (they
+        # are being tokenized on their handler threads): see arriving()
+        self._arriving = 0                      # guarded by: _cond, _lock
         self.on_shed = None  # callable(req, ShedReason) | None — metrics hook
         # called under the queue lock BEFORE the scheduler can take the
         # request: counting the admit here means no scrape window where a
@@ -230,6 +244,11 @@ class RequestQueue:
         # the scheduler counts multi-row takes that landed one gang
         # together. Must be cheap and lock-free like on_admit
         self.on_take = None  # callable(list[req]) | None — metrics hook
+        # called under the queue lock when a take_upto that held its
+        # coalescing window open commits: the seconds from the window's
+        # anchor to the take, and how many of the taken requests arrived
+        # inside it. Cheap: one leaf lock at most, like on_admit
+        self.on_window = None  # callable(held_s, joined) | None — metrics hook
         # gang-affinity pick (serve/gang.py): when an over-full take must
         # choose, cluster the head's gang first so fan-out siblings ride
         # one slot generation and share their template-header prefix in
@@ -250,6 +269,23 @@ class RequestQueue:
         self.heartbeat = None
 
     # -- producer side ---------------------------------------------------
+
+    @contextlib.contextmanager
+    def arriving(self):
+        """Announce a request that is on its way into the queue: the
+        submitter holds this around the work it does before ``submit``
+        (counting an 8k-token prompt's tokens takes 15-30 ms, PERF.md
+        section 6, PR 32). take_upto's coalescing window does not close on
+        a quiet gap while a request is announced, so the members of one
+        burst join together however long each takes to tokenize."""
+        with self._lock:
+            self._arriving += 1
+        try:
+            yield
+        finally:
+            with self._cond:
+                self._arriving -= 1
+                self._cond.notify_all()
 
     def submit(self, req: ServeRequest, *, force: bool = False) -> Future:
         """Admit or shed. Sheds raise RequestShed SYNCHRONOUSLY (the caller
@@ -465,7 +501,8 @@ class RequestQueue:
                 self._cond.wait(timeout=max(flush_at - now, 0.001))
 
     def take_upto(
-        self, max_take: int, key: tuple | None = None, wait_s: float = 0.0
+        self, max_take: int, key: tuple | None = None, wait_s: float = 0.0,
+        window_s: float = 0.0, expect: int = 0,
     ) -> list[ServeRequest] | None:
         """Slot-feeding take for the in-flight scheduler: up to ``max_take``
         requests compatible with ``key`` (None = the head-of-line request's
@@ -474,15 +511,38 @@ class RequestQueue:
         tokens leave the queue budget when its slot is taken, not when a
         whole batch flushes.
 
-        Unlike take_batch there is no coalescing window — the decode
-        segment cadence provides natural coalescing — but a positive
-        ``wait_s`` blocks up to that long for the FIRST compatible request
-        (the idle-loop case). Returns [] when nothing compatible arrived in
-        time, and None when the queue is closed and drained (the caller's
-        exit signal). Expired requests are shed on every wake-up."""
+        A positive ``wait_s`` blocks up to that long for the FIRST
+        compatible request. What happens once one is there depends on who
+        is asking. A loop that is DECODING passes no ``window_s``: the
+        take returns at once with whatever is compatible, because the
+        segment cadence coalesces for it (arrivals of one segment join at
+        its boundary) and a wait would stall resident rows. An IDLE loop
+        has no cadence, so it passes ``window_s`` and the take holds
+        take_batch's coalescing window before the join that follows:
+
+        - it returns the moment ``max_take`` compatible requests wait, the
+          queue closes, or a compatible request's deadline falls inside
+          the window (a taken request is never made to wait past it);
+        - otherwise it returns ``window_s`` after max(head arrival, this
+          call's entry) — a lone request at an idle server waits about
+          ``window_s``, and a backlog older than any window still leaves
+          one open for the requests the last answers unblocked;
+        - each arrival inside the window keeps it open for ``window_s``
+          more, and so does a request that is announced (``arriving``):
+          only a quiet gap ends it. It also stays open while fewer than
+          ``expect`` requests have arrived since this call's entry — the
+          caller passes the rows that finished at the boundary just
+          passed, whose clients are presumably on their way back. All of
+          that under the hard cap COALESCE_CAP_S after the anchor.
+
+        Returns [] when nothing compatible arrived in time, and None when
+        the queue is closed and drained (the caller's exit signal). Expired
+        requests are shed on every wake-up."""
         if max_take < 1:
             return []
-        t_end = time.monotonic() + wait_s
+        t_enter = time.monotonic()
+        t_end = t_enter + wait_s
+        held = False  # this call waited with a compatible request in hand
         with self._cond:
             while True:
                 if self.heartbeat is not None:
@@ -493,12 +553,44 @@ class RequestQueue:
                     k = key if key is not None else self._items[0].batch_key()
                     compat = self._compat_locked(k, max_take)
                     if compat:
-                        return self._take_locked(compat, max_take)
+                        anchor = max(compat[0].enqueued_at, t_enter)
+                        fresh = sum(r.enqueued_at > t_enter for r in compat)
+                        flush_at = self._window_end_locked(
+                            compat, max_take, anchor, now, window_s,
+                            fresh < expect)
+                        if now >= flush_at:
+                            batch = self._take_locked(compat, max_take)
+                            if held and self.on_window is not None:
+                                self.on_window(
+                                    now - anchor,
+                                    sum(r.enqueued_at > anchor for r in batch),
+                                )
+                            return batch
+                        held = True
+                        self._cond.wait(timeout=max(flush_at - now, 0.001))
+                        continue
                 elif self._closed:
                     return None
                 if now >= t_end:
                     return []
                 self._cond.wait(timeout=max(t_end - now, 0.001))
+
+    def _window_end_locked(self, compat: list[ServeRequest], max_take: int,
+                           anchor: float, now: float, window_s: float,
+                           followers_due: bool) -> float:
+        """When take_upto's coalescing window over ``compat`` closes
+        (monotonic seconds; 0.0 = it is closed, take now)."""
+        if window_s <= 0 or len(compat) >= max_take or self._closed:
+            return 0.0
+        flush_at = anchor + max(window_s, COALESCE_CAP_S)  # the hard cap
+        if not followers_due:
+            quiet_from = now if self._arriving else max(
+                anchor, max(r.enqueued_at for r in compat))
+            flush_at = min(quiet_from + window_s, flush_at)
+        if any(r.deadline is not None and r.deadline <= flush_at
+               for r in compat):
+            return 0.0
+        return flush_at
 
     # -- lifecycle / introspection ---------------------------------------
 

@@ -157,6 +157,7 @@ class MicroBatchScheduler:
         self.queue.on_shed = self._on_shed
         self.queue.on_admit = self._on_admit
         self.queue.on_take = self._on_take
+        self.queue.on_window = self.metrics.observe_window
         # structured jobs (serve/gang.py): gang admission, membership
         # journaling, and degraded-result marking. Always constructed —
         # gang bookkeeping is part of the serving contract; the bench A/B
@@ -278,43 +279,47 @@ class MicroBatchScheduler:
         into one slot generation, the preemption path evicts the group
         whole, and the member joins its gang's journal record at the next
         round flush."""
-        req = ServeRequest(
-            prompt=prompt,
-            max_new_tokens=max_new_tokens,
-            config=config,
-            reference=reference,
-            cache_hint=cache_hint,
-            deadline=deadline,
-            est_tokens=self.backend.count_tokens(prompt),
-            trace_id=trace_id or "",
-            journal_rid=journal_rid,
-            tenant=tenant,
-            tier=tier,
-            stream=stream,
-            gang_id=gang,
-            gang_phase=gang_phase,
-        )
-        # admission discount: only probed when a token budget exists — the
-        # probe re-tokenizes the prompt (a second pass on top of
-        # count_tokens above; acceptable because the path is opt-in and a
-        # cache-less backend short-circuits before encoding anything)
-        if self.queue.max_queued_tokens:
-            probe = getattr(self.backend, "cached_prefix_tokens", None)
-            if callable(probe):
-                req.cached_tokens = min(
-                    probe(prompt, cache_hint), req.est_tokens
-                )
-        if trace is not None:
-            req.trace = trace
-            req.trace_track = trace.next_track()
-        elif not trace_owned and self.obs is not None:
-            t = self.obs.start_request(req.trace_id)
-            if t is not None:
-                req.trace, req.own_trace = t, True
-                req.trace_track = t.next_track()
-        # the admit is counted by the queue's on_admit hook, under the queue
-        # lock, so metrics can never show a completion before its submit
-        fut = self.queue.submit(req, force=internal)  # raises RequestShed
+        # announced from before the prompt is tokenized until it is queued:
+        # an idle slot loop's coalescing window stays open meanwhile
+        with self.queue.arriving():
+            req = ServeRequest(
+                prompt=prompt,
+                max_new_tokens=max_new_tokens,
+                config=config,
+                reference=reference,
+                cache_hint=cache_hint,
+                deadline=deadline,
+                est_tokens=self.backend.count_tokens(prompt),
+                trace_id=trace_id or "",
+                journal_rid=journal_rid,
+                tenant=tenant,
+                tier=tier,
+                stream=stream,
+                gang_id=gang,
+                gang_phase=gang_phase,
+            )
+            # admission discount: only probed when a token budget exists —
+            # the probe re-tokenizes the prompt (a second pass on top of
+            # count_tokens above; acceptable because the path is opt-in and
+            # a cache-less backend short-circuits before encoding anything)
+            if self.queue.max_queued_tokens:
+                probe = getattr(self.backend, "cached_prefix_tokens", None)
+                if callable(probe):
+                    req.cached_tokens = min(
+                        probe(prompt, cache_hint), req.est_tokens
+                    )
+            if trace is not None:
+                req.trace = trace
+                req.trace_track = trace.next_track()
+            elif not trace_owned and self.obs is not None:
+                t = self.obs.start_request(req.trace_id)
+                if t is not None:
+                    req.trace, req.own_trace = t, True
+                    req.trace_track = t.next_track()
+            # the admit is counted by the queue's on_admit hook, under the
+            # queue lock, so metrics can never show a completion before its
+            # submit
+            fut = self.queue.submit(req, force=internal)  # raises RequestShed
         if gang:
             # AFTER admission: the queue's on_admit hook just assigned the
             # ledger id (journal.accept), so the membership note carries it;

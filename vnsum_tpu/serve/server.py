@@ -1530,6 +1530,10 @@ def make_handler(state: ServeState):
                     state.obs.finish_request(_trace, status)
 
                 fut.add_done_callback(_finalize_trace)
+            # the drain loop re-checks fut.done() between empty pops: end
+            # the pop in flight when the future resolves, so the terminal
+            # event leaves at once and not a poll interval later
+            fut.add_done_callback(lambda _f: channel.wake())
             state.streams.register(self._rid, channel, fut)
             gen = channel.attach()
             outcome = self._stream_response(
@@ -1835,7 +1839,14 @@ def main(argv: list[str] | None = None) -> int:
                         "model's max_seq_len — small configs like --model "
                         "tiny need this lowered)")
     p.add_argument("--max-wait-ms", type=float, default=10.0,
-                   help="max time a head-of-line request waits for company")
+                   help="max time a head-of-line request waits for company. "
+                        "With --inflight it is the coalescing window an IDLE "
+                        "slot loop holds before a join: a lone request waits "
+                        "about this long, each arrival (or request still "
+                        "being tokenized) keeps the window open this much "
+                        "longer, a full set of slots ends it at once, and "
+                        "50 ms after the first request it ends whatever "
+                        "happens; a loop that is decoding never waits")
     p.add_argument("--mesh", default=None,
                    help='multi-chip serving mesh spec, e.g. "data=2,model=4"'
                         " (tpu backend only): shards the engine's decode/"

@@ -78,6 +78,7 @@ class StreamChannel:
         self._sent = ""               # guarded by: _cond, _lock
         self._closed = False          # guarded by: _cond, _lock
         self._gen = 0                 # guarded by: _cond, _lock
+        self._woken = False           # guarded by: _cond, _lock
         self.events_pushed = 0
         self.coalesced = 0
         # idle-consumer clock: refreshed by every pop/attach; read lock-free
@@ -168,6 +169,19 @@ class StreamChannel:
         with self._cond:
             self._append_locked(kind, dict(payload))
 
+    def wake(self) -> None:
+        """Cut the consumer's empty poll short: its pending pop — or, if it
+        is between pops, its next one — returns None at once instead of
+        after the timeout. The HTTP layer hangs this on the request
+        future's resolution: the last delta is pushed BEFORE the future
+        resolves, so a handler that has just written it is back inside an
+        empty pop when the future turns done, and without the wake the
+        terminal event (and the client's next request) waited out the
+        whole poll interval."""
+        with self._cond:
+            self._woken = True
+            self._cond.notify_all()
+
     # -- consumer side (HTTP handler thread) ------------------------------
 
     def pop(self, timeout_s: float,
@@ -184,6 +198,9 @@ class StreamChannel:
                     raise StreamDetached(self.request_id)
                 if self._q:
                     return self._q.popleft()
+                if self._woken:
+                    self._woken = False
+                    return None
                 remaining = t_end - time.monotonic()
                 if remaining <= 0:
                     return None
