@@ -45,11 +45,12 @@ import traceback
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
-PHASES = ("device", "kernels", "offline", "serve", "mesh")
+PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
+    "experts": 420,
 }
 
 
@@ -68,6 +69,9 @@ def sizes(rehearsal: bool) -> dict:
             serve_prefix_bytes=120, serve_doc_bytes=26_000,
             mesh_prompt_bytes=200, mesh_batch=8, mesh_max_new=4,
             mesh_seq=512,
+            experts_seq=328, experts_batch=4, experts_max_new=8,
+            experts_prefill_chunk=128, experts_prompt_bytes=250,
+            experts_parity=(150, 256, 4),
         )
     return dict(
         kernel_geometries=None,  # derived from MODEL_REGISTRY
@@ -86,6 +90,10 @@ def sizes(rehearsal: bool) -> dict:
         serve_prefix_bytes=6_000, serve_doc_bytes=26_000,
         mesh_prompt_bytes=3_500, mesh_batch=8, mesh_max_new=32,
         mesh_seq=4352,
+        # experts: one dispatch of 8 rows in the S=2048 bucket, two chunks
+        experts_seq=2112, experts_batch=8, experts_max_new=64,
+        experts_prefill_chunk=1024, experts_prompt_bytes=5_500,
+        experts_parity=(1500, 2048, 4),   # prompt tokens, bucket, steps
     )
 
 
@@ -627,6 +635,92 @@ def phase_mesh(args) -> dict:
     return {**c.report(), "runs": runs}
 
 
+def phase_experts(args) -> dict:
+    """The DeepSeek-V2 family on the one-shot path: latent attention with
+    an absorbed decode, sparse experts of which this chip holds 40 of 160,
+    shared experts — at the published widths with one dense and two expert
+    layers, int8, through ``TpuBackend.generate``; and its logits against
+    the plain reference (prefill and decode steps)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import reference_deepseek_v2 as reference
+    from benchmarks.engine_setup_deepseek_v2 import sizes_from
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models import jitted_init
+    from vnsum_tpu.models.deepseek import deepseek_v2, tiny_deepseek
+    from vnsum_tpu.models.quant import init_params_quantized
+
+    sz = sizes(args.rehearsal)
+    c = Checks()
+    if args.rehearsal:
+        cfg = tiny_deepseek(experts_held=8, max_seq_len=sz["experts_seq"])
+    else:
+        cfg = deepseek_v2(n_layers=3, experts_held=40,
+                          max_seq_len=sz["experts_seq"])
+    params = jitted_init(init_params_quantized, cfg, 3)
+    backend = TpuBackend(
+        model_config=cfg, tokenizer="byte", params=params,
+        batch_size=sz["experts_batch"], max_new_tokens=sz["experts_max_new"],
+        quantize=True, quantize_act=True, quantize_kv=False,
+        prefill_chunk_tokens=sz["experts_prefill_chunk"],
+        generation=GenerationConfig(temperature=1.0, seed=3),
+        interpret=args.rehearsal)
+    prompts = [_vn_text(sz["experts_prompt_bytes"] - 300 * i, f"e{i}")
+               for i in range(sz["experts_batch"])]
+    t0 = time.time()
+    outs = backend.generate(prompts)
+    first_s = time.time() - t0
+    t0 = time.time()
+    outs = backend.generate(prompts)
+    second_s = time.time() - t0
+    st = backend.stats
+    paths = st.attention_paths
+    c.check("attention paths are the kernels", bool(paths) and all(
+        p == "kernel" for prog in paths.values() for p in prog.values()),
+        paths)
+    c.check("every row answered", len(outs) == len(prompts)
+            and sum(bool(o) for o in outs) >= len(outs) - 1)
+    k, layers = cfg.num_experts_per_tok, cfg.n_expert_layers
+    # both calls count: prompt tokens go through prefill, and every row runs
+    # every decode step of the budget (no extra EOS here, but a sampled EOS
+    # ends nothing early for the others), so at least the prompts' tokens
+    c.check("slots routed cover the prompts' tokens",
+            st.expert_slots_routed >= st.prompt_tokens * k * layers,
+            (st.expert_slots_routed, st.prompt_tokens * k * layers))
+    share = st.expert_slots_held / max(st.expert_slots_routed, 1)
+    want = cfg.n_held / cfg.n_routed_experts
+    c.check("held share near experts held / experts routed",
+            0.4 * want <= share <= 1.8 * want, (share, want))
+    tokens = np.asarray(st.expert_tokens)
+    c.check("expert_tokens add up to slots held",
+            tokens.shape == (layers, cfg.n_held)
+            and int(tokens.sum()) == st.expert_slots_held, tokens.shape)
+
+    n, bucket, steps = sz["experts_parity"]
+    ids = backend.tok.encode(_vn_text((n + steps) * 3, "parity"))[:n + steps]
+    got = np.asarray(backend.prefill_then_decode_logits(
+        ids[:n], ids[n:], bucket=bucket), np.float64)
+    sizes_ref = sizes_from(cfg)
+    want_l = np.asarray(jax.jit(lambda p, t: reference.logits(
+        p, t, sizes_ref, expert_offset=cfg.expert_offset, last=steps + 1))(
+        backend.params, jnp.asarray(ids, jnp.int32)), np.float64)
+    errors = (np.linalg.norm(got - want_l, axis=-1)
+              / np.linalg.norm(want_l, axis=-1))
+    c.check("logits within 0.05 of the plain reference, prefill and decode",
+            bool(np.all(np.isfinite(errors)) and errors.max() <= 0.05),
+            errors.tolist())
+    rep = c.report()
+    rep.update(first_call_s=round(first_s, 2),
+               second_call_s=round(second_s, 2),
+               parity_errors=errors.tolist(), held_share=share,
+               expert_tokens=tokens.tolist(),
+               engine=backend.describe())
+    return rep
+
+
 def _rehearsal_server(argv: list[str]) -> int:
     """The rehearsal's server child: the real serve.server.main, with the
     engine's kernels emulated (the product has no such flag, on purpose)."""
@@ -652,7 +746,8 @@ def _child(args) -> int:
     try:
         compiles = _watch_compiles()
         rep.update({"device": phase_device, "kernels": phase_kernels,
-                    "offline": phase_offline, "mesh": phase_mesh}[phase](args))
+                    "offline": phase_offline, "mesh": phase_mesh,
+                    "experts": phase_experts}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

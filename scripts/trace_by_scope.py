@@ -20,7 +20,9 @@ under its scope, and per module what fell under no scope, by operation.
 Scope names (none carries a shape or a number, so cells compare):
 phases ``prefill`` ``decode`` ``adopt`` (backend/engine.py); components
 ``embed`` ``qkv`` ``kv_write`` ``attn`` ``attn_out`` ``mlp`` ``lm_head``
-(models/llama.py) and ``sample`` ``emit`` (backend/engine.py).
+(models/llama.py), ``q_lora`` ``kv_latent`` ``router`` ``experts``
+``shared_experts`` beside them (models/deepseek.py) and ``sample`` ``emit``
+(backend/engine.py).
 """
 from __future__ import annotations
 

@@ -1,0 +1,411 @@
+"""The DeepSeek-V2 family on the CPU at tiny sizes: routing, the three
+kernels (interpreted) against the dense formulations, the engine's one-shot
+program through the family seam, the expert counters, the int8 layout of
+stacked experts, and the entries that refuse the family by name."""
+from __future__ import annotations
+
+import dataclasses
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from vnsum_tpu.backend.engine import TpuBackend
+from vnsum_tpu.models import MODEL_REGISTRY
+from vnsum_tpu.models import deepseek as ds
+from vnsum_tpu.models.family import family_of
+from vnsum_tpu.models.quant import (
+    dequantize_params,
+    init_params_quantized,
+    is_quantized,
+    quantize_params,
+)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = ds.tiny_deepseek()
+    return cfg, ds.init_params(jax.random.key(0), cfg)
+
+
+# -- the config and the registry ---------------------------------------------
+
+
+def test_registry_holds_the_uncut_published_model():
+    cfg = MODEL_REGISTRY["deepseek-v2"]()
+    assert (cfg.n_layers, cfg.dim, cfg.n_heads, cfg.vocab_size) == (
+        60, 5120, 128, 102_400)
+    assert (cfg.kv_lora_rank, cfg.qk_rope_head_dim, cfg.latent_width) == (
+        512, 64, 576)
+    assert (cfg.n_routed_experts, cfg.n_held, cfg.num_experts_per_tok) == (
+        160, 160, 6)
+    assert family_of(cfg).name == "deepseek-v2"
+    assert family_of(MODEL_REGISTRY["qwen3-8b"]()).name == "llama"
+
+
+def test_softmax_scale_carries_yarns_m_squared():
+    cfg = ds.deepseek_v2()
+    m = 0.1 * 0.707 * math.log(40.0) + 1.0
+    assert cfg.softmax_scale == pytest.approx(192 ** -0.5 * m * m)
+    assert m * m == pytest.approx(1.5896, abs=1e-3)
+
+
+def test_yarn_frequencies_blend_between_the_correction_dimensions():
+    """Hand-worked for the published values: dim 64, base 10000, original
+    4096: correction dims floor(10.29) = 10 (32 rotations) and ceil(22.34)
+    = 23 (one rotation). Pairs below 10 keep their frequency, pairs from 23
+    on are divided by 40, pair 16 is 6/13 of the way."""
+    cfg = ds.deepseek_v2()
+    inv = np.asarray(ds.yarn_inv_freq(cfg))
+    plain = 1.0 / 10000.0 ** (np.arange(0, 64, 2) / 64)
+    np.testing.assert_allclose(inv[:11], plain[:11], rtol=1e-6)
+    np.testing.assert_allclose(inv[23:], plain[23:] / 40.0, rtol=1e-6)
+    ramp = 6 / 13
+    np.testing.assert_allclose(
+        inv[16], plain[16] / 40.0 * ramp + plain[16] * (1 - ramp), rtol=1e-6)
+
+
+def test_a_share_past_the_last_expert_is_refused():
+    with pytest.raises(ValueError, match="past n_routed_experts"):
+        ds.tiny_deepseek(expert_offset=12, experts_held=8)
+
+
+# -- routing -----------------------------------------------------------------
+
+
+def test_router_drops_the_picks_of_the_fourth_group():
+    """8 groups of 2, keep 3 groups, pick 6: a token whose six largest
+    scores lie in groups 0, 1, 2 and 3 (the fourth by its best member)
+    loses the one in group 3 to the next best inside the kept groups."""
+    cfg = ds.tiny_deepseek(n_routed_experts=16, n_group=8, topk_group=3,
+                           num_experts_per_tok=6, routed_scaling_factor=16.0)
+    scores = np.full((1, 16), 0.001, np.float32)
+    # group g holds experts 2g, 2g + 1
+    scores[0, [0, 1]] = [0.20, 0.15]      # group 0
+    scores[0, [2, 3]] = [0.18, 0.02]      # group 1
+    scores[0, [4, 5]] = [0.16, 0.01]      # group 2
+    scores[0, [6, 7]] = [0.14, 0.003]     # group 3: fourth by its best
+    # the six largest overall: 0, 2, 4, 1, 6, 3 -> 6 is in group 3
+    ids, weights = ds.route(jnp.asarray(scores), cfg)
+    assert sorted(np.asarray(ids[0]).tolist()) == [0, 1, 2, 3, 4, 5]
+    assert 6 not in np.asarray(ids[0])
+    order = np.argsort(-np.asarray(weights[0]))
+    assert np.asarray(ids[0])[order].tolist() == [0, 2, 4, 1, 3, 5]
+    # weights are the scores times the scaling factor, not renormalised
+    np.testing.assert_allclose(
+        np.sort(np.asarray(weights[0]))[::-1],
+        16.0 * np.array([0.20, 0.18, 0.16, 0.15, 0.02, 0.01]), rtol=1e-6)
+
+
+# -- the kernels against the dense formulations -------------------------------
+
+
+@pytest.mark.parametrize("S,offset,pads", [
+    (64, 0, [0, 17]),          # whole prompt, one row padded
+    (48, 80, [0, 100]),        # a later chunk; row 1's pad covers 20 queries
+    (40, 24, [3, 5]),          # lengths that leave partial blocks
+])
+def test_prefill_kernel_matches_dense_attention(S, offset, pads):
+    from vnsum_tpu.ops.mla_attention import mla_prefill_attention
+
+    B, H, dn, dr, dv = 2, 3, 16, 8, 16
+    T = offset + S
+    ks = jax.random.split(jax.random.key(S), 5)
+    qn = jax.random.normal(ks[0], (B, H, S, dn))
+    qr = jax.random.normal(ks[1], (B, H, S, dr))
+    kn = jax.random.normal(ks[2], (B, H, T, dn))
+    kr = jax.random.normal(ks[3], (B, T, dr))
+    v = jax.random.normal(ks[4], (B, H, T, dv))
+    pad = jnp.asarray(pads, jnp.int32)
+    got = mla_prefill_attention(qn, qr, kn, kr, v, pad, scale=0.2,
+                                q_offset=offset, block_q=32, block_k=32,
+                                interpret=True)
+    s = (jnp.einsum("bhsk,bhtk->bhst", qn, kn)
+         + jnp.einsum("bhsk,btk->bhst", qr, kr)) * 0.2
+    q_pos = offset + jnp.arange(S)[:, None]
+    k_pos = jnp.arange(T)[None, :]
+    mask = (k_pos <= q_pos)[None] & (k_pos[None] >= pad[:, None, None])
+    want = jnp.einsum(
+        "bhst,bhtk->bhsk",
+        jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), -1), v)
+    real = np.asarray(q_pos[None, :, 0] >= pad[:, None])     # [B, S]
+    for b in range(B):
+        np.testing.assert_allclose(
+            np.asarray(got)[b][:, real[b]], np.asarray(want)[b][:, real[b]],
+            atol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+@pytest.mark.parametrize("fill,pads", [(37, [0, 9]), (99, [40, 0])])
+def test_absorbed_decode_kernel_matches_dense_attention(fill, pads):
+    """All heads against one latent row a token: scores over rank + rope,
+    values over rank. C = 100 leaves a partial last block of 32."""
+    from vnsum_tpu.ops.mla_attention import mla_decode_attention
+
+    L, B, H, C, rank, dr = 2, 2, 4, 100, 32, 8
+    ks = jax.random.split(jax.random.key(fill), 3)
+    ql = jax.random.normal(ks[0], (B, H, rank))
+    qr = jax.random.normal(ks[1], (B, H, dr))
+    cache = jax.random.normal(ks[2], (L, B, C, rank + dr))
+    pad = jnp.asarray(pads, jnp.int32)
+    got = mla_decode_attention(ql, qr, cache, 1, pad, fill, scale=0.3,
+                               rank=rank, block_k=32, interpret=True)
+    lat = cache[1]
+    s = (jnp.einsum("bhc,btc->bht", ql, lat[..., :rank])
+         + jnp.einsum("bhk,btk->bht", qr, lat[..., rank:])) * 0.3
+    k_pos = jnp.arange(C)[None, None, :]
+    mask = (k_pos <= fill) & (k_pos >= pad[:, None, None])
+    want = jnp.einsum("bht,btc->bhc",
+                      jax.nn.softmax(jnp.where(mask, s, -1e30), -1),
+                      lat[..., :rank])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def _expert_inputs(cfg, T, seed=0):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    x = jax.random.normal(ks[0], (T, cfg.dim))
+    scores = jax.nn.softmax(
+        jax.random.normal(ks[1], (T, cfg.n_routed_experts)) * 2, -1)
+    ids, weights = ds.route(scores, cfg)
+    local = ids - cfg.expert_offset
+    held = (local >= 0) & (local < cfg.n_held)
+    return x, jnp.where(held, local, -1), weights
+
+
+@pytest.mark.parametrize("quantized", [False, True])
+@pytest.mark.parametrize("held,offset", [(16, 0), (8, 8)])
+def test_grouped_experts_match_the_dense_sum(quantized, held, offset):
+    cfg = ds.tiny_deepseek(experts_held=held, expert_offset=offset,
+                           w8a8_prefill=quantized)
+    params = ds.init_params(jax.random.key(3), cfg)
+    if quantized:
+        params = quantize_params(params)
+    experts = {n: params["layers"][n] for n in ds._EXPERTS}
+    x, local, weights = _expert_inputs(cfg, 70)
+    want = ds.dense_experts(x, local, weights, experts, 1, cfg)
+    got = ds.grouped_experts(x, local, weights, experts, 1, cfg,
+                             interpret=True)
+    # int8 rows round the activations twice; float rows only reorder sums
+    tol = 0.03 * float(jnp.abs(want).max()) if quantized else 2e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol)
+    assert float(jnp.abs(want).max()) > 0.01
+
+
+def test_no_token_is_dropped_when_every_token_picks_one_expert():
+    """Every pick of every token on expert 2: the worst case for a scheme
+    with a capacity. The grouped product has none."""
+    cfg = ds.tiny_deepseek()
+    params = ds.init_params(jax.random.key(4), cfg)
+    experts = {n: params["layers"][n] for n in ds._EXPERTS}
+    T, k = 300, cfg.num_experts_per_tok
+    x = jax.random.normal(jax.random.key(5), (T, cfg.dim))
+    local = jnp.full((T, k), 2, jnp.int32)
+    weights = jnp.full((T, k), 1.5, jnp.float32)
+    got = ds.grouped_experts(x, local, weights, experts, 0, cfg,
+                             interpret=True)
+    w = {n: experts[n][0, 2] for n in ds._EXPERTS}
+    one = (jax.nn.silu(x @ w["we_gate"]) * (x @ w["we_up"])) @ w["we_down"]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(one * 1.5 * k),
+                               atol=2e-5)
+
+
+def test_expert_layout_gives_every_slot_a_row_of_its_expert():
+    from vnsum_tpu.ops.expert_matmul import expert_layout
+
+    slots = jnp.asarray([3, -1, 0, 3, 3, 1, -1, 0, 3], jnp.int32)
+    row, tile_expert, used, sizes, M = expert_layout(slots, 4, 2)
+    row, tile_expert = np.asarray(row), np.asarray(tile_expert)
+    assert np.asarray(sizes).tolist() == [2, 1, 0, 4]
+    assert int(used[0]) == 1 + 1 + 0 + 2
+    held = np.asarray(slots) >= 0
+    assert len(set(row[held])) == held.sum()          # no two slots share a row
+    for r, e in zip(row[held], np.asarray(slots)[held]):
+        assert tile_expert[r // 2] == e
+    assert (row[~held] == M - 1).all() and M - 1 >= int(used[0]) * 2
+
+
+# -- the program through the engine ------------------------------------------
+
+
+def _engine(cfg, params, **kw):
+    kw.setdefault("interpret", True)
+    return TpuBackend(model_config=cfg, tokenizer="byte", batch_size=2,
+                      max_new_tokens=8, params=params, **kw)
+
+
+def test_generate_runs_the_kernels_and_counts_the_experts(tiny):
+    cfg, params = tiny
+    be = _engine(cfg, params, prefill_chunk_tokens=128)
+    outs = be.generate(["xin chao " * 30, "hello"], max_new_tokens=8)
+    assert len(outs) == 2
+    assert be.stats.attention_paths == {
+        "generate[B=2,S=248]": {"prefill": "kernel", "decode": "kernel"}}
+    st = be.stats
+    # every real token of the prompts and of 8 steps of both rows, 3 picks
+    # in each of the 2 expert layers; pad tokens are routed nowhere
+    tokens = st.prompt_tokens + 2 * 8
+    assert st.expert_slots_routed == tokens * 3 * 2
+    assert st.expert_slots_held == st.expert_slots_routed   # all 16 held
+    assert np.asarray(st.expert_tokens).shape == (2, 16)
+    assert int(np.sum(st.expert_tokens)) == st.expert_slots_held
+    be.generate(["them"], max_new_tokens=8)                 # they add up
+    assert int(np.sum(st.expert_tokens)) == st.expert_slots_held > tokens * 6
+
+
+def test_a_share_counts_only_the_experts_it_holds():
+    cfg = ds.tiny_deepseek(experts_held=4, expert_offset=4)
+    be = _engine(cfg, ds.init_params(jax.random.key(0), cfg))
+    be.generate(["xin chao " * 20], max_new_tokens=8)
+    st = be.stats
+    assert 0 < st.expert_slots_held < st.expert_slots_routed
+    assert np.asarray(st.expert_tokens).shape == (2, 4)
+
+
+@pytest.mark.parametrize("quantize", [False, True])
+def test_kernel_path_and_dense_path_agree(tiny, quantize):
+    """The same prompt through the kernels (chunked prefill, absorbed
+    decode, grouped experts) and through the dense XLA path."""
+    cfg, params = tiny
+    ids = list(range(5, 155))
+    forced = [7, 8, 9]
+    a = _engine(cfg, params, prefill_chunk_tokens=128, quantize=quantize)
+    b = _engine(cfg, params, flash=False, interpret=False, quantize=quantize)
+    got = a.prefill_then_decode_logits(ids, forced, bucket=256)
+    want = b.prefill_then_decode_logits(ids, forced, bucket=256)
+    assert got.shape == (4, cfg.vocab_size)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert a.stats.attention_paths["logits[B=1,S=256]"] == {
+        "prefill": "kernel", "decode": "kernel"}
+    assert b.stats.attention_paths["logits[B=1,S=256]"] == {
+        "prefill": "dense", "decode": "dense"}
+
+
+def test_the_dense_families_logits_entry_matches_their_forward():
+    """``prefill_then_decode_logits`` is the engine's, not this family's."""
+    from vnsum_tpu.models import init_params, tiny_llama
+    from vnsum_tpu.models.llama import forward_train
+
+    cfg = tiny_llama()
+    params = init_params(jax.random.key(0), cfg)
+    be = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=2,
+                    max_new_tokens=8, params=params, flash=False)
+    ids, forced = list(range(3, 40)), [4, 5]
+    got = be.prefill_then_decode_logits(ids, forced, bucket=64)
+    want = forward_train(params, cfg, jnp.asarray([ids + forced]),
+                         remat=False)[0, len(ids) - 1:]
+    np.testing.assert_allclose(got, np.asarray(want), atol=2e-5)
+
+
+# -- what refuses the family, by name ----------------------------------------
+
+
+def test_slot_loop_prefix_cache_mesh_and_speculation_refuse_the_family(tiny):
+    cfg, params = tiny
+    with pytest.raises(NotImplementedError, match="prefix cache.*no KV heads"):
+        _engine(cfg, params, cache_blocks=8)
+    with pytest.raises(NotImplementedError, match="mesh.*no expert axis"):
+        _engine(cfg, params, mesh=object())
+    with pytest.raises(ValueError, match="cache has no int8 form"):
+        _engine(cfg, params, quantize_kv=True)
+    be = _engine(cfg, params)
+    assert be.quantize_kv is False
+    with pytest.raises(NotImplementedError, match="slot loop.*latent cache"):
+        be.start_slot_loop(slots=2)
+    with pytest.raises(NotImplementedError, match="slot loop"):
+        be._get_seg_fn("slot_prefill", 2, 64, 8, be.gen_cfg)
+    from vnsum_tpu.backend.long_context import LongContextBackend
+    from vnsum_tpu.core.config import GenerationConfig
+
+    with pytest.raises(NotImplementedError, match="speculative decoding"):
+        be.generate(["a"], references=["a"],
+                    config=GenerationConfig(spec_k=2))
+    with pytest.raises(NotImplementedError, match="long-context backend"):
+        LongContextBackend(model_config=cfg, params=params,
+                           decode_kernel=False)
+
+
+# -- int8 leaves of the family ------------------------------------------------
+
+
+def test_stacked_experts_get_a_scale_per_expert_and_channel(tiny):
+    cfg, params = tiny
+    q = quantize_params(params)
+    assert is_quantized(q)
+    L, E, D, F = cfg.n_expert_layers, cfg.n_held, cfg.dim, cfg.moe_intermediate
+    assert q["layers"]["we_gate"]["q"].shape == (L, E, D, F)
+    assert q["layers"]["we_gate"]["s"].shape == (L, E, F)
+    assert q["layers"]["we_down"]["s"].shape == (L, E, D)
+    assert q["layers"]["wk_b"]["s"].shape == (L, cfg.n_heads,
+                                              cfg.qk_nope_head_dim)
+    assert q["dense"]["w_gate"]["s"].shape == (1, cfg.intermediate)
+    assert not isinstance(q["layers"]["router"], dict)   # full precision
+    back = dequantize_params(q)
+    for group in ("dense", "layers"):
+        for name, w in params[group].items():
+            err = float(jnp.abs(back[group][name] - w).max())
+            assert err <= float(jnp.abs(w).max()) / 127 + 1e-9, name
+
+
+def test_quantized_init_has_the_layout_of_quantize_params(tiny):
+    cfg, params = tiny
+    made = init_params_quantized(jax.random.key(1), cfg)
+    want = jax.eval_shape(quantize_params, params)
+    assert jax.tree.structure(made) == jax.tree.structure(want)
+    for a, b in zip(jax.tree.leaves(made), jax.tree.leaves(want)):
+        assert (a.shape, a.dtype) == (b.shape, b.dtype)
+
+
+def test_quantizing_a_dense_family_is_unchanged_by_the_new_groups():
+    from vnsum_tpu.models import init_params, tiny_llama
+
+    params = init_params(jax.random.key(0), tiny_llama())
+    q = quantize_params(params)
+    assert set(q) == {"embed", "layers", "final_norm"}   # tied head
+    assert q["layers"]["wq"]["s"].shape == params["layers"]["wq"].shape[:1] \
+        + params["layers"]["wq"].shape[2:]
+
+
+def test_w8a8_is_switched_on_by_the_engine_as_for_the_dense_families(tiny):
+    cfg, params = tiny
+    be = _engine(cfg, params, quantize=True, quantize_act=True)
+    assert be.cfg.w8a8_prefill and not cfg.w8a8_prefill
+    assert dataclasses.replace(cfg, w8a8_prefill=True) == be.cfg
+
+
+# -- scopes --------------------------------------------------------------------
+
+COMPONENTS = ("embed", "q_lora", "kv_latent", "kv_write", "attn", "attn_out",
+              "mlp", "router", "experts", "shared_experts", "lm_head",
+              "sample")
+
+
+def test_the_one_shot_program_carries_the_familys_scopes(tiny):
+    """Every component of README "Device time by layer" is a scope of the
+    compiled program under both phases, so a device trace can be read by
+    them (scope_maps builds the family's own state for the lowering)."""
+    cfg, params = tiny
+    be = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=2,
+                    max_new_tokens=4, params=params, flash=False)
+    be._get_fn(2, 64, 4, be.gen_cfg)
+    (m,) = be.scope_maps()
+    assert m["module"] == "jit_generate"
+    seen = {"/".join(p.split("/")[:2]) for p in m["scopes"].values()}
+    for phase in ("prefill", "decode"):
+        missing = [c for c in COMPONENTS if f"{phase}/{c}" not in seen]
+        assert not missing, (phase, missing)
+    assert "decode/emit" in seen
+
+
+def test_the_kernels_keep_their_contracted_names():
+    import re
+    from pathlib import Path
+
+    ops = Path(__file__).resolve().parents[1] / "vnsum_tpu" / "ops"
+    named = set()
+    for src in ("mla_attention.py", "expert_matmul.py"):
+        named |= set(re.findall(r'^\s+name="(\w+)",$',
+                                (ops / src).read_text(), re.M))
+    assert named == {"mla_prefill_attention", "mla_decode_attention",
+                     "expert_grouped_matmul"}

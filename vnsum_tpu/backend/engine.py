@@ -47,12 +47,11 @@ from .base import (
 from ..core.profiling import annotate
 from ..obs.trace import current_collector, emit
 from ..testing.faults import fault
+from ..models.family import family_of
 from ..models.llama import (
     LlamaConfig,
     decode_attention_mask,
     forward,
-    init_kv_cache,
-    init_params,
     llama32_3b,
     prefill_attention_mask,
     prefill_positions,
@@ -114,6 +113,13 @@ class EngineStats:
     attention_paths: dict = field(default_factory=dict)
     # host-phase wall clock (always on: the timers wrap pure-host work)
     phase_seconds: dict = field(default_factory=dict)
+    # sparse-expert families (models/deepseek.py), summed on the device and
+    # returned with each one-shot program's output: token x pick pairs the
+    # router saw, those that fell on an expert held here, and tokens per
+    # expert layer and held expert ([layers][experts] once a dispatch ran)
+    expert_slots_routed: int = 0
+    expert_slots_held: int = 0
+    expert_tokens: list = field(default_factory=list)
 
     def add_phase(self, name: str, seconds: float) -> None:
         self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + seconds
@@ -152,6 +158,14 @@ class TpuBackend:
 
         enable_compilation_cache()  # per-bucket programs amortize on disk
         self.cfg = model_config or llama32_3b()
+        # what differs between model families — the layer stack, the state
+        # a program carries, the attention over it — comes from here; the
+        # program around it is this module's, for every family
+        self.family = family_of(self.cfg)
+        if mesh is not None:
+            self.family.refuse("mesh")
+        if cache_blocks:
+            self.family.refuse("prefix cache")
         if quantize_act:
             # W8A8 prefill (models.llama._proj): double-rate s8xs8 MXU
             # dots on multi-token forwards. LOSSY (per-token activation
@@ -189,9 +203,15 @@ class TpuBackend:
         # kernel support (head_dim lane alignment — e.g. llama32_1b's
         # head_dim=64 can't take the kernels, and the dense fallback would
         # dequantize the whole cache per step)
-        kernels_supported = self.cfg.head_dim % 128 == 0 or self.interpret
+        kernels_supported = self.family.kernels_supported(
+            self.cfg, self.interpret)
         if quantize_kv == "auto":
-            quantize_kv = self.flash and kernels_supported
+            quantize_kv = (self.flash and kernels_supported
+                           and self.family.int8_cache)
+        elif quantize_kv and not self.family.int8_cache:
+            raise ValueError(
+                f"quantize_kv=True: the {self.family.name} family's cache "
+                "has no int8 form")
         elif quantize_kv and not (self.flash and kernels_supported):
             raise ValueError(
                 "quantize_kv=True needs the Pallas kernels (flash=True and "
@@ -274,7 +294,7 @@ class TpuBackend:
             t0 = time.time()
             from ..models import jitted_init
 
-            params = jitted_init(init_params, self.cfg, seed)
+            params = jitted_init(self.family.init_params, self.cfg, seed)
             logger.info("initialized random params in %.1fs", time.time() - t0)
         if quantize:
             from ..models.quant import is_quantized, quantize_params
@@ -290,6 +310,9 @@ class TpuBackend:
             if batch_size % mesh.shape.get("data", 1):
                 raise ValueError("batch_size must be divisible by mesh data axis")
         self.params = params
+        # the family's own keywords of forward (none for the dense stacks)
+        self._forward_kw = self.family.forward_kwargs(
+            self.cfg, self.flash, self.interpret)
 
     # -- compiled program per bucket ------------------------------------
 
@@ -381,7 +404,7 @@ class TpuBackend:
         done = lambda n: jax.ShapeDtypeStruct((n,), jnp.bool_)  # noqa: E731
 
         def cache_of(B, C):
-            return jax.eval_shape(lambda: init_kv_cache(
+            return jax.eval_shape(lambda: self.family.init_cache(
                 self.cfg, B, C, quantized=self.quantize_kv))
 
         programs = []   # (label, jitted function, arguments)
@@ -500,8 +523,8 @@ class TpuBackend:
             "generate", B, S, prefill=use_flash, decode=use_flash_decode
         )
         mesh = self.mesh
-        quantize_kv = self.quantize_kv
         interpret = self.interpret
+        family, forward_kw = self.family, self._forward_kw
         layer_window = self._layer_window_fn()
 
         # prefill runs whole-prompt or in prefill_chunk_tokens slices —
@@ -554,28 +577,12 @@ class TpuBackend:
                 pos = (S - pad_lens) + t
                 mask_t = decode_attention_mask(pad_lens, S + t, C)
                 stacked_fn = None
-                if use_flash_decode and mesh is not None:
-                    from ..ops.sharded import sharded_flash_decode
-
-                    def stacked_fn(q, cache, layer_idx):
-                        return sharded_flash_decode(
-                            mesh, q, cache, layer_idx, pad_lens, S + t,
-                            cfg.q_per_kv, layer_window(layer_idx),
-                            interpret=interpret,
-                        )
-                elif use_flash_decode:
-                    from ..ops.decode_attention import flash_decode_attention
-
-                    def stacked_fn(q, cache, layer_idx):
-                        return flash_decode_attention(
-                            q, cache, layer_idx, pad_lens, S + t,
-                            cfg.q_per_kv, layer_window(layer_idx),
-                            interpret=interpret,
-                        )
-
-                logits, cache = forward(
+                if use_flash_decode:
+                    stacked_fn = family.decode_attention(
+                        cfg, mesh, interpret, pad_lens, S, t, layer_window)
+                logits, cache = family.forward(
                     params, cfg, cur[:, None], pos[:, None], cache, S + t,
-                    mask_t, stacked_attention_fn=stacked_fn,
+                    mask_t, stacked_attention_fn=stacked_fn, **forward_kw,
                 )
                 with jax.named_scope("sample"):
                     step_keys = jax.vmap(
@@ -610,6 +617,7 @@ class TpuBackend:
         # final cache: decode never touches slots < S, so the prompt's
         # prefix KV survives for post-call insertion into the block pool
         return_cache = self.prefix_cache is not None
+        counters = self.family.counters
 
         def run(params, tokens, pad_lens, seed, cache):
             first, cache, done0 = prefill_part(
@@ -621,6 +629,10 @@ class TpuBackend:
                 params, jnp.int32(0), first, cache, done0, uids, out0,
                 pad_lens, max_new, seed,
             )
+            if counters is not None:
+                # what the family counted on the device (expert loads)
+                # leaves with the tokens: no host callback, no second fetch
+                return out, counters(cache)
             return (out, cache) if return_cache else out  # out: [B, max_new]
 
         if resume_from:
@@ -695,7 +707,7 @@ class TpuBackend:
         configs: 0 on global layers, else the config window — one compiled
         kernel serves both kinds. None-returning on dense configs."""
         cfg = self.cfg
-        if cfg.sliding_window:
+        if getattr(cfg, "sliding_window", 0):
             from ..models.llama import _layer_global_flags
 
             def layer_window(layer_idx):
@@ -712,7 +724,8 @@ class TpuBackend:
     def _init_prefill_cache(self, B: int, C: int):
         """Fresh KV cache with the mesh layout pinned (batch over data,
         heads over model) instead of left to GSPMD propagation."""
-        cache = init_kv_cache(self.cfg, B, C, quantized=self.quantize_kv)
+        cache = self.family.init_cache(
+            self.cfg, B, C, quantized=self.quantize_kv)
         if self.mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -730,33 +743,15 @@ class TpuBackend:
 
     def _prefill_stacked(self, use_flash, pad_lens, layer_window,
                          q_offset: int = 0):
-        """Flash/sharded-flash stacked-attention fn for a prefill-style
-        forward whose queries start at cache slot ``q_offset`` (0 = whole
-        prompt; chunked prefill passes each chunk's start). None when the
-        dense path is in effect."""
-        cfg = self.cfg
-        mesh = self.mesh
-        interpret = self.interpret
+        """The family's stacked-attention fn for a prefill-style forward
+        whose queries start at cache slot ``q_offset`` (0 = whole prompt;
+        chunked prefill passes each chunk's start). None when the dense
+        path is in effect."""
         if not use_flash:
             return None
-        if mesh is not None:
-            from ..ops.sharded import sharded_flash_prefill
-
-            def stacked_fn(q, cache, layer_idx):
-                return sharded_flash_prefill(
-                    mesh, q, cache, layer_idx, pad_lens, cfg.q_per_kv,
-                    layer_window(layer_idx), q_offset, interpret=interpret,
-                )
-        else:
-            from ..ops.flash_attention import flash_prefill_attention
-
-            def stacked_fn(q, cache, layer_idx):
-                return flash_prefill_attention(
-                    q, cache, layer_idx, pad_lens, cfg.q_per_kv,
-                    layer_window(layer_idx), q_offset, interpret=interpret,
-                )
-
-        return stacked_fn
+        return self.family.prefill_attention(
+            self.cfg, self.mesh, self.interpret, pad_lens, layer_window,
+            q_offset)
 
     def _prefill_forward(self, params, tokens, pad_lens, B, S, C,
                          use_flash, layer_window, cache=None, start=0):
@@ -780,13 +775,14 @@ class TpuBackend:
         # the full S — the kernel's q_offset places chunk c's queries at
         # cache slots [lo, hi) (see prefill_part's rationale comment)
         for lo, hi in self._prefill_spans(S, start):
-            logits, cache = forward(
+            logits, cache = self.family.forward(
                 params, cfg, tokens[:, lo:hi], positions[:, lo:hi],
                 cache, lo, mask[:, lo:hi, :],
                 last_only=(hi == S),
                 stacked_attention_fn=self._prefill_stacked(
                     use_flash, pad_lens, layer_window, q_offset=lo
                 ),
+                **self._forward_kw,
             )
         return logits, cache
 
@@ -804,7 +800,8 @@ class TpuBackend:
         """Add one dispatch's prefill-kernel cells, by class, to
         ``stats.prefill_blocks``. Pure host arithmetic on the pads the
         dispatch was packed with; nothing when its prefill is dense."""
-        if not self._decode_settings(S, C)[0]:
+        if (not self._decode_settings(S, C)[0]
+                or not self.family.counts_prefill_blocks):
             return
         from ..ops.flash_attention import prefill_block_classes
 
@@ -942,11 +939,8 @@ class TpuBackend:
         if use_flash:
             if self.interpret:  # interpret mode has no lane-alignment limits
                 return True, True
-            from ..ops.decode_attention import supports_decode
-            from ..ops.flash_attention import supports_flash
-
-            use_flash = supports_flash(S, C, self.cfg.head_dim)
-            use_flash_decode = supports_decode(C, self.cfg.head_dim)
+            use_flash, use_flash_decode = self.family.attention_supported(
+                self.cfg, S, C)
         return use_flash, use_flash_decode
 
     def _make_prefill_fn(self, B: int, S: int, max_new: int, gen,
@@ -1169,6 +1163,7 @@ class TpuBackend:
         outputs stay byte-identical to N=1."""
         from .inflight import TpuSlotLoop
 
+        self.family.refuse("slot loop")
         n_slots = slots or self.batch_size
         if self.mesh is not None:
             data_size = self.mesh.shape.get("data", 1)
@@ -1211,6 +1206,7 @@ class TpuBackend:
                     resume_from: int = 0, fused: int = 1):
         key = (kind, B, S, max_new, gen.with_(seed=0), resume_from, fused)
         if key not in self._seg_fns:
+            self.family.refuse("slot loop")
             if kind == "prefill":
                 fn = self._make_prefill_fn(B, S, max_new, gen, resume_from)
             elif kind == "slot_prefill":
@@ -1685,6 +1681,8 @@ class TpuBackend:
             and references is not None
             and any(references)
         )
+        if spec_on:
+            self.family.refuse("speculative decoding")
         if (
             spec_on
             and self.mesh is not None
@@ -1809,6 +1807,11 @@ class TpuBackend:
                         out_dev, final_cache = res if pc is not None else (res, None)
                         # lint-allow[host-sync-in-hot-path]: one-shot result fetch bounds the dispatch and feeds detok
                         out = jax.device_get(out_dev)
+                        if self.family.counters is not None:
+                            # a family that counts returns its counters
+                            # with the tokens: one fetch brought both
+                            out, counted = out
+                            self._add_expert_counts(counted)
                     # the fused prefill+decode program has no observable
                     # midpoint: one "dispatch" event bounds the whole device
                     # call (the result fetch above synced it) — TTFT consumers
@@ -1853,6 +1856,92 @@ class TpuBackend:
                            for r in spec_report]
         self._spec_report = spec_report
         return results  # type: ignore[return-value]
+
+    def _add_expert_counts(self, counted: dict) -> None:
+        """Add one dispatch's expert counters (host arrays by now) to the
+        statistics."""
+        st = self.stats
+        st.expert_slots_routed += int(counted["slots_routed"])
+        st.expert_slots_held += int(counted["slots_held"])
+        tokens = np.asarray(counted["expert_tokens"], np.int64)
+        if st.expert_tokens:
+            tokens = tokens + np.asarray(st.expert_tokens, np.int64)
+        st.expert_tokens = tokens.tolist()
+
+    def prefill_then_decode_logits(
+        self, prompt_ids, forced_ids, bucket: int | None = None,
+        return_state: bool = False,
+    ):
+        """Logits of the engine's own two phases on one prompt, for parity
+        checks: the prompt (token ids) goes through the chunked prefill as
+        ``generate`` runs it (left-padded into ``bucket``, default its
+        length bucket; the family's prefill kernel, W8A8 where on), then
+        each of ``forced_ids`` is fed as the next token through one decode
+        step over the cache (the family's decode kernel), whatever the
+        model would have sampled. Returns float32 ``[1 + len(forced_ids),
+        vocab]``: row 0 scores the token after the prompt, row i the token
+        after ``forced_ids[i - 1]``. With ``return_state`` also, as host
+        arrays, ``{"cache": ..., "rows": ...}``: the state the program ends
+        with (the family's cache of ``bucket + len(forced_ids)`` slots —
+        the prompt's rows end at slot ``bucket``, each forced token's row
+        follows — and whatever else it carries), and the family's
+        ``row_record`` of each scored position, stacked in the rows' order
+        (None for a family that records nothing). One row, no sampling,
+        nothing cached between calls; its program is compiled per (bucket,
+        steps)."""
+        n, steps = len(prompt_ids), len(forced_ids)
+        S = bucket or _bucket_len(n, self.cfg.max_seq_len - max(steps, 1))
+        if n > S:
+            raise ValueError(f"{n} prompt tokens do not fit bucket {S}")
+        C = S + max(steps, 1)
+        key = ("logits", S, steps)
+        if key not in self._fns:
+            use_flash, use_flash_decode = self._decode_settings(S, C)
+            self._note_attention("logits", 1, S, prefill=use_flash,
+                                 decode=use_flash_decode)
+            layer_window = self._layer_window_fn()
+            family, cfg = self.family, self.cfg
+
+            def program(params, tokens, pad_lens, forced):
+                with jax.named_scope("prefill"):
+                    first, cache = self._prefill_forward(
+                        params, tokens, pad_lens, 1, S, C, use_flash,
+                        layer_window)
+                rows = [first[:, -1, :]]
+                record = family.row_record
+                seen = [record(cache)] if record else []
+                with jax.named_scope("decode"):
+                    for t in range(steps):
+                        stacked_fn = None
+                        if use_flash_decode:
+                            stacked_fn = family.decode_attention(
+                                cfg, self.mesh, self.interpret, pad_lens,
+                                S, t, layer_window)
+                        logits, cache = family.forward(
+                            params, cfg, forced[:, t:t + 1],
+                            ((S - pad_lens) + t)[:, None], cache, S + t,
+                            decode_attention_mask(pad_lens, S + t, C),
+                            stacked_attention_fn=stacked_fn,
+                            **self._forward_kw)
+                        rows.append(logits[:, -1, :])
+                        if record:
+                            seen.append(record(cache))
+                return (jnp.concatenate(rows, axis=0), cache,
+                        jax.tree.map(lambda *a: jnp.stack(a), *seen)
+                        if seen else None)
+
+            self._fns[key] = self._timed_first_call(
+                jax.jit(program), f"logits[S={S},steps={steps}]")
+        tokens = np.full((1, S), self.tok.pad_id, np.int32)
+        tokens[0, S - n:] = np.asarray(prompt_ids, np.int32)
+        forced = np.asarray(forced_ids, np.int32).reshape(1, steps)
+        logits, cache, seen = self._fns[key](
+            self.params, jnp.asarray(tokens),
+            jnp.asarray([S - n], jnp.int32), jnp.asarray(forced))
+        if return_state:
+            return np.asarray(logits), jax.tree.map(
+                np.asarray, {"cache": cache, "rows": seen})
+        return np.asarray(logits)
 
     def _detok(self, ids: np.ndarray, extra_eos: tuple[int, ...] = ()) -> str:
         self.stats.generated_tokens += int((ids != self.tok.pad_id).sum())

@@ -484,6 +484,10 @@ class LongContextBackend:
         from ..core.jax_cache import enable_compilation_cache
 
         enable_compilation_cache()
+        if model_config is not None:
+            from ..models.family import family_of
+
+            family_of(model_config).refuse("long-context backend")
         if (model_config is not None) and model_config.sliding_window:
             raise NotImplementedError(
                 "LongContextBackend runs ring attention (global K/V "

@@ -28,6 +28,13 @@ def jitted_init(init_fn, cfg, seed: int = 0):
 
     return jax.jit(functools.partial(init_fn, cfg=cfg))(jax.random.key(seed))
 
+def _deepseek_v2(**kw):
+    # imported on use: the dense families' paths never load this module
+    from .deepseek import deepseek_v2
+
+    return deepseek_v2(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -44,6 +51,8 @@ MODEL_REGISTRY = {
     "phi4:14b": phi4_14b,
     "phi4-14b": phi4_14b,
     "tiny": tiny_llama,
+    # another family (models/deepseek.py): latent attention, sparse experts
+    "deepseek-v2": _deepseek_v2,
 }
 
 __all__ = [

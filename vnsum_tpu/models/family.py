@@ -1,0 +1,67 @@
+"""The seam between the one-shot generation program and a model family.
+
+``backend/engine.py`` owns the program: chunked prefill, left padding,
+sampling by (seed, uid, t), the early-exit decode loop, the statistics. What
+differs between families is the layer stack and what it keeps between
+steps, and that is what a ``Family`` supplies: ``forward``, the constructor
+of the state a program carries (the KV cache; for latent attention the
+latent cache and the expert counters), the parameters' init, and the two
+attention functions over the stacked cache, one per phase.
+
+``family_of(cfg)`` resolves a family from the config's type: the module a
+config class lives in names its family as ``FAMILY``. Entries of the engine
+that a family cannot run yet are named in ``missing`` with what they lack,
+and refuse it by that text; nothing falls back silently.
+"""
+from __future__ import annotations
+
+import importlib
+from dataclasses import dataclass, field
+from typing import Callable
+
+
+@dataclass(frozen=True)
+class Family:
+    name: str
+    # (params, cfg, tokens, positions, cache, write_index, mask, *,
+    #  last_only, stacked_attention_fn, **forward_kwargs) -> (logits, cache)
+    forward: Callable
+    # (cfg, batch, cache_len, *, quantized) -> the state a program carries
+    init_cache: Callable
+    init_params: Callable
+    # (cfg, interpret) -> can the Pallas kernels take this config at all
+    kernels_supported: Callable
+    # (cfg, S, C) -> (prefill kernel usable, decode kernel usable)
+    attention_supported: Callable
+    # (cfg, mesh, interpret, pad_lens, layer_window, q_offset) -> stacked fn
+    prefill_attention: Callable
+    # (cfg, mesh, interpret, pad_lens, S, t, layer_window) -> stacked fn of
+    # decode step t after a prompt bucket of S (its token at slot S + t)
+    decode_attention: Callable
+    # whether the cache has an int8 form (quantize_kv)
+    int8_cache: bool = True
+    # whether its prefill kernel is ops/flash_attention.py's, whose grid
+    # cells EngineStats.prefill_blocks counts by class
+    counts_prefill_blocks: bool = False
+    # (cfg, kernels on, interpret) -> further keywords of ``forward``
+    forward_kwargs: Callable = lambda cfg, kernels, interpret: {}
+    # final state -> {name: device array} returned with a program's output,
+    # or None when the family counts nothing
+    counters: Callable | None = None
+    # state after a forward -> what a parity check may see of the position
+    # just scored beside its logits (``prefill_then_decode_logits`` collects
+    # it after the prefill and after each step), or None
+    row_record: Callable | None = None
+    # engine entry -> what this family lacks for it
+    missing: dict = field(default_factory=dict)
+
+    def refuse(self, entry: str) -> None:
+        """Raise if this family cannot run ``entry`` of the engine."""
+        if entry in self.missing:
+            raise NotImplementedError(
+                f"the {self.name} family cannot run the engine's {entry} "
+                f"yet: {self.missing[entry]}")
+
+
+def family_of(cfg) -> Family:
+    return importlib.import_module(type(cfg).__module__).FAMILY

@@ -778,3 +778,86 @@ def verify_positions(
 ) -> jax.Array:
     """RoPE positions of the verify queries: (fills_b - pad_b) + i. [B, S]."""
     return (fills - pad_lens)[:, None] + jnp.arange(num_q)[None, :]
+
+
+# -- the engine's seam (models/family.py) -------------------------------------
+
+
+def _kernels_supported(cfg: LlamaConfig, interpret: bool) -> bool:
+    # the kernels want lane-aligned heads (llama32_1b's head_dim=64 cannot
+    # take them); interpret mode has no such limit
+    return cfg.head_dim % 128 == 0 or interpret
+
+
+def _attention_supported(cfg: LlamaConfig, S: int, C: int):
+    from ..ops.decode_attention import supports_decode
+    from ..ops.flash_attention import supports_flash
+
+    return supports_flash(S, C, cfg.head_dim), supports_decode(C, cfg.head_dim)
+
+
+def _prefill_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
+                       layer_window, q_offset: int = 0):
+    """Flash/sharded-flash stacked-attention fn for a prefill-style forward
+    whose queries start at cache slot ``q_offset`` (0 = whole prompt;
+    chunked prefill passes each chunk's start)."""
+    if mesh is not None:
+        from ..ops.sharded import sharded_flash_prefill
+
+        def stacked_fn(q, cache, layer_idx):
+            return sharded_flash_prefill(
+                mesh, q, cache, layer_idx, pad_lens, cfg.q_per_kv,
+                layer_window(layer_idx), q_offset, interpret=interpret,
+            )
+    else:
+        from ..ops.flash_attention import flash_prefill_attention
+
+        def stacked_fn(q, cache, layer_idx):
+            return flash_prefill_attention(
+                q, cache, layer_idx, pad_lens, cfg.q_per_kv,
+                layer_window(layer_idx), q_offset, interpret=interpret,
+            )
+
+    return stacked_fn
+
+
+def _decode_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
+                      S: int, t, layer_window):
+    """Stacked-attention fn of decode step ``t`` after a prompt bucket of
+    ``S``: its token sits at cache slot ``S + t``, summed where the layer
+    calls for it, as the program has always traced it."""
+    if mesh is not None:
+        from ..ops.sharded import sharded_flash_decode
+
+        def stacked_fn(q, cache, layer_idx):
+            return sharded_flash_decode(
+                mesh, q, cache, layer_idx, pad_lens, S + t,
+                cfg.q_per_kv, layer_window(layer_idx),
+                interpret=interpret,
+            )
+    else:
+        from ..ops.decode_attention import flash_decode_attention
+
+        def stacked_fn(q, cache, layer_idx):
+            return flash_decode_attention(
+                q, cache, layer_idx, pad_lens, S + t,
+                cfg.q_per_kv, layer_window(layer_idx),
+                interpret=interpret,
+            )
+
+    return stacked_fn
+
+
+def _family():
+    from .family import Family
+
+    return Family(
+        name="llama", forward=forward, init_cache=init_kv_cache,
+        init_params=init_params, kernels_supported=_kernels_supported,
+        attention_supported=_attention_supported,
+        prefill_attention=_prefill_attention,
+        decode_attention=_decode_attention, counts_prefill_blocks=True,
+    )
+
+
+FAMILY = _family()

@@ -35,6 +35,22 @@ _CONTRACT_AXES = {
     "w_gate": (0,), "w_up": (0,),          # [D, I] contract D
     "w_down": (0,),                        # [I, D] contract I
 }
+# the same for every family's stacked leaves: the dense stack's above (which
+# parallel/sharding.py also reads, name for name) and those of latent
+# attention and sparse experts (models/deepseek.py)
+_ALL_CONTRACT_AXES = {
+    **_CONTRACT_AXES,
+    "wq_a": (0,), "wq_b": (0,),            # [D, r], [r, H, hd]
+    "wkv_a": (0,),                         # [D, rank + rope]
+    "wk_b": (0,), "wv_b": (0,),            # [rank, H, k] contract rank
+    "ws_gate": (0,), "ws_up": (0,), "ws_down": (0,),   # shared experts
+    # stacked experts [E, D, F] / [E, F, D]: the expert axis stays, so the
+    # scale is per expert and output channel, [E, F] / [E, D]
+    "we_gate": (1,), "we_up": (1,), "we_down": (1,),
+}
+# the groups of stacked layers a params tree may hold: every family has
+# "layers"; one with leading dense layers keeps them under "dense"
+_LAYER_GROUPS = ("layers", "dense")
 
 
 def _quantize(w: jax.Array, contract_axes: tuple[int, ...]) -> dict:
@@ -51,18 +67,20 @@ def quantize_params(params: dict) -> dict:
     Layer weights have a leading stacked L dim, so their contract axes shift
     by one; the scale keeps the L dim for the layer scan.
     """
-    layers = {}
-    for name, w in params["layers"].items():
-        if name in _CONTRACT_AXES:
-            axes = tuple(a + 1 for a in _CONTRACT_AXES[name])
-            layers[name] = _quantize(w, axes)
-        else:  # norms
-            layers[name] = w
+    def stack(group: dict) -> dict:
+        layers = {}
+        for name, w in group.items():
+            if name in _ALL_CONTRACT_AXES:
+                axes = tuple(a + 1 for a in _ALL_CONTRACT_AXES[name])
+                layers[name] = _quantize(w, axes)
+            else:  # norms, a router
+                layers[name] = w
+        return layers
 
     out = {
         "embed": _quantize(params["embed"], (1,)),  # row max -> scale [V]
-        "layers": layers,
         "final_norm": params["final_norm"],
+        **{g: stack(params[g]) for g in _LAYER_GROUPS if g in params},
     }
     if "lm_head" in params:
         out["lm_head"] = _quantize(params["lm_head"], (0,))  # scale [V]
@@ -81,8 +99,10 @@ def init_params_quantized(key: jax.Array, cfg) -> dict:
     drift. Perf-sweep tool (real memory/compute shape, untrained values);
     jit via models.jitted_init like init_params.
     """
-    from .llama import init_params
+    import importlib
 
+    # the family's own init_params, from the module its config lives in
+    init_params = importlib.import_module(type(cfg).__module__).init_params
     shapes = jax.eval_shape(lambda k: init_params(k, cfg), key)
     n_leaves = len(jax.tree.leaves(shapes, is_leaf=lambda x: x is None))
     keys = iter(jax.random.split(key, max(n_leaves, 8)))
@@ -98,19 +118,29 @@ def init_params_quantized(key: jax.Array, cfg) -> dict:
         s = jnp.full(s_shape, (fan ** -0.5) / 127.0, jnp.float32)
         return {"q": q, "s": s}
 
-    layers = {}
-    for name, spec in shapes["layers"].items():
-        if name in _CONTRACT_AXES:
-            axes = tuple(a + 1 for a in _CONTRACT_AXES[name])
-            layers[name] = qinit(next(keys), spec, axes)
-        else:  # norm vectors
-            layers[name] = jnp.ones(spec.shape, spec.dtype)
+    def stack(group: dict) -> dict:
+        layers = {}
+        for name, spec in group.items():
+            if name in _ALL_CONTRACT_AXES:
+                axes = tuple(a + 1 for a in _ALL_CONTRACT_AXES[name])
+                layers[name] = qinit(next(keys), spec, axes)
+            elif spec.ndim > 2:  # a router: small, kept in full precision
+                layers[name] = (jax.random.normal(
+                    next(keys), spec.shape, jnp.float32) * 0.02
+                ).astype(spec.dtype)
+            else:  # norm vectors
+                layers[name] = jnp.ones(spec.shape, spec.dtype)
+        return layers
+
+    # the layer stacks draw their keys first, then the embedding, then the
+    # head: the order a seed's weights have always been drawn in
+    groups = {g: stack(shapes[g]) for g in _LAYER_GROUPS if g in shapes}
     out = {
         "embed": qinit(next(keys), shapes["embed"], (1,)),
-        "layers": layers,
         "final_norm": jnp.ones(
             shapes["final_norm"].shape, shapes["final_norm"].dtype
         ),
+        **groups,
     }
     if "lm_head" in shapes:
         out["lm_head"] = qinit(next(keys), shapes["lm_head"], (0,))
@@ -120,27 +150,32 @@ def init_params_quantized(key: jax.Array, cfg) -> dict:
 def dequantize_params(qparams: dict) -> dict:
     """Inverse transform (tests / round-trip checks)."""
 
-    def deq(leaf, contract_axes):
-        s = leaf["s"]
-        for a in sorted(contract_axes):
-            s = jnp.expand_dims(s, a)
-        return leaf["q"].astype(jnp.float32) * s
+    def stack(group: dict) -> dict:
+        return {
+            name: dequantize_leaf(
+                w, tuple(a + 1 for a in _ALL_CONTRACT_AXES[name]))
+            if name in _ALL_CONTRACT_AXES else w
+            for name, w in group.items()}
 
-    layers = {}
-    for name, w in qparams["layers"].items():
-        if name in _CONTRACT_AXES:
-            axes = tuple(a + 1 for a in _CONTRACT_AXES[name])
-            layers[name] = deq(w, axes)
-        else:
-            layers[name] = w
     out = {
-        "embed": deq(qparams["embed"], (1,)),
-        "layers": layers,
+        "embed": dequantize_leaf(qparams["embed"], (1,)),
         "final_norm": qparams["final_norm"],
+        **{g: stack(qparams[g]) for g in _LAYER_GROUPS if g in qparams},
     }
     if "lm_head" in qparams:
-        out["lm_head"] = deq(qparams["lm_head"], (0,))
+        out["lm_head"] = dequantize_leaf(qparams["lm_head"], (0,))
     return out
+
+
+def dequantize_leaf(leaf, contract_axes: tuple[int, ...]) -> jax.Array:
+    """A float32 weight from a plain or an int8 ``{"q", "s"}`` leaf whose
+    scale lacks ``contract_axes``."""
+    if not isinstance(leaf, dict):
+        return leaf.astype(jnp.float32)
+    s = leaf["s"]
+    for a in sorted(contract_axes):
+        s = jnp.expand_dims(s, a)
+    return leaf["q"].astype(jnp.float32) * s
 
 
 def is_quantized(params: dict) -> bool:
