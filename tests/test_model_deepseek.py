@@ -102,15 +102,30 @@ def test_router_drops_the_picks_of_the_fourth_group():
 # -- the kernels against the dense formulations -------------------------------
 
 
-@pytest.mark.parametrize("S,offset,pads", [
-    (64, 0, [0, 17]),          # whole prompt, one row padded
-    (48, 80, [0, 100]),        # a later chunk; row 1's pad covers 20 queries
-    (40, 24, [3, 5]),          # lengths that leave partial blocks
-])
-def test_prefill_kernel_matches_dense_attention(S, offset, pads):
+_PREFILL_CASES = [
+    (3, 64, 0, [0, 17], 32, 32),       # whole prompt, one row padded
+    (3, 48, 80, [0, 100], 32, 32),     # a later chunk; row 1's pad covers 20 queries
+    (3, 40, 24, [3, 5], 32, 32),       # lengths that leave partial blocks
+    # the cell of PR 34: a group of heads a step, two of them a loop step
+    (5, 64, 0, [0, 17], 32, 32),       # a prime H: one group of five, a head a loop step
+    (16, 64, 64, [0, 30], 32, 64),     # two groups of eight
+    (10, 64, 0, [40, 0], 64, 64),      # two groups of five; row 0's first rows under its pad
+    # key blocks wider than query blocks
+    (3, 128, 0, [0, 64], 32, 128),     # the diagonal crosses every block; a pad of half a block
+    (3, 48, 80, [0, 70], 32, 128),     # a pad that ends inside the one wide block
+    (4, 64, 128, [0, 200], 32, 128),   # block 0 interior for every query block, then an edge block; row 1's pad reaches into the chunk
+    (3, 64, 64, [0, 128], 32, 128),    # row 1 wholly under its pad
+    (3, 96, 64, [0, 9], 32, 128),      # 160 keys: the last key block is partial, its buffer past the end is NaN
+    (2, 100, 60, [0, 33], 32, 128),    # the same with a partial last query block
+    (3, 128, 128, [0, 140], 64, 128),  # row 1's pad covers key block 0 whole and the first query block
+]
+
+
+@pytest.mark.parametrize("H,S,offset,pads,bq,bk", _PREFILL_CASES)
+def test_prefill_kernel_matches_dense_attention(H, S, offset, pads, bq, bk):
     from vnsum_tpu.ops.mla_attention import mla_prefill_attention
 
-    B, H, dn, dr, dv = 2, 3, 16, 8, 16
+    B, dn, dr, dv = 2, 16, 8, 16
     T = offset + S
     ks = jax.random.split(jax.random.key(S), 5)
     qn = jax.random.normal(ks[0], (B, H, S, dn))
@@ -120,7 +135,7 @@ def test_prefill_kernel_matches_dense_attention(S, offset, pads):
     v = jax.random.normal(ks[4], (B, H, T, dv))
     pad = jnp.asarray(pads, jnp.int32)
     got = mla_prefill_attention(qn, qr, kn, kr, v, pad, scale=0.2,
-                                q_offset=offset, block_q=32, block_k=32,
+                                q_offset=offset, block_q=bq, block_k=bk,
                                 interpret=True)
     s = (jnp.einsum("bhsk,bhtk->bhst", qn, kn)
          + jnp.einsum("bhsk,btk->bhst", qr, kr)) * 0.2
@@ -136,6 +151,64 @@ def test_prefill_kernel_matches_dense_attention(S, offset, pads):
             np.asarray(got)[b][:, real[b]], np.asarray(want)[b][:, real[b]],
             atol=2e-5)
     assert np.isfinite(np.asarray(got)).all()
+
+
+def _brute_force_tiles(pads, S, T, offset, bq, bk):
+    """Class counts of the (bq x bk) tiles from the mask itself."""
+    from vnsum_tpu.ops.mla_attention import TILE_CLASSES
+
+    counts = dict.fromkeys(TILE_CLASSES, 0)
+    for pad in pads:
+        for q0 in range(offset, offset + S, bq):
+            q = q0 + np.arange(bq)[:, None]
+            for k0 in range(0, T, bk):
+                k = k0 + np.arange(bk)[None, :]
+                mask = (k <= q) & (k >= pad)
+                if not mask.any():
+                    counts["dead_causal" if k0 > q0 + bq - 1
+                           else "dead_pad"] += 1
+                else:
+                    counts["interior" if mask.all() else "masked"] += 1
+    return counts
+
+
+@pytest.mark.parametrize("H,S,offset,pads,bq,bk", _PREFILL_CASES)
+def test_prefill_tile_classes_match_a_brute_force_mask(H, S, offset, pads,
+                                                       bq, bk):
+    from vnsum_tpu.ops.mla_attention import prefill_tile_classes
+
+    T = offset + S
+    got = prefill_tile_classes(pads, S, T, offset, block_q=bq, block_k=bk)
+    assert got["tile"] == (min(bq, S), min(bk, T))
+    want = _brute_force_tiles(pads, S, T, offset, *got["tile"])
+    assert {c: got[c] for c in want} == want
+    q = offset + np.arange(S)[None, :, None]
+    k = np.arange(T)[None, None, :]
+    needed = (k <= q) & (k >= np.asarray(pads)[:, None, None])
+    assert got["scores_needed"] == int(needed.sum())
+    assert got["scores_computed"] == (
+        (want["interior"] + want["masked"]) * int(np.prod(got["tile"])))
+
+
+@pytest.mark.parametrize("pad,waste", [(0, 1.13), (392, 1.25)])
+def test_a_full_row_computes_no_tile_above_the_diagonal(pad, waste):
+    """The cell's own call shapes at the geometry the wrapper chooses: the
+    eight 1,024-query chunks of an 8,192 row. The tiles the kernel computes
+    are those that hold a score the row needs; the scores it computes
+    beyond them lie in the tiles the diagonal or the pad's end crosses."""
+    from vnsum_tpu.ops.mla_attention import prefill_tile_classes
+
+    computed = needed = 0
+    for offset in range(0, 8192, 1024):
+        T = offset + 1024
+        got = prefill_tile_classes([pad], 1024, T, offset)
+        want = _brute_force_tiles([pad], 1024, T, offset, *got["tile"])
+        assert (got["interior"], got["masked"]) == (
+            want["interior"], want["masked"])
+        computed += got["scores_computed"]
+        needed += got["scores_needed"]
+    assert needed == (8192 - pad) * (8192 - pad + 1) // 2
+    assert needed < computed < waste * needed
 
 
 @pytest.mark.parametrize("fill,pads", [(37, [0, 9]), (99, [40, 0])])

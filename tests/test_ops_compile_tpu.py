@@ -48,8 +48,15 @@ def _compiled(fn, one_chip, *shapes):
     return jax.jit(fn).lower(*args).compile()
 
 
-@pytest.mark.parametrize("S,offset", [(1024, 7168), (1024, 0), (2048, 2048)])
+@pytest.mark.parametrize("S,offset", [
+    (1024, 7168), (1024, 0), (2048, 2048),
+    (1024, 1024),   # the reduce's second chunk: 2,048 keys
+    (1024, 3072),   # parity's last chunk: 4,096 keys
+])
 def test_prefill_kernel_compiles_at_the_published_widths(one_chip, S, offset):
+    """With the geometry the wrapper chooses (a group of heads a step, key
+    blocks wider than query blocks): one that overflows scoped VMEM fails
+    here and not on the chip."""
     from vnsum_tpu.ops.mla_attention import mla_prefill_attention
 
     R, H, T = 1, 128, offset + S

@@ -11,7 +11,10 @@ per token (models/deepseek.py: 512 of normalised ``c_kv`` + 64 of rotated
   kernels of ops/flash_attention.py cannot express. Left-padded rows and
   chunked prefill (``q_offset``) as there: a block above the diagonal or
   under a row's pad is neither fetched nor computed, a block that needs no
-  mask builds none.
+  mask builds none (_tile_class; ``prefill_tile_classes`` counts a call's
+  tiles on the host by the same rule). A grid step holds a GROUP of heads
+  against one ``k_rope`` block, as a GQA group shares its K/V block there,
+  and a head computes a (1024, 1024) tile of scores at a time.
 - ``mla_decode_attention``: the ABSORBED decode step. The caller folds
   ``W_kvb``'s key half into the query (``q_lat = q_nope . W_k^T``, 512 wide)
   and the kernel is multi-query attention of all heads over the one latent
@@ -38,16 +41,72 @@ VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 
 # -- prefill ------------------------------------------------------------------
 
+# What one grid step holds, from a sweep on the v5e at the cell's call shapes
+# (q [1, 128, 1024, 128 | 64], 1,024-8,192 keys, bf16; PERF.md section 6,
+# PR 34). The tile of scores one head computes at a time sets the pace, and a
+# (1024, 1024) tile costs half of a (512, 512) one a score (5.4 against 10.7 ns
+# per 1,024); wider key blocks, sub-tiles of an edge block and a tile of 512
+# queries all cost more. The heads of a group share the step's k_rope block,
+# mask and positions, which buys 1-3%; they go two at a time, which buys 3%
+# more where four at a time spill (+50%).
+_BLOCK = 1024
+# the widest group whose step fits 48 MiB of scoped VMEM at that tile (16
+# heads do not compile)
+_GROUP = 8
+_HEADS_UNROLLED = 2
+
+
+def _prefill_geometry(H: int, S: int, T: int, block_q: int | None = None,
+                      block_k: int | None = None) -> tuple[int, int, int]:
+    """(G, bq, bk) of the prefill kernel's cell: the wrapper's rule, also
+    the counter's (prefill_tile_classes). G is the largest divisor of H of
+    at most _GROUP heads."""
+    bq = min(block_q or _BLOCK, S)
+    bk = min(block_k or _BLOCK, T)
+    G = max(g for g in range(1, _GROUP + 1) if H % g == 0)
+    return G, bq, bk
+
+
+def _tile_class(q_start, k_start, pad, rows: int, cols: int):
+    """What the (rows x cols) tile of scores at query slot ``q_start`` / key
+    slot ``k_start`` holds for a row with ``pad`` left-pad slots, as three
+    flags (above, under, interior): wholly above the causal diagonal; every
+    key or every query under the pad; the mask would be all true. A tile
+    with no flag set is one the diagonal or the pad's end crosses. Only
+    comparisons and bit operators: the kernel calls it on SMEM scalars and
+    prefill_tile_classes on numpy arrays, so the host's count is the
+    kernel's behaviour."""
+    q_last = q_start + (rows - 1)
+    k_last = k_start + (cols - 1)
+    above = k_start > q_last
+    under = (k_last < pad) | (q_last < pad)
+    interior = (k_last <= q_start) & (k_start >= pad)
+    return above, under, interior
+
 
 def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
-                    o_ref, acc_ref, m_ref, l_ref, *, block_q: int,
+                    o_ref, acc_ref, m_ref, l_ref, *, group: int, block_q: int,
                     block_k: int, n_keys: int, scale: float):
-    # qn [1,1,bq,dn] qr [1,1,bq,dr] kn/v [1,1,bk,dn|dv] kr [1,bk,dr]
+    # qn [1,G,bq,dn] qr [1,G,bq,dr] kn/v [1,G,bk,dn|dv] kr [1,bk,dr];
+    # acc [G*bq,dv], m/l [G*bq,LANES]: a head's state is a slice of rows
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
     pad = pad_ref[b]
     q_start = off_ref[0] + i * block_q
     k_start = j * block_k
+
+    def for_each_head(body):
+        """``body(g, rows)`` for the group's heads, a few of them unrolled
+        into one loop step: code size and compile time stay those of
+        _HEADS_UNROLLED heads, whatever the group."""
+        n = _HEADS_UNROLLED if group % _HEADS_UNROLLED == 0 else 1
+
+        def several(step, _):
+            for u in range(n):
+                g = step * n + u
+                body(g, pl.ds(pl.multiple_of(g * block_q, block_q), block_q))
+
+        jax.lax.fori_loop(0, group // n, several, None)
 
     @pl.when(j == 0)
     def _init():
@@ -55,62 +114,80 @@ def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    seen = (k_start <= q_start + block_q - 1) & (k_start + block_k > pad)
-    interior = (k_start + block_k - 1 <= q_start) & (k_start >= pad)
-
     def _accumulate(masked: bool):
-        s = jax.lax.dot_general(
-            qn_ref[0, 0], kn_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = s + jax.lax.dot_general(
-            qr_ref[0, 0], kr_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        s = s * scale                                        # [bq, bk]
-        v = v_ref[0, 0]
+        # what does not hang on the head is built once a step: the shared
+        # k_rope block, positions, the mask, the zeroing of a ragged tail
+        kr = kr_ref[0]
+        mask = v_ok = None
         if masked:
-            q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where((k_pos <= q_pos) & (k_pos >= pad), s, _NEG)
-            # a partial last key block holds stale memory past the keys'
-            # end: masked scores there are selected away, but a probability
-            # of 0 times a stale NaN value is NaN. Such a block holds the
-            # diagonal, so it is always a masked one
-            v_slot = k_start + jax.lax.broadcasted_iota(
-                jnp.int32, (block_k, 1), 0)
-            v = jnp.where(v_slot < n_keys, v, jnp.zeros_like(v))
-        m_prev = m_ref[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True),
-            l_ref.shape)
-        acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+            shape = (block_q, block_k)
+            q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+            mask = (k_pos <= q_pos) & (k_pos >= pad)
+            if n_keys % block_k:
+                # a partial last key block holds stale memory past the
+                # keys' end: masked scores there are selected away, but a
+                # probability of 0 times a stale NaN value is NaN. A block
+                # that reaches past the keys' end is never interior
+                v_ok = k_start + jax.lax.broadcasted_iota(
+                    jnp.int32, (block_k, 1), 0) < n_keys
 
-    @pl.when(seen & interior)
+        def _head(g, rows):
+            s = jax.lax.dot_general(
+                qn_ref[0, g], kn_ref[0, g], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = s + jax.lax.dot_general(
+                qr_ref[0, g], kr, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            s = s * scale                                    # [bq, bk]
+            v = v_ref[0, g]
+            if masked:
+                s = jnp.where(mask, s, _NEG)
+                if v_ok is not None:
+                    v = jnp.where(v_ok, v, jnp.zeros_like(v))
+            m_prev = m_ref[rows, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            l_ref[rows] = jnp.broadcast_to(
+                alpha * l_ref[rows, :1] + jnp.sum(p, axis=-1, keepdims=True),
+                (block_q, l_ref.shape[1]))
+            acc_ref[rows] = alpha * acc_ref[rows] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_ref[rows] = jnp.broadcast_to(m_new, (block_q, m_ref.shape[1]))
+
+        for_each_head(_head)
+
+    above, under, interior = _tile_class(q_start, k_start, pad,
+                                         block_q, block_k)
+
+    @pl.when(interior)
     def _interior():
         _accumulate(False)
 
-    @pl.when(seen & ~interior)
+    @pl.when(~(above | under | interior))
     def _edge():
         _accumulate(True)
 
     @pl.when(j == nj - 1)
     def _finalize():
-        # a query row wholly under its pad saw only masked scores: l > 0
-        # still (exp(0) sums), the row is garbage the caller never reads
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
-                       ).astype(o_ref.dtype)
+        # a query row under its pad saw only masked scores (l > 0 still:
+        # exp(0) sums) or, its whole block under the pad, no tile at all
+        # (0 / 1e-30): finite either way, the caller never reads it
+        def _store(g, rows):
+            o_ref[0, g] = (acc_ref[rows] / jnp.maximum(l_ref[rows, :1], 1e-30)
+                           ).astype(o_ref.dtype)
+
+        for_each_head(_store)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "scale", "q_offset", "block_q", "block_k", "interpret"))
 def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
                           scale: float, q_offset: int = 0,
-                          block_q: int = 512, block_k: int = 512,
+                          block_q: int | None = None,
+                          block_k: int | None = None,
                           interpret: bool = False):
     """Causal attention of ``S`` queries at cache slots ``[q_offset,
     q_offset + S)`` over the ``T = q_offset + S`` keys before them.
@@ -118,7 +195,8 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
     q_nope [B, H, S, dn], q_rope [B, H, S, dr]; k_nope [B, H, T, dn] and
     v [B, H, T, dv] are each head's keys and values expanded from the
     latent; k_rope [B, T, dr] is the one rotated key all heads share;
-    pad_lens [B] left pads. Returns [B, H, S, dv]."""
+    pad_lens [B] left pads. Returns [B, H, S, dv]. The cell is chosen from
+    the shapes (_prefill_geometry); ``block_q``/``block_k`` are for tests."""
     B, H, S, dn = q_nope.shape
     dr = q_rope.shape[-1]
     T, dv = v.shape[2], v.shape[3]
@@ -126,7 +204,7 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
         raise ValueError(f"{T} keys for queries at [{q_offset}, {q_offset + S})")
     # whole blocks at the engine's shapes (chunks and buckets are multiples
     # of 512 there); any other length gets a partial last block
-    bq, bk = min(block_q, S), min(block_k, T)
+    G, bq, bk = _prefill_geometry(H, S, T, block_q, block_k)
     off = q_offset
 
     def visible_j(b, i, j, pad, _off):
@@ -136,34 +214,35 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
         last = (off + i * bq + bq - 1) // bk
         return jnp.clip(j, jnp.minimum(first, last), last)
 
-    kernel = functools.partial(_prefill_kernel, block_q=bq, block_k=bk,
-                               n_keys=T, scale=scale)
+    kernel = functools.partial(
+        _prefill_kernel, group=G, block_q=bq, block_k=bk, n_keys=T,
+        scale=scale)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(B, H, pl.cdiv(S, bq), pl.cdiv(T, bk)),
+            grid=(B, H // G, pl.cdiv(S, bq), pl.cdiv(T, bk)),
             in_specs=[
-                pl.BlockSpec((1, 1, bq, dn),
+                pl.BlockSpec((1, G, bq, dn),
                              lambda b, h, i, j, pad, o: (b, h, i, 0)),
-                pl.BlockSpec((1, 1, bq, dr),
+                pl.BlockSpec((1, G, bq, dr),
                              lambda b, h, i, j, pad, o: (b, h, i, 0)),
-                pl.BlockSpec((1, 1, bk, dn),
+                pl.BlockSpec((1, G, bk, dn),
                              lambda b, h, i, j, pad, o:
                              (b, h, visible_j(b, i, j, pad, o), 0)),
                 pl.BlockSpec((1, bk, dr),
                              lambda b, h, i, j, pad, o:
                              (b, visible_j(b, i, j, pad, o), 0)),
-                pl.BlockSpec((1, 1, bk, dv),
+                pl.BlockSpec((1, G, bk, dv),
                              lambda b, h, i, j, pad, o:
                              (b, h, visible_j(b, i, j, pad, o), 0)),
             ],
-            out_specs=pl.BlockSpec((1, 1, bq, dv),
+            out_specs=pl.BlockSpec((1, G, bq, dv),
                                    lambda b, h, i, j, pad, o: (b, h, i, 0)),
             scratch_shapes=[
-                pltpu.VMEM((bq, dv), jnp.float32),
-                pltpu.VMEM((bq, _LANES), jnp.float32),
-                pltpu.VMEM((bq, _LANES), jnp.float32),
+                pltpu.VMEM((G * bq, dv), jnp.float32),
+                pltpu.VMEM((G * bq, _LANES), jnp.float32),
+                pltpu.VMEM((G * bq, _LANES), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, S, dv), q_nope.dtype),
@@ -177,6 +256,36 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
         name="mla_prefill_attention",
     )(pad_lens.astype(jnp.int32), jnp.full((1,), off, jnp.int32),
       q_nope, q_rope, k_nope, k_rope, v)
+
+
+TILE_CLASSES = ("dead_causal", "dead_pad", "interior", "masked")
+
+
+def prefill_tile_classes(pad_lens, S: int, T: int, q_offset: int = 0, *,
+                         block_q: int | None = None,
+                         block_k: int | None = None) -> dict:
+    """What one mla_prefill_attention call computes, counted on the host a
+    head: the (bq x bk) tiles of its grid by class — ``dead_causal``
+    (wholly above the diagonal, skipped before the pad is looked at),
+    ``dead_pad`` (under the row's pad), ``interior`` (no mask built) and
+    ``masked`` — with ``tile`` = (bq, bk), ``scores_computed`` (interior +
+    masked tiles, whole) and ``scores_needed`` (pad <= key <= query). Pure
+    numpy: the wrapper's geometry, the kernel's class rule (_tile_class)."""
+    import numpy as np
+
+    _, bq, bk = _prefill_geometry(1, S, T, block_q, block_k)
+    pad = np.asarray(pad_lens, np.int64).reshape(-1, 1, 1)
+    q_start = q_offset + bq * np.arange(-(-S // bq), dtype=np.int64)
+    k_start = bk * np.arange(-(-T // bk), dtype=np.int64)
+    above, under, interior = _tile_class(
+        q_start[None, :, None], k_start[None, None, :], pad, bq, bk)
+    grid = np.select([above, under, interior], [0, 1, 2], default=3)
+    out = {name: int((grid == c).sum()) for c, name in enumerate(TILE_CLASSES)}
+    out["tile"] = (bq, bk)
+    out["scores_computed"] = (out["interior"] + out["masked"]) * bq * bk
+    q_pos = q_offset + np.arange(S, dtype=np.int64)[None, :]
+    out["scores_needed"] = int(np.maximum(q_pos - pad[:, 0] + 1, 0).sum())
+    return out
 
 
 # -- absorbed decode ----------------------------------------------------------
