@@ -45,12 +45,12 @@ import traceback
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
-PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts")
+PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
-    "experts": 420,
+    "experts": 420, "moe": 420,
 }
 
 
@@ -72,6 +72,9 @@ def sizes(rehearsal: bool) -> dict:
             experts_seq=328, experts_batch=4, experts_max_new=8,
             experts_prefill_chunk=128, experts_prompt_bytes=250,
             experts_parity=(150, 256, 4),
+            moe_layers=8, moe_seq=328, moe_batch=4, moe_max_new=8,
+            moe_prefill_chunk=128, moe_prompt_bytes=250,
+            moe_parity=(150, 256, 4),
         )
     return dict(
         kernel_geometries=None,  # derived from MODEL_REGISTRY
@@ -94,6 +97,11 @@ def sizes(rehearsal: bool) -> dict:
         experts_seq=2112, experts_batch=8, experts_max_new=64,
         experts_prefill_chunk=1024, experts_prompt_bytes=5_500,
         experts_parity=(1500, 2048, 4),   # prompt tokens, bucket, steps
+        # moe: one period of [global, window, window, window] at the
+        # published widths; prompts past the 4096 window in the S=8192 bucket
+        moe_layers=4, moe_seq=8256, moe_batch=4, moe_max_new=64,
+        moe_prefill_chunk=2048, moe_prompt_bytes=6_000,
+        moe_parity=(4500, 8192, 4),
     )
 
 
@@ -635,6 +643,25 @@ def phase_mesh(args) -> dict:
     return {**c.report(), "runs": runs}
 
 
+def _generate_twice(backend, prompts, c: "Checks"):
+    """Two calls of ``backend.generate`` (the first compiles), with the
+    checks every one-shot family phase makes of them: (outputs, seconds of
+    the first call, of the second)."""
+    t0 = time.time()
+    backend.generate(prompts)
+    first_s = time.time() - t0
+    t0 = time.time()
+    outs = backend.generate(prompts)
+    second_s = time.time() - t0
+    paths = backend.stats.attention_paths
+    c.check("attention paths are the kernels", bool(paths) and all(
+        p == "kernel" for prog in paths.values() for p in prog.values()),
+        paths)
+    c.check("every row answered", len(outs) == len(prompts)
+            and sum(bool(o) for o in outs) >= len(outs) - 1)
+    return outs, first_s, second_s
+
+
 def phase_experts(args) -> dict:
     """The DeepSeek-V2 family on the one-shot path: latent attention with
     an absorbed decode, sparse experts of which this chip holds 40 of 160,
@@ -670,19 +697,8 @@ def phase_experts(args) -> dict:
         interpret=args.rehearsal)
     prompts = [_vn_text(sz["experts_prompt_bytes"] - 300 * i, f"e{i}")
                for i in range(sz["experts_batch"])]
-    t0 = time.time()
-    outs = backend.generate(prompts)
-    first_s = time.time() - t0
-    t0 = time.time()
-    outs = backend.generate(prompts)
-    second_s = time.time() - t0
+    _outs, first_s, second_s = _generate_twice(backend, prompts, c)
     st = backend.stats
-    paths = st.attention_paths
-    c.check("attention paths are the kernels", bool(paths) and all(
-        p == "kernel" for prog in paths.values() for p in prog.values()),
-        paths)
-    c.check("every row answered", len(outs) == len(prompts)
-            and sum(bool(o) for o in outs) >= len(outs) - 1)
     k, layers = cfg.num_experts_per_tok, cfg.n_expert_layers
     # both calls count: prompt tokens go through prefill, and every row runs
     # every decode step of the budget (no extra EOS here, but a sampled EOS
@@ -721,6 +737,95 @@ def phase_experts(args) -> dict:
     return rep
 
 
+def phase_moe(args) -> dict:
+    """The SmallThinker family on the one-shot path: GQA at 28/4 heads with
+    rotary 4096-window layers and position-free global layers, 64 ReGLU
+    experts all held, routed on the layer's input — at the published widths
+    with one period of four layers, int8, through ``TpuBackend.generate``
+    with prompts longer than the window; its counters; and its logits
+    against the plain reference (prefill and decode steps)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import reference_smallthinker as reference
+    from benchmarks.engine_setup_smallthinker import sizes_from
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models import jitted_init
+    from vnsum_tpu.models.quant import init_params_quantized
+    from vnsum_tpu.models.smallthinker import (
+        smallthinker_21b_a3b,
+        tiny_smallthinker,
+    )
+
+    sz = sizes(args.rehearsal)
+    c = Checks()
+    make = tiny_smallthinker if args.rehearsal else smallthinker_21b_a3b
+    cfg = make(n_layers=sz["moe_layers"], max_seq_len=sz["moe_seq"])
+    params = jitted_init(init_params_quantized, cfg, 3)
+    backend = TpuBackend(
+        model_config=cfg, tokenizer="byte", params=params,
+        batch_size=sz["moe_batch"], max_new_tokens=sz["moe_max_new"],
+        quantize=True, quantize_act=True, quantize_kv=True,
+        prefill_chunk_tokens=sz["moe_prefill_chunk"],
+        generation=GenerationConfig(temperature=1.0, seed=3),
+        interpret=args.rehearsal)
+    prompts = [_vn_text(sz["moe_prompt_bytes"] - 300 * i // 4, f"m{i}")
+               for i in range(sz["moe_batch"])]
+    _outs, first_s, second_s = _generate_twice(backend, prompts, c)
+    st = backend.stats
+    k, layers = cfg.num_experts_per_tok, cfg.n_layers
+    c.check("slots routed cover the prompts' tokens",
+            st.expert_slots_routed >= st.prompt_tokens * k * layers,
+            (st.expert_slots_routed, st.prompt_tokens * k * layers))
+    c.check("every expert is held: slots held == slots routed",
+            st.expert_slots_held == st.expert_slots_routed,
+            (st.expert_slots_held, st.expert_slots_routed))
+    tokens = np.asarray(st.expert_tokens)
+    c.check("expert_tokens add up to slots held",
+            tokens.shape == (layers, cfg.n_held)
+            and int(tokens.sum()) == st.expert_slots_held, tokens.shape)
+    steps = st.expert_decode_layer_steps
+    per_step = st.expert_decode_touched / max(steps, 1)
+    c.check("a decode step touches between k and rows x k experts a layer",
+            steps == 2 * layers * sz["moe_max_new"]
+            and k <= per_step <= min(cfg.n_held, sz["moe_batch"] * k),
+            (steps, per_step))
+    blocks = st.prefill_blocks
+    c.check("prompts leave the window: window layers skip cells below it",
+            # (the rehearsal's window is narrower than one grid cell)
+            (args.rehearsal or blocks.get("dead_causal", 0) > 0)
+            and blocks.get("edge", 0) > 0, blocks)
+
+    n, bucket, n_steps = sz["moe_parity"]
+    ids = backend.tok.encode(_vn_text((n + n_steps) * 3, "parity"))[:n + n_steps]
+    got, state = backend.prefill_then_decode_logits(
+        ids[:n], ids[n:], bucket=bucket, return_state=True)
+    got = np.asarray(got, np.float64)
+    sizes_ref = sizes_from(cfg)
+    # the routers' picks of the scored positions: the reference takes them
+    # where they are the top-k of its own logits inside a tie band
+    picks = jnp.asarray(state["rows"][:, :, 0].swapaxes(0, 1))
+    want_l = np.asarray(jax.jit(lambda p, t, theirs: reference.forward(
+        p, t, sizes_ref, last=n_steps + 1, theirs=theirs,
+        tie_band=0.1)["logits"])(
+        backend.params, jnp.asarray(ids, jnp.int32), picks), np.float64)
+    errors = (np.linalg.norm(got - want_l, axis=-1)
+              / np.linalg.norm(want_l, axis=-1))
+    c.check("logits within 0.05 of the plain reference, prefill and decode",
+            bool(np.all(np.isfinite(errors)) and errors.max() <= 0.05),
+            errors.tolist())
+    rep = c.report()
+    rep.update(first_call_s=round(first_s, 2),
+               second_call_s=round(second_s, 2),
+               parity_errors=errors.tolist(),
+               distinct_experts_a_decode_step=per_step,
+               prefill_blocks=blocks, expert_tokens=tokens.tolist(),
+               engine=backend.describe())
+    return rep
+
+
 def _rehearsal_server(argv: list[str]) -> int:
     """The rehearsal's server child: the real serve.server.main, with the
     engine's kernels emulated (the product has no such flag, on purpose)."""
@@ -747,7 +852,8 @@ def _child(args) -> int:
         compiles = _watch_compiles()
         rep.update({"device": phase_device, "kernels": phase_kernels,
                     "offline": phase_offline, "mesh": phase_mesh,
-                    "experts": phase_experts}[phase](args))
+                    "experts": phase_experts,
+                    "moe": phase_moe}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

@@ -1,0 +1,340 @@
+"""The SmallThinker family (models/smallthinker.py) and the expert layer it
+shares with DeepSeek-V2 (models/experts.py), on the CPU at a tiny size: two
+periods of [global, window, window, window], 8 experts top-2, a window
+shorter than the prompts."""
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from vnsum_tpu.models import MODEL_REGISTRY, experts, llama
+from vnsum_tpu.models import smallthinker as st
+from vnsum_tpu.models.family import family_of
+
+
+def _tokens(n=60, rows=2, seed=1):
+    return jax.random.randint(jax.random.key(seed), (rows, n), 0, 384)
+
+
+@pytest.fixture(scope="module")
+def tiny():
+    cfg = st.tiny_smallthinker()
+    return cfg, st.init_params(jax.random.key(0), cfg)
+
+
+# -- the config ----------------------------------------------------------------
+
+
+def test_published_config_and_its_period():
+    cfg = st.smallthinker_21b_a3b()
+    assert (cfg.dim, cfg.n_layers, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim,
+            cfg.moe_intermediate, cfg.n_routed_experts,
+            cfg.num_experts_per_tok, cfg.vocab_size, cfg.sliding_window) == (
+        2560, 52, 28, 4, 128, 768, 64, 6, 151_936, 4096)
+    assert cfg.q_per_kv == 7 and cfg.n_held == 64 and cfg.act == "relu"
+    assert cfg.rope_theta == 1.5e6 and not cfg.tie_embeddings
+    assert cfg.sliding_window_layout == cfg.rope_layout == (0, 1, 1, 1) * 13
+    assert st.layer_windows(cfg) == (0, 4096, 4096, 4096) * 13
+    assert MODEL_REGISTRY["smallthinker-21b-a3b"](n_layers=16) == \
+        st.smallthinker_21b_a3b(n_layers=16)
+    assert MODEL_REGISTRY["tiny-smallthinker"]() == st.tiny_smallthinker()
+
+
+@pytest.mark.parametrize("kw, text", [
+    (dict(sliding_window_layout=(0, 1)), "sliding_window_layout has 2"),
+    (dict(rope_layout=(1,) * 9), "rope_layout has 9"),
+    (dict(n_heads=5), "n_kv_heads must divide"),
+])
+def test_config_refuses_what_it_cannot_mean(kw, text):
+    with pytest.raises(ValueError, match=text):
+        st.tiny_smallthinker(**kw)
+
+
+def test_llama_config_gained_no_field():
+    """The family reuses llama's pieces, not its config."""
+    names = {f.name for f in dataclasses.fields(llama.LlamaConfig)}
+    assert names == {
+        "vocab_size", "dim", "n_layers", "n_heads", "n_kv_heads", "head_dim",
+        "intermediate", "rope_theta", "use_llama3_rope_scaling",
+        "rope_scale_factor", "rope_low_freq_factor", "rope_high_freq_factor",
+        "rope_original_max_len", "norm_eps", "max_seq_len", "tie_embeddings",
+        "qk_norm", "act", "sandwich_norms", "norm_plus_one", "embed_scale",
+        "query_scale", "sliding_window", "layer_is_global",
+        "rope_local_theta", "rope_linear_factor", "w8a8_prefill", "dtype"}
+
+
+# -- routing -------------------------------------------------------------------
+
+
+def test_route_is_top_k_on_the_logits_and_softmax_over_the_picked():
+    logits = jnp.asarray([[0.1, 2.0, -1.0, 1.0], [3.0, 0.0, 0.5, 2.9]])
+    ids, w = st.route(logits, 2)
+    assert ids.tolist() == [[1, 3], [0, 3]] and ids.dtype == jnp.int32
+    e = np.exp([2.0, 1.0])
+    np.testing.assert_allclose(w[0], e / e.sum(), rtol=1e-6)
+    np.testing.assert_allclose(w.sum(-1), 1.0, rtol=1e-6)
+    # not the picks' shares of a softmax over all four
+    assert abs(float(w[0, 0]) - float(jax.nn.softmax(logits[0])[1])) > 0.05
+
+
+@pytest.mark.parametrize("act, fn", [
+    ("relu", lambda x: np.maximum(x, 0)), ("silu", lambda x: x / (1 + np.exp(-x))),
+    ("gelu_tanh", None)])
+def test_mlp_act_knows_relu(act, fn):
+    x = jnp.linspace(-3, 3, 13)
+    got = np.asarray(llama._mlp_act(x, act))
+    if fn is None:
+        np.testing.assert_allclose(got, jax.nn.gelu(x, approximate=True))
+    else:
+        np.testing.assert_allclose(got, fn(np.asarray(x)), rtol=1e-6, atol=1e-7)
+
+
+# -- the layer's mechanisms -----------------------------------------------------
+
+
+def _shifted(cfg, params, toks, shift):
+    """Logits with every position moved by ``shift``."""
+    B, S = toks.shape
+    pos = jnp.broadcast_to(jnp.arange(S)[None] + shift, (B, S))
+    mask = jnp.broadcast_to(jnp.tril(jnp.ones((S, S), bool))[None], (B, S, S))
+    return st.forward(params, cfg, toks, pos, st.init_cache(cfg, B, S), 0,
+                      mask)[0]
+
+
+@pytest.mark.parametrize("rope_layout", [
+    (0,) * 8,           # position-free layers take no position
+    (0, 1, 1, 1) * 2,   # rotary is relative: a common shift is none
+])
+def test_a_common_shift_of_the_positions_changes_nothing(tiny, rope_layout):
+    _, params = tiny
+    cfg = st.tiny_smallthinker(rope_layout=rope_layout)
+    toks = _tokens()
+    np.testing.assert_allclose(_shifted(cfg, params, toks, 0),
+                               _shifted(cfg, params, toks, 11), atol=2e-5)
+
+
+def test_rotary_layers_do_read_positions(tiny):
+    """Stretching positions (not shifting them) changes rotary layers'
+    result and leaves an all-global, position-free stack alone."""
+    _, params = tiny
+    toks = _tokens()
+    B, S = toks.shape
+    mask = jnp.broadcast_to(jnp.tril(jnp.ones((S, S), bool))[None], (B, S, S))
+
+    def run(cfg, stretch):
+        pos = jnp.broadcast_to(jnp.arange(S)[None] * stretch, (B, S))
+        return st.forward(params, cfg, toks, pos, st.init_cache(cfg, B, S),
+                          0, mask)[0]
+
+    free = st.tiny_smallthinker(rope_layout=(0,) * 8)
+    np.testing.assert_allclose(run(free, 1), run(free, 3), atol=2e-5)
+    mixed = st.tiny_smallthinker()
+    assert float(jnp.abs(run(mixed, 1) - run(mixed, 3)).max()) > 1e-3
+
+
+@pytest.mark.parametrize("window, same", [(60, True), (256, True), (24, False),
+                                          (8, False)])
+def test_window_layers_drop_keys_past_the_window(tiny, window, same):
+    """A window no shorter than the prompt is no window; a shorter one
+    changes the result, on the window layers alone."""
+    _, params = tiny
+    toks = _tokens()
+    whole = st.forward_dense(
+        params, st.tiny_smallthinker(sliding_window_layout=(0,) * 8), toks)
+    got = st.forward_dense(
+        params, st.tiny_smallthinker(sliding_window=window), toks)
+    assert (float(jnp.abs(got - whole).max()) < 2e-5) is same
+    # the first ``window`` positions never leave it
+    np.testing.assert_allclose(got[:, :min(window, 60)],
+                               whole[:, :min(window, 60)], atol=2e-5)
+
+
+def test_router_reads_the_layers_input_not_the_attention(tiny):
+    """Zeroing the attention's output projection leaves every pick as it
+    was: the router hangs on nothing the attention computes."""
+    cfg, params = tiny
+    toks = _tokens(rows=1)
+    cut = dict(params, layers=dict(params["layers"],
+                                   wo=jnp.zeros_like(params["layers"]["wo"])))
+    S = toks.shape[1]
+    pos = jnp.arange(S)[None]
+    mask = jnp.tril(jnp.ones((S, S), bool))[None]
+
+    def first_layer_picks(p):
+        one = st.tiny_smallthinker(n_layers=1)
+        p = dict(p, layers=jax.tree.map(lambda a: a[:1], p["layers"]))
+        return st.forward(p, one, toks, pos, st.init_cache(one, 1, S), 0,
+                          mask)[1]["picks"]
+
+    np.testing.assert_array_equal(first_layer_picks(params),
+                                  first_layer_picks(cut))
+
+
+# -- state and counters ----------------------------------------------------------
+
+
+def test_counters_count_every_real_token_and_pick(tiny):
+    cfg, params = tiny
+    toks = _tokens(rows=2)
+    B, S = toks.shape
+    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+    # row 1 has a left pad of 7: those tokens attend nothing
+    pads = jnp.asarray([0, 7])
+    mask = llama.prefill_attention_mask(pads, S, S)
+    _, cache = st.forward(params, cfg, toks, pos, st.init_cache(cfg, B, S),
+                          0, mask)
+    real = 2 * S - 7
+    k, L = cfg.num_experts_per_tok, cfg.n_layers
+    assert int(cache["slots_routed"]) == int(cache["slots_held"]) == real * k * L
+    assert cache["expert_tokens"].shape == (L, cfg.n_held)
+    assert int(cache["expert_tokens"].sum()) == real * k * L
+    assert (np.asarray(cache["expert_tokens"]).sum(1) == real * k).all()
+    assert st.last_picks(cache).shape == (L, B, k)
+    # a multi-token forward is no decode step
+    assert int(cache["decode_touched"]) == int(cache["decode_layer_steps"]) == 0
+    assert set(st.counters(cache)) == {
+        "expert_tokens", "slots_routed", "slots_held", "decode_touched",
+        "decode_layer_steps"}
+
+
+def test_decode_steps_count_the_distinct_experts_they_touch(tiny):
+    cfg, params = tiny
+    B, C = 3, 16
+    cache = st.init_cache(cfg, B, C)
+    toks = _tokens(n=4, rows=B)
+    touched = 0
+    for t in range(4):
+        mask = jnp.broadcast_to(jnp.arange(C)[None, None] <= t, (B, 1, C))
+        before = np.asarray(cache["expert_tokens"])
+        _, cache = st.forward(params, cfg, toks[:, t:t + 1],
+                              jnp.full((B, 1), t), cache, t, mask)
+        step = np.asarray(cache["expert_tokens"]) - before
+        assert (step.sum(1) == B * cfg.num_experts_per_tok).all()
+        touched += int((step > 0).sum())
+    assert int(cache["decode_touched"]) == touched
+    assert int(cache["decode_layer_steps"]) == 4 * cfg.n_layers
+    k = cfg.num_experts_per_tok
+    assert 4 * cfg.n_layers * k <= touched <= 4 * cfg.n_layers * min(8, B * k)
+
+
+def test_deepseek_state_has_no_decode_counter():
+    """The counter is the family's to ask for: DeepSeek-V2's programs carry
+    what they carried."""
+    from vnsum_tpu.models import deepseek as ds
+
+    cache = ds.init_cache(ds.tiny_deepseek(), 2, 8)
+    assert set(cache) == {"latent", "expert_tokens", "slots_routed",
+                          "slots_held", "picks"}
+    assert set(ds.counters(cache)) == {"expert_tokens", "slots_routed",
+                                       "slots_held"}
+
+
+# -- the shared expert layer ------------------------------------------------------
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("act", ["relu", "silu"])
+def test_grouped_experts_agree_with_dense_experts(tiny, act, int8):
+    """The kernel path (interpreted) against the plain masked sum, ReGLU and
+    SwiGLU, float and int8 weights, some picks not held."""
+    from vnsum_tpu.models.quant import quantize_params
+
+    _, params = tiny
+    cfg = st.tiny_smallthinker(act=act)
+    if int8:
+        params = quantize_params(params)
+    stacked = {n: params["layers"][n] for n in experts.EXPERT_LEAVES}
+    x = jax.random.normal(jax.random.key(4), (40, cfg.dim))
+    ids = jax.random.randint(jax.random.key(5), (40, 2), -1, 8)
+    w = jax.random.uniform(jax.random.key(6), (40, 2))
+    dense = experts.dense_experts(x, ids, w, stacked, 3, cfg)
+    grouped = experts.grouped_experts(x, ids, w, stacked, 3, cfg,
+                                      interpret=True)
+    assert float(jnp.abs(dense).max()) > 1e-3
+    np.testing.assert_allclose(grouped, dense, atol=1e-5)
+
+
+def test_expert_matmul_refuses_an_unknown_gate():
+    from vnsum_tpu.ops.expert_matmul import expert_grouped_matmul
+
+    w = jnp.zeros((1, 1, 8, 128))
+    with pytest.raises(ValueError, match="silu or relu"):
+        expert_grouped_matmul(
+            jnp.zeros((16, 8)), None, w, w, 0, jnp.zeros((1,), jnp.int32),
+            jnp.ones((1,), jnp.int32), tm=16, tn=128, out_dtype=jnp.float32,
+            act="gelu", interpret=True)
+
+
+# -- the engine's seam ------------------------------------------------------------
+
+
+def test_family_resolves_and_names_what_it_lacks():
+    fam = family_of(st.tiny_smallthinker())
+    assert fam is st.FAMILY and fam.name == "smallthinker"
+    assert fam.int8_cache and fam.counts_prefill_blocks
+    assert fam.layer_windows(st.tiny_smallthinker()) == (0, 24, 24, 24) * 2
+    assert set(fam.missing) == {"slot loop", "prefix cache", "mesh",
+                                "speculative decoding",
+                                "long-context backend"}
+
+
+@pytest.mark.parametrize("entry", sorted(st.FAMILY.missing))
+def test_family_refuses_by_the_text_of_what_it_lacks(entry):
+    with pytest.raises(NotImplementedError) as e:
+        st.FAMILY.refuse(entry)
+    assert "smallthinker" in str(e.value) and entry in str(e.value)
+    assert st.FAMILY.missing[entry] in str(e.value)
+    assert len(st.FAMILY.missing[entry]) > 60   # says what, not just no
+
+
+@pytest.mark.parametrize("kw", [dict(cache_blocks=8), dict(mesh=object())])
+def test_engine_refuses_the_entries_at_construction(tiny, kw):
+    from vnsum_tpu.backend.engine import TpuBackend
+
+    cfg, params = tiny
+    with pytest.raises(NotImplementedError, match="smallthinker"):
+        TpuBackend(model_config=cfg, params=params, interpret=True, **kw)
+
+
+def test_llama_family_hands_out_gemmas_windows():
+    from vnsum_tpu.models import gemma3_4b, qwen3_8b
+
+    windows = llama.FAMILY.layer_windows(gemma3_4b())
+    assert len(windows) == 34 and set(windows) == {0, 1024}
+    assert [i for i, w in enumerate(windows) if not w] == [5, 11, 17, 23, 29]
+    assert llama.FAMILY.layer_windows(qwen3_8b()) is None
+    with pytest.raises(ValueError, match="layer_is_global has 2"):
+        llama.FAMILY.layer_windows(
+            gemma3_4b(layer_is_global=(True, False)))
+
+
+@pytest.mark.parametrize("quantize_kv", [False, True])
+def test_engine_generates_through_the_kernels_with_a_window(tiny, quantize_kv):
+    """``TpuBackend.generate`` with the kernels interpreted: chunked
+    prefill, the window a per-layer scalar, counters returned with the
+    output, the prefill's cells counted by class with the window."""
+    from vnsum_tpu.backend.engine import TpuBackend
+
+    cfg, params = tiny
+    be = TpuBackend(model_config=cfg, tokenizer="byte", params=params,
+                    batch_size=2, max_new_tokens=6, interpret=True,
+                    quantize_kv=quantize_kv, prefill_chunk_tokens=128)
+    outs = be.generate(["xin chào " * 22, "một hai ba"], max_new_tokens=6)
+    st_ = be.stats
+    assert len(outs) == 2
+    assert list(st_.attention_paths.values()) == [
+        {"prefill": "kernel", "decode": "kernel"}]
+    assert st_.expert_slots_held == st_.expert_slots_routed > 0
+    assert np.asarray(st_.expert_tokens).shape == (8, 8)
+    assert int(np.sum(st_.expert_tokens)) == st_.expert_slots_held
+    assert st_.expert_decode_layer_steps == 6 * 8
+    assert 6 * 8 * 2 <= st_.expert_decode_touched <= 6 * 8 * 4
+    assert sum(st_.prefill_blocks.values()) > 0
+    window = be._layer_window_fn()
+    assert [int(window(i)) for i in range(8)] == [0, 24, 24, 24] * 2
+    with pytest.raises(NotImplementedError, match="slot loop"):
+        be.start_slot_loop(2)

@@ -1,5 +1,6 @@
-"""The DeepSeek-V2 family's three kernels compiled for the chip at the
-published widths, with no chip: the TPU's compiler is installed here and
+"""The DeepSeek-V2 family's three kernels, and the GQA kernels and the
+expert product at the SmallThinker family's geometry, compiled for the chip
+at the published widths, with no chip: the TPU's compiler is installed here and
 compiles for a described v5e. Interpret mode cannot show what this does: a
 slice not aligned to the tiling, too much VMEM, an int8 product Mosaic
 refuses. Nothing runs and nothing is timed.
@@ -82,22 +83,36 @@ def test_absorbed_decode_kernel_compiles_over_the_576_wide_cache(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
-@pytest.mark.parametrize("tm,tiles", [(256, 233), (32, 46)])
-def test_grouped_expert_product_compiles_on_int8_rows(one_chip, tm, tiles):
+# (layers, experts held, hidden, expert width, gate, column tiles)
+_DEEPSEEK = (7, 40, 5120, 1536, "silu", (512, 1280))
+_SMALLTHINKER = (16, 64, 2560, 768, "relu", (768, 2560))
+
+
+@pytest.mark.parametrize("widths,tm,tiles", [
+    (_DEEPSEEK, 256, 233), (_DEEPSEEK, 32, 46),
+    # a piece of 8,192 tokens x 6 picks over 64 held experts; a decode step
+    # of 24 rows
+    (_SMALLTHINKER, 256, 257), (_SMALLTHINKER, 32, 70),
+])
+def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
+                                                      tiles):
     """Both products of an expert at the prefill's and the decode's row
-    tile: s8 x s8 -> s32 over K = 5120 and K = 1536, weights read from the
-    stack of 7 layers x 40 experts in place."""
-    from vnsum_tpu.models.deepseek import _column_tile
+    tile: s8 x s8 -> s32 over K = hidden and K = the expert's width, weights
+    read from the stack of layers x held experts in place; DeepSeek-V2's
+    SwiGLU experts of 5120 x 1536 and SmallThinker's ReGLU ones of 2560 x
+    768."""
+    from vnsum_tpu.models.experts import _column_tile
     from vnsum_tpu.ops.expert_matmul import expert_grouped_matmul
 
-    L, E, D, F, M = 7, 40, 5120, 1536, tm * tiles
+    L, E, D, F, act, column_tiles = widths
+    M = tm * tiles
     up = {"q": ((L, E, D, F), I8), "s": ((L, E, F), F32)}
     down = {"q": ((L, E, F, D), I8), "s": ((L, E, D), F32)}
     sched = (((tiles,), I32), ((1,), I32))
     c = _compiled(
         lambda x, xs, w, u, te, nu: expert_grouped_matmul(
             x, xs, w, u, 2, te, nu, tm=tm, tn=_column_tile(D, F),
-            out_dtype=BF16),
+            out_dtype=BF16, act=act),
         one_chip, ((M, D), I8), ((M, 1), F32), up, up, *sched)
     assert "tpu_custom_call" in c.as_text()
     c = _compiled(
@@ -106,4 +121,37 @@ def test_grouped_expert_product_compiles_on_int8_rows(one_chip, tm, tiles):
             out_dtype=BF16),
         one_chip, ((M, F), I8), ((M, 1), F32), down, *sched)
     assert "tpu_custom_call" in c.as_text()
-    assert (_column_tile(D, F), _column_tile(F, D)) == (512, 1280)
+    assert (_column_tile(D, F), _column_tile(F, D)) == column_tiles
+
+
+def _int8_cache(L, B, KV, C, hd):
+    return {"k": ((L, B, KV, C, hd), I8), "v": ((L, B, KV, C, hd), I8),
+            "ks": ((L, B, KV, C), F32), "vs": ((L, B, KV, C), F32)}
+
+
+@pytest.mark.parametrize("offset", [0, 2048, 6144])
+def test_gqa_prefill_kernel_compiles_at_g7_under_a_window(one_chip, offset):
+    """28/4 heads of 128 (a group of 7: bq 512 / bk 512), a 2,048-query
+    chunk of the S=8192 bucket over the int8 cache of 8,448 slots, the
+    layer's window a traced scalar."""
+    from vnsum_tpu.ops.flash_attention import flash_prefill_attention
+
+    c = _compiled(
+        lambda q, cache, pads, win: flash_prefill_attention(
+            q, cache, 3, pads, 7, win, offset),
+        one_chip, ((2, 2048, 28, 128), BF16), _int8_cache(4, 2, 4, 8448, 128),
+        ((2,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_gqa_decode_kernel_compiles_at_g7_under_a_window(one_chip):
+    """7 query rows a KV head — the first group size here that is no
+    divisor of the 8-sublane tile — over the int8 cache, 24 rows."""
+    from vnsum_tpu.ops.decode_attention import flash_decode_attention
+
+    c = _compiled(
+        lambda q, cache, pads, win: flash_decode_attention(
+            q, cache, 3, pads, 8200, 7, win),
+        one_chip, ((24, 1, 28, 128), BF16), _int8_cache(4, 24, 4, 8448, 128),
+        ((24,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()

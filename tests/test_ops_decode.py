@@ -55,11 +55,14 @@ def test_decode_kernel_ignores_past_fill_garbage():
     np.testing.assert_allclose(np.asarray(clean), np.asarray(poisoned))
 
 
+@pytest.mark.parametrize("H,KV", [(4, 2), (28, 4)])
 @pytest.mark.parametrize("win,fill", [(1, 37), (8, 37), (16, 8), (64, 37)])
-def test_decode_windowed_matches_dense(win, fill):
+def test_decode_windowed_matches_dense(win, fill, H, KV):
     """Sliding-window decode: kernel vs dense with the slot-space window
-    (k_slot > fill - win), including win > fill (window not yet binding)."""
-    L, B, KV, C, H, hd = 1, 2, 2, 64, 4, 128
+    (k_slot > fill - win), including win > fill (window not yet binding);
+    at 4/2 heads and at SmallThinker's 28/4 (7 query rows a KV head: the
+    first group size that is no divisor of the 8-sublane tile)."""
+    L, B, C, hd = 1, 2, 64, 128
     q, cache = make_case(L, B, KV, C, H, hd, seed=13)
     pad = jnp.asarray([0, 3], jnp.int32)
     mask = decode_attention_mask(pad, fill, C) & (

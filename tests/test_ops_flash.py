@@ -85,12 +85,14 @@ def test_flash_multiple_k_blocks():
     )
 
 
+@pytest.mark.parametrize("H,KV", [(2, 1), (28, 4)])
 @pytest.mark.parametrize("win", [1, 8, 24])
-def test_flash_windowed_matches_dense(win):
-    """Sliding-window clamp (Gemma local layers): kernel vs the dense path's
-    slot-space window mask (models.llama._block: k_slot > q_slot - window),
-    on shapes where below-window whole blocks get clamped/elided."""
-    L, B, S, C, H, KV, hd = 1, 2, 45, 61, 2, 1, 128
+def test_flash_windowed_matches_dense(win, H, KV):
+    """Sliding-window clamp (Gemma local layers; SmallThinker's window
+    layers at 28/4 heads, a group of 7): kernel vs the dense path's
+    slot-space window mask (models.llama._in_window: k_slot > q_slot -
+    window), on shapes where below-window whole blocks get clamped/elided."""
+    L, B, S, C, hd = 1, 2, 45, 61, 128
     q, cache = make_case(L, B, S, C, H, KV, hd, seed=9)
     pads = [0, 5]
     pad = jnp.asarray(pads, jnp.int32)

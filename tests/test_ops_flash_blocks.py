@@ -78,3 +78,41 @@ def test_block_classes_of_the_offline_cells_tail_chunks(pad, live):
     assert total["interior"] + total["edge"] == live
     if pad == 0:
         assert total["interior"] == 56
+
+
+@pytest.mark.parametrize("G,hd,want,why", [
+    (3, 128, (512, 2048), "the measured default"),
+    (4, 128, (512, 1024), "G * bk held to 3 * 2048: Qwen3, Phi-4"),
+    (2, 256, (512, 1024), "bk shrinks with the head size: Gemma3"),
+    # SmallThinker's 28/4 heads: 7 * 1024 is over 3 * 2048 too, so the
+    # per-head score temporaries of the static unroll push bk down to its
+    # floor of 512 — the (512, 512) tile PR 34 measured at twice the cost a
+    # score of (1024, 1024) on the latent kernel (PERF.md section 7)
+    (7, 128, (512, 512), "G * bk held to 3 * 2048, bk at its floor"),
+])
+def test_block_geometry_by_group_size(G, hd, want, why):
+    assert flash_attention._block_geometry(2048, 8448, G, hd) == want, why
+    assert flash_attention._block_geometry(8192, 8448, G, hd) == want, why
+
+
+@pytest.mark.parametrize("window", [0, 4096])
+def test_block_classes_of_a_window_layer_at_g7(window):
+    """The SmallThinker cell's map dispatch at the kernel's geometry for G=7
+    (bq 512 / bk 512; four 2048-query chunks of a full row over C=8448): a
+    global layer computes the 136 cells on and under the diagonal; a
+    4096-window layer computes, of those, only the cells that reach into
+    some query's window: 9 key blocks a query block past the eighth."""
+    total = dict.fromkeys(BLOCK_CLASSES, 0)
+    for lo in range(0, 8192, 2048):
+        for name, n in prefill_block_classes(
+            [0], 2048, 8448, lo, window, G=7, hd=128
+        ).items():
+            total[name] += n
+    computed = total["interior"] + total["edge"]
+    assert sum(total.values()) == 16 * 17 and total["dead_pad"] == 0
+    if not window:
+        assert computed == 136 and total["interior"] == 120
+    else:
+        # query block i (0..15) sees key blocks max(0, i - 8) .. i
+        assert computed == sum(min(i, 8) + 1 for i in range(16)) == 108
+        assert total["interior"] == 0     # a window layer masks every cell

@@ -113,13 +113,17 @@ class EngineStats:
     attention_paths: dict = field(default_factory=dict)
     # host-phase wall clock (always on: the timers wrap pure-host work)
     phase_seconds: dict = field(default_factory=dict)
-    # sparse-expert families (models/deepseek.py), summed on the device and
+    # sparse-expert families (models/experts.py), summed on the device and
     # returned with each one-shot program's output: token x pick pairs the
     # router saw, those that fell on an expert held here, and tokens per
-    # expert layer and held expert ([layers][experts] once a dispatch ran)
+    # expert layer and held expert ([layers][experts] once a dispatch ran);
+    # where the family counts them, distinct experts with a token summed
+    # over decode steps and layers, and the (step, layer) pairs counted
     expert_slots_routed: int = 0
     expert_slots_held: int = 0
     expert_tokens: list = field(default_factory=list)
+    expert_decode_touched: int = 0
+    expert_decode_layer_steps: int = 0
 
     def add_phase(self, name: str, seconds: float) -> None:
         self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + seconds
@@ -703,23 +707,20 @@ class TpuBackend:
     # -- shared prefill wiring -------------------------------------------
 
     def _layer_window_fn(self):
-        """Per-layer runtime window scalar for sliding-window (Gemma)
-        configs: 0 on global layers, else the config window — one compiled
-        kernel serves both kinds. None-returning on dense configs."""
-        cfg = self.cfg
-        if getattr(cfg, "sliding_window", 0):
-            from ..models.llama import _layer_global_flags
+        """Per-layer runtime window scalar for configs with sliding-window
+        layers (``Family.layer_windows``): 0 on global layers, else the
+        layer's window — one compiled kernel serves both kinds.
+        None-returning where no layer has a window."""
+        windows = self.family.layer_windows(self.cfg)
+        if windows is None:
+            return lambda layer_idx: None
 
-            def layer_window(layer_idx):
-                # flags built at trace time: a device array closed over
-                # from outside would be fetched to the host while tracing
-                return jnp.where(
-                    _layer_global_flags(cfg)[layer_idx], 0,
-                    cfg.sliding_window,
-                ).astype(jnp.int32)
+        def layer_window(layer_idx):
+            # the table is built at trace time: a device array closed over
+            # from outside would be fetched to the host while tracing
+            return jnp.asarray(windows, jnp.int32)[layer_idx]
 
-            return layer_window
-        return lambda layer_idx: None
+        return layer_window
 
     def _init_prefill_cache(self, B: int, C: int):
         """Fresh KV cache with the mesh layout pinned (batch over data,
@@ -806,11 +807,9 @@ class TpuBackend:
         from ..ops.flash_attention import prefill_block_classes
 
         cfg = self.cfg
-        layers = {0: cfg.n_layers}   # {window: layers that run with it}
-        if cfg.sliding_window:
-            n_global = sum(map(bool, cfg.layer_is_global))
-            layers = {0: n_global,
-                      cfg.sliding_window: cfg.n_layers - n_global}
+        # {window: layers that run with it}
+        windows = self.family.layer_windows(cfg) or (0,) * cfg.n_layers
+        layers = {w: windows.count(w) for w in sorted(set(windows))}
         total = self.stats.prefill_blocks
         for window, n_layers in layers.items():
             for lo, hi in self._prefill_spans(S, start):
@@ -1863,6 +1862,9 @@ class TpuBackend:
         st = self.stats
         st.expert_slots_routed += int(counted["slots_routed"])
         st.expert_slots_held += int(counted["slots_held"])
+        st.expert_decode_touched += int(counted.get("decode_touched", 0))
+        st.expert_decode_layer_steps += int(
+            counted.get("decode_layer_steps", 0))
         tokens = np.asarray(counted["expert_tokens"], np.int64)
         if st.expert_tokens:
             tokens = tokens + np.asarray(st.expert_tokens, np.int64)

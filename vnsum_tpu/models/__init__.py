@@ -35,6 +35,18 @@ def _deepseek_v2(**kw):
     return deepseek_v2(**kw)
 
 
+def _smallthinker(**kw):
+    from .smallthinker import smallthinker_21b_a3b
+
+    return smallthinker_21b_a3b(**kw)
+
+
+def _tiny_smallthinker(**kw):
+    from .smallthinker import tiny_smallthinker
+
+    return tiny_smallthinker(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -53,6 +65,10 @@ MODEL_REGISTRY = {
     "tiny": tiny_llama,
     # another family (models/deepseek.py): latent attention, sparse experts
     "deepseek-v2": _deepseek_v2,
+    # a third (models/smallthinker.py): GQA with window and global layers
+    # mixed, sparse ReGLU experts routed on the layer's input
+    "smallthinker-21b-a3b": _smallthinker,
+    "tiny-smallthinker": _tiny_smallthinker,
 }
 
 __all__ = [

@@ -3,9 +3,11 @@ experts with shared experts, for the one-shot generation program.
 
 A second model family beside ``models/llama.py``. It reuses that module's
 primitives (``_proj``, ``_rmsnorm``, ``_embed_lookup``, ``_lm_head_logits``,
-``_apply_rope``, ``_mlp_act``) and the ``{"q", "s"}`` int8 leaves of
-``models/quant.py``; what it owns is the config, the parameters, the cache,
-the block and ``forward``. ``FAMILY`` at the end is what the engine's seam
+``_apply_rope``, ``_mlp_act``), the ``{"q", "s"}`` int8 leaves of
+``models/quant.py`` and the expert layer of ``models/experts.py`` (picks ->
+experts held here -> counters -> grouped product; ``models/smallthinker.py``
+runs the same); what it owns is the config, the parameters, the cache, its
+routing rule, the shared experts, the block and ``forward``. ``FAMILY`` at the end is what the engine's seam
 (``backend/family.py``) picks up for a ``DeepseekV2Config``.
 
 The layer (``benchmarks/reference_deepseek_v2.py`` is the same equations in
@@ -58,6 +60,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from .experts import (  # noqa: F401  (dense_experts: a name tests take here)
+    EXPERT_LEAVES,
+    _EXPERT_PIECE_TOKENS,
+    counters,
+    dense_experts,
+    expert_layer,
+    grouped_experts,
+    init_expert_state,
+    last_picks,
+)
 from .llama import (
     _apply_rope,
     _embed_lookup,
@@ -200,7 +212,7 @@ def rope_cos_sin(cfg: DeepseekV2Config, positions: jax.Array):
 
 # -- parameters and state -----------------------------------------------------
 
-_EXPERTS = ("we_gate", "we_up", "we_down")
+_EXPERTS = EXPERT_LEAVES
 
 
 def init_params(key: jax.Array, cfg: DeepseekV2Config) -> dict:
@@ -261,30 +273,9 @@ def init_cache(cfg: DeepseekV2Config, batch: int, cache_len: int, *,
     return {
         "latent": jnp.zeros(
             (cfg.n_layers, batch, cache_len, cfg.latent_width), cfg.dtype),
-        "expert_tokens": jnp.zeros(
-            (cfg.n_expert_layers, cfg.n_held), jnp.int32),
-        "slots_routed": jnp.zeros((), jnp.int32),
-        "slots_held": jnp.zeros((), jnp.int32),
-        # what each expert layer's router picked for the last token of the
-        # latest forward, row by row (``last_picks``)
-        "picks": jnp.zeros(
-            (cfg.n_expert_layers, batch, cfg.num_experts_per_tok), jnp.int32),
+        **init_expert_state(cfg.n_expert_layers, cfg.n_held, batch,
+                            cfg.num_experts_per_tok),
     }
-
-
-def counters(cache: dict) -> dict:
-    """The expert counters of a program's final state."""
-    return {k: cache[k] for k in
-            ("expert_tokens", "slots_routed", "slots_held")}
-
-
-def last_picks(cache: dict) -> jax.Array:
-    """[expert layers, B, k] expert ids: the routers' picks for the last
-    token of the latest forward. A parity check needs them: where two
-    experts score within rounding of each other the program and a reference
-    may each rightly pick another (``TpuBackend.prefill_then_decode_logits``
-    hands them out position by position)."""
-    return cache["picks"]
 
 
 # -- routing and experts ------------------------------------------------------
@@ -308,141 +299,22 @@ def route(scores: jax.Array, cfg: DeepseekV2Config):
     return ids.astype(jnp.int32), weights * cfg.routed_scaling_factor
 
 
-def _quantize_rows(x: jax.Array):
-    """x [M, K] -> (int8, per-row float32 scale [M, 1])."""
-    x32 = x.astype(jnp.float32)
-    s = jnp.maximum(jnp.max(jnp.abs(x32), axis=-1, keepdims=True), 1e-8) / 127.0
-    return jnp.clip(jnp.round(x32 / s), -127, 127).astype(jnp.int8), s
-
-
-def _column_tile(K: int, N: int) -> int:
-    """Columns of a weight tile: the widest divisor of N in whole lanes
-    whose int8 tile [K, tn] stays under ~2.8 MB of VMEM."""
-    fits = [d for d in range(128, N + 1, 128)
-            if N % d == 0 and d * K <= 2_800_000]
-    return max(fits) if fits else N
-
-
-# tokens one grouped product takes at once: bounds the worst-case row
-# buffers (every pick of every token held here) at ~1.2 GB at the
-# published widths, while an expert still sees ~300 rows a weight fetch
-_EXPERT_PIECE_TOKENS = 8192
-
-
-def grouped_experts(x, local, weights, experts, slot,
-                    cfg: DeepseekV2Config, *, interpret: bool):
-    """The routed experts held here, through ``expert_grouped_matmul``.
-
-    x [T, D]; ``local`` [T, k] the picks as local expert ids, -1 where a pick
-    is not held (or the token is padding); ``weights`` [T, k]; ``experts``
-    the STACKED ``we_gate``/``we_up``/``we_down`` of every expert layer and
-    ``slot`` this layer's index in them (the kernel reads the stack in
-    place). Returns the weighted sum over each token's held picks, [T, D]."""
-    from ..ops.expert_matmul import expert_grouped_matmul, expert_layout
-
-    T, D = x.shape
-    k = local.shape[1]
-    quantized = isinstance(experts["we_gate"], dict)
-    # int8 rows (s8 x s8) whenever the weights are int8 and the engine runs
-    # W8A8: in a decode step too, where converting each expert's weight
-    # tile to bf16 in the kernel would cost more than fetching it
-    int8_rows = quantized and cfg.w8a8_prefill
-    tm = 256 if T >= 1024 else (32 if int8_rows else 16)
-    F = cfg.moe_intermediate
-
-    def piece(args):
-        x, local, weights = args
-        Tp = x.shape[0]
-        row_of_slot, tile_expert, used, _sizes, M = expert_layout(
-            local.reshape(-1), cfg.n_held, tm)
-        token_of_row = jnp.zeros((M,), jnp.int32).at[row_of_slot].set(
-            jnp.arange(Tp * k, dtype=jnp.int32) // k)
-        call = dict(layer=slot, tile_expert=tile_expert, tiles_used=used,
-                    tm=tm, interpret=interpret)
-        if int8_rows:
-            xq, xs = _quantize_rows(x)
-            rows, scale = xq[token_of_row], xs[token_of_row]
-        else:
-            rows, scale = x[token_of_row], None
-        hidden = expert_grouped_matmul(
-            rows, scale, experts["we_gate"], experts["we_up"],
-            tn=_column_tile(D, F),
-            out_dtype=x.dtype, **call)
-        if int8_rows:
-            hidden, scale = _quantize_rows(hidden)
-        y = expert_grouped_matmul(
-            hidden, scale, experts["we_down"], None, tn=_column_tile(F, D),
-            out_dtype=x.dtype, **call)
-        rows_of = row_of_slot.reshape(Tp, k)
-        out = jnp.zeros((Tp, D), jnp.float32)
-        for i in range(k):
-            # rows of tiles the kernel skipped are unspecified: select, do
-            # not multiply by a zero weight
-            out = out + jnp.where(
-                (local[:, i] >= 0)[:, None],
-                y[rows_of[:, i]].astype(jnp.float32)
-                * weights[:, i, None], 0.0)
-        return out.astype(x.dtype)
-
-    n = -(-T // _EXPERT_PIECE_TOKENS)
-    if n == 1:
-        return piece((x, local, weights))
-    Tp = -(-T // n)
-    pad = n * Tp - T
-    x, weights = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
-                  for a in (x, weights))
-    local = jnp.pad(local, ((0, pad), (0, 0)), constant_values=-1)
-    out = jax.lax.map(piece, tuple(
-        a.reshape((n, Tp) + a.shape[1:]) for a in (x, local, weights)))
-    return out.reshape(n * Tp, D)[:T]
-
-
-def dense_experts(x, local, weights, experts, slot, cfg: DeepseekV2Config):
-    """The same sum with no kernel: every held expert over every token,
-    masked. For the dense XLA path at small sizes."""
-    from .quant import dequantize_leaf
-
-    wg, wu, wd = (
-        dequantize_leaf(jax.tree.map(lambda a: a[slot], experts[n]), (1,)
-                        ).astype(x.dtype)
-        for n in _EXPERTS)
-    gate = (local[:, :, None] == jnp.arange(cfg.n_held)[None, None, :])
-    per_expert = jnp.sum(
-        jnp.where(gate, weights[:, :, None], 0.0), axis=1)       # [T, E]
-    h = _mlp_act(jnp.einsum("td,edf->tef", x, wg), cfg.act) \
-        * jnp.einsum("td,edf->tef", x, wu)
-    y = jnp.einsum("tef,efd->ted", h, wd)
-    return jnp.einsum("ted,te->td", y, per_expert.astype(x.dtype))
-
-
 def _expert_ffn(h, lp, experts, slot, valid, cache, cfg: DeepseekV2Config,
                 aq: bool, experts_fn):
-    """Routed experts held here + shared experts, and the counters."""
+    """Routed experts held here (``models/experts.py``, under this family's
+    routing rule) + shared experts, and the counters."""
     B, S, D = h.shape
     x = h.reshape(B * S, D)
-    with jax.named_scope("router"):
+
+    def picks():
         logits = jnp.einsum(
             "td,de->te", x.astype(jnp.float32),
             lp["router"].astype(jnp.float32))
-        ids, weights = route(jax.nn.softmax(logits, axis=-1), cfg)
-        real = valid.reshape(B * S, 1)
-        local = ids - cfg.expert_offset
-        held = (local >= 0) & (local < cfg.n_held) & real
-        local = jnp.where(held, local, -1)
-        tokens = jnp.sum(
-            local.reshape(-1, 1) == jnp.arange(cfg.n_held)[None, :],
-            axis=0, dtype=jnp.int32)
-        cache = dict(
-            cache,
-            expert_tokens=cache["expert_tokens"].at[slot].add(tokens),
-            slots_routed=cache["slots_routed"]
-            + jnp.sum(real, dtype=jnp.int32) * ids.shape[1],
-            slots_held=cache["slots_held"] + jnp.sum(held, dtype=jnp.int32),
-            picks=cache["picks"].at[slot].set(ids.reshape(B, S, -1)[:, -1]),
-        )
-    with jax.named_scope("experts"):
-        routed = (experts_fn or functools.partial(dense_experts, cfg=cfg))(
-            x, local, weights, experts, slot)
+        return route(jax.nn.softmax(logits, axis=-1), cfg)
+
+    routed, cache = expert_layer(
+        x, picks, valid, experts, slot, cache, cfg,
+        experts_fn, rows=B)
     with jax.named_scope("shared_experts"):
         gate = _proj("bsd,di->bsi", h, lp["ws_gate"], aq)
         up = _proj("bsd,di->bsi", h, lp["ws_up"], aq)

@@ -1,0 +1,213 @@
+"""The sparse-expert layer two families share: from a router's picks to the
+weighted sum of the experts this chip holds, with the counters.
+
+A family owns its router's RULE (``models/deepseek.py``: softmax over all
+experts, group-limited top-k, scaled; ``models/smallthinker.py``: top-k on
+the logits, softmax over the picked ones) and hands it to ``expert_layer``
+as a function. Everything after the rule is here, once: the picks become
+local ids of the experts held (``expert_offset``, ``n_held`` of the config;
+a pick outside them adds nothing on this chip), the counters are summed
+into the state the program carries, and the product runs through
+``grouped_experts`` (``ops/expert_matmul.py``, no capacity, nothing
+dropped) or, with no kernel, ``dense_experts``.
+
+What a config has to say: ``n_held``, ``expert_offset``,
+``moe_intermediate``, ``num_experts_per_tok``, ``act`` (the gate's
+activation), ``w8a8_prefill``. What the state holds (``init_expert_state``):
+``expert_tokens`` [expert layers, held experts], ``slots_routed``,
+``slots_held``, ``picks`` (the routers' picks for the last token of the
+latest forward) and, where a family asks for it, ``decode_touched`` /
+``decode_layer_steps``: distinct experts with at least one token, summed
+over the single-token forwards and layers, and how many such (step, layer)
+pairs were counted — what a decode step's expert bytes are.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from .llama import _mlp_act
+
+EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+_COUNTERS = ("expert_tokens", "slots_routed", "slots_held",
+             "decode_touched", "decode_layer_steps")
+
+
+def init_expert_state(n_layers: int, n_held: int, batch: int, top_k: int, *,
+                      decode_touched: bool = False) -> dict:
+    """The expert layer's part of what a program carries."""
+    state = {
+        "expert_tokens": jnp.zeros((n_layers, n_held), jnp.int32),
+        "slots_routed": jnp.zeros((), jnp.int32),
+        "slots_held": jnp.zeros((), jnp.int32),
+        # what each expert layer's router picked for the last token of the
+        # latest forward, row by row (``last_picks``)
+        "picks": jnp.zeros((n_layers, batch, top_k), jnp.int32),
+    }
+    if decode_touched:
+        state["decode_touched"] = jnp.zeros((), jnp.int32)
+        state["decode_layer_steps"] = jnp.zeros((), jnp.int32)
+    return state
+
+
+def counters(cache: dict) -> dict:
+    """The expert counters of a program's final state."""
+    return {k: cache[k] for k in _COUNTERS if k in cache}
+
+
+def last_picks(cache: dict) -> jax.Array:
+    """[expert layers, B, k] expert ids: the routers' picks for the last
+    token of the latest forward. A parity check needs them: where two
+    experts score within rounding of each other the program and a reference
+    may each rightly pick another (``TpuBackend.prefill_then_decode_logits``
+    hands them out position by position)."""
+    return cache["picks"]
+
+
+def _quantize_rows(x: jax.Array):
+    """x [M, K] -> (int8, per-row float32 scale [M, 1])."""
+    x32 = x.astype(jnp.float32)
+    s = jnp.maximum(jnp.max(jnp.abs(x32), axis=-1, keepdims=True), 1e-8) / 127.0
+    return jnp.clip(jnp.round(x32 / s), -127, 127).astype(jnp.int8), s
+
+
+def _column_tile(K: int, N: int) -> int:
+    """Columns of a weight tile: the widest divisor of N in whole lanes
+    whose int8 tile [K, tn] stays under ~2.8 MB of VMEM."""
+    fits = [d for d in range(128, N + 1, 128)
+            if N % d == 0 and d * K <= 2_800_000]
+    return max(fits) if fits else N
+
+
+# tokens one grouped product takes at once: bounds the worst-case row
+# buffers (every pick of every token held here) at ~1.2 GB at DeepSeek-V2's
+# widths, while an expert still sees ~300 rows a weight fetch
+_EXPERT_PIECE_TOKENS = 8192
+
+
+def grouped_experts(x, local, weights, experts, slot, cfg, *,
+                    interpret: bool):
+    """The routed experts held here, through ``expert_grouped_matmul``.
+
+    x [T, D]; ``local`` [T, k] the picks as local expert ids, -1 where a pick
+    is not held (or the token is padding); ``weights`` [T, k]; ``experts``
+    the STACKED ``we_gate``/``we_up``/``we_down`` of every expert layer and
+    ``slot`` this layer's index in them (the kernel reads the stack in
+    place). Returns the weighted sum over each token's held picks, [T, D]."""
+    from ..ops.expert_matmul import expert_grouped_matmul, expert_layout
+
+    T, D = x.shape
+    k = local.shape[1]
+    quantized = isinstance(experts["we_gate"], dict)
+    # int8 rows (s8 x s8) whenever the weights are int8 and the engine runs
+    # W8A8: in a decode step too, where converting each expert's weight
+    # tile to bf16 in the kernel would cost more than fetching it
+    int8_rows = quantized and cfg.w8a8_prefill
+    tm = 256 if T >= 1024 else (32 if int8_rows else 16)
+    F = cfg.moe_intermediate
+
+    def piece(args):
+        x, local, weights = args
+        Tp = x.shape[0]
+        row_of_slot, tile_expert, used, _sizes, M = expert_layout(
+            local.reshape(-1), cfg.n_held, tm)
+        token_of_row = jnp.zeros((M,), jnp.int32).at[row_of_slot].set(
+            jnp.arange(Tp * k, dtype=jnp.int32) // k)
+        call = dict(layer=slot, tile_expert=tile_expert, tiles_used=used,
+                    tm=tm, interpret=interpret)
+        if int8_rows:
+            xq, xs = _quantize_rows(x)
+            rows, scale = xq[token_of_row], xs[token_of_row]
+        else:
+            rows, scale = x[token_of_row], None
+        hidden = expert_grouped_matmul(
+            rows, scale, experts["we_gate"], experts["we_up"],
+            tn=_column_tile(D, F), act=cfg.act,
+            out_dtype=x.dtype, **call)
+        if int8_rows:
+            hidden, scale = _quantize_rows(hidden)
+        y = expert_grouped_matmul(
+            hidden, scale, experts["we_down"], None, tn=_column_tile(F, D),
+            out_dtype=x.dtype, **call)
+        rows_of = row_of_slot.reshape(Tp, k)
+        out = jnp.zeros((Tp, D), jnp.float32)
+        for i in range(k):
+            # rows of tiles the kernel skipped are unspecified: select, do
+            # not multiply by a zero weight
+            out = out + jnp.where(
+                (local[:, i] >= 0)[:, None],
+                y[rows_of[:, i]].astype(jnp.float32)
+                * weights[:, i, None], 0.0)
+        return out.astype(x.dtype)
+
+    n = -(-T // _EXPERT_PIECE_TOKENS)
+    if n == 1:
+        return piece((x, local, weights))
+    Tp = -(-T // n)
+    pad = n * Tp - T
+    x, weights = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                  for a in (x, weights))
+    local = jnp.pad(local, ((0, pad), (0, 0)), constant_values=-1)
+    out = jax.lax.map(piece, tuple(
+        a.reshape((n, Tp) + a.shape[1:]) for a in (x, local, weights)))
+    return out.reshape(n * Tp, D)[:T]
+
+
+def dense_experts(x, local, weights, experts, slot, cfg):
+    """The same sum with no kernel: every held expert over every token,
+    masked. For the dense XLA path at small sizes."""
+    from .quant import dequantize_leaf
+
+    wg, wu, wd = (
+        dequantize_leaf(jax.tree.map(lambda a: a[slot], experts[n]), (1,)
+                        ).astype(x.dtype)
+        for n in EXPERT_LEAVES)
+    gate = (local[:, :, None] == jnp.arange(cfg.n_held)[None, None, :])
+    per_expert = jnp.sum(
+        jnp.where(gate, weights[:, :, None], 0.0), axis=1)       # [T, E]
+    h = _mlp_act(jnp.einsum("td,edf->tef", x, wg), cfg.act) \
+        * jnp.einsum("td,edf->tef", x, wu)
+    y = jnp.einsum("tef,efd->ted", h, wd)
+    return jnp.einsum("ted,te->td", y, per_expert.astype(x.dtype))
+
+
+def expert_layer(x, picks, real, experts, slot, cache, cfg, experts_fn,
+                 rows: int):
+    """One layer's routed experts over x [T, D] (``rows`` batch rows of
+    T / rows tokens each): ``picks()`` is the family's routing rule and
+    gives (expert ids [T, k] int32 over ALL routed experts, weights [T, k]);
+    ``real`` (T booleans, any shape) says which tokens are not under a
+    row's left pad (such a token is routed nowhere and counted nowhere). Returns (the weighted
+    sum over each token's picks held here [T, D], the state with this
+    layer's counts added)."""
+    with jax.named_scope("router"):
+        ids, weights = picks()
+        real = real.reshape(-1, 1)
+        local = ids - cfg.expert_offset
+        held = (local >= 0) & (local < cfg.n_held) & real
+        local = jnp.where(held, local, -1)
+        tokens = jnp.sum(
+            local.reshape(-1, 1) == jnp.arange(cfg.n_held)[None, :],
+            axis=0, dtype=jnp.int32)
+        cache = dict(
+            cache,
+            expert_tokens=cache["expert_tokens"].at[slot].add(tokens),
+            slots_routed=cache["slots_routed"]
+            + jnp.sum(real, dtype=jnp.int32) * ids.shape[1],
+            slots_held=cache["slots_held"] + jnp.sum(held, dtype=jnp.int32),
+            picks=cache["picks"].at[slot].set(
+                ids.reshape(rows, x.shape[0] // rows, -1)[:, -1]),
+        )
+        if "decode_touched" in cache and x.shape[0] == rows:
+            # a single-token forward: a decode step. Each expert with a
+            # token is read once, whatever the number of its tokens
+            cache.update(
+                decode_touched=cache["decode_touched"]
+                + jnp.sum(tokens > 0, dtype=jnp.int32),
+                decode_layer_steps=cache["decode_layer_steps"] + 1)
+    with jax.named_scope("experts"):
+        routed = (experts_fn or functools.partial(dense_experts, cfg=cfg))(
+            x, local, weights, experts, slot)
+    return routed, cache

@@ -6,7 +6,8 @@ differs between families is the layer stack and what it keeps between
 steps, and that is what a ``Family`` supplies: ``forward``, the constructor
 of the state a program carries (the KV cache; for latent attention the
 latent cache and the expert counters), the parameters' init, and the two
-attention functions over the stacked cache, one per phase.
+attention functions over the stacked cache, one per phase, and each
+layer's sliding window where it has layers of that kind.
 
 ``family_of(cfg)`` resolves a family from the config's type: the module a
 config class lives in names its family as ``FAMILY``. Entries of the engine
@@ -43,6 +44,10 @@ class Family:
     # whether its prefill kernel is ops/flash_attention.py's, whose grid
     # cells EngineStats.prefill_blocks counts by class
     counts_prefill_blocks: bool = False
+    # cfg -> each layer's sliding window in cache slots (0 = the layer
+    # attends globally), or None where no layer has a window: what the
+    # kernels clamp their key range with, layer by layer
+    layer_windows: Callable = lambda cfg: None
     # (cfg, kernels on, interpret) -> further keywords of ``forward``
     forward_kwargs: Callable = lambda cfg, kernels, interpret: {}
     # final state -> {name: device array} returned with a program's output,

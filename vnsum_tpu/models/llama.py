@@ -329,6 +329,8 @@ def _rmsnorm(
 def _mlp_act(x: jax.Array, act: str) -> jax.Array:
     if act == "gelu_tanh":
         return jax.nn.gelu(x, approximate=True)
+    if act == "relu":
+        return jnp.maximum(x, 0)
     return jax.nn.silu(x)
 
 
@@ -424,6 +426,63 @@ def _cache_write(buf, val, layer_idx, write_index):
     return buf
 
 
+def _in_window(write_index, S: int, C: int, window):
+    """Which cache slots lie inside a sliding window of ``window`` slots
+    behind each of S queries written from ``write_index``: [1, S, C] for a
+    scalar index, [B, S, C] for per-row slots (spec verify). Slot space, as
+    the kernels: a left pad shifts queries and keys alike."""
+    k_slot = jnp.arange(C)
+    if jnp.ndim(write_index) == 0:
+        q_slot = write_index + jnp.arange(S)
+        return (k_slot[None, :] > q_slot[:, None] - window)[None]
+    q_slot = write_index[:, None] + jnp.arange(S)[None, :]
+    return k_slot[None, None, :] > q_slot[:, :, None] - window
+
+
+def _write_kv(cache: dict, k, v, layer_idx, write_index) -> dict:
+    """Write a layer's new keys and values k, v [B, S, KV, hd] into the
+    stacked cache at ``write_index`` (int8 with per-token scales where the
+    cache is quantized). Scope ``kv_write``; shared by every family whose
+    cache is ``init_kv_cache``'s."""
+    with jax.named_scope("kv_write"):
+        kt = k.transpose(0, 2, 1, 3)  # [B, KV, S, hd] — cache-native
+        vt = v.transpose(0, 2, 1, 3)
+        if is_quantized_cache(cache):
+            k8, ks = _quantize_kv(kt)
+            v8, vs = _quantize_kv(vt)
+            return dict(
+                cache,
+                k=_cache_write(cache["k"], k8, layer_idx, write_index),
+                v=_cache_write(cache["v"], v8, layer_idx, write_index),
+                ks=_cache_write(cache["ks"], ks, layer_idx, write_index),
+                vs=_cache_write(cache["vs"], vs, layer_idx, write_index),
+            )
+        return dict(
+            cache,
+            k=_cache_write(cache["k"], kt, layer_idx, write_index),
+            v=_cache_write(cache["v"], vt, layer_idx, write_index),
+        )
+
+
+def _cache_attention(q, cache: dict, layer_idx, mask, q_per_kv: int,
+                     attention_fn=None, stacked_attention_fn=None):
+    """Attention of q [B, S, H, hd] over layer ``layer_idx`` of the stacked
+    cache: ``stacked_attention_fn`` (the Pallas kernels, reading the cache
+    in place), else ``attention_fn`` or the dense ``_attention`` over the
+    extracted layer under ``mask``. Scope ``attn``."""
+    with jax.named_scope("attn"):
+        if stacked_attention_fn is not None:
+            # reads the stacked cache in place (Pallas kernels): no
+            # per-layer extraction copy materializes
+            return stacked_attention_fn(q, cache, layer_idx)
+        k_cache, v_cache = dequantize_cache_layer(cache, layer_idx)
+        k_cache = k_cache.astype(q.dtype)
+        v_cache = v_cache.astype(q.dtype)
+        if attention_fn is None:
+            return _attention(q, k_cache, v_cache, mask, q_per_kv)
+        return attention_fn(q, k_cache, v_cache, mask, q_per_kv)
+
+
 def _block(
     x, lp, layer_idx, rope, mask, is_global, cache, write_index,
     cfg: LlamaConfig, attention_fn=None, stacked_attention_fn=None,
@@ -454,20 +513,8 @@ def _block(
         (cos_l, sin_l) = rope[1]
         cos = jnp.where(is_global, cos, cos_l)
         sin = jnp.where(is_global, sin, sin_l)
-        C = mask.shape[-1]
-        S = x.shape[1]
-        k_slot = jnp.arange(C)
-        if jnp.ndim(write_index) == 0:
-            q_slot = write_index + jnp.arange(S)
-            in_window = (
-                k_slot[None, :] > q_slot[:, None] - cfg.sliding_window
-            )[None]
-        else:  # per-row write slots (spec verify): [B, S] query slots
-            q_slot = write_index[:, None] + jnp.arange(S)[None, :]
-            in_window = (
-                k_slot[None, None, :]
-                > q_slot[:, :, None] - cfg.sliding_window
-            )
+        in_window = _in_window(
+            write_index, x.shape[1], mask.shape[-1], cfg.sliding_window)
         mask = mask & (is_global | in_window)
 
     # W8A8 only on MULTI-token forwards (prefill): decode's single-token
@@ -496,39 +543,9 @@ def _block(
         q = _apply_rope(q, cos, sin)
         k = _apply_rope(k, cos, sin)
 
-    with jax.named_scope("kv_write"):
-        kt = k.transpose(0, 2, 1, 3)  # [B, KV, S, hd] — cache-native
-        vt = v.transpose(0, 2, 1, 3)
-        if is_quantized_cache(cache):
-            k8, ks = _quantize_kv(kt)
-            v8, vs = _quantize_kv(vt)
-            cache = dict(
-                cache,
-                k=_cache_write(cache["k"], k8, layer_idx, write_index),
-                v=_cache_write(cache["v"], v8, layer_idx, write_index),
-                ks=_cache_write(cache["ks"], ks, layer_idx, write_index),
-                vs=_cache_write(cache["vs"], vs, layer_idx, write_index),
-            )
-        else:
-            cache = dict(
-                cache,
-                k=_cache_write(cache["k"], kt, layer_idx, write_index),
-                v=_cache_write(cache["v"], vt, layer_idx, write_index),
-            )
-
-    with jax.named_scope("attn"):
-        if stacked_attention_fn is not None:
-            # reads the stacked cache in place (Pallas kernels): no
-            # per-layer extraction copy materializes
-            attn = stacked_attention_fn(q, cache, layer_idx)
-        else:
-            k_cache, v_cache = dequantize_cache_layer(cache, layer_idx)
-            k_cache = k_cache.astype(q.dtype)
-            v_cache = v_cache.astype(q.dtype)
-            if attention_fn is None:
-                attn = _attention(q, k_cache, v_cache, mask, cfg.q_per_kv)
-            else:
-                attn = attention_fn(q, k_cache, v_cache, mask, cfg.q_per_kv)
+    cache = _write_kv(cache, k, v, layer_idx, write_index)
+    attn = _cache_attention(q, cache, layer_idx, mask, cfg.q_per_kv,
+                            attention_fn, stacked_attention_fn)
     with jax.named_scope("attn_out"):
         attn_out = _proj("bshk,hkd->bsd", attn, lp["wo"], aq)
         if cfg.sandwich_norms:
@@ -848,6 +865,19 @@ def _decode_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
     return stacked_fn
 
 
+def _layer_windows(cfg: LlamaConfig):
+    """Each layer's window for the kernels (``Family.layer_windows``): the
+    host's reading of ``_layer_global_flags``."""
+    if not cfg.sliding_window:
+        return None
+    flags = cfg.layer_is_global or (False,) * cfg.n_layers
+    if len(flags) != cfg.n_layers:
+        raise ValueError(
+            f"layer_is_global has {len(flags)} entries for "
+            f"{cfg.n_layers} layers")
+    return tuple(0 if g else cfg.sliding_window for g in flags)
+
+
 def _family():
     from .family import Family
 
@@ -857,6 +887,7 @@ def _family():
         attention_supported=_attention_supported,
         prefill_attention=_prefill_attention,
         decode_attention=_decode_attention, counts_prefill_blocks=True,
+        layer_windows=_layer_windows,
     )
 
 

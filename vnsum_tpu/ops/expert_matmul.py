@@ -11,9 +11,9 @@ the worst case (every pick of every token on an expert held here): nothing
 has a capacity, nothing is dropped.
 
 ``expert_grouped_matmul(lhs, w, ...)`` is one product; with ``w_up`` it is
-the SwiGLU front half ``silu(lhs . w) * (lhs . w_up)`` in one pass over
-``lhs``. The weights are the STACKED leaves of every expert layer, ``[L, E,
-K, N]``, read in place: the layer arrives by scalar prefetch and only steers
+the gated front half ``act(lhs . w) * (lhs . w_up)`` in one pass over
+``lhs`` (``act`` ``silu``: SwiGLU, ``relu``: ReGLU). The weights are the
+STACKED leaves of every expert layer, ``[L, E, K, N]``, read in place: the layer arrives by scalar prefetch and only steers
 the block index, so no layer's 900 MB of experts is ever copied out of the
 stack. int8 weights are ``{"q": [L, E, K, N], "s": [L, E, N]}`` (per expert
 and output channel, models/quant.py); with int8 ``lhs`` and its per-row
@@ -64,7 +64,7 @@ def expert_layout(expert_of_slot, n_experts: int, tm: int):
 
 
 def _kernel(layer_ref, te_ref, used_ref, *refs, quantized: bool,
-            int8_lhs: bool, gated: bool):
+            int8_lhs: bool, gated: bool, act: str):
     refs = list(refs)
     x_ref = refs.pop(0)
     xs_ref = refs.pop(0) if int8_lhs else None
@@ -95,23 +95,25 @@ def _kernel(layer_ref, te_ref, used_ref, *refs, quantized: bool,
 
         y = product(w_ref, ws_ref)
         if gated:
-            y = jax.nn.silu(y) * product(u_ref, us_ref)
+            gate = jnp.maximum(y, 0.0) if act == "relu" else jax.nn.silu(y)
+            y = gate * product(u_ref, us_ref)
         o_ref[...] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "tm", "tn", "out_dtype", "interpret"))
+    "tm", "tn", "out_dtype", "act", "interpret"))
 def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
                           tiles_used, *, tm: int, tn: int, out_dtype,
-                          interpret: bool = False):
+                          act: str = "silu", interpret: bool = False):
     """``out[r] = lhs[r] . w[layer, tile_expert[r // tm]]`` for the rows of
     the first ``tiles_used`` tiles; the other rows of ``out`` are
     unspecified.
 
     lhs [M, K] (int8 with ``lhs_scale`` [M, 1] float32, or a float type with
     ``lhs_scale`` None); ``w`` and the optional ``w_up`` [L, E, K, N] or int8
-    ``{"q", "s"}`` leaves; ``layer`` a scalar; returns [M, N] in
-    ``out_dtype``."""
+    ``{"q", "s"}`` leaves; ``layer`` a scalar; ``act`` the gate's
+    activation where ``w_up`` is given (``silu`` or ``relu``); returns
+    [M, N] in ``out_dtype``."""
     M, K = lhs.shape
     quantized = isinstance(w, dict)
     wq = w["q"] if quantized else w
@@ -122,6 +124,8 @@ def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
     if M % tm or N % tn:
         raise ValueError(f"[{M}, {N}] is not whole tiles of [{tm}, {tn}]")
     gated = w_up is not None
+    if act not in ("silu", "relu"):
+        raise ValueError(f"act={act!r}: the gate is silu or relu")
 
     def tile(m, used):
         # a tile past the last one used repeats its index: nothing is
@@ -146,7 +150,7 @@ def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
             in_specs.append(pl.BlockSpec((1, 1, 1, tn), weight))
             operands.append(leaf["s"][:, :, None, :])
     kernel = functools.partial(_kernel, quantized=quantized,
-                               int8_lhs=int8_lhs, gated=gated)
+                               int8_lhs=int8_lhs, gated=gated, act=act)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
