@@ -131,9 +131,10 @@ def _int8_cache(L, B, KV, C, hd):
 
 @pytest.mark.parametrize("offset", [0, 2048, 6144])
 def test_gqa_prefill_kernel_compiles_at_g7_under_a_window(one_chip, offset):
-    """28/4 heads of 128 (a group of 7: bq 512 / bk 512), a 2,048-query
-    chunk of the S=8192 bucket over the int8 cache of 8,448 slots, the
-    layer's window a traced scalar."""
+    """28/4 heads of 128 (a group of 7: its heads looped two a step and one
+    after, bq 1024 / bk 1024, 38.5 MiB of scoped VMEM asked for), a
+    2,048-query chunk of the S=8192 bucket over the int8 cache of 8,448
+    slots, the layer's window a traced scalar."""
     from vnsum_tpu.ops.flash_attention import flash_prefill_attention
 
     c = _compiled(
@@ -141,6 +142,28 @@ def test_gqa_prefill_kernel_compiles_at_g7_under_a_window(one_chip, offset):
             q, cache, 3, pads, 7, win, offset),
         one_chip, ((2, 2048, 28, 128), BF16), _int8_cache(4, 2, 4, 8448, 128),
         ((2,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("G,KV,hd,geometry", [
+    (7, 4, 128, (1024, 1024)),
+    (16, 2, 128, (1024, 1024)),   # 61 MiB counted: the widest at 1024 rows
+    (32, 1, 128, (512, 1024)),
+    (8, 2, 256, (1024, 512)),
+])
+def test_gqa_prefill_kernel_compiles_at_wide_groups(one_chip, G, KV, hd,
+                                                    geometry):
+    """Groups wider than four at the geometry the wrapper gives them, over a
+    bf16 cache (2 MiB more of VMEM than an int8 one): the limit the kernel
+    asks for from its own count (_vmem_bytes) is one Mosaic accepts."""
+    from vnsum_tpu.ops import flash_attention
+
+    assert flash_attention._block_geometry(2048, 8448, G, hd) == geometry
+    cache = {"k": ((2, 2, KV, 8448, hd), BF16), "v": ((2, 2, KV, 8448, hd), BF16)}
+    c = _compiled(
+        lambda q, cache, pads, win: flash_attention.flash_prefill_attention(
+            q, cache, 1, pads, G, win, 6144),
+        one_chip, ((2, 2048, G * KV, hd), BF16), cache, ((2,), I32), ((), I32))
     assert "tpu_custom_call" in c.as_text()
 
 

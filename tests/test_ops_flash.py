@@ -234,9 +234,10 @@ def test_flash_bf16_compute_dtype_close_to_f32():
         )
 
 
-def test_vmem_guard_shrinks_bq_for_wide_groups():
-    """G=16 bottoms the bk guard at 512; the continuation must shrink bq
-    (not compile-OOM) and the interpreted kernel still matches dense."""
+def test_a_group_of_16_loops_its_heads_and_matches_dense():
+    """G=16 (an MQA-like ratio): the group's heads go through the kernel's
+    loop, the q tile and the softmax state carry 16 x bq rows, and the
+    interpreted kernel still matches dense."""
     L, B, S, C, H, KV, hd = 1, 1, 16, 16, 16, 1, 128
     q, cache = make_case(L, B, S, C, H, KV, hd, seed=5)
     pad = jnp.zeros((B,), jnp.int32)
@@ -250,18 +251,63 @@ def test_vmem_guard_shrinks_bq_for_wide_groups():
 
 
 def test_vmem_guard_rejects_explicit_overrides_with_geometry():
-    """An explicit block_q that exceeds the scoped-VMEM ceiling must raise
-    a ValueError naming the geometry instead of a Mosaic compile OOM."""
+    """Explicit blocks that exceed the kernel's VMEM budget must raise a
+    ValueError naming the geometry and the bytes instead of a Mosaic
+    compile OOM."""
     L, B, S, C, H, KV, hd = 1, 1, 4096, 4096, 16, 1, 128
     q = jnp.zeros((B, S, H, hd), jnp.float32)
     cache = {
         "k": jnp.zeros((L, B, KV, C, hd), jnp.float32),
         "v": jnp.zeros((L, B, KV, C, hd), jnp.float32),
     }
-    with pytest.raises(ValueError, match="scoped-VMEM.*G=16"):
+    with pytest.raises(
+        ValueError, match=r"scoped-VMEM.*G=16.*bq=1024, bk=2048 need \d+ bytes"
+    ):
         flash_prefill_attention(
             q, cache, 0, jnp.zeros((B,), jnp.int32), H // KV,
-            block_q=512, block_k=2048,
+            block_q=1024, block_k=2048,
+        )
+
+
+# (window, q_offset, block_k): C = 77 leaves a partial tail key block of 13
+# slots at bk 16 and of 13 at bk 32; the rows carry pads 0 and 5
+_G7_CASES = {
+    "global": (0, 0, 16),
+    "window": (24, 0, 16),
+    "global_chunk": (0, 32, 32),
+    "window_chunk": (24, 32, 16),
+    "window_wide_keys": (40, 32, 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_G7_CASES))
+def test_flash_looped_heads_match_dense_at_g7(case):
+    """SmallThinker's 28/4 heads: a group of 7 is wider than the kernel
+    unrolls, so its heads go through the loop, each on its own rows of the
+    scratch; against the dense path under no window and under one smaller
+    than C, with a left pad, a chunk offset and a partial tail key block."""
+    win, off, bk = _G7_CASES[case]
+    assert flash_attention._heads_per_step(7) < 7
+    L, B, S, C, H, KV, hd = 1, 2, 45, 77, 28, 4, 128
+    q, cache = make_case(L, B, off + S, C, H, KV, hd, seed=13)
+    q = q[:, off:]
+    pads = [0, 5]
+    pad = jnp.asarray(pads, jnp.int32)
+    mask = prefill_attention_mask(pad, off + S, C)[:, off:]
+    if win:
+        mask = mask & (
+            jnp.arange(C)[None, :] > off + jnp.arange(S)[:, None] - win
+        )[None]
+    dense = _attention(q, cache["k"][0], cache["v"][0], mask, H // KV)
+    flash = flash_prefill_attention(
+        q, cache, 0, pad, H // KV, jnp.int32(win), jnp.int32(off),
+        block_q=16, block_k=bk, interpret=True,
+    )
+    for b in range(B):
+        lo = max(0, pads[b] - off)
+        np.testing.assert_allclose(
+            np.asarray(dense)[b, lo:], np.asarray(flash)[b, lo:],
+            rtol=2e-5, atol=2e-5,
         )
 
 
@@ -340,9 +386,14 @@ _CLASS_CASES = {
     # partial tail block: C % bk != 0, and S % bq != 0
     "partial_tail_blocks": (45, 61, 0, 0, 16, 16, [0, 17, 45]),
     "wide_k_partial_tail": (64, 80, 0, 0, 16, 32, [0, 33, 64]),
-    # window > 0 with a pad: the windowed layer keeps the edge path
-    "window_with_pad": (64, 96, 0, 24, 16, 16, [0, 20, 64]),
-    "window_chunk_with_pad": (32, 96, 32, 8, 16, 16, [0, 37, 64]),
+    # window > 0 with a pad. A window of more than bq + bk - 1 slots holds
+    # whole cells under the diagonal: interior, no mask built
+    "window_with_pad": (64, 96, 0, 40, 16, 16, [0, 20, 64]),
+    "window_chunk_with_pad": (32, 96, 32, 36, 16, 16, [0, 37, 64]),
+    "window_wide_keys_with_pad": (64, 96, 0, 56, 16, 32, [0, 20, 64]),
+    # a narrower one crosses every cell it reaches: all edge, as before
+    "narrow_window_with_pad": (64, 96, 0, 24, 16, 16, [0, 20, 64]),
+    "narrow_window_chunk_with_pad": (32, 96, 32, 8, 16, 16, [0, 37, 64]),
 }
 
 
@@ -351,8 +402,10 @@ _CLASS_CASES = {
 def test_block_classes_bit_identical_to_masking_every_block(
     case, kind, all_edge_kernel
 ):
-    """Skipping the dead cells and dropping the mask in the interior ones
-    must not change one bit of the output, pad rows (zeros) included."""
+    """Skipping the dead cells and dropping the mask in the interior ones —
+    under a window too, where every slot of the cell is inside the last
+    query row's window — must not change one bit of the output, pad rows
+    (zeros) included."""
     S, C, off, win, bq, bk, pads = _CLASS_CASES[case]
     L, B, H, KV, hd = 2, len(pads), 4, 2, 256
     q, cache = make_case(L, B, off + S, C, H, KV, hd, seed=11)
@@ -362,7 +415,7 @@ def test_block_classes_bit_identical_to_masking_every_block(
         pads, S, C, off, win, H // KV, hd, block_q=bq, block_k=bk
     )
     assert classes["dead_pad"] > 0 or max(pads) == 0
-    assert (classes["interior"] > 0) == (win == 0)
+    assert (classes["interior"] > 0) == (win == 0 or win > bq + bk - 1)
 
     cache = _cache_as(cache, kind)
     if kind != "f32":

@@ -13,9 +13,15 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   mandatory attention bytes). The causal/pad/window mask is also computed
   once per cell and shared by the G heads;
 - scratch (acc, m, l) carries the running softmax across K blocks per
-  head (static G-sliced rows of one scratch buffer — leading dims may
-  MERGE in-kernel but never split, so per-head slices beat a reshape);
-  output written on the last K block;
+  head (a head's state is BQ rows of one buffer — leading dims may MERGE
+  in-kernel but never split, so row slices beat a reshape); output
+  written on the last K block;
+- **the group's heads go one tile at a time** (_kernel's for_each_head):
+  K and V blocks, their casts, the value scales and the mask are built
+  once a cell, then each head computes its [BQ, BK] tile of scores against
+  them. Up to four heads are a static unroll; a wider group is a loop of
+  two heads a step (an odd head out after it), so the score temporaries
+  alive, the code size and the compile time do not grow with the group;
 - **ceil-division grids with masked tails**: block sizes stay MXU-friendly
   for ANY S/C. An earlier divisor-only picker collapsed to 32-wide
   K blocks at C=2080 (8 KB DMAs) and the kernel ran 60% of total profile
@@ -36,14 +42,28 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   sum and the cast beside the MXU's own result traffic (inferred: no
   bundle dump was read). The lever that pays is not running a cell:
   with a group's four tail chunks in the dispatch, 50.2 -> 35.8 ms;
-- **block geometry**: for the group-major grid the default is bq=512 /
-  bk=2048 at hd=128, G≤3 (chosen on an earlier machine: 30.8 ms/layer at
-  the worst e2e chunk vs the per-head kernel's best 37.5 — not re-swept
-  on the current one). bk
-  shrinks with head_dim (hd=256 Gemma3 → 1024) AND with G (the unrolled
-  per-head score temporaries stay live: G=4 at bk=2048 exceeds the 16 MB
-  scoped-VMEM budget, so G·bk is capped at 3·2048 — phi-4's 4:1 groups
-  resolve to bk=1024, measured working at 14.7 GB int8 on chip);
+- **block geometry** (_block_geometry, from G and hd alone). The cost a
+  score follows the tile ONE head computes at a time, above all its key
+  width, not the group. Swept on the v5e, kernel alone at the SmallThinker
+  cell's map dispatch (24 rows of 4 KV heads x 7, hd 128, int8 cache, four
+  2048-query chunks over C=8448, 4 global and 12 window-4096 layers;
+  seconds for the dispatch's cells / ns per 1,024 computed scores / MiB
+  Mosaic needs; PERF.md section 6, PR 36): the parent's unroll of 7 at
+  (512, 512) 2.42 s / 8.5 / 12; looped, one and two heads a step:
+  (512, 512) 2.68 and 2.52 / 9.4 and 8.9; (512, 1024) 1.67 and 1.55 /
+  5.3 and 5.0 / 15 and 16; (512, 2048) 1.77 and 1.71 / 4.8 and 4.6 / 21
+  and 24 (19% more scores computed: the window's floor and the diagonal
+  cost a whole tile each); **(1024, 1024) 1.47 and 1.44 / 4.7 and 4.6 / 28
+  and 31 — adopted for every group wider than four**, at 512 query rows
+  where the q, o and state rows of 1024 pass the budget (G > 16). At G=4
+  (Qwen3's dispatch, (512, 1024)) the loop loses to the unroll — 1.400 s
+  a head a step, 1.301 two, 1.28-1.32 unrolled — so groups of up to four
+  keep the unroll and the geometry they had: bq=512 / bk=2048 at G<=3 (an
+  earlier machine's choice), bk 1024 at G=4 and at hd=256 (Gemma3);
+  re-sweeping those is ROADMAP Queue 1 item 1d. _vmem_bytes counts what a
+  geometry needs; past the 32 MiB the attention kernels share, this
+  kernel alone asks for its count, and a group that fits nothing under 64
+  MiB (G > 42 at hd 128) is a ValueError with the numbers;
 - **consumes the FULL stacked cache [L, B, KV, C, hd]** like the decode twin
   (ops/decode_attention.py): the layer index arrives via scalar prefetch and
   steers the index_map, eliminating the per-layer 2×(B·C·hd·KV) extraction
@@ -56,7 +76,10 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   pad, or any cell of a query block that is all pad (a batch-bucketing
   filler row is dead everywhere) — are neither fetched nor computed;
   *interior* cells, whose mask would be all true, build no mask and run
-  no select; *edge* cells run the masked body. The output is the same
+  no select — under a window too, where the cell's first slot is inside
+  the last query row's window (18 of a full row's 30 computed cells on a
+  4096-window layer at (1024, 1024)); *edge* cells run the masked body.
+  The output is the same
   bit for bit as masking every cell. ``prefill_block_classes`` counts the
   cells of a call on the host with the same rule.
 
@@ -95,7 +118,9 @@ def _block_class(q_start, k_start, pad, win, q_end, cache_len,
       row, pad == S, is padded everywhere) — dead;
     - ``interior``: the per-element mask would be all true — wholly at or
       under every query's diagonal, past the pad, every query real
-      (< ``q_end``), every slot in the cache, no window;
+      (< ``q_end``), every slot in the cache, and with a window the first
+      slot inside the LAST query row's window (then every slot is inside
+      every row's);
     - the rest is edge.
 
     Only comparisons and bit operators: the kernel calls it on SMEM
@@ -107,7 +132,7 @@ def _block_class(q_start, k_start, pad, win, q_end, cache_len,
     padded = (k_last < pad) | (q_last < pad) | (q_end <= pad)
     interior = (
         (k_last <= q_start) & (k_start >= pad) & (q_last < q_end)
-        & (k_last < cache_len) & (win == 0)
+        & (k_last < cache_len) & ((win == 0) | (k_start > q_last - win))
     )
     return seen, padded, interior
 
@@ -125,6 +150,7 @@ def _kernel(
     scale: float,
     quantized: bool,
     q_per_kv: int,
+    heads_per_step: int,
 ):
     if quantized:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
@@ -135,7 +161,7 @@ def _kernel(
     # ks_ref/vs_ref [1, 1, KV, BK] (full KV axis — Mosaic requires the
     # second-minor block dim be 8-divisible or whole; the group's row is
     # selected in-kernel); scratch acc [G*BQ, hd] f32, m/l [G*BQ, LANES]
-    # f32 — per-head state lives in static G-slices of one buffer
+    # f32 — a head's state is a slice of BQ rows of one buffer
 
     b = pl.program_id(0)
     kv = pl.program_id(1)
@@ -154,6 +180,25 @@ def _kernel(
         block_q, block_k,
     )
 
+    def for_each_head(body):
+        """``body(g, rows)`` for the group's heads, ``heads_per_step`` of
+        them unrolled into one step of a loop: the [BQ, BK] f32 score
+        temporaries alive at a time, the code size and the compile time are
+        those of ``heads_per_step`` heads whatever the group (ops/
+        mla_attention.py has the same loop). A group of no more heads than
+        that is the plain static unroll."""
+        def several(step, _):
+            for u in range(heads_per_step):
+                g = step * heads_per_step + u
+                body(g, pl.ds(pl.multiple_of(g * block_q, block_q), block_q))
+
+        steps = q_per_kv // heads_per_step
+        looped = steps * heads_per_step if steps > 1 else 0
+        if looped:
+            jax.lax.fori_loop(0, steps, several, None)
+        for g in range(looped, q_per_kv):  # the unroll, or an odd head out
+            body(g, pl.ds(g * block_q, block_q))
+
     @pl.when(j == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -161,9 +206,10 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def _accumulate(masked: bool):
-        # casts hoisted out of the G-unroll: one [BK, hd] conversion per
-        # grid cell, not G (int8 cache values are exact in the query
-        # dtype — see the dot comment below)
+        # what does not hang on the head is built once a cell, outside the
+        # loop over the group: one [BK, hd] conversion of each block, not G
+        # (int8 cache values are exact in the query dtype — see the dot
+        # comment below), the value scales, the mask
         kb = k_ref[0, 0, 0].astype(q_ref.dtype)
         vb = v_ref[0, 0, 0].astype(q_ref.dtype)
         v_scale = vs_ref[0, 0, kv][None, :] if quantized else None
@@ -209,8 +255,7 @@ def _kernel(
             )
             mask = mask & ((win == 0) | (k_pos > q_pos - win))
 
-        for g in range(q_per_kv):  # static unroll over the GQA group
-            lo, hi = g * block_q, (g + 1) * block_q
+        def _head(g, rows):
             # MXU inputs stay in the QUERY dtype with f32 accumulation
             # (preferred_element_type): f32 parity tests keep exact f32
             # dots, the engine's bf16 takes the native-rate MXU path. int8
@@ -226,7 +271,7 @@ def _kernel(
             if masked:
                 s = jnp.where(mask, s, _NEG)
 
-            m_prev = m_ref[lo:hi, :1]                   # [BQ, 1]
+            m_prev = m_ref[rows, :1]                    # [BQ, 1]
             m_cur = jnp.max(s, axis=1, keepdims=True)   # [BQ, 1]
             m_new = jnp.maximum(m_prev, m_cur)
             corr = jnp.exp(m_prev - m_new)
@@ -234,18 +279,20 @@ def _kernel(
             if masked:
                 p = jnp.where(mask, p, 0.0)             # dead rows stay dead
 
-            l_new = l_ref[lo:hi, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+            l_new = l_ref[rows, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
             if quantized:
                 p = p * v_scale
             # probabilities drop to the query dtype for the PV dot (bf16
             # adds ~0.4% relative rounding — same class as the int8 V
             # scale already applied above); accumulation stays f32
-            acc_ref[lo:hi] = acc_ref[lo:hi] * corr + jax.lax.dot_general(
+            acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
                 p.astype(qg.dtype), vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            m_ref[lo:hi] = jnp.broadcast_to(m_new, (block_q, m_ref.shape[1]))
-            l_ref[lo:hi] = jnp.broadcast_to(l_new, (block_q, l_ref.shape[1]))
+            m_ref[rows] = jnp.broadcast_to(m_new, (block_q, m_ref.shape[1]))
+            l_ref[rows] = jnp.broadcast_to(l_new, (block_q, l_ref.shape[1]))
+
+        for_each_head(_head)
 
     # Each cell does its class's work and no more. Dead cells (nothing to
     # see: above the diagonal, below the window floor, under the row's left
@@ -262,10 +309,42 @@ def _kernel(
 
     @pl.when(j == nj - 1)
     def _finalize():
-        for g in range(q_per_kv):
-            lo, hi = g * block_q, (g + 1) * block_q
-            l = jnp.maximum(l_ref[lo:hi, :1], 1e-30)
-            o_ref[0, 0, g] = (acc_ref[lo:hi] / l).astype(o_ref.dtype)
+        def _store(g, rows):
+            l = jnp.maximum(l_ref[rows, :1], 1e-30)
+            o_ref[0, 0, g] = (acc_ref[rows] / l).astype(o_ref.dtype)
+
+        for_each_head(_store)
+
+
+# A group of more than _UNROLLED_GROUP heads goes through the kernel's head
+# loop, _HEADS_LOOPED of them a loop step, on a (1024, 1024) tile; a narrower
+# one is a static unroll of the whole group on the tile it had (module
+# docstring, "block geometry").
+_UNROLLED_GROUP = 4
+_HEADS_LOOPED = 2
+_LOOPED_BLOCK = 1024
+# the most scoped VMEM this kernel asks for, of the core's 128 MiB (what the
+# expert product asks for and runs with)
+_VMEM_MOST = 64 * 1024 * 1024
+
+
+def _heads_per_step(G: int) -> int:
+    return G if G <= _UNROLLED_GROUP else _HEADS_LOOPED
+
+
+def _vmem_bytes(G: int, hd: int, bq: int, bk: int) -> int:
+    """Scoped VMEM a grid step needs at the engine's types (bf16 queries; a
+    bf16 cache, which needs 2 MiB more than an int8 one): the q and o tiles
+    double-buffered and the acc, m, l scratch, all G * bq rows; the k and v
+    blocks double-buffered; and what a cell keeps per score — Mosaic's own
+    count, bisected on the compiler at G 3-64, is 11.5-14 bytes with one
+    head's [bq, bk] temporaries alive (a static unroll keeps no more) and
+    2-3 more for a second head. At most 30% above what Mosaic needs, never
+    under (PERF.md section 6, PR 36)."""
+    rows = G * bq * (2 * 2 * 2 * hd + 4 * (hd + 2 * _LANES))
+    blocks = 2 * 2 * bk * hd * 2
+    heads = min(_heads_per_step(G), 2)
+    return rows + blocks + bq * bk * (12 + 4 * heads)
 
 
 def _block_geometry(S: int, C: int, G: int, hd: int,
@@ -274,42 +353,36 @@ def _block_geometry(S: int, C: int, G: int, hd: int,
     """(bq, bk) of the kernel's grid for S queries over a cache of C slots
     with GQA group G and head size hd — the wrapper's rule, also the
     counter's (prefill_block_classes)."""
-    # measured-best geometry for the GROUP-major grid (worst e2e chunk,
-    # B=16/S=2048@off=6144/C=8320 int8: 512/2048 = 30.8 ms/layer vs the
-    # per-head kernel's best 37.5; map shape 19.4 vs 20.7; not re-measured
-    # on the current machine). Two VMEM scaling rules hold the footprint at
-    # the measured G=3, hd=128 level: the K width shrinks with head_dim
-    # (hd=256 Gemma3 → bk 1024), AND with the group size — the per-head loop is a static
-    # unroll whose [bq, bk] f32 score temporaries stay live per head, so
-    # G=4 at bk=2048 exceeds scoped vmem by ~2 MB (measured compile OOM;
-    # G*bk is held ≤ 3*2048). bq stays 512: the q tile already carries
-    # G*512 rows, and bq=1024 geometries fail to compile at G=3.
+    default_bq = 512
+    # the key width shrinks with the head size (hd=256 Gemma3: half)
     default_bk = max(512, 2048 * _LANES // max(hd, 1))
-    while G * default_bk > 3 * 2048 and default_bk > 512:
-        default_bk //= 2
-    bq = min(block_q or 512, S)
+    if G <= _UNROLLED_GROUP:
+        # G * bk held to 3 * 2048 since the default limit of 16 MiB (Qwen3
+        # and Phi-4's 4:1 groups: bk 1024). Mosaic's count says the unroll
+        # keeps one head's temporaries, not G: re-sweeping these is ROADMAP
+        # Queue 1 item 1d
+        while G * default_bk > 3 * 2048 and default_bk > 512:
+            default_bk //= 2
+    else:
+        default_bk = max(512, _LOOPED_BLOCK * _LANES // max(hd, 1))
+        # 1024 query rows while the group's q, o and state rows fit (G <= 16
+        # at hd 128), else 512 (1.55 s for 1.44 at G=7)
+        if _vmem_bytes(G, hd, _LOOPED_BLOCK, default_bk) <= _VMEM_MOST:
+            default_bq = _LOOPED_BLOCK
+    bq = min(block_q or default_bq, S)
     # scratch is G-sliced at multiples of bq — keep the slice offsets
     # sublane-aligned when S is small and not 8-divisible
     bq = -(-bq // 8) * 8
     bk = min(block_k or default_bk, C)
-    # the bk guard above bottoms out at 512; very wide GQA groups (G > 12)
-    # can still blow the scoped-VMEM score budget there, so continue the
-    # scaling on bq (the q tile and the per-head [bq, bk] f32 temporaries
-    # both shrink with it). G*bq*bk <= 3*2048*512 is the measured-working
-    # ceiling at the default geometry (G=3, bq=512, bk=2048).
-    _VMEM_CELLS = 3 * 2048 * 512
-    if block_q is None:
-        while G * bq * bk > _VMEM_CELLS and bq > 8:
-            bq = max(-(-(bq // 2) // 8) * 8, 8)
-    if G * bq * bk > _VMEM_CELLS and not interpret:
-        # an explicit block_q/block_k overrode the autoscaler into a
-        # geometry that will OOM in Mosaic — fail with the numbers instead
-        # of a compile-time scoped-vmem error naming none of them
+    need = _vmem_bytes(G, hd, bq, bk)
+    if need > _VMEM_MOST and not interpret:
+        # the q and o tiles and the softmax state grow with the group: fail
+        # with the numbers, not with a Mosaic error that names none of them
         raise ValueError(
-            f"flash prefill geometry exceeds the scoped-VMEM "
-            f"budget: G={G}, head_dim={hd}, bq={bq}, "
-            f"bk={bk} (G*bq*bk={G * bq * bk} > {_VMEM_CELLS}) — pass a "
-            f"smaller block_q/block_k or drop to the dense path"
+            f"flash prefill geometry exceeds the scoped-VMEM budget: G={G}, "
+            f"head_dim={hd}, bq={bq}, bk={bk} need {need} bytes of "
+            f"{_VMEM_MOST} — pass a smaller block_q/block_k or drop to the "
+            f"dense path"
         )
     return bq, bk
 
@@ -414,6 +487,7 @@ def flash_prefill_attention(
     kernel = functools.partial(
         _kernel, block_q=bq, block_k=bk, seq_len=S, cache_len=C,
         scale=1.0 / (hd ** 0.5), quantized=quantized, q_per_kv=G,
+        heads_per_step=_heads_per_step(G),
     )
     out = pl.pallas_call(
         kernel,
@@ -433,7 +507,9 @@ def flash_prefill_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, S, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT_BYTES
+            # the attention kernels' common limit where the geometry fits
+            # it, else this kernel's own count
+            vmem_limit_bytes=max(VMEM_LIMIT_BYTES, _vmem_bytes(G, hd, bq, bk))
         ),
         interpret=interpret,
         # a contract: the device trace, the ledger and benchmark metrics name
