@@ -3,7 +3,7 @@ only LangSmith @traceable + ad-hoc wall-clock fields; we provide aggregated
 spans + gated jax.profiler traces)."""
 import threading
 
-from vnsum_tpu.core.profiling import Tracer, annotate, device_profile
+from vnsum_tpu.core.profiling import Tracer, device_profile
 
 
 def test_span_aggregates():
@@ -87,11 +87,6 @@ def test_device_profile_writes_trace(tmp_path):
         (jnp.ones((8, 8)) @ jnp.ones((8, 8))).block_until_ready()
     # jax.profiler.trace writes plugins/profile/<ts>/ under the log dir
     assert any(tmp_path.rglob("*.xplane.pb"))
-
-
-def test_annotate_is_usable():
-    with annotate("phase"):
-        pass
 
 
 def test_pipeline_records_tracing(tmp_path):

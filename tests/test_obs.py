@@ -148,19 +148,20 @@ def test_rolling_window_evicts_old_samples():
     assert Rolling(4).rate() == 0.0  # empty denominator -> 0, not ZeroDivision
 
 
-# -- span recorder (the shared Tracer/RequestTrace primitive) -----------------
+# -- span recorder (the Tracer's timeline; core.profiling names the spans) ----
 
 
-def test_span_recorder_hierarchical_names_and_bound():
+def test_span_recorder_keeps_completion_order_and_is_bounded():
     rec = SpanRecorder(maxlen=3)
-    with rec.span("outer"):
-        with rec.span("inner"):
-            pass
-    names = [s.name for s in rec.spans()]
-    assert names == ["outer/inner", "outer"]  # closed in completion order
+    rec.add("outer/inner", 0.0, 0.1, docs=2)
+    rec.add("outer", 0.0, 0.2)
+    assert [s.name for s in rec.spans()] == ["outer/inner", "outer"]
+    assert rec.spans()[0].args == {"docs": 2}
     for i in range(5):
         rec.add(f"extra{i}", 0.0, 0.1)
     assert len(rec.spans()) == 3  # bounded, never unbounded growth
+    rec.clear()
+    assert rec.spans() == []
 
 
 def test_request_trace_tracks_and_finish():

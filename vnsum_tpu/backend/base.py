@@ -14,9 +14,13 @@ device syncs. emit() no-ops on a single contextvar read unless a caller
 (the serving scheduler) installed a collector, so backends wrap
 their hot paths unconditionally. Recognized phase names: "tokenize",
 "prefill"/"spec_prefill" (their end is the TTFT anchor), "decode",
-"decode_seg", "spec_step", "dispatch" (fused one-shot program),
-"detokenize". TpuBackend and FakeBackend implement it; HTTP parity backends
-(ollama/hf) simply emit nothing.
+"decode_seg", "spec_step", "dispatch" (fused one-shot program, pack to
+detokenize), "detokenize". TpuBackend publishes them through
+``core.profiling.host_span`` — one interval for the profiler, this collector
+and an always-on aggregate — so the collector also sees that primitive's
+other bare names ("pack", "enqueue", "wait", "count", "cache_lookup",
+"cache_gather", "cache_insert", "adopt", "harvest"); FakeBackend emits
+directly; HTTP parity backends (ollama/hf) simply emit nothing.
 
 Optional prefix-cache contract (vnsum_tpu.cache): backends with a prefix KV
 cache additionally expose ``cached_prefix_tokens(text, cache_hint=None)``

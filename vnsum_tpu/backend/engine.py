@@ -44,8 +44,7 @@ from .base import (
     terminator_ids,
     trim_to_eos,
 )
-from ..core.profiling import annotate
-from ..obs.trace import current_collector, emit
+from ..core.profiling import SpanStats, host_span
 from ..testing.faults import fault
 from ..models.family import family_of
 from ..models.llama import (
@@ -111,8 +110,16 @@ class EngineStats:
     # head dim, the slot/verify kernel under a mesh) is visible here and in
     # the log instead of only in the timings
     attention_paths: dict = field(default_factory=dict)
-    # host-phase wall clock (always on: the timers wrap pure-host work)
-    phase_seconds: dict = field(default_factory=dict)
+    # host work between device programs (core.profiling.host_span's sink,
+    # always on): {"engine/<name>": SpanStats}. The names are a contract
+    # (README, "Device time by layer"); none carries a shape
+    host_spans: dict = field(default_factory=dict)
+    # per dispatch shape, the two sides of the device queue:
+    # {(B, S): {"enqueue": SpanStats, "wait": SpanStats}} — ``enqueue`` is
+    # the program's call alone (arguments transferred, output buffers
+    # allocated, program queued), ``wait`` the result fetch (execution and
+    # the copy back). A call that stalls says here on which side it did
+    dispatch_by_bucket: dict = field(default_factory=dict)
     # sparse-expert families (models/experts.py), summed on the device and
     # returned with each one-shot program's output: token x pick pairs the
     # router saw, those that fell on an expert held here, and tokens per
@@ -125,8 +132,14 @@ class EngineStats:
     expert_decode_touched: int = 0
     expert_decode_layer_steps: int = 0
 
-    def add_phase(self, name: str, seconds: float) -> None:
-        self.phase_seconds[name] = self.phase_seconds.get(name, 0.0) + seconds
+    def note_dispatch(self, B: int, S: int, enqueue_s: float,
+                      wait_s: float) -> None:
+        sides = self.dispatch_by_bucket.get((B, S))
+        if sides is None:
+            sides = self.dispatch_by_bucket[(B, S)] = {
+                "enqueue": SpanStats(), "wait": SpanStats()}
+        sides["enqueue"].add(enqueue_s)
+        sides["wait"].add(wait_s)
 
     @property
     def tokens_per_second(self) -> float:
@@ -889,13 +902,13 @@ class TpuBackend:
         self.stats.prompts += len(prompts)
         max_input = self.cfg.max_seq_len
         encoded: list[list[int]] = []
-        t_enc = time.time()
-        for tok_ids in self.tok.encode_batch(prompts, add_bos=True):
-            if len(tok_ids) > max_input:
-                tok_ids = [tok_ids[0]] + tok_ids[-(max_input - 1):]
-            encoded.append(tok_ids)
-            self.stats.prompt_tokens += len(tok_ids)
-        self.stats.add_phase("tokenize_host", time.time() - t_enc)
+        sink = self.stats.host_spans
+        with host_span("engine", "tokenize", sink, prompts=len(prompts)):
+            for tok_ids in self.tok.encode_batch(prompts, add_bos=True):
+                if len(tok_ids) > max_input:
+                    tok_ids = [tok_ids[0]] + tok_ids[-(max_input - 1):]
+                encoded.append(tok_ids)
+                self.stats.prompt_tokens += len(tok_ids)
 
         order = sorted(range(len(encoded)), key=lambda i: len(encoded[i]))
         results: list[int] = [0] * len(encoded)
@@ -916,12 +929,12 @@ class TpuBackend:
                         self._make_choice_fn(B, S, len(ids)),
                         f"choice[B={B},S={S}]",
                     )
-                with annotate(f"choice[B={B},S={S}]"):
+                with host_span("engine", "choice", sink, B=B, S=S):
                     idx = self._fns[key](
                         self.params, tokens, pad_lens, choice_dev
                     )
-                # lint-allow[host-sync-in-hot-path]: result fetch — the host needs the chosen indices
-                idx_h = jax.device_get(idx)
+                    # lint-allow[host-sync-in-hot-path]: result fetch — the host needs the chosen indices
+                    idx_h = jax.device_get(idx)
                 self.stats.batches += 1
                 self.stats.by_bucket[(B, S)] = (
                     self.stats.by_bucket.get((B, S), 0) + 1
@@ -1390,16 +1403,13 @@ class TpuBackend:
         lens_full = np.zeros((B,), dtype=np.int32)
         lens_full[: len(group)] = ref_lens_np
 
-        tracing = current_collector() is not None  # once per dispatch
+        sink = self.stats.host_spans
         prefill = self._get_seg_fn("prefill", B, S, max_new + k + 1, gen)
-        t_pre = time.time()
-        t_pre_m = time.monotonic()
-        with annotate(f"spec_prefill[B={B},S={S}]"):
+        # the dispatch is asynchronous: this bounds submission, not device
+        # time (synced=False keeps it from anchoring a TTFT)
+        with host_span("engine", "spec_prefill", sink, B=B, S=S,
+                       occupancy=len(group), synced=False):
             cur, cache, done = prefill(self.params, tokens, pads, seed)
-        # the dispatch is asynchronous: this bounds submission, not device time
-        if tracing:
-            emit("spec_prefill", t_pre_m, time.time() - t_pre, B=B, S=S,
-                 occupancy=len(group), synced=False)
         self.stats.batches += 1
         self.stats.by_bucket[(B, S)] = self.stats.by_bucket.get((B, S), 0) + 1
 
@@ -1416,29 +1426,26 @@ class TpuBackend:
         # lint-allow[host-sync-in-hot-path]: prefill done mask seeds the host loop's exit condition
         prev_done = jax.device_get(done)
         while not prev_done.all():
-            t_step = time.monotonic() if tracing else 0.0
-            with annotate(f"spec_step[B={B},S={S},k={k}]"):
+            # one span a verify step (dispatch to its fetch), as the loop
+            # has one sync a step: never one a token
+            with host_span("engine", "spec_step", sink, B=B, k=k) as step:
                 cur, cache, done, e, out, nd, acc = fn(
                     self.params, cur, cache, done, e, out, pad_dev,
                     ref_dev, lens_dev, seed,
                 )
-            steps_live += ~prev_done
-            # ONE explicit fetch per verify step: draft/accept counts feed
-            # the acceptance stats and done drives the loop exit — this is
-            # the sync the host loop already owes
-            # lint-allow[host-sync-in-hot-path]: per-step nd/acc/done fetch is the verify loop's control dependency
-            nd_h, acc_h, prev_done = jax.device_get((nd, acc, done))
+                steps_live += ~prev_done
+                # ONE explicit fetch per verify step: draft/accept counts
+                # feed the acceptance stats and done drives the loop exit —
+                # this is the sync the host loop already owes
+                # lint-allow[host-sync-in-hot-path]: per-step nd/acc/done fetch is the verify loop's control dependency
+                nd_h, acc_h, prev_done = jax.device_get((nd, acc, done))
+                # drafted vs accepted feeds the rolling acceptance gauge's
+                # per-step ground truth (the collector's, when installed)
+                step.note(live=int((~prev_done).sum()),
+                          drafted=int(nd_h.sum()), accepted=int(acc_h.sum()))
             drafted += nd_h
             accepted += acc_h
             self.stats.spec_verify_steps += 1
-            # per-verify-step telemetry: the nd/acc/done fetches above are
-            # the sync the loop already paid — drafted vs accepted feeds the
-            # rolling acceptance gauge's per-step ground truth. Gated: the
-            # sums/kwargs cost nothing on untraced runs
-            if tracing:
-                emit("spec_step", t_step, time.monotonic() - t_step, B=B,
-                     k=k, live=int((~prev_done).sum()),
-                     drafted=int(nd_h.sum()), accepted=int(acc_h.sum()))
         self.stats.spec_draft_tokens += int(drafted[: len(group)].sum())
         self.stats.spec_accepted_tokens += int(accepted[: len(group)].sum())
 
@@ -1455,7 +1462,7 @@ class TpuBackend:
     # -- prefix KV cache (vnsum_tpu.cache) -------------------------------
 
     def _prepare_resume(self, group, encoded, matches, pad_lens, B, S,
-                        max_new: int, tracing: bool):
+                        max_new: int):
         """Compute the trace-static skip boundary K for one packed group and
         gather the matched prefix blocks into a seeded cache.
 
@@ -1502,24 +1509,22 @@ class TpuBackend:
             nb_max = max(nb_max, len(blocks))
         if nb_max == 0:
             return None
-        t0 = time.time()
-        t0_m = time.monotonic() if tracing else 0.0
-        ids = np.full((B, nb_max), pc.store.scratch_id, dtype=np.int32)
-        for row, blocks in enumerate(ids_rows):
-            ids[row, : len(blocks)] = blocks
-        cache = self._init_prefill_cache(B, S + max_new)
-        cache = pc.gather(cache, ids, pad_lens)
-        skipped = [
-            max(K - int(pad_lens[row]), 0) for row in range(len(group))
-        ]
-        if tracing:
-            emit("cache_gather", t0_m, time.time() - t0, B=B, K=K,
-                 blocks=int((ids != pc.store.scratch_id).sum()),
-                 hit_tokens=sum(skipped))
+        with host_span("engine", "cache_gather", self.stats.host_spans,
+                       B=B, K=K) as sp:
+            ids = np.full((B, nb_max), pc.store.scratch_id, dtype=np.int32)
+            for row, blocks in enumerate(ids_rows):
+                ids[row, : len(blocks)] = blocks
+            cache = self._init_prefill_cache(B, S + max_new)
+            cache = pc.gather(cache, ids, pad_lens)
+            skipped = [
+                max(K - int(pad_lens[row]), 0) for row in range(len(group))
+            ]
+            sp.note(blocks=int((ids != pc.store.scratch_id).sum()),
+                    hit_tokens=sum(skipped))
         return K, cache, skipped
 
-    def _cache_insert(self, cache, group, encoded, matches, hints, pad_lens,
-                      tracing: bool) -> int:
+    def _cache_insert(self, cache, group, encoded, matches, hints,
+                      pad_lens) -> int:
         """Index the freshly prefilled prompts and copy their new prefix
         blocks into the pool. A cache_hint bounds the insertion to the
         hint-covered prefix (template headers, carried-forward summaries) so
@@ -1530,24 +1535,22 @@ class TpuBackend:
             # ladder rung NO_CACHE_INSERT: stop pool churn; hits still serve
             return 0
         BLK = pc.block_tokens
-        t0 = time.time()
-        t0_m = time.monotonic() if tracing else 0.0
-        evict0 = pc.index.stats.evictions
-        rows = []
-        for row, i in enumerate(group):
-            ids = encoded[i]
-            target = len(ids) - 1
-            hint = hints[i] if hints else None
-            if hint:
-                target = min(self._hint_prefix_len(hint, ids), target)
-            upto = target // BLK * BLK
-            if upto > matches[i].tokens:
-                rows.append((row, int(pad_lens[row]), ids, upto))
-        # every row's new blocks go to the pool in one dispatch
-        new_blocks = pc.insert(cache, rows)
-        if tracing and (new_blocks or pc.index.stats.evictions != evict0):
-            emit("cache_insert", t0_m, time.time() - t0, blocks=new_blocks,
-                 evictions=pc.index.stats.evictions - evict0)
+        with host_span("engine", "cache_insert", self.stats.host_spans) as sp:
+            evict0 = pc.index.stats.evictions
+            rows = []
+            for row, i in enumerate(group):
+                ids = encoded[i]
+                target = len(ids) - 1
+                hint = hints[i] if hints else None
+                if hint:
+                    target = min(self._hint_prefix_len(hint, ids), target)
+                upto = target // BLK * BLK
+                if upto > matches[i].tokens:
+                    rows.append((row, int(pad_lens[row]), ids, upto))
+            # every row's new blocks go to the pool in one dispatch
+            new_blocks = pc.insert(cache, rows)
+            sp.note(blocks=new_blocks,
+                    evictions=pc.index.stats.evictions - evict0)
         return new_blocks
 
     def _hint_prefix_len(self, hint: str, ids: list[int]) -> int:
@@ -1620,20 +1623,22 @@ class TpuBackend:
 
         Shared by the one-shot and spec paths and the choice scorer — their
         greedy-parity guarantee depends on identical bucketing and padding."""
-        t_pack = time.time()
-        max_input = self.cfg.max_seq_len - max_new
-        data_size = self.mesh.shape.get("data", 1) if self.mesh is not None else 1
-        S = _bucket_len(max(len(encoded[i]) for i in group), max_input)
-        # bucket the batch dim too, so a trailing partial group doesn't pay
-        # for all-pad rows up to the full batch_size
-        B = data_size
-        while B < len(group):
-            B *= 2
-        B = min(B, self.batch_size)
-        tokens, pad_lens = left_pad_batch(
-            [encoded[i] for i in group], B, S, self.tok.pad_id
-        )
-        self.stats.add_phase("pack_host", time.time() - t_pack)
+        with host_span("engine", "pack", self.stats.host_spans,
+                       rows=len(group)):
+            max_input = self.cfg.max_seq_len - max_new
+            data_size = (
+                self.mesh.shape.get("data", 1) if self.mesh is not None else 1
+            )
+            S = _bucket_len(max(len(encoded[i]) for i in group), max_input)
+            # bucket the batch dim too, so a trailing partial group doesn't
+            # pay for all-pad rows up to the full batch_size
+            B = data_size
+            while B < len(group):
+                B *= 2
+            B = min(B, self.batch_size)
+            tokens, pad_lens = left_pad_batch(
+                [encoded[i] for i in group], B, S, self.tok.pad_id
+            )
         return tokens, pad_lens, B, S
 
     # hot path
@@ -1707,25 +1712,21 @@ class TpuBackend:
         # scheduler's take_cache_report to misread
         self._cache_report = []
 
-        # telemetry gate, resolved once per generate() call (see the obs
-        # contract in backend/base.py): untraced runs skip every emit's
-        # timestamp/kwargs work, not just the emit itself
-        tracing = current_collector() is not None
+        # host spans (core.profiling.host_span): a fixed count a call and
+        # a dispatch, never one a row or a token — tests/test_host_spans.py
+        # holds the numbers
+        sink = self.stats.host_spans
         max_input = self.cfg.max_seq_len - max_new
         encoded: list[list[int]] = []
-        t_enc = time.time()
-        t_enc_m = time.monotonic()
-        # ONE batched call into the tokenizer (Rust side parallelizes and
-        # skips per-prompt Python overhead; measured 1.4x on this phase)
-        for ids in self.tok.encode_batch(prompts, add_bos=True):
-            if len(ids) > max_input:
-                ids = ids[:max_input]
-            encoded.append(ids)
-            self.stats.prompt_tokens += len(ids)
-        self.stats.add_phase("tokenize_host", time.time() - t_enc)
-        if tracing:
-            emit("tokenize", t_enc_m, time.time() - t_enc,
-                 prompts=len(prompts))
+        with host_span("engine", "tokenize", sink, prompts=len(prompts)):
+            # ONE batched call into the tokenizer (Rust side parallelizes
+            # and skips per-prompt Python overhead; measured 1.4x on this
+            # phase)
+            for ids in self.tok.encode_batch(prompts, add_bos=True):
+                if len(ids) > max_input:
+                    ids = ids[:max_input]
+                encoded.append(ids)
+                self.stats.prompt_tokens += len(ids)
 
         # prefix KV cache (vnsum_tpu.cache): match every prompt against the
         # radix index (pinning the matched blocks against eviction for the
@@ -1739,15 +1740,12 @@ class TpuBackend:
         matches = None
         cache_report = [0] * len(encoded)
         if use_cache:
-            t_cl = time.time()
-            t_cl_m = time.monotonic() if tracing else 0.0
-            matches = [
-                pc.match(ids, max_tokens=len(ids) - 1) for ids in encoded
-            ]
-            if tracing:
-                emit("cache_lookup", t_cl_m, time.time() - t_cl,
-                     prompts=len(encoded),
-                     hit_tokens=sum(m.tokens for m in matches))
+            with host_span("engine", "cache_lookup", sink,
+                           prompts=len(encoded)) as sp:
+                matches = [
+                    pc.match(ids, max_tokens=len(ids) - 1) for ids in encoded
+                ]
+                sp.note(hit_tokens=sum(m.tokens for m in matches))
             order = sorted(
                 range(len(encoded)),
                 key=lambda i: (len(encoded[i]) - matches[i].tokens,
@@ -1780,60 +1778,81 @@ class TpuBackend:
                             spec_report, seed,
                         )
                         continue
-                    tokens, pad_lens, B, S = self._pack_group(
-                        group, encoded, max_new
-                    )
-                    resume = None
-                    if matches is not None:
-                        resume = self._prepare_resume(
-                            group, encoded, matches, pad_lens, B, S, max_new,
-                            tracing,
+                    # one dispatch of the one-shot program, each phase under
+                    # its span, all inside ``engine/dispatch``, which closes
+                    # with the dispatch: a phase too short to name an idle
+                    # gap of the device leaves the gap to this parent. The
+                    # program is CALLED FROM THIS FRAME and no other
+                    # (tests/test_host_spans.py pins it, and says why)
+                    with host_span("engine", "dispatch", sink,
+                                   rows=len(group)) as disp:
+                        tokens, pad_lens, B, S = self._pack_group(
+                            group, encoded, max_new
                         )
-                    if resume is not None:
-                        for row, i in enumerate(group):
-                            cache_report[i] = resume[2][row]
-                    K = resume[0] if resume else 0
-                    fn = self._get_fn(B, S, max_new, gen, resume_from=K)
-                    t_disp = time.monotonic() if tracing else 0.0
-                    with annotate(f"generate[B={B},S={S}]"):
-                        if K:
-                            res = fn(self.params, tokens, pad_lens, seed,
-                                     resume[1])
-                        else:
-                            res = fn(self.params, tokens, pad_lens, seed)
-                        # with the prefix cache on, the program also returns its
-                        # final cache so new prefix blocks can be pooled
+                        disp.note(B=B, S=S, occupancy=len(group),
+                                  max_new=max_new)
+                        resume = None
+                        if matches is not None:
+                            resume = self._prepare_resume(
+                                group, encoded, matches, pad_lens, B, S,
+                                max_new,
+                            )
+                        if resume is not None:
+                            for row, i in enumerate(group):
+                                cache_report[i] = resume[2][row]
+                        K = resume[0] if resume else 0
+                        fn = self._get_fn(B, S, max_new, gen, resume_from=K)
+                        # the call alone: arguments transferred, output
+                        # buffers allocated, program queued — it returns
+                        # before the device ends
+                        with host_span("engine", "enqueue", sink,
+                                       B=B, S=S) as enqueue:
+                            if K:
+                                res = fn(self.params, tokens, pad_lens, seed,
+                                         resume[1])
+                            else:
+                                res = fn(self.params, tokens, pad_lens, seed)
+                        # with the prefix cache on, the program also returns
+                        # its final cache so new prefix blocks can be pooled
                         out_dev, final_cache = res if pc is not None else (res, None)
-                        # lint-allow[host-sync-in-hot-path]: one-shot result fetch bounds the dispatch and feeds detok
-                        out = jax.device_get(out_dev)
-                        if self.family.counters is not None:
-                            # a family that counts returns its counters
-                            # with the tokens: one fetch brought both
-                            out, counted = out
-                            self._add_expert_counts(counted)
-                    # the fused prefill+decode program has no observable
-                    # midpoint: one "dispatch" event bounds the whole device
-                    # call (the result fetch above synced it) — TTFT consumers
-                    # treat its end as the first-token upper bound
-                    if tracing:
-                        emit("dispatch", t_disp, time.monotonic() - t_disp,
-                             B=B, S=S, occupancy=len(group), max_new=max_new)
-                    self.stats.batches += 1
-                    self.stats.by_bucket[(B, S)] = (
-                        self.stats.by_bucket.get((B, S), 0) + 1
-                    )
-                    self._count_prefill_blocks(pad_lens, S, S + max_new, K)
-                    if use_cache:
-                        self._cache_insert(
-                            final_cache, group, encoded, matches,
-                            cache_hints, pad_lens, tracing,
+                        # the fused prefill+decode program has no observable
+                        # midpoint: this fetch bounds the whole device call
+                        # (execution and the copy back) — TTFT consumers
+                        # treat the dispatch's end as the first-token upper
+                        # bound
+                        with host_span("engine", "wait", sink,
+                                       B=B, S=S) as wait:
+                            # lint-allow[host-sync-in-hot-path]: one-shot result fetch bounds the dispatch and feeds detok
+                            out = jax.device_get(out_dev)
+                        with host_span("engine", "count", sink):
+                            if self.family.counters is not None:
+                                # a family that counts returns its counters
+                                # with the tokens: one fetch brought both
+                                out, counted = out
+                                self._add_expert_counts(counted)
+                            self._count_prefill_blocks(
+                                pad_lens, S, S + max_new, K)
+                        self.stats.batches += 1
+                        self.stats.by_bucket[(B, S)] = (
+                            self.stats.by_bucket.get((B, S), 0) + 1
                         )
-                    t_detok = time.monotonic() if tracing else 0.0
-                    for row, i in enumerate(group):
-                        results[i] = self._detok(out[row], tuple(gen.eos_ids))
-                    if tracing:
-                        emit("detokenize", t_detok, time.monotonic() - t_detok,
-                             rows=len(group))
+                        if use_cache:
+                            self._cache_insert(
+                                final_cache, group, encoded, matches,
+                                cache_hints, pad_lens,
+                            )
+                        with host_span("engine", "detokenize", sink,
+                                       rows=len(group)) as detok:
+                            eos = tuple(gen.eos_ids)
+                            for row, i in enumerate(group):
+                                results[i] = self._detok(out[row], eos)
+                    self.stats.note_dispatch(B, S, enqueue.dur, wait.dur)
+                    logger.info(
+                        "dispatch B=%d S=%d rows=%d: enqueue %.3fs wait "
+                        "%.3fs detokenize %.3fs of %.3fs",
+                        B, S, len(group), enqueue.dur, wait.dur, detok.dur,
+                        disp.dur,
+                    )
         finally:
             if matches is not None:
                 for m in matches:

@@ -39,7 +39,7 @@ import numpy as np
 
 from ..analysis.sanitizers import hot_path_transfer_guard
 from ..core.logging import get_logger
-from ..obs.trace import current_collector, emit
+from ..core.profiling import host_span
 from ..testing.faults import fault
 from .base import left_pad_batch, trim_to_eos
 
@@ -204,8 +204,8 @@ class TpuSlotLoop:
         import jax.numpy as jnp
 
         b = self.backend
+        sink = b.stats.host_spans
         t_admit = time.monotonic()
-        tracing = current_collector() is not None
         items = list(items)
         if not items or not self.free:
             return [], []
@@ -216,99 +216,115 @@ class TpuSlotLoop:
         keys = [it[0] for it in items]
         prompts = [it[1] for it in items]
         hints = [it[2] for it in items]
-        encoded = b.tok.encode_batch(prompts, add_bos=True)
-        rejected = [
-            keys[i] for i in range(len(items)) if len(encoded[i]) > self.S
-        ]
-        ok = [i for i in range(len(items)) if len(encoded[i]) <= self.S]
-        if not ok:
-            return [], rejected
-        free_slots = [s for s, k in enumerate(self._keys) if k is None]
-        n = min(len(ok), len(free_slots))
-        # the join bucket starts at the mesh data-axis size (join batches
-        # shard their rows over `data` exactly like the resident batch, so
-        # Bj must stay divisible by it; 1 single-chip) and grows by doubling
-        data_size = (
-            b.mesh.shape.get("data", 1) if b.mesh is not None else 1
-        )
-        Bj = data_size
-        while Bj < n:
-            Bj *= 2
-        if Bj > len(free_slots):
-            # the bucket's filler rows need free slots too — shrink the
-            # admit to the largest data_size * 2^k that fits outright; with
-            # fewer free slots than DP rows need, wait for the next boundary
-            if len(free_slots) < data_size:
-                return [], rejected
-            n = Bj = _pow2_floor(len(free_slots) // data_size) * data_size
-        take = ok[:n]
-
         pc = b.prefix_cache
         matches = None
-        if pc is not None:
-            matches = {i: pc.match(encoded[i], max_tokens=len(encoded[i]) - 1)
-                       for i in take}
-            # order the join group by UNCOVERED suffix so its shared resume
-            # boundary K is as deep as the coldest row allows (same policy
-            # as generate()'s cache ordering)
-            take.sort(key=lambda i: (len(encoded[i]) - matches[i].tokens,
-                                     len(encoded[i])))
+        # host spans, one set a JOIN (core.profiling.host_span): slot/pack
+        # (tokenize, match, pack), slot/prefill (dispatch to the done0
+        # fetch), slot/adopt — never one a row
         try:
-            group_ids = [encoded[i] for i in take]
-            group_hints = [hints[i] for i in take]
-            tokens, pad_lens = left_pad_batch(
-                group_ids, Bj, self.S, b.tok.pad_id
-            )
-            resume = None
-            if matches is not None:
-                group_matches = [matches[i] for i in take]
-                resume = b._prepare_resume(
-                    list(range(len(take))), group_ids, group_matches,
-                    pad_lens, Bj, self.S, self.max_new, tracing,
+            with host_span("slot", "pack", sink, prompts=len(items)):
+                encoded = b.tok.encode_batch(prompts, add_bos=True)
+                rejected = [
+                    keys[i] for i in range(len(items))
+                    if len(encoded[i]) > self.S
+                ]
+                ok = [i for i in range(len(items)) if len(encoded[i]) <= self.S]
+                if not ok:
+                    return [], rejected
+                free_slots = [s for s, k in enumerate(self._keys) if k is None]
+                n = min(len(ok), len(free_slots))
+                # the join bucket starts at the mesh data-axis size (join
+                # batches shard their rows over `data` exactly like the
+                # resident batch, so Bj must stay divisible by it; 1
+                # single-chip) and grows by doubling
+                data_size = (
+                    b.mesh.shape.get("data", 1) if b.mesh is not None else 1
                 )
-            K = resume[0] if resume else 0
-            uids = [self._uid_next + j for j in range(len(take))]
-            self._uid_next += len(take)
-            uids_np = np.zeros((Bj,), np.int32)
-            uids_np[: len(take)] = uids
+                Bj = data_size
+                while Bj < n:
+                    Bj *= 2
+                if Bj > len(free_slots):
+                    # the bucket's filler rows need free slots too — shrink
+                    # the admit to the largest data_size * 2^k that fits
+                    # outright; with fewer free slots than DP rows need,
+                    # wait for the next boundary
+                    if len(free_slots) < data_size:
+                        return [], rejected
+                    n = Bj = (
+                        _pow2_floor(len(free_slots) // data_size) * data_size
+                    )
+                take = ok[:n]
+
+                if pc is not None:
+                    matches = {
+                        i: pc.match(encoded[i], max_tokens=len(encoded[i]) - 1)
+                        for i in take
+                    }
+                    # order the join group by UNCOVERED suffix so its shared
+                    # resume boundary K is as deep as the coldest row allows
+                    # (same policy as generate()'s cache ordering)
+                    take.sort(key=lambda i: (
+                        len(encoded[i]) - matches[i].tokens, len(encoded[i])))
+                group_ids = [encoded[i] for i in take]
+                group_hints = [hints[i] for i in take]
+                tokens, pad_lens = left_pad_batch(
+                    group_ids, Bj, self.S, b.tok.pad_id
+                )
+                resume = None
+                if matches is not None:
+                    group_matches = [matches[i] for i in take]
+                    resume = b._prepare_resume(
+                        list(range(len(take))), group_ids, group_matches,
+                        pad_lens, Bj, self.S, self.max_new,
+                    )
+                K = resume[0] if resume else 0
+                uids = [self._uid_next + j for j in range(len(take))]
+                self._uid_next += len(take)
+                uids_np = np.zeros((Bj,), np.int32)
+                uids_np[: len(take)] = uids
             prefill = b._get_seg_fn(
                 "slot_prefill", Bj, self.S, self.max_new, self.gen, K
             )
-            t_pre = time.monotonic()
             with hot_path_transfer_guard():
-                if resume:
-                    first, join_cache, done0 = prefill(
-                        b.params, tokens, pad_lens, self.seed, uids_np,
-                        resume[1],
+                # the collector's "prefill": its end is the joiners' TTFT
+                # anchor, bounded by the fetch below (synced)
+                with host_span("slot", "prefill", sink, B=Bj, S=self.S,
+                               occupancy=len(take), synced=True) as pre:
+                    if resume:
+                        first, join_cache, done0 = prefill(
+                            b.params, tokens, pad_lens, self.seed, uids_np,
+                            resume[1],
+                        )
+                    else:
+                        first, join_cache, done0 = prefill(
+                            b.params, tokens, pad_lens, self.seed, uids_np
+                        )
+                    if pc is not None:
+                        # prefix-cache insertion reads the join cache BEFORE
+                        # the adopt dispatch donates it (the copies enter
+                        # the stream first)
+                        b._cache_insert(
+                            join_cache, list(range(len(take))), group_ids,
+                            group_matches, group_hints, pad_lens,
+                        )
+                    # the joiners' first token IS their TTFT: bound the
+                    # prefill dispatch with the cheapest output so the
+                    # anchor is honest
+                    # lint-allow[host-sync-in-hot-path]: sync makes the per-joiner TTFT anchor real, one [Bj] bool fetch per admit
+                    jax.device_get(done0)
+                prefill_end = pre.t0 + pre.dur
+                with host_span("slot", "adopt", sink, B=Bj):
+                    # lint-allow[host-sync-in-hot-path]: host list -> host array for the scatter indices, no device sync
+                    slot_idx = np.asarray(free_slots[:Bj], np.int32)
+                    adopt = b._get_seg_fn(
+                        "adopt", Bj, self.S, self.max_new, self.gen
                     )
-                else:
-                    first, join_cache, done0 = prefill(
-                        b.params, tokens, pad_lens, self.seed, uids_np
+                    (self._cache, self._cur, self._done, self._t, self._out,
+                     self._pads) = adopt(
+                        self._cache, self._cur, self._done, self._t,
+                        self._out, self._pads, join_cache, first, done0,
+                        jnp.asarray(pad_lens), slot_idx,
                     )
-                if pc is not None:
-                    # prefix-cache insertion reads the join cache BEFORE the
-                    # adopt dispatch donates it (the copies enter the stream
-                    # first)
-                    b._cache_insert(
-                        join_cache, list(range(len(take))), group_ids,
-                        group_matches, group_hints, pad_lens, tracing,
-                    )
-                # the joiners' first token IS their TTFT: bound the prefill
-                # dispatch with the cheapest output so the anchor is honest
-                # lint-allow[host-sync-in-hot-path]: sync makes the per-joiner TTFT anchor real, one [Bj] bool fetch per admit
-                jax.device_get(done0)
-                prefill_end = time.monotonic()
-                # lint-allow[host-sync-in-hot-path]: host list -> host array for the scatter indices, no device sync
-                slot_idx = np.asarray(free_slots[:Bj], np.int32)
-                adopt = b._get_seg_fn(
-                    "adopt", Bj, self.S, self.max_new, self.gen
-                )
-                (self._cache, self._cur, self._done, self._t, self._out,
-                 self._pads) = adopt(
-                    self._cache, self._cur, self._done, self._t, self._out,
-                    self._pads, join_cache, first, done0,
-                    jnp.asarray(pad_lens), slot_idx,
-                )
         finally:
             if matches is not None:
                 for m in matches.values():
@@ -344,9 +360,6 @@ class TpuSlotLoop:
             hit = sum(skipped)
             st.cache_hit_tokens += hit
             st.cache_miss_tokens += sum(len(g) for g in group_ids) - hit
-        if tracing:
-            emit("prefill", t_pre, prefill_end - t_pre, B=Bj, S=self.S,
-                 occupancy=len(take), synced=True)
         return admissions, rejected
 
     # -- one decode segment ----------------------------------------------
@@ -393,71 +406,73 @@ class TpuSlotLoop:
         import jax
 
         b = self.backend
-        tracing = current_collector() is not None
+        sink = b.stats.host_spans
         seg_fn = b._get_seg_fn(
             "slot_seg", self.slots, self.S, self.max_new, self.gen,
             fused=self.fused_segments,
         )
-        t0 = time.monotonic()
         self._out_snap = None
-        with hot_path_transfer_guard():
-            # lint-allow[host-sync-in-hot-path]: host list -> host array for the uids argument, no device sync
-            uids_np = np.asarray(self._uids, np.int32)
-            (self._t, self._cur, self._cache, self._done,
-             self._out) = seg_fn(
-                b.params, self._t, self._cur, self._cache, self._done,
-                uids_np, self._out, self._pads, self.seed,
-            )
-            # whether a row finished is unknowable before the done poll, so
-            # the out buffer ALWAYS rides the boundary fetch — one coalesced
-            # d2h covers harvest AND streaming instead of the former
-            # fetch-done-then-maybe-fetch-out / fetch-out-again-per-stream
-            # pattern (a [B, max_new] int32 block, small next to a segment's
-            # compute)
-            ctrl = (self._done, self._t, self._out)
-            self._await_retirement(ctrl)
-            # ONE explicit fetch for the whole boundary: control values and
-            # the output buffer together (the copies already landed — this
-            # resolves them without a fresh device sync)
-            # lint-allow[host-sync-in-hot-path]: segment-boundary done/t/out fetch is the loop's control dependency, already resident host-side via the async copies
-            done_h, t_h, out_h = jax.device_get(ctrl)
-            finished = [
-                s for s, k in enumerate(self._keys)
-                if k is not None and done_h[s]
+        # one span a fused DISPATCH, call to boundary fetch (the collector's
+        # "decode_seg"); nothing inside _await_retirement's poll
+        seg = host_span("slot", "segment", sink, event="decode_seg",
+                        B=self.slots, S=self.S, live=res.live, refill=True)
+        with seg:
+            with hot_path_transfer_guard():
+                # lint-allow[host-sync-in-hot-path]: host list -> host array for the uids argument, no device sync
+                uids_np = np.asarray(self._uids, np.int32)
+                (self._t, self._cur, self._cache, self._done,
+                 self._out) = seg_fn(
+                    b.params, self._t, self._cur, self._cache, self._done,
+                    uids_np, self._out, self._pads, self.seed,
+                )
+                # whether a row finished is unknowable before the done poll, so
+                # the out buffer ALWAYS rides the boundary fetch — one coalesced
+                # d2h covers harvest AND streaming instead of the former
+                # fetch-done-then-maybe-fetch-out / fetch-out-again-per-stream
+                # pattern (a [B, max_new] int32 block, small next to a segment's
+                # compute)
+                ctrl = (self._done, self._t, self._out)
+                self._await_retirement(ctrl)
+                # ONE explicit fetch for the whole boundary: control values and
+                # the output buffer together (the copies already landed — this
+                # resolves them without a fresh device sync)
+                # lint-allow[host-sync-in-hot-path]: segment-boundary done/t/out fetch is the loop's control dependency, already resident host-side via the async copies
+                done_h, t_h, out_h = jax.device_get(ctrl)
+                finished = [
+                    s for s, k in enumerate(self._keys)
+                    if k is not None and done_h[s]
+                ]
+            deltas = [
+                int(t_h[s]) - int(self._t_host[s])
+                for s, k in enumerate(self._keys) if k is not None
             ]
-        res.seconds = time.monotonic() - t0
-        deltas = [
-            int(t_h[s]) - int(self._t_host[s])
-            for s, k in enumerate(self._keys) if k is not None
-        ]
-        res.new_tokens = int(sum(deltas))
-        # how many on-device segment boundaries the fused dispatch crossed:
-        # the deepest row's advance, in segment_tokens units (early-stopped
-        # dispatches report fewer than fused_segments)
-        seg_tokens = max(int(b.segment_tokens), 1)
-        res.device_segments = min(
-            max(-(-max(deltas, default=0) // seg_tokens), 1),
-            self.fused_segments,
-        )
+            res.new_tokens = int(sum(deltas))
+            # how many on-device segment boundaries the fused dispatch crossed:
+            # the deepest row's advance, in segment_tokens units (early-stopped
+            # dispatches report fewer than fused_segments)
+            seg_tokens = max(int(b.segment_tokens), 1)
+            res.device_segments = min(
+                max(-(-max(deltas, default=0) // seg_tokens), 1),
+                self.fused_segments,
+            )
+            seg.note(fused=res.device_segments)
+        res.seconds = seg.dur
         for s, k in enumerate(self._keys):
             if k is not None:
                 self._t_host[s] = int(t_h[s])
         self._out_snap = out_h
-        for s in finished:
-            text = self._row_text(out_h[s], int(t_h[s]))
-            res.completions.append(SlotCompletion(
-                key=self._keys[s], text=text, slot=s,
-                gen_tokens=int(t_h[s]),
-            ))
-            self._keys[s] = None
-            self._prompts[s] = None
-            self._admissions.pop(s, None)
+        with host_span("slot", "harvest", sink, rows=len(finished)):
+            for s in finished:
+                text = self._row_text(out_h[s], int(t_h[s]))
+                res.completions.append(SlotCompletion(
+                    key=self._keys[s], text=text, slot=s,
+                    gen_tokens=int(t_h[s]),
+                ))
+                self._keys[s] = None
+                self._prompts[s] = None
+                self._admissions.pop(s, None)
         self.segments += res.device_segments
         self.fused_dispatches += 1
-        if tracing:
-            emit("decode_seg", t0, res.seconds, B=self.slots, S=self.S,
-                 live=res.live, refill=True,
-                 fused=res.device_segments)
         return res
 
     # -- preemption / streaming (serve/qos.py + serve/stream.py) ---------

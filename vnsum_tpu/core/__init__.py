@@ -13,13 +13,13 @@ from .faults import (
     call_with_retries,
 )
 from .logging import get_logger, setup_run_logging
-from .profiling import Tracer, annotate, device_profile
+from .profiling import Tracer, device_profile, host_span
 from .results import DocumentRecord, ModelRunRecord, PipelineResults
 
 __all__ = [
     "Tracer",
-    "annotate",
     "device_profile",
+    "host_span",
     "ApproachName",
     "EvalConfig",
     "GenerationConfig",

@@ -6,21 +6,26 @@ only when LangSmith env vars are set) and manual wall-clock spans stored in the
 run record (run_full_evaluation_pipeline.py:439,572-591). This module keeps
 those capabilities and makes them first-class:
 
+- `host_span(layer, name, sink)` — THE span primitive for host work: one
+  `time.monotonic()` interval given to the profiler (a `TraceAnnotation`
+  named `layer/name`, so a device trace's idle gaps carry the program's own
+  names), to the installed obs collector (`obs.trace.emit`, bare name) and to
+  an always-on aggregate (`sink`: `{layer/name: SpanStats}`). Names are a
+  contract like the kernels' `name=`: fixed strings, no shape or number in
+  one; shapes, rows and indices go in the keyword arguments.
 - `Tracer.span(name)` — nested wall-clock spans with aggregated statistics,
   thread-safe (strategy batches may fan out over a thread pool), persisted in
-  the structured run record instead of log lines. Rebased onto the obs span
-  model (`obs/trace.SpanRecorder`): pipeline runs and the serving layer now
-  share ONE span primitive, so a pipeline run can export the same
-  Perfetto-loadable Chrome trace the serving `/debug/trace` endpoint serves
-  (`Tracer.chrome_trace()`, written next to results by pipeline/runner.py
-  when profiling is armed).
+  the structured run record instead of log lines. Each is a `host_span` (so
+  it reaches the profiler too) kept under a hierarchical `parent/child` key
+  and on a timeline of the obs span model (`obs/trace.Span`), so a pipeline
+  run can export the same Perfetto-loadable Chrome trace the serving
+  `/debug/trace` endpoint serves (`Tracer.chrome_trace()`, written next to
+  results by pipeline/runner.py when profiling is armed).
 - `device_profile(log_dir)` — `jax.profiler.trace` wrapper producing TensorBoard
   / Perfetto traces of the on-device work (the TPU-native analog of the
   reference's LangSmith tracing). Gated: no-op unless a directory is given or
   `VNSUM_PROFILE_DIR` is set, mirroring the reference's env-gated LangSmith
   activation (...critique.py:22-23).
-- `annotate(name)` — `jax.profiler.TraceAnnotation` passthrough so host-side
-  phases show up inside device traces.
 - `hlo_scope_map(hlo_text)` — the device half: which `jax.named_scope` each
   instruction of a compiled program was written under. The device trace
   names an operation by its HLO line alone, so the program hands this map
@@ -32,12 +37,13 @@ from __future__ import annotations
 import contextlib
 import os
 import re
+import sys
 import threading
 import time
 from collections import Counter
 from dataclasses import dataclass
 
-from ..obs.trace import Span, SpanRecorder
+from ..obs.trace import Span, SpanRecorder, emit
 
 
 @dataclass
@@ -63,6 +69,66 @@ class SpanStats:
         }
 
 
+_TraceAnnotation = None   # jax.profiler's, once jax is in the process
+
+
+class host_span:
+    """One interval of host work, read once and given to three readers.
+
+    ``with host_span("engine", "wait", sink, B=8, S=8192):`` reads
+    ``time.monotonic()`` at entry and at exit; the block runs inside a
+    ``jax.profiler.TraceAnnotation("engine/wait", B=8, S=8192)`` (the
+    profiler's clock, the device plane's file: `benchmarks/trace_reduce.py`
+    names idle gaps by it); at exit the interval is added to ``sink``
+    (``{"engine/wait": SpanStats}``, always on) and emitted to the obs
+    collector under ``event`` (default: the bare ``name``), if one is
+    installed. ``note(**kw)`` adds arguments known only inside the block;
+    they reach the collector, not the annotation, which is open by then.
+    ``t0`` and ``dur`` stay readable after the block. With no profiler
+    session and no collector a span costs two clock reads, one dict update
+    and an empty TraceMe. The import of ``jax.profiler`` is this module's
+    (``obs/`` stays stdlib-only), and it is taken only once something else
+    has imported jax: no profiler session runs in a process that has not,
+    and a FakeBackend server must not pay a cold jax import for a span.
+    """
+
+    __slots__ = ("full", "event", "sink", "args", "t0", "dur", "_ann")
+
+    def __init__(self, layer: str, name: str, sink: dict | None = None,
+                 event: str | None = None, **args) -> None:
+        self.full = f"{layer}/{name}"
+        self.event = event or name
+        self.sink = sink
+        self.args = args
+        self.t0 = self.dur = 0.0
+
+    def note(self, **args) -> None:
+        self.args.update(args)
+
+    def __enter__(self) -> "host_span":
+        global _TraceAnnotation
+        if _TraceAnnotation is None and "jax" in sys.modules:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self._ann = None
+        if _TraceAnnotation is not None:
+            self._ann = _TraceAnnotation(self.full, **self.args)
+            self._ann.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.dur = time.monotonic() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self.sink is not None:
+            st = self.sink.get(self.full)
+            if st is None:
+                st = self.sink[self.full] = SpanStats()
+            st.add(self.dur)
+        emit(self.event, self.t0, self.dur, **self.args)
+        return False
+
+
 class Tracer:
     """Aggregating wall-clock tracer over the shared obs span model.
 
@@ -73,22 +139,38 @@ class Tracer:
     Two views of the same spans: `stats()` aggregates per name (bounded
     state, any run length — what lands in the run record), and `timeline()`
     keeps the first `timeline_maxlen` raw spans for `chrome_trace()` export.
-    The recorder's `on_close` hook feeds aggregation, so the two views can
-    never disagree about a span's duration.
+    Both take the one interval the span's `host_span` read, so they can
+    never disagree about a span's duration. The profiler sees the span as
+    `layer/name` (fixed strings: the hierarchy is this record's alone).
     """
 
-    def __init__(self, timeline_maxlen: int = 4096) -> None:
+    def __init__(self, timeline_maxlen: int = 4096,
+                 layer: str = "pipeline") -> None:
+        self.layer = layer
         self._stats: dict[str, SpanStats] = {}
         self._lock = threading.Lock()
-        self._rec = SpanRecorder(maxlen=timeline_maxlen,
-                                 on_close=self._aggregate)
+        self._rec = SpanRecorder(maxlen=timeline_maxlen)
+        self._local = threading.local()
 
     def _aggregate(self, full_name: str, duration: float) -> None:
         with self._lock:
             self._stats.setdefault(full_name, SpanStats()).add(duration)
 
-    def span(self, name: str):
-        return self._rec.span(name)
+    @contextlib.contextmanager
+    def span(self, name: str, layer: str | None = None, **args):
+        if not hasattr(self._local, "stack"):
+            self._local.stack = []
+        stack = self._local.stack
+        key = "/".join([*stack, name])
+        stack.append(name)
+        sp = host_span(layer or self.layer, name, **args)
+        try:
+            with sp:
+                yield sp
+        finally:
+            stack.pop()
+            self._aggregate(key, sp.dur)
+            self._rec.add(key, sp.t0, sp.dur, **sp.args)
 
     def record(self, name: str, duration: float) -> None:
         """Record an externally-timed span (e.g. a device-side step time)."""
@@ -134,19 +216,6 @@ def device_profile(log_dir: str | None = None):
     import jax
 
     with jax.profiler.trace(log_dir):
-        yield
-
-
-@contextlib.contextmanager
-def annotate(name: str):
-    """Named region inside a device trace (XPlane TraceMe annotation)."""
-    try:
-        import jax
-
-        cm = jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - jax always present in this image
-        cm = contextlib.nullcontext()
-    with cm:
         yield
 
 

@@ -165,6 +165,10 @@ class ServingStats:
     windows: int = 0
     window_joined: int = 0
     window_wait_seconds: float = 0.0
+    # host time between the slot loop's device calls (loop.admit /
+    # loop.step): gaps counted and their seconds, the window's wait included
+    host_gaps: int = 0
+    host_gap_seconds: float = 0.0
     # fused multi-step decode: host dispatches of the slot loop (each
     # covers up to --fused-segments on-device segments; == segments at N=1)
     fused_dispatches: int = 0

@@ -34,9 +34,10 @@ Six pieces, one span model:
                      to XLA device profiles from `core.profiling`
 
 Consumers: `serve/metrics.py` (histogram registry + /metrics), the
-scheduler (span recording + TTFT), `backend/engine.py` and `backend/fake.py`
-(phase emission), `core/profiling.Tracer` (pipeline spans rebased onto the
-same `SpanRecorder`), and the `/debug/trace` endpoint (`serve/server.py`).
+scheduler (span recording + TTFT), `backend/fake.py` and, through
+`core.profiling.host_span`, `backend/engine.py` and `backend/inflight.py`
+(phase emission), `core/profiling.Tracer` (its timeline is a
+`SpanRecorder`), and the `/debug/trace` endpoint (`serve/server.py`).
 """
 from .histogram import Histogram
 from .recorder import FlightRecorder

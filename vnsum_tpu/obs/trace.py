@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
-import threading
 import time
 from dataclasses import dataclass, field
 
@@ -48,45 +47,18 @@ class Span:
 
 
 class SpanRecorder:
-    """Thread-safe span sink with hierarchical naming.
+    """Thread-safe bounded span sink: the timeline under
+    `core/profiling.Tracer` (pipeline runs), which names and times its spans
+    itself (`core.profiling.host_span`) and appends the closed ones here."""
 
-    The shared span primitive under both `core/profiling.Tracer` (pipeline
-    runs) and :class:`RequestTrace` (serving): nested ``span()`` blocks get
-    `parent/child` names via a per-thread stack, closed spans append to a
-    bounded list, and an optional ``on_close(full_name, duration)`` callback
-    lets owners aggregate (the Tracer's SpanStats) without a second pass.
-    """
-
-    def __init__(self, maxlen: int = 4096, on_close=None) -> None:
+    def __init__(self, maxlen: int = 4096) -> None:
         self.maxlen = maxlen
-        self.on_close = on_close
         self._spans: list[Span] = []            # guarded by: _lock
         # lock-order-sanitizer hook: plain threading.Lock in production
         self._lock = make_lock("obs.spans")
-        self._local = threading.local()
-
-    def _stack(self) -> list[str]:
-        if not hasattr(self._local, "stack"):
-            self._local.stack = []
-        return self._local.stack
-
-    @contextlib.contextmanager
-    def span(self, name: str, track: int = 0, **args):
-        stack = self._stack()
-        full = "/".join([*stack, name])
-        stack.append(name)
-        t0 = time.monotonic()
-        try:
-            yield
-        finally:
-            dur = time.monotonic() - t0
-            stack.pop()
-            self.add(full, t0, dur, track=track, **args)
-            if self.on_close is not None:
-                self.on_close(full, dur)
 
     def add(self, name: str, t0: float, dur: float, track: int = 0, **args) -> None:
-        """Record an externally-timed span (no nesting bookkeeping)."""
+        """Record a closed span."""
         sp = Span(name, t0, dur, track, args or None)
         with self._lock:
             if len(self._spans) < self.maxlen:

@@ -209,43 +209,49 @@ class PipelineRunner:
         record = ModelRunRecord(model=model, approach=cfg.approach)
         t_start = time.time()
 
-        backend = self.backend_factory(model)
-        self.preflight(backend)
-        strategy_kw = {}
-        if cfg.approach == "truncated" and getattr(backend, "tok", None) is not None:
-            # the truncated cut must count tokens with the backend's OWN
-            # tokenizer — weights_dir/long-context runs rewrite it to the
-            # checkpoint's HF tokenizer, and a byte-token cut there would
-            # over-truncate ~4x
-            strategy_kw["tokenizer"] = backend.tok
-        strategy = get_strategy(cfg.approach, backend, cfg, **strategy_kw)
+        # host spans (core.profiling): each names the idle gap of the device
+        # it covers in a profiler trace — `pipeline/setup`, `pipeline/read`,
+        # `pipeline/write` here, `strategy/*` in the strategy, under the
+        # parents `pipeline/batch` and `pipeline/summarize`
+        with self.tracer.span("setup"):
+            backend = self.backend_factory(model)
+            self.preflight(backend)
+            strategy_kw = {}
+            if cfg.approach == "truncated" and getattr(backend, "tok", None) is not None:
+                # the truncated cut must count tokens with the backend's OWN
+                # tokenizer — weights_dir/long-context runs rewrite it to the
+                # checkpoint's HF tokenizer, and a byte-token cut there would
+                # over-truncate ~4x
+                strategy_kw["tokenizer"] = backend.tok
+            strategy = get_strategy(
+                cfg.approach, backend, cfg, tracer=self.tracer, **strategy_kw)
 
-        ds = DocumentDataset(cfg.docs_dir, cfg.summary_dir)
-        out_dir = self._output_dir(model)
-        out_dir.mkdir(parents=True, exist_ok=True)
+            ds = DocumentDataset(cfg.docs_dir, cfg.summary_dir)
+            out_dir = self._output_dir(model)
+            out_dir.mkdir(parents=True, exist_ok=True)
 
-        tree = None
-        if cfg.approach == "mapreduce_hierarchical":
-            tree_path = Path(cfg.tree_json_path)
-            if tree_path.is_file():
-                tree = DocumentTree.load(tree_path)
-            else:
-                logger.warning(
-                    "tree JSON %s missing; hierarchical will wrap plain text",
-                    tree_path,
-                )
+            tree = None
+            if cfg.approach == "mapreduce_hierarchical":
+                tree_path = Path(cfg.tree_json_path)
+                if tree_path.is_file():
+                    tree = DocumentTree.load(tree_path)
+                else:
+                    logger.warning(
+                        "tree JSON %s missing; hierarchical will wrap plain text",
+                        tree_path,
+                    )
 
-        names = ds.filenames(cfg.max_samples)
-        pending: list[str] = []
-        for name in names:
-            gen_path = out_dir / name
-            if gen_path.is_file():  # resume-by-file (ref :422-431)
-                logger.info("  %s: already exists, skipping", name)
-                continue
-            if self.config.summary_dir and not ds.has_reference(name):
-                logger.warning("  %s: no reference summary, skipping", name)
-                continue
-            pending.append(name)
+            names = ds.filenames(cfg.max_samples)
+            pending: list[str] = []
+            for name in names:
+                gen_path = out_dir / name
+                if gen_path.is_file():  # resume-by-file (ref :422-431)
+                    logger.info("  %s: already exists, skipping", name)
+                    continue
+                if self.config.summary_dir and not ds.has_reference(name):
+                    logger.warning("  %s: no reference summary, skipping", name)
+                    continue
+                pending.append(name)
 
         logger.info(
             "model %s: %d docs pending (%d total)", model, len(pending), len(names)
@@ -284,12 +290,14 @@ class PipelineRunner:
                                 zip([n for n, _ in tree_items], tree_results)
                             )
                         if docs_fallback:
-                            texts = [ds.read_doc(n) for n in docs_fallback]
+                            with self.tracer.span("read", docs=len(docs_fallback)):
+                                texts = [ds.read_doc(n) for n in docs_fallback]
                             results.extend(
                                 zip(docs_fallback, strategy.summarize_batch(texts))
                             )
                         return results
-                    texts = [ds.read_doc(n) for n in group]
+                    with self.tracer.span("read", docs=len(group)):
+                        texts = [ds.read_doc(n) for n in group]
                     return list(zip(group, strategy.summarize_batch(texts)))
 
             try:
@@ -320,18 +328,19 @@ class PipelineRunner:
             # wall time is amortized (record.time_basis); chunk/call counts
             # are true per-document values from the strategy
             per_doc_time = batch_time / max(len(results), 1)
-            for name, res in results:
-                summary = clean_thinking_tokens(res.summary)  # ref :560-561
-                (out_dir / name).write_text(summary, encoding="utf-8")
-                record.total_documents += 1
-                record.successful += 1
-                record.total_chunks += res.num_chunks
-                record.processing_details.append(
-                    DocumentRecord(
-                        name, res.num_chunks, per_doc_time, len(summary),
-                        llm_calls=res.llm_calls,
+            with self.tracer.span("write", docs=len(results)):
+                for name, res in results:
+                    summary = clean_thinking_tokens(res.summary)  # ref :560-561
+                    (out_dir / name).write_text(summary, encoding="utf-8")
+                    record.total_documents += 1
+                    record.successful += 1
+                    record.total_chunks += res.num_chunks
+                    record.processing_details.append(
+                        DocumentRecord(
+                            name, res.num_chunks, per_doc_time, len(summary),
+                            llm_calls=res.llm_calls,
+                        )
                     )
-                )
             logger.info(
                 "  batch of %d docs in %.1fs (%.1fs/doc)",
                 len(results), batch_time, per_doc_time,

@@ -57,6 +57,7 @@ import time
 
 from ..backend.base import Backend
 from ..core.logging import get_logger
+from ..core.profiling import host_span
 from ..core.results import ServeRequestRecord
 from .queue import ServeRequest, ShedReason
 from .scheduler import MicroBatchScheduler, _Completion
@@ -117,6 +118,15 @@ class InflightScheduler(MicroBatchScheduler):
         # (scheduler-thread state): their clients are presumably on their
         # way back, so the next IDLE take's window expects that many
         self._just_finished = 0
+        # host work of this thread between the loop's device calls
+        # (core.profiling.host_span's sink: serve/take, serve/admit,
+        # serve/complete — one a boundary, none a row or a stream event),
+        # and when the last of those calls came back: the next one's entry
+        # closes a host gap (metrics.observe_host_gap), unless a take came
+        # back empty in between — the loop had nothing to do then, and the
+        # time was the clients'
+        self.host_spans: dict = {}
+        self._device_returned_at: float | None = None
         super().__init__(backend, **kw)
 
     # -- scrape surface ---------------------------------------------------
@@ -333,8 +343,31 @@ class InflightScheduler(MicroBatchScheduler):
         stranded.extend(self._dispatching or [])
         return stranded
 
+    def _device_call(self, call, *args):
+        """``loop.admit`` or ``loop.step``, the two calls that put work on
+        the device: its entry closes the host gap since the last one came
+        back (``inflight_host_gap_seconds_total``), its return opens the
+        next."""
+        if self._device_returned_at is not None:
+            self.metrics.observe_host_gap(
+                time.monotonic() - self._device_returned_at)
+        try:
+            return call(*args)
+        finally:
+            self._device_returned_at = time.monotonic()
+
     def _take(self, loop, loop_key, active: int):
-        """One queue interaction. Idle (no row decodes): block for the
+        """One queue interaction, under the host span ``serve/take`` (the
+        wait and the coalescing window are inside it; nothing is opened
+        inside the queue's own wait)."""
+        with host_span("serve", "take", self.host_spans, active=active):
+            taken = self._take_from_queue(loop, loop_key, active)
+        if not taken and not active:
+            self._device_returned_at = None   # idle: no gap of the host's
+        return taken
+
+    def _take_from_queue(self, loop, loop_key, active: int):
+        """Idle (no row decodes): block for the
         head, then hold the coalescing window ``max_wait_s`` for company
         before the join — it closes the moment the free slots are full,
         and the rows that finished at the boundary just passed tell the
@@ -555,6 +588,7 @@ class InflightScheduler(MicroBatchScheduler):
     def _close_loop(self, loop) -> None:
         if loop is not None:
             self._live_loop = None
+            self._device_returned_at = None   # no gap outlives its loop
             loop.close()
 
     def _evict_all(self, loop, pending: list[ServeRequest]):
@@ -569,6 +603,14 @@ class InflightScheduler(MicroBatchScheduler):
     # -- admission ---------------------------------------------------------
 
     def _admit(self, loop, pending: list[ServeRequest]) -> list[ServeRequest]:
+        # serve/admit: what this method does around loop.admit (expiry,
+        # journal, records); the loop's own slot/* spans nest inside it
+        with host_span("serve", "admit", self.host_spans,
+                       pending=len(pending)):
+            return self._admit_pending(loop, pending)
+
+    def _admit_pending(self, loop,
+                       pending: list[ServeRequest]) -> list[ServeRequest]:
         now = time.monotonic()
         live: list[ServeRequest] = []
         for r in pending:
@@ -594,7 +636,7 @@ class InflightScheduler(MicroBatchScheduler):
         # chunked prefill — token-scaled budget like a one-shot dispatch
         ticket = self._wd_begin("slot_admit", [r for r, _p, _h in items])
         try:
-            admissions, rejected = loop.admit(items)
+            admissions, rejected = self._device_call(loop.admit, items)
         finally:
             self._wd_end(ticket)
         if self._stale_thread():
@@ -659,7 +701,7 @@ class InflightScheduler(MicroBatchScheduler):
                 self.watchdog.segment_budget(self.fused_segments),
             )
         try:
-            res = loop.step()
+            res = self._device_call(loop.step)
         finally:
             self._wd_end(ticket)
         if self._stale_thread():
@@ -671,6 +713,13 @@ class InflightScheduler(MicroBatchScheduler):
             # resolve is a done-guarded no-op)
             self._requeue_stale([c.key for c in res.completions])
             return
+        # serve/complete: everything after the segment's answers — stream
+        # deltas, request records, journal, futures — in one span
+        with host_span("serve", "complete", self.host_spans,
+                       completions=len(res.completions)):
+            self._complete_segment(loop, res)
+
+    def _complete_segment(self, loop, res) -> None:
         self.metrics.observe_segment(
             res.live, res.seconds, res.new_tokens,
             device_segments=getattr(res, "device_segments", 1),

@@ -103,6 +103,13 @@ _reg("inflight_window_joined_total", "counter",
      "in the same take as its head")
 _reg("inflight_window_wait_seconds_total", "counter",
      "seconds idle slot loops held coalescing windows open, anchor to take")
+_reg("inflight_host_gap_seconds_total", "counter",
+     "host seconds between the slot loop's device calls: from one "
+     "loop.admit / loop.step returning to the next one's entry, the "
+     "coalescing window's wait included; a take that came back empty at an "
+     "idle loop ends the gap uncounted")
+_reg("inflight_host_gaps_total", "counter",
+     "gaps counted in inflight_host_gap_seconds_total")
 _reg("inflight_fused_dispatches_total", "counter",
      "fused slot-loop dispatches by the in-flight scheduler (each covers "
      "up to --fused-segments on-device decode segments; equals "
@@ -501,6 +508,13 @@ class ServeMetrics:
             self._stats.window_joined += joined
             self._stats.window_wait_seconds += held_s
 
+    def observe_host_gap(self, gap_s: float) -> None:
+        """Host time between two device calls of the slot loop (the
+        in-flight scheduler's _device_call)."""
+        with self._lock:
+            self._stats.host_gaps += 1
+            self._stats.host_gap_seconds += gap_s
+
     # -- fault-tolerance hooks (serve/supervisor.py consumers) -----------
 
     def observe_failure(self, failure_class: str) -> None:
@@ -856,6 +870,9 @@ class ServeMetrics:
         simple("inflight_window_joined_total", s.window_joined)
         simple("inflight_window_wait_seconds_total",
                round(s.window_wait_seconds, 6))
+        simple("inflight_host_gap_seconds_total",
+               round(s.host_gap_seconds, 6))
+        simple("inflight_host_gaps_total", s.host_gaps)
         simple("inflight_fused_dispatches_total", s.fused_dispatches)
         typ, help_ = _METRICS["fault_failures_total"]
         lines.append(f"# HELP {_PREFIX}fault_failures_total {help_}")

@@ -13,6 +13,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Protocol
 
 from ..backend.base import Backend
+from ..core.profiling import host_span
 from ..text.tokenizer import whitespace_token_count
 
 
@@ -44,6 +45,17 @@ class Strategy(Protocol):
     def summarize(
         self, doc: str, *, backend: Backend | None = None
     ) -> StrategyResult: ...
+
+
+def strategy_span(strategy, name: str, **args):
+    """A host span of the strategy layer (``strategy/<name>`` to the
+    profiler): through the run's Tracer where ``get_strategy`` was handed
+    one, so the run record's ``tracing`` holds it too, else the bare
+    primitive. One a round or a document stage, never one a chunk."""
+    tracer = getattr(strategy, "tracer", None)
+    if tracer is not None:
+        return tracer.span(name, layer="strategy", **args)
+    return host_span("strategy", name, **args)
 
 
 class _BatchCounter:
@@ -126,10 +138,13 @@ def register_strategy(cls):
     return cls
 
 
-def get_strategy(name: str, backend: Backend, config, **kw):
-    """Instantiate a strategy from PipelineConfig-style settings."""
+def get_strategy(name: str, backend: Backend, config, tracer=None, **kw):
+    """Instantiate a strategy from PipelineConfig-style settings; ``tracer``
+    (a ``core.profiling.Tracer``) receives its host spans."""
     if name not in STRATEGY_REGISTRY:
         raise ValueError(
             f"unknown strategy {name!r}; have {sorted(STRATEGY_REGISTRY)}"
         )
-    return STRATEGY_REGISTRY[name].from_config(backend, config, **kw)
+    strategy = STRATEGY_REGISTRY[name].from_config(backend, config, **kw)
+    strategy.tracer = tracer
+    return strategy

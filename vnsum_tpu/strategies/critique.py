@@ -17,7 +17,13 @@ from typing import Callable
 from ..backend.base import Backend
 from ..text.splitter import RecursiveTokenSplitter
 from ..text.tokenizer import whitespace_token_count
-from .base import StrategyResult, _BatchCounter, register_strategy, split_by_token_budget
+from .base import (
+    StrategyResult,
+    _BatchCounter,
+    register_strategy,
+    split_by_token_budget,
+    strategy_span,
+)
 from .prompts import (
     CRITIQUE_ACCEPT_STRINGS,
     CRITIQUE_CRITIQUE,
@@ -130,16 +136,18 @@ class MapReduceCritiqueStrategy:
     ) -> list[StrategyResult]:
         gen = _BatchCounter(backend or self.backend, self.max_new_tokens)
 
-        chunks_per_doc = [self.splitter.split_text(d) or [d] for d in docs]
+        with strategy_span(self, "split", docs=len(docs)):
+            chunks_per_doc = [self.splitter.split_text(d) or [d] for d in docs]
         results = [
             StrategyResult(summary="", num_chunks=len(c)) for c in chunks_per_doc
         ]
 
-        flat = [
-            (di, CRITIQUE_MAP.format(content=c))
-            for di, chunks in enumerate(chunks_per_doc)
-            for c in chunks
-        ]
+        with strategy_span(self, "map_prompts", docs=len(docs)):
+            flat = [
+                (di, CRITIQUE_MAP.format(content=c))
+                for di, chunks in enumerate(chunks_per_doc)
+                for c in chunks
+            ]
         outs = gen(
             [p for _, p in flat], owners=[di for di, _ in flat],
             cache_hints=[template_header(CRITIQUE_MAP)] * len(flat),

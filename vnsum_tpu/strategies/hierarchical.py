@@ -22,7 +22,12 @@ from ..text.tree import (
     replace_node_with_paragraph,
     tree_depth,
 )
-from .base import StrategyResult, _BatchCounter, register_strategy
+from .base import (
+    StrategyResult,
+    _BatchCounter,
+    register_strategy,
+    strategy_span,
+)
 from .prompts import (
     HIERARCHICAL_MAP,
     HIERARCHICAL_POLISH,
@@ -91,12 +96,14 @@ class HierarchicalStrategy:
             getattr(be, "harvest", None)
         ):
             return self._mapreduce_texts_streaming(be, gen, texts, owners)
-        chunks_per = [self.splitter.split_text(t) or [t] for t in texts]
-        flat = [
-            (ti, HIERARCHICAL_MAP.format(content=c))
-            for ti, chunks in enumerate(chunks_per)
-            for c in chunks
-        ]
+        with strategy_span(self, "split", docs=len(texts)):
+            chunks_per = [self.splitter.split_text(t) or [t] for t in texts]
+        with strategy_span(self, "map_prompts", docs=len(texts)):
+            flat = [
+                (ti, HIERARCHICAL_MAP.format(content=c))
+                for ti, chunks in enumerate(chunks_per)
+                for c in chunks
+            ]
         outs = gen(
             [p for _, p in flat], owners=[owners[ti] for ti, _ in flat],
             cache_hints=[template_header(HIERARCHICAL_MAP)] * len(flat),
@@ -121,7 +128,8 @@ class HierarchicalStrategy:
         call."""
         from concurrent.futures import FIRST_COMPLETED, wait
 
-        chunks_per = [self.splitter.split_text(t) or [t] for t in texts]
+        with strategy_span(self, "split", docs=len(texts)):
+            chunks_per = [self.splitter.split_text(t) or [t] for t in texts]
         per_text: list[list[str | None]] = [
             [None] * len(c) for c in chunks_per
         ]

@@ -10,7 +10,12 @@ from __future__ import annotations
 
 from ..backend.base import Backend
 from ..text.splitter import RecursiveTokenSplitter
-from .base import StrategyResult, _BatchCounter, register_strategy
+from .base import (
+    StrategyResult,
+    _BatchCounter,
+    register_strategy,
+    strategy_span,
+)
 from .prompts import ITERATIVE_INITIAL, ITERATIVE_REFINE, template_header
 
 # the refine prompt up to (not including) {context}: header + the carried
@@ -51,7 +56,8 @@ class IterativeStrategy:
         self, docs: list[str], *, backend: Backend | None = None
     ) -> list[StrategyResult]:
         gen = _BatchCounter(backend or self.backend, self.max_new_tokens)
-        chunks_per_doc = [self.splitter.split_text(d) or [d] for d in docs]
+        with strategy_span(self, "split", docs=len(docs)):
+            chunks_per_doc = [self.splitter.split_text(d) or [d] for d in docs]
         summaries = [""] * len(docs)
         max_rounds = max(len(c) for c in chunks_per_doc) if docs else 0
 

@@ -14,7 +14,13 @@ from typing import Callable
 from ..backend.base import Backend
 from ..text.splitter import RecursiveTokenSplitter
 from ..text.tokenizer import whitespace_token_count
-from .base import StrategyResult, _BatchCounter, register_strategy, split_by_token_budget
+from .base import (
+    StrategyResult,
+    _BatchCounter,
+    register_strategy,
+    split_by_token_budget,
+    strategy_span,
+)
 from .prompts import MAPREDUCE_MAP, MAPREDUCE_REDUCE, template_header
 
 
@@ -75,7 +81,8 @@ class MapReduceStrategy:
             return self._summarize_batch_streaming(docs, be)
         gen = _BatchCounter(be, self.max_new_tokens)
 
-        chunks_per_doc = [self.splitter.split_text(d) or [d] for d in docs]
+        with strategy_span(self, "split", docs=len(docs)):
+            chunks_per_doc = [self.splitter.split_text(d) or [d] for d in docs]
         results = [
             StrategyResult(summary="", num_chunks=len(c)) for c in chunks_per_doc
         ]
@@ -87,12 +94,13 @@ class MapReduceStrategy:
         # template header is the cache_hint: every map prompt of every
         # document starts with it, so one prefilled header (vnsum_tpu.cache)
         # serves the whole fan-out
-        map_hint = template_header(self.map_prompt)
-        flat = [
-            (di, self.map_prompt.format(content=c), c)
-            for di, chunks in enumerate(chunks_per_doc)
-            for c in chunks
-        ]
+        with strategy_span(self, "map_prompts", docs=len(docs)):
+            map_hint = template_header(self.map_prompt)
+            flat = [
+                (di, self.map_prompt.format(content=c), c)
+                for di, chunks in enumerate(chunks_per_doc)
+                for c in chunks
+            ]
         outs = gen(
             [p for _, p, _ in flat],
             owners=[di for di, _, _ in flat],
@@ -114,38 +122,42 @@ class MapReduceStrategy:
         # a pure scheduling change.
         final_texts: dict[int, str] = {}
         for round_no in range(self.max_collapse_rounds + 1):
-            over = [
-                di
-                for di, s in enumerate(summaries)
-                if di not in final_texts
-                and sum(self.count(x) for x in s) > self.token_max
-            ]
-            ready = [
-                di for di in range(len(docs))
-                if di not in final_texts and di not in over
-            ]
-            if round_no == self.max_collapse_rounds and over:
-                # collapse budget exhausted (ref recursion_limit=10, :196):
-                # force the final over whatever remains, as the sequential
-                # formulation did
-                ready += over
-                over = []
-            batch: list[tuple[str, int, int]] = []
-            prompts: list[str] = []
-            refs: list[str] = []
-            for di in ready:
-                batch.append(("final", di, 0))
-                prompts.append(self._reduce_one(summaries[di]))
-                # reduce output re-emits spans of the summaries it merges
-                refs.append("\n\n".join(summaries[di]))
-            grouped: dict[int, list[list[str]]] = {}
-            for di in over:
-                groups = split_by_token_budget(summaries[di], self.token_max, self.count)
-                grouped[di] = groups
-                for gi, g in enumerate(groups):
-                    batch.append(("collapse", di, gi))
-                    prompts.append(self._reduce_one(g))
-                    refs.append("\n\n".join(g))
+            # the budget split and the formatting between two rounds of
+            # generation: one span a round, whatever the documents
+            with strategy_span(self, "reduce_prompts", round=round_no):
+                over = [
+                    di
+                    for di, s in enumerate(summaries)
+                    if di not in final_texts
+                    and sum(self.count(x) for x in s) > self.token_max
+                ]
+                ready = [
+                    di for di in range(len(docs))
+                    if di not in final_texts and di not in over
+                ]
+                if round_no == self.max_collapse_rounds and over:
+                    # collapse budget exhausted (ref recursion_limit=10, :196):
+                    # force the final over whatever remains, as the sequential
+                    # formulation did
+                    ready += over
+                    over = []
+                batch: list[tuple[str, int, int]] = []
+                prompts: list[str] = []
+                refs: list[str] = []
+                for di in ready:
+                    batch.append(("final", di, 0))
+                    prompts.append(self._reduce_one(summaries[di]))
+                    # reduce output re-emits spans of the summaries it merges
+                    refs.append("\n\n".join(summaries[di]))
+                grouped: dict[int, list[list[str]]] = {}
+                for di in over:
+                    groups = split_by_token_budget(
+                        summaries[di], self.token_max, self.count)
+                    grouped[di] = groups
+                    for gi, g in enumerate(groups):
+                        batch.append(("collapse", di, gi))
+                        prompts.append(self._reduce_one(g))
+                        refs.append("\n\n".join(g))
             if not prompts:
                 break
             outs = gen(
@@ -186,7 +198,8 @@ class MapReduceStrategy:
         fails the whole call — there is no summary to degrade to."""
         from concurrent.futures import FIRST_COMPLETED, wait
 
-        chunks_per_doc = [self.splitter.split_text(d) or [d] for d in docs]
+        with strategy_span(self, "split", docs=len(docs)):
+            chunks_per_doc = [self.splitter.split_text(d) or [d] for d in docs]
         results = [
             StrategyResult(summary="", num_chunks=len(c)) for c in chunks_per_doc
         ]
@@ -217,40 +230,39 @@ class MapReduceStrategy:
         parts_left = [0] * len(docs)
         rounds_done = [0] * len(docs)
         final_texts: dict[int, str] = {}
-        submit(
-            [
+        with strategy_span(self, "map_prompts", docs=len(docs)):
+            map_entries = [
                 (("map", di, ci), self.map_prompt.format(content=c), c)
                 for di, chunks in enumerate(chunks_per_doc)
                 for ci, c in enumerate(chunks)
-            ],
-            "map",
-            map_hint,
-        )
+            ]
+        submit(map_entries, "map", map_hint)
 
         def advance(di: int) -> None:
             # this doc's maps (or its current collapse round) all landed:
-            # submit the next reduce stage immediately
-            texts = [s for s in summaries[di] if s is not None]
-            if (
-                sum(self.count(x) for x in texts) <= self.token_max
-                or rounds_done[di] >= self.max_collapse_rounds
-            ):
-                submit(
-                    [(("final", di, 0), self._reduce_one(texts),
-                      "\n\n".join(texts))],
-                    "reduce", reduce_hint,
+            # submit the next reduce stage immediately (one span a document
+            # stage: the budget split and the formatting, not the submit)
+            with strategy_span(self, "reduce_prompts", doc=di):
+                texts = [s for s in summaries[di] if s is not None]
+                final = (
+                    sum(self.count(x) for x in texts) <= self.token_max
+                    or rounds_done[di] >= self.max_collapse_rounds
                 )
-                return
-            groups = split_by_token_budget(texts, self.token_max, self.count)
-            summaries[di] = [None] * len(groups)
-            parts_left[di] = len(groups)
-            submit(
-                [
-                    (("collapse", di, gi), self._reduce_one(g), "\n\n".join(g))
-                    for gi, g in enumerate(groups)
-                ],
-                "reduce", reduce_hint,
-            )
+                if final:
+                    entries = [(("final", di, 0), self._reduce_one(texts),
+                                "\n\n".join(texts))]
+                else:
+                    groups = split_by_token_budget(
+                        texts, self.token_max, self.count)
+                    entries = [
+                        (("collapse", di, gi), self._reduce_one(g),
+                         "\n\n".join(g))
+                        for gi, g in enumerate(groups)
+                    ]
+            if not final:
+                summaries[di] = [None] * len(groups)
+                parts_left[di] = len(groups)
+            submit(entries, "reduce", reduce_hint)
 
         while pending:
             done, _ = wait(list(pending), return_when=FIRST_COMPLETED)
