@@ -284,19 +284,66 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert():
                                atol=2e-5)
 
 
-def test_expert_layout_gives_every_slot_a_row_of_its_expert():
+def _layout_case(name):
+    rng = np.random.default_rng(7)
+    return {
+        # (slots, experts, tm)
+        "mixed": ([3, -1, 0, 3, 3, 1, -1, 0, 3], 4, 2),
+        "all_on_one_expert": ([2] * 37, 5, 8),
+        "no_slot_held": ([-1] * 12, 3, 4),
+        "group_ends_on_a_tile_edge": ([0] * 8 + [1] * 4 + [-1] + [2] * 12, 3, 4),
+        "one_slot": ([0], 1, 16),
+        "random_wide": (rng.integers(-1, 64, 600).tolist(), 64, 32),
+        "random_mostly_not_held": (
+            np.where(rng.random(500) < 0.76, -1,
+                     rng.integers(0, 40, 500)).tolist(), 40, 16),
+    }[name]
+
+
+@pytest.mark.parametrize("case", [
+    "mixed", "all_on_one_expert", "no_slot_held", "group_ends_on_a_tile_edge",
+    "one_slot", "random_wide", "random_mostly_not_held"])
+def test_expert_layout_gives_every_slot_a_row_of_its_expert(case):
+    """The layout's contract: every held slot a row of its own in a tile of
+    its expert, in the slots' order inside a group; groups padded to whole
+    tiles from a tile's edge; a spare row for the slots not held; each row
+    names its slot, padding names none; static M at the worst case."""
     from vnsum_tpu.ops.expert_matmul import expert_layout
 
-    slots = jnp.asarray([3, -1, 0, 3, 3, 1, -1, 0, 3], jnp.int32)
-    row, tile_expert, used, sizes, M = expert_layout(slots, 4, 2)
-    row, tile_expert = np.asarray(row), np.asarray(tile_expert)
-    assert np.asarray(sizes).tolist() == [2, 1, 0, 4]
-    assert int(used[0]) == 1 + 1 + 0 + 2
-    held = np.asarray(slots) >= 0
+    slots, E, tm = _layout_case(case)
+    slots = np.asarray(slots, np.int32)
+    carry = np.arange(1, len(slots) + 1, dtype=np.float32) * 0.5
+    row, slot_of_row, tile_expert, used, sizes, M, (carried,) = expert_layout(
+        jnp.asarray(slots), E, tm, [jnp.asarray(carry)])
+    row, slot_of_row, tile_expert, sizes = (
+        np.asarray(a) for a in (row, slot_of_row, tile_expert, sizes))
+    N = len(slots)
+    assert M == (-(-N // tm) + E + 1) * tm == len(slot_of_row)
+    assert len(tile_expert) == M // tm
+    want_sizes = [int((slots == e).sum()) for e in range(E)]
+    assert sizes.tolist() == want_sizes
+    n_used = sum(-(-s // tm) for s in want_sizes)
+    assert int(used[0]) == n_used
+    held = slots >= 0
     assert len(set(row[held])) == held.sum()          # no two slots share a row
-    for r, e in zip(row[held], np.asarray(slots)[held]):
-        assert tile_expert[r // 2] == e
-    assert (row[~held] == M - 1).all() and M - 1 >= int(used[0]) * 2
+    for r, e in zip(row[held], slots[held]):
+        assert tile_expert[r // tm] == e and r < n_used * tm
+    assert (row[~held] == M - 1).all() and M - 1 >= n_used * tm
+    # a group starts on a tile's edge and keeps the slots' order
+    start = 0
+    for e, size in enumerate(want_sizes):
+        mine = np.flatnonzero(slots == e)
+        assert row[mine].tolist() == list(range(start, start + size))
+        start += -(-size // tm) * tm
+    # the permutation read from the rows' side
+    assert (slot_of_row[row[held]] == np.flatnonzero(held)).all()
+    assert (slot_of_row >= 0).sum() == held.sum()
+    assert slot_of_row.min() >= -1 and slot_of_row.max() < N
+    # what a slot carries arrives on its row, 0 on padding
+    assert (np.asarray(carried) == np.where(
+        slot_of_row >= 0, carry[np.maximum(slot_of_row, 0)], 0)).all()
+    if case == "mixed":
+        assert want_sizes == [2, 1, 0, 4] and n_used == 1 + 1 + 0 + 2
 
 
 # -- the program through the engine ------------------------------------------
@@ -480,5 +527,7 @@ def test_the_kernels_keep_their_contracted_names():
     for src in ("mla_attention.py", "expert_matmul.py"):
         named |= set(re.findall(r'^\s+name="(\w+)",$',
                                 (ops / src).read_text(), re.M))
+    # ``expert_combine`` (PR 40) is a kernel of its own: the products keep
+    # the name the trace readers and both rooflines read
     assert named == {"mla_prefill_attention", "mla_decode_attention",
-                     "expert_grouped_matmul"}
+                     "expert_grouped_matmul", "expert_combine"}

@@ -258,6 +258,116 @@ def test_grouped_experts_agree_with_dense_experts(tiny, act, int8):
     np.testing.assert_allclose(grouped, dense, atol=1e-5)
 
 
+def _layer_inputs(cfg, T, k, seed=4, not_held=0.2):
+    ks = jax.random.split(jax.random.key(seed), 4)
+    x = jax.random.normal(ks[0], (T, cfg.dim))
+    _, ids = jax.lax.top_k(jax.random.normal(ks[1], (T, cfg.n_held)), k)
+    ids = jnp.where(jax.random.uniform(ks[2], (T, k)) < not_held, -1, ids)
+    return x, ids.astype(jnp.int32), jax.random.uniform(ks[3], (T, k)) + 0.1
+
+
+def _stacked(params, int8):
+    from vnsum_tpu.models.quant import quantize_params
+
+    if int8:
+        params = quantize_params(params)
+    return {n: params["layers"][n] for n in experts.EXPERT_LEAVES}
+
+
+@pytest.mark.parametrize("T", [150, 1100])   # the gather back, the kernel back
+@pytest.mark.parametrize("rows", ["float", "int8"])
+@pytest.mark.parametrize("act", ["relu", "silu"])
+def test_grouped_experts_across_a_piece_boundary(tiny, monkeypatch, T, rows,
+                                                 act):
+    """Three pieces of the map, the last one padded: float rows on int8
+    weights and int8 rows (W8A8), both gates, below and above the size at
+    which ``expert_combine`` brings the rows back."""
+    _, params = tiny
+    cfg = st.tiny_smallthinker(act=act, w8a8_prefill=rows == "int8")
+    stacked = _stacked(params, True)
+    monkeypatch.setattr(experts, "_EXPERT_PIECE_TOKENS", -(-T // 3) + 1)
+    x, ids, w = _layer_inputs(cfg, T, 2)
+    dense = experts.dense_experts(x, ids, w, stacked, 2, cfg)
+    grouped = experts.grouped_experts(x, ids, w, stacked, 2, cfg,
+                                      interpret=True)
+    scale = float(jnp.abs(dense).max())
+    assert scale > 1e-3
+    # int8 rows round the activations twice; float rows only reorder sums
+    np.testing.assert_allclose(grouped, dense,
+                               atol=0.03 * scale if rows == "int8" else 1e-5)
+
+
+@pytest.mark.parametrize("T", [40, 1024])
+@pytest.mark.parametrize("rows", ["float", "int8"])
+def test_rows_of_a_skipped_tile_never_reach_the_output(tiny, monkeypatch, T,
+                                                       rows):
+    """What the product leaves in the tiles past ``tiles_used`` is
+    unspecified: made NaN here, in both products. The way back selects (or
+    never fetches); a sum weighted with zeros would give NaN."""
+    from vnsum_tpu.ops import expert_matmul as em
+
+    product = em.expert_grouped_matmul
+
+    def poisoned(lhs, lhs_scale, w, w_up, layer, tile_expert, tiles_used,
+                 **kw):
+        out = product(lhs, lhs_scale, w, w_up, layer, tile_expert,
+                      tiles_used, **kw)
+        row_tile = jnp.arange(out.shape[0])[:, None] // kw["tm"]
+        return jnp.where(row_tile < tiles_used[0], out, jnp.nan)
+
+    monkeypatch.setattr(em, "expert_grouped_matmul", poisoned)
+    _, params = tiny
+    cfg = st.tiny_smallthinker(w8a8_prefill=rows == "int8")
+    stacked = _stacked(params, True)
+    x, ids, w = _layer_inputs(cfg, T, 2, not_held=0.5)
+    got = experts.grouped_experts(x, ids, w, stacked, 1, cfg, interpret=True)
+    assert bool(jnp.isfinite(got).all())
+    dense = experts.dense_experts(x, ids, w, stacked, 1, cfg)
+    scale = float(jnp.abs(dense).max())
+    np.testing.assert_allclose(got, dense,
+                               atol=0.03 * scale if rows == "int8" else 1e-5)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+@pytest.mark.parametrize("T", [24, 2048])   # a decode step, a prefill piece
+def test_expert_layer_lowers_to_one_permutation(tiny, T):
+    """The structure around the grouped product: no scatter, no running sum
+    down an [N, E] one-hot, and the row-wide gathers do not multiply with k:
+    one in all (a prefill piece gathers its rows into expert order and
+    ``expert_combine`` brings them back; a decode step picks its rows with
+    a 0/1 product and gathers them back)."""
+    _, params = tiny
+    cfg = st.tiny_smallthinker(w8a8_prefill=True)
+    stacked = _stacked(params, True)
+
+    def wide_gathers(k):
+        x, ids, w = _layer_inputs(cfg, T, k, not_held=0.0)
+        jaxpr = jax.make_jaxpr(lambda *a: experts.grouped_experts(
+            *a, stacked, 0, cfg, interpret=True))(x, ids, w)
+        names = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+        assert not any("scatter" in n for n in names)
+        for e in _eqns(jaxpr.jaxpr):
+            if e.primitive.name.startswith("cum"):   # tables, not slots
+                assert T * k not in e.invars[0].aval.shape, e
+        assert "sort" in names
+        return sum(1 for e in _eqns(jaxpr.jaxpr)
+                   if e.primitive.name == "gather"
+                   and e.outvars[0].aval.shape[-1:] == (cfg.dim,))
+
+    few, many = wide_gathers(2), wide_gathers(6)
+    assert few == many == 1
+
+
 def test_expert_matmul_refuses_an_unknown_gate():
     from vnsum_tpu.ops.expert_matmul import expert_grouped_matmul
 

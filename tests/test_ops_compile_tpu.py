@@ -124,6 +124,26 @@ def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
     assert (_column_tile(D, F), _column_tile(F, D)) == column_tiles
 
 
+@pytest.mark.parametrize("widths,tokens", [
+    (_DEEPSEEK, 128), (_SMALLTHINKER, 256)])
+def test_expert_combine_compiles_at_a_piece_of_the_cells(one_chip, widths,
+                                                         tokens):
+    """The rows' way back at a piece of 8,192 tokens x 6 picks: chunks of 16
+    rows by DMA into two row buffers that fit the scoped VMEM at both
+    widths (5,120 wide a token tile is 128, not 256), the picks summed by a
+    0/1 product."""
+    from vnsum_tpu.ops.expert_matmul import _combine_tile, expert_combine
+
+    _L, E, D, _F, _act, _tiles = widths
+    N = 8192 * 6
+    M = (N // 256 + E + 1) * 256
+    assert _combine_tile(6, E, D, 2) == tokens
+    c = _compiled(
+        lambda y, e, r: expert_combine(y, e, r, n_experts=E, k=6),
+        one_chip, ((M, D), BF16), ((N,), I32), ((N,), I32))
+    assert "expert_combine" in c.as_text()
+
+
 def _int8_cache(L, B, KV, C, hd):
     return {"k": ((L, B, KV, C, hd), I8), "v": ((L, B, KV, C, hd), I8),
             "ks": ((L, B, KV, C), F32), "vs": ((L, B, KV, C), F32)}

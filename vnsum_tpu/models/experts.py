@@ -11,6 +11,14 @@ into the state the program carries, and the product runs through
 ``grouped_experts`` (``ops/expert_matmul.py``, no capacity, nothing
 dropped) or, with no kernel, ``dense_experts``.
 
+``grouped_experts`` is one permutation around two products: a sort puts the
+slots into expert order and carries each slot's routing weight and its
+token's int8 scale along (``expert_layout``), ONE row gather builds the
+experts' rows, the weight goes into the down product's per-row factor, and
+the rows come back summed a token by ``expert_combine`` (a prefill piece)
+or by one gather (a decode step). Which of the two, like the row tile,
+follows the static token count alone.
+
 What a config has to say: ``n_held``, ``expert_offset``,
 ``moe_intermediate``, ``num_experts_per_tok``, ``act`` (the gate's
 activation), ``w8a8_prefill``. What the state holds (``init_expert_state``):
@@ -95,8 +103,19 @@ def grouped_experts(x, local, weights, experts, slot, cfg, *,
     is not held (or the token is padding); ``weights`` [T, k]; ``experts``
     the STACKED ``we_gate``/``we_up``/``we_down`` of every expert layer and
     ``slot`` this layer's index in them (the kernel reads the stack in
-    place). Returns the weighted sum over each token's held picks, [T, D]."""
-    from ..ops.expert_matmul import expert_grouped_matmul, expert_layout
+    place). Returns the weighted sum over each token's held picks, [T, D].
+
+    Around the two products there is one permutation (``expert_layout``):
+    token rows are gathered into expert order once, the routing weight goes
+    into the down product's rows there (the per-row factor the kernel
+    applies: the rows' int8 scale times the weight, or the weight alone),
+    and a token's k rows come back summed in float32 (``expert_combine``,
+    or one gather where T is a decode step's)."""
+    from ..ops.expert_matmul import (
+        expert_combine,
+        expert_grouped_matmul,
+        expert_layout,
+    )
 
     T, D = x.shape
     k = local.shape[1]
@@ -105,42 +124,74 @@ def grouped_experts(x, local, weights, experts, slot, cfg, *,
     # W8A8: in a decode step too, where converting each expert's weight
     # tile to bf16 in the kernel would cost more than fetching it
     int8_rows = quantized and cfg.w8a8_prefill
-    tm = 256 if T >= 1024 else (32 if int8_rows else 16)
+    # a prefill piece, or a decode step's few tokens: the row tile and the
+    # two ends of the permutation follow that, and nothing else
+    prefill = T >= 1024
+    tm = 256 if prefill else (32 if int8_rows else 16)
     F = cfg.moe_intermediate
+
+    def take(a, idx):   # every index below is a slot's or a row's own
+        return a.at[idx].get(mode="promise_in_bounds")
 
     def piece(args):
         x, local, weights = args
-        Tp = x.shape[0]
-        row_of_slot, tile_expert, used, _sizes, M = expert_layout(
-            local.reshape(-1), cfg.n_held, tm)
-        token_of_row = jnp.zeros((M,), jnp.int32).at[row_of_slot].set(
-            jnp.arange(Tp * k, dtype=jnp.int32) // k)
+        dtype = x.dtype
+        carry = [weights.reshape(-1).astype(jnp.float32)]
+        if int8_rows:
+            x, xs = _quantize_rows(x)
+            carry.append(jnp.repeat(xs[:, 0], k))
+        row_of_slot, slot_of_row, tile_expert, used, _sizes, _M, carried = \
+            expert_layout(local.reshape(-1), cfg.n_held, tm, carry)
+        # a row of padding takes the token of its tile's first row (a real
+        # one in every tile the product computes): nobody reads its product,
+        # but ``expert_combine`` may meet it beside a row it asked for
+        first = jnp.broadcast_to(slot_of_row.reshape(-1, tm)[:, :1],
+                                 (slot_of_row.shape[0] // tm, tm))
+        token_of_row = jnp.maximum(
+            jnp.where(slot_of_row < 0, first.reshape(-1), slot_of_row),
+            0) // k
+        if prefill:
+            rows = take(x, token_of_row)
+        else:
+            # a decode step builds ~15 rows of padding a real one: a product
+            # with a 0/1 matrix picks them (exactly) faster than a gather
+            rows = jax.lax.dot(
+                (token_of_row[:, None] == jnp.arange(x.shape[0])[None, :]
+                 ).astype(x.dtype), x,
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=(jnp.int32 if int8_rows
+                                        else jnp.float32)).astype(x.dtype)
+        factor = carried[0][:, None]
         call = dict(layer=slot, tile_expert=tile_expert, tiles_used=used,
                     tm=tm, interpret=interpret)
-        if int8_rows:
-            xq, xs = _quantize_rows(x)
-            rows, scale = xq[token_of_row], xs[token_of_row]
-        else:
-            rows, scale = x[token_of_row], None
         hidden = expert_grouped_matmul(
-            rows, scale, experts["we_gate"], experts["we_up"],
-            tn=_column_tile(D, F), act=cfg.act,
-            out_dtype=x.dtype, **call)
+            rows, carried[1][:, None] if int8_rows else None,
+            experts["we_gate"], experts["we_up"],
+            tn=_column_tile(D, F), act=cfg.act, out_dtype=dtype, **call)
         if int8_rows:
             hidden, scale = _quantize_rows(hidden)
+            factor = factor * scale
         y = expert_grouped_matmul(
-            hidden, scale, experts["we_down"], None, tn=_column_tile(F, D),
-            out_dtype=x.dtype, **call)
-        rows_of = row_of_slot.reshape(Tp, k)
-        out = jnp.zeros((Tp, D), jnp.float32)
-        for i in range(k):
-            # rows of tiles the kernel skipped are unspecified: select, do
-            # not multiply by a zero weight
-            out = out + jnp.where(
-                (local[:, i] >= 0)[:, None],
-                y[rows_of[:, i]].astype(jnp.float32)
-                * weights[:, i, None], 0.0)
-        return out.astype(x.dtype)
+            hidden, factor, experts["we_down"], None, tn=_column_tile(F, D),
+            out_dtype=dtype, **call)
+        if prefill:
+            # a tile of tokens fetches its rows a range an expert and sums
+            # them in VMEM; it reads no row of a tile the product skipped
+            # (the barrier keeps XLA from fusing the kernel into the map's
+            # stacking of its results, where it loses its VMEM limit)
+            return jax.lax.optimization_barrier(expert_combine(
+                y, local.reshape(-1), row_of_slot, n_experts=cfg.n_held,
+                k=k, interpret=interpret))
+        # a decode step's few rows: one gather, pick-major. Rows of tiles
+        # the product skipped are unspecified: select, never a product with
+        # a zero weight
+        picked = jnp.where(
+            (local.T >= 0)[:, :, None],
+            take(y, row_of_slot.reshape(-1, k).T).astype(jnp.float32), 0.0)
+        out = picked[0]
+        for j in range(1, k):
+            out = out + picked[j]
+        return out.astype(dtype)
 
     n = -(-T // _EXPERT_PIECE_TOKENS)
     if n == 1:
