@@ -45,12 +45,13 @@ import traceback
 from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
-PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe")
+PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe",
+          "laguna")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
-    "experts": 420, "moe": 420,
+    "experts": 420, "moe": 420, "laguna": 480,
 }
 
 
@@ -74,7 +75,11 @@ def sizes(rehearsal: bool) -> dict:
             experts_parity=(150, 256, 4),
             moe_layers=8, moe_seq=328, moe_batch=4, moe_max_new=8,
             moe_prefill_chunk=128, moe_prompt_bytes=250,
-            moe_parity=(150, 256, 4),
+            moe_parity=(150, 256, 4), moe_tolerance=0.05, moe_tie_band=0.1,
+            laguna_layers=9, laguna_seq=328, laguna_batch=4, laguna_max_new=8,
+            laguna_prefill_chunk=128, laguna_prompt_bytes=250,
+            laguna_parity=(150, 256, 4), laguna_tolerance=0.12,
+            laguna_tie_band=0.1,
         )
     return dict(
         kernel_geometries=None,  # derived from MODEL_REGISTRY
@@ -101,7 +106,15 @@ def sizes(rehearsal: bool) -> dict:
         # published widths; prompts past the 4096 window in the S=8192 bucket
         moe_layers=4, moe_seq=8256, moe_batch=4, moe_max_new=64,
         moe_prefill_chunk=2048, moe_prompt_bytes=6_000,
-        moe_parity=(4500, 8192, 4),
+        moe_parity=(4500, 8192, 4), moe_tolerance=0.05, moe_tie_band=0.1,
+        # laguna: the dense layer and one period of [sliding, sliding,
+        # sliding, full] at the published widths (10.7 GB of int8 weights);
+        # prompts three 512-windows long in the S=2048 bucket
+        laguna_layers=5, laguna_seq=2304, laguna_batch=4, laguna_max_new=32,
+        laguna_prefill_chunk=1024, laguna_prompt_bytes=1_900,
+        # the cell's own limits (benchmarks/configs/laguna-s-2.1-l5-int8.json)
+        laguna_parity=(1500, 2048, 4), laguna_tolerance=0.115,
+        laguna_tie_band=0.3,
     )
 
 
@@ -737,45 +750,38 @@ def phase_experts(args) -> dict:
     return rep
 
 
-def phase_moe(args) -> dict:
-    """The SmallThinker family on the one-shot path: GQA at 28/4 heads with
-    rotary 4096-window layers and position-free global layers, 64 ReGLU
-    experts all held, routed on the layer's input — at the published widths
-    with one period of four layers, int8, through ``TpuBackend.generate``
-    with prompts longer than the window; its counters; and its logits
-    against the plain reference (prefill and decode steps)."""
+def _windowed_expert_family(args, key: str, cfg, expert_layers: int,
+                            reference, sizes_ref: dict) -> dict:
+    """What the phases ``moe`` and ``laguna`` share: a family with GQA
+    window layers and every expert held, at the published widths and int8,
+    through ``TpuBackend.generate`` with prompts longer than the window; its
+    counters; and its logits against its plain reference (prefill and
+    decode steps). ``key`` prefixes the phase's entries of ``sizes``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks import reference_smallthinker as reference
-    from benchmarks.engine_setup_smallthinker import sizes_from
     from vnsum_tpu.backend.engine import TpuBackend
     from vnsum_tpu.core.config import GenerationConfig
     from vnsum_tpu.models import jitted_init
     from vnsum_tpu.models.quant import init_params_quantized
-    from vnsum_tpu.models.smallthinker import (
-        smallthinker_21b_a3b,
-        tiny_smallthinker,
-    )
 
-    sz = sizes(args.rehearsal)
+    sz = {k[len(key) + 1:]: v for k, v in sizes(args.rehearsal).items()
+          if k.startswith(key + "_")}
     c = Checks()
-    make = tiny_smallthinker if args.rehearsal else smallthinker_21b_a3b
-    cfg = make(n_layers=sz["moe_layers"], max_seq_len=sz["moe_seq"])
     params = jitted_init(init_params_quantized, cfg, 3)
     backend = TpuBackend(
         model_config=cfg, tokenizer="byte", params=params,
-        batch_size=sz["moe_batch"], max_new_tokens=sz["moe_max_new"],
+        batch_size=sz["batch"], max_new_tokens=sz["max_new"],
         quantize=True, quantize_act=True, quantize_kv=True,
-        prefill_chunk_tokens=sz["moe_prefill_chunk"],
+        prefill_chunk_tokens=sz["prefill_chunk"],
         generation=GenerationConfig(temperature=1.0, seed=3),
         interpret=args.rehearsal)
-    prompts = [_vn_text(sz["moe_prompt_bytes"] - 300 * i // 4, f"m{i}")
-               for i in range(sz["moe_batch"])]
+    prompts = [_vn_text(sz["prompt_bytes"] - 300 * i // 4, f"{key[0]}{i}")
+               for i in range(sz["batch"])]
     _outs, first_s, second_s = _generate_twice(backend, prompts, c)
     st = backend.stats
-    k, layers = cfg.num_experts_per_tok, cfg.n_layers
+    k, layers = cfg.num_experts_per_tok, expert_layers
     c.check("slots routed cover the prompts' tokens",
             st.expert_slots_routed >= st.prompt_tokens * k * layers,
             (st.expert_slots_routed, st.prompt_tokens * k * layers))
@@ -789,33 +795,36 @@ def phase_moe(args) -> dict:
     steps = st.expert_decode_layer_steps
     per_step = st.expert_decode_touched / max(steps, 1)
     c.check("a decode step touches between k and rows x k experts a layer",
-            steps == 2 * layers * sz["moe_max_new"]
-            and k <= per_step <= min(cfg.n_held, sz["moe_batch"] * k),
+            steps == 2 * layers * sz["max_new"]
+            and k <= per_step <= min(cfg.n_held, sz["batch"] * k),
             (steps, per_step))
     blocks = st.prefill_blocks
-    c.check("prompts leave the window: window layers skip cells below it",
+    c.check("prompts leave the window: window layers skip cells below it "
+            "and compute more scores than the window needs",
             # (the rehearsal's window is narrower than one grid cell)
             (args.rehearsal or blocks.get("dead_causal", 0) > 0)
-            and blocks.get("edge", 0) > 0, blocks)
+            and blocks.get("edge", 0) > 0
+            and blocks.get("window_scores_computed", 0)
+            > blocks.get("window_scores_needed", 0) > 0, blocks)
 
-    n, bucket, n_steps = sz["moe_parity"]
+    n, bucket, n_steps = sz["parity"]
     ids = backend.tok.encode(_vn_text((n + n_steps) * 3, "parity"))[:n + n_steps]
     got, state = backend.prefill_then_decode_logits(
         ids[:n], ids[n:], bucket=bucket, return_state=True)
     got = np.asarray(got, np.float64)
-    sizes_ref = sizes_from(cfg)
     # the routers' picks of the scored positions: the reference takes them
     # where they are the top-k of its own logits inside a tie band
     picks = jnp.asarray(state["rows"][:, :, 0].swapaxes(0, 1))
     want_l = np.asarray(jax.jit(lambda p, t, theirs: reference.forward(
         p, t, sizes_ref, last=n_steps + 1, theirs=theirs,
-        tie_band=0.1)["logits"])(
+        tie_band=sz["tie_band"])["logits"])(
         backend.params, jnp.asarray(ids, jnp.int32), picks), np.float64)
     errors = (np.linalg.norm(got - want_l, axis=-1)
               / np.linalg.norm(want_l, axis=-1))
-    c.check("logits within 0.05 of the plain reference, prefill and decode",
-            bool(np.all(np.isfinite(errors)) and errors.max() <= 0.05),
-            errors.tolist())
+    c.check(f"logits within {sz['tolerance']} of the plain reference, "
+            "prefill and decode",
+            bool(np.all(np.isfinite(errors))
+                 and errors.max() <= sz["tolerance"]), errors.tolist())
     rep = c.report()
     rep.update(first_call_s=round(first_s, 2),
                second_call_s=round(second_s, 2),
@@ -824,6 +833,42 @@ def phase_moe(args) -> dict:
                prefill_blocks=blocks, expert_tokens=tokens.tolist(),
                engine=backend.describe())
     return rep
+
+
+def phase_moe(args) -> dict:
+    """The SmallThinker family on the one-shot path: GQA at 28/4 heads with
+    rotary 4096-window layers and position-free global layers, 64 ReGLU
+    experts all held, routed on the layer's input — one period of four
+    layers (``_windowed_expert_family``)."""
+    from benchmarks import reference_smallthinker as reference
+    from benchmarks.engine_setup_smallthinker import sizes_from
+    from vnsum_tpu.models.smallthinker import (
+        smallthinker_21b_a3b,
+        tiny_smallthinker,
+    )
+
+    sz = sizes(args.rehearsal)
+    make = tiny_smallthinker if args.rehearsal else smallthinker_21b_a3b
+    cfg = make(n_layers=sz["moe_layers"], max_seq_len=sz["moe_seq"])
+    return _windowed_expert_family(args, "moe", cfg, cfg.n_layers, reference,
+                                   sizes_from(cfg))
+
+
+def phase_laguna(args) -> dict:
+    """The Laguna family on the one-shot path: GQA at 48/8 heads on full
+    layers and 72/8 in a 512 window (both kernels at G = 6 and 9 in one
+    program), the per-head gate, YaRN partial and plain rotary, the leading
+    dense layer, 256 SwiGLU experts top-10 and the shared one all held — the
+    dense layer and one period of four (``_windowed_expert_family``)."""
+    from benchmarks import reference_laguna as reference
+    from benchmarks.engine_setup_laguna import sizes_from
+    from vnsum_tpu.models.laguna import laguna_s_2_1, tiny_laguna
+
+    sz = sizes(args.rehearsal)
+    make = tiny_laguna if args.rehearsal else laguna_s_2_1
+    cfg = make(n_layers=sz["laguna_layers"], max_seq_len=sz["laguna_seq"])
+    return _windowed_expert_family(args, "laguna", cfg, cfg.n_sparse_layers,
+                                   reference, sizes_from(cfg))
 
 
 def _rehearsal_server(argv: list[str]) -> int:
@@ -853,7 +898,8 @@ def _child(args) -> int:
         rep.update({"device": phase_device, "kernels": phase_kernels,
                     "offline": phase_offline, "mesh": phase_mesh,
                     "experts": phase_experts,
-                    "moe": phase_moe}[phase](args))
+                    "moe": phase_moe,
+                    "laguna": phase_laguna}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

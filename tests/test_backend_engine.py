@@ -510,7 +510,20 @@ def test_prefill_blocks_counts_what_each_dispatch_ran(kw):
     (_, pad_lens, B, S), = packed
     assert B == 4 and (pad_lens == S).sum() == 1   # one filler row
     chunk = kw.get("prefill_chunk_tokens") or S
-    want = dict.fromkeys(be.stats.prefill_blocks, 0)
+    got = dict(be.stats.prefill_blocks)
+    # beside the classes, where layers have a window: the scores those
+    # layers computed and the scores a window needs (tests/test_model_laguna)
+    scores = {k: got.pop(k) for k in list(got) if k.startswith("window_")}
+    assert set(scores) == ({"window_scores_computed", "window_scores_needed"}
+                           if window else set())
+    if window:
+        heads = cfg.n_heads * 2          # two window layers
+        assert scores["window_scores_needed"] == heads * sum(
+            min(i + 1 - int(p), window) for p in pad_lens
+            for i in range(int(p), S))
+        assert scores["window_scores_computed"] \
+            >= scores["window_scores_needed"]
+    want = dict.fromkeys(got, 0)
     for win, n_layers in ((0, 1), (window, 2)) if window else ((0, 3),):
         for lo in range(0, S, chunk):
             for name, n in prefill_block_classes(
@@ -518,7 +531,7 @@ def test_prefill_blocks_counts_what_each_dispatch_ran(kw):
                 cfg.q_per_kv, cfg.head_dim,
             ).items():
                 want[name] += n * n_layers
-    assert be.stats.prefill_blocks == want
+    assert got == want
     assert S > chunk or "prefill_chunk_tokens" not in kw
     # the filler row alone is a whole row of dead cells in every layer
     assert want["dead_pad"] >= sum(want.values()) // B

@@ -86,6 +86,7 @@ def test_absorbed_decode_kernel_compiles_over_the_576_wide_cache(one_chip):
 # (layers, experts held, hidden, expert width, gate, column tiles)
 _DEEPSEEK = (7, 40, 5120, 1536, "silu", (512, 1280))
 _SMALLTHINKER = (16, 64, 2560, 768, "relu", (768, 2560))
+_LAGUNA = (4, 256, 3072, 1024, "silu", (512, 1536))
 
 
 @pytest.mark.parametrize("widths,tm,tiles", [
@@ -93,6 +94,9 @@ _SMALLTHINKER = (16, 64, 2560, 768, "relu", (768, 2560))
     # a piece of 8,192 tokens x 6 picks over 64 held experts; a decode step
     # of 24 rows
     (_SMALLTHINKER, 256, 257), (_SMALLTHINKER, 32, 70),
+    # a piece of 8,192 tokens x 10 picks over 256 held experts; a decode
+    # step of 12 rows
+    (_LAGUNA, 256, 577), (_LAGUNA, 32, 260),
 ])
 def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
                                                       tiles):
@@ -124,22 +128,25 @@ def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
     assert (_column_tile(D, F), _column_tile(F, D)) == column_tiles
 
 
-@pytest.mark.parametrize("widths,tokens", [
-    (_DEEPSEEK, 128), (_SMALLTHINKER, 256)])
-def test_expert_combine_compiles_at_a_piece_of_the_cells(one_chip, widths,
-                                                         tokens):
-    """The rows' way back at a piece of 8,192 tokens x 6 picks: chunks of 16
-    rows by DMA into two row buffers that fit the scoped VMEM at both
-    widths (5,120 wide a token tile is 128, not 256), the picks summed by a
-    0/1 product."""
-    from vnsum_tpu.ops.expert_matmul import _combine_tile, expert_combine
+@pytest.mark.parametrize("widths,k,tokens,columns", [
+    (_DEEPSEEK, 6, 128, 5120), (_SMALLTHINKER, 6, 256, 2560),
+    (_LAGUNA, 10, 128, 1024)])
+def test_expert_combine_compiles_at_a_piece_of_the_cells(one_chip, widths, k,
+                                                         tokens, columns):
+    """The rows' way back at a piece of 8,192 tokens x k picks: chunks of 16
+    rows by DMA into two row buffers that fit the scoped VMEM at every
+    cell's widths (5,120 wide a token tile is 128, not 256; with 256
+    experts a tile may need 512 chunks before any pick, and a grid step
+    takes a third of the 3,072 columns), the picks summed by a 0/1
+    product."""
+    from vnsum_tpu.ops.expert_matmul import _combine_geometry, expert_combine
 
     _L, E, D, _F, _act, _tiles = widths
-    N = 8192 * 6
+    N = 8192 * k
     M = (N // 256 + E + 1) * 256
-    assert _combine_tile(6, E, D, 2) == tokens
+    assert _combine_geometry(k, E, D, 2) == (tokens, columns)
     c = _compiled(
-        lambda y, e, r: expert_combine(y, e, r, n_experts=E, k=6),
+        lambda y, e, r: expert_combine(y, e, r, n_experts=E, k=k),
         one_chip, ((M, D), BF16), ((N,), I32), ((N,), I32))
     assert "expert_combine" in c.as_text()
 
@@ -184,6 +191,37 @@ def test_gqa_prefill_kernel_compiles_at_wide_groups(one_chip, G, KV, hd,
         lambda q, cache, pads, win: flash_attention.flash_prefill_attention(
             q, cache, 1, pads, G, win, 6144),
         one_chip, ((2, 2048, G * KV, hd), BF16), cache, ((2,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("G,offset", [(6, 6144), (9, 0), (9, 6144)])
+def test_gqa_prefill_kernel_compiles_at_lagunas_two_groups(one_chip, G,
+                                                           offset):
+    """48/8 and 72/8 heads of 128 (groups of 6 and 9: looped two heads a
+    step, an odd head out at 9; (1024, 1024) tiles), a 2,048-query chunk
+    over the int8 cache of 8,448 slots under the 512 window: one program
+    holds both calls."""
+    from vnsum_tpu.ops import flash_attention
+
+    assert flash_attention._block_geometry(2048, 8448, G, 128) == (1024, 1024)
+    c = _compiled(
+        lambda q, cache, pads, win: flash_attention.flash_prefill_attention(
+            q, cache, 2, pads, G, win, offset),
+        one_chip, ((2, 2048, 8 * G, 128), BF16),
+        _int8_cache(5, 2, 8, 8448, 128), ((2,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("G", [6, 9])
+def test_gqa_decode_kernel_compiles_at_lagunas_two_groups(one_chip, G):
+    """6 and 9 query rows a KV head over the int8 cache, 12 rows."""
+    from vnsum_tpu.ops.decode_attention import flash_decode_attention
+
+    c = _compiled(
+        lambda q, cache, pads, win: flash_decode_attention(
+            q, cache, 3, pads, 8200, G, win),
+        one_chip, ((12, 1, 8 * G, 128), BF16),
+        _int8_cache(5, 12, 8, 8448, 128), ((12,), I32), ((), I32))
     assert "tpu_custom_call" in c.as_text()
 
 

@@ -24,7 +24,9 @@ the vnsum_serve_ttft_seconds anchor and the /debug/trace batch tracks.
 from __future__ import annotations
 
 import contextlib
+import gc
 import time
+from collections import Counter
 from dataclasses import dataclass, field
 from functools import partial
 
@@ -103,7 +105,11 @@ class EngineStats:
     # summed over layers, chunks and one-shot dispatches
     # (ops.flash_attention.prefill_block_classes): dead_causal and dead_pad
     # cells are neither fetched nor computed, interior cells run unmasked,
-    # edge cells masked — what the kernel skips, counted from the pads
+    # edge cells masked — what the kernel skips, counted from the pads.
+    # Beside the classes, where layers have a window:
+    # ``window_scores_computed`` (the cells window layers fetched x the
+    # tile's area x their query heads) and ``window_scores_needed`` (for
+    # each real query row min(row + 1 - pad, window) keys x those heads)
     prefill_blocks: dict = field(default_factory=dict)
     # which attention each built program got, keyed "program[B=..,S=..]" →
     # {"prefill"|"decode": "kernel"|"dense"}: a dense fallback (unaligned
@@ -350,6 +356,14 @@ class TpuBackend:
             dt = time.time() - t0
             self.stats.compile_seconds += dt
             logger.info("first call of %s took %.1fs", label, dt)
+            # Tracing and lowering a program leaves millions of long-lived
+            # objects in JAX's caches. A collection of the oldest generation
+            # walks them all, 0.3-0.4 s with four programs built, at some
+            # point of the steady state: the stall that made one run in
+            # three read 0.7% low (PERF.md section 6, PR 41). Collect what
+            # is garbage now and take the rest out of the collector's sight.
+            gc.collect()
+            gc.freeze()
             return out
 
         return call
@@ -817,20 +831,39 @@ class TpuBackend:
         if (not self._decode_settings(S, C)[0]
                 or not self.family.counts_prefill_blocks):
             return
-        from ..ops.flash_attention import prefill_block_classes
+        from ..ops.flash_attention import (
+            BLOCK_CLASSES,
+            prefill_block_class_grid,
+        )
 
         cfg = self.cfg
-        # {window: layers that run with it}
         windows = self.family.layer_windows(cfg) or (0,) * cfg.n_layers
-        layers = {w: windows.count(w) for w in sorted(set(windows))}
+        groups = (self.family.layer_groups(cfg)
+                  or (cfg.q_per_kv,) * cfg.n_layers)
         total = self.stats.prefill_blocks
-        for window, n_layers in layers.items():
+        pads = np.asarray(pad_lens, np.int64)
+        # {(window, query heads a KV head): layers of that kind}
+        for (window, group), n_layers in sorted(
+                Counter(zip(windows, groups)).items()):
+            computed = 0
             for lo, hi in self._prefill_spans(S, start):
-                for name, n in prefill_block_classes(
-                    pad_lens, hi - lo, C, lo, window, cfg.q_per_kv,
-                    cfg.head_dim,
-                ).items():
-                    total[name] = total.get(name, 0) + n * n_layers
+                grid, (bq, bk) = prefill_block_class_grid(
+                    pad_lens, hi - lo, C, lo, window, group, cfg.head_dim)
+                counts = np.bincount(grid.ravel(), minlength=len(BLOCK_CLASSES))
+                for name, n in zip(BLOCK_CLASSES, counts):
+                    total[name] = total.get(name, 0) + int(n) * n_layers
+                # interior and edge cells are fetched and computed whole
+                computed += int(counts[2] + counts[3]) * bq * bk
+            if window:
+                # what the kernel computed on window layers against what the
+                # window needs: a real query row at slot i sees
+                # min(i + 1 - pad, window) keys, every query head of it
+                seen = np.clip(
+                    np.arange(start, S)[None, :] + 1 - pads[:, None], 0, window)
+                heads = group * cfg.n_kv_heads * n_layers
+                for name, n in (("window_scores_computed", computed),
+                                ("window_scores_needed", int(seen.sum()))):
+                    total[name] = total.get(name, 0) + n * heads
 
     # -- constrained choice scoring --------------------------------------
 

@@ -47,6 +47,18 @@ def _tiny_smallthinker(**kw):
     return tiny_smallthinker(**kw)
 
 
+def _laguna(**kw):
+    from .laguna import laguna_s_2_1
+
+    return laguna_s_2_1(**kw)
+
+
+def _tiny_laguna(**kw):
+    from .laguna import tiny_laguna
+
+    return tiny_laguna(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -69,6 +81,11 @@ MODEL_REGISTRY = {
     # mixed, sparse ReGLU experts routed on the layer's input
     "smallthinker-21b-a3b": _smallthinker,
     "tiny-smallthinker": _tiny_smallthinker,
+    # a fourth (models/laguna.py): query heads that differ by layer kind, a
+    # per-head output gate, two rotary schemes, a leading dense layer, 256
+    # experts top-10 with a shared one
+    "laguna-s-2.1": _laguna,
+    "tiny-laguna": _tiny_laguna,
 }
 
 __all__ = [

@@ -63,6 +63,7 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from .experts import (  # noqa: F401  (dense_experts: a name tests take here)
     EXPERT_LEAVES,
     _EXPERT_PIECE_TOKENS,
+    by_rows,
     counters,
     dense_experts,
     expert_layer,
@@ -78,6 +79,7 @@ from .llama import (
     _proj,
     _rmsnorm,
 )
+from .llama import yarn_inv_freq as _yarn_inv_freq  # this module's takes cfg
 
 
 @dataclass(frozen=True)
@@ -180,26 +182,11 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def yarn_inv_freq(cfg: DeepseekV2Config) -> jax.Array:
-    """Inverse frequencies of the rotated ``qk_rope_head_dim``: interpolated
-    (divided by ``factor``) below the low correction dimension, extrapolated
-    (unchanged) above the high one, a linear ramp between."""
-    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
-    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
-    extra = 1.0 / (base ** exponent)
-    inter = extra / cfg.rope_factor
-
-    def correction_dim(rotations: float) -> float:
-        return (dim * math.log(cfg.rope_original_max_len
-                               / (rotations * 2 * math.pi))
-                / (2 * math.log(base)))
-
-    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
-    if low == high:
-        high += 0.001
-    ramp = jnp.clip(
-        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
-    return inter * ramp + extra * (1.0 - ramp)
+    """Inverse frequencies of the rotated ``qk_rope_head_dim``
+    (``models.llama.yarn_inv_freq`` at this config's numbers)."""
+    return _yarn_inv_freq(
+        cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
+        cfg.rope_original_max_len, cfg.rope_beta_fast, cfg.rope_beta_slow)
 
 
 def rope_cos_sin(cfg: DeepseekV2Config, positions: jax.Array):
@@ -501,19 +488,6 @@ def decode_attention(cfg: DeepseekV2Config, pad_lens, S: int, t, *,
 # -- the block and forward ----------------------------------------------------
 
 
-def _by_rows(fn, h, max_tokens: int):
-    """``fn(h)`` over h [B, S, D], a few batch rows at a time where the
-    whole is more than ``max_tokens`` tokens: a 12,288-wide SwiGLU over a
-    chunk of 24 rows would hold gigabytes of intermediates."""
-    B, S, _ = h.shape
-    R = max((r for r in range(1, B + 1)
-             if B % r == 0 and r * S <= max_tokens), default=1)
-    if R == B:
-        return fn(h)
-    out = jax.lax.map(fn, h.reshape((B // R, R) + h.shape[1:]))
-    return out.reshape((B,) + out.shape[2:])
-
-
 def _latent_rows(c_kv, k_rope, dtype):
     """What the cache keeps of a token: its normalised latent and its one
     rotated key, side by side."""
@@ -559,7 +533,7 @@ def _block(x, lp, layer_idx, slot, rope, attention: LatentAttention, valid,
                 return _proj("bsi,id->bsd", _mlp_act(gate, cfg.act) * up,
                              lp["w_down"], aq)
 
-        return x + _by_rows(mlp, h, _EXPERT_PIECE_TOKENS), cache
+        return x + by_rows(mlp, h, _EXPERT_PIECE_TOKENS), cache
     out, cache = _expert_ffn(h, lp, experts, slot, valid, cache, cfg, aq,
                              experts_fn)
     return x + out, cache

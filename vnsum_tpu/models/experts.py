@@ -95,6 +95,19 @@ def _column_tile(K: int, N: int) -> int:
 _EXPERT_PIECE_TOKENS = 8192
 
 
+def by_rows(fn, h, max_tokens: int):
+    """``fn(h)`` over h [B, S, D], a few batch rows at a time where the
+    whole is more than ``max_tokens`` tokens: a 12,288-wide SwiGLU over a
+    chunk of 24 rows would hold gigabytes of intermediates."""
+    B, S, _ = h.shape
+    R = max((r for r in range(1, B + 1)
+             if B % r == 0 and r * S <= max_tokens), default=1)
+    if R == B:
+        return fn(h)
+    out = jax.lax.map(fn, h.reshape((B // R, R) + h.shape[1:]))
+    return out.reshape((B,) + out.shape[2:])
+
+
 def grouped_experts(x, local, weights, experts, slot, cfg, *,
                     interpret: bool):
     """The routed experts held here, through ``expert_grouped_matmul``.

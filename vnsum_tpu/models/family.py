@@ -48,6 +48,9 @@ class Family:
     # attends globally), or None where no layer has a window: what the
     # kernels clamp their key range with, layer by layer
     layer_windows: Callable = lambda cfg: None
+    # cfg -> each layer's query heads a KV head where the layers differ in
+    # them, or None where ``cfg.q_per_kv`` holds for every layer
+    layer_groups: Callable = lambda cfg: None
     # (cfg, kernels on, interpret) -> further keywords of ``forward``
     forward_kwargs: Callable = lambda cfg, kernels, interpret: {}
     # final state -> {name: device array} returned with a program's output,

@@ -355,6 +355,33 @@ def _rope_inv_freq(cfg: LlamaConfig) -> jax.Array:
     return jnp.where(between, smooth, out)
 
 
+def yarn_inv_freq(dim: int, base: float, factor: float,
+                  original_max_len: int, beta_fast: float,
+                  beta_slow: float) -> jax.Array:
+    """YaRN inverse frequencies of ``dim`` rotated dims at ``base``:
+    interpolated (divided by ``factor``) below the low correction
+    dimension, extrapolated (unchanged) above the high one, a linear ramp
+    between. What the families with a YaRN table share
+    (``models/deepseek.py``, ``models/laguna.py``)."""
+    import math
+
+    exponent = jnp.arange(0, dim, 2, dtype=jnp.float32) / dim
+    extra = 1.0 / (base ** exponent)
+    inter = extra / factor
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(original_max_len / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
 def _rope_cos_sin(cfg: LlamaConfig, positions: jax.Array):
     """positions [B, S] -> cos/sin [B, S, hd/2] (float32)."""
     pos = positions[..., None].astype(jnp.float32)
@@ -813,6 +840,13 @@ def _attention_supported(cfg: LlamaConfig, S: int, C: int):
     return supports_flash(S, C, cfg.head_dim), supports_decode(C, cfg.head_dim)
 
 
+def _group_of(q, cache: dict) -> int:
+    """Query heads a KV head, off the operands: q [B, S, H, hd] over the
+    stacked cache [L, B, KV, C, hd]. A family whose layers differ in their
+    query heads (models/laguna.py) hands each layer's own queries in."""
+    return q.shape[2] // cache["k"].shape[2]
+
+
 def _prefill_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
                        layer_window, q_offset: int = 0):
     """Flash/sharded-flash stacked-attention fn for a prefill-style forward
@@ -823,7 +857,7 @@ def _prefill_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
 
         def stacked_fn(q, cache, layer_idx):
             return sharded_flash_prefill(
-                mesh, q, cache, layer_idx, pad_lens, cfg.q_per_kv,
+                mesh, q, cache, layer_idx, pad_lens, _group_of(q, cache),
                 layer_window(layer_idx), q_offset, interpret=interpret,
             )
     else:
@@ -831,7 +865,7 @@ def _prefill_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
 
         def stacked_fn(q, cache, layer_idx):
             return flash_prefill_attention(
-                q, cache, layer_idx, pad_lens, cfg.q_per_kv,
+                q, cache, layer_idx, pad_lens, _group_of(q, cache),
                 layer_window(layer_idx), q_offset, interpret=interpret,
             )
 
@@ -849,7 +883,7 @@ def _decode_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
         def stacked_fn(q, cache, layer_idx):
             return sharded_flash_decode(
                 mesh, q, cache, layer_idx, pad_lens, S + t,
-                cfg.q_per_kv, layer_window(layer_idx),
+                _group_of(q, cache), layer_window(layer_idx),
                 interpret=interpret,
             )
     else:
@@ -858,7 +892,7 @@ def _decode_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
         def stacked_fn(q, cache, layer_idx):
             return flash_decode_attention(
                 q, cache, layer_idx, pad_lens, S + t,
-                cfg.q_per_kv, layer_window(layer_idx),
+                _group_of(q, cache), layer_window(layer_idx),
                 interpret=interpret,
             )
 

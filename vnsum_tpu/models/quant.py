@@ -49,8 +49,10 @@ _ALL_CONTRACT_AXES = {
     "we_gate": (1,), "we_up": (1,), "we_down": (1,),
 }
 # the groups of stacked layers a params tree may hold: every family has
-# "layers"; one with leading dense layers keeps them under "dense"
-_LAYER_GROUPS = ("layers", "dense")
+# "layers"; one with leading dense layers keeps them under "dense"; one
+# whose attention differs in shape by layer kind keeps each kind's under
+# "full" and "sliding" (models/laguna.py)
+_LAYER_GROUPS = ("layers", "dense", "full", "sliding")
 
 
 def _quantize(w: jax.Array, contract_axes: tuple[int, ...]) -> dict:

@@ -244,12 +244,24 @@ def _combine_rows(tt: int, k: int, n_experts: int) -> int:
     return -(-rows // _COMBINE_BLOCK) * _COMBINE_BLOCK
 
 
-def _combine_tile(k: int, n_experts: int, D: int, itemsize: int) -> int:
-    """Tokens a grid step sums: as many as keep both row buffers in VMEM."""
-    for tt in (256, 128, 64, 32):
-        if 2 * _combine_rows(tt, k, n_experts) * D * itemsize <= _COMBINE_VMEM:
-            return tt
-    return 16
+def _combine_geometry(k: int, n_experts: int, D: int,
+                      itemsize: int) -> tuple[int, int]:
+    """(tokens a grid step sums, columns of the rows it fetches): as many
+    tokens as keep both row buffers in VMEM at the rows' whole width; where
+    no tile does — many experts: a tile may need two chunks for each of
+    them, 256 experts 3,072 wide are 50 MB a buffer before any pick —
+    the widest whole-lane share of the columns at which one does, and a
+    grid step sums one such share of a token tile."""
+    for columns in range(1, max(D // 128, 1) + 1):
+        if columns > 1 and D % (columns * 128):
+            continue
+        for tt in (256, 128, 64, 32):
+            if (2 * _combine_rows(tt, k, n_experts) * (D // columns)
+                    * itemsize <= _COMBINE_VMEM):
+                return tt, D // columns
+    raise ValueError(
+        f"expert_combine: no tile of tokens fits {_COMBINE_VMEM} bytes of "
+        f"VMEM at k={k}, {n_experts} experts, {D} columns")
 
 
 def _combine_plan(expert_of_slot, row_of_slot, n_experts: int, k: int,
@@ -288,22 +300,35 @@ def _combine_plan(expert_of_slot, row_of_slot, n_experts: int, k: int,
 
 
 def _combine_kernel(chunk_row_ref, n_chunks_ref, pos_ref, y_ref, o_ref,
-                    buf, acc, sem, *, C: int, k: int, exact: bool):
+                    buf, acc, sem, *, C: int, k: int, exact: bool,
+                    columns: int):
+    # grid step i sums column share i % columns of token tile i // columns
+    # (one share: the whole rows of tile i)
     i = pl.program_id(0)
-    tt = o_ref.shape[0]
+    tt, dc = o_ref.shape
 
-    def chunk(tile, half, c):
-        r = pl.multiple_of(chunk_row_ref[tile * C + c], _CHUNK)
+    def tile_of(step):
+        return step // columns if columns > 1 else step
+
+    def chunk(step, half, c):
+        r = pl.multiple_of(chunk_row_ref[tile_of(step) * C + c], _CHUNK)
+        rows = y_ref.at[pl.ds(r, _CHUNK)]
+        if columns > 1:
+            rows = y_ref.at[pl.ds(r, _CHUNK), pl.ds(
+                pl.multiple_of(step % columns * dc, 128), dc)]
         return pltpu.make_async_copy(
-            y_ref.at[pl.ds(r, _CHUNK)],
+            rows,
             buf.at[half, pl.ds(pl.multiple_of(c * _CHUNK, _CHUNK), _CHUNK)],
             sem.at[half])
 
-    def fetch(tile, half):
+    def chunks_of(step):
+        return n_chunks_ref[tile_of(step)]
+
+    def fetch(step, half):
         def start(c, carry):
-            chunk(tile, half, c).start()
+            chunk(step, half, c).start()
             return carry
-        jax.lax.fori_loop(0, n_chunks_ref[tile], start, 0)
+        jax.lax.fori_loop(0, chunks_of(step), start, 0)
 
     @pl.when(i == 0)
     def _first():
@@ -321,7 +346,7 @@ def _combine_kernel(chunk_row_ref, n_chunks_ref, pos_ref, y_ref, o_ref,
     def wait(c, carry):
         chunk(i, half, c).wait()
         return carry
-    jax.lax.fori_loop(0, n_chunks_ref[i], wait, 0)
+    jax.lax.fori_loop(0, chunks_of(i), wait, 0)
 
     acc[...] = jnp.zeros_like(acc)
     B = _COMBINE_BLOCK
@@ -337,7 +362,7 @@ def _combine_kernel(chunk_row_ref, n_chunks_ref, pos_ref, y_ref, o_ref,
             preferred_element_type=jnp.float32,
             precision=jax.lax.Precision.HIGHEST if exact else None)
         return carry
-    jax.lax.fori_loop(0, -(-n_chunks_ref[i] * _CHUNK // B), block, 0)
+    jax.lax.fori_loop(0, -(-chunks_of(i) * _CHUNK // B), block, 0)
     o_ref[...] = acc[...].astype(o_ref.dtype)
 
 
@@ -354,7 +379,8 @@ def expert_combine(y, expert_of_slot, row_of_slot, *, n_experts: int, k: int,
     Rows outside the chunks that hold a pick are never read."""
     D = y.shape[1]
     T = expert_of_slot.shape[0] // k
-    tt = _combine_tile(k, n_experts, D, y.dtype.itemsize)
+    tt, dc = _combine_geometry(k, n_experts, D, y.dtype.itemsize)
+    columns = D // dc
     pad = -T % tt
     if pad:
         expert_of_slot = jnp.pad(expert_of_slot, (0, pad * k),
@@ -364,17 +390,22 @@ def expert_combine(y, expert_of_slot, row_of_slot, *, n_experts: int, k: int,
         expert_of_slot, row_of_slot, n_experts, k, tt)
     R = _combine_rows(tt, k, n_experts)
     kernel = functools.partial(_combine_kernel, C=C, k=k,
-                               exact=y.dtype == jnp.float32)
+                               exact=y.dtype == jnp.float32, columns=columns)
+    if columns == 1:
+        pick_block = out_block = lambda i, cr, nc: (i, 0)   # noqa: E731
+    else:
+        pick_block = lambda i, cr, nc: (i // columns, 0)    # noqa: E731
+        out_block = lambda i, cr, nc: (i // columns, i % columns)  # noqa: E731
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=((T + pad) // tt,),
-            in_specs=[pl.BlockSpec((tt, k), lambda i, cr, nc: (i, 0)),
+            grid=((T + pad) // tt * columns,),
+            in_specs=[pl.BlockSpec((tt, k), pick_block),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((tt, D), lambda i, cr, nc: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((2, R, D), y.dtype),
-                            pltpu.VMEM((tt, D), jnp.float32),
+            out_specs=pl.BlockSpec((tt, dc), out_block),
+            scratch_shapes=[pltpu.VMEM((2, R, dc), y.dtype),
+                            pltpu.VMEM((tt, dc), jnp.float32),
                             pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=jax.ShapeDtypeStruct((T + pad, D), y.dtype),
