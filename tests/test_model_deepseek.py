@@ -5,6 +5,7 @@ stacked experts, and the entries that refuse the family by name."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -297,17 +298,42 @@ def _layout_case(name):
         "random_mostly_not_held": (
             np.where(rng.random(500) < 0.76, -1,
                      rng.integers(0, 40, 500)).tolist(), 40, 16),
+        **_DECODE_STEPS,
     }[name]
+
+
+def _decode_steps():
+    """A decode step's slots in the three cells' proportions, in small:
+    Laguna's 120 slots on 256 experts, SmallThinker's 144 on 64,
+    DeepSeek-V2's 144 of which a quarter is held among 40, and the two
+    ends: every slot on one expert, no slot held."""
+    rng = np.random.default_rng(42)
+    return {
+        "step_more_experts_than_slots": (
+            rng.integers(0, 64, 30).tolist(), 64, 32),
+        "step_fewer_experts_than_slots": (
+            rng.integers(0, 16, 72).tolist(), 16, 16),
+        "step_mostly_not_held": (
+            np.where(rng.random(72) < 0.76, -1,
+                     rng.integers(0, 10, 72)).tolist(), 10, 32),
+        "step_every_slot_on_one_expert": ([5] * 30, 64, 16),
+        "step_no_slot_held": ([-1] * 24, 64, 32),
+    }
+
+
+_DECODE_STEPS = _decode_steps()
 
 
 @pytest.mark.parametrize("case", [
     "mixed", "all_on_one_expert", "no_slot_held", "group_ends_on_a_tile_edge",
-    "one_slot", "random_wide", "random_mostly_not_held"])
+    "one_slot", "random_wide", "random_mostly_not_held", *_DECODE_STEPS])
 def test_expert_layout_gives_every_slot_a_row_of_its_expert(case):
     """The layout's contract: every held slot a row of its own in a tile of
     its expert, in the slots' order inside a group; groups padded to whole
     tiles from a tile's edge; a spare row for the slots not held; each row
-    names its slot, padding names none; static M at the worst case."""
+    names its slot, padding names none; static M at the worst case, which
+    follows the slots: a used tile holds one, so no more tiles than slots
+    and no more than the whole tiles plus one an expert."""
     from vnsum_tpu.ops.expert_matmul import expert_layout
 
     slots, E, tm = _layout_case(case)
@@ -318,12 +344,12 @@ def test_expert_layout_gives_every_slot_a_row_of_its_expert(case):
     row, slot_of_row, tile_expert, sizes = (
         np.asarray(a) for a in (row, slot_of_row, tile_expert, sizes))
     N = len(slots)
-    assert M == (-(-N // tm) + E + 1) * tm == len(slot_of_row)
+    assert M == (min(N, N // tm + E) + 1) * tm == len(slot_of_row)
     assert len(tile_expert) == M // tm
     want_sizes = [int((slots == e).sum()) for e in range(E)]
     assert sizes.tolist() == want_sizes
     n_used = sum(-(-s // tm) for s in want_sizes)
-    assert int(used[0]) == n_used
+    assert int(used[0]) == n_used <= M // tm - 1     # the spare tile is spare
     held = slots >= 0
     assert len(set(row[held])) == held.sum()          # no two slots share a row
     for r, e in zip(row[held], slots[held]):
@@ -344,6 +370,150 @@ def test_expert_layout_gives_every_slot_a_row_of_its_expert(case):
         slot_of_row >= 0, carry[np.maximum(slot_of_row, 0)], 0)).all()
     if case == "mixed":
         assert want_sizes == [2, 1, 0, 4] and n_used == 1 + 1 + 0 + 2
+
+
+def _static_grid_product(lhs, lhs_scale, w, w_up, layer, tile_expert,
+                         tiles_used, *, tm, tn, out_dtype, act="silu"):
+    """The product as it was before its grid followed the slots, kept as the
+    plain reference: the kernel's own body on EVERY row tile of the layout,
+    a tile past ``tiles_used`` skipped by ``pl.when`` with its block index
+    repeated. The grid step is still taken."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from vnsum_tpu.ops import expert_matmul as em
+
+    M, K = lhs.shape
+    quantized = isinstance(w, dict)
+    N = (w["q"] if quantized else w).shape[-1]
+    body = functools.partial(
+        em._kernel, quantized=quantized, int8_lhs=lhs.dtype == jnp.int8,
+        scaled=lhs_scale is not None, gated=w_up is not None, act=act)
+
+    def kernel(layer_ref, te_ref, used_ref, *refs):
+        @pl.when(pl.program_id(1) < used_ref[0])
+        def _compute():
+            body(layer_ref, te_ref, *refs)
+
+    def tile(m, used):
+        return jnp.minimum(m, jnp.maximum(used[0] - 1, 0))
+
+    row = lambda n, m, layer, te, used: (tile(m, used), 0)  # noqa: E731
+    weight = lambda n, m, layer, te, used: (  # noqa: E731
+        layer[0], te[tile(m, used)], 0, n)
+    in_specs, operands = [pl.BlockSpec((tm, K), row)], [lhs]
+    if lhs_scale is not None:
+        in_specs.append(pl.BlockSpec((tm, 1), row))
+        operands.append(lhs_scale)
+    for leaf in (w, w_up) if w_up is not None else (w,):
+        in_specs.append(pl.BlockSpec((1, 1, K, tn), weight))
+        operands.append(leaf["q"] if quantized else leaf)
+        if quantized:
+            in_specs.append(pl.BlockSpec((1, 1, 1, tn), weight))
+            operands.append(leaf["s"][:, :, None, :])
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(N // tn, M // tm),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec(
+                (tm, tn), lambda n, m, layer, te, used: (tile(m, used), n))),
+        out_shape=jax.ShapeDtypeStruct((M, N), out_dtype), interpret=True,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tile_expert, tiles_used,
+      *operands)
+
+
+def _product_operands(case, rows, gated):
+    """One product's operands over a decode step's layout: K = 64 in, two
+    column tiles of 128 out, a stack of 2 layers."""
+    from vnsum_tpu.ops.expert_matmul import expert_layout
+
+    slots, E, _ = _layout_case(case)
+    tm = 32 if rows == "int8" else 16
+    _, _, tile_expert, used, _, M, _ = expert_layout(
+        jnp.asarray(slots, jnp.int32), E, tm)
+    ks = jax.random.split(jax.random.key(len(slots) + E), 6)
+    K, N = 64, 256
+
+    def leaf(key):
+        if rows == "int8":
+            return {"q": jax.random.randint(key, (2, E, K, N), -127, 128,
+                                            jnp.int8),
+                    "s": jax.random.uniform(key, (2, E, N), jnp.float32,
+                                            1e-3, 2e-3)}
+        return (jax.random.normal(key, (2, E, K, N)) * 0.1).astype(
+            jnp.bfloat16)
+
+    if rows == "int8":
+        lhs = jax.random.randint(ks[0], (M, K), -127, 128, jnp.int8)
+    else:
+        lhs = jax.random.normal(ks[0], (M, K)).astype(jnp.bfloat16)
+    scale = jax.random.uniform(ks[1], (M, 1), jnp.float32, 0.01, 0.02)
+    args = (lhs, scale, leaf(ks[2]), leaf(ks[3]) if gated else None, 1,
+            tile_expert, used)
+    return args, dict(tm=tm, tn=128, out_dtype=jnp.bfloat16, act="silu"), \
+        (len(slots), E, tm, int(used[0]), M)
+
+
+@pytest.mark.parametrize("gated", [False, True])
+@pytest.mark.parametrize("rows", ["int8", "bf16"])
+@pytest.mark.parametrize("case", list(_DECODE_STEPS))
+def test_the_product_on_the_used_tiles_is_the_static_grids_bit_for_bit(
+        case, rows, gated):
+    """A grid that walks only the tiles that hold a slot gives the rows of
+    those tiles what the grid over every tile gave them, to the bit: the
+    same products, scales and order of sums, int8 and bf16 rows, gated and
+    not. With no slot held there is no grid step and nothing to compare."""
+    from vnsum_tpu.ops.expert_matmul import expert_grouped_matmul
+
+    args, kw, (N, E, tm, used, M) = _product_operands(case, rows, gated)
+    got = expert_grouped_matmul(*args, interpret=True, **kw)
+    want = _static_grid_product(*args, **kw)
+    assert got.shape == want.shape == (M, 256) and got.dtype == want.dtype
+    live = used * tm
+    assert np.array_equal(np.asarray(got[:live].astype(jnp.float32)),
+                          np.asarray(want[:live].astype(jnp.float32)))
+    if used:
+        assert float(jnp.abs(got[:live].astype(jnp.float32)).max()) > 0
+    if case == "step_more_experts_than_slots":
+        # what the static grid walked, and what part of it was live: the
+        # worst-case tiles of every expert for a few dozen slots
+        walked = -(-N // tm) + E + 1
+        assert used / walked < 0.4 and M // tm == N + 1 < walked
+
+
+@pytest.mark.parametrize("case", ["step_more_experts_than_slots",
+                                  "step_no_slot_held"])
+def test_the_products_grid_is_bounded_by_the_tiles_used(case):
+    """The row dimension of the grid is ``tiles_used`` itself, a bound read
+    when the kernel starts: walked = used, whatever the layout's static
+    worst case, and a step with no slot held takes no grid step at all (it
+    runs, reads nothing, and nobody reads its output)."""
+    from vnsum_tpu.ops.expert_matmul import expert_grouped_matmul
+
+    args, kw, (_, _, _, used, M) = _product_operands(case, "int8", True)
+    jaxpr = jax.make_jaxpr(lambda *a: expert_grouped_matmul(
+        *a, args[3], 1, *args[5:], interpret=True, **kw))(*args[:3])
+
+    def eqns(j):
+        for e in j.eqns:
+            yield e
+            for v in e.params.values():
+                if hasattr(getattr(v, "jaxpr", v), "eqns"):
+                    yield from eqns(getattr(v, "jaxpr", v))
+
+    (call,) = [e for e in eqns(jaxpr.jaxpr)
+               if e.primitive.name == "pallas_call"]
+    mapping = call.params["grid_mapping"]
+    assert mapping.num_dynamic_grid_bounds == 1
+    assert [d for d in mapping.grid if isinstance(d, int)] == [2]
+    out = expert_grouped_matmul(*args, interpret=True, **kw)
+    assert out.shape == (M, 256)
+    if not used:
+        poisoned = (jnp.full_like(args[0], 127), args[1],
+                    *args[2:5], jnp.full_like(args[5], 10**6), args[6])
+        assert expert_grouped_matmul(
+            *poisoned, interpret=True, **kw).shape == (M, 256)
 
 
 # -- the program through the engine ------------------------------------------

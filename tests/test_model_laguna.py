@@ -4,6 +4,8 @@ heads a KV head, 16 experts top-4 with a shared one, a window shorter than
 the prompts."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -283,7 +285,7 @@ def test_counters_count_every_real_token_and_pick_of_the_sparse_layers(tiny):
     assert int(cache["decode_touched"]) == int(cache["decode_layer_steps"]) == 0
     assert set(lg.counters(cache)) == {
         "expert_tokens", "slots_routed", "slots_held", "decode_touched",
-        "decode_layer_steps"}
+        "decode_layer_steps", "decode_tiles_used", "decode_tiles_walked"}
 
 
 def test_decode_steps_count_the_distinct_experts_they_touch(tiny):
@@ -302,6 +304,35 @@ def test_decode_steps_count_the_distinct_experts_they_touch(tiny):
         touched += int((step > 0).sum())
     assert int(cache["decode_touched"]) == touched
     assert int(cache["decode_layer_steps"]) == 4 * cfg.n_sparse_layers
+    # the dense path runs no grouped product: no tiles
+    assert int(cache["decode_tiles_used"]) == 0
+
+
+def test_decode_steps_count_the_tiles_their_slots_fill(tiny):
+    """A step's 48 rows x 4 picks on 16 experts fill 12 + 4 tiles of 16 or
+    so a layer; the grouped product's grid walks exactly those (the count
+    recomputed here from the step's picks), not the 16 + 12 + 1 of the
+    layout's worst case."""
+    from vnsum_tpu.models import experts
+
+    cfg, params = tiny
+    B, C, tm = 48, 16, 16
+    cache, tiles = lg.init_cache(cfg, B, C), 0
+    toks = _tokens(n=2, rows=B)
+    grouped = functools.partial(experts.grouped_experts, cfg=cfg,
+                                interpret=True)
+    for t in range(2):
+        mask = jnp.broadcast_to(jnp.arange(C)[None, None] <= t, (B, 1, C))
+        before = np.asarray(cache["expert_tokens"])
+        _, cache = lg.forward(params, cfg, toks[:, t:t + 1],
+                              jnp.full((B, 1), t), cache, t, mask,
+                              experts_fn=grouped)
+        step = np.asarray(cache["expert_tokens"]) - before
+        tiles += int((-(-step // tm)).sum())
+    assert int(cache["decode_tiles_used"]) == tiles \
+        == int(cache["decode_tiles_walked"])
+    assert int(cache["decode_touched"]) < tiles \
+        < 2 * cfg.n_sparse_layers * (B * 4 // tm + 16 + 1)
 
 
 # -- the engine's seam ------------------------------------------------------------
@@ -410,6 +441,8 @@ def test_engine_generates_and_counts_its_cells_by_layer_kind(tiny):
     assert np.asarray(st_.expert_tokens).shape == (8, 16)
     assert st_.expert_decode_layer_steps == 6 * 8
     assert 6 * 8 * 4 <= st_.expert_decode_touched <= 6 * 8 * 8
+    assert st_.expert_decode_tiles_used == st_.expert_decode_tiles_walked \
+        == st_.expert_decode_touched     # 8 slots a step: a tile an expert
     (_, pad_lens, B, S), = packed
     C = S + 6
     want = dict.fromkeys(("dead_causal", "dead_pad", "interior", "edge"), 0)

@@ -5,6 +5,8 @@ shorter than the prompts."""
 from __future__ import annotations
 
 import dataclasses
+import functools
+import logging
 
 import jax
 import jax.numpy as jnp
@@ -198,7 +200,7 @@ def test_counters_count_every_real_token_and_pick(tiny):
     assert int(cache["decode_touched"]) == int(cache["decode_layer_steps"]) == 0
     assert set(st.counters(cache)) == {
         "expert_tokens", "slots_routed", "slots_held", "decode_touched",
-        "decode_layer_steps"}
+        "decode_layer_steps", "decode_tiles_used", "decode_tiles_walked"}
 
 
 def test_decode_steps_count_the_distinct_experts_they_touch(tiny):
@@ -219,6 +221,34 @@ def test_decode_steps_count_the_distinct_experts_they_touch(tiny):
     assert int(cache["decode_layer_steps"]) == 4 * cfg.n_layers
     k = cfg.num_experts_per_tok
     assert 4 * cfg.n_layers * k <= touched <= 4 * cfg.n_layers * min(8, B * k)
+
+
+def test_decode_steps_count_the_tiles_their_slots_fill(tiny):
+    """Beside the experts touched: the row tiles of the grouped product that
+    held a slot, recomputed here from each step's picks, and the tiles its
+    grid walked — the same number, since the grid's bound is the tiles
+    used. 40 rows x 2 picks on 8 experts: some expert fills a second tile
+    of 16. With no grouped product (the dense path) there are no tiles."""
+    cfg, params = tiny
+    B, C, tm = 40, 16, 16
+    toks = _tokens(n=3, rows=B)
+    grouped = functools.partial(experts.grouped_experts, cfg=cfg,
+                                interpret=True)
+    for experts_fn in (grouped, None):
+        cache, tiles, touched = st.init_cache(cfg, B, C), 0, 0
+        for t in range(3):
+            mask = jnp.broadcast_to(jnp.arange(C)[None, None] <= t, (B, 1, C))
+            before = np.asarray(cache["expert_tokens"])
+            _, cache = st.forward(params, cfg, toks[:, t:t + 1],
+                                  jnp.full((B, 1), t), cache, t, mask,
+                                  experts_fn=experts_fn)
+            step = np.asarray(cache["expert_tokens"]) - before
+            tiles += int((-(-step // tm)).sum())
+            touched += int((step > 0).sum())
+        assert int(cache["decode_touched"]) == touched < tiles
+        want = tiles if experts_fn else 0
+        assert int(cache["decode_tiles_used"]) == want
+        assert int(cache["decode_tiles_walked"]) == want
 
 
 def test_deepseek_state_has_no_decode_counter():
@@ -256,6 +286,39 @@ def test_grouped_experts_agree_with_dense_experts(tiny, act, int8):
                                       interpret=True)
     assert float(jnp.abs(dense).max()) > 1e-3
     np.testing.assert_allclose(grouped, dense, atol=1e-5)
+
+
+@pytest.mark.parametrize("rows", ["float", "int8"])
+@pytest.mark.parametrize("n_experts", [8, 64])
+@pytest.mark.parametrize("T", [4, 12, 24])
+def test_a_decode_steps_grouped_experts_agree_with_dense_experts(T, n_experts,
+                                                                 rows):
+    """A decode step of 4, 12 and 24 rows (the reduce's, Laguna's and
+    SmallThinker's map), fewer experts than slots and more: the rows built
+    around T * k slots, the grid over the tiles they fill, one gather
+    back."""
+    cfg = st.tiny_smallthinker(n_routed_experts=n_experts,
+                               w8a8_prefill=rows == "int8")
+    stacked = _stacked(st.init_params(jax.random.key(2), cfg), rows == "int8")
+    x, ids, w = _layer_inputs(cfg, T, 2)
+    dense = experts.dense_experts(x, ids, w, stacked, 5, cfg)
+    grouped = experts.grouped_experts(x, ids, w, stacked, 5, cfg,
+                                      interpret=True)
+    scale = float(jnp.abs(dense).max())
+    assert scale > 1e-3
+    np.testing.assert_allclose(grouped, dense,
+                               atol=0.03 * scale if rows == "int8" else 1e-5)
+
+
+def test_a_decode_step_with_no_slot_held_adds_nothing(tiny):
+    """No pick held here (a DeepSeek-V2 reduce step can be one): the product
+    takes no grid step, and the way back selects zeros, never its rows."""
+    cfg, params = tiny
+    x, ids, w = _layer_inputs(cfg, 12, 2, not_held=1.1)
+    assert int(ids.max()) == -1
+    got = experts.grouped_experts(x, ids, w, _stacked(params, True), 1, cfg,
+                                  interpret=True)
+    assert got.shape == x.shape and not bool(jnp.any(got))
 
 
 def _layer_inputs(cfg, T, k, seed=4, not_held=0.2):
@@ -433,7 +496,16 @@ def test_engine_generates_through_the_kernels_with_a_window(tiny, quantize_kv):
     be = TpuBackend(model_config=cfg, tokenizer="byte", params=params,
                     batch_size=2, max_new_tokens=6, interpret=True,
                     quantize_kv=quantize_kv, prefill_chunk_tokens=128)
-    outs = be.generate(["xin chào " * 22, "một hai ba"], max_new_tokens=6)
+    # the engine's logger does not propagate: listen on it directly
+    log, heard = logging.getLogger("vnsum.engine"), []
+    listener = logging.Handler()
+    listener.emit = lambda record: heard.append(record.getMessage())
+    log.addHandler(listener)
+    try:
+        outs = be.generate(["xin chào " * 22, "một hai ba"],
+                           max_new_tokens=6)
+    finally:
+        log.removeHandler(listener)
     st_ = be.stats
     assert len(outs) == 2
     assert list(st_.attention_paths.values()) == [
@@ -443,6 +515,14 @@ def test_engine_generates_through_the_kernels_with_a_window(tiny, quantize_kv):
     assert int(np.sum(st_.expert_tokens)) == st_.expert_slots_held
     assert st_.expert_decode_layer_steps == 6 * 8
     assert 6 * 8 * 2 <= st_.expert_decode_touched <= 6 * 8 * 4
+    # 4 slots a step: a touched expert fills one tile, and the grid walks
+    # the tiles used
+    assert st_.expert_decode_tiles_used == st_.expert_decode_tiles_walked \
+        == st_.expert_decode_touched
+    (line,) = [m for m in heard if m.startswith("dispatch B=")]
+    assert line.endswith(
+        f"expert tiles used {st_.expert_decode_tiles_used} of "
+        f"{st_.expert_decode_tiles_walked} walked (1.000)")
     assert sum(st_.prefill_blocks.values()) > 0
     window = be._layer_window_fn()
     assert [int(window(i)) for i in range(8)] == [0, 24, 24, 24] * 2

@@ -90,13 +90,13 @@ _LAGUNA = (4, 256, 3072, 1024, "silu", (512, 1536))
 
 
 @pytest.mark.parametrize("widths,tm,tiles", [
-    (_DEEPSEEK, 256, 233), (_DEEPSEEK, 32, 46),
+    (_DEEPSEEK, 256, 233), (_DEEPSEEK, 32, 45),
     # a piece of 8,192 tokens x 6 picks over 64 held experts; a decode step
     # of 24 rows
-    (_SMALLTHINKER, 256, 257), (_SMALLTHINKER, 32, 70),
+    (_SMALLTHINKER, 256, 257), (_SMALLTHINKER, 32, 69),
     # a piece of 8,192 tokens x 10 picks over 256 held experts; a decode
-    # step of 12 rows
-    (_LAGUNA, 256, 577), (_LAGUNA, 32, 260),
+    # step of 12 rows: no more tiles than its 120 slots
+    (_LAGUNA, 256, 577), (_LAGUNA, 32, 121),
 ])
 def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
                                                       tiles):
@@ -104,7 +104,8 @@ def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
     tile: s8 x s8 -> s32 over K = hidden and K = the expert's width, weights
     read from the stack of layers x held experts in place; DeepSeek-V2's
     SwiGLU experts of 5120 x 1536 and SmallThinker's ReGLU ones of 2560 x
-    768."""
+    768. The grid's row bound is the traced ``tiles_used``: a bound Mosaic
+    refuses fails here."""
     from vnsum_tpu.models.experts import _column_tile
     from vnsum_tpu.ops.expert_matmul import expert_grouped_matmul
 
@@ -126,6 +127,42 @@ def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
         one_chip, ((M, F), I8), ((M, 1), F32), down, *sched)
     assert "tpu_custom_call" in c.as_text()
     assert (_column_tile(D, F), _column_tile(F, D)) == column_tiles
+
+
+@pytest.mark.parametrize("widths,k,rows,tiles", [
+    # a map step's rows and the reduce's 4, each cell's picks a token
+    (_DEEPSEEK, 6, 24, 45), (_DEEPSEEK, 6, 4, 25),
+    (_SMALLTHINKER, 6, 24, 69), (_SMALLTHINKER, 6, 4, 25),
+    (_LAGUNA, 10, 12, 121), (_LAGUNA, 10, 4, 41),
+])
+def test_a_decode_steps_expert_layer_compiles_with_its_dynamic_grid(
+        one_chip, widths, k, rows, tiles):
+    """A whole decode step of ``grouped_experts`` at the cells' widths: the
+    layout by sort over ``min(N, N // tm + E) + 1`` row tiles, the rows by a
+    0/1 product, both products under a grid bounded by the layout's own
+    ``tiles_used`` (a scalar the program computes, not a constant), one
+    gather back."""
+    import types
+
+    from vnsum_tpu.models.experts import grouped_experts
+    from vnsum_tpu.ops.expert_matmul import expert_layout
+
+    L, E, D, F, act, _tiles = widths
+    cfg = types.SimpleNamespace(n_held=E, moe_intermediate=F, act=act,
+                                w8a8_prefill=True)
+    up = {"q": ((L, E, D, F), I8), "s": ((L, E, F), F32)}
+    down = {"q": ((L, E, F, D), I8), "s": ((L, E, D), F32)}
+    c = _compiled(
+        lambda x, local, weights, g, u, d: grouped_experts(
+            x, local, weights, {"we_gate": g, "we_up": u, "we_down": d}, 1,
+            cfg, interpret=False),
+        one_chip, ((rows, D), BF16), ((rows, k), I32), ((rows, k), F32),
+        up, up, down)
+    assert c.as_text().count("tpu_custom_call") >= 2
+    M = jax.eval_shape(
+        lambda e: expert_layout(e, E, 32)[1],
+        jax.ShapeDtypeStruct((rows * k,), I32)).shape[0]
+    assert M == tiles * 32
 
 
 @pytest.mark.parametrize("widths,k,tokens,columns", [

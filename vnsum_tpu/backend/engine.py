@@ -131,12 +131,17 @@ class EngineStats:
     # router saw, those that fell on an expert held here, and tokens per
     # expert layer and held expert ([layers][experts] once a dispatch ran);
     # where the family counts them, distinct experts with a token summed
-    # over decode steps and layers, and the (step, layer) pairs counted
+    # over decode steps and layers, and the (step, layer) pairs counted;
+    # with them the row tiles of the grouped product that held a slot in
+    # those steps and the row tiles its grid took a step for (used / walked:
+    # the live share of the product's grid)
     expert_slots_routed: int = 0
     expert_slots_held: int = 0
     expert_tokens: list = field(default_factory=list)
     expert_decode_touched: int = 0
     expert_decode_layer_steps: int = 0
+    expert_decode_tiles_used: int = 0
+    expert_decode_tiles_walked: int = 0
 
     def note_dispatch(self, B: int, S: int, enqueue_s: float,
                       wait_s: float) -> None:
@@ -1858,11 +1863,12 @@ class TpuBackend:
                             # lint-allow[host-sync-in-hot-path]: one-shot result fetch bounds the dispatch and feeds detok
                             out = jax.device_get(out_dev)
                         with host_span("engine", "count", sink):
+                            grid = ""
                             if self.family.counters is not None:
                                 # a family that counts returns its counters
                                 # with the tokens: one fetch brought both
                                 out, counted = out
-                                self._add_expert_counts(counted)
+                                grid = self._add_expert_counts(counted)
                             self._count_prefill_blocks(
                                 pad_lens, S, S + max_new, K)
                         self.stats.batches += 1
@@ -1882,9 +1888,9 @@ class TpuBackend:
                     self.stats.note_dispatch(B, S, enqueue.dur, wait.dur)
                     logger.info(
                         "dispatch B=%d S=%d rows=%d: enqueue %.3fs wait "
-                        "%.3fs detokenize %.3fs of %.3fs",
+                        "%.3fs detokenize %.3fs of %.3fs%s",
                         B, S, len(group), enqueue.dur, wait.dur, detok.dur,
-                        disp.dur,
+                        disp.dur, grid,
                     )
         finally:
             if matches is not None:
@@ -1908,19 +1914,27 @@ class TpuBackend:
         self._spec_report = spec_report
         return results  # type: ignore[return-value]
 
-    def _add_expert_counts(self, counted: dict) -> None:
+    def _add_expert_counts(self, counted: dict) -> str:
         """Add one dispatch's expert counters (host arrays by now) to the
-        statistics."""
+        statistics. Returns what the dispatch's log line says of them: the
+        live share of the grouped product's grid in its decode steps."""
         st = self.stats
         st.expert_slots_routed += int(counted["slots_routed"])
         st.expert_slots_held += int(counted["slots_held"])
-        st.expert_decode_touched += int(counted.get("decode_touched", 0))
-        st.expert_decode_layer_steps += int(
-            counted.get("decode_layer_steps", 0))
+        decode = {name: int(counted.get(name, 0)) for name in (
+            "decode_touched", "decode_layer_steps", "decode_tiles_used",
+            "decode_tiles_walked")}
+        for name, n in decode.items():
+            setattr(st, f"expert_{name}", getattr(st, f"expert_{name}") + n)
         tokens = np.asarray(counted["expert_tokens"], np.int64)
         if st.expert_tokens:
             tokens = tokens + np.asarray(st.expert_tokens, np.int64)
         st.expert_tokens = tokens.tolist()
+        used, walked = decode["decode_tiles_used"], decode["decode_tiles_walked"]
+        if not walked:
+            return ""
+        return (f", expert tiles used {used} of {walked} walked "
+                f"({used / walked:.3f})")
 
     def prefill_then_decode_logits(
         self, prompt_ids, forced_ids, bucket: int | None = None,

@@ -27,7 +27,11 @@ activation), ``w8a8_prefill``. What the state holds (``init_expert_state``):
 latest forward) and, where a family asks for it, ``decode_touched`` /
 ``decode_layer_steps``: distinct experts with at least one token, summed
 over the single-token forwards and layers, and how many such (step, layer)
-pairs were counted — what a decode step's expert bytes are.
+pairs were counted — what a decode step's expert bytes are — with
+``decode_tiles_used`` / ``decode_tiles_walked``: the row tiles of the
+grouped product that hold a slot, and those its grid takes a step for (the
+same sum since the grid's row bound is ``tiles_used``: no step for a tile
+no slot fills), summed over the same pairs where the product runs.
 """
 from __future__ import annotations
 
@@ -39,8 +43,10 @@ import jax.numpy as jnp
 from .llama import _mlp_act
 
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
-_COUNTERS = ("expert_tokens", "slots_routed", "slots_held",
-             "decode_touched", "decode_layer_steps")
+# the scalars a family asks for with ``decode_touched``
+_DECODE_COUNTERS = ("decode_touched", "decode_layer_steps",
+                    "decode_tiles_used", "decode_tiles_walked")
+_COUNTERS = ("expert_tokens", "slots_routed", "slots_held", *_DECODE_COUNTERS)
 
 
 def init_expert_state(n_layers: int, n_held: int, batch: int, top_k: int, *,
@@ -55,8 +61,7 @@ def init_expert_state(n_layers: int, n_held: int, batch: int, top_k: int, *,
         "picks": jnp.zeros((n_layers, batch, top_k), jnp.int32),
     }
     if decode_touched:
-        state["decode_touched"] = jnp.zeros((), jnp.int32)
-        state["decode_layer_steps"] = jnp.zeros((), jnp.int32)
+        state.update({k: jnp.zeros((), jnp.int32) for k in _DECODE_COUNTERS})
     return state
 
 
@@ -93,6 +98,19 @@ def _column_tile(K: int, N: int) -> int:
 # buffers (every pick of every token held here) at ~1.2 GB at DeepSeek-V2's
 # widths, while an expert still sees ~300 rows a weight fetch
 _EXPERT_PIECE_TOKENS = 8192
+
+
+def _int8_rows(experts, cfg) -> bool:
+    """int8 rows (s8 x s8) whenever the weights are int8 and the engine runs
+    W8A8: in a decode step too, where converting each expert's weight tile
+    to bf16 in the kernel would cost more than fetching it."""
+    return isinstance(experts["we_gate"], dict) and cfg.w8a8_prefill
+
+
+def _row_tile(T: int, int8_rows: bool) -> int:
+    """Rows of a tile of the grouped product over T tokens: a prefill
+    piece's, or a decode step's few tokens'."""
+    return 256 if T >= 1024 else (32 if int8_rows else 16)
 
 
 def by_rows(fn, h, max_tokens: int):
@@ -132,15 +150,11 @@ def grouped_experts(x, local, weights, experts, slot, cfg, *,
 
     T, D = x.shape
     k = local.shape[1]
-    quantized = isinstance(experts["we_gate"], dict)
-    # int8 rows (s8 x s8) whenever the weights are int8 and the engine runs
-    # W8A8: in a decode step too, where converting each expert's weight
-    # tile to bf16 in the kernel would cost more than fetching it
-    int8_rows = quantized and cfg.w8a8_prefill
     # a prefill piece, or a decode step's few tokens: the row tile and the
     # two ends of the permutation follow that, and nothing else
     prefill = T >= 1024
-    tm = 256 if prefill else (32 if int8_rows else 16)
+    int8_rows = _int8_rows(experts, cfg)
+    tm = _row_tile(T, int8_rows)
     F = cfg.moe_intermediate
 
     def take(a, idx):   # every index below is a slot's or a row's own
@@ -166,8 +180,9 @@ def grouped_experts(x, local, weights, experts, slot, cfg, *,
         if prefill:
             rows = take(x, token_of_row)
         else:
-            # a decode step builds ~15 rows of padding a real one: a product
-            # with a 0/1 matrix picks them (exactly) faster than a gather
+            # a decode step builds rows of padding by the dozen for a real
+            # one: a product with a 0/1 matrix picks them (exactly) faster
+            # than a gather
             rows = jax.lax.dot(
                 (token_of_row[:, None] == jnp.arange(x.shape[0])[None, :]
                  ).astype(x.dtype), x,
@@ -271,6 +286,14 @@ def expert_layer(x, picks, real, experts, slot, cache, cfg, experts_fn,
                 decode_touched=cache["decode_touched"]
                 + jnp.sum(tokens > 0, dtype=jnp.int32),
                 decode_layer_steps=cache["decode_layer_steps"] + 1)
+            if experts_fn is not None:
+                # the grouped product's grid: a step a column tile for each
+                # row tile that holds a slot, and for no other
+                tm = _row_tile(rows, _int8_rows(experts, cfg))
+                tiles = jnp.sum(-(-tokens // tm), dtype=jnp.int32)
+                cache.update(
+                    decode_tiles_used=cache["decode_tiles_used"] + tiles,
+                    decode_tiles_walked=cache["decode_tiles_walked"] + tiles)
     with jax.named_scope("experts"):
         routed = (experts_fn or functools.partial(dense_experts, cfg=cfg))(
             x, local, weights, experts, slot)

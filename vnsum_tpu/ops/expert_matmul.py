@@ -10,12 +10,15 @@ a slot brings along (its routing weight, its token's int8 scale) to row
 order in the same sort; a second sort gives the slots their rows. No
 one-hot is summed down the slots, nothing is scattered, no index is looked
 up a scalar at a time (5-7 ns each on this chip; PERF.md, PR 40). The
-product kernel walks the row tiles and multiplies each by its expert's
-weights, picked by a scalar-prefetched ``tile_expert``; tiles past
-``tiles_used`` are neither fetched nor computed, so the cost follows the
-slots that are really there while every shape stays static at the worst
-case (every pick of every token on an expert held here): nothing has a
-capacity, nothing is dropped.
+product kernel walks the row tiles that hold a slot and multiplies each by
+its expert's weights, picked by a scalar-prefetched ``tile_expert``. The
+row dimension of its grid is ``tiles_used`` itself, a bound read when the
+kernel starts: no grid step is taken for a tile past it (a step skipped by
+``pl.when`` still cost 0.10-0.13 us, and a decode step's 120 slots on 256
+experts walked 1,044 steps for 208; PERF.md, PR 42), so the cost follows
+the slots that are really there while every shape stays static at the
+worst case (every pick of every token on an expert held here): nothing has
+a capacity, nothing is dropped.
 
 ``expert_grouped_matmul(lhs, w, ...)`` is one product; with ``w_up`` it is
 the gated front half ``act(lhs . w) * (lhs . w_up)`` in one pass over
@@ -59,14 +62,18 @@ def expert_layout(expert_of_slot, n_experts: int, tm: int, carry=()):
     whose group it fills. Returns ``row_of_slot`` [N] (the last row, a
     spare, for -1 slots), ``slot_of_row`` [M] (-1 for a row of padding:
     nobody reads its product), ``tile_expert`` [Mt], ``tiles_used`` [1],
-    ``group_sizes`` [n_experts], the static row count ``M = Mt * tm`` (every
-    slot plus up to a tile of padding an expert, plus the spare tile) and
+    ``group_sizes`` [n_experts], the static row count ``M = Mt * tm`` (the
+    most tiles N slots can fill — one each, or their whole tiles and a
+    partly filled one an expert, whichever is less — plus the spare tile:
+    121 tiles for a decode step's 120 slots on 256 experts) and
     ``carry`` ([N] arrays, a value a slot) moved to row order by the same
     sort, 0 on padding. Nothing is scattered and no index is looked up: a
     second sort brings the slots' rows back to slot order."""
     N = expert_of_slot.shape[0]
     E = n_experts
-    Mt = -(-N // tm) + E + 1
+    # a used tile holds a slot, and an expert leaves at most one tile partly
+    # filled: tiles_used <= min(N, N // tm + E) at any N, plus the spare
+    Mt = min(N, N // tm + E) + 1
     M = Mt * tm
     i32 = jnp.int32
     experts = jnp.arange(E, dtype=i32)[None, :]
@@ -100,8 +107,8 @@ def expert_layout(expert_of_slot, n_experts: int, tm: int, carry=()):
             group_sizes, M, [jnp.where(own, c, 0) for c in carried])
 
 
-def _kernel(layer_ref, te_ref, used_ref, *refs, quantized: bool,
-            int8_lhs: bool, scaled: bool, gated: bool, act: str):
+def _kernel(layer_ref, te_ref, *refs, quantized: bool, int8_lhs: bool,
+            scaled: bool, gated: bool, act: str):
     refs = list(refs)
     x_ref = refs.pop(0)
     xs_ref = refs.pop(0) if scaled else None
@@ -111,31 +118,29 @@ def _kernel(layer_ref, te_ref, used_ref, *refs, quantized: bool,
     us_ref = refs.pop(0) if gated and quantized else None
     o_ref = refs.pop(0)
 
-    @pl.when(pl.program_id(1) < used_ref[0])
-    def _compute():
-        x = x_ref[...]
+    x = x_ref[...]
 
-        def product(wr, sr):
-            w = wr[0, 0]
-            if int8_lhs:
-                y = jax.lax.dot_general(
-                    x, w, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.int32).astype(jnp.float32)
-            else:
-                y = jax.lax.dot_general(
-                    x, w.astype(x.dtype), (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-            if scaled:
-                y = y * xs_ref[...]
-            if sr is not None:
-                y = y * sr[0, 0]
-            return y
+    def product(wr, sr):
+        w = wr[0, 0]
+        if int8_lhs:
+            y = jax.lax.dot_general(
+                x, w, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.int32).astype(jnp.float32)
+        else:
+            y = jax.lax.dot_general(
+                x, w.astype(x.dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        if scaled:
+            y = y * xs_ref[...]
+        if sr is not None:
+            y = y * sr[0, 0]
+        return y
 
-        y = product(w_ref, ws_ref)
-        if gated:
-            gate = jnp.maximum(y, 0.0) if act == "relu" else jax.nn.silu(y)
-            y = gate * product(u_ref, us_ref)
-        o_ref[...] = y.astype(o_ref.dtype)
+    y = product(w_ref, ws_ref)
+    if gated:
+        gate = jnp.maximum(y, 0.0) if act == "relu" else jax.nn.silu(y)
+        y = gate * product(u_ref, us_ref)
+    o_ref[...] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -144,7 +149,8 @@ def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
                           tiles_used, *, tm: int, tn: int, out_dtype,
                           act: str = "silu", interpret: bool = False):
     """``out[r] = lhs[r] . w[layer, tile_expert[r // tm]]`` for the rows of
-    the first ``tiles_used`` tiles; the other rows of ``out`` are
+    the first ``tiles_used`` tiles, the only ones the grid takes a step for
+    (with none used it takes no step); the other rows of ``out`` are
     unspecified.
 
     lhs [M, K] (int8 with ``lhs_scale`` [M, 1] float32, or a float type with
@@ -169,16 +175,11 @@ def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
     if act not in ("silu", "relu"):
         raise ValueError(f"act={act!r}: the gate is silu or relu")
 
-    def tile(m, used):
-        # a tile past the last one used repeats its index: nothing is
-        # fetched for it, and its (skipped) output block is not written out
-        return jnp.minimum(m, jnp.maximum(used[0] - 1, 0))
+    def row(n, m, layer, te):
+        return (m, 0)
 
-    def row(n, m, layer, te, used):
-        return (tile(m, used), 0)
-
-    def weight(n, m, layer, te, used):
-        return (layer[0], te[tile(m, used)], 0, n)
+    def weight(n, m, layer, te):
+        return (layer[0], te[m], 0, n)
 
     in_specs = [pl.BlockSpec((tm, K), row)]
     operands = [lhs]
@@ -197,12 +198,11 @@ def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(N // tn, M // tm),
+            num_scalar_prefetch=2,
+            # the row tiles that hold a slot, and no step for the others
+            grid=(N // tn, tiles_used[0]),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (tm, tn),
-                lambda n, m, layer, te, used: (tile(m, used), n)),
+            out_specs=pl.BlockSpec((tm, tn), lambda n, m, layer, te: (m, n)),
         ),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         compiler_params=pltpu.CompilerParams(
@@ -212,8 +212,7 @@ def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
         # a contract: the device trace and the benchmark's metrics name this
         # kernel by it
         name="expert_grouped_matmul",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), tile_expert, tiles_used,
-      *operands)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), tile_expert, *operands)
 
 
 # -- back to token order ------------------------------------------------------
