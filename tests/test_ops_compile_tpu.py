@@ -1,6 +1,7 @@
-"""The DeepSeek-V2 family's three kernels, and the GQA kernels and the
-expert product at the SmallThinker family's geometry, compiled for the chip
-at the published widths, with no chip: the TPU's compiler is installed here and
+"""The DeepSeek-V2 family's three kernels, the GQA kernels and the
+expert product at the SmallThinker family's geometry, and the Granite-4.0-H
+family's two scan kernels and the GQA kernels at its 64-wide heads, compiled
+for the chip at the published widths, with no chip: the TPU's compiler is installed here and
 compiles for a described v5e. Interpret mode cannot show what this does: a
 slice not aligned to the tiling, too much VMEM, an int8 product Mosaic
 refuses. Nothing runs and nothing is timed.
@@ -273,3 +274,75 @@ def test_gqa_decode_kernel_compiles_at_g7_under_a_window(one_chip):
         one_chip, ((24, 1, 28, 128), BF16), _int8_cache(4, 24, 4, 8448, 128),
         ((24,), I32), ((), I32))
     assert "tpu_custom_call" in c.as_text()
+
+
+# -- the Granite-4.0-H family: the scan kernels, the GQA kernels at hd 64 ----
+
+
+@pytest.mark.parametrize("offset", [0, 6144])
+def test_gqa_prefill_kernel_compiles_at_64_wide_heads(one_chip, offset):
+    """32/8 heads of 64 as blocks of the array's own width (half of each
+    lane tile holds nothing), a 2,048-query chunk of the S=8192 bucket over
+    the int8 cache of the 4 attention layers, 24 rows."""
+    from vnsum_tpu.ops import flash_attention
+
+    assert flash_attention.head_dim_supported(64)
+    assert not flash_attention.head_dim_supported(96)
+    c = _compiled(
+        lambda q, cache, pads: flash_attention.flash_prefill_attention(
+            q, cache, 1, pads, 4, None, offset),
+        one_chip, ((24, 2048, 32, 64), BF16), _int8_cache(4, 24, 8, 8448, 64),
+        ((24,), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_gqa_decode_kernel_compiles_at_64_wide_heads(one_chip):
+    from vnsum_tpu.ops.decode_attention import flash_decode_attention
+
+    c = _compiled(
+        lambda q, cache, pads: flash_decode_attention(
+            q, cache, 3, pads, 8200, 4),
+        one_chip, ((24, 1, 32, 64), BF16), _int8_cache(4, 24, 8, 8448, 64),
+        ((24,), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def _scan_shapes(B, S):
+    """x, dt, A, B, C, D at Granite-4.0-H-Micro's widths: 64 heads of 64, a
+    state of 128."""
+    seq = (S,) if S else ()
+    return (((B, *seq, 64, 64), BF16), ((B, *seq, 64), F32), ((64,), F32),
+            ((B, *seq, 128), BF16), ((B, *seq, 128), BF16), ((64,), F32))
+
+
+def test_prefill_scan_kernel_compiles_at_the_cells_shapes(one_chip):
+    """A 2,048-token prefill chunk of 24 rows in scan chunks of 256 over the
+    stacked float32 state of 36 layers, written in place: the x and y
+    blocks of [256, 4096], the row's state in, out and in scratch, and the
+    lane tiles sliced by a traced index, within the VMEM the kernel asks
+    for."""
+    from vnsum_tpu.ops import ssd_scan
+
+    c = _compiled(
+        lambda x, dt, A, Bm, Cm, D, state, pads: ssd_scan.ssd_prefill_scan(
+            x, dt, A, Bm, Cm, D, state, 7, pads, chunk=256),
+        one_chip, *_scan_shapes(24, 2048), ((36, 24, 128, 4096), F32),
+        ((24,), I32))
+    assert "tpu_custom_call" in c.as_text()
+    assert ssd_scan.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024
+
+
+def test_decode_update_kernel_compiles_in_place_at_the_cells_shapes(one_chip):
+    """One token for 24 rows: the layer's 2 MiB state blocks read and
+    written in place. With the stacked state donated the compiled program
+    holds no second copy of its 1.81 GB."""
+    from vnsum_tpu.ops import ssd_scan
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (*_scan_shapes(24, 0), ((36, 24, 128, 4096), F32))]
+    c = jax.jit(
+        lambda x, dt, A, Bm, Cm, D, state: ssd_scan.ssm_decode_update(
+            x, dt, A, Bm, Cm, D, state, 7),
+        donate_argnums=(6,)).lower(*args).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024

@@ -122,6 +122,50 @@ def test_kernel_name_is_a_contract(kernel):
     assert _pallas_names(jax.make_jaxpr(call)(cache).jaxpr) == [kernel]
 
 
+@pytest.mark.parametrize("kernel", ["ssd_prefill_scan", "ssm_decode_update"])
+def test_scan_kernel_name_is_a_contract(kernel):
+    """The two scan kernels of ``ops/ssd_scan.py``: the benchmark's
+    ``ssd_prefill_scan_roofline``, ``ssm_decode_update_roofline`` and
+    ``ssm_busy_share`` read the trace by these names."""
+    from vnsum_tpu.ops import ssd_scan
+
+    H, P, N = 2, 8, 4
+    x = jnp.zeros((2, 8, H, P))
+    dt = jnp.ones((2, 8, H))
+    A, D, bc = -jnp.ones((H,)), jnp.ones((H,)), jnp.zeros((2, 8, N))
+    call = {
+        "ssd_prefill_scan": lambda st: ssd_scan.ssd_prefill_scan(
+            x, dt, A, bc, bc, D, st, 0, jnp.zeros((2,), jnp.int32), chunk=4,
+            interpret=True),
+        "ssm_decode_update": lambda st: ssd_scan.ssm_decode_update(
+            x[:, 0], dt[:, 0], A, bc[:, 0], bc[:, 0], D, st, 0,
+            interpret=True),
+    }[kernel]
+    state = jnp.zeros((1, 2, N, H * P))
+    assert _pallas_names(jax.make_jaxpr(call)(state).jaxpr) == [kernel]
+
+
+def test_a_recurrent_familys_program_carries_its_component_scopes():
+    """``ssm_in``, ``conv``, ``ssd`` and ``ssm_out`` under both phases of
+    the one-shot program of ``models/granite_hybrid.py``, beside the
+    attention layers' and the feed-forward's: ``scope_maps()`` books the
+    family with no edit (README, "Device time by layer")."""
+    from vnsum_tpu.models.granite_hybrid import init_params, tiny_granite_h
+
+    cfg = tiny_granite_h(max_seq_len=128)
+    b = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
+                   max_new_tokens=NEW, seed=1, flash=False,
+                   params=init_params(jax.random.key(0), cfg))
+    b._get_fn(B, S, NEW, b.gen_cfg)
+    (m,) = b.scope_maps()
+    assert m["module"] == "jit_generate"
+    got = paths(m["scopes"])
+    for phase in ("prefill", "decode"):
+        assert {f"{phase}/{c}" for c in MODEL + (
+            "ssm_in", "conv", "ssd", "ssm_out", "sample")} <= got
+    assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
+
+
 @pytest.mark.parametrize("line, name, scope", [
     ('  %dot.5 = f32[8,64]{1,0} dot(%a, %b), metadata={op_name='
      '"jit(generate)/decode/while/body/mlp/bsd,di->bsi/dot_general" '

@@ -109,7 +109,11 @@ class EngineStats:
     # Beside the classes, where layers have a window:
     # ``window_scores_computed`` (the cells window layers fetched x the
     # tile's area x their query heads) and ``window_scores_needed`` (for
-    # each real query row min(row + 1 - pad, window) keys x those heads)
+    # each real query row min(row + 1 - pad, window) keys x those heads).
+    # Where the family runs a scan (``Family.prefill_counts``):
+    # ``scan_tokens_real`` (real prompt tokens x its scan layers) and
+    # ``scan_tokens_computed`` (tokens of the chunks the scan kernel did not
+    # skip x those layers)
     prefill_blocks: dict = field(default_factory=dict)
     # which attention each built program got, keyed "program[B=..,S=..]" →
     # {"prefill"|"decode": "kernel"|"dense"}: a dense fallback (unaligned
@@ -228,8 +232,8 @@ class TpuBackend:
             )
         # int8 KV cache halves decode-attention HBM traffic; the in-kernel
         # dequant needs the Pallas path, so "auto" follows flash AND actual
-        # kernel support (head_dim lane alignment — e.g. llama32_1b's
-        # head_dim=64 can't take the kernels, and the dense fallback would
+        # kernel support (a head_dim the kernels take:
+        # ops/flash_attention.head_dim_supported; the dense fallback would
         # dequantize the whole cache per step)
         kernels_supported = self.family.kernels_supported(
             self.cfg, self.interpret)
@@ -243,7 +247,7 @@ class TpuBackend:
         elif quantize_kv and not (self.flash and kernels_supported):
             raise ValueError(
                 "quantize_kv=True needs the Pallas kernels (flash=True and "
-                "head_dim a multiple of 128); the dense fallback would "
+                "a head_dim of whole lane tiles or 64); the dense fallback would "
                 "dequantize the whole cache per step"
             )
         self.quantize_kv = bool(quantize_kv)
@@ -406,6 +410,15 @@ class TpuBackend:
             "quantize_kv": self.quantize_kv,
             "attention_paths": self.stats.attention_paths.copy(),
             "compile_seconds": round(self.stats.compile_seconds, 3),
+            # one row of the state a program carries, by leaf, at
+            # max_seq_len slots: what grows with the row (keys and values)
+            # beside what does not (a recurrent state)
+            "state_bytes_per_row": {
+                name: leaf.size * leaf.dtype.itemsize
+                for name, leaf in jax.eval_shape(
+                    lambda: self.family.init_cache(
+                        self.cfg, 1, self.cfg.max_seq_len,
+                        quantized=self.quantize_kv)).items()},
             "memory": [
                 {k: int(v) for k, v in (d.memory_stats() or {}).items()
                  if k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
@@ -842,10 +855,17 @@ class TpuBackend:
         )
 
         cfg = self.cfg
-        windows = self.family.layer_windows(cfg) or (0,) * cfg.n_layers
-        groups = (self.family.layer_groups(cfg)
-                  or (cfg.q_per_kv,) * cfg.n_layers)
         total = self.stats.prefill_blocks
+        if self.family.prefill_counts is not None:
+            # what else the family counts from the pads (a scan's tokens)
+            for name, n in self.family.prefill_counts(
+                    cfg, pad_lens, self._prefill_spans(S, start)).items():
+                total[name] = total.get(name, 0) + n
+        # the layers that attend: all of them, or a family's few
+        attending = self.family.attention_layers(cfg)
+        windows = self.family.layer_windows(cfg) or (0,) * attending
+        groups = (self.family.layer_groups(cfg)
+                  or (cfg.q_per_kv,) * attending)
         pads = np.asarray(pad_lens, np.int64)
         # {(window, query heads a KV head): layers of that kind}
         for (window, group), n_layers in sorted(

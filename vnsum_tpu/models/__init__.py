@@ -59,6 +59,18 @@ def _tiny_laguna(**kw):
     return tiny_laguna(**kw)
 
 
+def _granite_h_micro(**kw):
+    from .granite_hybrid import granite_4_0_h_micro
+
+    return granite_4_0_h_micro(**kw)
+
+
+def _tiny_granite_h(**kw):
+    from .granite_hybrid import tiny_granite_h
+
+    return tiny_granite_h(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -86,6 +98,10 @@ MODEL_REGISTRY = {
     # experts top-10 with a shared one
     "laguna-s-2.1": _laguna,
     "tiny-laguna": _tiny_laguna,
+    # a fifth (models/granite_hybrid.py): Mamba-2 layers beside a few
+    # position-free GQA layers, a recurrent state in the program's carry
+    "granite-4.0-h-micro": _granite_h_micro,
+    "tiny-granite-h": _tiny_granite_h,
 }
 
 __all__ = [

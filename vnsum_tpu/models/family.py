@@ -5,9 +5,10 @@ sampling by (seed, uid, t), the early-exit decode loop, the statistics. What
 differs between families is the layer stack and what it keeps between
 steps, and that is what a ``Family`` supplies: ``forward``, the constructor
 of the state a program carries (the KV cache; for latent attention the
-latent cache and the expert counters), the parameters' init, and the two
-attention functions over the stacked cache, one per phase, and each
-layer's sliding window where it has layers of that kind.
+latent cache and the expert counters; for a recurrence its state beside
+the keys and values), the parameters' init, and the two attention
+functions over the stacked cache, one per phase, and each layer's sliding
+window where it has layers of that kind.
 
 ``family_of(cfg)`` resolves a family from the config's type: the module a
 config class lives in names its family as ``FAMILY``. Entries of the engine
@@ -51,6 +52,13 @@ class Family:
     # cfg -> each layer's query heads a KV head where the layers differ in
     # them, or None where ``cfg.q_per_kv`` holds for every layer
     layer_groups: Callable = lambda cfg: None
+    # cfg -> how many layers attend over the cache (a family that mixes
+    # attention with other layers holds keys and values for those alone)
+    attention_layers: Callable = lambda cfg: cfg.n_layers
+    # (cfg, pad_lens, prefill spans) -> {name: count} a dispatch's prefill
+    # adds to ``EngineStats.prefill_blocks`` beside the attention's cells,
+    # from the pads it was packed with (a scan's tokens), or None
+    prefill_counts: Callable | None = None
     # (cfg, kernels on, interpret) -> further keywords of ``forward``
     forward_kwargs: Callable = lambda cfg, kernels, interpret: {}
     # final state -> {name: device array} returned with a program's output,

@@ -828,9 +828,11 @@ def verify_positions(
 
 
 def _kernels_supported(cfg: LlamaConfig, interpret: bool) -> bool:
-    # the kernels want lane-aligned heads (llama32_1b's head_dim=64 cannot
-    # take them); interpret mode has no such limit
-    return cfg.head_dim % 128 == 0 or interpret
+    # the kernels want heads of whole lane tiles or half of one
+    # (ops/flash_attention.head_dim_supported); interpret mode has no limit
+    from ..ops.flash_attention import head_dim_supported
+
+    return head_dim_supported(cfg.head_dim) or interpret
 
 
 def _attention_supported(cfg: LlamaConfig, S: int, C: int):

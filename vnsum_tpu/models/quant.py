@@ -47,12 +47,19 @@ _ALL_CONTRACT_AXES = {
     # stacked experts [E, D, F] / [E, F, D]: the expert axis stays, so the
     # scale is per expert and output channel, [E, F] / [E, D]
     "we_gate": (1,), "we_up": (1,), "we_down": (1,),
+    # a Mamba-2 mixer's products (models/granite_hybrid.py: in_proj held
+    # as its parts z | xBC | dt, and out_proj); its convolution, dt_bias,
+    # A_log, D and gated norm stay float32
+    "in_z": (0,), "in_xbc": (0,), "in_dt": (0,),   # [D, inner | conv | H]
+    "out_proj": (0,),                              # [inner, D]
 }
 # the groups of stacked layers a params tree may hold: every family has
 # "layers"; one with leading dense layers keeps them under "dense"; one
 # whose attention differs in shape by layer kind keeps each kind's under
-# "full" and "sliding" (models/laguna.py)
-_LAYER_GROUPS = ("layers", "dense", "full", "sliding")
+# "full" and "sliding" (models/laguna.py); one that mixes Mamba and attention
+# layers keeps each kind's mixer under "mamba" and "attn" and every layer's
+# feed-forward under "layers" (models/granite_hybrid.py)
+_LAYER_GROUPS = ("layers", "dense", "full", "sliding", "mamba", "attn")
 
 
 def _quantize(w: jax.Array, contract_axes: tuple[int, ...]) -> dict:
@@ -104,7 +111,8 @@ def init_params_quantized(key: jax.Array, cfg) -> dict:
     import importlib
 
     # the family's own init_params, from the module its config lives in
-    init_params = importlib.import_module(type(cfg).__module__).init_params
+    module = importlib.import_module(type(cfg).__module__)
+    init_params = module.init_params
     shapes = jax.eval_shape(lambda k: init_params(k, cfg), key)
     n_leaves = len(jax.tree.leaves(shapes, is_leaf=lambda x: x is None))
     keys = iter(jax.random.split(key, max(n_leaves, 8)))
@@ -137,6 +145,12 @@ def init_params_quantized(key: jax.Array, cfg) -> dict:
     # the layer stacks draw their keys first, then the embedding, then the
     # head: the order a seed's weights have always been drawn in
     groups = {g: stack(shapes[g]) for g in _LAYER_GROUPS if g in shapes}
+    if hasattr(module, "float_leaves"):
+        # full-precision leaves the family draws its own way (a scan's
+        # decays), from a key of their own: no other draw moves
+        for g, leaves in module.float_leaves(
+                jax.random.fold_in(key, 1), cfg).items():
+            groups[g].update(leaves)
     out = {
         "embed": qinit(next(keys), shapes["embed"], (1,)),
         "final_norm": jnp.ones(

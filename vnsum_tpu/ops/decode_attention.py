@@ -46,7 +46,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _LANES, _NEG, VMEM_LIMIT_BYTES
+from .flash_attention import (
+    _LANES,
+    _NEG,
+    VMEM_LIMIT_BYTES,
+    head_dim_supported,
+)
 
 
 def _zero_past_cache(vb, k_start, cache_len: int):
@@ -295,8 +300,9 @@ def _pick_block_b(batch: int) -> int:
 
 
 def supports_decode(cache_len: int, head_dim: int) -> bool:
-    """Ceil-div grid handles any C; only lane-aligned head dims matter."""
-    return head_dim % _LANES == 0
+    """Ceil-div grid handles any C; only the head dim matters (whole lane
+    tiles, or half of one: flash_attention.head_dim_supported)."""
+    return head_dim_supported(head_dim)
 
 
 @functools.partial(
@@ -332,7 +338,7 @@ def flash_decode_attention(
     L, _, KV, C, _ = k_all.shape
     if S != 1:
         raise ValueError(f"decode kernel is single-token (S=1), got S={S}")
-    if hd % _LANES and not interpret:
+    if not (head_dim_supported(hd) or interpret):
         raise ValueError(f"unsupported decode head_dim={hd}")
     bk = min(block_k, C)
     bb = _pick_block_b(B)
@@ -473,7 +479,7 @@ def flash_spec_verify_attention(
     quantized = "ks" in cache
     B, Sq, H, hd = q.shape
     L, _, KV, C, _ = k_all.shape
-    if hd % _LANES and not interpret:
+    if not (head_dim_supported(hd) or interpret):
         raise ValueError(f"unsupported verify head_dim={hd}")
     G = q_per_kv
     if H != KV * G:

@@ -387,10 +387,18 @@ def _block_geometry(S: int, C: int, G: int, hd: int,
     return bq, bk
 
 
+def head_dim_supported(head_dim: int) -> bool:
+    """Head sizes the attention kernels take on the chip: whole lane tiles,
+    or half of one (64) as blocks of the array's own width — the DMA moves
+    64-wide rows and the products contract 64, on tiles whose other 64
+    lanes hold nothing."""
+    return head_dim % _LANES == 0 or head_dim == _LANES // 2
+
+
 def supports_flash(seq_len: int, cache_len: int, head_dim: int) -> bool:
-    """Ceil-div grids handle any S/C; only the lane-aligned head dim is
-    load-bearing on real hardware."""
-    return head_dim % _LANES == 0
+    """Ceil-div grids handle any S/C; only the head dim (whole lane tiles,
+    or half of one) is load-bearing on real hardware."""
+    return head_dim_supported(head_dim)
 
 
 @functools.partial(
@@ -431,7 +439,7 @@ def flash_prefill_attention(
     quantized = "ks" in cache
     B, S, H, hd = q.shape
     L, _, KV, C, _ = k_all.shape
-    if hd % _LANES and not interpret:
+    if not (head_dim_supported(hd) or interpret):
         raise ValueError(f"unsupported flash head_dim={hd}")
     G = H // KV
     if q_per_kv != G:
