@@ -122,36 +122,74 @@ _PREFILL_CASES = [
 ]
 
 
-@pytest.mark.parametrize("H,S,offset,pads,bq,bk", _PREFILL_CASES)
-def test_prefill_kernel_matches_dense_attention(H, S, offset, pads, bq, bk):
-    from vnsum_tpu.ops.mla_attention import mla_prefill_attention
-
-    B, dn, dr, dv = 2, 16, 8, 16
-    T = offset + S
+def _prefill_operands(H, S, T, B=2, rank=32, dn=16, dr=8, dv=16):
+    """Queries, latent rows and the two halves of ``W_kvb`` a head, and the
+    keys and values the reference expands from them by einsum."""
     ks = jax.random.split(jax.random.key(S), 5)
     qn = jax.random.normal(ks[0], (B, H, S, dn))
     qr = jax.random.normal(ks[1], (B, H, S, dr))
-    kn = jax.random.normal(ks[2], (B, H, T, dn))
-    kr = jax.random.normal(ks[3], (B, T, dr))
-    v = jax.random.normal(ks[4], (B, H, T, dv))
-    pad = jnp.asarray(pads, jnp.int32)
-    got = mla_prefill_attention(qn, qr, kn, kr, v, pad, scale=0.2,
-                                q_offset=offset, block_q=bq, block_k=bk,
-                                interpret=True)
+    lat = jax.random.normal(ks[2], (B, T, rank + dr))
+    wk = jax.random.normal(ks[3], (H, rank, dn)) * rank ** -0.5
+    wv = jax.random.normal(ks[4], (H, rank, dv)) * rank ** -0.5
+    kn = jnp.einsum("btc,hck->bhtk", lat[..., :rank], wk)
+    v = jnp.einsum("btc,hck->bhtk", lat[..., :rank], wv)
+    return qn, qr, lat, wk, wv, kn, lat[..., rank:], v
+
+
+def _dense_prefill(qn, qr, kn, kr, v, pad, offset, scale):
+    """Masked softmax attention over expanded keys and values, and which
+    query rows are real [B, S]."""
+    S, T = qn.shape[2], kn.shape[2]
     s = (jnp.einsum("bhsk,bhtk->bhst", qn, kn)
-         + jnp.einsum("bhsk,btk->bhst", qr, kr)) * 0.2
+         + jnp.einsum("bhsk,btk->bhst", qr, kr)) * scale
     q_pos = offset + jnp.arange(S)[:, None]
     k_pos = jnp.arange(T)[None, :]
     mask = (k_pos <= q_pos)[None] & (k_pos[None] >= pad[:, None, None])
     want = jnp.einsum(
         "bhst,bhtk->bhsk",
         jax.nn.softmax(jnp.where(mask[:, None], s, -1e30), -1), v)
-    real = np.asarray(q_pos[None, :, 0] >= pad[:, None])     # [B, S]
-    for b in range(B):
+    return want, np.asarray(q_pos[None, :, 0] >= pad[:, None])
+
+
+@pytest.mark.parametrize("H,S,offset,pads,bq,bk", _PREFILL_CASES)
+def test_prefill_kernel_matches_dense_attention(H, S, offset, pads, bq, bk):
+    """The kernel takes the latent rows and the weights; the reference
+    expands keys and values by einsum, as the caller did before PR 44."""
+    from vnsum_tpu.ops.mla_attention import mla_prefill_attention
+
+    qn, qr, lat, wk, wv, kn, kr, v = _prefill_operands(H, S, offset + S)
+    pad = jnp.asarray(pads, jnp.int32)
+    got = mla_prefill_attention(qn, qr, lat, wk, wv, pad, scale=0.2,
+                                q_offset=offset, block_q=bq, block_k=bk,
+                                interpret=True)
+    want, real = _dense_prefill(qn, qr, kn, kr, v, pad, offset, 0.2)
+    for b in range(qn.shape[0]):
         np.testing.assert_allclose(
             np.asarray(got)[b][:, real[b]], np.asarray(want)[b][:, real[b]],
             atol=2e-5)
     assert np.isfinite(np.asarray(got)).all()
+
+
+def test_a_key_block_under_the_pad_expands_nothing():
+    """Row 1's pad covers key block 0 whole: the kernel neither fetches nor
+    expands it, so NaN latent rows there reach no output; and rows of the
+    partial last block past the keys' end are zeroed before the expansion
+    (the interpreter fills them with NaN)."""
+    from vnsum_tpu.ops.mla_attention import mla_prefill_attention
+
+    H, S, offset, pads, bq, bk = 3, 96, 64, [0, 140], 32, 128
+    qn, qr, lat, wk, wv, kn, kr, v = _prefill_operands(H, S, offset + S)
+    pad = jnp.asarray(pads, jnp.int32)
+    want, real = _dense_prefill(qn, qr, kn, kr, v, pad, offset, 0.2)
+    poisoned = lat.at[1, :bk].set(jnp.nan)
+    got = mla_prefill_attention(qn, qr, poisoned, wk, wv, pad, scale=0.2,
+                                q_offset=offset, block_q=bq, block_k=bk,
+                                interpret=True)
+    assert np.isfinite(np.asarray(got)).all()
+    for b in range(2):
+        np.testing.assert_allclose(
+            np.asarray(got)[b][:, real[b]], np.asarray(want)[b][:, real[b]],
+            atol=2e-5)
 
 
 def _brute_force_tiles(pads, S, T, offset, bq, bk):
@@ -189,6 +227,99 @@ def test_prefill_tile_classes_match_a_brute_force_mask(H, S, offset, pads,
     assert got["scores_needed"] == int(needed.sum())
     assert got["scores_computed"] == (
         (want["interior"] + want["masked"]) * int(np.prod(got["tile"])))
+
+
+def _keys_expanded_by_brute_force(pads, S, T, offset, bq, bk):
+    """Keys a call expands a head, from the mask itself: bk for every
+    (bq x bk) tile that holds a score some query may see."""
+    tiles = _brute_force_tiles(pads, S, T, offset, bq, bk)
+    return bk * (tiles["interior"] + tiles["masked"])
+
+
+@pytest.mark.parametrize("H,S,offset,pads,bq,bk", _PREFILL_CASES)
+def test_latent_keys_expanded_match_a_brute_force_mask(H, S, offset, pads,
+                                                       bq, bk):
+    """The counter of PR 44: a computed tile expands its key block, a dead
+    one nothing; the family's hook counts the same at the wrapper's own
+    geometry, x layers, beside the keys the rows have."""
+    from vnsum_tpu.ops.mla_attention import prefill_tile_classes
+
+    T = offset + S
+    got = prefill_tile_classes(pads, S, T, offset, block_q=bq, block_k=bk)
+    assert got["keys_expanded"] == _keys_expanded_by_brute_force(
+        pads, S, T, offset, *got["tile"])
+    cfg = ds.tiny_deepseek()
+    counted = ds.prefill_counts(cfg, pads, [(offset, T)])
+    tile = prefill_tile_classes(pads, S, T, offset)["tile"]
+    assert counted["latent_keys_expanded"] == cfg.n_layers * (
+        _keys_expanded_by_brute_force(pads, S, T, offset, *tile))
+    assert counted["latent_keys_real"] == cfg.n_layers * sum(
+        max(T - pad, 0) for pad in pads)
+
+
+def test_a_map_dispatch_expands_every_key_four_and_a_half_times():
+    """The cell's map dispatch: rows of ~7.8k tokens behind their pads in
+    the 8192 bucket, eight chunks of 1,024. Chunk c reads the c + 1 key
+    blocks before its end, so a full row's 8 blocks are expanded 36 times:
+    the kernel hides the redundancy under its softmax, it does not remove
+    it."""
+    cfg = ds.deepseek_v2(n_layers=8)
+    spans = [(lo, lo + 1024) for lo in range(0, 8192, 1024)]
+    full = ds.prefill_counts(cfg, [0], spans)
+    assert full == {"latent_keys_expanded": 8 * 36 * 1024,
+                    "latent_keys_real": 8 * 8192}
+    pads = [392 + 16 * r for r in range(24)]            # 7,800 tokens and fewer
+    got = ds.prefill_counts(cfg, pads, spans)
+    ratio = got["latent_keys_expanded"] / got["latent_keys_real"]
+    assert got["latent_keys_real"] == 8 * sum(8192 - p for p in pads)
+    assert 4.5 < ratio < 5.0
+    # a row wholly under its pad (a batch's filler row) expands nothing
+    assert ds.prefill_counts(cfg, [8192], spans) == {
+        "latent_keys_expanded": 0, "latent_keys_real": 0}
+
+
+def test_a_piece_of_the_prefill_attention_is_four_rows_at_the_cells_shapes():
+    """A piece holds queries and outputs alone (168 MB a row at 1,024
+    queries of 128 heads): 4 rows of the map dispatch's 24 under ~0.8 GB,
+    whatever the number of keys; it was 1 row while a piece held a row's
+    expanded keys and values (704 MB at 8,192 keys). Six rows, the issue's
+    reckoning at the old 1.2 GB, cost 114 MB more of temporaries on the
+    chip and bought nothing (PERF.md section 6, PR 44)."""
+    cfg = ds.deepseek_v2()
+    assert ds._rows_a_piece(cfg, 24, 1024) == 4
+    assert ds._rows_a_piece(cfg, 4, 1024) == 4          # the reduce: one piece
+    assert ds._rows_a_piece(cfg, 1, 1024) == 1          # parity's row
+    assert ds._rows_a_piece(cfg, 24, 2048) == 2
+
+
+@pytest.mark.parametrize("offset,pads", [(0, [0, 17]), (128, [0, 150])])
+def test_int8_leaves_scales_are_folded_outside_the_kernel(offset, pads):
+    """``prefill_attention`` with int8 ``wk_b`` / ``wv_b``: the kernel gets
+    the leaves' integers in the latent's type, the scales multiply the
+    queries and the output (``_expanded_attention``'s rule), and the result
+    is the dense path's over the same leaves."""
+    cfg = ds.tiny_deepseek()
+    params = quantize_params(ds.init_params(jax.random.key(3), cfg))
+    lp = jax.tree.map(lambda w: w[0], params["layers"])
+    assert isinstance(lp["wk_b"], dict) and isinstance(lp["wv_b"], dict)
+    B, S, C = 2, 128, 256                # offset + S <= C
+    ks = jax.random.split(jax.random.key(offset), 2)
+    c_q = jax.random.normal(ks[0], (B, S, cfg.q_lora_rank), cfg.dtype)
+    cache = {"latent": jax.random.normal(
+        ks[1], (cfg.n_layers, B, C, cfg.latent_width), cfg.dtype)}
+    pad = jnp.asarray(pads, jnp.int32)
+    positions = jnp.maximum(offset + jnp.arange(S)[None, :] - pad[:, None], 0)
+    rope = ds.rope_cos_sin(cfg, positions)
+    q_pos = offset + jnp.arange(S)[None, :, None]
+    k_pos = jnp.arange(C)[None, None, :]
+    mask = (k_pos <= q_pos) & (k_pos >= pad[:, None, None])
+    got = ds.prefill_attention(cfg, pad, offset, interpret=True).attend(
+        c_q, rope, cache, 1, lp, False)
+    want = ds.dense_attention(cfg, mask).attend(c_q, rope, cache, 1, lp, False)
+    real = np.asarray(q_pos[0, :, 0][None] >= pad[:, None])
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[real], np.asarray(want, np.float32)[real],
+        atol=2e-2, rtol=2e-2)
 
 
 @pytest.mark.parametrize("pad,waste", [(0, 1.13), (392, 1.25)])
@@ -542,6 +673,37 @@ def test_generate_runs_the_kernels_and_counts_the_experts(tiny):
     assert int(np.sum(st.expert_tokens)) == st.expert_slots_held
     be.generate(["them"], max_new_tokens=8)                 # they add up
     assert int(np.sum(st.expert_tokens)) == st.expert_slots_held > tokens * 6
+
+
+def test_a_dispatch_counts_the_keys_its_prefill_expanded(tiny):
+    """``EngineStats.prefill_blocks`` and the dispatch's INFO line carry the
+    family's own counts (``Family.prefill_counts``), though it counts no GQA
+    cell: host arithmetic on the pads the dispatch was packed with."""
+    import logging
+
+    cfg, params = tiny
+    be = _engine(cfg, params, prefill_chunk_tokens=128)
+    lines = []
+    handler = logging.Handler()
+    handler.emit = lambda record: lines.append(record.getMessage())
+    logger = logging.getLogger("vnsum.engine")
+    logger.addHandler(handler)
+    try:
+        be.generate(["xin chao " * 30, "hello"], max_new_tokens=8)
+    finally:
+        logger.removeHandler(handler)
+    assert not family_of(cfg).counts_prefill_blocks
+    S = 248                          # the first prompt fills its bucket
+    pads = [0, S - (be.stats.prompt_tokens - S)]
+    want = ds.prefill_counts(cfg, pads, [(0, 128), (128, S)])
+    assert be.stats.prefill_blocks == want
+    assert want["latent_keys_real"] == cfg.n_layers * be.stats.prompt_tokens
+    said = [ln for ln in lines if ln.startswith("dispatch B=2 S=248")]
+    assert len(said) == 1
+    assert (f"latent_keys_expanded {want['latent_keys_expanded']}, "
+            f"latent_keys_real {want['latent_keys_real']}") in said[0]
+    be.generate(["them"], max_new_tokens=8)                 # they add up
+    assert be.stats.prefill_blocks["latent_keys_real"] > want["latent_keys_real"]
 
 
 def test_a_share_counts_only_the_experts_it_holds():

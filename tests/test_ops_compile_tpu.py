@@ -56,17 +56,18 @@ def _compiled(fn, one_chip, *shapes):
     (1024, 3072),   # parity's last chunk: 4,096 keys
 ])
 def test_prefill_kernel_compiles_at_the_published_widths(one_chip, S, offset):
-    """With the geometry the wrapper chooses (a group of heads a step, key
-    blocks wider than query blocks): one that overflows scoped VMEM fails
-    here and not on the chip."""
+    """With the geometry the wrapper chooses (a group of heads a step, their
+    blocks of ``W_kb`` / ``W_vb`` beside a latent block whose lanes are
+    sliced at 512): one that overflows scoped VMEM fails here and not on
+    the chip."""
     from vnsum_tpu.ops.mla_attention import mla_prefill_attention
 
-    R, H, T = 1, 128, offset + S
+    R, H, T = 4, 128, offset + S
     c = _compiled(
-        lambda qn, qr, kn, kr, v, p: mla_prefill_attention(
-            qn, qr, kn, kr, v, p, scale=0.1147, q_offset=offset),
+        lambda qn, qr, lat, wk, wv, p: mla_prefill_attention(
+            qn, qr, lat, wk, wv, p, scale=0.1147, q_offset=offset),
         one_chip, ((R, H, S, 128), BF16), ((R, H, S, 64), BF16),
-        ((R, H, T, 128), BF16), ((R, T, 64), BF16), ((R, H, T, 128), BF16),
+        ((R, T, 576), BF16), ((H, 512, 128), BF16), ((H, 512, 128), BF16),
         ((R,), I32))
     assert "tpu_custom_call" in c.as_text()
 

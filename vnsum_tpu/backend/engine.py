@@ -113,7 +113,12 @@ class EngineStats:
     # Where the family runs a scan (``Family.prefill_counts``):
     # ``scan_tokens_real`` (real prompt tokens x its scan layers) and
     # ``scan_tokens_computed`` (tokens of the chunks the scan kernel did not
-    # skip x those layers)
+    # skip x those layers). Where its prefill kernel expands keys and values
+    # from a latent cache (the same hook; such a family counts no GQA cell):
+    # ``latent_keys_real`` (the rows' keys x layers) and
+    # ``latent_keys_expanded`` (the keys of the key blocks the kernel's
+    # computed tiles read, each expanded in VMEM, a head x layers: a chunked
+    # prefill expands a key once for every chunk that reads it)
     prefill_blocks: dict = field(default_factory=dict)
     # which attention each built program got, keyed "program[B=..,S=..]" →
     # {"prefill"|"decode": "kernel"|"dense"}: a dense fallback (unaligned
@@ -842,25 +847,31 @@ class TpuBackend:
         return [(lo, min(S, lo + CL)) for lo in range(start, S, CL)]
 
     def _count_prefill_blocks(self, pad_lens, S: int, C: int,
-                              start: int = 0) -> None:
+                              start: int = 0) -> str:
         """Add one dispatch's prefill-kernel cells, by class, to
         ``stats.prefill_blocks``. Pure host arithmetic on the pads the
-        dispatch was packed with; nothing when its prefill is dense."""
-        if (not self._decode_settings(S, C)[0]
-                or not self.family.counts_prefill_blocks):
-            return
+        dispatch was packed with; nothing when its prefill is dense.
+        Returns what the dispatch's log line says of the family's own
+        counts."""
+        if not self._decode_settings(S, C)[0]:
+            return ""
+        cfg = self.cfg
+        total = self.stats.prefill_blocks
+        said = ""
+        if self.family.prefill_counts is not None:
+            # what the family counts from the pads itself (a scan's tokens,
+            # the keys a latent kernel expands)
+            for name, n in self.family.prefill_counts(
+                    cfg, pad_lens, self._prefill_spans(S, start)).items():
+                total[name] = total.get(name, 0) + n
+                said += f", {name} {n}"
+        if not self.family.counts_prefill_blocks:
+            return said
         from ..ops.flash_attention import (
             BLOCK_CLASSES,
             prefill_block_class_grid,
         )
 
-        cfg = self.cfg
-        total = self.stats.prefill_blocks
-        if self.family.prefill_counts is not None:
-            # what else the family counts from the pads (a scan's tokens)
-            for name, n in self.family.prefill_counts(
-                    cfg, pad_lens, self._prefill_spans(S, start)).items():
-                total[name] = total.get(name, 0) + n
         # the layers that attend: all of them, or a family's few
         attending = self.family.attention_layers(cfg)
         windows = self.family.layer_windows(cfg) or (0,) * attending
@@ -889,6 +900,7 @@ class TpuBackend:
                 for name, n in (("window_scores_computed", computed),
                                 ("window_scores_needed", int(seen.sum()))):
                     total[name] = total.get(name, 0) + n * heads
+        return said
 
     # -- constrained choice scoring --------------------------------------
 
@@ -1889,7 +1901,7 @@ class TpuBackend:
                                 # with the tokens: one fetch brought both
                                 out, counted = out
                                 grid = self._add_expert_counts(counted)
-                            self._count_prefill_blocks(
+                            grid += self._count_prefill_blocks(
                                 pad_lens, S, S + max_new, K)
                         self.stats.batches += 1
                         self.stats.by_bucket[(B, S)] = (

@@ -19,8 +19,11 @@ plain float32):
   heads share; per head ``k_nope = c_kv W_kb``, ``v = c_kv W_vb``; ``score =
   (q_nope k_nope + q_rope k_rope) scale``. The cache holds ``(c_kv,
   k_rope)`` only: ``[L, B, C, kv_lora_rank + qk_rope_head_dim]``. Prefill
-  expands keys and values from it, a few batch rows at a time
-  (``prefill_attention``); decode is ABSORBED (``decode_attention``):
+  hands the kernel the latent rows and ``W_kb`` / ``W_vb``, a few batch
+  rows at a time, and the kernel expands a key block's keys and values in
+  VMEM: none is written to HBM (``prefill_attention``; the dense XLA path
+  of small sizes expands them by einsum, ``_expanded_attention``); decode
+  is ABSORBED (``decode_attention``):
   ``q_lat = q_nope W_kb^T``, attention of all heads over the latent rows,
   ``out = o_lat W_vb`` — keys and values are never expanded in a step.
 - **FFN.** The first ``first_k_dense_replace`` layers are a dense SwiGLU.
@@ -348,6 +351,14 @@ def _project_out(attn, lp, aq: bool):
         return _proj("bshk,hkd->bsd", attn.transpose(0, 2, 1, 3), lp["wo"], aq)
 
 
+def _scaled(x, s):
+    """x [B, H, S, k] times an int8 leaf's scale s [H, k] a head and
+    channel; a plain leaf has none."""
+    if s is None:
+        return x
+    return (x.astype(jnp.float32) * s[None, :, None, :]).astype(x.dtype)
+
+
 def _expanded_attention(q_nope, q_rope, lat, lp, cfg, attention):
     """Attention over keys and values expanded from latent rows ``lat``
     [B, T, rank + rope]; ``attention(q_nope, q_rope, k_nope, k_rope, v)``.
@@ -356,15 +367,9 @@ def _expanded_attention(q_nope, q_rope, lat, lp, cfg, attention):
     expansion itself is one product with no epilogue."""
     c, kr = lat[..., :cfg.kv_lora_rank], lat[..., cfg.kv_lora_rank:]
     (wk, sk), (wv, sv) = _leaf(lp["wk_b"]), _leaf(lp["wv_b"])
-    if sk is not None:
-        q_nope = (q_nope.astype(jnp.float32) * sk[None, :, None, :]
-                  ).astype(q_nope.dtype)
     k_nope = jnp.einsum("btc,chk->bhtk", c, wk.astype(c.dtype))
     v = jnp.einsum("btc,chk->bhtk", c, wv.astype(c.dtype))
-    out = attention(q_nope, q_rope, k_nope, kr, v)
-    if sv is not None:
-        out = (out.astype(jnp.float32) * sv[None, :, None, :]).astype(out.dtype)
-    return out
+    return _scaled(attention(_scaled(q_nope, sk), q_rope, k_nope, kr, v), sv)
 
 
 def dense_attention(cfg: DeepseekV2Config, mask) -> LatentAttention:
@@ -392,37 +397,33 @@ def dense_attention(cfg: DeepseekV2Config, mask) -> LatentAttention:
     return LatentAttention(attend)
 
 
-def _rows_a_piece(cfg: DeepseekV2Config, B: int, S: int, T: int) -> int:
+def _rows_a_piece(cfg: DeepseekV2Config, B: int, S: int) -> int:
     """Batch rows one piece of the prefill attention takes: as many as keep
     its arrays — S queries a head (projected, split, rotated) and the
-    output, T expanded keys and values a head — under ~1.2 GB. Widths count
-    in whole lanes of 128, as the device lays them out."""
+    output; the kernel expands keys and values itself — under ~0.8 GB.
+    Widths count in whole lanes of 128, as the device lays them out. (Four
+    rows of the cell's 24: at six the program's temporaries were 114 MB more
+    and the dispatch no faster; PERF.md section 6, PR 44.)"""
     lanes = lambda w: -(-w // 128) * 128  # noqa: E731
     dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    row = cfg.n_heads * jnp.dtype(cfg.dtype).itemsize * (
-        S * (lanes(cfg.head_dim) + lanes(dn) + lanes(dr) + lanes(dv))
-        + T * (lanes(dn) + lanes(dv)))
-    limit = max(1, int(1.2e9) // row)
+    row = cfg.n_heads * jnp.dtype(cfg.dtype).itemsize * S * (
+        lanes(cfg.head_dim) + lanes(dn) + lanes(dr) + lanes(dv))
+    limit = max(1, int(0.8e9) // row)
     return max(r for r in range(1, B + 1) if B % r == 0 and r <= limit)
 
 
 def prefill_attention(cfg: DeepseekV2Config, pad_lens, q_offset: int, *,
                       interpret: bool) -> LatentAttention:
     """The prefill attention of one chunk whose queries start at cache slot
-    ``q_offset``: for a few batch rows at a time, the queries are projected
-    up, keys and values are expanded from the latent (whole, 24 rows of
-    8,192 would be 12.9 GB a layer) and go through
-    ``mla_prefill_attention``, and the output is projected."""
+    ``q_offset``: the layer's ``wk_b`` / ``wv_b`` are laid out a head once,
+    then for a few batch rows at a time the queries are projected up and go
+    with the rows' latent slots through ``mla_prefill_attention``, which
+    expands a key block's keys and values in VMEM (whole in HBM, 24 rows of
+    8,192 would be 12.9 GB a layer), and the output is projected. An int8
+    leaf's scale lies on a channel the attention keeps, so it multiplies
+    the queries (keys) and the output (values): ``_expanded_attention``'s
+    rule."""
     from ..ops.mla_attention import mla_prefill_attention
-
-    def attention(pads):
-        def kernel(q_nope, q_rope, k_nope, k_rope, v):
-            return mla_prefill_attention(
-                q_nope, q_rope, k_nope, k_rope, v, pads,
-                scale=cfg.softmax_scale, q_offset=q_offset,
-                interpret=interpret)
-
-        return kernel
 
     def attend(c_q, rope, cache, layer_idx, lp, aq):
         B, S, _ = c_q.shape
@@ -430,14 +431,20 @@ def prefill_attention(cfg: DeepseekV2Config, pad_lens, q_offset: int, *,
         lat = jax.lax.dynamic_slice(
             cache["latent"], (layer_idx, 0, 0, 0),
             (1, B, T, cfg.latent_width))[0]
-        R = _rows_a_piece(cfg, B, S, T)
+        (wk, sk), (wv, sv) = _leaf(lp["wk_b"]), _leaf(lp["wv_b"])
+        with jax.named_scope("attn"):
+            # [rank, H, k] -> a head's [rank, k] block, in the latent's type
+            wk, wv = (w.astype(lat.dtype).transpose(1, 0, 2) for w in (wk, wv))
+        R = _rows_a_piece(cfg, B, S)
 
         def piece(args):
             c_q, cos, sin, lat, pads = args
             q_nope, q_rope = _queries(c_q, (cos, sin), lp, aq, cfg)
             with jax.named_scope("attn"):
-                attn = _expanded_attention(
-                    q_nope, q_rope, lat, lp, cfg, attention(pads))
+                attn = _scaled(mla_prefill_attention(
+                    _scaled(q_nope, sk), q_rope, lat, wk, wv, pads,
+                    scale=cfg.softmax_scale, q_offset=q_offset,
+                    interpret=interpret), sv)
             return _project_out(attn, lp, aq)
 
         args = (c_q, *rope, lat, pad_lens)
@@ -615,6 +622,26 @@ def forward_dense(params: dict, cfg: DeepseekV2Config, tokens) -> jax.Array:
 # -- the engine's seam (models/family.py) -------------------------------------
 
 
+def prefill_counts(cfg: DeepseekV2Config, pad_lens, spans) -> dict:
+    """What the prefill kernel of one dispatch expanded from the latent, a
+    head, from the pads it was packed with: ``latent_keys_expanded`` (the
+    keys of the key blocks its computed tiles read: every chunk expands
+    again what the chunks before it wrote) and ``latent_keys_real`` (the
+    keys the rows have: what expanding each once would take), both x
+    layers. ``spans`` are the prefill's query spans [lo, hi) over the
+    bucket; a span's call sees the ``hi`` keys before its end."""
+    import numpy as np
+
+    from ..ops.mla_attention import prefill_tile_classes
+
+    expanded = sum(
+        prefill_tile_classes(pad_lens, hi - lo, hi, lo)["keys_expanded"]
+        for lo, hi in spans)
+    real = np.clip(spans[-1][1] - np.asarray(pad_lens, np.int64), 0, None)
+    return {"latent_keys_expanded": expanded * cfg.n_layers,
+            "latent_keys_real": int(real.sum()) * cfg.n_layers}
+
+
 def _forward_kwargs(cfg: DeepseekV2Config, kernels: bool, interpret: bool):
     if not kernels:
         return {}   # flash=False: dense attention and dense_experts
@@ -642,7 +669,8 @@ def _family():
             cfg, pad_lens, q_offset, interpret=interpret),
         decode_attention=lambda cfg, mesh, interpret, pad_lens, S, t,
         window: decode_attention(cfg, pad_lens, S, t, interpret=interpret),
-        int8_cache=False, forward_kwargs=_forward_kwargs, counters=counters,
+        int8_cache=False, prefill_counts=prefill_counts,
+        forward_kwargs=_forward_kwargs, counters=counters,
         row_record=last_picks,
         missing={
             "slot loop": slot_loop,

@@ -57,7 +57,9 @@ class Family:
     attention_layers: Callable = lambda cfg: cfg.n_layers
     # (cfg, pad_lens, prefill spans) -> {name: count} a dispatch's prefill
     # adds to ``EngineStats.prefill_blocks`` beside the attention's cells,
-    # from the pads it was packed with (a scan's tokens), or None
+    # from the pads it was packed with (a scan's tokens, the keys a latent
+    # kernel expands), or None; counted whether or not
+    # ``counts_prefill_blocks``
     prefill_counts: Callable | None = None
     # (cfg, kernels on, interpret) -> further keywords of ``forward``
     forward_kwargs: Callable = lambda cfg, kernels, interpret: {}

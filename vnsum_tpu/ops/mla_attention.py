@@ -5,16 +5,26 @@ per token (models/deepseek.py: 512 of normalised ``c_kv`` + 64 of rotated
 ``k_rope``), shared by every head. Two kernels read it, one per phase:
 
 - ``mla_prefill_attention``: causal attention over keys and values that the
-  caller EXPANDED from the latent for a piece of the batch (``k_nope`` and
-  ``v`` per head, ``k_rope`` once for all heads). The query/key width
-  (nope + rope = 192) differs from the value width (128), which the GQA
-  kernels of ops/flash_attention.py cannot express. Left-padded rows and
-  chunked prefill (``q_offset``) as there: a block above the diagonal or
-  under a row's pad is neither fetched nor computed, a block that needs no
-  mask builds none (_tile_class; ``prefill_tile_classes`` counts a call's
-  tiles on the host by the same rule). A grid step holds a GROUP of heads
-  against one ``k_rope`` block, as a GQA group shares its K/V block there,
-  and a head computes a (1024, 1024) tile of scores at a time.
+  KERNEL expands from the latent rows, a key block and a head at a time, in
+  VMEM (``k_nope = c_kv W_kb`` and ``v = c_kv W_vb`` per head, rounded to
+  the inputs' type; ``k_rope`` is the block's last lanes, shared by all
+  heads): nothing expanded is written to HBM. The query/key width (nope +
+  rope = 192) differs from the value width (128), which the GQA kernels of
+  ops/flash_attention.py cannot express. Left-padded rows and chunked
+  prefill (``q_offset``) as there: a block above the diagonal or under a
+  row's pad is neither fetched nor expanded nor computed, a block that
+  needs no mask builds none (_tile_class; ``prefill_tile_classes`` counts a
+  call's tiles and the keys it expands on the host by the same rule). A
+  grid step holds a GROUP of heads (their queries and their blocks of
+  ``W_kb`` / ``W_vb``) against one latent block, as a GQA group shares its
+  K/V block there, and a head computes a (1024, 1024) tile of scores at a
+  time. A chunked prefill calls it once a chunk, so a row's keys are
+  expanded once for every chunk that reads them. The kernel's pace is its
+  products' (a head and tile: 8 passes of 1,024 rows through the 128 x 128
+  units for the expansion, 8 + 8 for the scores — the 64-wide rope product
+  costs what a 128-wide one does — and 8 for the values); the softmax's
+  vector work runs beside them, for which a loop step is WRITTEN products
+  first (for_each_head).
 - ``mla_decode_attention``: the ABSORBED decode step. The caller folds
   ``W_kvb``'s key half into the query (``q_lat = q_nope . W_k^T``, 512 wide)
   and the kernel is multi-query attention of all heads over the one latent
@@ -46,9 +56,13 @@ VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 # PR 34). The tile of scores one head computes at a time sets the pace, and a
 # (1024, 1024) tile costs half of a (512, 512) one a score (5.4 against 10.7 ns
 # per 1,024); wider key blocks, sub-tiles of an edge block and a tile of 512
-# queries all cost more. The heads of a group share the step's k_rope block,
+# queries all cost more. The heads of a group share the step's latent block,
 # mask and positions, which buys 1-3%; they go two at a time, which buys 3%
-# more where four at a time spill (+50%).
+# more where four at a time spill (+50%; +35-47% with the expansion inside,
+# PR 44, at a scoped limit raised to fit them). The pace itself is the matrix
+# units' — 32 passes of 1,024 rows a head and tile, 8 of them the expansion's
+# — so past this cell what counts is the order a step is written in
+# (for_each_head).
 _BLOCK = 1024
 # the widest group whose step fits 48 MiB of scoped VMEM at that tile (16
 # heads do not compile)
@@ -84,10 +98,10 @@ def _tile_class(q_start, k_start, pad, rows: int, cols: int):
     return above, under, interior
 
 
-def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
+def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, lat_ref, wk_ref, wv_ref,
                     o_ref, acc_ref, m_ref, l_ref, *, group: int, block_q: int,
-                    block_k: int, n_keys: int, scale: float):
-    # qn [1,G,bq,dn] qr [1,G,bq,dr] kn/v [1,G,bk,dn|dv] kr [1,bk,dr];
+                    block_k: int, n_keys: int, rank: int, scale: float):
+    # qn [1,G,bq,dn] qr [1,G,bq,dr] lat [1,bk,rank+dr] wk/wv [G,rank,dn|dv];
     # acc [G*bq,dv], m/l [G*bq,LANES]: a head's state is a slice of rows
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
@@ -95,16 +109,26 @@ def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
     q_start = off_ref[0] + i * block_q
     k_start = j * block_k
 
-    def for_each_head(body):
-        """``body(g, rows)`` for the group's heads, a few of them unrolled
-        into one loop step: code size and compile time stay those of
-        _HEADS_UNROLLED heads, whatever the group."""
+    def rows_of(g):
+        return pl.ds(pl.multiple_of(g * block_q, block_q), block_q)
+
+    def for_each_head(front, back):
+        """``front(g)`` for a few of the group's heads, then ``back(g,
+        front's result)`` for the same heads, unrolled into one loop step:
+        code size and compile time stay those of _HEADS_UNROLLED heads,
+        whatever the group. The order is the point. Mosaic runs a step
+        much as it is written, and work overlaps what stands next to it:
+        with every head's products (``front``: its keys and scores) written
+        before the first head's softmax (``back``), that softmax runs
+        beside the next head's products and not after them — 6.90 -> 6.14
+        ms a row of 8,192 keys on the v5e (PERF.md section 6, PR 44)."""
         n = _HEADS_UNROLLED if group % _HEADS_UNROLLED == 0 else 1
 
         def several(step, _):
-            for u in range(n):
-                g = step * n + u
-                body(g, pl.ds(pl.multiple_of(g * block_q, block_q), block_q))
+            heads = [step * n + u for u in range(n)]
+            held = [front(g) for g in heads]
+            for g, h in zip(heads, held):
+                back(g, h)
 
         jax.lax.fori_loop(0, group // n, several, None)
 
@@ -115,10 +139,11 @@ def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def _accumulate(masked: bool):
-        # what does not hang on the head is built once a step: the shared
-        # k_rope block, positions, the mask, the zeroing of a ragged tail
-        kr = kr_ref[0]
-        mask = v_ok = None
+        # what does not hang on the head is built once a step: the latent
+        # block's two halves, positions, the mask, the zeroing of a ragged
+        # tail
+        blk = lat_ref[0]                                     # [bk, rank+dr]
+        mask = None
         if masked:
             shape = (block_q, block_k)
             q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
@@ -127,24 +152,32 @@ def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
             if n_keys % block_k:
                 # a partial last key block holds stale memory past the
                 # keys' end: masked scores there are selected away, but a
-                # probability of 0 times a stale NaN value is NaN. A block
-                # that reaches past the keys' end is never interior
-                v_ok = k_start + jax.lax.broadcasted_iota(
+                # probability of 0 times a stale NaN value is NaN, so the
+                # rows are zeroed before anything is expanded from them. A
+                # block that reaches past the keys' end is never interior
+                row_ok = k_start + jax.lax.broadcasted_iota(
                     jnp.int32, (block_k, 1), 0) < n_keys
+                blk = jnp.where(row_ok, blk, jnp.zeros_like(blk))
+        c, kr = blk[:, :rank], blk[:, rank:]
 
-        def _head(g, rows):
+        def expanded(w_ref, g):
+            # the head's keys or values of this block, rounded as the
+            # caller's einsum rounded them when it expanded them into HBM
+            return jnp.dot(c, w_ref[g], preferred_element_type=jnp.float32
+                           ).astype(c.dtype)                 # [bk, dn | dv]
+
+        def _scores(g):
             s = jax.lax.dot_general(
-                qn_ref[0, g], kn_ref[0, g], (((1,), (1,)), ((), ())),
+                qn_ref[0, g], expanded(wk_ref, g), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             s = s + jax.lax.dot_general(
                 qr_ref[0, g], kr, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
             s = s * scale                                    # [bq, bk]
-            v = v_ref[0, g]
-            if masked:
-                s = jnp.where(mask, s, _NEG)
-                if v_ok is not None:
-                    v = jnp.where(v_ok, v, jnp.zeros_like(v))
+            return jnp.where(mask, s, _NEG) if masked else s
+
+        def _attend(g, s):
+            rows = rows_of(g)
             m_prev = m_ref[rows, :1]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
@@ -152,12 +185,14 @@ def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
             l_ref[rows] = jnp.broadcast_to(
                 alpha * l_ref[rows, :1] + jnp.sum(p, axis=-1, keepdims=True),
                 (block_q, l_ref.shape[1]))
+            # the values are expanded here, beside the exponentials
+            v = expanded(wv_ref, g)
             acc_ref[rows] = alpha * acc_ref[rows] + jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
             m_ref[rows] = jnp.broadcast_to(m_new, (block_q, m_ref.shape[1]))
 
-        for_each_head(_head)
+        for_each_head(_scores, _attend)
 
     above, under, interior = _tile_class(q_start, k_start, pad,
                                          block_q, block_k)
@@ -179,12 +214,12 @@ def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref,
             o_ref[0, g] = (acc_ref[rows] / jnp.maximum(l_ref[rows, :1], 1e-30)
                            ).astype(o_ref.dtype)
 
-        for_each_head(_store)
+        for_each_head(rows_of, _store)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "scale", "q_offset", "block_q", "block_k", "interpret"))
-def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
+def mla_prefill_attention(q_nope, q_rope, latent, wk, wv, pad_lens, *,
                           scale: float, q_offset: int = 0,
                           block_q: int | None = None,
                           block_k: int | None = None,
@@ -192,16 +227,21 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
     """Causal attention of ``S`` queries at cache slots ``[q_offset,
     q_offset + S)`` over the ``T = q_offset + S`` keys before them.
 
-    q_nope [B, H, S, dn], q_rope [B, H, S, dr]; k_nope [B, H, T, dn] and
-    v [B, H, T, dv] are each head's keys and values expanded from the
-    latent; k_rope [B, T, dr] is the one rotated key all heads share;
+    q_nope [B, H, S, dn], q_rope [B, H, S, dr]; latent [B, T, rank + dr]
+    is the cache's rows of those slots (``c_kv`` then the one rotated key
+    all heads share); wk [H, rank, dn] and wv [H, rank, dv] are the two
+    halves of ``W_kvb`` a head, in the latent's type: the kernel expands a
+    key block's ``k_nope = c_kv wk[h]`` and ``v = c_kv wv[h]`` itself;
     pad_lens [B] left pads. Returns [B, H, S, dv]. The cell is chosen from
     the shapes (_prefill_geometry); ``block_q``/``block_k`` are for tests."""
     B, H, S, dn = q_nope.shape
     dr = q_rope.shape[-1]
-    T, dv = v.shape[2], v.shape[3]
+    T, rank, dv = latent.shape[1], wk.shape[1], wv.shape[2]
     if T != q_offset + S:
         raise ValueError(f"{T} keys for queries at [{q_offset}, {q_offset + S})")
+    if latent.shape[2] != rank + dr:
+        raise ValueError(f"latent rows of {latent.shape[2]} for rank {rank} "
+                         f"and {dr} rotated lanes")
     # whole blocks at the engine's shapes (chunks and buckets are multiples
     # of 512 there); any other length gets a partial last block
     G, bq, bk = _prefill_geometry(H, S, T, block_q, block_k)
@@ -216,7 +256,7 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
 
     kernel = functools.partial(
         _prefill_kernel, group=G, block_q=bq, block_k=bk, n_keys=T,
-        scale=scale)
+        rank=rank, scale=scale)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -227,15 +267,14 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
                              lambda b, h, i, j, pad, o: (b, h, i, 0)),
                 pl.BlockSpec((1, G, bq, dr),
                              lambda b, h, i, j, pad, o: (b, h, i, 0)),
-                pl.BlockSpec((1, G, bk, dn),
-                             lambda b, h, i, j, pad, o:
-                             (b, h, visible_j(b, i, j, pad, o), 0)),
-                pl.BlockSpec((1, bk, dr),
+                pl.BlockSpec((1, bk, rank + dr),
                              lambda b, h, i, j, pad, o:
                              (b, visible_j(b, i, j, pad, o), 0)),
-                pl.BlockSpec((1, G, bk, dv),
-                             lambda b, h, i, j, pad, o:
-                             (b, h, visible_j(b, i, j, pad, o), 0)),
+                # the group's weights: fetched when the group changes
+                pl.BlockSpec((G, rank, dn),
+                             lambda b, h, i, j, pad, o: (h, 0, 0)),
+                pl.BlockSpec((G, rank, dv),
+                             lambda b, h, i, j, pad, o: (h, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, G, bq, dv),
                                    lambda b, h, i, j, pad, o: (b, h, i, 0)),
@@ -255,7 +294,7 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, pad_lens, *,
         # kernel by it
         name="mla_prefill_attention",
     )(pad_lens.astype(jnp.int32), jnp.full((1,), off, jnp.int32),
-      q_nope, q_rope, k_nope, k_rope, v)
+      q_nope, q_rope, latent, wk, wv)
 
 
 TILE_CLASSES = ("dead_causal", "dead_pad", "interior", "masked")
@@ -269,8 +308,10 @@ def prefill_tile_classes(pad_lens, S: int, T: int, q_offset: int = 0, *,
     (wholly above the diagonal, skipped before the pad is looked at),
     ``dead_pad`` (under the row's pad), ``interior`` (no mask built) and
     ``masked`` — with ``tile`` = (bq, bk), ``scores_computed`` (interior +
-    masked tiles, whole) and ``scores_needed`` (pad <= key <= query). Pure
-    numpy: the wrapper's geometry, the kernel's class rule (_tile_class)."""
+    masked tiles, whole), ``scores_needed`` (pad <= key <= query) and
+    ``keys_expanded`` (a computed tile expands its key block's bk keys and
+    values from the latent, dead tiles none). Pure numpy: the wrapper's
+    geometry, the kernel's class rule (_tile_class)."""
     import numpy as np
 
     _, bq, bk = _prefill_geometry(1, S, T, block_q, block_k)
@@ -282,7 +323,8 @@ def prefill_tile_classes(pad_lens, S: int, T: int, q_offset: int = 0, *,
     grid = np.select([above, under, interior], [0, 1, 2], default=3)
     out = {name: int((grid == c).sum()) for c, name in enumerate(TILE_CLASSES)}
     out["tile"] = (bq, bk)
-    out["scores_computed"] = (out["interior"] + out["masked"]) * bq * bk
+    out["keys_expanded"] = (out["interior"] + out["masked"]) * bk
+    out["scores_computed"] = out["keys_expanded"] * bq
     q_pos = q_offset + np.arange(S, dtype=np.int64)[None, :]
     out["scores_needed"] = int(np.maximum(q_pos - pad[:, 0] + 1, 0).sum())
     return out
