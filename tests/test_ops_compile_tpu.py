@@ -233,6 +233,34 @@ def test_gqa_prefill_kernel_compiles_at_wide_groups(one_chip, G, KV, hd,
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("G,KV,hd,geometry,cache_type", [
+    (4, 8, 128, (512, 1024), I8),     # Qwen3, the served join
+    (4, 10, 128, (512, 1024), BF16),  # Phi-4's heads over a bf16 cache
+    (3, 8, 128, (512, 2048), BF16),   # Llama-3.2's 24/8: 22 MiB of the 32
+    (2, 4, 256, (512, 1024), BF16),   # Gemma3's 256-wide heads
+])
+def test_gqa_prefill_kernel_compiles_unrolled_one_product_ahead(
+        one_chip, G, KV, hd, geometry, cache_type):
+    """Groups of up to four heads at the geometry the wrapper gives them:
+    a static unroll written one score product ahead (PR 45), so two heads'
+    [bq, bk] float32 tiles are alive where one was, inside the limit the
+    kernel asks for."""
+    from vnsum_tpu.ops import flash_attention
+
+    assert flash_attention._heads_ahead(G) == 1
+    assert flash_attention._block_geometry(2048, 8448, G, hd) == geometry
+    if cache_type == I8:
+        cache = _int8_cache(2, 2, KV, 8448, hd)
+    else:
+        cache = {"k": ((2, 2, KV, 8448, hd), BF16),
+                 "v": ((2, 2, KV, 8448, hd), BF16)}
+    c = _compiled(
+        lambda q, cache, pads, win: flash_attention.flash_prefill_attention(
+            q, cache, 1, pads, G, win, 6144),
+        one_chip, ((2, 2048, G * KV, hd), BF16), cache, ((2,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
 @pytest.mark.parametrize("G,offset", [(6, 6144), (9, 0), (9, 6144)])
 def test_gqa_prefill_kernel_compiles_at_lagunas_two_groups(one_chip, G,
                                                            offset):

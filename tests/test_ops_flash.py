@@ -132,7 +132,8 @@ def test_flash_window_zero_is_global():
 def test_supports_flash():
     assert supports_flash(1024, 1152, 128)
     assert supports_flash(1001, 1153, 256)  # any S/C via ceil-div grids
-    assert not supports_flash(1024, 1152, 64)  # head_dim not a lane multiple
+    assert supports_flash(1024, 1152, 64)  # half a lane tile: Granite-4.0-H
+    assert not supports_flash(1024, 1152, 96)  # no lane multiple, no half
 
 
 def test_forward_remat_with_attention_fn():

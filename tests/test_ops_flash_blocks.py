@@ -115,15 +115,17 @@ def test_a_group_that_fits_no_geometry_raises_with_the_numbers():
 
 
 @pytest.mark.parametrize("G,hd,bq,bk,mosaic_mib", [
-    (3, 128, 512, 2048, 20), (4, 128, 512, 1024, 13), (7, 128, 1024, 1024, 34),
+    (3, 128, 512, 2048, 22), (4, 128, 512, 1024, 14), (7, 128, 1024, 1024, 34),
     (8, 128, 1024, 1024, 33), (12, 128, 1024, 1024, 42),
     (16, 128, 1024, 1024, 53), (32, 128, 512, 1024, 47),
-    (8, 256, 1024, 512, 41),
+    (8, 256, 1024, 512, 41), (4, 64, 512, 1024, 13), (2, 256, 512, 1024, 14),
 ])
 def test_vmem_count_stands_above_what_mosaic_needs(G, hd, bq, bk, mosaic_mib):
     """_vmem_bytes against the least scoped VMEM the chip's compiler took
-    for the kernel at a bf16 cache (bisected to the MiB, PR 36): never
-    under it, at most three tenths over."""
+    for the kernel at a bf16 cache (bisected to the MiB: the looped groups
+    in PR 36; the unrolled ones in PR 45, whose order keeps two heads'
+    score tiles alive — 22 and 14 MiB where one took 20 and 13): never under
+    it, at most three tenths over."""
     counted = flash_attention._vmem_bytes(G, hd, bq, bk) / 2**20
     assert mosaic_mib <= counted <= 1.3 * mosaic_mib
 

@@ -16,32 +16,55 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   head (a head's state is BQ rows of one buffer — leading dims may MERGE
   in-kernel but never split, so row slices beat a reshape); output
   written on the last K block;
-- **the group's heads go one tile at a time** (_kernel's for_each_head):
-  K and V blocks, their casts, the value scales and the mask are built
-  once a cell, then each head computes its [BQ, BK] tile of scores against
-  them. Up to four heads are a static unroll; a wider group is a loop of
-  two heads a step (an odd head out after it), so the score temporaries
-  alive, the code size and the compile time do not grow with the group;
+- **the group's heads go one tile at a time, written one product ahead**
+  (_kernel's for_each_head): K and V blocks, their casts, the value scales
+  and the mask are built once a cell, then each head computes its [BQ, BK]
+  tile of scores against them (_scores) and runs its softmax and ``P.V``
+  over it (_attend). Up to four heads are a static unroll; a wider group is
+  a loop of two heads a step (an odd head out after it), so the score
+  temporaries alive, the code size and the compile time do not grow with
+  the group;
+- **the order a cell is written in** (PERF.md section 6, PR 45; the latent
+  kernel's finding of PR 44): Mosaic runs a cell much as it is written and
+  overlaps work with what stands next to it, no further. The unroll is
+  written ``Aq Bq A.. Cq B.. Dq C.. D..`` (q = a head's score product, ..
+  = its running max, exponentials, row sum, ``P.V`` and three state
+  stores): every softmax but the last stands beside the next head's
+  product. Kernel alone on the v5e at Qwen3's group of three map
+  dispatches (8 rows x 8 KV heads x 4, 36 layers; every output equal to
+  the head-by-head kernel's bit for bit): head by head 4.69 s, pairs
+  ``Aq Bq A.. B.. Cq Dq C.. D..`` 4.34, **one ahead 4.10 (-12.5%)**, two
+  ahead 4.68, all four products first 4.57. The loop does not take it: two
+  heads a step written products first cost 4.73 ns per 1,024 computed
+  scores for 4.60 at G=7, 4.77 for 4.60 at G=6, 5.48 for 5.46 at G=9 (and
+  5.36 for 5.13-5.19 at G=4), three a step the same, so a looped step stays
+  head by head (_heads_ahead) and its kernel is the one it was; a static
+  unroll of 6, 7 or 9 heads, or an odd head joined to the last pair, on
+  the (1024, 1024) tile costs 12-24 ns — 2.6-4.3 x;
 - **ceil-division grids with masked tails**: block sizes stay MXU-friendly
   for ANY S/C. An earlier divisor-only picker collapsed to 32-wide
   K blocks at C=2080 (8 KB DMAs) and the kernel ran 60% of total profile
   time — tail masking costs one wasted partial block instead;
 - **what binds it, measured on the v5e** (kernel alone, int8 cache, the
   benchmark's shapes: four 2048-query chunks of an S=8192 dispatch over
-  C=8448, bq 512 / bk 1024; PERF.md, PR 27): a computed cell costs
-  11.0 us (Qwen3 KV=8/G=4, 8 rows: 50.7 ms a layer over 4,608 cells;
-  Phi-4 KV=10/G=4, 12 rows: 94.8 ms over 8,640) against 5.4 us for its
-  two matrix products at the bf16 peak and 0.3 us for its 262 KB of int8
-  K and V at the HBM peak. So neither the DMA nor the MXU sets the pace
-  (an earlier machine's reading, "DMA-granularity-bound", does not hold
-  here). Taking both selects and the mask build out of EVERY cell bought
-  9% (46.1 ms), folding ``scale`` into the ``ks`` row bought nothing:
-  it is not the count of vector operations per score either. What is
-  left is the [bq, bk] f32 score tile, 2 MB a head and far beyond the
-  vector registers, going through VMEM for the row max, the exp, the row
-  sum and the cast beside the MXU's own result traffic (inferred: no
-  bundle dump was read). The lever that pays is not running a cell:
-  with a group's four tail chunks in the dispatch, 50.2 -> 35.8 ms;
+  C=8448, bq 512 / bk 1024; PERF.md, PRs 27 and 45): written head by head
+  a computed cell cost 10.5-11.0 us (Qwen3 KV=8/G=4, 8 rows: 50.7 ms a
+  layer over 4,608 cells; Phi-4 KV=10/G=4, 12 rows: 94.8 ms over 8,640),
+  **one product ahead it costs 9.2 us** (Phi-4 9.23, the served join's
+  four rows 10.0 for 11.3, Granite's 64-wide heads 9.1 for 10.5), against
+  5.4 us for its two matrix products at the bf16 peak and 0.3 us for its
+  262 KB of int8 K and V at the HBM peak. So neither the DMA nor the MXU
+  sets the pace (an earlier machine's reading, "DMA-granularity-bound",
+  does not hold here). Taking both selects and the mask build out of EVERY
+  cell bought 9% (46.1 ms), folding ``scale`` into the ``ks`` row bought
+  nothing: it is not the count of vector operations per score either.
+  What the order shows: the [bq, bk] f32 score tile's vector work (the row
+  max, the exp, the row sum, the cast, 2 MB a head through VMEM) runs
+  after the products unless a product is written next to it, and then
+  1.3 of its ~5 us hide; the last head's softmax has nothing beside it,
+  and more products ahead lose again (their tiles wait in VMEM). The lever
+  that pays most is still not running a cell: with a group's four tail
+  chunks in the dispatch, 50.2 -> 35.8 ms;
 - **block geometry** (_block_geometry, from G and hd alone). The cost a
   score follows the tile ONE head computes at a time, above all its key
   width, not the group. Swept on the v5e, kernel alone at the SmallThinker
@@ -60,7 +83,11 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   a head a step, 1.301 two, 1.28-1.32 unrolled — so groups of up to four
   keep the unroll and the geometry they had: bq=512 / bk=2048 at G<=3 (an
   earlier machine's choice), bk 1024 at G=4 and at hd=256 (Gemma3);
-  re-sweeping those is ROADMAP Queue 1 item 1d. _vmem_bytes counts what a
+  under the order above (1024, 1024) ties at G=4 (4.1035 s for 4.1051 at
+  Qwen3's group, -0.9% at Phi-4's, -0.7% at the join's, -0.4% at
+  Granite's, the control's noise 0.4-1.2%; PR 45), so the tile stays;
+  G <= 3 and hd=256 have no cell (ROADMAP Queue 1 item 1c). _vmem_bytes
+  counts what a
   geometry needs; past the 32 MiB the attention kernels share, this
   kernel alone asks for its count, and a group that fits nothing under 64
   MiB (G > 42 at hd 128) is a ValueError with the numbers;
@@ -151,6 +178,7 @@ def _kernel(
     quantized: bool,
     q_per_kv: int,
     heads_per_step: int,
+    heads_ahead: int,
 ):
     if quantized:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
@@ -180,24 +208,43 @@ def _kernel(
         block_q, block_k,
     )
 
-    def for_each_head(body):
-        """``body(g, rows)`` for the group's heads, ``heads_per_step`` of
-        them unrolled into one step of a loop: the [BQ, BK] f32 score
-        temporaries alive at a time, the code size and the compile time are
-        those of ``heads_per_step`` heads whatever the group (ops/
-        mla_attention.py has the same loop). A group of no more heads than
-        that is the plain static unroll."""
+    def for_each_head(back, front=lambda g: None):
+        """``back(g, rows, front(g))`` for the group's heads, ``rows`` the
+        head's rows of the scratch. ``heads_per_step`` heads are unrolled
+        into one step of a loop: the [BQ, BK] f32 score temporaries alive at
+        a time, the code size and the compile time are those of one step's
+        heads whatever the group (ops/mla_attention.py has the same loop). A
+        group of no more heads than that is the plain static unroll. The
+        order inside a step is the point: a head's ``front`` (its score
+        product) is written ``heads_ahead`` heads before its ``back`` (its
+        softmax and ``P.V``), because Mosaic runs a step much as it is
+        written and overlaps work with what stands next to it — that softmax
+        then runs beside the next head's product, not after it (module
+        docstring, "the order a cell is written in")."""
+        def written(heads, aligned=lambda row: row):
+            # a head's index and rows are computed as it is reached (the
+            # loop hands a generator), so a looped step traces to the
+            # equations it always had (tests/test_ops_flash_order.py)
+            held = []
+            for g in heads:
+                rows = pl.ds(aligned(g * block_q), block_q)
+                held.append((g, rows, front(g)))
+                if len(held) > heads_ahead:
+                    back(*held.pop(0))
+            for h in held:
+                back(*h)
+
         def several(step, _):
-            for u in range(heads_per_step):
-                g = step * heads_per_step + u
-                body(g, pl.ds(pl.multiple_of(g * block_q, block_q), block_q))
+            written(
+                (step * heads_per_step + u for u in range(heads_per_step)),
+                lambda row: pl.multiple_of(row, block_q),
+            )
 
         steps = q_per_kv // heads_per_step
         looped = steps * heads_per_step if steps > 1 else 0
         if looped:
             jax.lax.fori_loop(0, steps, several, None)
-        for g in range(looped, q_per_kv):  # the unroll, or an odd head out
-            body(g, pl.ds(g * block_q, block_q))
+        written(range(looped, q_per_kv))  # the unroll, or an odd head out
 
     @pl.when(j == 0)
     def _init():
@@ -255,22 +302,23 @@ def _kernel(
             )
             mask = mask & ((win == 0) | (k_pos > q_pos - win))
 
-        def _head(g, rows):
+        def _scores(g):
             # MXU inputs stay in the QUERY dtype with f32 accumulation
             # (preferred_element_type): f32 parity tests keep exact f32
             # dots, the engine's bf16 takes the native-rate MXU path. int8
             # cache values (-128..127) are exactly representable in bf16,
             # so the dequant algebra is unchanged.
-            qg = q_ref[0, 0, g]
             s = jax.lax.dot_general(
-                qg, kb, (((1,), (1,)), ((), ())),
+                q_ref[0, 0, g], kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale  # [BQ, BK] f32
             if quantized:
                 s = s * ks_ref[0, 0, kv][None, :]
             if masked:
                 s = jnp.where(mask, s, _NEG)
+            return s
 
+        def _attend(g, rows, s):
             m_prev = m_ref[rows, :1]                    # [BQ, 1]
             m_cur = jnp.max(s, axis=1, keepdims=True)   # [BQ, 1]
             m_new = jnp.maximum(m_prev, m_cur)
@@ -286,13 +334,13 @@ def _kernel(
             # adds ~0.4% relative rounding — same class as the int8 V
             # scale already applied above); accumulation stays f32
             acc_ref[rows] = acc_ref[rows] * corr + jax.lax.dot_general(
-                p.astype(qg.dtype), vb, (((1,), (0,)), ((), ())),
+                p.astype(q_ref.dtype), vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             m_ref[rows] = jnp.broadcast_to(m_new, (block_q, m_ref.shape[1]))
             l_ref[rows] = jnp.broadcast_to(l_new, (block_q, l_ref.shape[1]))
 
-        for_each_head(_head)
+        for_each_head(_attend, _scores)
 
     # Each cell does its class's work and no more. Dead cells (nothing to
     # see: above the diagonal, below the window floor, under the row's left
@@ -309,7 +357,7 @@ def _kernel(
 
     @pl.when(j == nj - 1)
     def _finalize():
-        def _store(g, rows):
+        def _store(g, rows, _):
             l = jnp.maximum(l_ref[rows, :1], 1e-30)
             o_ref[0, 0, g] = (acc_ref[rows] / l).astype(o_ref.dtype)
 
@@ -332,15 +380,24 @@ def _heads_per_step(G: int) -> int:
     return G if G <= _UNROLLED_GROUP else _HEADS_LOOPED
 
 
+def _heads_ahead(G: int) -> int:
+    """How many heads a head's score product is written ahead of its softmax
+    (for_each_head): one in the static unroll, none in the loop, where it
+    loses (module docstring, "the order a cell is written in")."""
+    return 1 if G <= _UNROLLED_GROUP else 0
+
+
 def _vmem_bytes(G: int, hd: int, bq: int, bk: int) -> int:
     """Scoped VMEM a grid step needs at the engine's types (bf16 queries; a
     bf16 cache, which needs 2 MiB more than an int8 one): the q and o tiles
     double-buffered and the acc, m, l scratch, all G * bq rows; the k and v
     blocks double-buffered; and what a cell keeps per score — Mosaic's own
-    count, bisected on the compiler at G 3-64, is 11.5-14 bytes with one
-    head's [bq, bk] temporaries alive (a static unroll keeps no more) and
-    2-3 more for a second head. At most 30% above what Mosaic needs, never
-    under (PERF.md section 6, PR 36)."""
+    count, bisected on the compiler at G 2-64, is 11.5-14 bytes with one
+    head's [bq, bk] temporaries alive and 2-4 more for a second head's:
+    a looped step holds two heads, and the unroll, written one product
+    ahead, two score tiles (14 MiB for 13 at G=4, 22 for 20 at G=3). At
+    most 30% above what Mosaic needs, never under (PERF.md section 6, PRs
+    36 and 45)."""
     rows = G * bq * (2 * 2 * 2 * hd + 4 * (hd + 2 * _LANES))
     blocks = 2 * 2 * bk * hd * 2
     heads = min(_heads_per_step(G), 2)
@@ -358,9 +415,8 @@ def _block_geometry(S: int, C: int, G: int, hd: int,
     default_bk = max(512, 2048 * _LANES // max(hd, 1))
     if G <= _UNROLLED_GROUP:
         # G * bk held to 3 * 2048 since the default limit of 16 MiB (Qwen3
-        # and Phi-4's 4:1 groups: bk 1024). Mosaic's count says the unroll
-        # keeps one head's temporaries, not G: re-sweeping these is ROADMAP
-        # Queue 1 item 1d
+        # and Phi-4's 4:1 groups: bk 1024); (1024, 1024) ties under the
+        # unroll's order (module docstring, "block geometry")
         while G * default_bk > 3 * 2048 and default_bk > 512:
             default_bk //= 2
     else:
@@ -495,7 +551,7 @@ def flash_prefill_attention(
     kernel = functools.partial(
         _kernel, block_q=bq, block_k=bk, seq_len=S, cache_len=C,
         scale=1.0 / (hd ** 0.5), quantized=quantized, q_per_kv=G,
-        heads_per_step=_heads_per_step(G),
+        heads_per_step=_heads_per_step(G), heads_ahead=_heads_ahead(G),
     )
     out = pl.pallas_call(
         kernel,
