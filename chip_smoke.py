@@ -12,7 +12,7 @@ random weights made from a seed, through the entry points a user calls:
              --backend tpu` runs): VN-LongSum-length documents,
              mapreduce, a full-batch S=8192 dispatch, a reduce, evaluation
     serve    `python -m vnsum_tpu.serve.server --backend tpu --inflight
-             --fused-segments N --journal-dir ...` on a cold program cache:
+             --journal-dir ...` on a cold program cache:
              /v1/generate (shared prefix, stream), /v1/summarize, /metrics,
              SIGTERM drain
     mesh     the generate step under TpuBackend(mesh=) at model=4 and
@@ -66,7 +66,7 @@ def sizes(rehearsal: bool) -> dict:
             token_max=300, offline_batch=4, offline_max_new=8,  # rehearsal engine
             offline_seq=640, offline_prefill_chunk=128, probe_tokens=380,
             serve_model="tiny", serve_slots=2, serve_slot_tokens=192,
-            serve_max_new=24, serve_fused=2, serve_block_tokens=16,
+            serve_max_new=24, serve_block_tokens=16,
             serve_prefix_bytes=120, serve_doc_bytes=26_000,
             mesh_prompt_bytes=200, mesh_batch=8, mesh_max_new=4,
             mesh_seq=512,
@@ -94,7 +94,7 @@ def sizes(rehearsal: bool) -> dict:
         # serve: bf16 weights (6.4 GB, the server has no --quantize) leave
         # room for two slots whose prompt bucket holds a 12k-token map chunk
         serve_model="llama3.2:3b", serve_slots=2, serve_slot_tokens=12_800,
-        serve_max_new=640, serve_fused=4, serve_block_tokens=64,
+        serve_max_new=640, serve_block_tokens=64,
         serve_prefix_bytes=6_000, serve_doc_bytes=26_000,
         mesh_prompt_bytes=3_500, mesh_batch=8, mesh_max_new=32,
         mesh_seq=4352,
@@ -1039,7 +1039,6 @@ def phase_serve(env: dict, work: Path, logs: Path, timeout_s: float,
     journal, flight = work / "journal", work / "flight"
     server_args = [
         "--backend", "tpu", "--model", sz["serve_model"], "--inflight",
-        "--fused-segments", str(sz["serve_fused"]),
         "--slots", str(sz["serve_slots"]),
         "--max-batch", str(sz["serve_slots"]),
         "--slot-prompt-tokens", str(sz["serve_slot_tokens"]),
@@ -1177,7 +1176,7 @@ def phase_serve(env: dict, work: Path, logs: Path, timeout_s: float,
             "watchdog_stalls_total", "watchdog_hung_dispatches_total",
             "fault_failures_total", "fault_retries_total",
             "degraded_steps_total", "degraded_rung",
-            "inflight_fused_dispatches_total", "inflight_segments_total",
+            "inflight_segments_total",
             "cache_hit_tokens_total", "requests_total",
         )}
         rep["metrics"] = m
@@ -1185,9 +1184,9 @@ def phase_serve(env: dict, work: Path, logs: Path, timeout_s: float,
                      "fault_failures_total", "fault_retries_total",
                      "degraded_steps_total", "degraded_rung"):
             c.check(f"{name}_is_0", not m[name], m[name])
-        c.check("inflight_fused_dispatches_positive",
-                (m["inflight_fused_dispatches_total"] or 0) > 0,
-                m["inflight_fused_dispatches_total"])
+        c.check("inflight_segments_positive",
+                (m["inflight_segments_total"] or 0) > 0,
+                m["inflight_segments_total"])
         c.check("cache_hit_tokens_positive",
                 (m["cache_hit_tokens_total"] or 0) > 0,
                 m["cache_hit_tokens_total"])

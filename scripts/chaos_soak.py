@@ -403,11 +403,6 @@ def churn_soak(args) -> int:
         "--drain-timeout-s", "20",
         "--trace-sample", "0",
         "--inflight", "--slots", "4",
-        # fused multi-step decode: cancels, disconnects, and preemptions
-        # must land at the COARSER fused-dispatch cadence without leaking
-        # a slot or a pin — the audit's reclamation invariants run against
-        # the fused loop, not the N=1 special case
-        "--fused-segments", "4",
         "--tenants", "interactive:4:0,batch:1:0:batch",
         "--fake-batch-overhead-ms", str(args.fake_batch_overhead_ms),
         "--fake-per-prompt-ms", str(args.fake_per_prompt_ms),
@@ -461,7 +456,6 @@ def churn_soak(args) -> int:
             "vnsum_serve_qos_preemptions_total",
             "vnsum_serve_stream_backpressure_coalesced_total",
             "vnsum_serve_stream_heartbeats_total",
-            "vnsum_serve_inflight_fused_dispatches_total",
             "vnsum_serve_inflight_segments_total",
         ):
             counters[name] = scrape_metric(port, name)
@@ -567,12 +561,8 @@ def churn_soak(args) -> int:
         and (counters.get("vnsum_serve_cancel_disconnects_total") or 0) > 0
         and (counters.get("vnsum_serve_qos_preemptions_total") or 0) > 0
         and len(preempt_cancel_overlap) > 0
-        # the whole soak ran on the FUSED loop: dispatches happened and
-        # each host round trip really covered >1 on-device segment
-        and (counters.get(
-            "vnsum_serve_inflight_fused_dispatches_total") or 0) > 0
-        and (counters.get("vnsum_serve_inflight_segments_total") or 0)
-        > (counters.get("vnsum_serve_inflight_fused_dispatches_total") or 0)
+        # the whole soak ran on the slot loop
+        and (counters.get("vnsum_serve_inflight_segments_total") or 0) > 0
     )
     print("churn ledger invariant:", "OK" if ok else "VIOLATED")
     return 0 if ok else 1
@@ -594,14 +584,7 @@ def hang_soak(args) -> int:
       segment. Recovery tears the loop down and REQUEUES every resident
       through the journal's replayable ACCEPT — clients see nothing but
       latency; byte-identity holds on the rebuilt loop.
-    - epoch 3 (``mid_fused_loop``): the same slot-loop hang, but under
-      fused multi-step decode (``--fused-segments 4``). The watchdog's
-      budget is N-scaled (``segment_budget(4)``), so the epoch proves two
-      things at once: slow-but-legitimate fused dispatches never read as
-      HUNG (exactly ONE dispatch stall — the injected hang — and zero
-      false positives), and a genuinely wedged fused dispatch still trips
-      and recovers with the residents requeued byte-identically.
-    - epoch 4 (``mid_fsync``): a forever-hang inside the journal's
+    - epoch 3 (``mid_fsync``): a forever-hang inside the journal's
       group-commit fsync — the scheduler wedges INSIDE the journal lock,
       where a replacement thread would deadlock too. The watchdog
       classifies it as a lock stall and escalates: supervised
@@ -648,9 +631,6 @@ def hang_soak(args) -> int:
          f"seed={s};fake.dispatch:hang@on_call=4,delay_s=0",
          "dispatch", "sigterm"),
         ("mid_slot_loop", inflight,
-         f"seed={s};fake.slot_step:hang@on_call=6,delay_s=0",
-         "dispatch", "sigterm"),
-        ("mid_fused_loop", inflight + ["--fused-segments", "4"],
          f"seed={s};fake.slot_step:hang@on_call=6,delay_s=0",
          "dispatch", "sigterm"),
         ("mid_fsync", ["--journal-fsync-ms", "0"],
@@ -707,9 +687,6 @@ def hang_soak(args) -> int:
                         port, "vnsum_serve_watchdog_recoveries_total"),
                     "hung_dispatches": scrape_metric(
                         port, "vnsum_serve_watchdog_hung_dispatches_total"),
-                    "fused_dispatches": scrape_metric(
-                        port,
-                        "vnsum_serve_inflight_fused_dispatches_total"),
                     "segments": scrape_metric(
                         port, "vnsum_serve_inflight_segments_total"),
                 })
@@ -823,8 +800,8 @@ def hang_soak(args) -> int:
         except ValueError:
             dumps_well_formed = False
 
-    fused_epoch = next(
-        (c for c in epoch_counters if c.get("epoch") == "mid_fused_loop"),
+    slot_epoch = next(
+        (c for c in epoch_counters if c.get("epoch") == "mid_slot_loop"),
         None,
     )
 
@@ -832,10 +809,9 @@ def hang_soak(args) -> int:
         "bench": "chaos_soak_hang_injection",
         "seed": args.seed,
         "epochs": epoch_counters,
-        "fused_segments": 4,
-        "fused_false_hung": (
-            (fused_epoch["stalls_dispatch"] or 0) - 1
-            if fused_epoch else None
+        "slot_loop_false_hung": (
+            (slot_epoch["stalls_dispatch"] or 0) - 1
+            if slot_epoch else None
         ),
         "escalation_exit_code": escalate_rc,
         "sealed": sealed,
@@ -876,7 +852,7 @@ def hang_soak(args) -> int:
         and dumps_well_formed
         # both stall classes actually exercised, stacks on the tape, and
         # the typed stall event in a flight dump
-        and dump_kinds.get("dispatch", 0) >= 3  # one per in-process epoch
+        and dump_kinds.get("dispatch", 0) >= 2  # one per in-process epoch
         and dump_kinds.get("lock", 0) >= 1
         and stacks_show_wedge
         and stall_events > 0
@@ -887,15 +863,12 @@ def hang_soak(args) -> int:
         # the monitor interval is 0.1s, so the slack is host-scheduling
         # headroom, not a loophole
         and all(lat <= args.detect_slack_s for lat in detect_latencies)
-        # fused epoch: dispatches actually fused (segments > dispatches),
-        # and the ONLY dispatch stall was the injected hang — a fused
-        # dispatch that is merely N segments slow must never read as HUNG
-        and fused_epoch is not None
-        and (fused_epoch["fused_dispatches"] or 0) > 0
-        and (fused_epoch["segments"] or 0)
-        > (fused_epoch["fused_dispatches"] or 0)
-        and fused_epoch["stalls_dispatch"] == 1
-        and (fused_epoch["recoveries"] or 0) >= 1
+        # slot-loop epoch: segments ran, and the ONLY dispatch stall was
+        # the injected hang — a live segment must never read as HUNG
+        and slot_epoch is not None
+        and (slot_epoch["segments"] or 0) > 0
+        and slot_epoch["stalls_dispatch"] == 1
+        and (slot_epoch["recoveries"] or 0) >= 1
     )
     print("hang-soak liveness invariant:", "OK" if ok else "VIOLATED")
     return 0 if ok else 1

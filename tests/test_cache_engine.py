@@ -80,6 +80,36 @@ def test_engine_has_no_option_that_selects_another_decode_loop(
                    **{option: True})
 
 
+@pytest.mark.parametrize("entry", [
+    "TpuBackend.start_slot_loop", "FakeBackend.start_slot_loop",
+    "InflightScheduler", "ServeState", "serve.server"])
+def test_no_entry_takes_an_option_that_fuses_segments(
+    cfg, params, entry, capsys
+):
+    """One segment a dispatch is the slot loop's only cadence (PR 46: the
+    served cell's chip waits at joins and the coalescing window, 0.0000189 s
+    of 51 at segment boundaries): no entry point takes N."""
+    from vnsum_tpu.backend import FakeBackend
+    from vnsum_tpu.serve import server
+    from vnsum_tpu.serve.inflight import InflightScheduler
+
+    call = {
+        "TpuBackend.start_slot_loop": lambda: make_backend(
+            cfg, params, cache_blocks=0).start_slot_loop(2, fused_segments=2),
+        "FakeBackend.start_slot_loop": lambda: FakeBackend().start_slot_loop(
+            2, fused_segments=2),
+        "InflightScheduler": lambda: InflightScheduler(
+            FakeBackend(), slots=2, fused_segments=2),
+        "ServeState": lambda: server.ServeState(
+            FakeBackend(), inflight=True, fused_segments=2),
+        "serve.server": lambda: server.main(
+            ["--backend", "fake", "--inflight", "--fused-segments", "2"]),
+    }[entry]
+    with pytest.raises((TypeError, SystemExit)) as e:
+        call()
+    assert "fused" in str(e.value) + capsys.readouterr().err
+
+
 def test_plain_generate_is_one_program_whatever_the_budget(cfg, params):
     """A budget several ``segment_tokens`` long still runs the one-shot
     program: one ``_fns`` entry, nothing among the split/slot programs."""

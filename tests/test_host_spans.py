@@ -337,7 +337,7 @@ def test_the_slot_loop_opens_spans_a_boundary_and_keeps_the_old_names():
     assert c.bt.first_token_at == pytest.approx(pre.t0 + pre.dur)
     assert admissions[0].prefill_end == pytest.approx(pre.t0 + pre.dur)
     segs = c.named("decode_seg")
-    assert len(segs) == steps and all("fused" in s.args for s in segs)
+    assert len(segs) == steps == loop.segments
     loop.close()
 
 
@@ -366,5 +366,5 @@ def test_host_gap_counters_move_by_no_more_than_the_wall():
     spans = sched.host_spans
     assert {"serve/take", "serve/admit", "serve/complete"} <= set(spans)
     # one serve/complete a segment, one serve/admit a join: never one a row
-    assert spans["serve/complete"].count == stats.fused_dispatches
+    assert spans["serve/complete"].count == stats.segments
     assert spans["serve/admit"].count <= spans["serve/take"].count

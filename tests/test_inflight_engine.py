@@ -151,6 +151,23 @@ def test_sampled_stream_independent_of_join_timing_and_companions():
     assert outs_a[0] != "" or outs_b[0] != ""
 
 
+def test_the_key_rule_is_one_function():
+    """A one-shot row and the same request joined alone into a slot loop,
+    sampled at temperature 1 under the same seed and uid, draw the same
+    tokens: prefill, the one-shot decode and the slot segment take their
+    keys from one rule (engine._stream_keys: seed, uid, the token's index
+    and nothing else)."""
+    gen = GenerationConfig(temperature=1.0, seed=7, max_new_tokens=24)
+    one_shot = make_backend().generate([PROMPTS[2]], config=gen)[0]
+    assert one_shot   # row 0 of the first dispatch: seed fold 0, uid 0
+
+    loop = make_backend().start_slot_loop(4, config=gen)   # seed fold 0
+    loop.admit([(0, PROMPTS[2], None)])                    # uid 0
+    outs: dict = {}
+    drain(loop, outs)
+    assert outs[0] == one_shot
+
+
 # -- prefix-cache interaction ------------------------------------------------
 
 
@@ -265,100 +282,51 @@ def test_partial_outputs_are_prefixes_of_the_final_text():
     assert final[0].startswith(grown[-1])
 
 
-# -- fused multi-step decode (--fused-segments) ------------------------------
+# -- one segment a dispatch: the stop on the device, the boundary fetch -------
 
 
-@pytest.mark.parametrize("fused", [2, 4])
-def test_fused_byte_identity_vs_n1_with_staggered_joins(fused):
-    """N on-device segments per host dispatch run the SAME per-row update
-    as N=1 — only the host round-trip cadence changes — so greedy outputs
-    must stay byte-identical under staggered joins and ragged EOS exits,
-    while the segments/dispatches counters diverge by the fusing win."""
-    gen = ragged_eos_config()
-
-    def run(n):
-        b = make_backend()
-        loop = b.start_slot_loop(4, config=gen, fused_segments=n)
-        outs: dict[int, str] = {}
-        adm, rej = loop.admit([(i, PROMPTS[i], None) for i in (0, 1, 2)])
-        assert rej == []
-        pending = [i for i in range(len(PROMPTS))
-                   if i not in {a.key for a in adm}]
-        for _ in range(64):
-            res = loop.step()
-            for c in res.completions:
-                outs[c.key] = c.text
-            if pending and loop.free:
-                adm, rej = loop.admit(
-                    [(i, PROMPTS[i], None) for i in pending]
-                )
-                assert rej == []
-                for a in adm:
-                    pending.remove(a.key)
-            if not pending and loop.active == 0:
-                break
-        assert loop.active == 0 and not pending
-        return [outs[i] for i in range(len(PROMPTS))], loop
-
-    base, base_loop = run(1)
-    fused_outs, loop = run(fused)
-    assert fused_outs == base
-    # at N=1 every dispatch is one segment; fused really amortized: more
-    # on-device segments retired than host round-trips, and fewer
-    # round-trips than the unfused run needed
-    assert base_loop.segments == base_loop.fused_dispatches
-    assert loop.segments > loop.fused_dispatches
-    assert loop.fused_dispatches < base_loop.fused_dispatches
-
-
-def test_fused_early_stop_and_device_segment_accounting():
-    """The fused while_loop stops on-device the moment every row is done:
-    a single resident retires in ONE host round-trip even at fused=8, and
-    device_segments reports the segments actually run — never the fused
-    bound — so the histogram sees real amortization, not the knob."""
+def test_segment_stops_on_device_when_every_row_is_done():
+    """The segment's while_loop stops the moment every row is done: with a
+    segment longer than the whole budget a single resident retires in ONE
+    host round trip, having run only the steps its tokens took, and the
+    loop counts that one segment."""
     solo = make_backend().generate([PROMPTS[2]])[0]
-    b = make_backend()
-    loop = b.start_slot_loop(2, fused_segments=8)
+    b = make_backend(segment_tokens=64)   # max_new is 24
+    loop = b.start_slot_loop(2)
     adm, _ = loop.admit([(0, PROMPTS[2], None)])
     assert len(adm) == 1
     res = loop.step()
-    assert loop.active == 0 and loop.fused_dispatches == 1
+    assert loop.active == 0 and loop.segments == 1
     assert [c.text for c in res.completions] == [solo]
-    # ceil(tokens / segment_tokens) segments ran on device, strictly under
-    # the fused bound of 8 (max_new=24, segment_tokens=4 -> at most 6)
-    assert res.device_segments == -(-res.new_tokens // b.segment_tokens)
-    assert 1 <= res.device_segments <= 6
-    assert loop.segments == res.device_segments
+    assert res.new_tokens == res.completions[0].gen_tokens <= 24
     loop.close()
 
 
-def test_fused_partial_outputs_ride_the_boundary_snapshot():
-    """Streaming partials at fused cadence are served from the coalesced
-    boundary fetch (no extra device sync) and still extend monotonically
-    into the final text."""
+def test_partial_outputs_ride_the_boundary_snapshot(monkeypatch):
+    """Streaming partials are served from the out buffer that rode the
+    segment's one coalesced done/t/out fetch: between a step and the next
+    admit ``partial_outputs`` asks the device for nothing. An adopt rewrites
+    out rows, so after an admit the snapshot is gone and the cold fallback
+    fetches."""
+    import jax
+
     b = make_backend()
-    loop = b.start_slot_loop(2, fused_segments=2)
+    loop = b.start_slot_loop(2)
     adm, _ = loop.admit([(0, PROMPTS[2], None)])
-    assert len(adm) == 1
     key = adm[0].key
-    snapshots = []
-    final = {}
-    for _ in range(64):
-        res = loop.step()
-        for c in res.completions:
-            final[c.key] = c.text
-        if loop.active:
-            part = loop.partial_outputs([key])
-            if part:
-                snapshots.append(part[id(key)])
-        if not loop.active:
-            break
-    assert final[0] == make_backend().generate([PROMPTS[2]])[0]
-    grown = [s for s in snapshots if s]
-    assert grown, "no partial text surfaced during fused decode"
-    for a, bnext in zip(grown, grown[1:]):
-        assert bnext.startswith(a)
-    assert final[0].startswith(grown[-1])
+    loop.step()
+    assert loop.active and loop._out_snap is not None
+    fetches = []
+    real_get = jax.device_get
+    monkeypatch.setattr(
+        jax, "device_get", lambda x: fetches.append(1) or real_get(x))
+    warm = loop.partial_outputs([key])[id(key)]
+    assert warm and not fetches
+    loop.admit([(1, PROMPTS[1], None)])
+    assert loop._out_snap is None
+    fetches.clear()   # the admit's own done0 fetch
+    assert loop.partial_outputs([key])[id(key)] == warm and fetches == [1]
+    loop.close()
 
 
 # -- slot bookkeeping --------------------------------------------------------

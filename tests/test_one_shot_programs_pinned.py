@@ -10,10 +10,26 @@ heads one score product ahead, its body is in every one of these families'
 programs and their tiny forms all have groups of at most four heads, so
 the five were recomputed; `deepseek-v2`, whose family shares no line of
 that kernel, was hashed on PR 44's tree first and is what PR 45 did NOT
-move."""
+move.
+
+**PR 46 added the slot programs** of the tiny llama family (`_SLOT_PINNED`),
+built as `TpuSlotLoop` gets them (`_get_seg_fn`, default arguments only, so
+this file reads the same on the parent's tree and on the PR's): their four
+hashes were taken at PR 45's tree before PR 46 rebuilt the slot programs
+from the one-shot program's parts, and PR 46 moved none of the ten.
+
+A hash says that a program moved, not what moved. `program_pins.json` beside
+this file keeps, for every pinned program, one hex digit a line of the
+running hash of its text: a failing pin prints the first line that differs
+(`assert_pinned`). `python tests/test_one_shot_programs_pinned.py` (from the
+repo's root, `PYTHONPATH=.`) traces all ten, prints both tables as they
+would have to read and rewrites that file — the one place that regenerates
+them."""
 from __future__ import annotations
 
 import hashlib
+import json
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -34,13 +50,66 @@ _PINNED = {
     "deepseek-v2": (tiny_deepseek, {}, "90bc1e80a899c229"),
 }
 
+# the slot loop's programs of the tiny llama family, "kind-rows" -> the same
+# hash: a join of 1 and of 2 rows, the segment of 4 slots, the adopt of a
+# 2-row join into them
+_SLOT_PINNED = {
+    "slot_prefill-1": "2d5a63d1a6dc8b63",
+    "slot_prefill-2": "aab563206132a4ee",
+    "slot_seg-4": "43ed76b480b213c3",
+    "adopt-2": "335d7da10ac8614a",
+}
+_SLOTS = 4
+
+_LADDERS = Path(__file__).with_name("program_pins.json")
+
+
+def _sha(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _ladder(text: str) -> str:
+    """One hex digit a line: of the running hash of the text up to and
+    including that line, so the first digit that differs is at (or, one
+    time in 16 a line, just after) the first line that does."""
+    h, out = hashlib.sha256(), []
+    for line in text.split("\n"):
+        h.update(line.encode() + b"\n")
+        out.append(h.copy().hexdigest()[0])
+    return "".join(out)
+
+
+def assert_pinned(name: str, text: str, want: str) -> None:
+    """``text`` hashes to ``want``; where it does not, say where the two
+    programs part: the first line of ``text`` whose running hash is not the
+    pinned program's, with the lines before it."""
+    got = _sha(text)
+    if got == want:
+        return
+    was = json.loads(_LADDERS.read_text()).get(name, "")
+    now, lines = _ladder(text), text.split("\n")
+    first = next((i for i, (a, b) in enumerate(zip(was, now)) if a != b),
+                 min(len(was), len(now)))
+    where = "\n".join(
+        f"{'>' if i == first else ' '} {i + 1}: {lines[i][:300]}"
+        for i in range(max(first - 3, 0), min(first + 2, len(lines))))
+    pytest.fail(
+        f"{name}: the program hashes to {got}, pinned {want}; it has "
+        f"{len(lines)} lines for {len(was)} and first differs at or just "
+        f"before line "
+        f"{first + 1}:\n{where}", pytrace=False)
+
+
+def _backend(cfg, B: int, new: int) -> TpuBackend:
+    return TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
+                      max_new_tokens=new, interpret=True, quantize=True,
+                      prefill_chunk_tokens=128)
+
 
 def one_shot_jaxpr(cfg, B: int = 2, S: int = 256, new: int = 8) -> str:
     """The text of the (B, S) one-shot program's jaxpr for ``cfg``, traced
     on shapes alone."""
-    be = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
-                    max_new_tokens=new, interpret=True, quantize=True,
-                    prefill_chunk_tokens=128)
+    be = _backend(cfg, B, new)
     fn = be._make_fn(B, S, new, be.gen_cfg)
     return str(jax.make_jaxpr(fn)(
         jax.eval_shape(lambda: be.params),
@@ -49,9 +118,71 @@ def one_shot_jaxpr(cfg, B: int = 2, S: int = 256, new: int = 8) -> str:
         jax.ShapeDtypeStruct((), jnp.uint32)))
 
 
+def slot_jaxpr(program: str, S: int = 256, new: int = 8) -> str:
+    """The text of one slot-loop program's jaxpr ("kind-rows"), asked of
+    the engine as ``TpuSlotLoop`` asks and traced on the shapes the loop
+    calls it with (``admit`` / ``step`` of backend/inflight.py)."""
+    kind, rows = program.split("-")
+    B, N, C = int(rows), _SLOTS, S + new
+    be = _backend(MODEL_REGISTRY["tiny"](), N, new)
+    sds = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: be.params)
+    seed = sds((), jnp.uint32)
+
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+
+    def done(n):
+        return sds((n,), jnp.bool_)
+
+    def cache(n):
+        return jax.eval_shape(lambda: be._init_prefill_cache(n, C))
+
+    fn = be._get_seg_fn(kind, B, S, new, be.gen_cfg)
+    args = {
+        # params, tokens, pad_lens, seed, uids
+        "slot_prefill": lambda: (params, i32(B, S), i32(B), seed, i32(B)),
+        # params, t, cur, cache, done, uids, out, pads, seed
+        "slot_seg": lambda: (params, i32(B), i32(B), cache(B), done(B),
+                             i32(B), i32(B, new), i32(B), seed),
+        # the resident cache, cur, done, t, out, pads; the join's cache,
+        # first, done0, pads; the slots it lands on
+        "adopt": lambda: (cache(N), i32(N), done(N), i32(N), i32(N, new),
+                          i32(N), cache(B), i32(B), done(B), i32(B), i32(B)),
+    }[kind]()
+    return str(jax.make_jaxpr(fn)(*args))
+
+
 @pytest.mark.parametrize("family", list(_PINNED))
 def test_the_one_shot_program_traces_to_the_pinned_jaxpr(family):
     config, kw, want = _PINNED[family]
     text = one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw))
     assert "pallas_call" in text          # the kernels' bodies are hashed too
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+    assert_pinned(family, text, want)
+
+
+@pytest.mark.parametrize("program", list(_SLOT_PINNED))
+def test_a_slot_program_traces_to_the_pinned_jaxpr(program):
+    text = slot_jaxpr(program)
+    assert ("pallas_call" in text) == (not program.startswith("adopt"))
+    assert_pinned(program, text, _SLOT_PINNED[program])
+
+
+def regenerate() -> None:
+    """Trace all ten programs, print the two tables' hashes as they are now
+    and rewrite the line ladders."""
+    texts = [("_PINNED", family, want,
+              one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw)))
+             for family, (config, kw, want) in _PINNED.items()]
+    texts += [("_SLOT_PINNED", program, want, slot_jaxpr(program))
+              for program, want in _SLOT_PINNED.items()]
+    for table, name, want, text in texts:
+        print(f"{table:12} {name!r}: {_sha(text)!r}"
+              f"{'' if _sha(text) == want else '   # was ' + want}")
+    _LADDERS.write_text(json.dumps(
+        {name: _ladder(text) for _, name, _, text in texts},
+        indent=0, sort_keys=True) + "\n")
+
+
+if __name__ == "__main__":
+    regenerate()

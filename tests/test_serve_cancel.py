@@ -118,28 +118,24 @@ def test_cancel_resident_slot_reclaimed_without_requeue_or_pins(tmp_path):
     assert entries["r-1"].status == "cancelled"
 
 
-def test_cancel_resident_lands_within_one_fused_window():
-    """--fused-segments coarsens the cancel sweep to host-dispatch
-    cadence: a resident cancel must land at the NEXT fused boundary —
-    at most the in-flight dispatch plus one, never several windows — and
-    reclaim the slot without a requeue."""
+def test_cancel_resident_lands_within_one_segment():
+    """The cancel sweep runs at every segment boundary: a resident cancel
+    lands at the NEXT one — at most the segment in flight plus one, never
+    several — and reclaims the slot without a requeue."""
     backend = FakeBackend(segment_words=2, segment_overhead_s=0.02)
-    sched = InflightScheduler(backend, slots=2, max_wait_s=0.01,
-                              fused_segments=4)
+    sched = InflightScheduler(backend, slots=2, max_wait_s=0.01)
     try:
         fut = sched.submit("van ban dai can tom tat " * 12, trace_id="fz-1")
-        assert wait_for(
-            lambda: sched.metrics.snapshot().fused_dispatches >= 1
-        )
-        before = sched.metrics.snapshot().fused_dispatches
+        assert wait_for(lambda: sched.metrics.snapshot().segments >= 1)
+        before = sched.metrics.snapshot().segments
         sched.cancel("fz-1")
         with pytest.raises(RequestCancelled) as exc:
             fut.result(timeout=10)
         assert exc.value.stage == "resident"
         snap = sched.metrics.snapshot()
-        # the sweep ran right after the in-flight dispatch retired: at most
-        # one more full fused window elapsed before the cancel landed
-        assert snap.fused_dispatches - before <= 2
+        # the sweep ran right after the segment in flight retired: at most
+        # one more elapsed before the cancel landed
+        assert snap.segments - before <= 2
         assert snap.cancelled.get("resident") == 1
         assert snap.requeues == 0
         assert wait_for(lambda: sched.slot_state()[1] == 0)

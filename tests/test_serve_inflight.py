@@ -439,58 +439,6 @@ def test_queued_backend_fanout_rides_slot_loop():
         sched.close()
 
 
-# -- fused multi-step decode (--fused-segments) ------------------------------
-
-
-def test_fused_scheduler_outputs_and_dispatch_counters():
-    """--fused-segments 4: outputs stay byte-identical to an unfused run
-    (same per-row math, coarser host cadence) and the counters expose the
-    amortization — more segments retired than host dispatches (also rerun
-    under VNSUM_SANITIZERS=all in CI: the transfer guard proves the fused
-    boundary fetch is the only device sync)."""
-    backend = make_backend(segment_words=4)
-    sched = make_sched(backend, fused_segments=4)
-    try:
-        prompts = [f"tai lieu hop nhat {i} noi dung rieng " * 8
-                   for i in range(6)]
-        futs = [sched.submit(p) for p in prompts]
-        for p, f in zip(prompts, futs):
-            assert f.result(timeout=30).text == FakeBackend().generate([p])[0]
-        snap = sched.metrics.snapshot()
-        assert snap.completed == 6
-        assert snap.fused_dispatches > 0
-        assert snap.segments > snap.fused_dispatches
-        text = sched.metrics.render_prometheus(
-            queue_depth=0, queued_tokens=0, slot_state=sched.slot_state()
-        )
-    finally:
-        sched.close()
-    assert "vnsum_serve_inflight_fused_dispatches_total" in text
-    assert "vnsum_serve_inflight_fused_segments_bucket" in text
-
-
-def test_fused_refill_joins_at_dispatch_boundaries():
-    """Joins coarsen to fused-dispatch cadence but still land WHILE the
-    resident decodes — the refill counter moves before the long request
-    finishes, exactly as at N=1."""
-    backend = make_backend(segment_words=4, per_step_s=0.002)
-    sched = make_sched(backend, fused_segments=2)
-    try:
-        long_fut = sched.submit("dai " * 60)
-        time.sleep(0.04)  # a fused dispatch or two deep
-        short_futs = [sched.submit(f"ngan {i} muoi tu " * 3)
-                      for i in range(3)]
-        long_c = long_fut.result(timeout=30)
-        short_cs = [f.result(timeout=30) for f in short_futs]
-        snap = sched.metrics.snapshot()
-        assert snap.refills >= 2, snap.refills
-        assert any(c.record.batch_size > 1 for c in short_cs)
-        assert long_c.record.status == "ok"
-        assert snap.fused_dispatches > 0
-    finally:
-        sched.close()
-
-
 # -- take_upto unit behavior -------------------------------------------------
 
 

@@ -273,35 +273,6 @@ def test_preemption_interactive_reclaims_slots():
         sched.close()
 
 
-def test_preemption_lands_at_fused_dispatch_boundary():
-    """Fused decode (N=4) coarsens preemption polls to host-dispatch
-    cadence: an interactive arrival still reclaims a slot at the next
-    fused boundary, and the preempted batch job replays byte-identically
-    — the eviction round trip is lossless at every fused cadence."""
-    backend, sched = make_inflight(
-        fused_segments=4, backend_kw=dict(per_step_s=0.002),
-    )
-    try:
-        long_prompt = "phan tich chuyen sau noi dung hop nhat " * 12
-        b_futs = [
-            sched.submit(long_prompt + f" so {i}", tenant="batch",
-                         tier="batch")
-            for i in range(2)
-        ]
-        time.sleep(0.04)  # both resident, a fused dispatch or so deep
-        i_c = sched.submit("ngan gon", tenant="interactive").result(timeout=30)
-        b_cs = [f.result(timeout=30) for f in b_futs]
-        snap = sched.metrics.snapshot()
-        assert snap.preemptions >= 1 and snap.requeues >= 1
-        assert snap.fused_dispatches > 0
-        assert i_c.record.status == "ok"
-        for i, c in enumerate(b_cs):
-            ref = FakeBackend().generate([long_prompt + f" so {i}"])[0]
-            assert c.text == ref
-    finally:
-        sched.close()
-
-
 def test_preemption_pins_prefix_blocks_and_releases_them():
     """Eviction pins the victim's cached prefix (it survives LRU while
     requeued) and every pin is released by terminal resolution."""

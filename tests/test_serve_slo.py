@@ -357,26 +357,36 @@ def test_http_slo_usage_recorder_surfaces(slo_server):
 def test_slo_endpoints_404_when_unconfigured():
     from vnsum_tpu.serve.server import ServeState, make_server
 
-    state = ServeState(FakeBackend(), max_batch=4, max_wait_s=0.005,
-                       flight_recorder=False, windowed_metrics=False)
+    state = ServeState(FakeBackend(), max_batch=4, max_wait_s=0.005)
     server = make_server(state, "127.0.0.1", 0)
     threading.Thread(target=server.serve_forever, daemon=True).start()
     base = f"http://127.0.0.1:{server.server_address[1]}"
     try:
-        for path in ("/debug/slo", "/debug/flightrecorder", "/v1/usage"):
-            with pytest.raises(urllib.error.HTTPError) as exc:
-                _get(base + path)
-            assert exc.value.code == 404
-        # the all-off arm renders no slo/usage/recorder series at all
+        with pytest.raises(urllib.error.HTTPError) as exc:
+            _get(base + "/debug/slo")
+        assert exc.value.code == 404
+        # the recorder and the usage ledger are always on: no --slo takes
+        # neither endpoint away
+        for path in ("/debug/flightrecorder", "/v1/usage"):
+            assert _get(base + path)[0] == 200
         _, body = _get(base + "/metrics")
         text = body.decode()
         assert "vnsum_serve_slo_" not in text
-        assert "vnsum_serve_usage_" not in text
-        assert "vnsum_serve_recorder_" not in text
+        assert "vnsum_serve_recorder_" in text
     finally:
         server.shutdown()
         server.server_close()
         state.close()
+
+
+@pytest.mark.parametrize("lever", ["flight_recorder", "windowed_metrics"])
+def test_serve_state_has_no_lever_that_turns_observability_off(lever):
+    """The recorder and the rolling windows were switched off by a bench
+    that is gone (PR 31); nothing may switch them off again."""
+    from vnsum_tpu.serve.server import ServeState
+
+    with pytest.raises(TypeError, match=lever):
+        ServeState(FakeBackend(), **{lever: False})
 
 
 # -- the acceptance scenario --------------------------------------------------

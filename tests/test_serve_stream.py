@@ -344,36 +344,6 @@ def test_streamed_deltas_under_staggered_joins(inflight_server):
         assert deltas_of(events) == expect, f"stream {i} corrupted"
 
 
-def test_streamed_deltas_at_fused_cadence_reassemble_exactly():
-    """--fused-segments 4 coarsens delta pushes to one per host dispatch
-    (the coalesced boundary fetch) — the reassembled stream must still be
-    byte-identical to the non-streaming reply for the same prompt."""
-    state = ServeState(
-        FakeBackend(segment_words=4, segment_overhead_s=0.002,
-                    batch_overhead_s=0.005),
-        max_batch=4, max_wait_s=0.005, inflight=True, slots=4,
-        fused_segments=4,
-    )
-    server = make_server(state, "127.0.0.1", 0)
-    threading.Thread(target=server.serve_forever, daemon=True).start()
-    base = f"http://127.0.0.1:{server.server_address[1]}"
-    try:
-        prompt = "dong chay hop nhat bon phan doan mot luot " * 8
-        events = sse_post(base, "/v1/generate",
-                          {"prompt": prompt, "stream": True})
-        assert events[-1][0] == "done"
-        text = events[-1][1]["completions"][0]["text"]
-        assert deltas_of(events) == text
-        assert text == FakeBackend().generate([prompt])[0]
-        snap = state.scheduler.metrics.snapshot()
-        assert snap.fused_dispatches > 0
-        assert snap.segments >= snap.fused_dispatches
-    finally:
-        server.shutdown()
-        server.server_close()
-        state.close()
-
-
 def test_streamed_generate_on_batch_scheduler_single_final_delta():
     """The one-shot dispatch path has no observable mid-decode boundary:
     streaming degrades to one delta carrying the whole text, and the

@@ -181,22 +181,22 @@ def test_dispatch_past_budget_is_hung_and_fires_once():
     assert wd.check() == []
 
 
-def test_fused_segment_budget_math_and_false_positive_immunity():
-    """--fused-segments N holds the host for up to N on-device segments
-    per ticket: the slot_segment budget scales by N (a fused dispatch
-    inside it is never a stall) and a dispatch past the SCALED budget
-    still trips as a real HUNG."""
+def test_flat_segment_budget_spares_slow_live_segments_and_trips_past_it():
+    """A slot segment's ticket carries the flat ``segment_budget_s``: a run
+    of slow but live segments, each inside its own budget and well past it
+    together, is never a stall, and one segment past the budget trips as a
+    real HUNG."""
     clock = FakeClock()
     wd = Watchdog(loop_deadline_s=100.0, segment_budget_s=2.0, clock=clock)
     wd.register("scheduler", kind="loop")
-    assert wd.segment_budget() == pytest.approx(2.0)
-    assert wd.segment_budget(4) == pytest.approx(8.0)
-    t = wd.begin_dispatch("scheduler", "slot_segment", wd.segment_budget(4))
-    clock.advance(7.5)  # would be HUNG at N=1; inside the N=4 budget
-    assert wd.check() == []
-    wd.end_dispatch(t)
-    t2 = wd.begin_dispatch("scheduler", "slot_segment", wd.segment_budget(4))
-    clock.advance(8.2)  # past even the scaled budget -> real hang
+    for _ in range(3):
+        t = wd.begin_dispatch("scheduler", "slot_segment",
+                              wd.segment_budget_s)
+        clock.advance(1.9)
+        assert wd.check() == []
+        wd.end_dispatch(t)
+    t2 = wd.begin_dispatch("scheduler", "slot_segment", wd.segment_budget_s)
+    clock.advance(2.2)
     stalls = wd.check()
     assert [(s.kind, s.name) for s in stalls] == [("dispatch", "scheduler")]
     assert stalls[0].ticket is t2
@@ -342,42 +342,6 @@ def test_slot_loop_rebuild_byte_identity_for_requeued_requests():
         _wait_until(lambda: wd.recoveries_total == 1)
         stats = sched.metrics.snapshot()
         assert stats.requeues >= 3  # every resident went back via requeue
-    finally:
-        plan.release_hangs()
-        sched.close(timeout=5)
-        wd.close()
-
-
-def test_fused_slot_loop_hang_recovery_byte_identity():
-    """A hang inside a FUSED dispatch (N=2): the N-scaled budget keeps
-    healthy fused dispatches unflagged, the wedged one trips exactly once,
-    and the rebuilt loop (same fused_segments) replays every requeued
-    resident byte-identically."""
-    prompts = [
-        f"<content>\nhop nhat {i} mot hai ba bon nam sau bay tam\n</content>"
-        for i in range(3)
-    ]
-    reference = FakeBackend(segment_words=2).generate(prompts)
-
-    wd = Watchdog(interval_s=0.03, loop_deadline_s=5.0, dispatch_base_s=5.0,
-                  segment_budget_s=0.25)
-    wd.start()
-    backend = FakeBackend(segment_words=2, segment_overhead_s=0.005)
-    sched = InflightScheduler(backend, slots=4, max_wait_s=0.02, watchdog=wd,
-                              fused_segments=2)
-    plan = FaultPlan([FaultSpec(site="fake.slot_step", kind="hang",
-                                on_call=2, delay_s=0.0)])
-    try:
-        with injected(plan):
-            futs = [sched.submit(p) for p in prompts]
-            outs = [f.result(timeout=15).text for f in futs]
-        assert outs == reference
-        assert wd.stalls_total["dispatch"] == 1
-        _wait_until(lambda: wd.recoveries_total == 1)
-        stats = sched.metrics.snapshot()
-        assert stats.requeues >= 3
-        # the post-recovery traffic really ran fused
-        assert stats.fused_dispatches > 0
     finally:
         plan.release_hangs()
         sched.close(timeout=5)

@@ -28,7 +28,6 @@ import gc
 import time
 from collections import Counter
 from dataclasses import dataclass, field
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -52,7 +51,6 @@ from ..models.family import family_of
 from ..models.llama import (
     LlamaConfig,
     decode_attention_mask,
-    forward,
     llama32_3b,
     prefill_attention_mask,
     prefill_positions,
@@ -72,6 +70,26 @@ def _bucket_len(n: int, max_len: int) -> int:
         if n <= b and b <= max_len:
             return b
     return max_len
+
+
+def _stream_keys(base, uids):
+    """The sampling-stream rule, stated once: the row of request ``uid`` owns
+    the stream ``fold_in(base, uid)`` (``base = key(seed)``) and its token
+    ``t`` draws from ``fold_in(stream, t)``. A request's tokens therefore
+    depend on (seed, uid, t) alone: never on its batch position, on when it
+    joined a slot loop or on whom it joined with. Returns ``at(t) -> keys``
+    for token indices ``t`` that are one scalar all rows share, one a row
+    ([B]) or several a row ([B, n], the spec step's positions)."""
+    streams = jax.vmap(lambda u: jax.random.fold_in(base, u))(uids)
+
+    def at(t):
+        one = jax.random.fold_in
+        for _ in range(jnp.ndim(t) - 1):
+            one = jax.vmap(one, in_axes=(None, 0))
+        return jax.vmap(one, in_axes=(0, 0 if jnp.ndim(t) else None))(
+            streams, t)
+
+    return at
 
 
 @dataclass
@@ -471,9 +489,9 @@ class TpuBackend:
                 self._make_fn(B, S, max_new, gen, K),
                 (self.params, i32(B, S), i32(B), 0)
                 + ((cache_of(B, S + max_new),) if K else ())))
-        for kind, B, S, max_new, gen, K, fused in self._seg_fns:
+        for kind, B, S, max_new, gen, K in self._seg_fns:
             C = S + max_new
-            label = f"{kind}[B={B},S={S},new={max_new},resume={K},fused={fused}]"
+            label = f"{kind}[B={B},S={S},new={max_new},resume={K}]"
             prompt = (self.params, i32(B, S), i32(B), 0)
             resumed = (cache_of(B, C),) if K else ()
             # a decode batch's carry after the step counter: cur, cache,
@@ -487,7 +505,7 @@ class TpuBackend:
                 fn = self._make_slot_prefill_fn(B, S, max_new, gen, K)
                 args = prompt + (i32(B),) + resumed
             elif kind == "slot_seg":
-                fn = self._make_slot_segment_fn(B, S, max_new, gen, fused)
+                fn = self._make_slot_segment_fn(B, S, max_new, gen)
                 args = (self.params, i32(B)) + carry
             else:
                 # adopt. The resident batch is not in its key: it is the B
@@ -545,29 +563,70 @@ class TpuBackend:
 
         return eos, vocab_limit, restrict
 
-    def _make_parts(self, B: int, S: int, max_new: int, gen: GenerationConfig,
-                    resume_from: int = 0):
-        """The two traceable halves every generation program is composed of:
-
-        prefill_part(params, tokens, pad_lens, seed[, cache])
-            -> (first_token, cache, done0)
-        decode_part(params, t0, cur, cache, done, uids, out, pad_lens,
-                    t_end, seed)
-            -> (t, cur, cache, done, out)
-
-        Sampling is counter-based per row: step t of row uid draws from
-        fold_in(fold_in(key(seed), uid), t). A row's stream therefore
-        depends only on (seed, uid, t) — never on its batch position.
-
-        The one-shot program is prefill + one decode to t_end=max_new in a
-        single jit; the spec path jits prefill_part alone
-        (_make_prefill_fn) and decodes with its own verify step.
+    def _make_prefill_part(self, B: int, S: int, max_new: int,
+                           gen: GenerationConfig, resume_from: int = 0):
+        """The traceable prefill every generation program starts with:
+        the prompt's forward into a cache of S + max_new slots and each
+        row's token 0, sampled from its own stream (``uids``: the rows'
+        positions where none are given, as in a one-shot batch).
 
         ``resume_from=K`` (prefix KV cache, vnsum_tpu.cache) builds the
         resume-prefill variant: prefill_part takes a cache pre-seeded with
         gathered prefix blocks and runs the forward only over cache slots
         [K, S) — positions and masks are unchanged, so the math over the
         computed span is identical to full prefill's."""
+        C = S + max_new
+        _eos, vocab_limit, restrict = self._sampling_setup(gen)
+        use_flash, _ = self._decode_settings(S, C)
+        layer_window = self._layer_window_fn()
+
+        # prefill runs whole-prompt or in prefill_chunk_tokens slices —
+        # chunking caps transient activations (q/k/v, MLP intermediates)
+        # at a chunk's worth, which is what lets B=16 decode fit at S=8192;
+        # see _prefill_forward
+        def prefill_part(params, tokens, pad_lens, seed, cache=None,
+                         uids=None):
+            with jax.named_scope("prefill"):
+                logits, cache = self._prefill_forward(
+                    params, tokens, pad_lens, B, S, C, use_flash,
+                    layer_window, cache=cache, start=resume_from,
+                )
+                with jax.named_scope("sample"):
+                    base = jax.random.key(seed)
+                    if uids is None:
+                        uids = jnp.arange(B, dtype=jnp.int32)
+                    keys0 = _stream_keys(base, uids)(0)
+                    first = sample_logits_rows(
+                        restrict(logits[:, -1, :vocab_limit]), keys0,
+                        gen.temperature, gen.top_k, gen.top_p,
+                    )
+                # all-pad dummy rows (batch bucketing filler) start done,
+                # else their garbage decode would keep the early exit from
+                # firing
+                done0 = pad_lens == S
+            return first, cache, done0
+
+        return prefill_part
+
+    def _make_parts(self, B: int, S: int, max_new: int, gen: GenerationConfig,
+                    resume_from: int = 0):
+        """The two traceable halves every generation program is composed of:
+
+        prefill_part(params, tokens, pad_lens, seed[, cache[, uids]])
+            -> (first_token, cache, done0)        (_make_prefill_part)
+        decode_part(params, t0, cur, cache, done, uids, out, pad_lens,
+                    t_end, seed)
+            -> (t, cur, cache, done, out)
+
+        Sampling is counter-based per row (``_stream_keys``): prefill
+        draws each row's token 0, decode step t its token t + 1. ``uids``
+        are the rows' positions in a one-shot batch and the requests' own
+        numbers in a slot loop's join (_make_slot_prefill_fn).
+
+        The one-shot program is prefill + one decode to t_end=max_new in a
+        single jit; the spec path jits prefill_part alone
+        (_make_prefill_fn) and decodes with its own verify step, and a slot
+        loop's join jits it with the requests' uids (_make_slot_prefill_fn)."""
         cfg = self.cfg
         C = S + max_new
         eos, vocab_limit, restrict = self._sampling_setup(gen)
@@ -580,34 +639,7 @@ class TpuBackend:
         interpret = self.interpret
         family, forward_kw = self.family, self._forward_kw
         layer_window = self._layer_window_fn()
-
-        # prefill runs whole-prompt or in prefill_chunk_tokens slices —
-        # chunking caps transient activations (q/k/v, MLP intermediates)
-        # at a chunk's worth, which is what lets B=16 decode fit at S=8192;
-        # see _prefill_forward
-        def prefill_part(params, tokens, pad_lens, seed, cache=None):
-            with jax.named_scope("prefill"):
-                logits, cache = self._prefill_forward(
-                    params, tokens, pad_lens, B, S, C, use_flash,
-                    layer_window, cache=cache, start=resume_from,
-                )
-                with jax.named_scope("sample"):
-                    base = jax.random.key(seed)
-                    uids0 = jnp.arange(B, dtype=jnp.int32)
-                    keys0 = jax.vmap(
-                        lambda u: jax.random.fold_in(
-                            jax.random.fold_in(base, u), 0
-                        )
-                    )(uids0)
-                    first = sample_logits_rows(
-                        restrict(logits[:, -1, :vocab_limit]), keys0,
-                        gen.temperature, gen.top_k, gen.top_p,
-                    )
-                # all-pad dummy rows (batch bucketing filler) start done,
-                # else their garbage decode would keep the early exit from
-                # firing
-                done0 = pad_lens == S
-            return first, cache, done0
+        prefill_part = self._make_prefill_part(B, S, max_new, gen, resume_from)
 
         def decode_part(
             params, t0, cur, cache, done, uids, out, pad_lens, t_end, seed
@@ -639,11 +671,7 @@ class TpuBackend:
                     mask_t, stacked_attention_fn=stacked_fn, **forward_kw,
                 )
                 with jax.named_scope("sample"):
-                    step_keys = jax.vmap(
-                        lambda u: jax.random.fold_in(
-                            jax.random.fold_in(base, u), t + 1
-                        )
-                    )(uids)
+                    step_keys = _stream_keys(base, uids)(t + 1)
                     nxt = sample_logits_rows(
                         restrict(logits[:, -1, :vocab_limit]), step_keys,
                         gen.temperature, gen.top_k, gen.top_p,
@@ -689,13 +717,10 @@ class TpuBackend:
                 return out, counters(cache)
             return (out, cache) if return_cache else out  # out: [B, max_new]
 
-        if resume_from:
-            # the seeded cache is consumed — donate its buffer
-            return jax.jit(run, donate_argnums=(4,))
-
         def generate(params, tokens, pad_lens, seed):
             return run(params, tokens, pad_lens, seed, None)
 
+        out_sh = None
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -714,12 +739,30 @@ class TpuBackend:
                         is_leaf=lambda x: not isinstance(x, dict),
                     ),
                 )
-            return jax.jit(
-                generate,
-                in_shardings=self._mesh_in_shardings(),
-                out_shardings=out_sh,
-            )
-        return jax.jit(generate)
+        return self._jit_program(generate, run, resume_from,
+                                 out_shardings=out_sh)
+
+    def _jit_program(self, fresh, seeded, resume_from: int, *,
+                     row_args: int = 0, out_shardings=None):
+        """The one way a program over (params, tokens, pad_lens, seed and
+        ``row_args`` more vectors of a number a row) is jitted. A resume
+        program (``seeded``: the same arguments, then the cache the prefix
+        pool seeded) consumes that cache, so its buffer is donated; under a
+        mesh the cache's layout was committed by the sharded gather, so
+        propagation and not in_shardings carries it through. Any other
+        (``fresh``) compiles against ``_mesh_in_shardings()`` under a mesh,
+        the further vectors riding the batch rows on `data`."""
+        if resume_from:
+            return jax.jit(seeded, donate_argnums=(4 + row_args,))
+        if self.mesh is None:
+            return jax.jit(fresh)
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        shardings = {"in_shardings": self._mesh_in_shardings()
+                     + (NamedSharding(self.mesh, P("data")),) * row_args}
+        if out_shardings is not None:
+            shardings["out_shardings"] = out_shardings
+        return jax.jit(fresh, **shardings)
 
     def _mesh_in_shardings(self):
         """in_shardings for (params, tokens, pad_lens, seed) — shared by the
@@ -1029,72 +1072,32 @@ class TpuBackend:
                          resume_from: int = 0):
         prefill_part, _ = self._make_parts(B, S, max_new, gen, resume_from)
 
-        if resume_from:
-            return jax.jit(prefill_part, donate_argnums=(4,))
-
         def prefill(params, tokens, pad_lens, seed):
             return prefill_part(params, tokens, pad_lens, seed)
 
-        if self.mesh is not None:
-            return jax.jit(prefill, in_shardings=self._mesh_in_shardings())
-        return jax.jit(prefill)
+        return self._jit_program(prefill, prefill_part, resume_from)
 
     # -- in-flight slot serving programs (backend/inflight.py) -----------
 
     def _make_slot_prefill_fn(self, B: int, S: int, max_new: int, gen,
                               resume_from: int = 0):
-        """Prefill for a JOIN group of the in-flight slot loop: the same
-        forward as _make_parts' prefill_part (shared _prefill_forward, so
-        chunked and resume prefill ride along), but the first-token sampling
-        keys fold per-REQUEST uids passed in rather than the row's position
-        in the join batch — a request's sampled stream must not depend on
-        when it joined or who it joined with."""
-        C = S + max_new
-        _eos, vocab_limit, restrict = self._sampling_setup(gen)
-        use_flash, _ = self._decode_settings(S, C)
+        """Prefill for a JOIN group of the in-flight slot loop: the one-shot
+        program's prefill_part (chunked and resume prefill ride along), its
+        first-token keys folding the per-REQUEST uids passed in and not the
+        rows' positions in the join batch — a request's sampled stream must
+        not depend on when it joined or who it joined with. All-pad filler
+        rows (join-batch bucketing) start done, as a one-shot batch's do."""
+        use_flash, _ = self._decode_settings(S, S + max_new)
         self._note_attention("slot_prefill", B, S, prefill=use_flash)
-        layer_window = self._layer_window_fn()
+        prefill_part = self._make_prefill_part(B, S, max_new, gen, resume_from)
 
         def slot_prefill(params, tokens, pad_lens, seed, uids, cache=None):
-            with jax.named_scope("prefill"):
-                logits, cache = self._prefill_forward(
-                    params, tokens, pad_lens, B, S, C, use_flash,
-                    layer_window, cache=cache, start=resume_from,
-                )
-                with jax.named_scope("sample"):
-                    base = jax.random.key(seed)
-                    keys0 = jax.vmap(
-                        lambda u: jax.random.fold_in(
-                            jax.random.fold_in(base, u), 0
-                        )
-                    )(uids)
-                    first = sample_logits_rows(
-                        restrict(logits[:, -1, :vocab_limit]), keys0,
-                        gen.temperature, gen.top_k, gen.top_p,
-                    )
-                # all-pad filler rows (join-batch bucketing) start done
-                done0 = pad_lens == S
-            return first, cache, done0
+            return prefill_part(params, tokens, pad_lens, seed, cache, uids)
 
-        if resume_from:
-            # the prefix-cache-seeded cache is consumed — donate its buffer;
-            # under a mesh its layout is committed by the sharded gather, so
-            # propagation (not in_shardings) carries the mesh layout through
-            return jax.jit(slot_prefill, donate_argnums=(5,))
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        return self._jit_program(slot_prefill, slot_prefill, resume_from,
+                                 row_args=1)
 
-            # same input layouts as every other prefill builder, plus the
-            # per-request uids vector riding the batch rows on `data`
-            return jax.jit(
-                slot_prefill,
-                in_shardings=self._mesh_in_shardings()
-                + (NamedSharding(self.mesh, P("data")),),
-            )
-        return jax.jit(slot_prefill)
-
-    def _make_slot_segment_fn(self, B: int, S: int, max_new: int, gen,
-                              fused_segments: int = 1):
+    def _make_slot_segment_fn(self, B: int, S: int, max_new: int, gen):
         """One in-flight decode segment: advance every live slot by up to
         ``segment_tokens`` tokens with PER-ROW step counters — the refill
         path's defining requirement is that slots at different generation
@@ -1103,14 +1106,7 @@ class TpuBackend:
         spec-verify machinery (verify_attention_mask + vector write_index,
         num_q=1). For any single row the emitted-token math is exactly
         decode_part's, so greedy outputs match the one-shot path up to the
-        last bits that another batch shape's matmul tiling moves.
-
-        ``fused_segments`` fuses N host boundaries into ONE dispatch
-        (Kernel Looping, arXiv 2410.23668): the same while_loop simply runs
-        to ``segment_tokens * N`` with the on-device all-rows-done stop
-        unchanged — per-row math is identical to N back-to-back dispatches,
-        so greedy outputs are byte-identical to N=1 by construction; only
-        the host's join/poll cadence coarsens."""
+        last bits that another batch shape's matmul tiling moves."""
         cfg = self.cfg
         C = S + max_new
         eos, vocab_limit, restrict = self._sampling_setup(gen)
@@ -1121,8 +1117,9 @@ class TpuBackend:
         use_kernel = use_flash_decode and self.mesh is None
         self._note_attention("slot_seg", B, S, decode=use_kernel)
         interpret = self.interpret
+        family, forward_kw = self.family, self._forward_kw
         layer_window = self._layer_window_fn()
-        seg = self.segment_tokens * max(int(fused_segments), 1)
+        seg = self.segment_tokens
 
         def segment(params, t, cur, cache, done, uids, out, pads, seed):
             def emit_row(o, c, tt, d):
@@ -1158,16 +1155,12 @@ class TpuBackend:
                             interpret=interpret,
                         )
 
-                logits, cache = forward(
+                logits, cache = family.forward(
                     params, cfg, cur[:, None], positions, cache, fills,
-                    mask, stacked_attention_fn=stacked_fn,
+                    mask, stacked_attention_fn=stacked_fn, **forward_kw,
                 )
                 with jax.named_scope("sample"):
-                    step_keys = jax.vmap(
-                        lambda u, tt: jax.random.fold_in(
-                            jax.random.fold_in(base, u), tt + 1
-                        )
-                    )(uids, t)
+                    step_keys = _stream_keys(base, uids)(t + 1)
                     nxt = sample_logits_rows(
                         restrict(logits[:, -1, :vocab_limit]), step_keys,
                         gen.temperature, gen.top_k, gen.top_p,
@@ -1226,7 +1219,6 @@ class TpuBackend:
         max_new_tokens: int | None = None,
         config: GenerationConfig | None = None,
         prompt_tokens: int = 0,
-        fused_segments: int = 1,
     ):
         """Open a persistent in-flight serving loop: a fixed-shape decode
         batch of ``slots`` rows where finished rows are harvested at every
@@ -1239,10 +1231,7 @@ class TpuBackend:
         ``prompt_tokens`` fixes the prompt bucket S (0 = the full context
         minus the decode budget); prompts that don't fit are rejected at
         admit for the caller to route through the one-shot path, which is
-        generate()'s only program. ``fused_segments`` fuses N decode segments
-        into one dispatch with async host polling (see TpuSlotLoop.step) —
-        joins/cancels/preemption coarsen to the fused cadence while greedy
-        outputs stay byte-identical to N=1."""
+        generate()'s only program."""
         from .inflight import TpuSlotLoop
 
         self.family.refuse("slot loop")
@@ -1281,12 +1270,11 @@ class TpuBackend:
             )
         return TpuSlotLoop(
             self, n_slots, S, max_new, gen, seed=self._next_seed(gen),
-            fused_segments=fused_segments,
         )
 
     def _get_seg_fn(self, kind: str, B: int, S: int, max_new: int, gen,
-                    resume_from: int = 0, fused: int = 1):
-        key = (kind, B, S, max_new, gen.with_(seed=0), resume_from, fused)
+                    resume_from: int = 0):
+        key = (kind, B, S, max_new, gen.with_(seed=0), resume_from)
         if key not in self._seg_fns:
             self.family.refuse("slot loop")
             if kind == "prefill":
@@ -1294,15 +1282,14 @@ class TpuBackend:
             elif kind == "slot_prefill":
                 fn = self._make_slot_prefill_fn(B, S, max_new, gen, resume_from)
             elif kind == "slot_seg":
-                fn = self._make_slot_segment_fn(B, S, max_new, gen, fused)
+                fn = self._make_slot_segment_fn(B, S, max_new, gen)
             elif kind == "adopt":
                 fn = self._make_adopt_fn(B)
             else:
                 raise ValueError(f"no program of kind {kind!r}")
             self._seg_fns[key] = self._timed_first_call(
                 fn,
-                f"{kind}[B={B},S={S},new={max_new},resume={resume_from},"
-                f"fused={fused}]",
+                f"{kind}[B={B},S={S},new={max_new},resume={resume_from}]",
             )
         return self._seg_fns[key]
 
@@ -1348,6 +1335,7 @@ class TpuBackend:
         use_verify_kernel = use_flash_decode and self.mesh is None
         self._note_attention("spec", B, S, decode=use_verify_kernel)
         interpret = self.interpret
+        family, forward_kw = self.family, self._forward_kw
         layer_window = self._layer_window_fn()
 
         def spec_step(params, cur, cache, done, e, out, pads, ref,
@@ -1387,9 +1375,9 @@ class TpuBackend:
                         layer_window(layer_idx), interpret=interpret,
                     )
 
-            logits, cache = forward(
+            logits, cache = family.forward(
                 params, cfg, toks, positions, cache, fills, mask,
-                stacked_attention_fn=stacked_fn,
+                stacked_attention_fn=stacked_fn, **forward_kw,
             )
             logits = restrict(logits[:, :, :vocab_limit])
 
@@ -1398,11 +1386,7 @@ class TpuBackend:
             # that absolute position so acceptance raggedness never replays
             # a row's randomness
             pos_ids = e[:, None] + jnp.arange(k1, dtype=jnp.int32)[None, :] + 1
-            keys = jax.vmap(
-                lambda u, ps: jax.vmap(
-                    lambda p: jax.random.fold_in(jax.random.fold_in(base, u), p)
-                )(ps)
-            )(uids, pos_ids)
+            keys = _stream_keys(base, uids)(pos_ids)
             m, nxt = draft_acceptance_rows(
                 logits, drafts, n_draft, keys,
                 gen.temperature, gen.top_k, gen.top_p,

@@ -367,7 +367,6 @@ class FakeBackend:
         max_new_tokens: int | None = None,
         config: GenerationConfig | None = None,
         prompt_tokens: int = 0,
-        fused_segments: int = 1,
     ) -> "FakeSlotLoop":
         """The in-flight batching contract, hermetically: admission runs the
         REAL radix prefix index (when configured) and sleeps the prefill
@@ -376,15 +375,11 @@ class FakeBackend:
         extractive output and sleeps the segment model. ``prompt_tokens``
         bounds admitted prompts exactly like the engine's S bucket (0 =
         unlimited) so scheduler fallback paths are testable without a
-        device. ``fused_segments`` mirrors TpuSlotLoop's fused multi-step
-        decode: one step() covers up to N segments and charges
-        ``segment_overhead_s`` ONCE per dispatch (per-step cost unchanged)
-        — the dispatch-amortization economics the fused A/B measures."""
+        device."""
         max_new = max_new_tokens
         if max_new is None and config is not None:
             max_new = config.max_new_tokens
-        return FakeSlotLoop(self, slots or 8, prompt_tokens, max_new,
-                            fused_segments=fused_segments)
+        return FakeSlotLoop(self, slots or 8, prompt_tokens, max_new)
 
 
 class FakeSlotLoop:
@@ -394,7 +389,7 @@ class FakeSlotLoop:
     the same scheduler paths the real engine loop serves."""
 
     def __init__(self, backend: FakeBackend, slots: int, prompt_tokens: int,
-                 max_new: int | None, fused_segments: int = 1) -> None:
+                 max_new: int | None) -> None:
         from .inflight import (
             SegmentResult,
             SlotAdmission,
@@ -414,9 +409,7 @@ class FakeSlotLoop:
         self._words: list[list[str] | None] = [None] * self.slots
         self._prompts: list[str | None] = [None] * self.slots
         self._emitted: list[int] = [0] * self.slots
-        self.fused_segments = max(int(fused_segments), 1)
-        self.segments = 0           # inner segments retired (device cadence)
-        self.fused_dispatches = 0   # step() calls that did work
+        self.segments = 0           # step() calls that did work
         self.refills = 0
         self._closed = False
 
@@ -488,13 +481,11 @@ class FakeSlotLoop:
         return admissions, rejected
 
     def step(self):
-        """One FUSED dispatch: up to ``fused_segments`` inner segments with
-        the on-device early stop mirrored (a window whose rows all finish
-        stops advancing), harvest at dispatch retirement only. The latency
-        model charges ``segment_overhead_s`` ONCE per dispatch — that is
-        the dispatch/sync tax fusing amortizes — while per-slot-segment and
-        per-step costs accrue for the work actually run, so the fused A/B
-        is honest hermetically."""
+        """One decode segment: every live row advances by up to
+        ``segment_words`` words, then finished rows are harvested. The
+        latency model charges ``segment_overhead_s`` for the dispatch,
+        ``per_slot_segment_s`` for the live rows a DP replica holds and
+        ``per_step_s`` for the deepest row's steps."""
         if self._closed:
             raise RuntimeError("slot loop is closed")
         res = self._SegmentResult(live=self.active)
@@ -508,33 +499,20 @@ class FakeSlotLoop:
         b = self.backend
         t0 = time.monotonic()
         steps = 0
-        segments_run = 0
-        slot_segment_units = 0  # sum over inner segments of ceil(live/rep)
-        for _ in range(self.fused_segments):
-            live_rows = [
-                s for s, k in enumerate(self._keys)
-                if k is not None and self._emitted[s] < len(self._words[s])
-            ]
-            if not live_rows:
-                break  # the on-device all-rows-done stop
-            seg_steps = 0
-            for s in live_rows:
-                words = self._words[s]
-                advance = min(b.segment_words, len(words) - self._emitted[s])
-                seg_steps = max(seg_steps, advance)
-                self._emitted[s] += advance
-                res.new_tokens += advance
-            steps += seg_steps
-            segments_run += 1
-            slot_segment_units += -(-len(live_rows) // b.dp_replicas)
-        res.device_segments = max(segments_run, 1)
+        live_rows = [
+            s for s, k in enumerate(self._keys)
+            if k is not None and self._emitted[s] < len(self._words[s])
+        ]
+        for s in live_rows:
+            words = self._words[s]
+            advance = min(b.segment_words, len(words) - self._emitted[s])
+            steps = max(steps, advance)
+            self._emitted[s] += advance
+            res.new_tokens += advance
         seg_s = (
-            # ONE dispatch overhead per fused window — the host round-trip
-            # cost fusing exists to amortize
             b.segment_overhead_s
-            # live rows spread over DP replicas, per inner segment actually
-            # run; segment depth doesn't
-            + b.per_slot_segment_s * slot_segment_units
+            # live rows spread over DP replicas; segment depth doesn't
+            + b.per_slot_segment_s * -(-len(live_rows) // b.dp_replicas)
             + b.per_step_s * steps
         )
         if seg_s:
@@ -551,11 +529,9 @@ class FakeSlotLoop:
                 self._keys[s] = None
                 self._words[s] = None
                 self._prompts[s] = None
-        self.segments += res.device_segments
-        self.fused_dispatches += 1
+        self.segments += 1
         res.seconds = time.monotonic() - t0
-        emit("decode_seg", t0, res.seconds, live=res.live, refill=True,
-             fused=res.device_segments)
+        emit("decode_seg", t0, res.seconds, live=res.live, refill=True)
         return res
 
     def evict(self, keys, pin: bool = True):

@@ -77,14 +77,12 @@ class SlotCompletion:
 
 @dataclass
 class SegmentResult:
-    """One decode dispatch's outcome (up to ``fused_segments`` on-device
-    segment boundaries per dispatch — N=1 is the classic one-segment step)."""
+    """One decode segment's outcome."""
 
     completions: list = field(default_factory=list)
     live: int = 0               # rows live at dispatch start
     new_tokens: int = 0         # tokens retired across all rows this dispatch
     seconds: float = 0.0
-    device_segments: int = 1    # segments the fused dispatch actually ran
 
 
 @dataclass
@@ -117,7 +115,7 @@ class TpuSlotLoop:
     """
 
     def __init__(self, backend, slots: int, S: int, max_new: int, gen,
-                 seed: int, fused_segments: int = 1) -> None:
+                 seed: int) -> None:
         import jax.numpy as jnp
 
         self.backend = backend
@@ -126,10 +124,6 @@ class TpuSlotLoop:
         self.max_new = int(max_new)
         self.gen = gen
         self.seed = seed
-        # fused multi-step decode (Kernel Looping, arXiv 2410.23668): one
-        # dispatch covers up to N on-device segment boundaries, and the
-        # host polls array readiness instead of blocking per segment
-        self.fused_segments = max(int(fused_segments), 1)
         b = backend
         B = self.slots
         # resident device state: every slot starts FREE (all-pad, done)
@@ -164,8 +158,7 @@ class TpuSlotLoop:
         self._admissions: dict[int, SlotAdmission] = {}
         self._t_host = np.zeros((B,), np.int64)
         self._uid_next = 0
-        self.segments = 0           # on-device segments retired
-        self.fused_dispatches = 0   # host dispatches (== segments at N=1)
+        self.segments = 0           # decode segments dispatched
         self.refills = 0
         # boundary out-buffer snapshot: when step(fetch_outputs=True) rode
         # the control fetch, partial_outputs serves from it instead of
@@ -368,7 +361,7 @@ class TpuSlotLoop:
     def _await_retirement(arrays) -> None:
         """Async host polling: request the d2h copies up front (non-
         blocking), then poll ``jax.Array`` readiness with a backing-off
-        sleep until the fused dispatch retires. The host never blocks
+        sleep until the segment retires. The host never blocks
         inside the runtime while the device is still looping — the poll
         is pure host time, and the later explicit ``device_get`` finds the
         copies already landed. On a TPU the transfer guard counts
@@ -388,12 +381,12 @@ class TpuSlotLoop:
 
     # hot path
     def step(self) -> SegmentResult:
-        """Advance every live slot by up to ``segment_tokens *
-        fused_segments`` tokens in ONE dispatch (the on-device while_loop
-        owns the early all-rows-done stop), then harvest finished rows at
-        the boundary. The host does not block per segment: it dispatches
-        the fused program, polls array readiness asynchronously, and pays
-        ONE coalesced done/t/out fetch when the dispatch retires. The out
+        """Advance every live slot by up to ``segment_tokens`` tokens in
+        one dispatch (the on-device while_loop owns the early all-rows-done
+        stop), then harvest finished rows at the boundary. The host does
+        not block inside the runtime: it dispatches the segment, polls
+        array readiness asynchronously, and pays ONE coalesced done/t/out
+        fetch when the dispatch retires. The out
         snapshot it leaves behind serves ``partial_outputs`` — a streaming
         boundary costs one d2h, not two."""
         if self._closed:
@@ -409,10 +402,9 @@ class TpuSlotLoop:
         sink = b.stats.host_spans
         seg_fn = b._get_seg_fn(
             "slot_seg", self.slots, self.S, self.max_new, self.gen,
-            fused=self.fused_segments,
         )
         self._out_snap = None
-        # one span a fused DISPATCH, call to boundary fetch (the collector's
+        # one span a segment, call to boundary fetch (the collector's
         # "decode_seg"); nothing inside _await_retirement's poll
         seg = host_span("slot", "segment", sink, event="decode_seg",
                         B=self.slots, S=self.S, live=res.live, refill=True)
@@ -442,20 +434,10 @@ class TpuSlotLoop:
                     s for s, k in enumerate(self._keys)
                     if k is not None and done_h[s]
                 ]
-            deltas = [
+            res.new_tokens = sum(
                 int(t_h[s]) - int(self._t_host[s])
                 for s, k in enumerate(self._keys) if k is not None
-            ]
-            res.new_tokens = int(sum(deltas))
-            # how many on-device segment boundaries the fused dispatch crossed:
-            # the deepest row's advance, in segment_tokens units (early-stopped
-            # dispatches report fewer than fused_segments)
-            seg_tokens = max(int(b.segment_tokens), 1)
-            res.device_segments = min(
-                max(-(-max(deltas, default=0) // seg_tokens), 1),
-                self.fused_segments,
             )
-            seg.note(fused=res.device_segments)
         res.seconds = seg.dur
         for s, k in enumerate(self._keys):
             if k is not None:
@@ -471,8 +453,7 @@ class TpuSlotLoop:
                 self._keys[s] = None
                 self._prompts[s] = None
                 self._admissions.pop(s, None)
-        self.segments += res.device_segments
-        self.fused_dispatches += 1
+        self.segments += 1
         return res
 
     # -- preemption / streaming (serve/qos.py + serve/stream.py) ---------

@@ -169,9 +169,6 @@ class ServingStats:
     # loop.step): gaps counted and their seconds, the window's wait included
     host_gaps: int = 0
     host_gap_seconds: float = 0.0
-    # fused multi-step decode: host dispatches of the slot loop (each
-    # covers up to --fused-segments on-device segments; == segments at N=1)
-    fused_dispatches: int = 0
     # fault tolerance (serve/supervisor.py): classified dispatch failures,
     # retries scheduled, bisection splits, requests quarantined as poison,
     # total backoff slept, and degradation-ladder transitions

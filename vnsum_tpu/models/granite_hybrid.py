@@ -549,11 +549,11 @@ def _family():
         missing={
             "slot loop": (
                 "the slot programs (backend/inflight.py, engine._make_slot_*"
-                ", _make_adopt_fn) call models.llama.forward by name, fill "
-                "one row at a time and scatter a joined batch's cache leaf "
-                "by leaf as keys and values; adopting, evicting and filling "
-                "a row would have to move a recurrent state they do not "
-                "carry: " + carries_state),
+                ", _make_adopt_fn) fill one row at a time and scatter every "
+                "leaf of a joined batch's cache on its second axis, as keys "
+                "and values; adopting, evicting and filling a row would "
+                "have to move a recurrent state they do not carry: "
+                + carries_state),
             "prefix cache": (
                 "cache/radix.py and cache/store.py slice keys and values "
                 "by block at any token; a recurrent state can be resumed "

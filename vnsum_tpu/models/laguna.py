@@ -539,9 +539,9 @@ def _family():
         missing={
             "slot loop": (
                 "the slot programs (backend/inflight.py, engine._make_slot_*"
-                ", _make_adopt_fn) call models.llama.forward by name and "
-                "scatter a joined batch's cache leaf by leaf as keys and "
-                "values: " + carries_counters + "; " + one_group),
+                ", _make_adopt_fn) scatter every leaf of a joined batch's "
+                "cache on its second axis, as keys and values, and return "
+                "no counters: " + carries_counters + "; " + one_group),
             "prefix cache": (
                 "the resume program (cache/store.py gather, engine."
                 "_prepare_resume) seeds a KV cache alone and returns the "
@@ -552,8 +552,9 @@ def _family():
                 "experts, no expert axis and no exchange of the experts' "
                 "partial sums"),
             "speculative decoding": (
-                "the verify step calls models.llama.forward by name with "
-                "per-row write slots: " + carries_counters + "; " + one_group),
+                "the verify step writes the cache at per-row slots and its "
+                "host loop hands a KV cache from step to step: "
+                + carries_counters + "; " + one_group),
             "long-context backend": (
                 "the ring prefill runs models.llama.cache_free_block, "
                 "which has no window, no head gate, one rotary scheme and "

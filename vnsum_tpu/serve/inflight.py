@@ -78,7 +78,6 @@ class InflightScheduler(MicroBatchScheduler):
         slot_prompt_tokens: int = 0,
         switch_grace_s: float = 0.5,
         preempt_budget: int = 16,
-        fused_segments: int = 1,
         **kw,
     ) -> None:
         if not callable(getattr(backend, "start_slot_loop", None)):
@@ -91,11 +90,6 @@ class InflightScheduler(MicroBatchScheduler):
         self.slots = slots or kw.get("max_batch", 8)
         self.slot_prompt_tokens = slot_prompt_tokens
         self.switch_grace_s = switch_grace_s
-        # fused multi-step decode: the loop dispatches N on-device segments
-        # per host round-trip, so joins, cancel/preempt polls, and stream
-        # deltas run at the FUSED cadence — the TTFT/goodput trade knob
-        # (--fused-segments)
-        self.fused_segments = max(int(fused_segments), 1)
         # preemption cap per request: a batch-tier request evicted this
         # many times becomes non-evictable — bounded interference instead
         # of starvation-by-interactive-pressure (it keeps its slot from
@@ -404,8 +398,6 @@ class InflightScheduler(MicroBatchScheduler):
         nobody will ever resume. Freed slots refill from the queue at this
         very boundary, which is what makes cancelling a saturating tenant
         hand the engine back within one segment."""
-        if not self.cancellation_enabled:
-            return
         if not self._cancelled_ids and self.stream_idle_timeout_s is None:
             return  # unlocked fast path, same contract as the base sweep
         self._cancel_sweep()
@@ -580,7 +572,6 @@ class InflightScheduler(MicroBatchScheduler):
             max_new_tokens=head.max_new_tokens,
             config=head.config,
             prompt_tokens=self.slot_prompt_tokens,
-            fused_segments=self.fused_segments,
         )
         self._live_loop = loop
         return loop
@@ -692,13 +683,8 @@ class InflightScheduler(MicroBatchScheduler):
         # of trace ids per segment would be allocation for a report field
         ticket = None
         if self.watchdog is not None:
-            # N-scaled: a fused dispatch holds the host for up to N
-            # segments of legitimate work — budget accordingly, so fusing
-            # never manufactures a false HUNG (and a real hang still trips
-            # after N segment budgets)
             ticket = self.watchdog.begin_dispatch(
-                "scheduler", "slot_segment",
-                self.watchdog.segment_budget(self.fused_segments),
+                "scheduler", "slot_segment", self.watchdog.segment_budget_s,
             )
         try:
             res = self._device_call(loop.step)
@@ -720,10 +706,7 @@ class InflightScheduler(MicroBatchScheduler):
             self._complete_segment(loop, res)
 
     def _complete_segment(self, loop, res) -> None:
-        self.metrics.observe_segment(
-            res.live, res.seconds, res.new_tokens,
-            device_segments=getattr(res, "device_segments", 1),
-        )
+        self.metrics.observe_segment(res.live, res.seconds, res.new_tokens)
         now = time.monotonic()
         self._just_finished = len(res.completions)
         self._emit_stream_deltas(loop)

@@ -110,14 +110,6 @@ _reg("inflight_host_gap_seconds_total", "counter",
      "idle loop ends the gap uncounted")
 _reg("inflight_host_gaps_total", "counter",
      "gaps counted in inflight_host_gap_seconds_total")
-_reg("inflight_fused_dispatches_total", "counter",
-     "fused slot-loop dispatches by the in-flight scheduler (each covers "
-     "up to --fused-segments on-device decode segments; equals "
-     "inflight_segments_total at N=1)")
-_reg("inflight_fused_segments", "histogram",
-     "on-device decode segments retired per fused slot-loop dispatch "
-     "(the on-device all-rows-done stop reports fewer than the "
-     "configured N on early exit)")
 _reg("slots_total", "gauge",
      "decode slots of the in-flight loop (scrape-time; in-flight mode only)")
 _reg("slots_busy", "gauge",
@@ -403,7 +395,6 @@ class ServeMetrics:
             "e2e_seconds": Histogram(E2E_BUCKETS_S),
             "batch_occupancy": Histogram(OCCUPANCY_BUCKETS),
             "slot_occupancy": Histogram(OCCUPANCY_BUCKETS),
-            "inflight_fused_segments": Histogram(OCCUPANCY_BUCKETS),
             "spec_accepted_per_step": Histogram(ACCEPT_BUCKETS),
         }
         self._rolling_accept = Rolling(256)     # guarded by: _lock
@@ -476,22 +467,14 @@ class ServeMetrics:
             self._rolling_tps.add(gen_tokens, engine_s)
 
     def observe_segment(self, live: int, seg_s: float,
-                        gen_tokens: int = 0,
-                        device_segments: int = 1) -> None:
-        """One in-flight decode dispatch: slot occupancy, engine residency,
+                        gen_tokens: int = 0) -> None:
+        """One in-flight decode segment: slot occupancy, engine residency,
         and the tokens it retired (feeds the rolling tokens/s gauge the way
-        observe_batch does for batch dispatches). ``device_segments`` is
-        how many on-device segment boundaries the dispatch covered —
-        segments_total counts those (device cadence) while
-        fused_dispatches counts host round trips, so the two series
-        diverge exactly by the fusing win."""
+        observe_batch does for batch dispatches)."""
         with self._lock:
-            n = max(int(device_segments), 1)
-            self._stats.segments += n
-            self._stats.fused_dispatches += 1
+            self._stats.segments += 1
             self._stats.engine_seconds += seg_s
             self._hists["slot_occupancy"].observe(live)
-            self._hists["inflight_fused_segments"].observe(n)
             self._rolling_tps.add(gen_tokens, seg_s)
 
     def observe_refill(self, n: int = 1) -> None:
@@ -873,7 +856,6 @@ class ServeMetrics:
         simple("inflight_host_gap_seconds_total",
                round(s.host_gap_seconds, 6))
         simple("inflight_host_gaps_total", s.host_gaps)
-        simple("inflight_fused_dispatches_total", s.fused_dispatches)
         typ, help_ = _METRICS["fault_failures_total"]
         lines.append(f"# HELP {_PREFIX}fault_failures_total {help_}")
         lines.append(f"# TYPE {_PREFIX}fault_failures_total {typ}")

@@ -186,10 +186,6 @@ class MicroBatchScheduler:
         # cancelled by the sweep. None = disabled (library default; the
         # HTTP server arms it via --stream-idle-timeout-s)
         self.stream_idle_timeout_s: float | None = None
-        # A/B lever: False skips the per-iteration cancel sweeps so the
-        # unused-path overhead is measurable against the same build. Never
-        # an operator flag — cancellation is part of the serving contract
-        self.cancellation_enabled = True
         self._closed = False
         # liveness (serve/watchdog.py): None = unmonitored (the pre-watchdog
         # contract, and the bench A/B's off arm). With a Watchdog, the loop
@@ -397,7 +393,7 @@ class MicroBatchScheduler:
         # racy read of scheduler-thread state for the COUNT only (stale =
         # off by one, never a crash); the authoritative reclaim runs on the
         # scheduler thread at the next segment boundary
-        pending = [] if self.cancellation_enabled is False else [
+        pending = [
             r for r in self._stranded_snapshot() if r.trace_id == rid
         ]
         known = bool(removed or pending or already)
@@ -420,8 +416,6 @@ class MicroBatchScheduler:
         idle streaming consumer), or None. The unlocked emptiness probe is
         the fast path: with no cancels and no idle window armed this is two
         attribute reads per call."""
-        if not self.cancellation_enabled:
-            return None
         # lint-allow[guarded-by]: unlocked EMPTINESS probe only — a stale read delays detection by one boundary; the authoritative lookup below holds the lock
         if self._cancelled_ids:
             with self._cancel_lock:
@@ -442,8 +436,6 @@ class MicroBatchScheduler:
         (or consumer-abandoned) requests out of the queue and resolve them.
         Residents/pending are swept by the in-flight subclass; the one-shot
         batch is checked inside _dispatch."""
-        if not self.cancellation_enabled:
-            return
         # lint-allow[guarded-by]: unlocked EMPTINESS probe only — the per-iteration fast path; a stale read delays one sweep, the matching reads hold the lock
         if not self._cancelled_ids and self.stream_idle_timeout_s is None:
             return  # unlocked fast path: nothing can match
@@ -754,7 +746,7 @@ class MicroBatchScheduler:
         # The poll runs on THIS thread inside generate — _cancelled ids are
         # read under their own lock, no engine state is touched
         set_poll = getattr(self.backend, "set_cancel_poll", None)
-        if callable(set_poll) and self.cancellation_enabled:
+        if callable(set_poll):
             set_poll(lambda: all(
                 self._cancel_reason_for(r) is not None for r in batch
             ))
@@ -784,8 +776,7 @@ class MicroBatchScheduler:
             self._wd_end(ticket)
             if token is not None:
                 reset_collector(token)
-            if (callable(set_poll) and self.cancellation_enabled
-                    and not self._stale_thread()):
+            if callable(set_poll) and not self._stale_thread():
                 # a stale thread must not clear the SUCCESSOR's poll
                 set_poll(None)
         if self._stale_thread():

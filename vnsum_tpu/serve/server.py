@@ -129,7 +129,6 @@ class ServeState:
         inflight: bool = False,
         slots: int | None = None,
         slot_prompt_tokens: int = 0,
-        fused_segments: int = 1,
         supervisor=None,
         supervise: bool = True,
         journal_dir: str | None = None,
@@ -145,8 +144,6 @@ class ServeState:
         slo_burn_slow: float = 1.0,
         flight_dir: str | None = None,
         flight_events: int = 4096,
-        flight_recorder: bool = True,
-        windowed_metrics: bool = True,
         watchdog: bool = True,
         watchdog_interval_s: float = 0.5,
         watchdog_stall_s: float = 10.0,
@@ -227,13 +224,10 @@ class ServeState:
         # production observability (this PR's tentpole): rolling-window
         # metrics + per-tenant usage ledger (serve/metrics.py over
         # obs/window.py), the flight recorder (obs/recorder.py), and the
-        # SLO engine (serve/slo.py). windowed_metrics=False /
-        # flight_recorder=False are the bench A/B's all-off levers — never
-        # operator flags (always-on is the serving contract)
+        # SLO engine (serve/slo.py); the first two are always on
         from .metrics import ServeMetrics
 
         self.metrics = ServeMetrics(
-            windowed=windowed_metrics,
             horizon_s=max(slo_slow_s, 2 * slo_fast_s),
             sub_windows=60,
         )
@@ -244,10 +238,8 @@ class ServeState:
             self.metrics.seed_tenants(tenants.stats().keys())
         from ..obs.recorder import FlightRecorder
 
-        self.recorder = (
-            FlightRecorder(capacity=flight_events, directory=flight_dir)
-            if flight_recorder else None
-        )
+        self.recorder = FlightRecorder(
+            capacity=flight_events, directory=flight_dir)
         # liveness (serve/watchdog.py, this PR's tentpole): heartbeat
         # registry + bounded-dispatch contract + stall recovery. ON by
         # default — hang detection is part of the serving contract;
@@ -297,8 +289,7 @@ class ServeState:
 
             self.scheduler = InflightScheduler(
                 backend, slots=slots,
-                slot_prompt_tokens=slot_prompt_tokens,
-                fused_segments=fused_segments, **common,
+                slot_prompt_tokens=slot_prompt_tokens, **common,
             )
         else:
             self.scheduler = MicroBatchScheduler(backend, **common)
@@ -448,9 +439,8 @@ class ServeState:
                 continue
             n += 1
         self.journal.note_replay(n, time.monotonic() - t0)
-        if self.recorder is not None:
-            self.recorder.record("journal_replay", replayed=n,
-                                 seconds=round(time.monotonic() - t0, 6))
+        self.recorder.record("journal_replay", replayed=n,
+                             seconds=round(time.monotonic() - t0, 6))
         if n:
             logger.info("journal replay: re-enqueued %d request(s)", n)
         self._replay_done = True
@@ -543,11 +533,10 @@ class ServeState:
             "wall_now": time.time(),
             "stacks": snapshot_stacks(),
         }
-        if self.recorder is not None:
-            payload["flightrecorder"] = self.recorder.snapshot()
-            dump_path = self.recorder.dump(f"incident_{incident}")
-            if dump_path is not None:
-                payload["dump_path"] = str(dump_path)
+        payload["flightrecorder"] = self.recorder.snapshot()
+        dump_path = self.recorder.dump(f"incident_{incident}")
+        if dump_path is not None:
+            payload["dump_path"] = str(dump_path)
         if self.watchdog is not None:
             payload["watchdog"] = self.watchdog.health_dict()
         return payload
@@ -614,8 +603,7 @@ class ServeState:
             stall.kind, stall.name, stall.stalled_for_s, stall.limit_s,
             WATCHDOG_EXIT_CODE,
         )
-        if self.recorder is not None:
-            self.recorder.dump("watchdog_escalate")
+        self.recorder.dump("watchdog_escalate")
         if self.journal is not None:
             t = _threading.Thread(target=self.journal.seal, daemon=True)
             t.start()
@@ -638,10 +626,9 @@ class ServeState:
             # so the seal is honest either way
             self.journal.seal()
             self.journal.close()
-        if self.recorder is not None:
-            # SIGTERM-drain dump: the recorder's last act — the full drain
-            # (including any overrun sheds) is in the ring it writes out
-            self.recorder.dump("drain")
+        # SIGTERM-drain dump: the recorder's last act — the full drain
+        # (including any overrun sheds) is in the ring it writes out
+        self.recorder.dump("drain")
 
 
 class _BadRequest(ValueError):
@@ -797,9 +784,6 @@ def make_handler(state: ServeState):
                     return
                 self._json(state.slo.debug_payload())
             elif path == "/debug/flightrecorder":
-                if state.recorder is None:
-                    self._json({"error": "flight recorder disabled"}, 404)
-                    return
                 self._json(state.recorder.snapshot())
             elif path == "/debug/stacks":
                 # every thread's Python stack on demand — the manual twin
@@ -936,10 +920,7 @@ def make_handler(state: ServeState):
                             state.slo.export_state()
                             if state.slo is not None else None
                         ),
-                        recorder_stats=(
-                            state.recorder.stats_dict()
-                            if state.recorder is not None else None
-                        ),
+                        recorder_stats=state.recorder.stats_dict(),
                         watchdog_stats=(
                             state.watchdog.stats_dict()
                             if state.watchdog is not None else None
@@ -1863,13 +1844,6 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--slot-prompt-tokens", type=int, default=0,
                    help="in-flight prompt bucket S; longer prompts fall "
                         "back to one-shot dispatch (0 = full context)")
-    p.add_argument("--fused-segments", type=int, default=1,
-                   help="fused multi-step decode: on-device segments per "
-                        "slot-loop dispatch (the host polls asynchronously "
-                        "and joins/cancels/streams at the fused cadence; "
-                        "N>1 amortizes the dispatch/sync tax at small "
-                        "batch, trading TTFT/poll latency bounded by N — "
-                        "greedy outputs identical at every N)")
     p.add_argument("--max-queue", type=int, default=256,
                    help="admission control: max queued requests")
     p.add_argument("--max-queued-tokens", type=int, default=0,
@@ -2030,11 +2004,6 @@ def main(argv: list[str] | None = None) -> int:
                         "so disconnect cancels land mid-decode)")
     args = p.parse_args(argv)
 
-    if args.fused_segments < 1:
-        p.error(f"--fused-segments {args.fused_segments} must be >= 1")
-    if args.fused_segments > 1 and not args.inflight:
-        p.error("--fused-segments > 1 requires --inflight (it is the slot "
-                "loop's dispatch-fusing knob)")
     cache_blocks = 0 if args.no_prefix_cache else args.cache_blocks
     mesh = None
     if args.mesh:
@@ -2134,7 +2103,6 @@ def main(argv: list[str] | None = None) -> int:
         inflight=args.inflight,
         slots=args.slots,
         slot_prompt_tokens=args.slot_prompt_tokens,
-        fused_segments=args.fused_segments,
         journal_dir=args.journal_dir,
         journal_fsync_s=args.journal_fsync_ms / 1000.0,
         mesh=mesh,

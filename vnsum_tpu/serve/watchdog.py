@@ -262,16 +262,6 @@ class Watchdog:
             int(tokens), 0
         )
 
-    def segment_budget(self, fused_segments: int = 1) -> float:
-        """Wall-clock budget for one slot-loop decode dispatch covering
-        ``fused_segments`` on-device segments: the flat per-segment budget
-        scaled by N. A fused dispatch legitimately holds the host N times
-        longer than a single segment — without the scaling every fused
-        dispatch slower than one segment's budget would be a false HUNG,
-        and with it a genuinely wedged dispatch still trips after N
-        budgets."""
-        return self.segment_budget_s * max(int(fused_segments), 1)
-
     def begin_dispatch(self, owner: str, kind: str, budget_s: float,
                        riders: tuple = (), tokens: int = 0) -> DispatchTicket:
         t = DispatchTicket(owner=owner, kind=kind, budget_s=float(budget_s),
