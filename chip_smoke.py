@@ -46,12 +46,12 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe",
-          "laguna")
+          "laguna", "nemotron_h")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
-    "experts": 420, "moe": 420, "laguna": 480,
+    "experts": 420, "moe": 420, "laguna": 480, "nemotron_h": 480,
 }
 
 
@@ -80,6 +80,11 @@ def sizes(rehearsal: bool) -> dict:
             laguna_prefill_chunk=128, laguna_prompt_bytes=250,
             laguna_parity=(150, 256, 4), laguna_tolerance=0.12,
             laguna_tie_band=0.1,
+            nemotron_h_layers=9, nemotron_h_seq=328, nemotron_h_batch=4,
+            nemotron_h_max_new=8, nemotron_h_prefill_chunk=128,
+            nemotron_h_prompt_bytes=250, nemotron_h_parity=(150, 256, 4),
+            nemotron_h_tolerance=0.05, nemotron_h_tie_band=0.02,
+            nemotron_h_state_tolerance=0.01,
         )
     return dict(
         kernel_geometries=None,  # derived from MODEL_REGISTRY
@@ -115,6 +120,14 @@ def sizes(rehearsal: bool) -> dict:
         # the cell's own limits (benchmarks/configs/laguna-s-2.1-l5-int8.json)
         laguna_parity=(1500, 2048, 4), laguna_tolerance=0.115,
         laguna_tie_band=0.3,
+        # the first six layers MEMEM* at the published widths: 3 Mamba-2 at
+        # 8 groups, 2 sparse of 128 experts each, 1 attention at 32/2 heads
+        # (3.5 GB of int8 weights)
+        nemotron_h_layers=6, nemotron_h_seq=2304, nemotron_h_batch=4,
+        nemotron_h_max_new=32, nemotron_h_prefill_chunk=1024,
+        nemotron_h_prompt_bytes=1_900, nemotron_h_parity=(1500, 2048, 4),
+        nemotron_h_tolerance=0.25, nemotron_h_tie_band=0.1,
+        nemotron_h_state_tolerance=0.006,
     )
 
 
@@ -750,13 +763,16 @@ def phase_experts(args) -> dict:
     return rep
 
 
-def _windowed_expert_family(args, key: str, cfg, expert_layers: int,
-                            reference, sizes_ref: dict) -> dict:
-    """What the phases ``moe`` and ``laguna`` share: a family with GQA
-    window layers and every expert held, at the published widths and int8,
-    through ``TpuBackend.generate`` with prompts longer than the window; its
-    counters; and its logits against its plain reference (prefill and
-    decode steps). ``key`` prefixes the phase's entries of ``sizes``."""
+def _expert_family(args, key: str, cfg, expert_layers: int, reference,
+                   sizes_ref: dict, *, window: bool = True,
+                   more=None) -> dict:
+    """What the phases ``moe``, ``laguna`` and ``nemotron_h`` share: a
+    family with every expert held, at the published widths and int8,
+    through ``TpuBackend.generate`` (with ``window``: GQA window layers and
+    prompts longer than the window); its counters; and its logits against
+    its plain reference (prefill and decode steps). ``key`` prefixes the
+    phase's entries of ``sizes``; ``more(c, sz, backend, state, ids)`` adds
+    the family's own checks of the parity run's state."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -799,13 +815,14 @@ def _windowed_expert_family(args, key: str, cfg, expert_layers: int,
             and k <= per_step <= min(cfg.n_held, sz["batch"] * k),
             (steps, per_step))
     blocks = st.prefill_blocks
-    c.check("prompts leave the window: window layers skip cells below it "
-            "and compute more scores than the window needs",
-            # (the rehearsal's window is narrower than one grid cell)
-            (args.rehearsal or blocks.get("dead_causal", 0) > 0)
-            and blocks.get("edge", 0) > 0
-            and blocks.get("window_scores_computed", 0)
-            > blocks.get("window_scores_needed", 0) > 0, blocks)
+    if window:
+        c.check("prompts leave the window: window layers skip cells below "
+                "it and compute more scores than the window needs",
+                # (the rehearsal's window is narrower than one grid cell)
+                (args.rehearsal or blocks.get("dead_causal", 0) > 0)
+                and blocks.get("edge", 0) > 0
+                and blocks.get("window_scores_computed", 0)
+                > blocks.get("window_scores_needed", 0) > 0, blocks)
 
     n, bucket, n_steps = sz["parity"]
     ids = backend.tok.encode(_vn_text((n + n_steps) * 3, "parity"))[:n + n_steps]
@@ -814,7 +831,9 @@ def _windowed_expert_family(args, key: str, cfg, expert_layers: int,
     got = np.asarray(got, np.float64)
     # the routers' picks of the scored positions: the reference takes them
     # where they are the top-k of its own logits inside a tie band
-    picks = jnp.asarray(state["rows"][:, :, 0].swapaxes(0, 1))
+    rows = state["rows"]
+    picks = jnp.asarray((rows["picks"] if isinstance(rows, dict) else rows)[
+        :, :, 0].swapaxes(0, 1))
     want_l = np.asarray(jax.jit(lambda p, t, theirs: reference.forward(
         p, t, sizes_ref, last=n_steps + 1, theirs=theirs,
         tie_band=sz["tie_band"])["logits"])(
@@ -825,7 +844,9 @@ def _windowed_expert_family(args, key: str, cfg, expert_layers: int,
             "prefill and decode",
             bool(np.all(np.isfinite(errors))
                  and errors.max() <= sz["tolerance"]), errors.tolist())
+    extra = more(c, sz, backend, state, ids) if more else {}
     rep = c.report()
+    rep.update(extra)
     rep.update(first_call_s=round(first_s, 2),
                second_call_s=round(second_s, 2),
                parity_errors=errors.tolist(),
@@ -839,7 +860,7 @@ def phase_moe(args) -> dict:
     """The SmallThinker family on the one-shot path: GQA at 28/4 heads with
     rotary 4096-window layers and position-free global layers, 64 ReGLU
     experts all held, routed on the layer's input — one period of four
-    layers (``_windowed_expert_family``)."""
+    layers (``_expert_family``)."""
     from benchmarks import reference_smallthinker as reference
     from benchmarks.engine_setup_smallthinker import sizes_from
     from vnsum_tpu.models.smallthinker import (
@@ -850,8 +871,8 @@ def phase_moe(args) -> dict:
     sz = sizes(args.rehearsal)
     make = tiny_smallthinker if args.rehearsal else smallthinker_21b_a3b
     cfg = make(n_layers=sz["moe_layers"], max_seq_len=sz["moe_seq"])
-    return _windowed_expert_family(args, "moe", cfg, cfg.n_layers, reference,
-                                   sizes_from(cfg))
+    return _expert_family(args, "moe", cfg, cfg.n_layers, reference,
+                          sizes_from(cfg))
 
 
 def phase_laguna(args) -> dict:
@@ -859,7 +880,7 @@ def phase_laguna(args) -> dict:
     layers and 72/8 in a 512 window (both kernels at G = 6 and 9 in one
     program), the per-head gate, YaRN partial and plain rotary, the leading
     dense layer, 256 SwiGLU experts top-10 and the shared one all held — the
-    dense layer and one period of four (``_windowed_expert_family``)."""
+    dense layer and one period of four (``_expert_family``)."""
     from benchmarks import reference_laguna as reference
     from benchmarks.engine_setup_laguna import sizes_from
     from vnsum_tpu.models.laguna import laguna_s_2_1, tiny_laguna
@@ -867,8 +888,60 @@ def phase_laguna(args) -> dict:
     sz = sizes(args.rehearsal)
     make = tiny_laguna if args.rehearsal else laguna_s_2_1
     cfg = make(n_layers=sz["laguna_layers"], max_seq_len=sz["laguna_seq"])
-    return _windowed_expert_family(args, "laguna", cfg, cfg.n_sparse_layers,
-                                   reference, sizes_from(cfg))
+    return _expert_family(args, "laguna", cfg, cfg.n_sparse_layers,
+                          reference, sizes_from(cfg))
+
+
+def phase_nemotron_h(args) -> dict:
+    """The Nemotron-H family on the one-shot path, which is also the
+    state-space phase: the first six layers ``MEMEM*`` at the published
+    widths — Mamba-2 at 8 groups of B and C through both scan kernels, 128
+    non-gated relu2 experts top-6 by sigmoid scores and the shared one all
+    held, GQA at 16 query heads a KV head — a recurrent state, expert
+    counters and keys and values in one carry (``_expert_family``), and
+    the first Mamba layer's recurrent state against the reference's after
+    the prompt and after each forced token."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import reference_nemotron_h as reference
+    from benchmarks.engine_setup_nemotron_h import sizes_from
+    from vnsum_tpu.models.nemotron_h import (
+        nemotron_3_nano_30b_a3b,
+        tiny_nemotron_h,
+    )
+
+    sz = sizes(args.rehearsal)
+    make = tiny_nemotron_h if args.rehearsal else nemotron_3_nano_30b_a3b
+    cfg = make(n_layers=sz["nemotron_h_layers"],
+               max_seq_len=sz["nemotron_h_seq"])
+    sizes_ref = sizes_from(cfg)
+
+    def state_check(c, sz, backend, state, ids):
+        n_rows = sz["parity"][2] + 1
+        want = np.asarray(jax.jit(
+            lambda p, t: reference.state_as_the_program_lays_it(
+                reference.forward(p, t, sizes_ref, last=n_rows)["ssm_rows"])
+        )(backend.params, jnp.asarray(ids, jnp.int32)), np.float64)
+        # [rows, first | last, 1, N, HP] against [first | last, rows, N, HP]
+        mine = np.asarray(state["rows"]["ssm"], np.float64)[:, 0, 0]
+        errors = [float(np.linalg.norm(mine[r] - want[0, r])
+                        / np.linalg.norm(want[0, r])) for r in range(n_rows)]
+        c.check(f"the first Mamba layer's state within "
+                f"{sz['state_tolerance']} of the reference's, after the "
+                "prompt and after each forced token",
+                max(errors) <= sz["state_tolerance"], errors)
+        blocks = backend.stats.prefill_blocks
+        c.check("the scan's tokens are counted beside the attention's cells",
+                0 < blocks.get("scan_tokens_real", 0)
+                <= blocks.get("scan_tokens_computed", 0)
+                and blocks.get("edge", 0) > 0, blocks)
+        return {"state_errors": errors,
+                "state_dtype": str(state["cache"]["ssm"].dtype)}
+
+    return _expert_family(args, "nemotron_h", cfg, cfg.n_sparse, reference,
+                          sizes_ref, window=False, more=state_check)
 
 
 def _rehearsal_server(argv: list[str]) -> int:
@@ -899,7 +972,8 @@ def _child(args) -> int:
                     "offline": phase_offline, "mesh": phase_mesh,
                     "experts": phase_experts,
                     "moe": phase_moe,
-                    "laguna": phase_laguna}[phase](args))
+                    "laguna": phase_laguna,
+                    "nemotron_h": phase_nemotron_h}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

@@ -18,6 +18,13 @@ this file reads the same on the parent's tree and on the PR's): their four
 hashes were taken at PR 45's tree before PR 46 rebuilt the slot programs
 from the one-shot program's parts, and PR 46 moved none of the ten.
 
+**PR 47 moved `granite-h` on purpose and added `nemotron-h`**: the Mamba-2
+mixer became `models/mamba_mixer.py`, shared by both families, and
+`ops/ssd_scan.py`'s kernels take B and C with a group dim (`[B, S, 1, N]`
+for Granite's one group); the other nine did not move.
+`test_granites_program_calls_the_kernels_it_called` holds what had to stay:
+the same kernels by name, as many calls of each.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
@@ -46,7 +53,8 @@ _PINNED = {
     "llama-qk-norm": ("tiny", {"qk_norm": True}, "89aa4218a7d72f06"),
     "smallthinker": ("tiny-smallthinker", {}, "b4b5483b7a77bcac"),
     "laguna": ("tiny-laguna", {}, "e7b30d5c4179f47b"),
-    "granite-h": ("tiny-granite-h", {}, "e6b35913e524e08f"),
+    "granite-h": ("tiny-granite-h", {}, "ae687d878a98f9b9"),
+    "nemotron-h": ("tiny-nemotron-h", {}, "a4aa98b6e08cc54b"),
     "deepseek-v2": (tiny_deepseek, {}, "90bc1e80a899c229"),
 }
 
@@ -159,6 +167,24 @@ def test_the_one_shot_program_traces_to_the_pinned_jaxpr(family):
     text = one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw))
     assert "pallas_call" in text          # the kernels' bodies are hashed too
     assert_pinned(family, text, want)
+
+
+def test_granites_program_calls_the_kernels_it_called():
+    """What PR 47 had to leave alone when the mixer became shared code: the
+    tiny Granite program names the kernels it named on PR 46's tree, as
+    often (counted in the jaxpr's text on both trees: a kernel's name
+    stands at its ``pallas_call`` and at each call of its jitted wrapper),
+    with one body a kernel."""
+    import re
+    from collections import Counter
+
+    text = one_shot_jaxpr(MODEL_REGISTRY["tiny-granite-h"]())
+    names = Counter(re.findall(r"name=(\w+)", text))
+    assert {k: n for k, n in names.items() if k.split("_")[0] in (
+        "ssd", "ssm", "flash", "expert", "mla")} == {
+        "ssd_prefill_scan": 5, "ssm_decode_update": 3,
+        "flash_prefill_attention": 3, "flash_decode_attention": 2}
+    assert text.count("pallas_call[") == 4
 
 
 @pytest.mark.parametrize("program", list(_SLOT_PINNED))

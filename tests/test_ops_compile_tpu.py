@@ -1,6 +1,9 @@
 """The DeepSeek-V2 family's three kernels, the GQA kernels and the
-expert product at the SmallThinker family's geometry, and the Granite-4.0-H
-family's two scan kernels and the GQA kernels at its 64-wide heads, compiled
+expert product at the SmallThinker family's geometry, the Granite-4.0-H
+family's two scan kernels and the GQA kernels at its 64-wide heads, and the
+Nemotron-H family's (the scan kernels at eight groups, the single-product
+expert form at experts stored 1,920 wide for 1,856, the GQA kernels at 16
+query heads on 2 KV heads), compiled
 for the chip at the published widths, with no chip: the TPU's compiler is installed here and
 compiles for a described v5e. Interpret mode cannot show what this does: a
 slice not aligned to the tiling, too much VMEM, an int8 product Mosaic
@@ -375,3 +378,120 @@ def test_decode_update_kernel_compiles_in_place_at_the_cells_shapes(one_chip):
         donate_argnums=(6,)).lower(*args).compile()
     assert "tpu_custom_call" in c.as_text()
     assert c.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+# -- the Nemotron-H family (PR 47) ---------------------------------------------
+
+
+@pytest.mark.parametrize("G,chunk", [(1, 256), (8, 128), (8, 256)])
+def test_scan_kernels_compile_at_one_group_and_at_eight(one_chip, G, chunk):
+    """Both scan kernels with B and C as [B, S, G, N]: Granite's one group
+    at its chunk of 256 and Nemotron-H's eight groups of eight heads (four
+    lane tiles a group; the group's rows of B^T and lanes of C sliced by a
+    traced index) at the published chunk of 128 and at 256, a 2,048-token
+    prefill chunk of 12 rows over the stacked state of 7 layers in place,
+    and the one-token update."""
+    from vnsum_tpu.ops import ssd_scan
+
+    x, dt, A, _, _, D = _scan_shapes(12, 2048)
+    bc = ((12, 2048, G, 128), BF16)
+    state = ((7, 12, 128, 4096), F32)
+    c = _compiled(
+        lambda x, dt, A, Bm, Cm, D, state, pads: ssd_scan.ssd_prefill_scan(
+            x, dt, A, Bm, Cm, D, state, 3, pads, chunk=chunk),
+        one_chip, x, dt, A, bc, bc, D, state, ((12,), I32))
+    assert "tpu_custom_call" in c.as_text()
+    x, dt, A, _, _, D = _scan_shapes(12, 0)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (x, dt, A, ((12, G, 128), BF16), ((12, G, 128), BF16),
+                         D, state)]
+    c = jax.jit(
+        lambda x, dt, A, Bm, Cm, D, state: ssd_scan.ssm_decode_update(
+            x, dt, A, Bm, Cm, D, state, 3),
+        donate_argnums=(6,)).lower(*args).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+# (layers, experts held, hidden, expert width as stored, column tiles)
+_NEMOTRON_H = (7, 128, 2688, 1920, (640, 896))
+
+
+@pytest.mark.parametrize("tm,tiles", [(256, 321), (32, 73)])
+def test_single_product_expert_form_compiles_at_the_stored_width(one_chip, tm,
+                                                                 tiles):
+    """Nemotron-H's two products of an expert with no gate — relu2 in the
+    kernel after ONE product, then the down product — on int8 rows at the
+    prefill's and the decode's row tile (a piece of 8,192 tokens x 6 picks
+    over 128 experts; a decode step of 12 rows), at the 1,920 the experts
+    are stored at. No copy of the stack: its last dim is whole lanes."""
+    from vnsum_tpu.models.experts import _column_tile
+    from vnsum_tpu.ops.expert_matmul import expert_grouped_matmul
+
+    L, E, D, F, column_tiles = _NEMOTRON_H
+    M = tm * tiles
+    up = {"q": ((L, E, D, F), I8), "s": ((L, E, F), F32)}
+    down = {"q": ((L, E, F, D), I8), "s": ((L, E, D), F32)}
+    sched = (((tiles,), I32), ((1,), I32))
+    c = _compiled(
+        lambda x, xs, w, te, nu: expert_grouped_matmul(
+            x, xs, w, None, 2, te, nu, tm=tm, tn=_column_tile(D, F),
+            out_dtype=BF16, act="relu2"),
+        one_chip, ((M, D), I8), ((M, 1), F32), up, *sched)
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+    c = _compiled(
+        lambda x, xs, w, te, nu: expert_grouped_matmul(
+            x, xs, w, None, 2, te, nu, tm=tm, tn=_column_tile(F, D),
+            out_dtype=BF16),
+        one_chip, ((M, F), I8), ((M, 1), F32), down, *sched)
+    assert "tpu_custom_call" in c.as_text()
+    assert (_column_tile(D, F), _column_tile(F, D)) == column_tiles
+
+
+def test_a_stack_stored_1856_wide_is_copied_whole_at_every_call(one_chip):
+    """Why the experts are stored padded: at the published 1,856 columns
+    (14.5 lane tiles) the whole-width block compiles, but XLA copies the
+    stack into a padded layout for the call — the temporaries are the
+    stack's own size."""
+    from vnsum_tpu.models.experts import _column_tile
+    from vnsum_tpu.ops.expert_matmul import expert_grouped_matmul
+
+    L, E, D, F = 2, 128, 2688, 1856
+    assert _column_tile(D, F) == F
+    up = {"q": ((L, E, D, F), I8), "s": ((L, E, F), F32)}
+    c = _compiled(
+        lambda x, xs, w, te, nu: expert_grouped_matmul(
+            x, xs, w, None, 1, te, nu, tm=32, tn=F, out_dtype=BF16,
+            act="relu2"),
+        one_chip, ((32 * 73, D), I8), ((32 * 73, 1), F32), up,
+        ((73,), I32), ((1,), I32))
+    assert c.memory_analysis().temp_size_in_bytes > L * E * D * F
+
+
+@pytest.mark.parametrize("offset", [0, 6144])
+def test_gqa_prefill_kernel_compiles_at_g16_on_two_kv_heads(one_chip, offset):
+    """Nemotron-H's 32 query heads on 2 KV heads of 128 over the int8
+    cache of its 2 attention layers, 12 rows: the looped group at the
+    (1024, 1024) tile, the scales' block the array's own 2 KV heads."""
+    from vnsum_tpu.ops import flash_attention
+
+    assert flash_attention._block_geometry(2048, 8448, 16, 128) == (1024, 1024)
+    c = _compiled(
+        lambda q, cache, pads, win: flash_attention.flash_prefill_attention(
+            q, cache, 1, pads, 16, win, offset),
+        one_chip, ((12, 2048, 32, 128), BF16), _int8_cache(2, 12, 2, 8448, 128),
+        ((12,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_gqa_decode_kernel_compiles_at_g16_on_two_kv_heads(one_chip):
+    """16 query rows a KV head over the int8 cache, 12 rows."""
+    from vnsum_tpu.ops.decode_attention import flash_decode_attention
+
+    c = _compiled(
+        lambda q, cache, pads, win: flash_decode_attention(
+            q, cache, 1, pads, 8200, 16, win),
+        one_chip, ((12, 1, 32, 128), BF16),
+        _int8_cache(2, 12, 2, 8448, 128), ((12,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()

@@ -166,6 +166,57 @@ def test_a_recurrent_familys_program_carries_its_component_scopes():
     assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
 
 
+def test_a_one_mixer_familys_program_carries_its_component_scopes():
+    """Every scope ``models/nemotron_h.py`` adds, under both phases of its
+    one-shot program: the shared mixer's four, the attention layers', the
+    router, the routed experts and the shared expert — no ``mlp``: no layer
+    has a dense feed-forward."""
+    from vnsum_tpu.models.nemotron_h import init_params, tiny_nemotron_h
+
+    cfg = tiny_nemotron_h(max_seq_len=128)
+    b = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
+                   max_new_tokens=NEW, seed=1, flash=False,
+                   params=init_params(jax.random.key(0), cfg))
+    b._get_fn(B, S, NEW, b.gen_cfg)
+    (m,) = b.scope_maps()
+    assert m["module"] == "jit_generate"
+    got = paths(m["scopes"])
+    for phase in ("prefill", "decode"):
+        assert {f"{phase}/{c}" for c in (
+            "ssm_in", "conv", "ssd", "ssm_out", "qkv", "kv_write", "attn",
+            "attn_out", "router", "experts", "shared_expert", "embed",
+            "lm_head", "sample")} <= got
+        assert f"{phase}/mlp" not in got
+    assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
+
+
+def test_a_one_mixer_familys_kernels_keep_their_contract_names():
+    """The tiny Nemotron-H program with every kernel on calls the six
+    kernels by the names the benchmark's metrics read, and no other."""
+    import re
+
+    from vnsum_tpu.models.nemotron_h import tiny_nemotron_h
+
+    b = TpuBackend(model_config=tiny_nemotron_h(max_seq_len=256),
+                   tokenizer="byte", batch_size=2, max_new_tokens=NEW,
+                   interpret=True, quantize=True, prefill_chunk_tokens=128)
+    fn = b._make_fn(2, 128, NEW, b.gen_cfg)
+    text = str(jax.make_jaxpr(fn)(
+        jax.eval_shape(lambda: b.params),
+        jax.ShapeDtypeStruct((2, 128), jnp.int32),
+        jax.ShapeDtypeStruct((2,), jnp.int32),
+        jax.ShapeDtypeStruct((), jnp.uint32)))
+    # every name a ``pallas_call`` of ops/ carries is a contract
+    contracted = set()
+    for src in (ROOT / "vnsum_tpu" / "ops").glob("*.py"):
+        contracted |= set(re.findall(r'^\s+name="(\w+)",$', src.read_text(),
+                                     re.M))
+    kernels = set(re.findall(r"name=(\w+)", text)) & contracted
+    assert kernels == {"ssd_prefill_scan", "ssm_decode_update",
+                       "flash_prefill_attention", "flash_decode_attention",
+                       "expert_grouped_matmul"}, kernels
+
+
 @pytest.mark.parametrize("line, name, scope", [
     ('  %dot.5 = f32[8,64]{1,0} dot(%a, %b), metadata={op_name='
      '"jit(generate)/decode/while/body/mlp/bsd,di->bsi/dot_general" '

@@ -71,6 +71,18 @@ def _tiny_granite_h(**kw):
     return tiny_granite_h(**kw)
 
 
+def _nemotron_3_nano(**kw):
+    from .nemotron_h import nemotron_3_nano_30b_a3b
+
+    return nemotron_3_nano_30b_a3b(**kw)
+
+
+def _tiny_nemotron_h(**kw):
+    from .nemotron_h import tiny_nemotron_h
+
+    return tiny_nemotron_h(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -102,6 +114,11 @@ MODEL_REGISTRY = {
     # position-free GQA layers, a recurrent state in the program's carry
     "granite-4.0-h-micro": _granite_h_micro,
     "tiny-granite-h": _tiny_granite_h,
+    # a sixth (models/nemotron_h.py): every layer ONE mixer - Mamba-2 at
+    # eight groups of B and C, non-gated relu2 experts with a shared one,
+    # or position-free GQA - state, counters and keys in one carry
+    "nemotron-3-nano-30b-a3b": _nemotron_3_nano,
+    "tiny-nemotron-h": _tiny_nemotron_h,
 }
 
 __all__ = [

@@ -21,7 +21,11 @@ follows the static token count alone.
 
 What a config has to say: ``n_held``, ``expert_offset``,
 ``moe_intermediate``, ``num_experts_per_tok``, ``act`` (the gate's
-activation), ``w8a8_prefill``. What the state holds (``init_expert_state``):
+activation; for experts with no gate, the front product's own: ``relu2``),
+``w8a8_prefill``. Which of the two forms an expert has is read off the
+leaves the family hands over: ``EXPERT_LEAVES`` (gate, up, down:
+``down(act(gate x) * up x)``) or ``UNGATED_EXPERT_LEAVES`` (up, down:
+``down(act(up x))``). What the state holds (``init_expert_state``):
 ``expert_tokens`` [expert layers, held experts], ``slots_routed``,
 ``slots_held``, ``picks`` (the routers' picks for the last token of the
 latest forward) and, where a family asks for it, ``decode_touched`` /
@@ -43,6 +47,8 @@ import jax.numpy as jnp
 from .llama import _mlp_act
 
 EXPERT_LEAVES = ("we_gate", "we_up", "we_down")
+# an expert with no gate: two matrices (Nemotron-H's relu2 experts)
+UNGATED_EXPERT_LEAVES = ("we_up", "we_down")
 # the scalars a family asks for with ``decode_touched``
 _DECODE_COUNTERS = ("decode_touched", "decode_layer_steps",
                     "decode_tiles_used", "decode_tiles_walked")
@@ -86,12 +92,30 @@ def _quantize_rows(x: jax.Array):
     return jnp.clip(jnp.round(x32 / s), -127, 127).astype(jnp.int8), s
 
 
+# the most an int8 weight tile [K, tn] may take where the rule below falls
+# back to the whole width: double-buffered it stays under a third of the
+# product kernel's VMEM limit (Nemotron-H's 2688 x 1856 is 5.0 MB)
+_WHOLE_WIDTH_TILE_BYTES = 8 * 1024 * 1024
+
+
 def _column_tile(K: int, N: int) -> int:
     """Columns of a weight tile: the widest divisor of N in whole lanes
-    whose int8 tile [K, tn] stays under ~2.8 MB of VMEM."""
+    whose int8 tile [K, tn] stays under ~2.8 MB of VMEM. A width with no
+    such divisor — one that is not whole lanes (1,856 = 14.5 of them), or a
+    K so deep that no whole-lane share of N fits — takes the WHOLE width:
+    the one block that is not whole lanes Mosaic takes is the array's own
+    dim (its last lane tile is masked). That tile may be larger than the
+    rule's bound, and is refused past ``_WHOLE_WIDTH_TILE_BYTES``."""
     fits = [d for d in range(128, N + 1, 128)
             if N % d == 0 and d * K <= 2_800_000]
-    return max(fits) if fits else N
+    if fits:
+        return max(fits)
+    if K * N > _WHOLE_WIDTH_TILE_BYTES:
+        raise ValueError(
+            f"no whole-lane divisor of {N} columns fits a [{K}, tn] int8 "
+            f"tile, and the whole width is {K * N} bytes: store the experts "
+            "padded to whole lanes")
+    return N
 
 
 # tokens one grouped product takes at once: bounds the worst-case row
@@ -104,7 +128,7 @@ def _int8_rows(experts, cfg) -> bool:
     """int8 rows (s8 x s8) whenever the weights are int8 and the engine runs
     W8A8: in a decode step too, where converting each expert's weight tile
     to bf16 in the kernel would cost more than fetching it."""
-    return isinstance(experts["we_gate"], dict) and cfg.w8a8_prefill
+    return isinstance(experts["we_down"], dict) and cfg.w8a8_prefill
 
 
 def _row_tile(T: int, int8_rows: bool) -> int:
@@ -132,9 +156,10 @@ def grouped_experts(x, local, weights, experts, slot, cfg, *,
 
     x [T, D]; ``local`` [T, k] the picks as local expert ids, -1 where a pick
     is not held (or the token is padding); ``weights`` [T, k]; ``experts``
-    the STACKED ``we_gate``/``we_up``/``we_down`` of every expert layer and
-    ``slot`` this layer's index in them (the kernel reads the stack in
-    place). Returns the weighted sum over each token's held picks, [T, D].
+    the STACKED ``we_gate``/``we_up``/``we_down`` of every expert layer
+    (``we_up``/``we_down`` alone for experts with no gate) and ``slot`` this
+    layer's index in them (the kernel reads the stack in place). Returns the
+    weighted sum over each token's held picks, [T, D].
 
     Around the two products there is one permutation (``expert_layout``):
     token rows are gathered into expert order once, the routing weight goes
@@ -155,7 +180,9 @@ def grouped_experts(x, local, weights, experts, slot, cfg, *,
     prefill = T >= 1024
     int8_rows = _int8_rows(experts, cfg)
     tm = _row_tile(T, int8_rows)
-    F = cfg.moe_intermediate
+    # the width the experts are stored at (a family may store them wider
+    # than ``moe_intermediate``, padded with zeros to whole lanes)
+    F = jax.tree.leaves(experts["we_down"])[0].shape[-2]
 
     def take(a, idx):   # every index below is a slot's or a row's own
         return a.at[idx].get(mode="promise_in_bounds")
@@ -192,9 +219,11 @@ def grouped_experts(x, local, weights, experts, slot, cfg, *,
         factor = carried[0][:, None]
         call = dict(layer=slot, tile_expert=tile_expert, tiles_used=used,
                     tm=tm, interpret=interpret)
+        # gated: act(gate x) * (up x); no gate: act(up x), one product
         hidden = expert_grouped_matmul(
             rows, carried[1][:, None] if int8_rows else None,
-            experts["we_gate"], experts["we_up"],
+            *((experts["we_gate"], experts["we_up"]) if "we_gate" in experts
+              else (experts["we_up"], None)),
             tn=_column_tile(D, F), act=cfg.act, out_dtype=dtype, **call)
         if int8_rows:
             hidden, scale = _quantize_rows(hidden)
@@ -239,16 +268,18 @@ def dense_experts(x, local, weights, experts, slot, cfg):
     masked. For the dense XLA path at small sizes."""
     from .quant import dequantize_leaf
 
-    wg, wu, wd = (
-        dequantize_leaf(jax.tree.map(lambda a: a[slot], experts[n]), (1,)
-                        ).astype(x.dtype)
-        for n in EXPERT_LEAVES)
+    w = {n: dequantize_leaf(jax.tree.map(lambda a: a[slot], leaf), (1,)
+                            ).astype(x.dtype)
+         for n, leaf in experts.items()}
     gate = (local[:, :, None] == jnp.arange(cfg.n_held)[None, None, :])
     per_expert = jnp.sum(
         jnp.where(gate, weights[:, :, None], 0.0), axis=1)       # [T, E]
-    h = _mlp_act(jnp.einsum("td,edf->tef", x, wg), cfg.act) \
-        * jnp.einsum("td,edf->tef", x, wu)
-    y = jnp.einsum("tef,efd->ted", h, wd)
+    h = jnp.einsum("td,edf->tef", x, w["we_up"])
+    if "we_gate" in w:
+        h = _mlp_act(jnp.einsum("td,edf->tef", x, w["we_gate"]), cfg.act) * h
+    else:
+        h = _mlp_act(h, cfg.act)
+    y = jnp.einsum("tef,efd->ted", h, w["we_down"])
     return jnp.einsum("ted,te->td", y, per_expert.astype(x.dtype))
 
 

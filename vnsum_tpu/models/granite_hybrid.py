@@ -7,8 +7,10 @@ between steps is not keys and values alone. Its attention layers are
 ``models/llama.py``'s — the ``[L, B, KV, C, hd]`` cache over those layers
 only (``init_kv_cache``, int8 with per-token scales), ``_write_kv``,
 ``_cache_attention`` and the two flash kernels, at 64-wide heads — and its
-recurrence is ``ops/ssd_scan.py``'s. What it owns is the config, the
-parameters, the Mamba-2 mixer, the state and ``forward``. ``FAMILY`` at the
+Mamba-2 mixer is ``models/mamba_mixer.py``'s (shared with
+``models/nemotron_h.py``; one group of B and C here), over
+``ops/ssd_scan.py``. What it owns is the config, the parameters, the
+state and ``forward``. ``FAMILY`` at the
 end is what the engine's seam picks up for a ``GraniteHybridConfig``.
 
 The equations (``benchmarks/reference_granite_h.py`` is the same in plain
@@ -79,6 +81,17 @@ from .llama import (
     _rmsnorm,
     _write_kv,
     init_kv_cache,
+)
+
+from .mamba_mixer import (  # noqa: F401  (the names this module had)
+    MAMBA_VECTORS,
+    causal_conv,
+    init_mamba_params,
+    init_mamba_state,
+    init_mamba_vectors,
+    last_state,
+    mamba_mixer,
+    prefill_counts,
 )
 
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
@@ -186,36 +199,6 @@ def tiny_granite_h(**kw) -> GraniteHybridConfig:
 
 # -- parameters and state -----------------------------------------------------
 
-# the Mamba mixer's leaves that stay in float32 whatever the weights' type:
-# the recurrence is sensitive to them and they are a few thousand numbers
-MAMBA_VECTORS = ("conv_w", "conv_b", "dt_bias", "A_log", "D", "ssm_norm")
-
-
-def init_mamba_vectors(key: jax.Array, cfg: GraniteHybridConfig) -> dict:
-    """What the scan is sensitive to, drawn as Mamba-2's published
-    initialisation draws it, so that a seeded model decays as a trained one
-    does: ``A_log = log(U[1, 16])``, ``dt_bias`` the inverse softplus of
-    ``dt ~ logU[1e-3, 1e-1]``, ``D = 1``, the convolution
-    ``U[-1/sqrt(d_conv), 1/sqrt(d_conv)]`` (a depth-wise ``Conv1d``'s
-    default), a unit norm weight. All float32."""
-    Lm, H, K = cfg.n_mamba, cfg.mamba_n_heads, cfg.mamba_d_conv
-    ka, kd, kw, kb = jax.random.split(key, 4)
-    f32 = jnp.float32
-    dt = jnp.exp(jax.random.uniform(
-        kd, (Lm, H), f32, jnp.log(1e-3), jnp.log(1e-1)))
-    bound = K ** -0.5
-    return {
-        "conv_w": jax.random.uniform(kw, (Lm, cfg.conv_dim, K), f32,
-                                     -bound, bound),
-        "conv_b": jax.random.uniform(kb, (Lm, cfg.conv_dim), f32,
-                                     -bound, bound),
-        # softplus(dt_bias) = dt
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-        "A_log": jnp.log(jax.random.uniform(ka, (Lm, H), f32, 1.0, 16.0)),
-        "D": jnp.ones((Lm, H), f32),
-        "ssm_norm": jnp.ones((Lm, cfg.d_inner), f32),
-    }
-
 
 def float_leaves(key: jax.Array, cfg: GraniteHybridConfig) -> dict:
     """{group: {leaf: array}} of the leaves ``models/quant.py``'s direct
@@ -236,17 +219,7 @@ def init_params(key: jax.Array, cfg: GraniteHybridConfig) -> dict:
 
     return {
         "embed": norm((cfg.vocab_size, D)),
-        "mamba": {
-            "mixer_norm": jnp.ones((Lm, D), cfg.dtype),
-            # in_proj, a product a part (z | xBC | dt): no slice of a
-            # chunk's 8,512-wide output, and every width whole lane tiles
-            # but dt's
-            "in_z": norm((Lm, D, cfg.d_inner)),
-            "in_xbc": norm((Lm, D, cfg.conv_dim)),
-            "in_dt": norm((Lm, D, cfg.mamba_n_heads)),
-            "out_proj": norm((Lm, cfg.d_inner, D)),
-            **init_mamba_vectors(next(keys), cfg),
-        },
+        "mamba": init_mamba_params(norm, next(keys), cfg),
         "attn": {
             "mixer_norm": jnp.ones((La, D), cfg.dtype),
             "wq": norm((La, D, H, hd)), "wk": norm((La, D, KV, hd)),
@@ -270,96 +243,11 @@ def init_cache(cfg: GraniteHybridConfig, batch: int, cache_len: int, *,
         head_dim=cfg.head_dim, dtype=cfg.dtype)
     return {
         **init_kv_cache(attention, batch, cache_len, quantized=quantized),
-        "conv": jnp.zeros((cfg.n_mamba, batch, cfg.mamba_d_conv - 1,
-                           cfg.conv_dim), cfg.dtype),
-        "ssm": jnp.zeros((cfg.n_mamba, batch, cfg.mamba_d_state,
-                          cfg.d_inner), cfg.state_dtype),
+        **init_mamba_state(cfg, batch),
     }
 
 
 # -- the mixers and forward ---------------------------------------------------
-
-
-def causal_conv(xbc, tail, w, b):
-    """Depth-wise causal convolution and silu: xbc [B, S, C] after ``tail``
-    [B, K - 1, C], the K - 1 inputs before it; w [C, K], b [C]. Returns
-    (silu(conv) [B, S, C] float32, the new tail)."""
-    K = w.shape[-1]
-    S = xbc.shape[1]
-    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-    acc = b.astype(jnp.float32)
-    for j in range(K):
-        acc = acc + w[:, j].astype(jnp.float32) * ext[:, j:j + S].astype(
-            jnp.float32)
-    return jax.nn.silu(acc), ext[:, S:]
-
-
-def _mamba_mixer(h, lp, slot, valid, cache, cfg: GraniteHybridConfig,
-                 scan_kernels: bool, interpret: bool):
-    """The Mamba-2 mixer over h [B, S, D] (normed, zero under the pad) at
-    Mamba slot ``slot`` of the state. The ``jax.named_scope`` names are
-    metadata a device trace is read by (README "Device time by layer")."""
-    B, S, _ = h.shape
-    H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
-    inner = cfg.d_inner
-    aq = cfg.w8a8_prefill and S > 1
-    f32 = jnp.float32
-    with jax.named_scope("ssm_in"):
-        z = _proj("bsd,de->bse", h, lp["in_z"], aq)
-        xbc = _proj("bsd,de->bse", h, lp["in_xbc"], aq)
-        dt = jax.nn.softplus(
-            _proj("bsd,de->bse", h, lp["in_dt"], aq).astype(f32)
-            + lp["dt_bias"])
-    with jax.named_scope("conv"):
-        tail = jax.lax.dynamic_index_in_dim(cache["conv"], slot, 0, False)
-        xbc, tail = causal_conv(xbc, tail, lp["conv_w"], lp["conv_b"])
-        # the bias would leak through a pad position
-        xbc = jnp.where(valid[..., None], xbc, 0.0).astype(h.dtype)
-        conv = jax.lax.dynamic_update_slice(
-            cache["conv"], tail.astype(cache["conv"].dtype)[None],
-            (slot, 0, 0, 0))
-    with jax.named_scope("ssd"):
-        x = xbc[..., :inner].reshape(B, S, H, P)
-        Bm = xbc[..., inner:inner + N]
-        Cm = xbc[..., inner + N:]
-        A = -jnp.exp(lp["A_log"].astype(f32))
-        ssm = cache["ssm"]
-        # imported on use, as llama's kernels: the dense families' paths
-        # never load it
-        from ..ops import ssd_scan
-
-        if scan_kernels and ssm.dtype == f32:
-            if S == 1:
-                y, ssm = ssd_scan.ssm_decode_update(
-                    x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], lp["D"], ssm,
-                    slot, interpret=interpret)
-                y = y[:, None]
-            else:
-                # left padding: a row's pads are its first positions
-                pads = S - jnp.sum(valid, axis=-1, dtype=jnp.int32)
-                y, ssm = ssd_scan.ssd_prefill_scan(
-                    x, dt, A, Bm, Cm, lp["D"], ssm, slot, pads,
-                    chunk=cfg.mamba_chunk_size, interpret=interpret)
-        else:
-            state = jax.lax.dynamic_index_in_dim(ssm, slot, 0, False).astype(
-                f32)
-            if S == 1:
-                y, state = ssd_scan.ssm_step_xla(
-                    x[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], lp["D"], state)
-                y = y[:, None]
-            else:
-                y, state = ssd_scan.ssd_chunked_xla(
-                    x, dt, A, Bm, Cm, lp["D"], state, cfg.mamba_chunk_size)
-            ssm = jax.lax.dynamic_update_slice(
-                ssm, state.astype(ssm.dtype)[None], (slot, 0, 0, 0))
-    with jax.named_scope("ssm_out"):
-        # gate, then norm, over the whole inner width
-        y = y.reshape(B, S, inner).astype(f32) * jax.nn.silu(z.astype(f32))
-        y = y * jax.lax.rsqrt(
-            jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
-        y = (y * lp["ssm_norm"]).astype(h.dtype)
-        out = _proj("bse,ed->bsd", y, lp["out_proj"], aq)
-    return out, dict(cache, conv=conv, ssm=ssm)
 
 
 def _attention_mixer(h, lp, slot, mask, cache, write_index,
@@ -426,7 +314,7 @@ def forward(params: dict, cfg: GraniteHybridConfig, tokens, positions, cache,
     def mamba_layer(x, cache, lp, ffn_lp, slot):
         h = _rmsnorm(x, lp["mixer_norm"], cfg.norm_eps)
         h = jnp.where(valid[..., None], h, jnp.zeros_like(h))
-        out, cache = _mamba_mixer(h, lp, slot, valid, cache, cfg,
+        out, cache = mamba_mixer(h, lp, slot, valid, cache, cfg,
                                   scan_kernels, interpret)
         return _ffn(x + out * jnp.asarray(res, x.dtype), ffn_lp, cfg), cache
 
@@ -493,35 +381,6 @@ def forward_dense(params: dict, cfg: GraniteHybridConfig,
 
 
 # -- the engine's seam (models/family.py) -------------------------------------
-
-
-def last_state(cache: dict) -> jax.Array:
-    """[2, B, N, heads * P]: the first and the last Mamba layer's recurrent
-    state after the latest forward, so that a parity check sees the state
-    and not the logits alone — the first layer's carries one product's
-    rounding and the scan's own arithmetic, the last layer's everything
-    before it."""
-    return jnp.stack([cache["ssm"][0], cache["ssm"][-1]])
-
-
-def prefill_counts(cfg: GraniteHybridConfig, pad_lens, spans) -> dict:
-    """What the scan of one dispatch's prefill saw, from the pads it was
-    packed with: real tokens x Mamba layers, and the tokens of the chunks
-    ``ssd_prefill_scan`` did not skip x Mamba layers. ``spans`` are the
-    prefill's query spans [lo, hi) over the bucket."""
-    import numpy as np
-
-    from ..ops.ssd_scan import scan_tokens_computed
-
-    pads = np.asarray(pad_lens, np.int64)
-    real = computed = 0
-    for lo, hi in spans:
-        inside = np.clip(pads - lo, 0, hi - lo)   # pads among these tokens
-        real += int(((hi - lo) - inside).sum())
-        computed += scan_tokens_computed(inside, hi - lo,
-                                         cfg.mamba_chunk_size)
-    return {"scan_tokens_real": real * cfg.n_mamba,
-            "scan_tokens_computed": computed * cfg.n_mamba}
 
 
 def _forward_kwargs(cfg: GraniteHybridConfig, kernels: bool, interpret: bool):

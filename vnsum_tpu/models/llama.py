@@ -331,6 +331,8 @@ def _mlp_act(x: jax.Array, act: str) -> jax.Array:
         return jax.nn.gelu(x, approximate=True)
     if act == "relu":
         return jnp.maximum(x, 0)
+    if act == "relu2":   # the square of relu: an expert with no gate
+        return jnp.square(jnp.maximum(x, 0))
     return jax.nn.silu(x)
 
 

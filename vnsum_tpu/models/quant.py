@@ -47,8 +47,8 @@ _ALL_CONTRACT_AXES = {
     # stacked experts [E, D, F] / [E, F, D]: the expert axis stays, so the
     # scale is per expert and output channel, [E, F] / [E, D]
     "we_gate": (1,), "we_up": (1,), "we_down": (1,),
-    # a Mamba-2 mixer's products (models/granite_hybrid.py: in_proj held
-    # as its parts z | xBC | dt, and out_proj); its convolution, dt_bias,
+    # a Mamba-2 mixer's products (models/mamba_mixer.py: in_proj held as
+    # its parts z | xBC | dt, and out_proj); its convolution, dt_bias,
     # A_log, D and gated norm stay float32
     "in_z": (0,), "in_xbc": (0,), "in_dt": (0,),   # [D, inner | conv | H]
     "out_proj": (0,),                              # [inner, D]
@@ -58,7 +58,10 @@ _ALL_CONTRACT_AXES = {
 # whose attention differs in shape by layer kind keeps each kind's under
 # "full" and "sliding" (models/laguna.py); one that mixes Mamba and attention
 # layers keeps each kind's mixer under "mamba" and "attn" and every layer's
-# feed-forward under "layers" (models/granite_hybrid.py)
+# feed-forward under "layers" (models/granite_hybrid.py); one whose layers
+# are each ONE mixer keeps the sparse-expert layers under "layers"
+# (models/nemotron_h.py: two matrices an expert, "we_up" and "we_down"; its
+# router and the router's correction bias stay float32)
 _LAYER_GROUPS = ("layers", "dense", "full", "sliding", "mamba", "attn")
 
 
@@ -151,6 +154,10 @@ def init_params_quantized(key: jax.Array, cfg) -> dict:
         for g, leaves in module.float_leaves(
                 jax.random.fold_in(key, 1), cfg).items():
             groups[g].update(leaves)
+    if hasattr(module, "zero_padding"):
+        # stored values that are no part of the model (experts stored wider
+        # than they are): zero, whatever was drawn there
+        groups = module.zero_padding(groups, cfg)
     out = {
         "embed": qinit(next(keys), shapes["embed"], (1,)),
         "final_norm": jnp.ones(

@@ -22,7 +22,10 @@ a capacity, nothing is dropped.
 
 ``expert_grouped_matmul(lhs, w, ...)`` is one product; with ``w_up`` it is
 the gated front half ``act(lhs . w) * (lhs . w_up)`` in one pass over
-``lhs`` (``act`` ``silu``: SwiGLU, ``relu``: ReGLU). The weights are the
+``lhs`` (``act`` ``silu``: SwiGLU, ``relu``: ReGLU); with ``act`` ``relu2``
+and no ``w_up`` it is the front half of an expert that has NO gate,
+``relu(lhs . w)^2`` (the square in float32 before the product is rounded;
+Nemotron-H's two-matrix experts). The weights are the
 STACKED leaves of every expert layer, ``[L, E, K, N]``, read in place: the layer arrives by scalar prefetch and only steers
 the block index, so no layer's 900 MB of experts is ever copied out of the
 stack. int8 weights are ``{"q": [L, E, K, N], "s": [L, E, N]}`` (per expert
@@ -140,6 +143,8 @@ def _kernel(layer_ref, te_ref, *refs, quantized: bool, int8_lhs: bool,
     if gated:
         gate = jnp.maximum(y, 0.0) if act == "relu" else jax.nn.silu(y)
         y = gate * product(u_ref, us_ref)
+    elif act == "relu2":
+        y = jnp.square(jnp.maximum(y, 0.0))
     o_ref[...] = y.astype(o_ref.dtype)
 
 
@@ -157,8 +162,12 @@ def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
     ``lhs_scale`` None or a factor a row, [M, 1] float32, applied to the
     product before it is rounded: a routing weight); ``w`` and the optional ``w_up`` [L, E, K, N] or int8
     ``{"q", "s"}`` leaves; ``layer`` a scalar; ``act`` the gate's
-    activation where ``w_up`` is given (``silu`` or ``relu``); returns
-    [M, N] in ``out_dtype``."""
+    activation where ``w_up`` is given (``silu`` or ``relu``); without
+    ``w_up`` a gate's name leaves the product plain (a down product, as
+    ever) and ``relu2`` makes it the single-product front half
+    ``relu(lhs . w)^2``; ``tn`` whole lane tiles, or the whole width N
+    where N has no such divisor (``models/experts.py::_column_tile``);
+    returns [M, N] in ``out_dtype``."""
     M, K = lhs.shape
     quantized = isinstance(w, dict)
     wq = w["q"] if quantized else w
@@ -172,8 +181,10 @@ def expert_grouped_matmul(lhs, lhs_scale, w, w_up, layer, tile_expert,
     if M % tm or N % tn:
         raise ValueError(f"[{M}, {N}] is not whole tiles of [{tm}, {tn}]")
     gated = w_up is not None
-    if act not in ("silu", "relu"):
-        raise ValueError(f"act={act!r}: the gate is silu or relu")
+    if act not in (("silu", "relu") if gated else ("silu", "relu", "relu2")):
+        raise ValueError(
+            f"act={act!r}: a gate is silu or relu, and a single product "
+            "plain under those names or relu2")
 
     def row(n, m, layer, te):
         return (m, 0)

@@ -86,6 +86,14 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   under the order above (1024, 1024) ties at G=4 (4.1035 s for 4.1051 at
   Qwen3's group, -0.9% at Phi-4's, -0.7% at the join's, -0.4% at
   Granite's, the control's noise 0.4-1.2%; PR 45), so the tile stays;
+  at G=16 on 2 KV heads (Nemotron-H's 32/2 heads of 128, the widest group
+  a cell runs and the last the rule holds at 1024 query rows; kernel alone
+  at its map dispatch — 12 rows, ten full and two tail rows, int8 cache, 2
+  layers; seconds / ns per 1,024 computed scores; PR 47): (512, 512) 0.2009
+  / 8.6; (512, 1024) 0.1162 / 4.7; **(1024, 1024) 0.1100 / 4.4, the
+  rule's**; (512, 2048) 0.1209 / 4.3 (13% more scores computed);
+  (1024, 512) 0.2070 / 8.4 — the key width sets the cost at G=16 as at
+  G=7, and the rule stands;
   G <= 3 and hd=256 have no cell (ROADMAP Queue 1 item 1c). _vmem_bytes
   counts what a
   geometry needs; past the 32 MiB the attention kernels share, this

@@ -4,11 +4,13 @@ decode, each as its XLA form and as a Pallas TPU kernel.
 
 The recurrence, per row, head ``h`` (``P`` channels, a state of ``N``):
 
-    H_t = exp(dt_t[h] * A[h]) * H_{t-1} + dt_t[h] * X_t[h] (x) B_t
-    Y_t[h] = H_t C_t + D[h] * X_t[h]
+    H_t = exp(dt_t[h] * A[h]) * H_{t-1} + dt_t[h] * X_t[h] (x) B_t[g(h)]
+    Y_t[h] = H_t C_t[g(h)] + D[h] * X_t[h]
 
 with ``dt`` already through its softplus, ``A`` negative, and ``B``, ``C``
-shared by the heads (one group). A position whose ``X`` is zero adds
+``[G, N]`` a token: ``G`` groups, head ``h`` reading group ``g(h) = h //
+(H / G)`` (one group: every head the same ``B`` and ``C``, Granite-4.0-H;
+eight groups of eight heads, Nemotron-H). A position whose ``X`` is zero adds
 nothing to the state, so a state that is zero stays zero through a row's
 left pad whatever ``dt`` reads there (``models/granite_hybrid.py`` zeroes
 ``X``, ``B`` and ``C`` under the pad).
@@ -18,7 +20,8 @@ sublanes and (head, channel) on the lanes, transposed from the
 ``[H, P, N]`` of the equations. Everything a step scales the state by — a
 head's decay, ``dt * X`` — varies along (head, channel), so it is a lane
 row of the ``[B, H * P]`` arrays the layer already has; ``B_t`` alone
-varies along the sublanes and is a ``[N, 1]`` column. The decode update is
+varies along the sublanes and is a ``[N, 1]`` column a group, which the
+group's heads — a run of ``H * P / G`` lanes — share. The decode update is
 then element-wise products and one sublane sum, in float32 with no matrix
 product and no transpose, and every matrix product of the prefill kernel
 is a plain ``[m, k] @ [k, n]``. Both kernels take the whole stacked state
@@ -36,8 +39,11 @@ With ``cum_i`` the running sum of ``dt * A`` inside a chunk, for one head:
 Grid (rows, chunks), chunks in sequence with the row's state — all heads —
 in VMEM scratch; the heads go ``128 / P`` at a time (a lane tile: two
 heads of 64), so every product is 128 lanes wide: the tile's heads share
-the operand ``dt * X`` and each takes its own lanes of the result. The
-decays, ``C B^T`` (once a chunk, shared by the heads), the masked product
+the operand ``dt * X`` and each takes its own lanes of the result (a tile
+never spans two groups: it holds ``min(128 / P, H / G)`` heads). The
+decays, ``C B^T`` (once a chunk and GROUP, shared by the group's heads: by
+all of them with one group, by eight heads = four lane tiles at Nemotron-H's
+widths), the masked product
 and the state update never leave VMEM. ``cum`` is summed outside, in
 float32 by XLA (a product on the MXU would round it), and handed in twice,
 by rows and by columns. A chunk wholly under the row's left pad is neither
@@ -72,79 +78,99 @@ def _whole_chunks(chunk: int, *arrays):
 # -- XLA forms ----------------------------------------------------------------
 
 
+def _by_group(a: jax.Array, ndim: int) -> jax.Array:
+    """B or C with its group dim: ``[..., N]`` of one group (``ndim - 1``
+    dims) becomes ``[..., 1, N]``."""
+    return a if a.ndim == ndim else a[..., None, :]
+
+
 def ssd_chunked_xla(x, dt, A, Bm, Cm, D, state, chunk: int):
     """The chunked scan in plain XLA: x [B, S, H, P], dt [B, S, H] float32
-    (through its softplus), A, D [H], Bm, Cm [B, S, N], state [B, N, H * P]
-    float32 -> (y [B, S, H, P] in x's type, the state after the S tokens).
-    S is padded at its END to whole chunks."""
+    (through its softplus), A, D [H], Bm, Cm [B, S, G, N] (or [B, S, N]: one
+    group), state [B, N, H * P] float32 -> (y [B, S, H, P] in x's type, the
+    state after the S tokens). S is padded at its END to whole chunks."""
     Bt, S, H, P = x.shape
-    N = Bm.shape[-1]
+    Bm, Cm = _by_group(Bm, 4), _by_group(Cm, 4)
+    G, N = Bm.shape[-2:]
+    R = H // G                                             # heads a group
     x, dt, Bm, Cm = _whole_chunks(chunk, x, dt, Bm, Cm)
     nc = x.shape[1] // chunk
     f32 = jnp.float32
-    xc = x.reshape(Bt, nc, chunk, H, P)
+    xc = x.reshape(Bt, nc, chunk, G, R, P)
     dtc = dt.astype(f32).reshape(Bt, nc, chunk, H)
-    Bc = Bm.reshape(Bt, nc, chunk, N)
-    Cc = Cm.reshape(Bt, nc, chunk, N)
+    Bc = Bm.reshape(Bt, nc, chunk, G, N)
+    Cc = Cm.reshape(Bt, nc, chunk, G, N)
     cum = jnp.cumsum(dtc * A.astype(f32), axis=2)          # [B, nc, Q, H]
     tri = jnp.tril(jnp.ones((chunk, chunk), bool))
     diff = cum[:, :, :, None, :] - cum[:, :, None, :, :]   # [B, nc, i, j, H]
-    decay = jnp.exp(jnp.where(tri[None, None, :, :, None], diff, -jnp.inf))
-    G = jnp.einsum("bcin,bcjn->bcij", Cc, Bc,
-                   preferred_element_type=f32)
-    xd = xc.astype(f32) * dtc[..., None]                   # dt * X
-    y = jnp.einsum("bcij,bcijh,bcjhp->bcihp", G, decay, xd)
+    decay = jnp.exp(jnp.where(tri[None, None, :, :, None], diff, -jnp.inf)
+                    ).reshape(Bt, nc, chunk, chunk, G, R)
+    CB = jnp.einsum("bcign,bcjgn->bcgij", Cc, Bc,
+                    preferred_element_type=f32)
+    xd = xc.astype(f32) * dtc.reshape(Bt, nc, chunk, G, R)[..., None]
+    y = jnp.einsum("bcgij,bcijgr,bcjgrp->bcigrp", CB, decay, xd)
     # what each chunk adds to the state, and the state entering each chunk
-    to_end = jnp.exp(cum[:, :, -1:, :] - cum)              # [B, nc, Q, H]
-    adds = jnp.einsum("bcjn,bcjh,bcjhp->bcnhp", Bc.astype(f32), to_end, xd)
-    total = jnp.exp(cum[:, :, -1, :])                      # [B, nc, H]
+    to_end = jnp.exp(cum[:, :, -1:, :] - cum).reshape(Bt, nc, chunk, G, R)
+    adds = jnp.einsum("bcjgn,bcjgr,bcjgrp->bcngrp", Bc.astype(f32), to_end,
+                      xd)
+    total = jnp.exp(cum[:, :, -1, :]).reshape(Bt, nc, G, R)
 
     def step(h, xs):
         add, tot = xs
-        return h * tot[:, None, :, None] + add, h
+        return h * tot[:, None, :, :, None] + add, h
 
-    h0 = state.reshape(Bt, N, H, P)
+    h0 = state.reshape(Bt, N, G, R, P)
     h_end, h_in = jax.lax.scan(
         step, h0, (adds.swapaxes(0, 1), total.swapaxes(0, 1)))
-    h_in = h_in.swapaxes(0, 1)                             # [B, nc, N, H, P]
-    y = y + jnp.einsum("bcin,bcnhp->bcihp", Cc.astype(f32), h_in) * jnp.exp(
-        cum)[..., None]
-    y = y + D.astype(f32)[:, None] * xc.astype(f32)
+    h_in = h_in.swapaxes(0, 1)                          # [B, nc, N, G, R, P]
+    y = y + jnp.einsum("bcign,bcngrp->bcigrp", Cc.astype(f32), h_in) \
+        * jnp.exp(cum).reshape(Bt, nc, chunk, G, R)[..., None]
+    y = y + D.astype(f32).reshape(G, R)[..., None] * xc.astype(f32)
     y = y.reshape(Bt, nc * chunk, H, P)[:, :S]
     return y.astype(x.dtype), h_end.reshape(Bt, N, H * P)
 
 
 def ssm_step_xla(x, dt, A, Bv, Cv, D, state):
     """One token of the recurrence: x [B, H, P], dt [B, H] float32, Bv, Cv
-    [B, N], state [B, N, H * P] float32 -> (y [B, H, P] float32, state)."""
+    [B, G, N] (or [B, N]: one group), state [B, N, H * P] float32 ->
+    (y [B, H, P] float32, state)."""
     Bt, H, P = x.shape
+    Bv, Cv = _by_group(Bv, 3), _by_group(Cv, 3)
+    G, N = Bv.shape[-2:]
     f32 = jnp.float32
     decay = jnp.repeat(jnp.exp(dt * A.astype(f32)), P, axis=-1)   # [B, HP]
     dtx = (dt[..., None] * x.astype(f32)).reshape(Bt, H * P)
-    state = (state * decay[:, None, :]
-             + Bv.astype(f32)[:, :, None] * dtx[:, None, :])
-    y = jnp.einsum("bnk,bn->bk", state, Cv.astype(f32),
+    # a group's heads are a run of H * P / G lanes
+    lanes = lambda a: a.reshape(a.shape[:-1] + (G, H * P // G))  # noqa: E731
+    state = (lanes(state) * lanes(decay)[:, None]
+             + Bv.astype(f32).swapaxes(1, 2)[..., None] * lanes(dtx)[:, None])
+    y = jnp.einsum("bngk,bgn->bgk", state, Cv.astype(f32),
                    precision=jax.lax.Precision.HIGHEST)
     y = y.reshape(Bt, H, P) + D.astype(f32)[:, None] * x.astype(f32)
-    return y, state
+    return y, state.reshape(Bt, N, H * P)
 
 
 # -- the prefill kernel -------------------------------------------------------
 
 
-def _heads_per_tile(H: int, P: int) -> int:
+def _heads_per_tile(H: int, P: int, G: int = 1) -> int:
     """Heads a lane tile holds: 128 / P of them, two at P = 64 (all of them
-    where the heads together are narrower than a tile)."""
-    return max(1, min(H, _LANES // P)) if P <= _LANES else 1
+    where the heads together are narrower than a tile), and never heads of
+    two groups."""
+    hpt = max(1, min(H, _LANES // P)) if P <= _LANES else 1
+    return min(hpt, H // G)
 
 
 def _prefill_kernel(lidx_ref, pad_ref, x_ref, dtc_ref, cumc_ref, cumr_ref,
                     bt_ref, c_ref, d_ref, hin_ref, y_ref, hout_ref, h_scr, *,
-                    chunk: int, n_heads: int, head_dim: int, hpt: int):
+                    chunk: int, n_heads: int, head_dim: int, hpt: int,
+                    n_groups: int):
     b = pl.program_id(0)
     c = pl.program_id(1)
     nc = pl.num_programs(1)
     Q, P, W = chunk, head_dim, hpt * head_dim
+    N = h_scr.shape[0]
+    tiles_a_group = n_heads // n_groups // hpt
     f32 = jnp.float32
 
     @pl.when(c == 0)
@@ -161,9 +187,6 @@ def _prefill_kernel(lidx_ref, pad_ref, x_ref, dtc_ref, cumc_ref, cumr_ref,
     @pl.when(live)
     def _chunk():
         dtype = x_ref.dtype
-        Cc = c_ref[0]                                        # [Q, N]
-        # C B^T, once a chunk: the heads share B and C (one group)
-        G = jnp.dot(Cc, bt_ref[0], preferred_element_type=f32)   # [Q, Q]
         tri = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
                >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
         head_lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_heads), 1)
@@ -177,44 +200,62 @@ def _prefill_kernel(lidx_ref, pad_ref, x_ref, dtc_ref, cumc_ref, cumr_ref,
             return jnp.sum(jnp.where(head_lane == h, block, 0.0), axis=1,
                            keepdims=True)
 
-        def tile(t, _):
-            lanes = pl.ds(pl.multiple_of(t * W, W), W)
-            xp = x_ref[0, :, lanes].astype(f32)              # [Q, W]
-            # per head of the tile: its columns, then one row or column of
-            # the tile's own lanes picked from them
-            cols = [(column(dtc, t * hpt + u), column(cumc, t * hpt + u))
-                    for u in range(hpt)]
+        def group(g, _):
+            # the group's B^T [N, Q] and C [Q, N]: its rows and its lanes of
+            # the blocks (all of them with one group)
+            if n_groups == 1:
+                Bt, Cc = bt_ref[0], c_ref[0]
+            else:
+                Bt = bt_ref[0, pl.ds(pl.multiple_of(g * N, N), N), :]
+                Cc = c_ref[0, :, pl.ds(pl.multiple_of(g * N, N), N)]
+            # C B^T, once a chunk and group: the group's heads share it
+            CB = jnp.dot(Cc, Bt, preferred_element_type=f32)     # [Q, Q]
 
-            def by_head(values):
-                out = values[0]
-                for u in range(1, hpt):
-                    out = jnp.where(tile_lane == u, values[u], out)
-                return out
+            def tile(t, _):
+                t = g * tiles_a_group + t
+                lanes = pl.ds(pl.multiple_of(t * W, W), W)
+                xp = x_ref[0, :, lanes].astype(f32)              # [Q, W]
+                # per head of the tile: its columns, then one row or column
+                # of the tile's own lanes picked from them
+                cols = [(column(dtc, t * hpt + u), column(cumc, t * hpt + u))
+                        for u in range(hpt)]
 
-            last = [cum[Q - 1:Q, :] for _, cum in cols]      # [1, 1] each
-            xd = xp * by_head([dt for dt, _ in cols])        # dt * X
-            xd_in = xd.astype(dtype)
-            y = None
-            for u in range(hpt):
-                cum = cols[u][1]
-                row = cumr_ref[0, pl.ds(t * hpt + u, 1), :]  # [1, Q]
-                decay = jnp.exp(jnp.where(tri, cum - row, -jnp.inf))
-                yu = jnp.dot((G * decay).astype(dtype), xd_in,
-                             preferred_element_type=f32)     # [Q, W]
-                y = yu if y is None else jnp.where(tile_lane == u, yu, y)
-            hp = h_scr[:, lanes]                             # [N, W] f32
-            y = y + jnp.dot(Cc, hp.astype(dtype),
-                            preferred_element_type=f32) * by_head(
-                                [jnp.exp(cum) for _, cum in cols])
-            y = y + d_ref[:, lanes] * xp
-            y_ref[0, :, lanes] = y.astype(y_ref.dtype)
-            xw = xd * by_head(
-                [jnp.exp(end - cum) for end, (_, cum) in zip(last, cols)])
-            h_scr[:, lanes] = hp * by_head([jnp.exp(end) for end in last]) \
-                + jnp.dot(bt_ref[0], xw.astype(dtype),
-                          preferred_element_type=f32)
+                def by_head(values):
+                    out = values[0]
+                    for u in range(1, hpt):
+                        out = jnp.where(tile_lane == u, values[u], out)
+                    return out
 
-        jax.lax.fori_loop(0, n_heads // hpt, tile, None)
+                last = [cum[Q - 1:Q, :] for _, cum in cols]      # [1, 1] each
+                xd = xp * by_head([dt for dt, _ in cols])        # dt * X
+                xd_in = xd.astype(dtype)
+                y = None
+                for u in range(hpt):
+                    cum = cols[u][1]
+                    row = cumr_ref[0, pl.ds(t * hpt + u, 1), :]  # [1, Q]
+                    decay = jnp.exp(jnp.where(tri, cum - row, -jnp.inf))
+                    yu = jnp.dot((CB * decay).astype(dtype), xd_in,
+                                 preferred_element_type=f32)     # [Q, W]
+                    y = yu if y is None else jnp.where(tile_lane == u, yu, y)
+                hp = h_scr[:, lanes]                             # [N, W] f32
+                y = y + jnp.dot(Cc, hp.astype(dtype),
+                                preferred_element_type=f32) * by_head(
+                                    [jnp.exp(cum) for _, cum in cols])
+                y = y + d_ref[:, lanes] * xp
+                y_ref[0, :, lanes] = y.astype(y_ref.dtype)
+                xw = xd * by_head(
+                    [jnp.exp(end - cum) for end, (_, cum) in zip(last, cols)])
+                h_scr[:, lanes] = hp * by_head(
+                    [jnp.exp(end) for end in last]) \
+                    + jnp.dot(Bt, xw.astype(dtype),
+                              preferred_element_type=f32)
+
+            jax.lax.fori_loop(0, tiles_a_group, tile, None)
+
+        if n_groups == 1:
+            group(0, None)
+        else:
+            jax.lax.fori_loop(0, n_groups, group, None)
 
     @pl.when(c == nc - 1)
     def _store():
@@ -226,16 +267,20 @@ def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
                      chunk: int, interpret: bool = False):
     """The chunked scan over S tokens from layer ``layer_idx``'s state of
     the stacked ``state`` [L, B, N, H * P] float32; x [B, S, H, P], dt
-    [B, S, H] float32, Bm, Cm [B, S, N], ``pad_lens`` [B] the left-pad
-    slots among these S (whole chunks of them are skipped). Returns
-    (y [B, S, H, P], the stacked state with the layer's block overwritten
-    in place). Semantics: ``ssd_chunked_xla``."""
+    [B, S, H] float32, Bm, Cm [B, S, G, N] (or [B, S, N]: one group),
+    ``pad_lens`` [B] the left-pad slots among these S (whole chunks of them
+    are skipped). Returns (y [B, S, H, P], the stacked state with the
+    layer's block overwritten in place). Semantics: ``ssd_chunked_xla``."""
     Bt, S, H, P = x.shape
-    N = Bm.shape[-1]
+    Bm, Cm = _by_group(Bm, 4), _by_group(Cm, 4)
+    G, N = Bm.shape[-2:]
     HP = H * P
-    hpt = _heads_per_tile(H, P)
-    if H % hpt:
-        raise ValueError(f"{H} heads do not fill lane tiles of {hpt}")
+    if H % G:
+        raise ValueError(f"{G} groups do not divide {H} heads")
+    hpt = _heads_per_tile(H, P, G)
+    if (H // G) % hpt:
+        raise ValueError(
+            f"a group's {H // G} heads do not fill lane tiles of {hpt}")
     x, dt, Bm, Cm = _whole_chunks(chunk, x, dt, Bm, Cm)
     Sp = x.shape[1]
     nc = Sp // chunk
@@ -247,7 +292,9 @@ def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
         (dt * A.astype(f32)).reshape(Bt, nc, chunk, H), axis=2
     ).reshape(Bt, Sp, H)
     cum_rows = cum.transpose(0, 2, 1)
-    Bt_rows = Bm.transpose(0, 2, 1)                          # [B, N, S]
+    # a group's B^T its N rows of [B, G * N, S], its C its N lanes
+    Bm, Cm = Bm.reshape(Bt, Sp, G * N), Cm.reshape(Bt, Sp, G * N)
+    Bt_rows = Bm.transpose(0, 2, 1)
     d_lanes = jnp.repeat(D.astype(f32), P)[None, :]          # [1, HP]
 
     def first_live(b, c, lidx, pad):
@@ -263,7 +310,8 @@ def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
     state_block = pl.BlockSpec(
         (1, 1, N, HP), lambda b, c, lidx, pad: (lidx[0], b, 0, 0))
     kernel = functools.partial(
-        _prefill_kernel, chunk=chunk, n_heads=H, head_dim=P, hpt=hpt)
+        _prefill_kernel, chunk=chunk, n_heads=H, head_dim=P, hpt=hpt,
+        n_groups=G)
     y, state = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -274,8 +322,8 @@ def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
                 seq_block(H),       # dt by columns
                 seq_block(H),       # cum by columns
                 row_block(H),       # cum by rows
-                row_block(N),       # B^T
-                seq_block(N),       # C
+                row_block(G * N),   # B^T, group by group
+                seq_block(G * N),   # C, group by group
                 pl.BlockSpec((1, HP), lambda b, c, lidx, pad: (0, 0)),
                 state_block,
             ],
@@ -323,33 +371,43 @@ def scan_tokens_computed(pad_lens, S: int, chunk: int) -> int:
 
 
 def _decode_kernel(lidx_ref, decay_ref, dtx_ref, bcol_ref, ccol_ref,
-                   hin_ref, y_ref, hout_ref):
-    h = (hin_ref[0, 0] * decay_ref[0]            # [N, HP] * [1, HP]
-         + bcol_ref[0] * dtx_ref[0])             # [N, 1] * [1, HP]
-    hout_ref[0, 0] = h
-    y_ref[0] = jnp.sum(h * ccol_ref[0], axis=0, keepdims=True)
+                   hin_ref, y_ref, hout_ref, *, n_groups: int):
+    # a group's heads are a run of H * P / G lanes; each run takes its
+    # group's columns of B and C
+    W = hin_ref.shape[-1] // n_groups
+    for g in range(n_groups):
+        lanes = slice(g * W, (g + 1) * W)
+        h = (hin_ref[0, 0, :, lanes] * decay_ref[0, :, lanes]    # [N, W]
+             + bcol_ref[0, g] * dtx_ref[0, :, lanes])        # [N, 1] * [1, W]
+        hout_ref[0, 0, :, lanes] = h
+        y_ref[0, :, lanes] = jnp.sum(h * ccol_ref[0, g], axis=0,
+                                     keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def ssm_decode_update(x, dt, A, Bv, Cv, D, state, layer_idx, *,
                       interpret: bool = False):
     """One token for every row: x [B, H, P], dt [B, H] float32, Bv, Cv
-    [B, N], the stacked ``state`` [L, B, N, H * P] float32, whose layer
-    ``layer_idx`` is read and overwritten in place. Returns (y [B, H, P]
-    float32, the stacked state). Semantics: ``ssm_step_xla``."""
+    [B, G, N] (or [B, N]: one group), the stacked ``state``
+    [L, B, N, H * P] float32, whose layer ``layer_idx`` is read and
+    overwritten in place. Returns (y [B, H, P] float32, the stacked state).
+    Semantics: ``ssm_step_xla``."""
     Bt, H, P = x.shape
-    N = Bv.shape[-1]
+    Bv, Cv = _by_group(Bv, 3), _by_group(Cv, 3)
+    G, N = Bv.shape[-2:]
     HP = H * P
+    if HP % G:
+        raise ValueError(f"{G} groups do not divide {H} heads")
     f32 = jnp.float32
     xf = x.astype(f32)
     decay = jnp.repeat(jnp.exp(dt * A.astype(f32)), P, axis=-1)
     dtx = (dt[..., None] * xf).reshape(Bt, HP)
     row = pl.BlockSpec((1, 1, HP), lambda b, lidx: (b, 0, 0))
-    col = pl.BlockSpec((1, N, 1), lambda b, lidx: (b, 0, 0))
+    col = pl.BlockSpec((1, G, N, 1), lambda b, lidx: (b, 0, 0, 0))
     state_block = pl.BlockSpec(
         (1, 1, N, HP), lambda b, lidx: (lidx[0], b, 0, 0))
     y, state = pl.pallas_call(
-        _decode_kernel,
+        functools.partial(_decode_kernel, n_groups=G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(Bt,),
@@ -370,7 +428,7 @@ def ssm_decode_update(x, dt, A, Bv, Cv, D, state, layer_idx, *,
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
         decay[:, None, :], dtx[:, None, :],
-        Bv.astype(f32)[:, :, None], Cv.astype(f32)[:, :, None], state,
+        Bv.astype(f32)[..., None], Cv.astype(f32)[..., None], state,
     )
     y = y.reshape(Bt, H, P) + D.astype(f32)[:, None] * xf
     return y, state
