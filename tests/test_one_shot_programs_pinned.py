@@ -25,15 +25,26 @@ for Granite's one group); the other nine did not move.
 `test_granites_program_calls_the_kernels_it_called` holds what had to stay:
 the same kernels by name, as many calls of each.
 
+**PR 48 moved none of the eleven and added `llama-row-pieces`**: the dense
+family's chunked prefill runs a chunk a row piece at a time where a piece
+holds `Family.prefill_piece_tokens` (2,048) tokens, and these tiny programs'
+whole chunks hold 256, so they are the whole batch a chunk as before —
+`llama`, `llama-qk-norm` and the slot programs included, which ISSUE 48
+expected to move under a piece counted in rows. The new pin is the tiny
+llama program with the piece set to its 128-token chunk (`_PIECE_TOKENS`):
+the loop over row pieces, the fifth prefetched vector of the prefill
+kernel (`cache_rows`) and the per-row cache writes are in it.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
 (`assert_pinned`). `python tests/test_one_shot_programs_pinned.py` (from the
-repo's root, `PYTHONPATH=.`) traces all ten, prints both tables as they
+repo's root, `PYTHONPATH=.`) traces all twelve, prints both tables as they
 would have to read and rewrites that file — the one place that regenerates
 them."""
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import json
 from pathlib import Path
@@ -56,7 +67,11 @@ _PINNED = {
     "granite-h": ("tiny-granite-h", {}, "ae687d878a98f9b9"),
     "nemotron-h": ("tiny-nemotron-h", {}, "a4aa98b6e08cc54b"),
     "deepseek-v2": (tiny_deepseek, {}, "90bc1e80a899c229"),
+    "llama-row-pieces": ("tiny", {}, "fbfed62a99d09da7"),
 }
+# family -> the tokens a row piece of its prefill holds, where the pinned
+# program is not the family's own (`Family.prefill_piece_tokens`)
+_PIECE_TOKENS = {"llama-row-pieces": 128}
 
 # the slot loop's programs of the tiny llama family, "kind-rows" -> the same
 # hash: a join of 1 and of 2 rows, the segment of 4 slots, the adopt of a
@@ -114,10 +129,14 @@ def _backend(cfg, B: int, new: int) -> TpuBackend:
                       prefill_chunk_tokens=128)
 
 
-def one_shot_jaxpr(cfg, B: int = 2, S: int = 256, new: int = 8) -> str:
+def one_shot_jaxpr(cfg, B: int = 2, S: int = 256, new: int = 8,
+                   piece_tokens: int | None = None) -> str:
     """The text of the (B, S) one-shot program's jaxpr for ``cfg``, traced
-    on shapes alone."""
+    on shapes alone; ``piece_tokens`` replaces the family's own."""
     be = _backend(cfg, B, new)
+    if piece_tokens:
+        be.family = dataclasses.replace(
+            be.family, prefill_piece_tokens=piece_tokens)
     fn = be._make_fn(B, S, new, be.gen_cfg)
     return str(jax.make_jaxpr(fn)(
         jax.eval_shape(lambda: be.params),
@@ -164,7 +183,8 @@ def slot_jaxpr(program: str, S: int = 256, new: int = 8) -> str:
 @pytest.mark.parametrize("family", list(_PINNED))
 def test_the_one_shot_program_traces_to_the_pinned_jaxpr(family):
     config, kw, want = _PINNED[family]
-    text = one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw))
+    text = one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw),
+                          piece_tokens=_PIECE_TOKENS.get(family))
     assert "pallas_call" in text          # the kernels' bodies are hashed too
     assert_pinned(family, text, want)
 
@@ -195,10 +215,11 @@ def test_a_slot_program_traces_to_the_pinned_jaxpr(program):
 
 
 def regenerate() -> None:
-    """Trace all ten programs, print the two tables' hashes as they are now
+    """Trace all twelve programs, print the two tables' hashes as they are now
     and rewrite the line ladders."""
     texts = [("_PINNED", family, want,
-              one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw)))
+              one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw),
+                             piece_tokens=_PIECE_TOKENS.get(family)))
              for family, (config, kw, want) in _PINNED.items()]
     texts += [("_SLOT_PINNED", program, want, slot_jaxpr(program))
               for program, want in _SLOT_PINNED.items()]

@@ -138,6 +138,13 @@ class EngineStats:
     # computed tiles read, each expanded in VMEM, a head x layers: a chunked
     # prefill expands a key once for every chunk that reads it)
     prefill_blocks: dict = field(default_factory=dict)
+    # (row, chunk) pieces of the one-shot dispatches' and the joins'
+    # prefills — a row of the batch in one prefill chunk — and those of
+    # them the program did not run because the row holds nothing but left
+    # pad there (``TpuBackend._prefill_forward``; 0 for a family that
+    # names no piece), counted from the pads
+    prefill_row_chunks_total: int = 0
+    prefill_row_chunks_dead: int = 0
     # which attention each built program got, keyed "program[B=..,S=..]" →
     # {"prefill"|"decode": "kernel"|"dense"}: a dense fallback (unaligned
     # head dim, the slot/verify kernel under a mesh) is visible here and in
@@ -582,8 +589,9 @@ class TpuBackend:
 
         # prefill runs whole-prompt or in prefill_chunk_tokens slices —
         # chunking caps transient activations (q/k/v, MLP intermediates)
-        # at a chunk's worth, which is what lets B=16 decode fit at S=8192;
-        # see _prefill_forward
+        # at a chunk's worth (of a row piece's rows, where the family names
+        # one), which is what lets B=16 decode fit at S=8192; see
+        # _prefill_forward
         def prefill_part(params, tokens, pad_lens, seed, cache=None,
                          uids=None):
             with jax.named_scope("prefill"):
@@ -836,16 +844,17 @@ class TpuBackend:
         return cache
 
     def _prefill_stacked(self, use_flash, pad_lens, layer_window,
-                         q_offset: int = 0):
+                         q_offset: int = 0, **piece):
         """The family's stacked-attention fn for a prefill-style forward
         whose queries start at cache slot ``q_offset`` (0 = whole prompt;
-        chunked prefill passes each chunk's start). None when the dense
+        chunked prefill passes each chunk's start), with a row piece's
+        ``cache_rows`` where the forward runs one. None when the dense
         path is in effect."""
         if not use_flash:
             return None
         return self.family.prefill_attention(
             self.cfg, self.mesh, self.interpret, pad_lens, layer_window,
-            q_offset)
+            q_offset, **piece)
 
     def _prefill_forward(self, params, tokens, pad_lens, B, S, C,
                          use_flash, layer_window, cache=None, start=0):
@@ -859,26 +868,108 @@ class TpuBackend:
         arrives pre-seeded with gathered prefix KV for slots < K and the
         forward runs only over [K, S) — the same shape as chunked prefill's
         later chunks (positions/masks are sliced, q_offset places the
-        queries), so resume and chunked share all their machinery."""
+        queries), so resume and chunked share all their machinery.
+
+        Where the family names a piece (``Family.prefill_piece_tokens``) a
+        chunk runs a ROW PIECE at a time, ``_prefill_piece_rows`` rows of
+        the batch: rows are left-padded, so a row holds nothing real in
+        chunk [lo, hi) when its pad reaches hi, and a piece of such rows is
+        not run at all — its cache slots stay as they came (zeros, which
+        every kernel masks by ``pad_lens`` as it masks a pad token's keys).
+        The pieces take the rows longest pad first, whatever order the batch
+        holds them in, so a chunk's dead pieces are the first of its loop
+        and the loop starts after them: one traced body a chunk, no
+        conditional. A piece reads and writes its rows of the batch's cache
+        in place (``cache_rows``); no slice of the cache is made."""
         cfg = self.cfg
         if cache is None:
             cache = self._init_prefill_cache(B, C)
         positions = prefill_positions(pad_lens, S)
         mask = prefill_attention_mask(pad_lens, S, C)
+
         # chunked: transient activations scale with the CHUNK length, not
-        # the full S — the kernel's q_offset places chunk c's queries at
-        # cache slots [lo, hi) (see prefill_part's rationale comment)
+        # the full S (and with a piece's rows, not the batch's) — the
+        # kernel's q_offset places chunk c's queries at cache slots
+        # [lo, hi) (see prefill_part's rationale comment)
+        by_pad = None
         for lo, hi in self._prefill_spans(S, start):
-            logits, cache = self.family.forward(
-                params, cfg, tokens[:, lo:hi], positions[:, lo:hi],
-                cache, lo, mask[:, lo:hi, :],
-                last_only=(hi == S),
-                stacked_attention_fn=self._prefill_stacked(
-                    use_flash, pad_lens, layer_window, q_offset=lo
-                ),
-                **self._forward_kw,
-            )
+            R = self._prefill_piece_rows(B, hi - lo)
+            if not R:
+                logits, cache = self.family.forward(
+                    params, cfg, tokens[:, lo:hi], positions[:, lo:hi],
+                    cache, lo, mask[:, lo:hi, :],
+                    last_only=(hi == S),
+                    stacked_attention_fn=self._prefill_stacked(
+                        use_flash, pad_lens, layer_window, q_offset=lo
+                    ),
+                    **self._forward_kw,
+                )
+                continue
+            if by_pad is None:
+                by_pad = jnp.argsort(-pad_lens)
+                # rows no piece runs (fillers) leave zeros nobody samples
+                logits = jnp.zeros((B, 1, cfg.vocab_size), jnp.float32)
+
+            # the body is traced where it stands, no helper between: a
+            # frame more on the way to a layer's equations is seconds of a
+            # first call on the chip's host (PERF.md section 6, PRs 38, 48)
+            def piece(carry):  # traced at once, below: this turn's lo, hi, R
+                i, logits, cache = carry
+                rows = jax.lax.dynamic_slice_in_dim(by_pad, i * R, R)
+                pads = pad_lens[rows]
+                last, cache = self.family.forward(
+                    params, cfg, tokens[rows][:, lo:hi],
+                    prefill_positions(pads, S)[:, lo:hi], cache, lo,
+                    prefill_attention_mask(pads, S, C)[:, lo:hi, :],
+                    last_only=True,
+                    stacked_attention_fn=self._prefill_stacked(
+                        use_flash, pads, layer_window, q_offset=lo,
+                        cache_rows=rows),
+                    cache_rows=rows, **self._forward_kw,
+                )
+                if hi == S:  # an earlier chunk's logits are never computed
+                    logits = logits.at[rows].set(last)
+                return i + 1, logits, cache
+
+            # the counter's type is said, not inferred: a carry whose type
+            # moves in the body is traced twice, the layers with it
+            dead = jnp.sum(pad_lens >= hi, dtype=jnp.int32)
+            _, logits, cache = jax.lax.while_loop(
+                lambda carry: carry[0] < B // R, piece,
+                (dead // R, logits, cache))
         return logits, cache
+
+    def _prefill_piece_rows(self, B: int, n: int) -> int:
+        """How many of a batch's B rows one piece of an n-token prefill
+        chunk holds: the fewest that divide B and hold the family's
+        ``prefill_piece_tokens``. 0 is the whole batch in one forward —
+        where the family names no piece, where the batch's rows are spread
+        over a mesh's `data` axis, and where the whole chunk holds fewer
+        tokens than a piece should."""
+        floor = self.family.prefill_piece_tokens
+        if floor is None or (self.mesh is not None
+                             and self.mesh.shape.get("data", 1) > 1):
+            return 0
+        return next((R for R in range(1, B + 1)
+                     if B % R == 0 and R * n >= floor), 0)
+
+    def _count_row_chunks(self, pad_lens, S: int,
+                          start: int = 0) -> tuple[int, int]:
+        """Add one prefill's (row, chunk) pieces to
+        ``stats.prefill_row_chunks_total`` and those ``_prefill_forward``
+        did not run to ``stats.prefill_row_chunks_dead``. Pure host
+        arithmetic on the pads the prefill was packed with; returns this
+        prefill's (dead, total) for its span and its log line."""
+        pads = np.asarray(pad_lens, np.int64)
+        B, total, dead = len(pads), 0, 0
+        for lo, hi in self._prefill_spans(S, start):
+            R = self._prefill_piece_rows(B, hi - lo)
+            total += B
+            if R:
+                dead += int((pads >= hi).sum()) // R * R
+        self.stats.prefill_row_chunks_total += total
+        self.stats.prefill_row_chunks_dead += dead
+        return dead, total
 
     def _prefill_spans(self, S: int, start: int = 0) -> list[tuple[int, int]]:
         """Query spans [lo, hi) one prefill forward over cache slots
@@ -893,9 +984,11 @@ class TpuBackend:
                               start: int = 0) -> str:
         """Add one dispatch's prefill-kernel cells, by class, to
         ``stats.prefill_blocks``. Pure host arithmetic on the pads the
-        dispatch was packed with; nothing when its prefill is dense.
-        Returns what the dispatch's log line says of the family's own
-        counts."""
+        dispatch was packed with; nothing when its prefill is dense. The
+        cells are the whole batch's in every chunk: those of a row piece
+        that was not run (``_count_row_chunks``) are among ``dead_pad``,
+        though the kernel no longer steps over them. Returns what the
+        dispatch's log line says of the family's own counts."""
         if not self._decode_settings(S, C)[0]:
             return ""
         cfg = self.cfg
@@ -1879,12 +1972,15 @@ class TpuBackend:
                             # lint-allow[host-sync-in-hot-path]: one-shot result fetch bounds the dispatch and feeds detok
                             out = jax.device_get(out_dev)
                         with host_span("engine", "count", sink):
-                            grid = ""
+                            dead, pieces = self._count_row_chunks(
+                                pad_lens, S, K)
+                            disp.note(dead_row_chunks=dead)
+                            grid = f", dead_row_chunks {dead}/{pieces}"
                             if self.family.counters is not None:
                                 # a family that counts returns its counters
                                 # with the tokens: one fetch brought both
                                 out, counted = out
-                                grid = self._add_expert_counts(counted)
+                                grid += self._add_expert_counts(counted)
                             grid += self._count_prefill_blocks(
                                 pad_lens, S, S + max_new, K)
                         self.stats.batches += 1

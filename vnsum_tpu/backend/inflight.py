@@ -275,6 +275,7 @@ class TpuSlotLoop:
                 self._uid_next += len(take)
                 uids_np = np.zeros((Bj,), np.int32)
                 uids_np[: len(take)] = uids
+                dead, _ = b._count_row_chunks(pad_lens, self.S, K)
             prefill = b._get_seg_fn(
                 "slot_prefill", Bj, self.S, self.max_new, self.gen, K
             )
@@ -282,7 +283,8 @@ class TpuSlotLoop:
                 # the collector's "prefill": its end is the joiners' TTFT
                 # anchor, bounded by the fetch below (synced)
                 with host_span("slot", "prefill", sink, B=Bj, S=self.S,
-                               occupancy=len(take), synced=True) as pre:
+                               occupancy=len(take), dead_row_chunks=dead,
+                               synced=True) as pre:
                     if resume:
                         first, join_cache, done0 = prefill(
                             b.params, tokens, pad_lens, self.seed, uids_np,

@@ -61,6 +61,16 @@ class Family:
     # kernel expands), or None; counted whether or not
     # ``counts_prefill_blocks``
     prefill_counts: Callable | None = None
+    # the fewest tokens a ROW PIECE of this family's prefill may hold
+    # without its layers losing their pace, or None. Where it is set the
+    # engine runs a prefill chunk a piece of the batch's rows at a time —
+    # as few rows as hold this many tokens — and does not run a piece whose
+    # rows hold nothing but left pad in that chunk
+    # (``TpuBackend._prefill_forward``); ``forward`` and
+    # ``prefill_attention`` then take ``cache_rows``, the piece's rows of the
+    # batch's cache, which they read and write in place. None is the whole
+    # batch a chunk: a family sets it after measuring its own layers
+    prefill_piece_tokens: int | None = None
     # (cfg, kernels on, interpret) -> further keywords of ``forward``
     forward_kwargs: Callable = lambda cfg, kernels, interpret: {}
     # final state -> {name: device array} returned with a program's output,

@@ -428,7 +428,7 @@ def _attention(
     return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, hd)
 
 
-def _cache_write(buf, val, layer_idx, write_index):
+def _cache_write(buf, val, layer_idx, write_index, rows=None):
     """Write a per-layer K/V (or scale) slab into the stacked cache.
 
     ``buf`` [L, B, KV, C(, hd)], ``val`` [B, KV, S(, hd)]. ``write_index``
@@ -442,8 +442,19 @@ def _cache_write(buf, val, layer_idx, write_index):
     front and back, and on the chip XLA materializes both whole-cache
     transposes per layer per step — the slot loop's first B=2 segment ran
     past 230 ms/step and was declared hung (B=1 hid it: a size-1 axis
-    transposes for free)."""
+    transposes for free).
+
+    ``rows`` [B] names val's rows of a cache that holds more (a row piece of
+    the engine's prefill, scalar ``write_index``): the same chain, each row
+    written in place at its own batch row."""
     tail = (0,) * (buf.ndim - 4)  # hd present on k/v, absent on ks/vs
+    if rows is not None:
+        for b in range(val.shape[0]):
+            buf = jax.lax.dynamic_update_slice(
+                buf, val[None, b : b + 1],
+                (layer_idx, rows[b], 0, write_index) + tail
+            )
+        return buf
     if jnp.ndim(write_index) == 0:
         return jax.lax.dynamic_update_slice(
             buf, val[None], (layer_idx, 0, 0, write_index) + tail
@@ -468,43 +479,39 @@ def _in_window(write_index, S: int, C: int, window):
     return k_slot[None, None, :] > q_slot[:, :, None] - window
 
 
-def _write_kv(cache: dict, k, v, layer_idx, write_index) -> dict:
+def _write_kv(cache: dict, k, v, layer_idx, write_index, rows=None) -> dict:
     """Write a layer's new keys and values k, v [B, S, KV, hd] into the
     stacked cache at ``write_index`` (int8 with per-token scales where the
-    cache is quantized). Scope ``kv_write``; shared by every family whose
-    cache is ``init_kv_cache``'s."""
+    cache is quantized), at the cache's batch rows ``rows`` where k and v
+    are a piece of its rows. Scope ``kv_write``; shared by every family
+    whose cache is ``init_kv_cache``'s."""
     with jax.named_scope("kv_write"):
-        kt = k.transpose(0, 2, 1, 3)  # [B, KV, S, hd] — cache-native
-        vt = v.transpose(0, 2, 1, 3)
+        new = {"k": k.transpose(0, 2, 1, 3),  # [B, KV, S, hd] — cache-native
+               "v": v.transpose(0, 2, 1, 3)}
         if is_quantized_cache(cache):
-            k8, ks = _quantize_kv(kt)
-            v8, vs = _quantize_kv(vt)
-            return dict(
-                cache,
-                k=_cache_write(cache["k"], k8, layer_idx, write_index),
-                v=_cache_write(cache["v"], v8, layer_idx, write_index),
-                ks=_cache_write(cache["ks"], ks, layer_idx, write_index),
-                vs=_cache_write(cache["vs"], vs, layer_idx, write_index),
-            )
-        return dict(
-            cache,
-            k=_cache_write(cache["k"], kt, layer_idx, write_index),
-            v=_cache_write(cache["v"], vt, layer_idx, write_index),
-        )
+            new["k"], new["ks"] = _quantize_kv(new["k"])
+            new["v"], new["vs"] = _quantize_kv(new["v"])
+        return dict(cache, **{
+            name: _cache_write(cache[name], val, layer_idx, write_index, rows)
+            for name, val in new.items()})
 
 
 def _cache_attention(q, cache: dict, layer_idx, mask, q_per_kv: int,
-                     attention_fn=None, stacked_attention_fn=None):
+                     attention_fn=None, stacked_attention_fn=None,
+                     rows=None):
     """Attention of q [B, S, H, hd] over layer ``layer_idx`` of the stacked
     cache: ``stacked_attention_fn`` (the Pallas kernels, reading the cache
     in place), else ``attention_fn`` or the dense ``_attention`` over the
-    extracted layer under ``mask``. Scope ``attn``."""
+    extracted layer under ``mask`` (its batch rows ``rows`` where q is a
+    piece of the cache's rows). Scope ``attn``."""
     with jax.named_scope("attn"):
         if stacked_attention_fn is not None:
             # reads the stacked cache in place (Pallas kernels): no
             # per-layer extraction copy materializes
             return stacked_attention_fn(q, cache, layer_idx)
         k_cache, v_cache = dequantize_cache_layer(cache, layer_idx)
+        if rows is not None:
+            k_cache, v_cache = k_cache[rows], v_cache[rows]
         k_cache = k_cache.astype(q.dtype)
         v_cache = v_cache.astype(q.dtype)
         if attention_fn is None:
@@ -515,6 +522,7 @@ def _cache_attention(q, cache: dict, layer_idx, mask, q_per_kv: int,
 def _block(
     x, lp, layer_idx, rope, mask, is_global, cache, write_index,
     cfg: LlamaConfig, attention_fn=None, stacked_attention_fn=None,
+    cache_rows=None,
 ):
     """One decoder layer.
 
@@ -525,7 +533,8 @@ def _block(
     decode HBM traffic at weights+cache-read — emitting per-layer caches as
     scan outputs would re-materialize the whole ~GB cache every decode
     step. ``write_index`` may be a [B] vector (see _cache_write) for the
-    speculative verify step's per-row fills.
+    speculative verify step's per-row fills. ``cache_rows`` [B] names x's
+    rows of a cache that holds more of them (``forward``).
 
     The ``jax.named_scope`` names here and in ``forward`` (embed, qkv,
     kv_write, attn, attn_out, mlp, lm_head) are metadata of the compiled
@@ -572,9 +581,9 @@ def _block(
         q = _apply_rope(q, cos, sin)
         k = _apply_rope(k, cos, sin)
 
-    cache = _write_kv(cache, k, v, layer_idx, write_index)
+    cache = _write_kv(cache, k, v, layer_idx, write_index, cache_rows)
     attn = _cache_attention(q, cache, layer_idx, mask, cfg.q_per_kv,
-                            attention_fn, stacked_attention_fn)
+                            attention_fn, stacked_attention_fn, cache_rows)
     with jax.named_scope("attn_out"):
         attn_out = _proj("bshk,hkd->bsd", attn, lp["wo"], aq)
         if cfg.sandwich_norms:
@@ -611,6 +620,7 @@ def forward(
     last_only: bool = False,
     attention_fn=None,
     stacked_attention_fn=None,
+    cache_rows=None,
 ) -> tuple[jax.Array, dict]:
     """Run the decoder; returns (logits [B, S, vocab] f32, updated cache).
 
@@ -622,7 +632,14 @@ def forward(
     dense cache attention on the extracted (dequantized) layer cache;
     ``stacked_attention_fn(q, cache, layer_idx)`` overrides it with a
     consumer of the FULL stacked cache dict (the Pallas kernels) and takes
-    precedence."""
+    precedence.
+
+    ``cache_rows`` [B] int32: the tokens are a row piece of a batch whose
+    cache ``kv_cache`` is (the engine's prefill, ``Family.
+    prefill_piece_tokens``) and row b of them lives at the cache's batch row
+    ``cache_rows[b]`` — written and read there in place, the cache's other
+    rows untouched. ``mask`` and a ``stacked_attention_fn`` are the
+    piece's own."""
     with jax.named_scope("embed"):
         x = _embed_lookup(params["embed"], tokens, cfg.dtype)
         if cfg.embed_scale:
@@ -650,7 +667,7 @@ def forward(
         lp, li, is_global = xs
         h, cache = block(
             h, lp, li, rope, mask, is_global, cache, write_index, cfg,
-            attention_fn, stacked_attention_fn,
+            attention_fn, stacked_attention_fn, cache_rows,
         )
         return (h, cache), None
 
@@ -852,17 +869,20 @@ def _group_of(q, cache: dict) -> int:
 
 
 def _prefill_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
-                       layer_window, q_offset: int = 0):
+                       layer_window, q_offset: int = 0, cache_rows=None):
     """Flash/sharded-flash stacked-attention fn for a prefill-style forward
     whose queries start at cache slot ``q_offset`` (0 = whole prompt;
-    chunked prefill passes each chunk's start)."""
+    chunked prefill passes each chunk's start). ``pad_lens`` and
+    ``cache_rows`` are those of a row piece where the forward runs one
+    (``forward``)."""
     if mesh is not None:
         from ..ops.sharded import sharded_flash_prefill
 
         def stacked_fn(q, cache, layer_idx):
             return sharded_flash_prefill(
                 mesh, q, cache, layer_idx, pad_lens, _group_of(q, cache),
-                layer_window(layer_idx), q_offset, interpret=interpret,
+                layer_window(layer_idx), q_offset, cache_rows,
+                interpret=interpret,
             )
     else:
         from ..ops.flash_attention import flash_prefill_attention
@@ -870,7 +890,8 @@ def _prefill_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,
         def stacked_fn(q, cache, layer_idx):
             return flash_prefill_attention(
                 q, cache, layer_idx, pad_lens, _group_of(q, cache),
-                layer_window(layer_idx), q_offset, interpret=interpret,
+                layer_window(layer_idx), q_offset, cache_rows,
+                interpret=interpret,
             )
 
     return stacked_fn
@@ -926,6 +947,9 @@ def _family():
         prefill_attention=_prefill_attention,
         decode_attention=_decode_attention, counts_prefill_blocks=True,
         layer_windows=_layer_windows,
+        # a row of one 2,048-token chunk: 4,096 operations a weight byte
+        # (W8A8), far over the v5e's ~480; measured in PERF.md, PR 48
+        prefill_piece_tokens=2048,
     )
 
 

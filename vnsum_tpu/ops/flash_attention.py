@@ -384,6 +384,14 @@ _LOOPED_BLOCK = 1024
 _VMEM_MOST = 64 * 1024 * 1024
 
 
+def _kernel_of_rows(lidx_ref, pad_ref, win_ref, off_ref, rows_ref, *refs,
+                    **geometry):
+    """``_kernel`` under a fifth prefetched vector (``cache_rows``), which
+    only the index_map reads."""
+    del rows_ref
+    _kernel(lidx_ref, pad_ref, win_ref, off_ref, *refs, **geometry)
+
+
 def _heads_per_step(G: int) -> int:
     return G if G <= _UNROLLED_GROUP else _HEADS_LOOPED
 
@@ -477,6 +485,7 @@ def flash_prefill_attention(
     q_per_kv: int,
     window: jax.Array | None = None,  # scalar int32; 0/None = global
     q_offset: jax.Array | None = None,  # scalar int32; cache slot of query 0
+    cache_rows: jax.Array | None = None,  # [B] int32; q's rows of the cache
     *,
     block_q: int | None = None,
     block_k: int | None = None,
@@ -491,6 +500,10 @@ def flash_prefill_attention(
     [q_offset, q_offset + S) — chunk c of a CHUNKED prefill (the engine's
     prefill_chunk_tokens path, which halves/quarters prefill transients so
     bigger decode batches fit); 0/None is the classic whole-prompt prefill.
+    ``cache_rows`` names, for each of q's B rows, its batch row of a cache
+    that holds more rows than q (a row piece of the engine's prefill): the
+    index_map reads that row in place, and no slice of the cache is made;
+    None is row b for row b.
 
     K/V blocks a query block has nothing to see in — strictly above the
     causal diagonal, wholly below the window floor, wholly under the row's
@@ -533,16 +546,22 @@ def flash_prefill_attention(
         # lo > j_hi and parks every step on j_hi (always in range)
         return jnp.minimum(jnp.maximum(j, lo), j_hi)
 
-    def kv_index(b, kv, i, j, lidx, pad, win, off):
-        return (lidx[0], b, kv, visible_j(b, i, j, pad, win, off), 0)
+    # a fifth prefetched vector, where the cache's rows are named: it
+    # steers the index_map alone, and the kernel's body never sees it
+    prefetch = 4 if cache_rows is None else 5
 
-    def scale_index(b, kv, i, j, lidx, pad, win, off):
-        return (lidx[0], b, 0, visible_j(b, i, j, pad, win, off))
+    def kv_index(b, kv, i, j, lidx, pad, win, off, *rows):
+        row = rows[0][b] if rows else b
+        return (lidx[0], row, kv, visible_j(b, i, j, pad, win, off), 0)
+
+    def scale_index(b, kv, i, j, lidx, pad, win, off, *rows):
+        row = rows[0][b] if rows else b
+        return (lidx[0], row, 0, visible_j(b, i, j, pad, win, off))
 
     in_specs = [
         pl.BlockSpec(
             (1, 1, G, bq, hd),
-            lambda b, kv, i, j, lidx, pad, win, off: (b, kv, 0, i, 0),
+            lambda b, kv, i, j, *prefetched: (b, kv, 0, i, 0),
         ),
         pl.BlockSpec((1, 1, 1, bk, hd), kv_index),
         pl.BlockSpec((1, 1, 1, bk, hd), kv_index),
@@ -557,19 +576,20 @@ def flash_prefill_attention(
 
     grid = (B, KV, pl.cdiv(S, bq), pl.cdiv(C, bk))
     kernel = functools.partial(
-        _kernel, block_q=bq, block_k=bk, seq_len=S, cache_len=C,
+        _kernel if cache_rows is None else _kernel_of_rows,
+        block_q=bq, block_k=bk, seq_len=S, cache_len=C,
         scale=1.0 / (hd ** 0.5), quantized=quantized, q_per_kv=G,
         heads_per_step=_heads_per_step(G), heads_ahead=_heads_ahead(G),
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=prefetch,
             grid=grid,
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
                 (1, 1, G, bq, hd),
-                lambda b, kv, i, j, lidx, pad, win, off: (b, kv, 0, i, 0),
+                lambda b, kv, i, j, *prefetched: (b, kv, 0, i, 0),
             ),
             scratch_shapes=[
                 pltpu.VMEM((G * bq, hd), jnp.float32),
@@ -592,6 +612,7 @@ def flash_prefill_attention(
         pad_lens.astype(jnp.int32),
         jnp.asarray(0 if window is None else window, jnp.int32).reshape(1),
         jnp.asarray(0 if q_offset is None else q_offset, jnp.int32).reshape(1),
+        *(() if cache_rows is None else (cache_rows.astype(jnp.int32),)),
         *operands,
     )
     return out.reshape(B, H, S, hd).transpose(0, 2, 1, 3)

@@ -39,26 +39,32 @@ def sharded_flash_prefill(
     q_per_kv: int,
     window=None,
     q_offset=None,
+    cache_rows=None,
     *,
     interpret: bool = False,
 ):
     """flash_prefill_attention with q/cache sharded over (data, model).
     ``window`` and ``q_offset`` are replicated scalars (0/None = global
-    layer / whole-prompt prefill)."""
+    layer / whole-prompt prefill). ``cache_rows`` (q a row piece of the
+    cache's batch) is a replicated vector: the engine makes pieces only
+    where the `data` axis holds the whole batch."""
     import jax.numpy as jnp
 
+    # the piece's rows ride last, where there are any
+    rows = () if cache_rows is None else (cache_rows,)
     fn = shard_map(
-        lambda qs, cs, li, pads, win, off: flash_prefill_attention(
-            qs, cs, li, pads, q_per_kv, win, off, interpret=interpret
+        lambda qs, cs, li, pads, win, off, *rows: flash_prefill_attention(
+            qs, cs, li, pads, q_per_kv, win, off, *rows, interpret=interpret
         ),
         mesh=mesh,
-        in_specs=(_Q_SPEC, _cache_specs(cache), P(), P(AXES.data), P(), P()),
+        in_specs=(_Q_SPEC, _cache_specs(cache), P(), P(AXES.data), P(), P())
+        + (P(),) * len(rows),
         out_specs=_Q_SPEC,
         check_vma=False,
     )
     win = jnp.asarray(0 if window is None else window, jnp.int32)
     off = jnp.asarray(0 if q_offset is None else q_offset, jnp.int32)
-    return fn(q, cache, layer_idx, pad_lens, win, off)
+    return fn(q, cache, layer_idx, pad_lens, win, off, *rows)
 
 
 def sharded_flash_decode(
