@@ -35,6 +35,16 @@ llama program with the piece set to its 128-token chunk (`_PIECE_TOKENS`):
 the loop over row pieces, the fifth prefetched vector of the prefill
 kernel (`cache_rows`) and the per-row cache writes are in it.
 
+**PR 49 moved eight of the twelve on purpose**: the two GQA decode kernels
+(`ops/decode_attention.py`) are one kernel body whose K/V block holds ONE
+row and whose grid walks each row from its own pad to its own fill, and that
+body is in the decode loop of every GQA family's one-shot program (`llama`,
+`llama-qk-norm`, `smallthinker`, `laguna`, `granite-h`, `nemotron-h`,
+`llama-row-pieces`) and in the slot segment (`slot_seg-4`). What it did NOT
+move: `deepseek-v2`, whose decode kernel is `mla_decode_attention`, and the
+three programs that decode nothing (`slot_prefill-1`, `slot_prefill-2`,
+`adopt-2`).
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
@@ -60,14 +70,14 @@ from vnsum_tpu.models.deepseek import tiny_deepseek
 # family -> (config, config keywords, sha256 of str(jaxpr)[:16]); a config is
 # a registry name, or the function itself where the family has none
 _PINNED = {
-    "llama": ("tiny", {}, "b53beb5e9cb8a35d"),
-    "llama-qk-norm": ("tiny", {"qk_norm": True}, "89aa4218a7d72f06"),
-    "smallthinker": ("tiny-smallthinker", {}, "b4b5483b7a77bcac"),
-    "laguna": ("tiny-laguna", {}, "e7b30d5c4179f47b"),
-    "granite-h": ("tiny-granite-h", {}, "ae687d878a98f9b9"),
-    "nemotron-h": ("tiny-nemotron-h", {}, "a4aa98b6e08cc54b"),
+    "llama": ("tiny", {}, "4aa1f80001ed0fb9"),
+    "llama-qk-norm": ("tiny", {"qk_norm": True}, "2d05f67423708be5"),
+    "smallthinker": ("tiny-smallthinker", {}, "a5b335b7034bb4ed"),
+    "laguna": ("tiny-laguna", {}, "c8b4edca989eecec"),
+    "granite-h": ("tiny-granite-h", {}, "3f9fcb64cac59b4a"),
+    "nemotron-h": ("tiny-nemotron-h", {}, "c14ede11a5e68ce7"),
     "deepseek-v2": (tiny_deepseek, {}, "90bc1e80a899c229"),
-    "llama-row-pieces": ("tiny", {}, "fbfed62a99d09da7"),
+    "llama-row-pieces": ("tiny", {}, "87c61288db10c21a"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)
@@ -79,7 +89,7 @@ _PIECE_TOKENS = {"llama-row-pieces": 128}
 _SLOT_PINNED = {
     "slot_prefill-1": "2d5a63d1a6dc8b63",
     "slot_prefill-2": "aab563206132a4ee",
-    "slot_seg-4": "43ed76b480b213c3",
+    "slot_seg-4": "1f818e78f7afc732",
     "adopt-2": "335d7da10ac8614a",
 }
 _SLOTS = 4

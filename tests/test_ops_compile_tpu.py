@@ -295,6 +295,22 @@ def test_gqa_decode_kernel_compiles_at_lagunas_two_groups(one_chip, G):
     assert "tpu_custom_call" in c.as_text()
 
 
+def test_verify_kernel_compiles_at_the_served_segments_shape(one_chip):
+    """A slot segment's step: four slots of 8,320, one query a row at its
+    own fill, 32/8 heads over the int8 cache of 36 layers — one row a block
+    of 512 slots (`decode_block_k`), from the row's pad to its fill."""
+    from vnsum_tpu.ops import decode_attention
+
+    assert decode_attention.decode_block_k(8, 128, 1, 8320) == 512
+    c = _compiled(
+        lambda q, cache, pads, fills: (
+            decode_attention.flash_spec_verify_attention(
+                q, cache, 35, pads, fills, 4)),
+        one_chip, ((4, 1, 32, 128), BF16), _int8_cache(36, 4, 8, 8320, 128),
+        ((4,), I32), ((4,), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
 def test_gqa_decode_kernel_compiles_at_g7_under_a_window(one_chip):
     """7 query rows a KV head — the first group size here that is no
     divisor of the 8-sublane tile — over the int8 cache, 24 rows."""

@@ -172,7 +172,8 @@ def test_int8_cache_quantization_roundtrip_accuracy():
 
 def test_supports_decode():
     assert supports_decode(1152, 128)
-    assert not supports_decode(1152, 64)  # head_dim not a lane multiple
+    assert supports_decode(1152, 64)      # half a lane tile (Granite-4.0-H)
+    assert not supports_decode(1152, 96)  # neither whole lane tiles nor half
     assert supports_decode(1151, 128)     # any C via ceil-div grid
 
 
@@ -454,3 +455,178 @@ def test_partial_tail_block_cannot_poison_the_output(quantized):
         _attention(q, kd, vd, prefill_attention_mask(pad, C, C), G),
         rows=slice(3, None),  # pad rows are garbage on both paths
     )
+
+
+# -- one row a block, from the row's first real slot to its fill (PR 49) -----
+
+# the served mix's first four prompts in 8,192-slot rows, a sixteenth the size
+_SERVED_PADS = [(8192 - n) // 16 for n in (6000, 7260, 2000, 540)]
+
+# name: rows' pads, rows' fills, window, KV heads, query heads a KV head,
+# head dim, int8 cache, queries a row; blocks of 32 slots in a cache of 528
+_ROW_CASES = {
+    "all_live": ([0, 0, 0, 0], [520] * 4, 0, 2, 2, 128, False, 1),
+    "served_mix": (_SERVED_PADS, [520] * 4, 0, 2, 2, 128, True, 1),
+    # 40 = block 1's slot 8; 95 = block 2's last slot; 96 = block 3's first
+    "pad_ends_inside_a_block": ([40, 95, 96, 0], [300] * 4, 0, 2, 2, 128,
+                                False, 1),
+    "all_pad_but_the_last_slot": ([300, 0, 511, 0], [300, 300, 511, 511], 0,
+                                  2, 2, 128, True, 1),
+    # window floor 300 - 100 + 1 = 201: pads 150 and 180 (below it), 201,
+    # 230 and 290 (above it)
+    "pads_under_a_window": ([150, 230, 201, 290], [300] * 4, 100, 2, 2, 128,
+                            False, 1),
+    "pads_under_a_window_int8": ([180, 230, 0, 290], [300] * 4, 100, 4, 7,
+                                 128, True, 1),
+    "ragged_fills": ([40, 0, 250, 96], [300, 95, 260, 511], 0, 2, 2, 128,
+                     False, 3),
+    "ragged_fills_window_int8": ([40, 0, 250, 96], [300, 95, 260, 511], 64,
+                                 2, 2, 128, True, 3),
+    "hd64_int8": ([0, 130, 40, 299], [300] * 4, 0, 2, 4, 64, True, 1),
+    "hd64_bf16": ([0, 130, 40, 299], [300] * 4, 0, 2, 4, 64, False, 2),
+}
+
+
+def _row_case(name, dtype=jnp.float32):
+    from vnsum_tpu.models.llama import dequantize_cache_layer
+
+    pads, fills, win, KV, G, hd, int8, Sq = _ROW_CASES[name]
+    B, C, L = len(pads), 528, 2
+    q, cache = make_verify_case(L, B, KV, C, Sq, KV * G, hd, seed=len(name))
+    cache = {k: v.astype(dtype) for k, v in cache.items()}
+    if int8:
+        cache = quantize_case(cache)
+    kd, vd = dequantize_cache_layer(cache, 1)
+    return (q, cache, kd.astype(jnp.float32), vd.astype(jnp.float32),
+            jnp.asarray(pads, jnp.int32), jnp.asarray(fills, jnp.int32),
+            win, G, Sq, C)
+
+
+def _window_mask(mask, limits, win, C):
+    if not win:
+        return mask
+    return mask & (jnp.arange(C)[None, None, :] > limits[:, :, None] - win)
+
+
+@pytest.mark.parametrize("name", sorted(_ROW_CASES))
+def test_verify_kernel_reads_each_row_from_its_pad_to_its_fill(name):
+    """``flash_spec_verify_attention`` (a slot segment's step where a row
+    holds one query) against the dense reference, with the cache poisoned
+    under every row's pad and past its last query: a block the row's bounds
+    left out, or a slot its mask let through, shows."""
+    from vnsum_tpu.models.llama import verify_attention_mask
+    from vnsum_tpu.ops.decode_attention import flash_spec_verify_attention
+
+    q, cache, kd, vd, pads, fills, win, G, Sq, C = _row_case(name)
+    limits = fills[:, None] + jnp.arange(Sq)[None, :]
+    mask = _window_mask(verify_attention_mask(pads, fills, Sq, C), limits,
+                        win, C)
+    dense = _attention(q, kd, vd, mask, G)
+    slot = jnp.arange(C)[None, None, :, None]
+    dead = ((slot < pads[:, None, None, None])
+            | (slot > limits[:, -1][:, None, None, None]))
+    dirty = dict(cache)
+    for leaf, poison in (("k", 100), ("v", 100)):
+        x = cache[leaf][1]
+        dirty[leaf] = cache[leaf].at[1].set(
+            jnp.where(dead, jnp.asarray(poison, x.dtype), x))
+    kernel = flash_spec_verify_attention(
+        q, dirty, 1, pads, fills, G, jnp.int32(win), block_k=32,
+        interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(dense), np.asarray(kernel), rtol=2e-5, atol=2e-5
+    )
+
+
+@pytest.mark.parametrize("partials", [False, True])
+@pytest.mark.parametrize(
+    "name", [n for n, c in sorted(_ROW_CASES.items())
+             if len(set(c[1])) == 1 and c[7] == 1])
+def test_decode_kernel_reads_each_row_from_its_pad_to_the_fill(name, partials):
+    """``flash_decode_attention`` on the same rows (one fill for all, one
+    query a row), normalized and as the partial sums
+    ``backend/long_context.py`` merges."""
+    q, cache, kd, vd, pads, fills, win, G, _, C = _row_case(name)
+    fill = int(fills[0])
+    mask = _window_mask(decode_attention_mask(pads, fill, C),
+                        jnp.full((len(pads), 1), fill), win, C)
+    dense = _attention(q, kd, vd, mask, G)
+    out = flash_decode_attention(
+        q, cache, 1, pads, fill, G, jnp.int32(win), block_k=32,
+        interpret=True, return_partials=partials,
+    )
+    if partials:
+        o, m, l = out
+        assert np.asarray(l).min() > 0.0
+        out = (o / l[..., None])[:, None]
+    np.testing.assert_allclose(
+        np.asarray(dense), np.asarray(out), rtol=2e-5, atol=2e-5
+    )
+
+
+def test_bf16_cache_at_hd64_matches_dense():
+    """A bfloat16 cache of 64-wide heads, two queries a row."""
+    from vnsum_tpu.models.llama import verify_attention_mask
+    from vnsum_tpu.ops.decode_attention import flash_spec_verify_attention
+
+    q, cache, kd, vd, pads, fills, win, G, Sq, C = _row_case(
+        "hd64_bf16", jnp.bfloat16)
+    assert cache["k"].dtype == jnp.bfloat16
+    dense = _attention(q, kd, vd, verify_attention_mask(pads, fills, Sq, C), G)
+    kernel = flash_spec_verify_attention(
+        q, cache, 1, pads, fills, G, block_k=32, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(dense), np.asarray(kernel), rtol=2e-5, atol=2e-5
+    )
+
+
+def test_a_rows_blocks_are_those_between_its_pad_and_its_fill():
+    """``_row_blocks``, the one rule the index maps and the kernel's guard
+    share: by hand on blocks of 32."""
+    from vnsum_tpu.ops.decode_attention import _row_blocks
+
+    def blocks(pad, fill, n_q=1, win=0):
+        lo, hi = _row_blocks(jnp.int32(pad), jnp.int32(fill), n_q,
+                             jnp.int32(win), 32, 528)
+        return int(lo), int(hi)
+
+    assert blocks(0, 520) == (0, 16)
+    assert blocks(40, 300) == (1, 9)        # the pad ends inside block 1
+    assert blocks(95, 300) == (2, 9)        # its last slot is block 2's last
+    assert blocks(96, 300) == (3, 9)
+    assert blocks(511, 511) == (15, 15)     # all pad but the last slot
+    assert blocks(528, 527) == (16, 16)     # all pad: the last block, masked
+    assert blocks(512, 300) == (16, 9)      # all pad below the fill: nothing
+    assert blocks(150, 300, win=100) == (6, 9)   # floor 201 above the pad
+    assert blocks(230, 300, win=100) == (7, 9)   # pad above the floor
+    assert blocks(0, 300, n_q=3) == (0, 9)
+    assert blocks(0, 318, n_q=3) == (0, 10)      # the last query's slot 320
+    assert blocks(0, 527, n_q=3) == (0, 16)      # never past the cache's end
+
+
+@pytest.mark.parametrize("cell,KV,hd,bk,kib", [
+    ("qwen3 offline and served, laguna", 8, 128, 512, 512),
+    ("phi-4", 10, 128, 512, 640),
+    ("smallthinker", 4, 128, 1024, 512),
+    # 64-wide heads fill half of each lane tile: 256 KiB of the cache are
+    # 512 KiB as VMEM holds them (the sweep: 512 slots read 0.6873 / 0.6503
+    # ms a call all-live / with the cell's pads, 1,024 read 0.6987 / 0.6647)
+    ("granite-4.0-h", 8, 64, 512, 512),
+    # 2 KV heads: 2,048 slots make the 512 KiB (it was 128 KiB at 4 rows)
+    ("nemotron-h", 2, 128, 2048, 512),
+])
+def test_a_one_row_blocks_keys_hold_half_a_mebibyte_to_one(cell, KV, hd, bk,
+                                                           kib):
+    """The block rule at the seven cells' shapes (int8 caches of 8,448 and
+    8,320 slots): a block's keys, as VMEM tiles them, lie in 512 KiB-1 MiB."""
+    from vnsum_tpu.ops.decode_attention import decode_block_k
+
+    for C in (8448, 8320):
+        assert decode_block_k(KV, hd, 1, C) == bk
+    assert KV * bk * max(hd, 128) == kib * 1024
+    assert 512 <= kib <= 1024
+    # a bfloat16 cache holds the same bytes in half the slots; a short cache
+    # is one block
+    assert decode_block_k(KV, hd, 2, 8448) * 2 == bk
+    assert decode_block_k(KV, hd, 1, 200) == 200

@@ -145,6 +145,16 @@ class EngineStats:
     # names no piece), counted from the pads
     prefill_row_chunks_total: int = 0
     prefill_row_chunks_dead: int = 0
+    # key blocks between slot 0 and the fill that the decode steps of the
+    # one-shot dispatches and the slot segments walked — a row of the batch
+    # on a layer that attends globally in one step, by the GQA decode
+    # kernels' own block (``ops.decode_attention.decode_block_k``) — and
+    # those of them the kernels neither copied nor computed because they lie
+    # wholly under the row's left pad (``TpuBackend._count_decode_kv_blocks``;
+    # 0 and 0 for a family whose decode kernel is another), counted from the
+    # pads
+    decode_kv_blocks_total: int = 0
+    decode_kv_blocks_skipped: int = 0
     # which attention each built program got, keyed "program[B=..,S=..]" →
     # {"prefill"|"decode": "kernel"|"dense"}: a dense fallback (unaligned
     # head dim, the slot/verify kernel under a mesh) is visible here and in
@@ -970,6 +980,41 @@ class TpuBackend:
         self.stats.prefill_row_chunks_total += total
         self.stats.prefill_row_chunks_dead += dead
         return dead, total
+
+    def _count_decode_kv_blocks(self, pad_lens, fills, S: int,
+                                C: int) -> tuple[int, int]:
+        """Add the key blocks some decode steps walked to
+        ``stats.decode_kv_blocks_total`` and those the decode kernels left
+        out for holding nothing but a row's left pad to
+        ``stats.decode_kv_blocks_skipped``. ``fills`` [steps, rows] — or
+        [steps, 1] where the rows share it — is the cache slot of a row's
+        query at each step, after a prompt bucket of ``S`` in a cache of
+        ``C`` slots. Window layers are not counted: they start at the window's
+        floor whatever the pad. Pure host arithmetic on the pads and the
+        block the kernels choose from the cache's shape; returns this call's
+        (skipped, total) for its span and its log line — 0 and 0 where the
+        decode steps ran another kernel than the GQA ones, or none."""
+        if not (self.family.counts_prefill_blocks
+                and self._decode_settings(S, C)[1]):
+            return 0, 0
+        from ..ops.decode_attention import decode_block_k
+
+        cfg = self.cfg
+        windows = (self.family.layer_windows(cfg)
+                   or (0,) * self.family.attention_layers(cfg))
+        bk = decode_block_k(
+            cfg.n_kv_heads, cfg.head_dim,
+            1 if self.quantize_kv else jnp.dtype(cfg.dtype).itemsize, C)
+        # a row that spent its budget sits one past the cache's last slot
+        under_pad, walked = np.broadcast_arrays(
+            np.asarray(pad_lens, np.int64) // bk,
+            np.minimum(np.asarray(fills, np.int64), C - 1) // bk + 1)
+        layers = sum(1 for w in windows if not w)
+        total = int(walked.sum()) * layers
+        skipped = int(np.minimum(under_pad, walked).sum()) * layers
+        self.stats.decode_kv_blocks_total += total
+        self.stats.decode_kv_blocks_skipped += skipped
+        return skipped, total
 
     def _prefill_spans(self, S: int, start: int = 0) -> list[tuple[int, int]]:
         """Query spans [lo, hi) one prefill forward over cache slots
@@ -1972,14 +2017,25 @@ class TpuBackend:
                             # lint-allow[host-sync-in-hot-path]: one-shot result fetch bounds the dispatch and feeds detok
                             out = jax.device_get(out_dev)
                         with host_span("engine", "count", sink):
-                            dead, pieces = self._count_row_chunks(
-                                pad_lens, S, K)
-                            disp.note(dead_row_chunks=dead)
-                            grid = f", dead_row_chunks {dead}/{pieces}"
+                            counted = None
                             if self.family.counters is not None:
                                 # a family that counts returns its counters
                                 # with the tokens: one fetch brought both
                                 out, counted = out
+                            dead, pieces = self._count_row_chunks(
+                                pad_lens, S, K)
+                            # the decode loop ran a step for every token of
+                            # the longest row, step t at slot S + t
+                            steps = int((out != self.tok.pad_id).any(0).sum())
+                            skipped, blocks = self._count_decode_kv_blocks(
+                                pad_lens, S + np.arange(steps)[:, None], S,
+                                S + max_new)
+                            disp.note(dead_row_chunks=dead,
+                                      skipped_kv_blocks=skipped,
+                                      kv_blocks=blocks)
+                            grid = (f", dead_row_chunks {dead}/{pieces}"
+                                    f", skipped_kv_blocks {skipped}/{blocks}")
+                            if counted is not None:
                                 grid += self._add_expert_counts(counted)
                             grid += self._count_prefill_blocks(
                                 pad_lens, S, S + max_new, K)

@@ -157,6 +157,10 @@ class TpuSlotLoop:
         self._uids: list[int] = [0] * B
         self._admissions: dict[int, SlotAdmission] = {}
         self._t_host = np.zeros((B,), np.int64)
+        # each slot's left pad as the device holds it, for the count of the
+        # key blocks a segment's steps skip; the loop's own (skipped, total)
+        self._pads_host = np.full((B,), S, np.int64)
+        self._kv_blocks = [0, 0]
         self._uid_next = 0
         self.segments = 0           # decode segments dispatched
         self.refills = 0
@@ -320,6 +324,8 @@ class TpuSlotLoop:
                         self._out, self._pads, join_cache, first, done0,
                         jnp.asarray(pad_lens), slot_idx,
                     )
+                    self._t_host[slot_idx] = 0
+                    self._pads_host[slot_idx] = pad_lens
         finally:
             if matches is not None:
                 for m in matches.values():
@@ -440,10 +446,19 @@ class TpuSlotLoop:
                 int(t_h[s]) - int(self._t_host[s])
                 for s, k in enumerate(self._keys) if k is not None
             )
+            if b.mesh is None:  # under a mesh the segment's attention is dense
+                # the loop ran a step for every token of the row that went
+                # furthest; a row that ended stays at its last slot
+                steps = int((t_h - self._t_host).max())
+                skipped, blocks = b._count_decode_kv_blocks(
+                    self._pads_host, self.S + np.minimum(
+                        self._t_host + np.arange(steps)[:, None], t_h),
+                    self.S, self.S + self.max_new)
+                seg.note(skipped_kv_blocks=skipped, kv_blocks=blocks)
+                self._kv_blocks[0] += skipped
+                self._kv_blocks[1] += blocks
         res.seconds = seg.dur
-        for s, k in enumerate(self._keys):
-            if k is not None:
-                self._t_host[s] = int(t_h[s])
+        self._t_host[:] = t_h
         self._out_snap = out_h
         with host_span("slot", "harvest", sink, rows=len(finished)):
             for s in finished:
@@ -548,6 +563,11 @@ class TpuSlotLoop:
         return [k for k in self._keys if k is not None]
 
     def close(self) -> None:
+        if not self._closed:
+            logger.info(
+                "slot loop closed after %d segments and %d joined rows: "
+                "skipped_kv_blocks %d/%d", self.segments, self.refills,
+                *self._kv_blocks)
         self._closed = True
         # drop the device state promptly — the resident cache is the big
         # HBM tenant, and a replacement loop allocates its own

@@ -18,20 +18,52 @@ no conversion.
 
 Block geometry matters more than anything here: a first cut that gridded
 over (B, KV, C/BK) issued tens-of-KB DMAs and ran 3x SLOWER than the XLA
-path (92 ms/step) because the pipeline never got deep enough. This version
-grids over (B/BB, ceil(C/BK)) with each block carrying all KV heads and BB
-batch rows (~MB-scale DMAs); the BB x KV attention groups are computed as an
-unrolled loop of small MXU dots against VMEM-resident tiles.
+path (92 ms/step) because the pipeline never got deep enough. Until PR 49 a
+block carried all KV heads of ``bb`` batch rows over 128 slots (~MB-scale
+DMAs), which left every row read from slot 0: a row's left pad was copied,
+upcast, multiplied and masked away. Now a block carries all KV heads of ONE
+row over ``bk`` slots, ``bk`` chosen from the shapes so that the block's
+keys stay ~512 KiB as VMEM tiles them (``decode_block_k``: 512 slots at
+KV=8 x hd=128 and at Phi-4's KV=10, 1,024 at SmallThinker's KV=4, 512 at
+Granite-4.0-H's 64-wide heads, which fill half of each lane tile, 2,048 at
+Nemotron-H's KV=2; half the slots for a bfloat16 cache), and each row
+walks its own blocks: grid step j of row b is block ``first_b + j``, from
+the block of the row's first real slot (under a sliding window, of its
+window's floor) to the block of its fill, and the grid takes as many steps
+a row as the row with most blocks needs (``_row_blocks``, computed once a
+call and prefetched: deriving the two bounds in each of the four index
+maps cost the all-live Qwen3 step 3%). A row with fewer blocks stays on
+its last one — Pallas skips the DMA when consecutive grid steps address
+the same block — and ``pl.when`` skips the compute, so a step at fill=600
+in a C=1152 cache reads only ~half the cache, a row that is three quarters
+pad a quarter of its slots, and a window layer's call is a few steps a row
+however long the cache. The KV attention groups are one batched MXU dot
+against VMEM-resident tiles.
 
-Blocks past the current fill position are elided by clamping the index_map
-(Pallas skips the DMA when consecutive grid steps address the same block)
-and `pl.when` skips their compute, so a step at fill=600 in a C=1152 cache
-reads only ~half the cache. The key block (``block_k``, 128) at the widest
-group a cell runs — G=16 on 2 KV heads, 12 rows at fill 8,320 of an int8
-cache, one call timed from the host, PR 47: 128 / 256 / 512 / 1024 slots
-0.220 / 0.200 / 0.203 / 0.205 ms, of which ~0.2 ms is the call itself — sets
-nothing a host's clock can tell apart, and stays; the cell's traced run
-gives the kernel's own seconds (``nemotron_decode_attention_roofline``).
+The sweep that chose it (PR 49, TPU v5e, ``scripts/profile_decode_blocks.py``:
+ms a call inside a jitted loop of 72 calls, int8 cache at fill 8,320;
+all-live rows / the cell's pads — the served mix's four rows, an offline
+group's four tails of 20-75% pad):
+
+  shape (rows x KV x G, hd)        bb x 128 (PR 48)    one row x bk (rule)
+  Qwen3 offline  8 x 8 x 4         0.2047 / 0.2054     0.2066 / 0.1684  (512)
+  Qwen3 served   4 x 8 x 4, Sq=1   0.1162 / 0.1147     0.1107 / 0.0694  (512)
+  Phi-4         12 x 10 x 4        0.4027 / 0.4017     0.3633 / 0.3180  (512)
+  SmallThinker  24 x 4 x 7         0.3412 / 0.3433     0.3199 / 0.3024  (1,024)
+   ... window 4,096                0.1972 / 0.1968     0.1812 / 0.1790
+  Laguna full   12 x 8 x 6         0.3427 / 0.3442     0.3050 / 0.2671  (512)
+   ... sliding, G=9, window 512    0.0785 / 0.0771     0.0480 / 0.0465
+  Granite-H     24 x 8 x 4, hd 64  0.6844 / 0.6867     0.6873 / 0.6503  (512)
+  Nemotron-H    12 x 2 x 16        0.1663 / 0.1679     0.0986 / 0.0922  (2,048)
+
+Off the rule: 256 slots at KV=8 read 0.286 (all-live Qwen3), 1,024 read
+0.2103 and 2,048 0.2194; SmallThinker at 512 0.3804; Granite at 1,024
+0.6987 / 0.6647; Nemotron-H at 512 / 1,024 0.159 / 0.111. Whole-grid
+residency of the queries and outputs (no block a row) read the same as a
+block a row; three buffers a block are refused by this Mosaic. The one
+shape that did not gain is the one whose old block was already 1 MiB of
+keys (8 rows x 8 KV heads): +0.9% all-live, inside the sweep's own repeat
+(0.2025-0.2054 over four readings of the old kernel).
 
 int8 KV caches (models.llama.init_kv_cache(quantized=True)) stream half the
 bytes again: the kernel loads int8 K/V blocks plus per-(token, head) f32
@@ -59,6 +91,11 @@ from .flash_attention import (
 )
 
 
+# key bytes of a K/V block in the cache's own type (module docstring: the
+# sweep that chose it)
+_BLOCK_KEY_BYTES = 512 * 1024
+
+
 def _zero_past_cache(vb, k_start, cache_len: int):
     """Zero the value rows of a K/V block that lie past the end of the cache.
 
@@ -72,47 +109,74 @@ def _zero_past_cache(vb, k_start, cache_len: int):
     return jnp.where(v_slot < cache_len, vb, 0.0)
 
 
+def decode_block_k(n_kv: int, head_dim: int, itemsize: int,
+                   cache_len: int) -> int:
+    """Key slots of a K/V block, which holds ONE row's KV heads: the fewest
+    whole lane tiles whose keys ``[KV, bk, hd]`` hold ``_BLOCK_KEY_BYTES``
+    as VMEM tiles them (a narrow head padded to whole lanes), no more than
+    leave half of ``VMEM_LIMIT_BYTES`` free beside what a grid step keeps
+    of them — K and V, two buffers each, and their float32 upcasts — and
+    never more than the cache. Shapes alone decide (module docstring)."""
+    tiled = n_kv * -(-head_dim // _LANES) * _LANES   # a slot's key elements
+    most = VMEM_LIMIT_BYTES // 2 // (tiled * (4 * itemsize + 8))
+    slots = min(-(-_BLOCK_KEY_BYTES // (tiled * itemsize)), most)
+    return min(max(-(-slots // _LANES) * _LANES, _LANES), cache_len)
+
+
+def _row_blocks(pad, fill, n_q: int, win, block_k: int, cache_len: int):
+    """(first, last) key block each row reads: from the block of its first
+    real slot — under a window the block of its first query's window floor,
+    where that lies higher — to the block of its last query's slot, or the
+    cache's last. Grid step j of a row is block first + j, clamped at last
+    (the index maps): the blocks under the row's pad, below its window and
+    past its fill are never copied, and a row past its last block stays on
+    it (consecutive grid steps that address the resident block copy
+    nothing) while the kernel skips the compute. A row that is all pad has
+    first > last: nothing is computed, its sums stay empty."""
+    floor = jnp.where(win > 0, jnp.maximum(fill - win + 1, 0), 0)
+    last = jnp.minimum(fill + n_q - 1, cache_len - 1) // block_k
+    return jnp.maximum(pad, floor) // block_k, last
+
+
 def _kernel(
-    lidx_ref,  # [1] int32 (SMEM) — layer to read
-    fill_ref,  # [1] int32 (SMEM) — last valid cache slot (inclusive)
-    win_ref,   # [1] int32 (SMEM) — sliding window; 0 = global
+    lidx_ref,   # [1] int32 (SMEM) — layer to read
+    first_ref,  # [B] int32 (SMEM) — each row's first key block
+    last_ref,   # [B] int32 (SMEM) — and its last (_row_blocks)
+    fills_ref,  # [B] int32 (SMEM) — each row's cache slot of query 0
+    win_ref,    # [1] int32 (SMEM) — sliding window; 0 = global
+    pads_ref,   # [B] int32 (SMEM) — each row's left pad
     *refs,
-    block_b: int,
     block_k: int,
-    n_kv: int,
     cache_len: int,
     scale: float,
     quantized: bool,
-    return_partials: bool = False,
+    per_query: bool,
+    return_partials: bool,
 ):
-    if return_partials:
-        # outputs are the UNNORMALIZED online-softmax state (acc, m, l) —
-        # the shard-local form the long-context path LSE-merges across the
-        # seq axis (backend.long_context make_long_decode_attention)
-        if quantized:
-            (q_ref, pads_ref, k_ref, v_ref, ks_ref, vs_ref,
-             o_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref) = refs
-        else:
-            (q_ref, pads_ref, k_ref, v_ref,
-             o_ref, mo_ref, lo_ref, acc_ref, m_ref, l_ref) = refs
-            ks_ref = vs_ref = None
-    elif quantized:
-        q_ref, pads_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
-        mo_ref = lo_ref = None
-    else:
-        q_ref, pads_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
-        ks_ref = vs_ref = mo_ref = lo_ref = None
-    # q_ref/o_ref [1, BB*KV, G, hd] (host pre-merges the batch/head dims —
-    # Mosaic supports MERGING leading dims in-kernel but not splitting them,
-    # and tpu.matmul takes a single batch dim); pads_ref [1, BB*KV, 1, BK]
-    # (per-row left-pads pre-broadcast on host: SMEM scalars can't be
-    # stacked into a vector in-kernel); k_ref/v_ref [1, BB, KV, BK, hd];
-    # ks_ref/vs_ref [1, BB, KV, BK]; scratch acc [BB*KV, G, hd],
-    # m/l [BB*KV, G, LANES]
+    """One grid step of both kernels: row ``b``'s query positions (row
+    s*G + g of the merged [KV, Sq*G] layout: position s, group head g)
+    against key block ``j`` of that row. Query s sits at slot fill_b + s and
+    attends pad_b <= slot <= fill_b + s. The single-token kernel's limit is
+    the scalar fill; the verify kernel's per-(row, query) limit arrives as a
+    lane-broadcast VMEM operand (``per_query``), because the merged rows
+    cannot be assembled from SMEM scalars in-kernel."""
+    refs = list(refs)
+    q_ref = refs.pop(0)                     # [1, KV, R, hd]
+    lim_ref = refs.pop(0) if per_query else None  # [1, KV, R, LANES]
+    k_ref, v_ref = refs.pop(0), refs.pop(0)       # [1, 1, KV, BK, hd]
+    ks_ref = refs.pop(0) if quantized else None   # [1, 1, KV, BK]
+    vs_ref = refs.pop(0) if quantized else None
+    # outputs [1, KV, R, hd] (+ m, l [1, KV, R, LANES] with return_partials:
+    # the UNNORMALIZED online-softmax state, the shard-local form the
+    # long-context path LSE-merges across the seq axis, backend.long_context
+    # make_long_decode_attention); scratch acc [KV, R, hd], m/l [KV, R, LANES]
+    *out_refs, acc_ref, m_ref, l_ref = refs
 
+    b = pl.program_id(0)
     j = pl.program_id(1)
     nj = pl.num_programs(1)
-    fill = fill_ref[0]
+    pad = pads_ref[b]
+    fill = fills_ref[b]
     win = win_ref[0]
 
     @pl.when(j == 0)
@@ -121,43 +185,43 @@ def _kernel(
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # blocks wholly past the fill point — or, with a sliding window, wholly
-    # below the window floor — were never DMA'd (clamped index_map); skip
-    # their compute so the clamped duplicate block isn't double-counted
-    @pl.when(
-        (j * block_k <= fill)
-        & ((win == 0) | (j * block_k + block_k - 1 >= fill - win + 1))
-    )
+    # grid step j is the row's j-th block from its first; the grid takes as
+    # many steps a row as the row with most blocks needs, and a row with
+    # fewer stays on its last block (clamped index_map: no DMA): skip the
+    # compute so the duplicate block isn't double-counted
+    jb = first_ref[b] + j
+
+    @pl.when(jb <= last_ref[b])
     def _compute():
-        G = q_ref.shape[2]
-        hd = q_ref.shape[3]
-        BKV = block_b * n_kv
-        # one batched dot over the merged (BB, KV) dim instead of BBxKV
-        # unrolled small dots: the unrolled form was VPU-bound (its softmax
-        # bookkeeping ran once per head) and an int8 cache gave no speedup
-        qb = q_ref[0].astype(jnp.float32)                       # [BKV, G, hd]
-        kb = k_ref[0].astype(jnp.float32).reshape(BKV, block_k, hd)
-        vb = v_ref[0].astype(jnp.float32).reshape(BKV, block_k, hd)
+        n_kv = k_ref.shape[2]
+        # one batched dot over the KV heads instead of unrolled small dots:
+        # the unrolled form was VPU-bound (its softmax bookkeeping ran once
+        # per head) and an int8 cache gave no speedup
+        qb = q_ref[0].astype(jnp.float32)                       # [KV, R, hd]
+        kb = k_ref[0, 0].astype(jnp.float32)                    # [KV, BK, hd]
+        vb = v_ref[0, 0].astype(jnp.float32)
 
         s = jax.lax.dot_general(
             qb, kb, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale  # [BKV, G, BK]
+        ) * scale  # [KV, R, BK]
         if quantized:
-            s = s * ks_ref[0].reshape(BKV, 1, block_k)
+            s = s * ks_ref[0, 0].reshape(n_kv, 1, block_k)
 
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (BKV, 1, block_k), 2
+        k_pos = jb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (n_kv, 1, block_k), 2
         )
         in_cache = k_pos < cache_len
         if not quantized:
-            vb = _zero_past_cache(vb, j * block_k, cache_len)
-        mask = (k_pos >= pads_ref[0]) & (k_pos <= fill)  # [BKV, 1, BK]
-        # window in slot space, matching the dense path's k_slot > fill - win
-        mask = mask & ((win == 0) | (k_pos > fill - win))
+            vb = _zero_past_cache(vb, jb * block_k, cache_len)
+        limit = lim_ref[0, :, :, :1] if per_query else fill     # [KV, R, 1]
+        mask = (k_pos >= pad) & (k_pos <= limit)
+        # window in slot space per query, matching the dense path's
+        # k_slot > (fill_b + s) - win
+        mask = mask & ((win == 0) | (k_pos > limit - win))
         s = jnp.where(mask, s, _NEG)
 
-        m_prev = m_ref[:, :, :1]                         # [BKV, G, 1]
+        m_prev = m_ref[:, :, :1]                         # [KV, R, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
@@ -169,139 +233,107 @@ def _kernel(
         )
         if quantized:
             p = p * jnp.where(
-                in_cache, vs_ref[0].reshape(BKV, 1, block_k), 0.0
+                in_cache, vs_ref[0, 0].reshape(n_kv, 1, block_k), 0.0
             )
         pv = jax.lax.dot_general(
             p, vb, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )  # [BKV, G, hd]
+        )  # [KV, R, hd]
         acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(j == nj - 1)
     def _finalize():
         if return_partials:
+            o_ref, mo_ref, lo_ref = out_refs
             o_ref[0] = acc_ref[...].astype(o_ref.dtype)
             mo_ref[0] = m_ref[...]
             lo_ref[0] = l_ref[...]
         else:
             l = jnp.maximum(l_ref[:, :, :1], 1e-30)
-            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+            out_refs[0][0] = (acc_ref[...] / l).astype(out_refs[0].dtype)
 
 
-def _verify_kernel(
-    lidx_ref,   # [1] int32 (SMEM) — layer to read
-    fmax_ref,   # [1] int32 (SMEM) — max over rows of (fill + Sq - 1)
-    fmin_ref,   # [1] int32 (SMEM) — min over rows of fill
-    win_ref,    # [1] int32 (SMEM) — sliding window; 0 = global
-    *refs,
-    block_b: int,
-    block_k: int,
-    n_kv: int,
+def _attend(
+    qg: jax.Array,             # [B, KV, R, hd] — R = n_q * G merged rows
+    limits: jax.Array | None,  # [B, KV, R, LANES] int32, or None: the fill
+    cache: dict,
+    layer_idx,
+    pad_lens: jax.Array,       # [B] int32
+    fills: jax.Array,          # [B] int32
+    window,
+    *,
     n_q: int,
-    cache_len: int,
-    scale: float,
-    quantized: bool,
+    name: str,
+    block_k: int | None,
+    interpret: bool,
+    return_partials: bool = False,
 ):
-    """Multi-position decode ("verify") attention for speculative decoding.
+    """The ``pallas_call`` both wrappers make: grid (row, key block), every
+    K/V block one row's KV heads over ``bk`` slots."""
+    k_all, v_all = cache["k"], cache["v"]
+    quantized = "ks" in cache
+    B, KV, R, hd = qg.shape
+    C = k_all.shape[3]
+    bk = min(block_k, C) if block_k else decode_block_k(
+        KV, hd, k_all.dtype.itemsize, C)
 
-    Same block geometry and online-softmax bookkeeping as _kernel, but each
-    row carries Sq query positions at PER-ROW cache offsets: query (b, s)
-    attends slots pad_b <= j <= fill_b + s. The per-(row, query) visibility
-    limit arrives as a pre-broadcast VMEM operand (limits_ref) because the
-    merged (bb*KV, Sq*G) row layout cannot be assembled from SMEM scalars
-    in-kernel; the SCALAR fill bounds (fmax/fmin) only steer DMA elision."""
-    if quantized:
-        (q_ref, pads_ref, lim_ref, k_ref, v_ref, ks_ref, vs_ref,
-         o_ref, acc_ref, m_ref, l_ref) = refs
-    else:
-        (q_ref, pads_ref, lim_ref, k_ref, v_ref,
-         o_ref, acc_ref, m_ref, l_ref) = refs
-        ks_ref = vs_ref = None
-    # q_ref/o_ref [1, BB*KV, Sq*G, hd] (row index s*G + g: query position s,
-    # group head g); pads_ref [1, BB*KV, 1, BK]; lim_ref [1, BB*KV, SqG,
-    # LANES] (per-(row, query) last visible slot, lane-broadcast);
-    # k_ref/v_ref [1, BB, KV, BK, hd]; scratch acc [BB*KV, SqG, hd],
-    # m/l [BB*KV, SqG, LANES]
+    pads, fills = pad_lens.astype(jnp.int32), fills.astype(jnp.int32)
+    win = jnp.asarray(0 if window is None else window, jnp.int32).reshape(1)
+    first, last = _row_blocks(pads, fills, n_q, win, bk, C)
+    # the steps a row: the most blocks any row reads (a window layer's few,
+    # whatever the cache's length)
+    steps = jnp.maximum(jnp.max(last - first) + 1, 1)
 
-    j = pl.program_id(1)
-    nj = pl.num_programs(1)
-    fill_hi = fmax_ref[0]
-    fill_lo = fmin_ref[0]
-    win = win_ref[0]
+    def scale_index(b, j, lidx, first, last, *_):
+        return (lidx[0], b, 0, jnp.minimum(first[b] + j, last[b]))
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def kv_index(*args):
+        return scale_index(*args) + (0,)
 
-    # blocks wholly past EVERY row's last visible slot — or, with a window,
-    # wholly below every row's window floor — were never DMA'd (clamped
-    # index_map); skip their compute so the duplicate block isn't counted
-    @pl.when(
-        (j * block_k <= fill_hi)
-        & ((win == 0) | (j * block_k + block_k - 1 >= fill_lo - win + 1))
+    row_block = lambda *shape: pl.BlockSpec(  # noqa: E731
+        (1, *shape), lambda b, j, *_: (b,) + (0,) * len(shape)
     )
-    def _compute():
-        hd = q_ref.shape[3]
-        BKV = block_b * n_kv
-        SG = q_ref.shape[2]
-        qb = q_ref[0].astype(jnp.float32)                       # [BKV, SG, hd]
-        kb = k_ref[0].astype(jnp.float32).reshape(BKV, block_k, hd)
-        vb = v_ref[0].astype(jnp.float32).reshape(BKV, block_k, hd)
+    in_specs = [row_block(KV, R, hd)]
+    operands = [qg]
+    if limits is not None:
+        in_specs.append(row_block(KV, R, _LANES))
+        operands.append(limits)
+    in_specs += [pl.BlockSpec((1, 1, KV, bk, hd), kv_index)] * 2
+    operands += [k_all, v_all]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, 1, KV, bk), scale_index)] * 2
+        operands += [cache["ks"], cache["vs"]]
 
-        s = jax.lax.dot_general(
-            qb, kb, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale  # [BKV, SG, BK]
-        if quantized:
-            s = s * ks_ref[0].reshape(BKV, 1, block_k)
-
-        k_pos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (BKV, 1, block_k), 2
-        )
-        in_cache = k_pos < cache_len
-        if not quantized:
-            vb = _zero_past_cache(vb, j * block_k, cache_len)
-        limit = lim_ref[0, :, :, :1]                     # [BKV, SG, 1]
-        mask = (k_pos >= pads_ref[0]) & (k_pos <= limit)
-        # window in slot space per query: k_slot > (fill_b + s) - win
-        mask = mask & ((win == 0) | (k_pos > limit - win))
-        s = jnp.where(mask, s, _NEG)
-
-        m_prev = m_ref[:, :, :1]                         # [BKV, SG, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)
-
-        l_ref[...] = jnp.broadcast_to(
-            l_ref[:, :, :1] * corr + jnp.sum(p, axis=2, keepdims=True),
-            l_ref.shape,
-        )
-        if quantized:
-            p = p * jnp.where(
-                in_cache, vs_ref[0].reshape(BKV, 1, block_k), 0.0
-            )
-        pv = jax.lax.dot_general(
-            p, vb, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [BKV, SG, hd]
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(j == nj - 1)
-    def _finalize():
-        l = jnp.maximum(l_ref[:, :, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
-
-
-def _pick_block_b(batch: int) -> int:
-    for b in (8, 4, 2, 1):
-        if batch % b == 0:
-            return b
-    return 1
+    # the output, with ``return_partials`` in float32 and its m and l beside
+    # it: the shapes of the scratch the kernel sums a row in
+    state = [(KV, R, hd), (KV, R, _LANES), (KV, R, _LANES)]
+    outs = state if return_partials else state[:1]
+    out_dtype = jnp.float32 if return_partials else qg.dtype
+    return pl.pallas_call(
+        functools.partial(
+            _kernel, block_k=bk, cache_len=C,
+            scale=1.0 / (hd ** 0.5), quantized=quantized,
+            per_query=limits is not None, return_partials=return_partials,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6,
+            grid=(B, steps),
+            in_specs=in_specs,
+            out_specs=[row_block(*shape) for shape in outs],
+            scratch_shapes=[pltpu.VMEM(shape, jnp.float32) for shape in state],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((B, *shape), out_dtype)
+                   for shape in outs],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES
+        ),
+        interpret=interpret,
+        name=name,
+    )(
+        jnp.asarray(layer_idx, jnp.int32).reshape(1), first, last, fills, win,
+        pads, *operands,
+    )
 
 
 def supports_decode(cache_len: int, head_dim: int) -> bool:
@@ -323,124 +355,36 @@ def flash_decode_attention(
     q_per_kv: int,
     window: jax.Array | None = None,  # scalar int32; 0/None = global
     *,
-    block_k: int = 128,
+    block_k: int | None = None,
     interpret: bool = False,
     return_partials: bool = False,
 ) -> jax.Array:
     """Semantics match _attention(q, dequantized cache[layer],
     mask=pad<=j<=fill); returns [B, 1, H, hd]. ``window`` > 0 restricts to
     the last ``window`` slots (Gemma sliding layers): below-window blocks
-    are compute-skipped and DMA-elided like past-fill blocks, so a sliding
-    layer's step reads only ~window worth of cache however long the fill.
+    are compute-skipped and DMA-elided like a row's pad and past-fill
+    blocks, so a sliding layer's step reads only ~window worth of cache
+    however long the fill. ``block_k`` is for tests: the block follows the
+    shapes (``decode_block_k``).
 
     ``return_partials=True`` returns the unnormalized online-softmax state
     ``(o [B, H, hd] f32, m [B, H] f32, l [B, H] f32)`` instead — the
     shard-local partial the long-context decode LSE-merges across the seq
     axis (same contract as backend.long_context._prefill_partial_local)."""
-    k_all, v_all = cache["k"], cache["v"]
-    quantized = "ks" in cache
     B, S, H, hd = q.shape
-    L, _, KV, C, _ = k_all.shape
+    KV = cache["k"].shape[2]
     if S != 1:
         raise ValueError(f"decode kernel is single-token (S=1), got S={S}")
     if not (head_dim_supported(hd) or interpret):
         raise ValueError(f"unsupported decode head_dim={hd}")
-    bk = min(block_k, C)
-    bb = _pick_block_b(B)
-
-    qg = q.reshape(B // bb, bb * KV, q_per_kv, hd)
-    # per-row left-pads, pre-broadcast to the merged-row block shape (the
-    # kernel can't assemble a vector out of SMEM scalars)
-    pads = jnp.broadcast_to(
-        pad_lens.astype(jnp.int32).reshape(B // bb, bb, 1, 1, 1),
-        (B // bb, bb, KV, 1, bk),
-    ).reshape(B // bb, bb * KV, 1, bk)
-    grid = (B // bb, pl.cdiv(C, bk))
-
-    def visible_j(j, fill, win, blk=bk):
-        # clamp past-fill (and, under a window, below-window) blocks onto
-        # the nearest visible block: consecutive grid steps then address the
-        # same block and Pallas elides the DMA
-        lo = jnp.where(
-            win[0] > 0, jnp.maximum(fill[0] - win[0] + 1, 0) // blk, 0
-        )
-        return jnp.clip(j, lo, fill[0] // blk)
-
-    def kv_index(b, j, lidx, fill, win):
-        return (lidx[0], b, 0, visible_j(j, fill, win), 0)
-
-    def scale_index(b, j, lidx, fill, win):
-        return (lidx[0], b, 0, visible_j(j, fill, win))
-
-    in_specs = [
-        pl.BlockSpec(
-            (1, bb * KV, q_per_kv, hd),
-            lambda b, j, lidx, fill, win: (b, 0, 0, 0),
-        ),
-        pl.BlockSpec(
-            (1, bb * KV, 1, bk), lambda b, j, lidx, fill, win: (b, 0, 0, 0)
-        ),
-        pl.BlockSpec((1, bb, KV, bk, hd), kv_index),
-        pl.BlockSpec((1, bb, KV, bk, hd), kv_index),
-    ]
-    operands = [qg, pads, k_all, v_all]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bb, KV, bk), scale_index),
-            pl.BlockSpec((1, bb, KV, bk), scale_index),
-        ]
-        operands += [cache["ks"], cache["vs"]]
-
-    kernel = functools.partial(
-        _kernel, block_b=bb, block_k=bk, n_kv=KV, cache_len=C,
-        scale=1.0 / (hd ** 0.5), quantized=quantized,
+    out = _attend(
+        q.reshape(B, KV, q_per_kv, hd), None, cache, layer_idx, pad_lens,
+        jnp.broadcast_to(jnp.asarray(fill, jnp.int32), (B,)), window,
+        n_q=1, block_k=block_k, interpret=interpret,
         return_partials=return_partials,
-    )
-    out_block = lambda shape: pl.BlockSpec(  # noqa: E731
-        (1, *shape), lambda b, j, lidx, fill, win: (b,) + (0,) * len(shape)
-    )
-    if return_partials:
-        out_specs = (
-            out_block((bb * KV, q_per_kv, hd)),
-            out_block((bb * KV, q_per_kv, _LANES)),
-            out_block((bb * KV, q_per_kv, _LANES)),
-        )
-        out_shape = (
-            jax.ShapeDtypeStruct((B // bb, bb * KV, q_per_kv, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B // bb, bb * KV, q_per_kv, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((B // bb, bb * KV, q_per_kv, _LANES), jnp.float32),
-        )
-    else:
-        out_specs = out_block((bb * KV, q_per_kv, hd))
-        out_shape = jax.ShapeDtypeStruct(
-            (B // bb, bb * KV, q_per_kv, hd), q.dtype
-        )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=[
-                pltpu.VMEM((bb * KV, q_per_kv, hd), jnp.float32),
-                pltpu.VMEM((bb * KV, q_per_kv, _LANES), jnp.float32),
-                pltpu.VMEM((bb * KV, q_per_kv, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT_BYTES
-        ),
-        interpret=interpret,
         # a contract: the device trace, the ledger and benchmark metrics name
         # this kernel by it, whatever the wrapper is called
         name="flash_decode_attention",
-    )(
-        jnp.asarray(layer_idx, jnp.int32).reshape(1),
-        jnp.asarray(fill, jnp.int32).reshape(1),
-        jnp.asarray(0 if window is None else window, jnp.int32).reshape(1),
-        *operands,
     )
     if return_partials:
         o, m, l = out
@@ -449,7 +393,7 @@ def flash_decode_attention(
             m[..., 0].reshape(B, H),
             l[..., 0].reshape(B, H),
         )
-    return out.reshape(B, 1, H, hd)
+    return out[0].reshape(B, 1, H, hd)
 
 
 @functools.partial(
@@ -465,118 +409,49 @@ def flash_spec_verify_attention(
     q_per_kv: int,
     window: jax.Array | None = None,  # scalar int32; 0/None = global
     *,
-    block_k: int = 128,
+    block_k: int | None = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """Multi-position decode attention for the speculative verify step:
-    query (b, s) sits at cache slot fills_b + s and attends
-    pad_b <= j <= fills_b + s (models.llama.verify_attention_mask
-    semantics). Returns [B, Sq, H, hd].
+    """Multi-position decode attention for the speculative verify step and
+    the slot segment (Sq = 1): query (b, s) sits at cache slot fills_b + s
+    and attends pad_b <= j <= fills_b + s
+    (models.llama.verify_attention_mask semantics). Returns [B, Sq, H, hd].
 
     This is the decode kernel generalized along two axes at once: several
     query positions per row (the Sq*G rows of one grid cell share each K/V
     block, so a verify step streams the cache ONCE for all k+1 positions —
     the whole point of batched verification) and PER-ROW fill offsets
     (after ragged draft acceptance, rows sit at different cache lengths).
-    DMA elision clamps against the batch-max fill; masking uses the exact
-    per-(row, query) limit."""
-    k_all, v_all = cache["k"], cache["v"]
-    quantized = "ks" in cache
+    Each row reads from its own pad to its own last query; masking uses the
+    exact per-(row, query) limit."""
     B, Sq, H, hd = q.shape
-    L, _, KV, C, _ = k_all.shape
+    KV = cache["k"].shape[2]
     if not (head_dim_supported(hd) or interpret):
         raise ValueError(f"unsupported verify head_dim={hd}")
     G = q_per_kv
     if H != KV * G:
         raise ValueError(f"q_per_kv={q_per_kv} inconsistent with H/KV={H // KV}")
-    bk = min(block_k, C)
-    bb = _pick_block_b(B)
-    SG = Sq * G
 
-    # merged layout [B//bb, bb*KV, Sq*G, hd] with query position MAJOR over
-    # the group heads (row s*G + g) so one limits row covers a position's
-    # whole GQA group
+    # merged layout [B, KV, Sq*G, hd] with query position MAJOR over the
+    # group heads (row s*G + g) so one limits row covers a position's whole
+    # GQA group
     qg = (
         q.transpose(0, 2, 1, 3)               # [B, H, Sq, hd]
         .reshape(B, KV, G, Sq, hd)
         .transpose(0, 1, 3, 2, 4)             # [B, KV, Sq, G, hd]
-        .reshape(B // bb, bb * KV, SG, hd)
+        .reshape(B, KV, Sq * G, hd)
     )
-    pads = jnp.broadcast_to(
-        pad_lens.astype(jnp.int32).reshape(B // bb, bb, 1, 1, 1),
-        (B // bb, bb, KV, 1, bk),
-    ).reshape(B // bb, bb * KV, 1, bk)
     # per-(row, query) last visible slot, lane-broadcast (the kernel cannot
     # assemble the merged-row vector from SMEM scalars)
     limits = fills.astype(jnp.int32)[:, None] + jnp.arange(Sq, dtype=jnp.int32)
     limits = jnp.broadcast_to(
         limits[:, None, :, None, None], (B, KV, Sq, G, _LANES)
-    ).reshape(B // bb, bb * KV, SG, _LANES)
-    fill_hi = jnp.max(fills) + Sq - 1
-    fill_lo = jnp.min(fills)
-    grid = (B // bb, pl.cdiv(C, bk))
-
-    def visible_j(j, fmax, fmin, win, blk=bk):
-        lo = jnp.where(
-            win[0] > 0, jnp.maximum(fmin[0] - win[0] + 1, 0) // blk, 0
-        )
-        return jnp.clip(j, lo, fmax[0] // blk)
-
-    def kv_index(b, j, lidx, fmax, fmin, win):
-        return (lidx[0], b, 0, visible_j(j, fmax, fmin, win), 0)
-
-    def scale_index(b, j, lidx, fmax, fmin, win):
-        return (lidx[0], b, 0, visible_j(j, fmax, fmin, win))
-
-    row_block = lambda shape: pl.BlockSpec(  # noqa: E731
-        (1, *shape), lambda b, j, lidx, fmax, fmin, win: (b,) + (0,) * len(shape)
-    )
-    in_specs = [
-        row_block((bb * KV, SG, hd)),
-        row_block((bb * KV, 1, bk)),
-        row_block((bb * KV, SG, _LANES)),
-        pl.BlockSpec((1, bb, KV, bk, hd), kv_index),
-        pl.BlockSpec((1, bb, KV, bk, hd), kv_index),
-    ]
-    operands = [qg, pads, limits, k_all, v_all]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, bb, KV, bk), scale_index),
-            pl.BlockSpec((1, bb, KV, bk), scale_index),
-        ]
-        operands += [cache["ks"], cache["vs"]]
-
-    kernel = functools.partial(
-        _verify_kernel, block_b=bb, block_k=bk, n_kv=KV, n_q=Sq, cache_len=C,
-        scale=1.0 / (hd ** 0.5), quantized=quantized,
-    )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=grid,
-            in_specs=in_specs,
-            out_specs=row_block((bb * KV, SG, hd)),
-            scratch_shapes=[
-                pltpu.VMEM((bb * KV, SG, hd), jnp.float32),
-                pltpu.VMEM((bb * KV, SG, _LANES), jnp.float32),
-                pltpu.VMEM((bb * KV, SG, _LANES), jnp.float32),
-            ],
-        ),
-        out_shape=jax.ShapeDtypeStruct((B // bb, bb * KV, SG, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=VMEM_LIMIT_BYTES
-        ),
-        interpret=interpret,
-        # a contract: the device trace, the ledger and benchmark metrics name
-        # this kernel by it, whatever the wrapper is called
+    ).reshape(B, KV, Sq * G, _LANES)
+    (out,) = _attend(
+        qg, limits, cache, layer_idx, pad_lens, fills, window, n_q=Sq,
+        block_k=block_k, interpret=interpret,
+        # a contract, as the decode kernel's
         name="flash_spec_verify_attention",
-    )(
-        jnp.asarray(layer_idx, jnp.int32).reshape(1),
-        jnp.asarray(fill_hi, jnp.int32).reshape(1),
-        jnp.asarray(fill_lo, jnp.int32).reshape(1),
-        jnp.asarray(0 if window is None else window, jnp.int32).reshape(1),
-        *operands,
     )
     return (
         out.reshape(B, KV, Sq, G, hd)
