@@ -46,13 +46,19 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe",
-          "laguna", "nemotron_h")
+          "laguna", "nemotron_h", "ouro")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
     "experts": 420, "moe": 420, "laguna": 480, "nemotron_h": 480,
+    "ouro": 360,
 }
+
+
+# the ouro phase's three limits on the chip: logits a row, the first pass's
+# cache rows, the last pass's (benchmarks/configs/ouro-2.6b-l12-int8.json)
+OURO_LIMITS = (0.125, 0.011, 0.095)
 
 
 def sizes(rehearsal: bool) -> dict:
@@ -85,6 +91,10 @@ def sizes(rehearsal: bool) -> dict:
             nemotron_h_prompt_bytes=250, nemotron_h_parity=(150, 256, 4),
             nemotron_h_tolerance=0.05, nemotron_h_tie_band=0.02,
             nemotron_h_state_tolerance=0.01,
+            ouro_layers=2, ouro_seq=328, ouro_batch=4, ouro_max_new=8,
+            ouro_prefill_chunk=128, ouro_prompt_bytes=250,
+            ouro_parity=(150, 256, 4), ouro_tolerance=0.05,
+            ouro_kv_tolerance=0.03, ouro_kv_last_pass_tolerance=0.05,
         )
     return dict(
         kernel_geometries=None,  # derived from MODEL_REGISTRY
@@ -128,6 +138,15 @@ def sizes(rehearsal: bool) -> dict:
         nemotron_h_prompt_bytes=1_900, nemotron_h_parity=(1500, 2048, 4),
         nemotron_h_tolerance=0.25, nemotron_h_tie_band=0.1,
         nemotron_h_state_tolerance=0.006,
+        # ouro: two layers at the published widths run FOUR times (8 cache
+        # layers), 16 / 16 heads; prompts of two 2,048-token chunks in the
+        # S=4096 bucket; the cell's own limits
+        # (benchmarks/configs/ouro-2.6b-l12-int8.json)
+        ouro_layers=2, ouro_seq=4352, ouro_batch=4, ouro_max_new=32,
+        ouro_prefill_chunk=2048, ouro_prompt_bytes=3_800,
+        ouro_parity=(3000, 4096, 4), ouro_tolerance=OURO_LIMITS[0],
+        ouro_kv_tolerance=OURO_LIMITS[1],
+        ouro_kv_last_pass_tolerance=OURO_LIMITS[2],
     )
 
 
@@ -944,6 +963,79 @@ def phase_nemotron_h(args) -> dict:
                           sizes_ref, window=False, more=state_check)
 
 
+def phase_ouro(args) -> dict:
+    """The dense family LOOPED over its weights (``LlamaConfig.loop_passes``,
+    Ouro-2.6B) on the one-shot path: two layers at the published widths run
+    four times — sandwich norms, the final norm after every pass, 16 query
+    heads on 16 KV heads, 8 cache layers — int8 and W8A8, through
+    ``TpuBackend.generate`` (both GQA kernels at one query head a KV head,
+    the prefill in two chunks of row pieces); its counters over every
+    (pass, layer); and its logits and the first layer's FIRST and LAST
+    pass's cache rows against ``benchmarks/reference_ouro.py`` (prefill in
+    two chunks behind a left pad, then decode steps)."""
+    from benchmarks import engine_setup_ouro as setup
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models import jitted_init, ouro_2p6b, tiny_ouro
+    from vnsum_tpu.models.llama import cache_layers
+    from vnsum_tpu.models.quant import init_params_quantized
+
+    sz = sizes(args.rehearsal)
+    c = Checks()
+    make = tiny_ouro if args.rehearsal else ouro_2p6b
+    cfg = make(n_layers=sz["ouro_layers"], max_seq_len=sz["ouro_seq"])
+    params = jitted_init(init_params_quantized, cfg, 50)
+    backend = TpuBackend(
+        model_config=cfg, tokenizer="byte", params=params,
+        batch_size=sz["ouro_batch"], max_new_tokens=sz["ouro_max_new"],
+        quantize=True, quantize_act=True,
+        prefill_chunk_tokens=sz["ouro_prefill_chunk"],
+        generation=GenerationConfig(temperature=1.0, seed=50),
+        interpret=args.rehearsal)
+    prompts = [_vn_text(sz["ouro_prompt_bytes"] - 300 * i // 4, f"o{i}")
+               for i in range(sz["ouro_batch"])]
+    _outs, first_s, second_s = _generate_twice(backend, prompts, c)
+    st = backend.stats
+    n_cache = cache_layers(cfg)
+    c.check("a cache layer a (pass, layer)",
+            n_cache == cfg.loop_passes * cfg.n_layers
+            and backend.family.attention_layers(cfg) == n_cache, n_cache)
+    c.check("both kernels at one query head a KV head",
+            cfg.q_per_kv == 1 and all(
+                set(p.values()) == {"kernel"}
+                for p in st.attention_paths.values()), st.attention_paths)
+    blocks = dict(st.prefill_blocks)
+    c.check("prefill scores counted over every pass and layer",
+            blocks.get("scores_needed", 0) > 0
+            and blocks["scores_computed"] >= blocks["scores_needed"]
+            and blocks["scores_needed"] % (cfg.n_heads * n_cache) == 0,
+            blocks)
+    c.check("decode key blocks walked over every pass and layer",
+            st.decode_kv_blocks_total > 0
+            and st.decode_kv_blocks_total % n_cache == 0,
+            (st.decode_kv_blocks_total, st.decode_kv_blocks_skipped))
+
+    n, bucket, steps = sz["ouro_parity"]
+    config = {"reference": {"parity": {
+        "prompt_tokens": n, "bucket": bucket, "decode_steps": steps,
+        "tolerance": sz["ouro_tolerance"],
+        "kv_tolerance": sz["ouro_kv_tolerance"],
+        "kv_last_pass_tolerance": sz["ouro_kv_last_pass_tolerance"]}},
+        "rehearsal": {}, **setup.MECHANISMS, **setup.sizes_from(cfg),
+        "layer_types": ["full_attention"] * cfg.n_layers}
+    parity = setup.parity_with_reference(backend, config, 50, False)
+    c.check("logits, first-pass and last-pass cache rows within the cell's "
+            "limits of the plain reference, prefill and decode",
+            parity["ok"], {k: parity[k] for k in (
+                "errors", "kv_error", "kv_last_pass_error",
+                "kv_decode_errors")})
+    rep = c.report()
+    rep.update(first_call_s=round(first_s, 2),
+               second_call_s=round(second_s, 2), parity=parity,
+               prefill_blocks=blocks, engine=backend.describe())
+    return rep
+
+
 def _rehearsal_server(argv: list[str]) -> int:
     """The rehearsal's server child: the real serve.server.main, with the
     engine's kernels emulated (the product has no such flag, on purpose)."""
@@ -973,7 +1065,8 @@ def _child(args) -> int:
                     "experts": phase_experts,
                     "moe": phase_moe,
                     "laguna": phase_laguna,
-                    "nemotron_h": phase_nemotron_h}[phase](args))
+                    "nemotron_h": phase_nemotron_h,
+                    "ouro": phase_ouro}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

@@ -4,7 +4,7 @@ One line of JSON a (shape, pads, geometry): the kernel's milliseconds a call,
 taken from a jitted loop of ``--calls`` calls over the layers of an int8 cache
 (a lone call from the host costs ~0.2 ms of its own, as much as the kernel at
 the smaller shapes), the least of ``--repeats`` loops. The shapes are the
-decode steps of the benchmark's seven cells at fill 8,320; ``all-live`` rows
+decode steps of the benchmark's eight offline and served cells at fill 8,320; ``all-live`` rows
 have no pad, ``mix`` the cell's: the served mix's four rows, an offline
 group's first dispatch (four tails of 20 to 75% pad).
 
@@ -47,6 +47,7 @@ SHAPES = [
     ("laguna-sliding", "decode", 12, 8, 9, 128, C_OFFLINE, 512),
     ("granite", "decode", 24, 8, 4, 64, C_OFFLINE, 0),
     ("nemotron", "decode", 12, 2, 16, 128, C_OFFLINE, 0),
+    ("ouro", "decode", 8, 16, 1, 128, C_OFFLINE, 0),
 ]
 SERVED_PROMPTS = (6000, 7260, 2000, 540)
 

@@ -326,6 +326,7 @@ def test_registry_configs_shard_structurally():
             small.tie_embeddings,
             qk_norm=small.qk_norm,
             sandwich_norms=small.sandwich_norms,
+            looped=getattr(small, "loop_passes", 1) > 1,
         )
         assert (
             jax.tree.structure(params) == jax.tree.structure(specs)

@@ -430,7 +430,9 @@ def test_family_resolves_and_names_what_it_lacks():
                                 "long-context backend"}
     # the families whose every layer attends say so by default
     assert llama.FAMILY.attention_layers(llama.tiny_llama()) == 2
-    assert llama.FAMILY.prefill_counts is None
+    # and count nothing of their own for a plain stack (a looped one: PR 50)
+    assert llama.FAMILY.prefill_counts(
+        llama.tiny_llama(), [0], [(0, 8)], 16) == {}
 
 
 @pytest.mark.parametrize("entry", sorted(gh.FAMILY.missing))

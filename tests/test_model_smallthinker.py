@@ -66,7 +66,8 @@ def test_llama_config_gained_no_field():
         "rope_original_max_len", "norm_eps", "max_seq_len", "tie_embeddings",
         "qk_norm", "act", "sandwich_norms", "norm_plus_one", "embed_scale",
         "query_scale", "sliding_window", "layer_is_global",
-        "rope_local_theta", "rope_linear_factor", "w8a8_prefill", "dtype"}
+        "rope_local_theta", "rope_linear_factor", "w8a8_prefill",
+        "loop_passes", "dtype"}
 
 
 # -- routing -------------------------------------------------------------------

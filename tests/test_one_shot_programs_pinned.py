@@ -45,11 +45,19 @@ move: `deepseek-v2`, whose decode kernel is `mla_decode_attention`, and the
 three programs that decode nothing (`slot_prefill-1`, `slot_prefill-2`,
 `adopt-2`).
 
+**PR 50 moved none of the twelve and added `llama-looped`**: the dense
+family's `forward` runs its stack `LlamaConfig.loop_passes` times over the
+same weights (Ouro-2.6B), and at one pass it traces what it traced — the
+loop over passes, the `loop_norm` and the cache-layer offset are static-gated
+on the config, as the Gemma deltas are. The new pin is the tiny looped preset
+(`tiny-ouro`: three passes over two layers, sandwich norms, a KV head a
+query head): the scan over passes around the layer scan is in it.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
 (`assert_pinned`). `python tests/test_one_shot_programs_pinned.py` (from the
-repo's root, `PYTHONPATH=.`) traces all twelve, prints both tables as they
+repo's root, `PYTHONPATH=.`) traces all thirteen, prints both tables as they
 would have to read and rewrites that file — the one place that regenerates
 them."""
 from __future__ import annotations
@@ -78,6 +86,7 @@ _PINNED = {
     "nemotron-h": ("tiny-nemotron-h", {}, "c14ede11a5e68ce7"),
     "deepseek-v2": (tiny_deepseek, {}, "90bc1e80a899c229"),
     "llama-row-pieces": ("tiny", {}, "87c61288db10c21a"),
+    "llama-looped": ("tiny-ouro", {}, "05e5ea3227bd67de"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)
@@ -225,7 +234,7 @@ def test_a_slot_program_traces_to_the_pinned_jaxpr(program):
 
 
 def regenerate() -> None:
-    """Trace all twelve programs, print the two tables' hashes as they are now
+    """Trace all thirteen programs, print the two tables' hashes as they are now
     and rewrite the line ladders."""
     texts = [("_PINNED", family, want,
               one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw),

@@ -511,3 +511,41 @@ def test_gqa_decode_kernel_compiles_at_g16_on_two_kv_heads(one_chip):
         one_chip, ((12, 1, 32, 128), BF16),
         _int8_cache(2, 12, 2, 8448, 128), ((12,), I32), ((), I32))
     assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("offset", [0, 6144])
+def test_gqa_prefill_kernel_compiles_at_one_query_head_a_kv_head(one_chip,
+                                                                 offset):
+    """Ouro-2.6B's 16 query heads on 16 KV heads of 128 over the int8 cache
+    of a four-pass loop — 48 cache layers, the last pass's last layer read —
+    as the engine calls it: ONE row's 2,048-token piece of an 8-row batch
+    (``cache_rows``), at the geometry the wrapper gives G = 1."""
+    from vnsum_tpu.ops import flash_attention
+
+    assert flash_attention._block_geometry(2048, 8448, 1, 128) == (1024, 1024)
+    c = _compiled(
+        lambda q, cache, pads, win, rows:
+        flash_attention.flash_prefill_attention(
+            q, cache, 47, pads, 1, win, offset, rows),
+        one_chip, ((1, 2048, 16, 128), BF16),
+        _int8_cache(48, 8, 16, 8448, 128), ((1,), I32), ((), I32),
+        ((1,), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_gqa_decode_kernel_compiles_at_one_query_head_a_kv_head(one_chip):
+    """One query row a KV head, 16 KV heads, 8 rows, over the 48 cache
+    layers of a four-pass loop: a K/V block holds one row's 16 heads over
+    ``decode_block_k`` slots."""
+    from vnsum_tpu.ops.decode_attention import (
+        decode_block_k,
+        flash_decode_attention,
+    )
+
+    assert decode_block_k(16, 128, 1, 8448) == 512   # 1 MiB of keys: PR 50
+    c = _compiled(
+        lambda q, cache, pads, win: flash_decode_attention(
+            q, cache, 47, pads, 8200, 1, win),
+        one_chip, ((8, 1, 16, 128), BF16),
+        _int8_cache(48, 8, 16, 8448, 128), ((8,), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()

@@ -190,6 +190,29 @@ def test_a_one_mixer_familys_program_carries_its_component_scopes():
     assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
 
 
+def test_a_looped_stacks_program_carries_the_norm_between_passes():
+    """A stack looped over its weights (``LlamaConfig.loop_passes``) adds
+    ONE scope to the dense family's, under both phases: ``loop_norm``, the
+    final norm after every pass. The pass is in no name, and a plain stack's
+    program has no such scope."""
+    from vnsum_tpu.models import tiny_ouro
+
+    def scopes(cfg):
+        b = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
+                       max_new_tokens=NEW, seed=1, flash=False)
+        b._get_fn(B, S, NEW, b.gen_cfg)
+        (m,) = b.scope_maps()
+        assert m["module"] == "jit_generate"
+        return paths(m["scopes"])
+
+    got = scopes(tiny_ouro(max_seq_len=128))
+    for phase in ("prefill", "decode"):
+        assert {f"{phase}/{c}" for c in MODEL + ("loop_norm", "sample")} <= got
+    assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
+    assert not [p for p in scopes(tiny_llama(max_seq_len=128))
+                if "loop_norm" in p]
+
+
 def test_a_one_mixer_familys_kernels_keep_their_contract_names():
     """The tiny Nemotron-H program with every kernel on calls the six
     kernels by the names the benchmark's metrics read, and no other."""

@@ -235,9 +235,9 @@ class TpuBackend:
         # program around it is this module's, for every family
         self.family = family_of(self.cfg)
         if mesh is not None:
-            self.family.refuse("mesh")
+            self.family.refuse("mesh", self.cfg)
         if cache_blocks:
-            self.family.refuse("prefix cache")
+            self.family.refuse("prefix cache", self.cfg)
         if quantize_act:
             # W8A8 prefill (models.llama._proj): double-rate s8xs8 MXU
             # dots on multi-token forwards. LOSSY (per-token activation
@@ -351,7 +351,7 @@ class TpuBackend:
 
             self.prefix_cache = PrefixCache(
                 cache_blocks, cache_block_tokens,
-                n_layers=self.cfg.n_layers,
+                n_layers=self.family.attention_layers(self.cfg),
                 n_kv_heads=self.cfg.n_kv_heads,
                 head_dim=self.cfg.head_dim, dtype=self.cfg.dtype,
                 quantized=self.quantize_kv, mesh=mesh,
@@ -797,6 +797,7 @@ class TpuBackend:
                 self.mesh, self.cfg.tie_embeddings, is_quantized(self.params),
                 qk_norm=self.cfg.qk_norm,
                 sandwich_norms=self.cfg.sandwich_norms,
+                looped="exit_gate" in self.params,
             ),
             ns(P("data", None)),
             ns(P("data")),
@@ -1043,7 +1044,7 @@ class TpuBackend:
             # what the family counts from the pads itself (a scan's tokens,
             # the keys a latent kernel expands)
             for name, n in self.family.prefill_counts(
-                    cfg, pad_lens, self._prefill_spans(S, start)).items():
+                    cfg, pad_lens, self._prefill_spans(S, start), C).items():
                 total[name] = total.get(name, 0) + n
                 said += f", {name} {n}"
         if not self.family.counts_prefill_blocks:
@@ -1372,7 +1373,7 @@ class TpuBackend:
         generate()'s only program."""
         from .inflight import TpuSlotLoop
 
-        self.family.refuse("slot loop")
+        self.family.refuse("slot loop", self.cfg)
         n_slots = slots or self.batch_size
         if self.mesh is not None:
             data_size = self.mesh.shape.get("data", 1)
@@ -1414,7 +1415,7 @@ class TpuBackend:
                     resume_from: int = 0):
         key = (kind, B, S, max_new, gen.with_(seed=0), resume_from)
         if key not in self._seg_fns:
-            self.family.refuse("slot loop")
+            self.family.refuse("slot loop", self.cfg)
             if kind == "prefill":
                 fn = self._make_prefill_fn(B, S, max_new, gen, resume_from)
             elif kind == "slot_prefill":
@@ -1878,7 +1879,7 @@ class TpuBackend:
             and any(references)
         )
         if spec_on:
-            self.family.refuse("speculative decoding")
+            self.family.refuse("speculative decoding", self.cfg)
         if (
             spec_on
             and self.mesh is not None

@@ -487,7 +487,8 @@ class LongContextBackend:
         if model_config is not None:
             from ..models.family import family_of
 
-            family_of(model_config).refuse("long-context backend")
+            family_of(model_config).refuse(
+                "long-context backend", model_config)
         if (model_config is not None) and model_config.sliding_window:
             raise NotImplementedError(
                 "LongContextBackend runs ring attention (global K/V "

@@ -8,10 +8,12 @@ from .llama import (
     gemma3_4b,
     llama32_1b,
     llama32_3b,
+    ouro_2p6b,
     phi4_14b,
     qwen3_0p6b,
     qwen3_8b,
     tiny_llama,
+    tiny_ouro,
 )
 from .sampling import sample_logits
 
@@ -99,6 +101,11 @@ MODEL_REGISTRY = {
     "phi4:14b": phi4_14b,
     "phi4-14b": phi4_14b,
     "tiny": tiny_llama,
+    # the dense family LOOPED over its weights (llama.LlamaConfig.loop_passes):
+    # the whole stack four times a token, a final norm after every pass,
+    # keys and values of their own for every (pass, layer)
+    "ouro-2.6b": ouro_2p6b,
+    "tiny-ouro": tiny_ouro,
     # another family (models/deepseek.py): latent attention, sparse experts
     "deepseek-v2": _deepseek_v2,
     # a third (models/smallthinker.py): GQA with window and global layers
@@ -131,6 +138,7 @@ __all__ = [
     "llama32_1b",
     "phi4_14b",
     "llama32_3b",
+    "ouro_2p6b",
     "qwen3_0p6b",
     "qwen3_8b",
     "tiny_llama",

@@ -622,7 +622,8 @@ def forward_dense(params: dict, cfg: DeepseekV2Config, tokens) -> jax.Array:
 # -- the engine's seam (models/family.py) -------------------------------------
 
 
-def prefill_counts(cfg: DeepseekV2Config, pad_lens, spans) -> dict:
+def prefill_counts(cfg: DeepseekV2Config, pad_lens, spans,
+                   cache_len=None) -> dict:
     """What the prefill kernel of one dispatch expanded from the latent, a
     head, from the pads it was packed with: ``latent_keys_expanded`` (the
     keys of the key blocks its computed tiles read: every chunk expands

@@ -52,14 +52,15 @@ class Family:
     # cfg -> each layer's query heads a KV head where the layers differ in
     # them, or None where ``cfg.q_per_kv`` holds for every layer
     layer_groups: Callable = lambda cfg: None
-    # cfg -> how many layers attend over the cache (a family that mixes
-    # attention with other layers holds keys and values for those alone)
+    # cfg -> how many layers of keys and values the cache holds (a family
+    # that mixes attention with other layers holds them for those alone; a
+    # stack looped over its weights holds a layer a (pass, layer))
     attention_layers: Callable = lambda cfg: cfg.n_layers
-    # (cfg, pad_lens, prefill spans) -> {name: count} a dispatch's prefill
-    # adds to ``EngineStats.prefill_blocks`` beside the attention's cells,
-    # from the pads it was packed with (a scan's tokens, the keys a latent
-    # kernel expands), or None; counted whether or not
-    # ``counts_prefill_blocks``
+    # (cfg, pad_lens, prefill spans, cache slots) -> {name: count} a
+    # dispatch's prefill adds to ``EngineStats.prefill_blocks`` beside the
+    # attention's cells, from the pads it was packed with (a scan's tokens,
+    # the keys a latent kernel expands, the scores a looped stack's kernel
+    # computed), or None; counted whether or not ``counts_prefill_blocks``
     prefill_counts: Callable | None = None
     # the fewest tokens a ROW PIECE of this family's prefill may hold
     # without its layers losing their pace, or None. Where it is set the
@@ -82,13 +83,22 @@ class Family:
     row_record: Callable | None = None
     # engine entry -> what this family lacks for it
     missing: dict = field(default_factory=dict)
+    # cfg -> the same for ONE configuration of a family that runs the entry
+    # otherwise (a stack looped over its weights)
+    config_missing: Callable = lambda cfg: {}
 
-    def refuse(self, entry: str) -> None:
-        """Raise if this family cannot run ``entry`` of the engine."""
+    def refuse(self, entry: str, cfg=None) -> None:
+        """Raise if this family, or this configuration of it where ``cfg``
+        is given, cannot run ``entry`` of the engine."""
         if entry in self.missing:
             raise NotImplementedError(
                 f"the {self.name} family cannot run the engine's {entry} "
                 f"yet: {self.missing[entry]}")
+        lacks = self.config_missing(cfg) if cfg is not None else {}
+        if entry in lacks:
+            raise NotImplementedError(
+                f"this {self.name} configuration cannot run the engine's "
+                f"{entry} yet: {lacks[entry]}")
 
 
 def family_of(cfg) -> Family:

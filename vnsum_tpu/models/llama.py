@@ -69,6 +69,13 @@ class LlamaConfig:
     # (single-token) keeps the exact mixed path — it is HBM-bound, not
     # MXU-bound. Requires int8-quantized weights to do anything.
     w8a8_prefill: bool = False
+    # a LOOPED stack (Ouro): the whole stack of n_layers runs this many
+    # times over the SAME weights, ``final_norm`` after every pass (the
+    # normed stream enters the next pass and, after the last, the head),
+    # and pass t of layer l has keys and values of its own: cache layer
+    # ``t * n_layers + l`` of ``cache_layers(cfg)``. 1 traces what it always
+    # traced
+    loop_passes: int = 1
     dtype: Any = field(default=jnp.bfloat16)
 
     @property
@@ -143,6 +150,44 @@ def phi4_14b(**kw) -> LlamaConfig:
     return LlamaConfig(**base)
 
 
+def ouro_2p6b(early_exit_threshold: float = 1.0, **kw) -> LlamaConfig:
+    """Ouro-2.6B (ByteDance; arXiv:2510.25741): a Qwen-like dense stack of 48
+    layers applied ``total_ut_steps`` = 4 times over the same weights —
+    sandwich norms, the final norm after every pass, 16 full KV heads, keys
+    and values of their own for every (pass, layer).
+
+    The published ``early_exit_threshold`` is 1: every token runs every
+    pass and the exit gate (``params["exit_gate"]``) changes no logit, so the
+    program does not evaluate it. A threshold under 1 lets the rows of one
+    batch leave the loop at different passes, and the later passes' keys
+    and values of a token that left have to be filled from somewhere: no
+    entry of the engine does either, so it is refused here by name."""
+    if early_exit_threshold < 1.0:
+        raise NotImplementedError(
+            f"early_exit_threshold {early_exit_threshold} < 1: adaptive exit "
+            "from the loop of passes is not built (rows of a batch leaving "
+            "at different passes; the later passes' cache of a token that "
+            "left). Every pass runs for every token, as at the published 1.0")
+    base = dict(
+        vocab_size=49_152, dim=2048, n_layers=48, n_heads=16, n_kv_heads=16,
+        head_dim=128, intermediate=5632, rope_theta=1_000_000.0,
+        use_llama3_rope_scaling=False, norm_eps=1e-6, max_seq_len=65_536,
+        tie_embeddings=False, qk_norm=False, sandwich_norms=True,
+        loop_passes=4,
+    )
+    base.update(kw)
+    return LlamaConfig(**base)
+
+
+def cache_layers(cfg: LlamaConfig) -> int:
+    """Layers of keys and values a program carries: one a (pass, layer).
+    THE count for the cache, the prefix pool, the kernels' per-layer tables
+    and the block counters; ``cfg.n_layers`` is the layers of WEIGHTS. The
+    families that borrow this module's cache hand in configs of their own,
+    which know no pass."""
+    return cfg.n_layers * getattr(cfg, "loop_passes", 1)
+
+
 def tiny_llama(**kw) -> LlamaConfig:
     """Small config for hermetic CPU tests."""
     base = dict(
@@ -153,6 +198,15 @@ def tiny_llama(**kw) -> LlamaConfig:
     )
     base.update(kw)
     return LlamaConfig(**base)
+
+
+def tiny_ouro(**kw) -> LlamaConfig:
+    """``ouro_2p6b``'s mechanisms at ``tiny_llama``'s size: three passes
+    over two layers, sandwich norms, a KV head a query head, untied head."""
+    base = dict(loop_passes=3, sandwich_norms=True, n_kv_heads=4,
+                tie_embeddings=False, norm_eps=1e-6)
+    base.update(kw)
+    return tiny_llama(**base)
 
 
 # -- parameters -------------------------------------------------------------
@@ -194,13 +248,21 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> dict:
         params["layers"]["post_ffw_norm"] = norm_init((L, D), cfg.dtype)
     if not cfg.tie_embeddings:
         params["lm_head"] = norm((D, cfg.vocab_size), next(keys))
+    if cfg.loop_passes > 1:
+        # the gate a looped model leaves the loop by: sigmoid(w . h + b) on
+        # a pass's normed stream. In the tree so that the tree is the
+        # model's; not evaluated at the published threshold (ouro_2p6b)
+        params["exit_gate"] = {
+            "w": jax.random.normal(next(keys), (D,), jnp.float32) * 0.02,
+            "b": jnp.zeros((), jnp.float32)}
     return params
 
 
 def init_kv_cache(
     cfg: LlamaConfig, batch: int, cache_len: int, *, quantized: bool = False
 ) -> dict:
-    """Stacked cache [L, B, KV, C, hd] — KV heads BEFORE the sequence dim.
+    """Stacked cache [L, B, KV, C, hd] — KV heads BEFORE the sequence dim;
+    L is ``cache_layers(cfg)``, a layer a (pass, layer) of a looped stack.
 
     This is the layout the attention einsums consume directly ((b, kv) as
     batch dims, hd/c as the minor contraction dims). With the sequence dim
@@ -213,7 +275,7 @@ def init_kv_cache(
     scales ``ks``/``vs`` [L, B, KV, C] — decode attention streams the whole
     cache every step, so this halves its HBM traffic (decode attention is
     the largest decode-phase cost once weights are int8)."""
-    shape = (cfg.n_layers, batch, cfg.n_kv_heads, cache_len, cfg.head_dim)
+    shape = (cache_layers(cfg), batch, cfg.n_kv_heads, cache_len, cfg.head_dim)
     if not quantized:
         return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
     return {
@@ -671,16 +733,40 @@ def forward(
         )
         return (h, cache), None
 
-    (x, new_cache), _ = jax.lax.scan(
-        layer_step,
-        (x, kv_cache),
-        (params["layers"], jnp.arange(cfg.n_layers), flags),
-    )
+    def stack(x, cache, first_cache_layer=None):
+        """The layers once over; layer l writes and reads cache layer
+        ``first_cache_layer + l`` (None: l, and nothing is added)."""
+        cache_layer = jnp.arange(cfg.n_layers)
+        if first_cache_layer is not None:
+            cache_layer = cache_layer + first_cache_layer
+        (x, cache), _ = jax.lax.scan(
+            layer_step, (x, cache), (params["layers"], cache_layer, flags))
+        return x, cache
+
+    if cfg.loop_passes == 1:
+        x, new_cache = stack(x, kv_cache)
+    else:
+        # a LOOPED stack: the same layers ``loop_passes`` times, pass t over
+        # cache layers [t * L, (t + 1) * L), the final norm after every pass
+        # (its output is the next pass's input). A scan over the passes and
+        # not a Python loop: one copy of the stack in the program whatever
+        # the number of passes, as the layer scan holds one copy of a layer
+        def pass_step(carry, t):
+            h, cache = stack(*carry, t * cfg.n_layers)
+            with jax.named_scope("loop_norm"):
+                h = _rmsnorm(h, params["final_norm"], cfg.norm_eps,
+                             cfg.norm_plus_one)
+            return (h, cache), None
+
+        (x, new_cache), _ = jax.lax.scan(
+            pass_step, (x, kv_cache), jnp.arange(cfg.loop_passes))
 
     with jax.named_scope("lm_head"):
         if last_only:
             x = x[:, -1:, :]
-        x = _rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
+        if cfg.loop_passes == 1:   # a looped stack's last pass normed it
+            x = _rmsnorm(
+                x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
         logits = _lm_head_logits(x, params, cfg)
     return logits, new_cache
 
@@ -793,8 +879,17 @@ def forward_train(
     def layer_step(carry, lp):
         return block(carry, lp), None
 
-    x, _ = jax.lax.scan(layer_step, x, params["layers"])
-    x = _rmsnorm(x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one)
+    def pass_step(x, _):
+        # the layers once over and the final norm: the whole of a plain
+        # stack, one pass of a looped one (``forward``)
+        x, _ = jax.lax.scan(layer_step, x, params["layers"])
+        return _rmsnorm(
+            x, params["final_norm"], cfg.norm_eps, cfg.norm_plus_one), None
+
+    if cfg.loop_passes == 1:
+        x, _ = pass_step(x, None)
+    else:
+        x, _ = jax.lax.scan(pass_step, x, None, length=cfg.loop_passes)
     return _lm_head_logits(x, params, cfg)
 
 
@@ -934,7 +1029,54 @@ def _layer_windows(cfg: LlamaConfig):
         raise ValueError(
             f"layer_is_global has {len(flags)} entries for "
             f"{cfg.n_layers} layers")
-    return tuple(0 if g else cfg.sliding_window for g in flags)
+    # a cache layer a (pass, layer): every pass's layers, in the cache's order
+    return tuple(0 if g else cfg.sliding_window
+                 for g in flags) * cfg.loop_passes
+
+
+def _prefill_counts(cfg: LlamaConfig, pad_lens, spans, cache_len) -> dict:
+    """What a LOOPED stack's prefill kernel computed against what its
+    attention needs (``Family.prefill_counts``), from the pads a dispatch
+    was packed with: ``scores_computed`` (the cells the kernel fetched,
+    interior and edge, by its own rule x the tile's area) and
+    ``scores_needed`` (the causal pairs of real tokens), both x query heads
+    x ``cache_layers`` — every (pass, layer) runs the kernel. What a tile
+    trades at one query head a KV head: a wider tile steps less and
+    computes more above the diagonal and under the pads. Nothing for a
+    plain stack (its counts are what they were) and for window layers
+    (``window_scores_*``, the engine's)."""
+    if cfg.loop_passes == 1 or cfg.sliding_window:
+        return {}
+    import numpy as np
+
+    from ..ops.flash_attention import prefill_block_class_grid
+
+    pads = np.asarray(pad_lens, np.int64)
+    computed = needed = 0
+    for lo, hi in spans:
+        grid, (bq, bk) = prefill_block_class_grid(
+            pad_lens, hi - lo, cache_len, lo, 0, cfg.q_per_kv, cfg.head_dim)
+        computed += int((grid >= 2).sum()) * bq * bk   # interior and edge
+        # a real query at slot i sees the i + 1 - pad keys of its row
+        needed += int(np.clip(
+            np.arange(lo, hi)[None, :] + 1 - pads[:, None], 0, None).sum())
+    heads = cfg.n_heads * cache_layers(cfg)
+    return {"scores_computed": computed * heads,
+            "scores_needed": needed * heads}
+
+
+def _config_missing(cfg: LlamaConfig) -> dict:
+    """Engine entries THIS config cannot run (``Family.config_missing``):
+    a looped stack on the entries that know no pass."""
+    if cfg.loop_passes == 1:
+        return {}
+    return {
+        "long-context backend": (
+            f"a stack looped {cfg.loop_passes} times: the ring prefill "
+            "(backend/long_context.py) stacks ONE pass's keys and values "
+            "into its frozen cache, a layer of weights a layer of cache, "
+            "and its decode step scans the layers once"),
+    }
 
 
 def _family():
@@ -946,7 +1088,8 @@ def _family():
         attention_supported=_attention_supported,
         prefill_attention=_prefill_attention,
         decode_attention=_decode_attention, counts_prefill_blocks=True,
-        layer_windows=_layer_windows,
+        layer_windows=_layer_windows, attention_layers=cache_layers,
+        config_missing=_config_missing, prefill_counts=_prefill_counts,
         # a row of one 2,048-token chunk: 4,096 operations a weight byte
         # (W8A8), far over the v5e's ~480; measured in PERF.md, PR 48
         prefill_piece_tokens=2048,

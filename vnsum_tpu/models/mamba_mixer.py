@@ -203,11 +203,12 @@ def last_state(cache: dict) -> jax.Array:
     return jnp.stack([cache["ssm"][0], cache["ssm"][-1]])
 
 
-def prefill_counts(cfg, pad_lens, spans) -> dict:
+def prefill_counts(cfg, pad_lens, spans, cache_len=None) -> dict:
     """What the scan of one dispatch's prefill saw, from the pads it was
     packed with: real tokens x Mamba layers, and the tokens of the chunks
     ``ssd_prefill_scan`` did not skip x Mamba layers. ``spans`` are the
-    prefill's query spans [lo, hi) over the bucket."""
+    prefill's query spans [lo, hi) over the bucket; a scan reads no cache,
+    so ``cache_len`` is taken and not read."""
     import numpy as np
 
     from ..ops.ssd_scan import scan_tokens_computed

@@ -96,6 +96,8 @@ def quantize_params(params: dict) -> dict:
     }
     if "lm_head" in params:
         out["lm_head"] = _quantize(params["lm_head"], (0,))  # scale [V]
+    if "exit_gate" in params:   # a looped stack's gate: float32 as it is
+        out["exit_gate"] = params["exit_gate"]
     return out
 
 
@@ -167,6 +169,15 @@ def init_params_quantized(key: jax.Array, cfg) -> dict:
     }
     if "lm_head" in shapes:
         out["lm_head"] = qinit(next(keys), shapes["lm_head"], (0,))
+    if "exit_gate" in shapes:
+        # a looped stack's gate, float32, from a key of its own: no other
+        # draw moves
+        gate = shapes["exit_gate"]
+        out["exit_gate"] = {
+            "w": jax.random.normal(
+                jax.random.fold_in(key, 2), gate["w"].shape, jnp.float32
+            ) * 0.02,
+            "b": jnp.zeros(gate["b"].shape, jnp.float32)}
     return out
 
 
@@ -187,6 +198,8 @@ def dequantize_params(qparams: dict) -> dict:
     }
     if "lm_head" in qparams:
         out["lm_head"] = dequantize_leaf(qparams["lm_head"], (0,))
+    if "exit_gate" in qparams:
+        out["exit_gate"] = qparams["exit_gate"]
     return out
 
 

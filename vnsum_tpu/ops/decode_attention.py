@@ -23,8 +23,9 @@ block carried all KV heads of ``bb`` batch rows over 128 slots (~MB-scale
 DMAs), which left every row read from slot 0: a row's left pad was copied,
 upcast, multiplied and masked away. Now a block carries all KV heads of ONE
 row over ``bk`` slots, ``bk`` chosen from the shapes so that the block's
-keys stay ~512 KiB as VMEM tiles them (``decode_block_k``: 512 slots at
-KV=8 x hd=128 and at Phi-4's KV=10, 1,024 at SmallThinker's KV=4, 512 at
+keys stay ~512 KiB as VMEM tiles them — a slot weighed as 8 heads of 128
+at most, so 1 MiB at 16 heads — (``decode_block_k``: 512 slots at KV=8 x hd=128, at Phi-4's KV=10 and at
+Ouro's KV=16, 1,024 at SmallThinker's KV=4, 512 at
 Granite-4.0-H's 64-wide heads, which fill half of each lane tile, 2,048 at
 Nemotron-H's KV=2; half the slots for a bfloat16 cache), and each row
 walks its own blocks: grid step j of row b is block ``first_b + j``, from
@@ -65,6 +66,17 @@ shape that did not gain is the one whose old block was already 1 MiB of
 keys (8 rows x 8 KV heads): +0.9% all-live, inside the sweep's own repeat
 (0.2025-0.2054 over four readings of the old kernel).
 
+At 16 KV heads of one query head each (Ouro-2.6B; PR 50, the same script:
+8 rows, all-live / the group's four tails): 128 slots 0.5475 / 0.4509; 256
+— 512 KiB of keys, the byte rule's own — 0.4117 / 0.3353; **512 0.3936 /
+0.3146**; 1,024 0.4050 / 0.3306. A block of 1 MiB of keys wins by 4-6%
+here as 512 slots did at KV=8: what a grid step costs beside its bytes is
+paid a block, and under 512 slots a row of 8k has more than 17 of them. So
+the rule weighs a slot as 8 heads of 128 at most: more heads a slot get the
+slots 8 heads get, not fewer; no other shape a cell or a test runs is wider
+than that, and their blocks are what they were. 0.3936 ms a call is 87% of the
+call's least time at 819 GB/s (281 MB of keys, values and scales).
+
 int8 KV caches (models.llama.init_kv_cache(quantized=True)) stream half the
 bytes again: the kernel loads int8 K/V blocks plus per-(token, head) f32
 scales and folds dequantization into the softmax algebra — scores multiply
@@ -91,9 +103,12 @@ from .flash_attention import (
 )
 
 
-# key bytes of a K/V block in the cache's own type (module docstring: the
-# sweep that chose it)
+# key bytes of a K/V block in the cache's own type, and the most key
+# elements a slot is weighed at: 8 heads of 128, so that a slot of more
+# heads is no reason for fewer slots than those 8 get (module docstring:
+# the sweeps that chose them)
 _BLOCK_KEY_BYTES = 512 * 1024
+_SLOT_KEYS_MOST = 1024
 
 
 def _zero_past_cache(vb, k_start, cache_len: int):
@@ -113,13 +128,17 @@ def decode_block_k(n_kv: int, head_dim: int, itemsize: int,
                    cache_len: int) -> int:
     """Key slots of a K/V block, which holds ONE row's KV heads: the fewest
     whole lane tiles whose keys ``[KV, bk, hd]`` hold ``_BLOCK_KEY_BYTES``
-    as VMEM tiles them (a narrow head padded to whole lanes), no more than
+    as VMEM tiles them (a narrow head padded to whole lanes), a slot
+    weighed at ``_SLOT_KEYS_MOST`` key elements at most (many KV heads: 16
+    of 128 weigh 512 KiB at 256 slots and read 4-6% faster at the 512 that
+    8 heads get), no more than
     leave half of ``VMEM_LIMIT_BYTES`` free beside what a grid step keeps
     of them — K and V, two buffers each, and their float32 upcasts — and
     never more than the cache. Shapes alone decide (module docstring)."""
     tiled = n_kv * -(-head_dim // _LANES) * _LANES   # a slot's key elements
     most = VMEM_LIMIT_BYTES // 2 // (tiled * (4 * itemsize + 8))
-    slots = min(-(-_BLOCK_KEY_BYTES // (tiled * itemsize)), most)
+    weighed = min(tiled, _SLOT_KEYS_MOST) * itemsize
+    slots = min(-(-_BLOCK_KEY_BYTES // weighed), most)
     return min(max(-(-slots // _LANES) * _LANES, _LANES), cache_len)
 
 

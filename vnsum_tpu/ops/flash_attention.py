@@ -94,7 +94,17 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   rule's**; (512, 2048) 0.1209 / 4.3 (13% more scores computed);
   (1024, 512) 0.2070 / 8.4 — the key width sets the cost at G=16 as at
   G=7, and the rule stands;
-  G <= 3 and hd=256 have no cell (ROADMAP Queue 1 item 1c). _vmem_bytes
+  at G=1 (Ouro-2.6B's 16 query heads on 16 KV heads of 128: every K/V
+  block's int8-to-float upcast serves ONE head's query rows; kernel alone
+  at its group's three map dispatches — 8 rows each, the first with four
+  tail rows, int8 cache, 48 cache layers = 4 passes x 12 layers; seconds
+  the group / the all-live dispatch / ns per 1,024 computed scores; PR 50):
+  (512, 512) 6.736 / 2.455 / 11.8; (512, 1024) 4.324 / 1.562 / 7.1;
+  (512, 2048), the rule's until then, 4.422 / 1.589 / 6.4; (1024, 2048)
+  4.119 / 1.480 / 6.0; (2048, 1024) 3.741 / 1.358 / 5.5; **(1024, 1024)
+  3.545 / 1.282 / 5.8 — adopted at G=1** (-20%: twice the query rows an
+  upcast, and 11% fewer scores computed than under a 2,048-wide key block);
+  G = 2, 3 and hd=256 have no cell (ROADMAP Queue 1 item 1c). _vmem_bytes
   counts what a
   geometry needs; past the 32 MiB the attention kernels share, this
   kernel alone asks for its count, and a group that fits nothing under 64
@@ -429,13 +439,16 @@ def _block_geometry(S: int, C: int, G: int, hd: int,
     default_bq = 512
     # the key width shrinks with the head size (hd=256 Gemma3: half)
     default_bk = max(512, 2048 * _LANES // max(hd, 1))
-    if G <= _UNROLLED_GROUP:
+    if 1 < G <= _UNROLLED_GROUP:
         # G * bk held to 3 * 2048 since the default limit of 16 MiB (Qwen3
         # and Phi-4's 4:1 groups: bk 1024); (1024, 1024) ties under the
         # unroll's order (module docstring, "block geometry")
         while G * default_bk > 3 * 2048 and default_bk > 512:
             default_bk //= 2
     else:
+        # the wide groups' tile, and a lone head's (G = 1: a K/V block's
+        # upcast serves one head, so twice the query rows halve the upcasts
+        # a score: -20% at Ouro's map dispatches, PR 50)
         default_bk = max(512, _LOOPED_BLOCK * _LANES // max(hd, 1))
         # 1024 query rows while the group's q, o and state rows fit (G <= 16
         # at hd 128), else 512 (1.55 s for 1.44 at G=7)

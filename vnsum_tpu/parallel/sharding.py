@@ -24,6 +24,7 @@ def param_specs(
     fsdp: bool = False,
     qk_norm: bool = False,
     sandwich_norms: bool = False,
+    looped: bool = False,
 ) -> dict[str, Any]:
     """PartitionSpec pytree matching models.llama param structure.
 
@@ -63,6 +64,10 @@ def param_specs(
         specs["layers"]["post_ffw_norm"] = P(L, None)
     if not tie_embeddings:
         specs["lm_head"] = P(None, _M)       # [D, V]
+    if looped:
+        # a looped stack's exit gate (llama.init_params): a vector and a
+        # scalar, float32, replicated
+        specs["exit_gate"] = {"w": P(None), "b": P()}
     if quantized:
         from ..models.quant import _CONTRACT_AXES
 
@@ -106,10 +111,12 @@ def param_shardings(
     fsdp: bool = False,
     qk_norm: bool = False,
     sandwich_norms: bool = False,
+    looped: bool = False,
 ) -> dict[str, Any]:
     return jax.tree.map(
         lambda spec: NamedSharding(mesh, spec),
-        param_specs(tie_embeddings, quantized, fsdp, qk_norm, sandwich_norms),
+        param_specs(tie_embeddings, quantized, fsdp, qk_norm, sandwich_norms,
+                    looped),
         is_leaf=lambda x: isinstance(x, P),
     )
 
@@ -125,8 +132,10 @@ def shard_params(params: Any, mesh: Mesh, tie_embeddings: bool = True) -> Any:
     quantized = is_quantized(params)
     qk_norm = "q_norm" in params["layers"]
     sandwich = "post_attn_norm" in params["layers"]
+    looped = "exit_gate" in params
     specs = param_specs(
-        tie_embeddings, quantized, qk_norm=qk_norm, sandwich_norms=sandwich
+        tie_embeddings, quantized, qk_norm=qk_norm, sandwich_norms=sandwich,
+        looped=looped,
     )
 
     def check(leaf, spec):
@@ -144,6 +153,6 @@ def shard_params(params: Any, mesh: Mesh, tie_embeddings: bool = True) -> Any:
     jax.tree.map(check, params, specs, is_leaf=lambda x: isinstance(x, P))
     shardings = param_shardings(
         mesh, tie_embeddings, quantized, qk_norm=qk_norm,
-        sandwich_norms=sandwich,
+        sandwich_norms=sandwich, looped=looped,
     )
     return jax.tree.map(jax.device_put, params, shardings)

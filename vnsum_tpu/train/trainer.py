@@ -105,6 +105,7 @@ class Trainer:
             mesh, self.cfg.tie_embeddings, fsdp=self.tc.fsdp,
             qk_norm=self.cfg.qk_norm,
             sandwich_norms=self.cfg.sandwich_norms,
+            looped=self.cfg.loop_passes > 1,
         )
         if params is None:
             # init directly into the sharded layout: each leaf is produced
@@ -162,6 +163,7 @@ class Trainer:
             self.cfg.tie_embeddings, fsdp=self.tc.fsdp,
             qk_norm=self.cfg.qk_norm,
             sandwich_norms=self.cfg.sandwich_norms,
+            looped=self.cfg.loop_passes > 1,
         )
         abstract = jax.eval_shape(
             lambda: init_params(jax.random.key(0), self.cfg)
