@@ -279,6 +279,72 @@ def test_prefill_kernel_skips_whole_chunks_of_pads_and_keeps_zero():
     assert ssd_scan.scan_tokens_computed([24], 24, 8) == 0
 
 
+@pytest.mark.parametrize("rows", [[3, 0, 4, 1, 2], [4, 1], [2]],
+                         ids=["permutation", "subset", "one-row"])
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+def test_a_row_piece_writes_its_rows_of_the_state_and_no_other(form, rows):
+    """``rows`` names, for each row of the inputs, the state's batch row it
+    continues (``ssd_prefill_scan``: of the stacked state's layer, in
+    place; ``ssd_chunked_xla``: of one layer's state): those rows read what
+    the same inputs give one row at a time, every other row — and the other
+    layer of the stack — is bit-equal to what came in."""
+    n = len(rows)
+    x, dt, A, Bm, Cm, D, _ = _scan_case(seed=3, rows=n, S=20)
+    state = jax.random.normal(jax.random.key(7), (2, 5, 16, 8 * 16))
+    idx = jnp.asarray(rows, jnp.int32)
+    # pads go by the piece's own rows, not the state's; a row behind a pad
+    # starts from zeros, as the engine's rows do
+    pads = jnp.asarray([0, 9, 17, 3, 8][:n], jnp.int32)
+    x = x * (jnp.arange(20)[None, :] >= pads[:, None])[..., None, None]
+    state = state.at[:, idx].multiply((pads == 0)[:, None, None])
+    with jax.default_matmul_precision("highest"):
+        want_y, want_h = _token_by_token(x, dt, A, Bm, Cm, D, state[1][idx])
+        if form == "kernel":
+            y, h = ssd_scan.ssd_prefill_scan(
+                x, dt, A, Bm, Cm, D, state, 1, pads, idx, chunk=8,
+                interpret=True)
+            np.testing.assert_array_equal(np.asarray(h[0]),
+                                          np.asarray(state[0]))
+            h = h[1]
+        else:
+            y, h = ssd_scan.ssd_chunked_xla(x, dt, A, Bm, Cm, D, state[1], 8,
+                                            idx)
+    assert h.shape == state[1].shape
+    # a row's whole chunks of pad read as zeros in the kernel and are
+    # computed from zeroed inputs in the XLA form: the same numbers
+    assert _rel(y, want_y) < 1e-5 and _rel(h[idx], want_h) < 1e-5
+    others = [r for r in range(5) if r not in rows]
+    np.testing.assert_array_equal(np.asarray(h)[others],
+                                  np.asarray(state[1])[others])
+
+
+def test_without_rows_the_scan_traces_to_the_form_it_had():
+    """``rows=None`` is the call every program made before a piece existed
+    (the text of its jaxpr hashed on PR 50's tree): the state operand 9
+    aliased to output 1. With rows the state is operand 10, behind a third
+    prefetched vector, and the kernel's body is the same."""
+    import hashlib
+
+    x, dt, A, Bm, Cm, D, state = _scan_case(S=16)
+    pads = jnp.zeros((3,), jnp.int32)
+
+    def text(*rows):
+        return str(jax.make_jaxpr(lambda *a: ssd_scan.ssd_prefill_scan(
+            *a, chunk=8, interpret=True))(
+                x, dt, A, Bm, Cm, D, state, 1, pads, *rows))
+
+    whole, piece = text(), text(jnp.arange(3, dtype=jnp.int32))
+    assert hashlib.sha256(whole.encode()).hexdigest()[:16] \
+        == "8d964679783ef578"
+    assert whole == text(None)
+    assert "input_output_aliases=((9, 1),)" in whole
+    assert "input_output_aliases=((10, 1),)" in piece
+    assert len(piece.split("\n")) == len(whole.split("\n"))
+    # the tiny Granite and Nemotron-H one-shot programs, whose chunks hold
+    # fewer tokens than a piece, are pinned whole in
+    # tests/test_one_shot_programs_pinned.py
+
+
 def test_decode_kernel_equals_its_xla_form_and_writes_one_layer():
     x, dt, A, Bm, Cm, D, state = _scan_case()
     args = (x[:, 5], dt[:, 5], A, Bm[:, 5], Cm[:, 5], D)

@@ -53,11 +53,22 @@ on the config, as the Gemma deltas are. The new pin is the tiny looped preset
 (`tiny-ouro`: three passes over two layers, sandwich norms, a KV head a
 query head): the scan over passes around the layer scan is in it.
 
+**PR 51 moved none of the thirteen and added `granite-h-row-pieces`**: the
+state-space hybrid names a piece of its own (`models/granite_hybrid.py`:
+2,048 tokens), its `forward`, the shared mixer (`models/mamba_mixer.py`) and
+`ssd_prefill_scan` take a row piece's `cache_rows`, and with none handed in
+each traces what it traced — `granite-h` and `nemotron-h`, whose tiny chunks
+hold fewer tokens than a piece, included. The new pin is the tiny Granite
+program with the piece set to its 128-token chunk: the loop over row pieces,
+the scan kernel's third prefetched vector (its body unchanged: one
+`ssd_prefill_scan` body still), the per-row writes of keys, values and
+convolution tail are in it.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
 (`assert_pinned`). `python tests/test_one_shot_programs_pinned.py` (from the
-repo's root, `PYTHONPATH=.`) traces all thirteen, prints both tables as they
+repo's root, `PYTHONPATH=.`) traces all fourteen, prints both tables as they
 would have to read and rewrites that file — the one place that regenerates
 them."""
 from __future__ import annotations
@@ -87,10 +98,11 @@ _PINNED = {
     "deepseek-v2": (tiny_deepseek, {}, "90bc1e80a899c229"),
     "llama-row-pieces": ("tiny", {}, "87c61288db10c21a"),
     "llama-looped": ("tiny-ouro", {}, "05e5ea3227bd67de"),
+    "granite-h-row-pieces": ("tiny-granite-h", {}, "8ece4d118fa328f3"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)
-_PIECE_TOKENS = {"llama-row-pieces": 128}
+_PIECE_TOKENS = {"llama-row-pieces": 128, "granite-h-row-pieces": 128}
 
 # the slot loop's programs of the tiny llama family, "kind-rows" -> the same
 # hash: a join of 1 and of 2 rows, the segment of 4 slots, the adopt of a
@@ -234,7 +246,7 @@ def test_a_slot_program_traces_to_the_pinned_jaxpr(program):
 
 
 def regenerate() -> None:
-    """Trace all thirteen programs, print the two tables' hashes as they are now
+    """Trace all fourteen programs, print the two tables' hashes as they are now
     and rewrite the line ladders."""
     texts = [("_PINNED", family, want,
               one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw),

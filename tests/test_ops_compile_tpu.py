@@ -380,6 +380,26 @@ def test_prefill_scan_kernel_compiles_at_the_cells_shapes(one_chip):
     assert ssd_scan.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024
 
 
+@pytest.mark.parametrize("R", [1, 2])
+def test_prefill_scan_kernel_compiles_for_a_row_piece_in_place(one_chip, R):
+    """A piece of R rows of the 24 (PR 51: ``rows``, a third prefetched
+    vector steering the state's block to ``(layer, rows[b])``): the same
+    kernel at the piece's shapes, and with the stacked state donated the
+    compiled program holds no second copy of its 1.81 GB."""
+    from vnsum_tpu.ops import ssd_scan
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (*_scan_shapes(R, 2048), ((36, 24, 128, 4096), F32),
+                         ((R,), I32), ((R,), I32))]
+    c = jax.jit(
+        lambda x, dt, A, Bm, Cm, D, state, pads, rows:
+        ssd_scan.ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, 7, pads, rows,
+                                  chunk=256),
+        donate_argnums=(6,)).lower(*args).compile()
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 256 * 1024 * 1024
+
+
 def test_decode_update_kernel_compiles_in_place_at_the_cells_shapes(one_chip):
     """One token for 24 rows: the layer's 2 MiB state blocks read and
     written in place. With the stacked state donated the compiled program

@@ -5,7 +5,9 @@ nothing but left pad in that chunk is not run. On the tiny llama family, on
 the CPU, kernels interpreted (and the dense path): the same tokens, logits
 and real cache slots as the whole batch a chunk, whatever the order of the
 rows; a dead piece's slots still zero; the counter equal to the count by
-hand.
+hand. On the tiny Granite-4.0-H family (one period: nine Mamba layers and
+an attention layer; all four kernels interpreted, and the XLA forms) the
+same, and every row's recurrent state and convolution tail beside them.
 
 The pad patterns are the benchmark's, a sixteenth the size (bucket 512 in
 chunks of 128 for 8,192 in chunks of 2,048): the served mix's seven joins
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 
 from vnsum_tpu.backend.engine import TpuBackend
 from vnsum_tpu.models import jitted_init
+from vnsum_tpu.models import granite_hybrid
 from vnsum_tpu.models.llama import init_params, tiny_llama
 from vnsum_tpu.models.quant import quantize_params
 
@@ -79,11 +82,10 @@ def _engine(cfg, params, piece_tokens, **kw):
     return be
 
 
-@pytest.fixture(scope="module")
-def engines(cfg, params):
-    """{tokens a piece (None = whole batch): engine}, and their jitted
-    prefills by batch, built once."""
-    made = {p: _engine(cfg, params, p) for p in (None, CHUNK, 2 * CHUNK)}
+def _prefills(made):
+    """The jitted prefills of ``made`` {tokens a piece: engine} by
+    (tokens a piece, batch), built once: last-position logits, the state,
+    the first sampled token and which rows were done before decoding."""
     programs = {}
 
     def prefill(piece_tokens, B):
@@ -103,7 +105,28 @@ def engines(cfg, params):
             programs[key] = jax.jit(program)
         return programs[key]
 
-    return made, prefill
+    return prefill
+
+
+@pytest.fixture(scope="module")
+def engines(cfg, params):
+    """{family-path: ({tokens a piece (None = whole batch): engine}, their
+    prefills, the weights)}: the dense family with its kernels interpreted,
+    the state-space hybrid with its kernels interpreted and with the XLA
+    forms of attention and scan."""
+    pieces = (None, CHUNK, 2 * CHUNK)
+    hybrid_cfg = granite_hybrid.tiny_granite_h(n_layers=10,
+                                               max_seq_len=S + 128)
+    hybrid = quantize_params(
+        jitted_init(granite_hybrid.init_params, hybrid_cfg, 0))
+    out = {}
+    for name, (c, p, kw) in {
+            "llama": (cfg, params, {}),
+            "granite-h": (hybrid_cfg, hybrid, {}),
+            "granite-h-xla": (hybrid_cfg, hybrid, dict(flash=False))}.items():
+        made = {n: _engine(c, p, n, **kw) for n in pieces}
+        out[name] = (made, _prefills(made), p)
+    return out
 
 
 def _batch(lens, rng):
@@ -116,9 +139,19 @@ def _batch(lens, rng):
     return tokens, np.asarray([S - L for L in lens], np.int32)
 
 
-@pytest.mark.parametrize("name", list(_PATTERNS))
-def test_pieces_give_what_the_whole_batch_gives(engines, params, name):
-    made, prefill = engines
+# the patterns the state-space hybrid runs: the offline group with its four
+# tails (and shuffled), no pad at all, filler rows, two rows a piece
+_HYBRID_PATTERNS = ("offline", "offline-shuffled", "all-live", "filler-row",
+                    "two-fillers-first", "offline-pairs", "shuffled-pairs")
+_KV_LEAVES = ("k", "v", "ks", "vs")
+
+
+@pytest.mark.parametrize("family, name", [
+    *(("llama", n) for n in _PATTERNS),
+    *((f, n) for f in ("granite-h", "granite-h-xla")
+      for n in _HYBRID_PATTERNS)])
+def test_pieces_give_what_the_whole_batch_gives(engines, family, name):
+    made, prefill, params = engines[family]
     lens, piece_tokens = _PATTERNS[name]
     tokens, pads = _batch(lens, np.random.default_rng(len(name)))
     B, R = len(lens), piece_tokens // CHUNK
@@ -139,27 +172,45 @@ def test_pieces_give_what_the_whole_batch_gives(engines, params, name):
 
     # (b) the cache: a row's real slots equal; the slots of a (row, chunk)
     # piece that was not run still zero
+    kv = [leaf for leaf in cache if leaf in _KV_LEAVES]
     dead_by_hand = 0
     for row, pad in enumerate(pads):
-        for leaf in cache:
+        for leaf in kv:
             np.testing.assert_allclose(
                 np.asarray(cache[leaf][:, row, :, pad:S], np.float32),
                 np.asarray(cache_w[leaf][:, row, :, pad:S], np.float32),
                 rtol=1e-4, atol=1e-4, err_msg=f"{leaf} row {row}")
     # the pieces take the rows longest pad first, R at a time
     by_pad = np.argsort(-pads, kind="stable")
+    never_run = set(range(B))
     for lo in range(0, S, CHUNK):
         dead_rows = int((pads >= lo + CHUNK).sum())
         skipped = by_pad[: dead_rows // R * R]
         dead_by_hand += len(skipped)
+        never_run &= set(skipped.tolist())
         for row in skipped:
-            for leaf in cache:
+            for leaf in kv:
                 assert not cache[leaf][:, row, :, lo:lo + CHUNK].any(), (
                     leaf, row, lo)
                 # ... which the whole batch filled with the pad token's
                 assert cache_w[leaf][:, row, :, lo:lo + CHUNK].any()
     if R == 1:
         assert dead_by_hand == sum((S - L * S // 8192) // CHUNK for L in lens)
+
+    # (b') the state that is not keys and values: every Mamba layer's
+    # recurrent state and convolution tail of every row as the whole batch
+    # left them; a row no piece ran (and any filler row: the pad rule)
+    # still holds the zeros it came with
+    for leaf in set(cache) - set(kv):
+        assert leaf in ("ssm", "conv") and cache[leaf][:, real].any()
+        np.testing.assert_allclose(
+            np.asarray(cache[leaf], np.float32),
+            np.asarray(cache_w[leaf], np.float32), rtol=1e-4, atol=1e-5,
+            err_msg=leaf)
+        for row in sorted(never_run | set(np.flatnonzero(~real).tolist())):
+            assert not cache[leaf][:, row].any(), (leaf, row)
+    assert never_run <= set(np.flatnonzero(~real).tolist())
+    assert (set(cache) - set(kv) == {"ssm", "conv"}) == (family != "llama")
 
     # (c) the counter, by hand; the whole batch a chunk counts none dead
     for be, dead in ((made[piece_tokens], dead_by_hand), (made[None], 0)):
@@ -277,18 +328,22 @@ def test_a_mesh_that_spreads_the_rows_keeps_the_whole_batch(cfg, params):
     assert _engine(cfg, params, None)._prefill_piece_rows(8, CHUNK) == 0
 
 
-def test_only_the_dense_family_names_a_piece():
+def test_which_families_name_a_piece():
+    """The dense family and the state-space hybrid name 2,048 tokens, one
+    row of the cells' prefill chunk; the four others leave None and run
+    the whole batch a chunk until each has its own measurement."""
     from vnsum_tpu.models import MODEL_REGISTRY
     from vnsum_tpu.models.deepseek import tiny_deepseek
     from vnsum_tpu.models.family import family_of
 
     named = {name: family_of(make()).prefill_piece_tokens for name, make in (
         ("llama", MODEL_REGISTRY["tiny"]),
+        ("ouro", MODEL_REGISTRY["tiny-ouro"]),
         ("smallthinker", MODEL_REGISTRY["tiny-smallthinker"]),
         ("laguna", MODEL_REGISTRY["tiny-laguna"]),
         ("granite-h", MODEL_REGISTRY["tiny-granite-h"]),
         ("nemotron-h", MODEL_REGISTRY["tiny-nemotron-h"]),
         ("deepseek-v2", tiny_deepseek))}
-    assert named == {"llama": 2048, "smallthinker": None, "laguna": None,
-                     "granite-h": None, "nemotron-h": None,
+    assert named == {"llama": 2048, "ouro": 2048, "smallthinker": None,
+                     "laguna": None, "granite-h": 2048, "nemotron-h": None,
                      "deepseek-v2": None}

@@ -251,7 +251,8 @@ def init_cache(cfg: GraniteHybridConfig, batch: int, cache_len: int, *,
 
 
 def _attention_mixer(h, lp, slot, mask, cache, write_index,
-                     cfg: GraniteHybridConfig, stacked_attention_fn):
+                     cfg: GraniteHybridConfig, stacked_attention_fn,
+                     cache_rows=None):
     aq = cfg.w8a8_prefill and h.shape[1] > 1
     with jax.named_scope("qkv"):
         # the kernels and the dense path divide by sqrt(hd); this family's
@@ -260,9 +261,9 @@ def _attention_mixer(h, lp, slot, mask, cache, write_index,
             cfg.attention_multiplier * cfg.head_dim ** 0.5, h.dtype)
         k = _proj("bsd,dhk->bshk", h, lp["wk"], aq)
         v = _proj("bsd,dhk->bshk", h, lp["wv"], aq)
-    cache = _write_kv(cache, k, v, slot, write_index)
+    cache = _write_kv(cache, k, v, slot, write_index, cache_rows)
     attn = _cache_attention(q, cache, slot, mask, cfg.q_per_kv, None,
-                            stacked_attention_fn)
+                            stacked_attention_fn, cache_rows)
     with jax.named_scope("attn_out"):
         return _proj("bshk,hkd->bsd", attn, lp["wo"], aq), cache
 
@@ -292,7 +293,7 @@ def _runs(kinds: tuple) -> list:
 def forward(params: dict, cfg: GraniteHybridConfig, tokens, positions, cache,
             write_index, mask, *, last_only: bool = False,
             stacked_attention_fn=None, scan_kernels: bool = False,
-            interpret: bool = False):
+            interpret: bool = False, cache_rows=None):
     """Run the decoder over ``tokens`` [B, S] written at cache slots
     ``write_index ..``; returns (logits [B, S, vocab] float32, state).
 
@@ -302,7 +303,17 @@ def forward(params: dict, cfg: GraniteHybridConfig, tokens, positions, cache,
     XLA attention under ``mask`` [B, S, C]. ``scan_kernels`` runs the
     recurrence through ``ops/ssd_scan.py``'s kernels (``interpret``: on the
     CPU), else through their XLA forms. The scan stands where the state
-    says: a prefill chunk continues the one before it."""
+    says: a prefill chunk continues the one before it.
+
+    ``cache_rows`` [B] int32: the tokens are a row piece of a batch whose
+    state holds more rows (the engine's prefill, ``Family.
+    prefill_piece_tokens``) and row b of them lives at the state's batch
+    row ``cache_rows[b]`` — keys, values, convolution tail and recurrent
+    state written and read there in place, the state's other rows left as
+    they are. A (row, chunk) piece that is all left pad need not run: under
+    the pad the mixer's input is zeroed, ``in_proj`` has no bias and
+    ``xBC`` is zeroed after the convolution, so tail and state stay the
+    zeros they came as."""
     del positions
     res = cfg.residual_multiplier
     with jax.named_scope("embed"):
@@ -315,13 +326,13 @@ def forward(params: dict, cfg: GraniteHybridConfig, tokens, positions, cache,
         h = _rmsnorm(x, lp["mixer_norm"], cfg.norm_eps)
         h = jnp.where(valid[..., None], h, jnp.zeros_like(h))
         out, cache = mamba_mixer(h, lp, slot, valid, cache, cfg,
-                                  scan_kernels, interpret)
+                                  scan_kernels, interpret, cache_rows)
         return _ffn(x + out * jnp.asarray(res, x.dtype), ffn_lp, cfg), cache
 
     def attention_layer(x, cache, lp, ffn_lp, slot):
         h = _rmsnorm(x, lp["mixer_norm"], cfg.norm_eps)
         out, cache = _attention_mixer(h, lp, slot, mask, cache, write_index,
-                                      cfg, stacked_attention_fn)
+                                      cfg, stacked_attention_fn, cache_rows)
         return _ffn(x + out * jnp.asarray(res, x.dtype), ffn_lp, cfg), cache
 
     period = cfg.period
@@ -404,6 +415,8 @@ def _family():
         decode_attention=_decode_attention, counts_prefill_blocks=True,
         attention_layers=lambda cfg: cfg.n_attention,
         prefill_counts=prefill_counts,
+        # one row of a 2,048-token chunk a piece (PERF.md section 6, PR 51)
+        prefill_piece_tokens=2048,
         forward_kwargs=_forward_kwargs, row_record=last_state,
         missing={
             "slot loop": (

@@ -35,7 +35,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .llama import _proj
+from .llama import _cache_write, _proj
 
 
 def d_inner(cfg) -> int:
@@ -121,10 +121,15 @@ def causal_conv(xbc, tail, w, b):
 
 
 def mamba_mixer(h, lp, slot, valid, cache, cfg, scan_kernels: bool,
-                interpret: bool):
+                interpret: bool, cache_rows=None):
     """The Mamba-2 mixer over h [B, S, D] (normed, zero under the pad) at
-    Mamba slot ``slot`` of the state. The ``jax.named_scope`` names are
-    metadata a device trace is read by (README "Device time by layer")."""
+    Mamba slot ``slot`` of the state. ``cache_rows`` [B] int32: h is a row
+    piece of a batch whose state holds more rows (the engine's prefill,
+    ``Family.prefill_piece_tokens``), and row b's convolution tail and
+    recurrent state live at the state's batch row ``cache_rows[b]`` — read
+    and written there in place, no slice of ``ssm`` made. The
+    ``jax.named_scope`` names are metadata a device trace is read by
+    (README "Device time by layer")."""
     B, S, _ = h.shape
     H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
     G, inner = cfg.mamba_n_groups, d_inner(cfg)
@@ -138,12 +143,15 @@ def mamba_mixer(h, lp, slot, valid, cache, cfg, scan_kernels: bool,
             + lp["dt_bias"])
     with jax.named_scope("conv"):
         tail = jax.lax.dynamic_index_in_dim(cache["conv"], slot, 0, False)
+        if cache_rows is not None:
+            tail = tail[cache_rows]
         xbc, tail = causal_conv(xbc, tail, lp["conv_w"], lp["conv_b"])
         # the bias would leak through a pad position
         xbc = jnp.where(valid[..., None], xbc, 0.0).astype(h.dtype)
-        conv = jax.lax.dynamic_update_slice(
-            cache["conv"], tail.astype(cache["conv"].dtype)[None],
-            (slot, 0, 0, 0))
+        # the whole batch's tails in one update, a piece's a row at a time
+        # at its own batch row: the KV cache's write, on [layers, B, 3, C]
+        conv = _cache_write(cache["conv"], tail.astype(cache["conv"].dtype),
+                            slot, 0, cache_rows)
     with jax.named_scope("ssd"):
         x = xbc[..., :inner].reshape(B, S, H, P)
         Bm = xbc[..., inner:inner + G * N].reshape(B, S, G, N)
@@ -164,7 +172,7 @@ def mamba_mixer(h, lp, slot, valid, cache, cfg, scan_kernels: bool,
                 # left padding: a row's pads are its first positions
                 pads = S - jnp.sum(valid, axis=-1, dtype=jnp.int32)
                 y, ssm = ssd_scan.ssd_prefill_scan(
-                    x, dt, A, Bm, Cm, lp["D"], ssm, slot, pads,
+                    x, dt, A, Bm, Cm, lp["D"], ssm, slot, pads, cache_rows,
                     chunk=cfg.mamba_chunk_size, interpret=interpret)
         else:
             state = jax.lax.dynamic_index_in_dim(ssm, slot, 0, False).astype(
@@ -175,7 +183,8 @@ def mamba_mixer(h, lp, slot, valid, cache, cfg, scan_kernels: bool,
                 y = y[:, None]
             else:
                 y, state = ssd_scan.ssd_chunked_xla(
-                    x, dt, A, Bm, Cm, lp["D"], state, cfg.mamba_chunk_size)
+                    x, dt, A, Bm, Cm, lp["D"], state, cfg.mamba_chunk_size,
+                    cache_rows)
             ssm = jax.lax.dynamic_update_slice(
                 ssm, state.astype(ssm.dtype)[None], (slot, 0, 0, 0))
     with jax.named_scope("ssm_out"):

@@ -27,7 +27,10 @@ product and no transpose, and every matrix product of the prefill kernel
 is a plain ``[m, k] @ [k, n]``. Both kernels take the whole stacked state
 with the layer's index as a prefetched scalar and write the layer's block
 back **in place** (``input_output_aliases``): the decode loop's carry does
-not copy it.
+not copy it. The prefill kernel also takes a ROW PIECE: ``rows`` names, for
+each row of its inputs, the batch row of the state it continues (a third
+prefetched vector, which steers the state's index_map alone), and the
+state's other rows stay as they are.
 
 **The chunked form** (``ssd_prefill_scan``; ``chunk`` = the config's
 ``mamba_chunk_size``) is how the recurrence is computed, not another model.
@@ -84,11 +87,17 @@ def _by_group(a: jax.Array, ndim: int) -> jax.Array:
     return a if a.ndim == ndim else a[..., None, :]
 
 
-def ssd_chunked_xla(x, dt, A, Bm, Cm, D, state, chunk: int):
+def ssd_chunked_xla(x, dt, A, Bm, Cm, D, state, chunk: int, rows=None):
     """The chunked scan in plain XLA: x [B, S, H, P], dt [B, S, H] float32
     (through its softplus), A, D [H], Bm, Cm [B, S, G, N] (or [B, S, N]: one
     group), state [B, N, H * P] float32 -> (y [B, S, H, P] in x's type, the
-    state after the S tokens). S is padded at its END to whole chunks."""
+    state after the S tokens). S is padded at its END to whole chunks.
+    ``rows`` [B] int32: x's rows are a piece of a ``state`` that holds more
+    of them, row b of x continuing ``state[rows[b]]``; those rows of the
+    state are returned rewritten, its others as they came."""
+    if rows is not None:
+        y, piece = ssd_chunked_xla(x, dt, A, Bm, Cm, D, state[rows], chunk)
+        return y, state.at[rows].set(piece)
     Bt, S, H, P = x.shape
     Bm, Cm = _by_group(Bm, 4), _by_group(Cm, 4)
     G, N = Bm.shape[-2:]
@@ -262,15 +271,27 @@ def _prefill_kernel(lidx_ref, pad_ref, x_ref, dtc_ref, cumc_ref, cumr_ref,
         hout_ref[0, 0] = h_scr[...]
 
 
+def _prefill_kernel_of_rows(lidx_ref, pad_ref, rows_ref, *refs, **geometry):
+    """``_prefill_kernel`` under a third prefetched vector (``rows``), which
+    only the state's index_map reads."""
+    del rows_ref
+    _prefill_kernel(lidx_ref, pad_ref, *refs, **geometry)
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
-                     chunk: int, interpret: bool = False):
+def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens,
+                     rows=None, *, chunk: int, interpret: bool = False):
     """The chunked scan over S tokens from layer ``layer_idx``'s state of
     the stacked ``state`` [L, B, N, H * P] float32; x [B, S, H, P], dt
     [B, S, H] float32, Bm, Cm [B, S, G, N] (or [B, S, N]: one group),
     ``pad_lens`` [B] the left-pad slots among these S (whole chunks of them
     are skipped). Returns (y [B, S, H, P], the stacked state with the
-    layer's block overwritten in place). Semantics: ``ssd_chunked_xla``."""
+    layer's block overwritten in place). Semantics: ``ssd_chunked_xla``.
+
+    ``rows`` [B] int32 (distinct): x's rows are a piece of a state that
+    holds more of them, and row b continues — and overwrites, in place —
+    the state's batch row ``rows[b]``; no other row of the state is
+    fetched or written."""
     Bt, S, H, P = x.shape
     Bm, Cm = _by_group(Bm, 4), _by_group(Cm, 4)
     G, N = Bm.shape[-2:]
@@ -297,25 +318,30 @@ def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
     Bt_rows = Bm.transpose(0, 2, 1)
     d_lanes = jnp.repeat(D.astype(f32), P)[None, :]          # [1, HP]
 
-    def first_live(b, c, lidx, pad):
+    # a third prefetched vector, where the state's rows are named: it steers
+    # the state's index_map alone, and the kernel's body never sees it
+    prefetch = 2 if rows is None else 3
+
+    def first_live(b, c, pad):
         # a pad chunk parks on the row's first live one: no fetch of its own
         return jnp.minimum(jnp.maximum(c, pad[b] // chunk), nc - 1)
 
     seq_block = lambda width: pl.BlockSpec(  # noqa: E731
         (1, chunk, width),
-        lambda b, c, lidx, pad: (b, first_live(b, c, lidx, pad), 0))
-    row_block = lambda rows: pl.BlockSpec(  # noqa: E731
-        (1, rows, chunk),
-        lambda b, c, lidx, pad: (b, 0, first_live(b, c, lidx, pad)))
+        lambda b, c, lidx, pad, *rows: (b, first_live(b, c, pad), 0))
+    row_block = lambda height: pl.BlockSpec(  # noqa: E731
+        (1, height, chunk),
+        lambda b, c, lidx, pad, *rows: (b, 0, first_live(b, c, pad)))
     state_block = pl.BlockSpec(
-        (1, 1, N, HP), lambda b, c, lidx, pad: (lidx[0], b, 0, 0))
+        (1, 1, N, HP), lambda b, c, lidx, pad, *rows: (
+            lidx[0], rows[0][b] if rows else b, 0, 0))
     kernel = functools.partial(
-        _prefill_kernel, chunk=chunk, n_heads=H, head_dim=P, hpt=hpt,
-        n_groups=G)
+        _prefill_kernel if rows is None else _prefill_kernel_of_rows,
+        chunk=chunk, n_heads=H, head_dim=P, hpt=hpt, n_groups=G)
     y, state = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=prefetch,
             grid=(Bt, nc),
             in_specs=[
                 seq_block(HP),      # x
@@ -324,12 +350,12 @@ def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
                 row_block(H),       # cum by rows
                 row_block(G * N),   # B^T, group by group
                 seq_block(G * N),   # C, group by group
-                pl.BlockSpec((1, HP), lambda b, c, lidx, pad: (0, 0)),
+                pl.BlockSpec((1, HP), lambda b, c, *prefetched: (0, 0)),
                 state_block,
             ],
             out_specs=[
                 pl.BlockSpec((1, chunk, HP),
-                             lambda b, c, lidx, pad: (b, c, 0)),
+                             lambda b, c, *prefetched: (b, c, 0)),
                 state_block,
             ],
             scratch_shapes=[pltpu.VMEM((N, HP), f32)],
@@ -338,8 +364,9 @@ def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
             jax.ShapeDtypeStruct((Bt, Sp, HP), x.dtype),
             jax.ShapeDtypeStruct(state.shape, f32),
         ],
-        # operand 9 of the call (two prefetched scalars first) is the state
-        input_output_aliases={9: 1},
+        # the call's last operand, after the prefetched scalars and the seven
+        # blocks before it, is the state
+        input_output_aliases={prefetch + 7: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
@@ -350,6 +377,7 @@ def ssd_prefill_scan(x, dt, A, Bm, Cm, D, state, layer_idx, pad_lens, *,
     )(
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
         pad_lens.astype(jnp.int32),
+        *(() if rows is None else (rows.astype(jnp.int32),)),
         x.reshape(Bt, Sp, HP), dt, cum, cum_rows, Bt_rows, Cm, d_lanes,
         state,
     )
