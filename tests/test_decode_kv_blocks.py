@@ -245,6 +245,7 @@ def test_a_segment_counts_its_rows_blocks_and_notes_them_on_its_span(
     t_end = np.asarray(loop._t_host)
     steps = int(t_end.max())
     assert 1 <= steps <= 8 and res.new_tokens == int(t_end.sum())
+    assert res.steps == steps       # what the segment's pace is counted in
     pads = [1024 - n for n in lens] + [1024]
     skipped = total = 0
     for i in range(steps):
@@ -259,3 +260,20 @@ def test_a_segment_counts_its_rows_blocks_and_notes_them_on_its_span(
     assert said == [
         "slot loop closed after 1 segments and 3 joined rows: "
         f"skipped_kv_blocks {skipped}/{total}"]
+    # the engine keeps the count by program, every loop of the shape in
+    # one; a second loop's line still says what that loop added, no more
+    again = be.start_slot_loop(4, max_new_tokens=8, prompt_tokens=1024)
+    again.admit([(0, _prompts([1000])[0], None)])
+    again.step()
+    del said[:]
+    log.addHandler(handler)
+    try:
+        again.close()
+    finally:
+        log.removeHandler(handler)
+    ex = be.stats.executions[("segment", 4, 1024)]
+    assert ex.count == 2 and ex.kv_blocks > total
+    assert said == [
+        "slot loop closed after 1 segments and 1 joined rows: "
+        f"skipped_kv_blocks {ex.kv_blocks_skipped - skipped}"
+        f"/{ex.kv_blocks - total}"]

@@ -219,14 +219,16 @@ def test_enqueue_and_wait_lie_inside_their_dispatch(engine):
     assert len(lines) == 1
     assert "enqueue" in lines[0] and "wait" in lines[0] \
         and "detokenize" in lines[0] and "rows=3" in lines[0]
-    # and per (B, S) the count, total and max of each side
-    (bucket,) = [k for k in engine.stats.dispatch_by_bucket
-                 if engine.stats.by_bucket.get(k)]
-    sides = engine.stats.dispatch_by_bucket[bucket]
-    assert set(sides) == {"enqueue", "wait"}
-    assert sides["enqueue"].count == sides["wait"].count \
-        == engine.stats.by_bucket[bucket]
-    assert sides["wait"].max_s <= sides["wait"].total_s
+    # and per (program, B, S) the count, total and max of each side
+    (key,) = [k for k in engine.stats.executions
+              if engine.stats.by_bucket.get(k[1:])]
+    ex = engine.stats.executions[key]
+    assert key[0] == "generate"
+    assert ex.enqueue.count == ex.wait.count == ex.count \
+        == engine.stats.by_bucket[key[1:]]
+    assert ex.wait.max_s <= ex.wait.total_s
+    assert ex.blocked.total_s == pytest.approx(
+        ex.enqueue.total_s + ex.wait.total_s)
 
 
 def test_no_span_name_carries_a_shape_or_a_number(engine):
@@ -245,6 +247,9 @@ def test_what_the_primitive_replaced_is_gone(engine):
     assert not hasattr(profiling, "annotate") and not hasattr(core, "annotate")
     assert not hasattr(engine.stats, "phase_seconds")
     assert not hasattr(engine.stats, "add_phase")
+    # PR 52: one account of executions in place of the two sides a bucket
+    assert not hasattr(engine.stats, "dispatch_by_bucket")
+    assert not hasattr(engine.stats, "note_dispatch")
 
 
 def test_the_collector_still_sees_the_one_shot_names(engine):

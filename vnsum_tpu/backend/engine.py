@@ -26,7 +26,7 @@ from __future__ import annotations
 import contextlib
 import gc
 import time
-from collections import Counter
+from collections import Counter, deque
 from dataclasses import dataclass, field
 
 import jax
@@ -45,7 +45,13 @@ from .base import (
     terminator_ids,
     trim_to_eos,
 )
-from ..core.profiling import SpanStats, host_span
+from ..core.profiling import (
+    SpanStats,
+    execution_span,
+    host_span,
+    snapshot_delta,
+)
+from ..obs.trace import emit
 from ..testing.faults import fault
 from ..models.family import family_of
 from ..models.llama import (
@@ -90,6 +96,81 @@ def _stream_keys(base, uids):
             streams, t)
 
     return at
+
+
+# an execution is held where it took this many times its shape's pace, and
+# that many seconds more than it: the factor sits between the widest step
+# two clean dispatches of one bucket took (7.46 s with tails, then 8.711 s
+# all live: x1.17) and the narrowest stall on record (10.778 s on 8.711:
+# x1.24); the floor keeps a millisecond program's jitter and a short
+# segment's boundary fetch out (tests/test_execution_account.py has the table)
+HELD_FACTOR = 1.2
+HELD_FLOOR_S = 0.1
+HELD_KEPT = 64
+
+
+@dataclass
+class ExecutionStats:
+    """One (program, B, S)'s executions: how many, how long the host was
+    blocked on them, the work they carried, and the pace they set."""
+
+    count: int = 0
+    # a program's first call (trace, lowering, compile or the cache's load:
+    # ``_timed_first_call`` times it and lists the program as warm after
+    # it): counted and timed with the rest, but it sets no pace, takes no
+    # snapshot and is never held
+    first_calls: int = 0
+    # the host's blocked seconds an execution: for ``generate`` the two
+    # sides of the device queue — ``enqueue`` is the program's call alone
+    # (arguments transferred, output buffers allocated, program queued),
+    # ``wait`` the result fetch (execution and the copy back) — and their
+    # sum; for a slot program the one span that brackets it
+    blocked: SpanStats = field(default_factory=SpanStats)
+    enqueue: SpanStats | None = None
+    wait: SpanStats | None = None
+    # the work the executions carried, as the program counts it on the host
+    rows: int = 0
+    pieces_live: int = 0
+    pieces_dead: int = 0
+    steps: int = 0
+    kv_blocks: int = 0
+    kv_blocks_skipped: int = 0
+    # the largest blocked seconds of an earlier warm execution, per unit of
+    # the work that tells this shape's executions apart (a join's live
+    # pieces, a segment's steps; 1 elsewhere), and the most of each kind of
+    # work an earlier warm execution carried: one that carries more of any
+    # is not judged, it sets the pace (a later chunk attends to more keys,
+    # so a long row's piece costs more than a short row's); one that carries
+    # under half the most units seen is judged and sets none (a segment of
+    # one step is mostly its boundary fetch)
+    pace_s: float = 0.0
+    pace_units: int = 0
+    pace_work: tuple | None = None
+    # the longest warm enqueue and wait, to say which side a held one grew on
+    pace_sides: tuple = (0.0, 0.0)
+    held: int = 0
+    held_excess_s: float = 0.0
+
+    def to_dict(self) -> dict:
+        out = {
+            "count": self.count, "first_calls": self.first_calls,
+            "blocked": self.blocked.to_dict(),
+            "rows": self.rows, "pieces_live": self.pieces_live,
+            "pieces_dead": self.pieces_dead, "steps": self.steps,
+            "kv_blocks": self.kv_blocks,
+            "kv_blocks_skipped": self.kv_blocks_skipped,
+            "pace_s": self.pace_s, "held": self.held,
+            "held_excess_s": self.held_excess_s,
+        }
+        if self.enqueue is not None:
+            out["enqueue"] = self.enqueue.to_dict()
+            out["wait"] = self.wait.to_dict()
+        return out
+
+
+def execution_key(key: tuple) -> str:
+    """``("generate", 8, 8192)`` as a record spells it: generate[B=8,S=8192]."""
+    return f"{key[0]}[B={key[1]},S={key[2]}]"
 
 
 @dataclass
@@ -164,12 +245,20 @@ class EngineStats:
     # always on): {"engine/<name>": SpanStats}. The names are a contract
     # (README, "Device time by layer"); none carries a shape
     host_spans: dict = field(default_factory=dict)
-    # per dispatch shape, the two sides of the device queue:
-    # {(B, S): {"enqueue": SpanStats, "wait": SpanStats}} — ``enqueue`` is
-    # the program's call alone (arguments transferred, output buffers
-    # allocated, program queued), ``wait`` the result fetch (execution and
-    # the copy back). A call that stalls says here on which side it did
-    dispatch_by_bucket: dict = field(default_factory=dict)
+    # every device execution of the four programs the engine's two loops
+    # run, by {(program, B, S): ExecutionStats} with ``program`` one of
+    # ``generate``, ``slot_prefill``, ``adopt``, ``segment``
+    # (``note_execution``): count, blocked seconds — for ``generate`` by
+    # side of the device queue, so a call that stalls says on which side it
+    # did —, the work carried and the pace
+    executions: dict = field(default_factory=dict)
+    # executions held past their shape's pace: how many, by how many
+    # seconds in all, and the last ``HELD_KEPT`` of them whole (program,
+    # shape, blocked, pace, side and what the host was doing meanwhile:
+    # ``core.profiling.snapshot_delta``; PERF.md section 7 (u) reads one)
+    executions_held: int = 0
+    held_excess_seconds: float = 0.0
+    held: deque = field(default_factory=lambda: deque(maxlen=HELD_KEPT))
     # sparse-expert families (models/experts.py), summed on the device and
     # returned with each one-shot program's output: token x pick pairs the
     # router saw, those that fell on an expert held here, and tokens per
@@ -187,14 +276,94 @@ class EngineStats:
     expert_decode_tiles_used: int = 0
     expert_decode_tiles_walked: int = 0
 
-    def note_dispatch(self, B: int, S: int, enqueue_s: float,
-                      wait_s: float) -> None:
-        sides = self.dispatch_by_bucket.get((B, S))
-        if sides is None:
-            sides = self.dispatch_by_bucket[(B, S)] = {
-                "enqueue": SpanStats(), "wait": SpanStats()}
-        sides["enqueue"].add(enqueue_s)
-        sides["wait"].add(wait_s)
+    def note_execution(self, program: str, B: int, S: int, span,
+                       closing=None, *, first: bool = False,
+                       units: int = 1, rows: int = 0,
+                       pieces: tuple[int, int] = (0, 0), steps: int = 0,
+                       kv_blocks: tuple[int, int] = (0, 0)) -> None:
+        """Book one execution under (program, B, S). ``span`` is the
+        ``execution_span`` that bracketed it — or its first part (the
+        enqueue), with ``closing`` the last (the wait): their ``dur`` are
+        the blocked seconds, no clock is read here. ``first`` says that
+        this was the program's first call: the caller looks the program up
+        in ``TpuBackend._warm`` ahead of the call (``_timed_first_call``), so
+        a program the account does not cover moves nothing here. ``pieces``
+        is (dead, total) and ``kv_blocks`` (skipped, total), as the counting
+        methods return them;
+        ``units`` is how much of the work that tells this shape's
+        executions apart it carried. Where the execution took more than
+        ``HELD_FACTOR`` times its pace it is held: one WARNING line, one
+        entry of ``held``, one ``held`` event to the obs collector."""
+        ex = self.executions.get((program, B, S))
+        if ex is None:
+            ex = self.executions[(program, B, S)] = ExecutionStats()
+            if closing is not None:
+                ex.enqueue, ex.wait = SpanStats(), SpanStats()
+        blocked = span.dur + closing.dur if closing is not None else span.dur
+        ex.count += 1
+        ex.blocked.add(blocked)
+        if closing is not None:
+            ex.enqueue.add(span.dur)
+            ex.wait.add(closing.dur)
+        ex.rows += rows
+        ex.pieces_live += pieces[1] - pieces[0]
+        ex.pieces_dead += pieces[0]
+        ex.steps += steps
+        ex.kv_blocks += kv_blocks[1]
+        ex.kv_blocks_skipped += kv_blocks[0]
+        if first:
+            ex.first_calls += 1
+            return
+        units = max(units, 1)
+        work = (pieces[1] - pieces[0], steps)
+        judged = ex.pace_work is not None and all(
+            w <= m for w, m in zip(work, ex.pace_work))
+        pace = ex.pace_s * units
+        if (judged and blocked > HELD_FACTOR * pace
+                and blocked - pace > HELD_FLOOR_S):
+            self._note_held(ex, program, B, S, span, closing, blocked, pace)
+            # a held execution moves the pace by the factor at most: one
+            # stall does not hide the next, and a program that has become
+            # slower for good is held a few times, not for ever
+            blocked = HELD_FACTOR * pace
+        elif closing is not None:
+            ex.pace_sides = (max(ex.pace_sides[0], span.dur),
+                             max(ex.pace_sides[1], closing.dur))
+        if 2 * units >= ex.pace_units:
+            ex.pace_s = max(ex.pace_s, blocked / units)
+            ex.pace_units = max(ex.pace_units, units)
+        ex.pace_work = tuple(map(max, work, ex.pace_work or work))
+
+    def _note_held(self, ex: ExecutionStats, program: str, B: int, S: int,
+                   span, closing, blocked: float, pace: float) -> None:
+        excess = blocked - pace
+        ex.held += 1
+        ex.held_excess_s += excess
+        self.executions_held += 1
+        self.held_excess_seconds += excess
+        # of generate's two sides, the one further past its longest warm
+        last = closing or span
+        side = last.full
+        if closing is not None and (span.dur - ex.pace_sides[0]
+                                    > closing.dur - ex.pace_sides[1]):
+            side = span.full
+        doing = (snapshot_delta(span.before, last.after)
+                 if span.before is not None and last.after is not None
+                 else {})
+        entry = {
+            "program": program, "B": B, "S": S, "t0": span.t0,
+            "blocked_s": blocked, "pace_s": pace, "excess_s": excess,
+            "side": side, **doing,
+        }
+        self.held.append(entry)
+        logger.warning(
+            "execution held: %s B=%d S=%d blocked %.3fs on a pace of %.3fs "
+            "(excess %.3fs) side %s: %s",
+            program, B, S, blocked, pace, excess, side,
+            " ".join(f"{k} {v:.3f}" if isinstance(v, float) else f"{k} {v}"
+                     for k, v in doing.items()))
+        emit("held", span.t0, last.t0 + last.dur - span.t0,
+             **{k: v for k, v in entry.items() if k != "t0"})
 
     @property
     def tokens_per_second(self) -> float:
@@ -320,6 +489,8 @@ class TpuBackend:
         self.compile_scope = contextlib.nullcontext
         self._fns: dict[tuple[int, int, int], callable] = {}
         self._seg_fns: dict = {}
+        # the programs whose first call is behind them (_timed_first_call)
+        self._warm: set = set()
         self._seed = seed
         self._dispatch = 0
         # reference-guided speculative decoding (vnsum_tpu.spec): cap on
@@ -391,7 +562,9 @@ class TpuBackend:
     def _timed_first_call(self, fn, label: str):
         """Wrap a freshly built program so the call that compiles it runs
         inside ``compile_scope`` and lands in ``stats.compile_seconds``;
-        every later call goes straight to ``fn``."""
+        every later call goes straight to ``fn``. A program whose first
+        call is behind it is in ``self._warm``: the execution account looks
+        there ahead of a call to tell a first call from a warm one."""
         compiled = False
 
         def call(*args):
@@ -402,6 +575,7 @@ class TpuBackend:
             with self.compile_scope():
                 out = fn(*args)
             compiled = True
+            self._warm.add(call)
             dt = time.time() - t0
             self.stats.compile_seconds += dt
             logger.info("first call of %s took %.1fs", label, dt)
@@ -1793,6 +1967,34 @@ class TpuBackend:
             return None
         return self.prefix_cache.stats_dict()
 
+    def engine_counters(self) -> dict:
+        """The engine's work counters and its held executions as one flat
+        dict, for /metrics (the ``engine_*`` families of serve/metrics.py):
+        read at scrape time like ``prefix_cache_stats()``, never mirrored."""
+        st = self.stats
+        return {
+            "prefill_row_chunks": st.prefill_row_chunks_total,
+            "prefill_row_chunks_dead": st.prefill_row_chunks_dead,
+            "decode_kv_blocks": st.decode_kv_blocks_total,
+            "decode_kv_blocks_skipped": st.decode_kv_blocks_skipped,
+            "executions_held": st.executions_held,
+            "held_excess_seconds": st.held_excess_seconds,
+        }
+
+    def engine_record(self) -> dict:
+        """The engine's own account of a run, for the run record
+        (``PipelineRunner``: ``tracing["engine"]``): host spans, executions
+        by ``program[B=..,S=..]``, the held ones whole, the work counters."""
+        st = self.stats
+        return {
+            "host_spans": {k: v.to_dict()
+                           for k, v in sorted(st.host_spans.items())},
+            "executions": {execution_key(k): v.to_dict()
+                           for k, v in sorted(st.executions.items())},
+            "held": list(st.held),
+            **self.engine_counters(),
+        }
+
     def take_cache_report(self) -> list[int]:
         """Per-prompt prefill tokens served from the prefix cache on the
         LAST generate call (empty when the cache was off), cleared on read —
@@ -1998,8 +2200,10 @@ class TpuBackend:
                         # the call alone: arguments transferred, output
                         # buffers allocated, program queued — it returns
                         # before the device ends
-                        with host_span("engine", "enqueue", sink,
-                                       B=B, S=S) as enqueue:
+                        warm = fn in self._warm
+                        with execution_span("engine", "enqueue", sink,
+                                            closes=False, probe=warm,
+                                            B=B, S=S) as enqueue:
                             if K:
                                 res = fn(self.params, tokens, pad_lens, seed,
                                          resume[1])
@@ -2013,8 +2217,10 @@ class TpuBackend:
                         # (execution and the copy back) — TTFT consumers
                         # treat the dispatch's end as the first-token upper
                         # bound
-                        with host_span("engine", "wait", sink,
-                                       B=B, S=S) as wait:
+                        with execution_span("engine", "wait", sink,
+                                            opens=False, probe=warm,
+                                            B=B, S=S) as wait:
+                            fault("engine.wait")
                             # lint-allow[host-sync-in-hot-path]: one-shot result fetch bounds the dispatch and feeds detok
                             out = jax.device_get(out_dev)
                         with host_span("engine", "count", sink):
@@ -2054,7 +2260,10 @@ class TpuBackend:
                             eos = tuple(gen.eos_ids)
                             for row, i in enumerate(group):
                                 results[i] = self._detok(out[row], eos)
-                    self.stats.note_dispatch(B, S, enqueue.dur, wait.dur)
+                    self.stats.note_execution(
+                        "generate", B, S, enqueue, wait, first=not warm,
+                        rows=len(group), pieces=(dead, pieces), steps=steps,
+                        kv_blocks=(skipped, blocks))
                     logger.info(
                         "dispatch B=%d S=%d rows=%d: enqueue %.3fs wait "
                         "%.3fs detokenize %.3fs of %.3fs%s",

@@ -531,6 +531,7 @@ class FakeSlotLoop:
                 self._prompts[s] = None
         self.segments += 1
         res.seconds = time.monotonic() - t0
+        res.steps = steps
         emit("decode_seg", t0, res.seconds, live=res.live, refill=True)
         return res
 

@@ -39,7 +39,7 @@ import numpy as np
 
 from ..analysis.sanitizers import hot_path_transfer_guard
 from ..core.logging import get_logger
-from ..core.profiling import host_span
+from ..core.profiling import execution_span, host_span
 from ..testing.faults import fault
 from .base import left_pad_batch, trim_to_eos
 
@@ -83,6 +83,7 @@ class SegmentResult:
     live: int = 0               # rows live at dispatch start
     new_tokens: int = 0         # tokens retired across all rows this dispatch
     seconds: float = 0.0
+    steps: int = 0              # decode steps the dispatch ran (its deepest row's)
 
 
 @dataclass
@@ -158,9 +159,11 @@ class TpuSlotLoop:
         self._admissions: dict[int, SlotAdmission] = {}
         self._t_host = np.zeros((B,), np.int64)
         # each slot's left pad as the device holds it, for the count of the
-        # key blocks a segment's steps skip; the loop's own (skipped, total)
+        # key blocks a segment's steps skip; the engine keeps that count by
+        # program (every loop of this shape), so the loop notes where it
+        # stood and its closing line says what this loop added
         self._pads_host = np.full((B,), S, np.int64)
-        self._kv_blocks = [0, 0]
+        self._kv_blocks_before = self._segment_kv_blocks()
         self._uid_next = 0
         self.segments = 0           # decode segments dispatched
         self.refills = 0
@@ -279,16 +282,17 @@ class TpuSlotLoop:
                 self._uid_next += len(take)
                 uids_np = np.zeros((Bj,), np.int32)
                 uids_np[: len(take)] = uids
-                dead, _ = b._count_row_chunks(pad_lens, self.S, K)
+                dead, pieces = b._count_row_chunks(pad_lens, self.S, K)
             prefill = b._get_seg_fn(
                 "slot_prefill", Bj, self.S, self.max_new, self.gen, K
             )
             with hot_path_transfer_guard():
                 # the collector's "prefill": its end is the joiners' TTFT
                 # anchor, bounded by the fetch below (synced)
-                with host_span("slot", "prefill", sink, B=Bj, S=self.S,
-                               occupancy=len(take), dead_row_chunks=dead,
-                               synced=True) as pre:
+                warm = prefill in b._warm
+                with execution_span("slot", "prefill", sink, probe=warm,
+                                    B=Bj, S=self.S, occupancy=len(take),
+                                    dead_row_chunks=dead, synced=True) as pre:
                     if resume:
                         first, join_cache, done0 = prefill(
                             b.params, tokens, pad_lens, self.seed, uids_np,
@@ -312,12 +316,20 @@ class TpuSlotLoop:
                     # lint-allow[host-sync-in-hot-path]: sync makes the per-joiner TTFT anchor real, one [Bj] bool fetch per admit
                     jax.device_get(done0)
                 prefill_end = pre.t0 + pre.dur
-                with host_span("slot", "adopt", sink, B=Bj):
+                # a join's pieces are what tells its executions apart: the
+                # pace is a live piece's
+                b.stats.note_execution(
+                    "slot_prefill", Bj, self.S, pre, first=not warm,
+                    units=pieces - dead, rows=len(take),
+                    pieces=(dead, pieces))
+                adopt = b._get_seg_fn(
+                    "adopt", Bj, self.S, self.max_new, self.gen
+                )
+                warm = adopt in b._warm
+                with execution_span("slot", "adopt", sink, probe=warm,
+                                    B=Bj) as adopted:
                     # lint-allow[host-sync-in-hot-path]: host list -> host array for the scatter indices, no device sync
                     slot_idx = np.asarray(free_slots[:Bj], np.int32)
-                    adopt = b._get_seg_fn(
-                        "adopt", Bj, self.S, self.max_new, self.gen
-                    )
                     (self._cache, self._cur, self._done, self._t, self._out,
                      self._pads) = adopt(
                         self._cache, self._cur, self._done, self._t,
@@ -326,6 +338,8 @@ class TpuSlotLoop:
                     )
                     self._t_host[slot_idx] = 0
                     self._pads_host[slot_idx] = pad_lens
+                b.stats.note_execution("adopt", Bj, self.S, adopted,
+                                       first=not warm, rows=Bj)
         finally:
             if matches is not None:
                 for m in matches.values():
@@ -414,8 +428,11 @@ class TpuSlotLoop:
         self._out_snap = None
         # one span a segment, call to boundary fetch (the collector's
         # "decode_seg"); nothing inside _await_retirement's poll
-        seg = host_span("slot", "segment", sink, event="decode_seg",
-                        B=self.slots, S=self.S, live=res.live, refill=True)
+        warm = seg_fn in b._warm
+        seg = execution_span("slot", "segment", sink, event="decode_seg",
+                             probe=warm, B=self.slots, S=self.S,
+                             live=res.live, refill=True)
+        skipped = blocks = 0
         with seg:
             with hot_path_transfer_guard():
                 # lint-allow[host-sync-in-hot-path]: host list -> host array for the uids argument, no device sync
@@ -446,18 +463,22 @@ class TpuSlotLoop:
                 int(t_h[s]) - int(self._t_host[s])
                 for s, k in enumerate(self._keys) if k is not None
             )
+            # the loop ran a step for every token of the row that went
+            # furthest; a row that ended stays at its last slot
+            res.steps = int((t_h - self._t_host).max())
             if b.mesh is None:  # under a mesh the segment's attention is dense
-                # the loop ran a step for every token of the row that went
-                # furthest; a row that ended stays at its last slot
-                steps = int((t_h - self._t_host).max())
                 skipped, blocks = b._count_decode_kv_blocks(
                     self._pads_host, self.S + np.minimum(
-                        self._t_host + np.arange(steps)[:, None], t_h),
+                        self._t_host + np.arange(res.steps)[:, None], t_h),
                     self.S, self.S + self.max_new)
                 seg.note(skipped_kv_blocks=skipped, kv_blocks=blocks)
-                self._kv_blocks[0] += skipped
-                self._kv_blocks[1] += blocks
         res.seconds = seg.dur
+        # a segment's steps are what tells its executions apart: the pace
+        # is a step's
+        b.stats.note_execution(
+            "segment", self.slots, self.S, seg, first=not warm,
+            units=res.steps, rows=res.live, steps=res.steps,
+            kv_blocks=(skipped, blocks))
         self._t_host[:] = t_h
         self._out_snap = out_h
         with host_span("slot", "harvest", sink, rows=len(finished)):
@@ -562,12 +583,21 @@ class TpuSlotLoop:
         """Keys still resident (the caller drains before closing)."""
         return [k for k in self._keys if k is not None]
 
+    def _segment_kv_blocks(self) -> tuple[int, int]:
+        """(skipped, walked) key blocks of this shape's segment program so
+        far, from the engine's account."""
+        ex = self.backend.stats.executions.get(
+            ("segment", self.slots, self.S))
+        return (ex.kv_blocks_skipped, ex.kv_blocks) if ex else (0, 0)
+
     def close(self) -> None:
         if not self._closed:
+            skipped, blocks = self._segment_kv_blocks()
             logger.info(
                 "slot loop closed after %d segments and %d joined rows: "
                 "skipped_kv_blocks %d/%d", self.segments, self.refills,
-                *self._kv_blocks)
+                skipped - self._kv_blocks_before[0],
+                blocks - self._kv_blocks_before[1])
         self._closed = True
         # drop the device state promptly — the resident cache is the big
         # HBM tenant, and a replacement loop allocates its own

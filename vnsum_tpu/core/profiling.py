@@ -13,6 +13,13 @@ those capabilities and makes them first-class:
   an always-on aggregate (`sink`: `{layer/name: SpanStats}`). Names are a
   contract like the kernels' `name=`: fixed strings, no shape or number in
   one; shapes, rows and indices go in the keyword arguments.
+- `execution_span(...)` — the `host_span` around one device execution (or
+  its first or last part): it also takes a `host_snapshot()` at the
+  execution's entry and at its exit — what the process and the machine were
+  doing meanwhile (`snapshot_delta`), kept only where the engine's account
+  finds the execution held past its pace (`EngineStats.note_execution`). A
+  program's first call takes none, and between executions the probe's
+  thread is parked and its collector callbacks are off the list.
 - `Tracer.span(name)` — nested wall-clock spans with aggregated statistics,
   thread-safe (strategy batches may fan out over a thread pool), persisted in
   the structured run record instead of log lines. Each is a `host_span` (so
@@ -35,8 +42,10 @@ those capabilities and makes them first-class:
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import re
+import resource
 import sys
 import threading
 import time
@@ -126,6 +135,189 @@ class host_span:
                 st = self.sink[self.full] = SpanStats()
             st.add(self.dur)
         emit(self.event, self.t0, self.dur, **self.args)
+        return False
+
+
+# -- what the host was doing during one device execution -----------------------
+
+SLEEPER_PERIOD_S = 0.05
+_TICK_S = 1.0 / os.sysconf("SC_CLK_TCK")
+
+
+class _HostProbe:
+    """The process-wide sources a snapshot reads, set up with the first
+    snapshot: the two /proc files held open (one ``pread`` at offset 0 a
+    snapshot regenerates a seq file; a file that is not there is left out),
+    a ``gc.callbacks`` pair that adds up the collector's seconds and count,
+    and a daemon thread that only sleeps ``SLEEPER_PERIOD_S`` at a time and
+    keeps the largest lateness of a wake-up — a process that was frozen, or
+    a thread that held the interpreter, shows there and nowhere else.
+
+    The pair and the thread work only while an execution is open (``arm``
+    at its entry snapshot, ``disarm`` at its exit one): between executions,
+    and through every execution that takes no snapshot — a program's first
+    call: its trace, lowering and compile — the callbacks are off the
+    collector's list and the thread is parked on an event, so the process
+    runs no code of the probe there."""
+
+    def __init__(self) -> None:
+        self.stat_fd = self._open("/proc/stat")
+        self.pressure_fd = self._open("/proc/pressure/cpu")
+        self.gc_seconds = 0.0
+        self.gc_count = 0
+        self._gc_t0 = 0.0
+        self.late_max = 0.0
+        self.asleep_since = 0.0
+        self.pid = os.getpid()
+        self._lock = threading.Lock()
+        self.open = 0               # executions open now
+        self._awake = threading.Event()
+        self.sleeper = threading.Thread(
+            target=self._sleep, name="vnsum-host-probe", daemon=True)
+        self.sleeper.start()
+
+    @staticmethod
+    def _open(path: str) -> int | None:
+        try:
+            return os.open(path, os.O_RDONLY)
+        except OSError:
+            return None
+
+    def arm(self) -> None:
+        """An execution opens: with the first one open, the collector's
+        pair goes on its list, the sleeper wakes, and its largest lateness
+        starts from zero — the exit's reads what fell inside."""
+        with self._lock:
+            self.open += 1
+            if self.open == 1:
+                gc.callbacks.append(self._on_gc)
+                self.late_max = 0.0
+                self.asleep_since = time.monotonic()
+                self._awake.set()
+
+    def disarm(self) -> None:
+        with self._lock:
+            self.open -= 1
+            if self.open == 0:
+                gc.callbacks.remove(self._on_gc)
+                self._awake.clear()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._gc_t0 = time.perf_counter()
+        elif self._gc_t0:
+            # (a stop whose start ran before the pair was listed counts
+            # nothing)
+            self.gc_seconds += time.perf_counter() - self._gc_t0
+            self.gc_count += 1
+            self._gc_t0 = 0.0
+
+    def _sleep(self) -> None:
+        while True:
+            self._awake.wait()      # parked while no execution is open
+            t = self.asleep_since = time.monotonic()
+            time.sleep(SLEEPER_PERIOD_S)
+            late = time.monotonic() - t - SLEEPER_PERIOD_S
+            if late > self.late_max:
+                self.late_max = late
+
+
+_probe: _HostProbe | None = None
+
+SNAPSHOT_FIELDS = (
+    "process_cpu_s", "thread_cpu_s", "machine_user_s", "machine_system_s",
+    "machine_iowait_s", "machine_steal_s", "cpu_pressure_some_s",
+    "loadavg_1m", "involuntary_switches", "gc_s", "gc_collections",
+    "sleeper_late_max_s")
+# read as they stand at the exit, not as a difference of the two snapshots
+_SNAPSHOT_LEVELS = frozenset({"loadavg_1m", "sleeper_late_max_s"})
+
+
+def host_snapshot(opens: bool = False, closes: bool = False) -> tuple:
+    """What this process and its machine have done so far, in the order of
+    ``SNAPSHOT_FIELDS``: a dozen numbers, two ``pread``s and no file
+    opened after the first call. ``opens=True`` is the snapshot at an
+    execution's entry and ``closes=True`` the one at its exit: the
+    collector's seconds and the sleeper's lateness are kept between the
+    two alone (``_HostProbe.arm``). A number whose source the machine lacks
+    is None."""
+    global _probe
+    p = _probe
+    if p is None or p.pid != os.getpid() or not p.sleeper.is_alive():
+        # the first snapshot of this process (a fork's child starts anew:
+        # threads do not survive a fork)
+        p = _probe = _HostProbe()
+    if opens:
+        p.arm()
+    user = system = iowait = steal = pressure = None
+    if p.stat_fd is not None:
+        # "cpu  user nice system idle iowait irq softirq steal ..."
+        f = os.pread(p.stat_fd, 512, 0).split(b"\n", 1)[0].split()
+        user, system = int(f[1]) * _TICK_S, int(f[3]) * _TICK_S
+        iowait, steal = int(f[5]) * _TICK_S, int(f[8]) * _TICK_S
+    if p.pressure_fd is not None:
+        # "some avg10=0.00 avg60=0.00 avg300=0.00 total=<microseconds>"
+        line = os.pread(p.pressure_fd, 256, 0).split(b"\n", 1)[0]
+        pressure = int(line.rsplit(b"=", 1)[1]) * 1e-6
+    late = p.late_max
+    if p.open:
+        # a thread that comes out of a freeze together with the sleeper may
+        # get here first: a wake-up that is overdue right now counts as
+        # late already
+        late = max(late, time.monotonic() - p.asleep_since - SLEEPER_PERIOD_S)
+    snapshot = (
+        time.process_time(), time.thread_time(), user, system, iowait, steal,
+        pressure, os.getloadavg()[0],
+        resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw,
+        p.gc_seconds, p.gc_count, late)
+    if closes:
+        p.disarm()
+    return snapshot
+
+
+def snapshot_delta(before: tuple, after: tuple) -> dict:
+    """What happened between two snapshots, by ``SNAPSHOT_FIELDS``'s names."""
+    out = {}
+    for name, a, b in zip(SNAPSHOT_FIELDS, before, after):
+        if b is None:
+            continue
+        out[name] = b if name in _SNAPSHOT_LEVELS else b - a
+    return out
+
+
+class execution_span(host_span):
+    """The ``host_span`` around one device execution, or around its first
+    (``closes=False``) or last (``opens=False``) part where two spans share
+    it: ``before`` is a ``host_snapshot`` taken ahead of the span's entry,
+    ``after`` one taken behind its exit, so the span's own interval is what
+    it was without them. The engine's account compares the execution with
+    its shape's pace and keeps the pair's difference only where it was held
+    (``EngineStats.note_execution``); otherwise the two tuples die with
+    the span. ``probe=False`` — a program's first call, which is never
+    judged — takes no snapshot and is a plain ``host_span``. Not for a span
+    that brackets no execution."""
+
+    __slots__ = ("opens", "closes", "before", "after")
+
+    def __init__(self, layer: str, name: str, sink: dict | None = None,
+                 event: str | None = None, *, opens: bool = True,
+                 closes: bool = True, probe: bool = True, **args) -> None:
+        super().__init__(layer, name, sink, event, **args)
+        self.opens, self.closes = opens and probe, closes and probe
+        self.before = self.after = None
+
+    def __enter__(self) -> "execution_span":
+        if self.opens:
+            self.before = host_snapshot(opens=True)
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        if self.closes:
+            self.after = host_snapshot(closes=True)
+        elif self.before is not None and exc[0] is not None:
+            # the execution ends here: its last part will never open
+            _probe.disarm()
         return False
 
 

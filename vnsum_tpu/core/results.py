@@ -159,6 +159,14 @@ class ServingStats:
     # segment boundary (0 for the batch-dispatch scheduler)
     segments: int = 0
     refills: int = 0
+    # the slot loop's two device calls, each timed by the span that brackets
+    # it (backend/inflight.py): a join — slot admission to the joiners'
+    # first token — and the rows it carried; a segment, call to boundary
+    # fetch, and the decode steps it ran. Both seconds are in engine_seconds
+    join_seconds: float = 0.0
+    join_rows: int = 0
+    segment_seconds: float = 0.0
+    segment_steps: int = 0
     # the idle loop's coalescing window (RequestQueue.take_upto): takes
     # that held it open, followers that arrived inside one and joined with
     # its head, and the seconds held

@@ -51,6 +51,7 @@ class PipelineRunner:
         self.llm_judge = llm_judge
         self.results = PipelineResults(config=config.to_dict())
         self.tracer = Tracer()
+        self._engine_record: dict | None = None
         self.log_path = setup_run_logging(config.logs_dir)
         logger.info("pipeline configured: approach=%s backend=%s models=%s",
                     config.approach, config.backend, config.models)
@@ -348,6 +349,10 @@ class PipelineRunner:
 
         record.total_time = time.time() - t_start
         self.results.add_summarization(record)
+        # the engine's own account of the run (host spans, executions, the
+        # held ones, the work counters), for ``tracing["engine"]``: a
+        # backend without one leaves the key out; of several models the last
+        self._engine_record = getattr(backend, "engine_record", lambda: None)()
         return record
 
     def run_evaluation_for_model(self, model: str) -> dict:
@@ -481,6 +486,8 @@ class PipelineRunner:
                 logger.error("model %s evaluation failed: %s", model, e)
                 self.results.add_evaluation(model, {"status": "failed", "error": str(e)})
         self.results.tracing = self.tracer.to_dict()
+        if self._engine_record is not None:
+            self.results.tracing["engine"] = self._engine_record
         path = self.results.save(self.config.results_dir)
         logger.info("results saved to %s", path)
         # when device profiling is armed (VNSUM_PROFILE_DIR), drop the host

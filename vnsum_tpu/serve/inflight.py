@@ -647,7 +647,7 @@ class InflightScheduler(MicroBatchScheduler):
                 self.journal.start(r.journal_rid)
         if admissions:
             prefill_s = admissions[0].prefill_end - admissions[0].admitted_at
-            self.metrics.observe_batch(len(admissions), prefill_s)
+            self.metrics.observe_batch(len(admissions), prefill_s, join=True)
             if self.recorder is not None:
                 # guarded, not _fr: the riders list must not be built on
                 # the recorder-less hot path (the all-off arm's contract)
@@ -706,7 +706,8 @@ class InflightScheduler(MicroBatchScheduler):
             self._complete_segment(loop, res)
 
     def _complete_segment(self, loop, res) -> None:
-        self.metrics.observe_segment(res.live, res.seconds, res.new_tokens)
+        self.metrics.observe_segment(res.live, res.seconds, res.new_tokens,
+                                     res.steps)
         now = time.monotonic()
         self._just_finished = len(res.completions)
         self._emit_stream_deltas(loop)

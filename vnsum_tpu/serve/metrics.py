@@ -95,6 +95,38 @@ _reg("inflight_segments_total", "counter",
      "decode segments dispatched by the in-flight slot loop")
 _reg("inflight_refills_total", "counter",
      "requests admitted into a running decode batch at a segment boundary")
+_reg("inflight_join_seconds_total", "counter",
+     "seconds of the slot loop's joins, slot admission to the joiners' first "
+     "token (the slot/prefill span's end); part of engine_seconds_total")
+_reg("inflight_join_rows_total", "counter",
+     "requests the joins counted in inflight_join_seconds_total admitted: "
+     "the divisor of a join's seconds a request (join_span_s_per_req)")
+_reg("inflight_segment_seconds_total", "counter",
+     "seconds of the slot loop's decode segments, program call to boundary "
+     "fetch (the slot/segment span); part of engine_seconds_total")
+_reg("inflight_segment_steps_total", "counter",
+     "decode steps the segments counted in inflight_segment_seconds_total "
+     "ran (a segment's deepest row's)")
+_reg("engine_prefill_row_chunks_total", "counter",
+     "(row, chunk) pieces of the engine's prefills, one-shot dispatches and "
+     "joins (scrape-time; TPU engine only)")
+_reg("engine_prefill_row_chunks_dead_total", "counter",
+     "pieces of engine_prefill_row_chunks_total the program did not run "
+     "because the row holds nothing but left pad there")
+_reg("engine_decode_kv_blocks_total", "counter",
+     "key blocks between slot 0 and the fill that the engine's decode steps "
+     "walked, a row a global-attention layer a step (scrape-time; TPU "
+     "engine only)")
+_reg("engine_decode_kv_blocks_skipped_total", "counter",
+     "blocks of engine_decode_kv_blocks_total the decode kernels left out "
+     "for lying wholly under a row's left pad")
+_reg("engine_executions_held_total", "counter",
+     "device executions that took more than their shape's pace by the "
+     "engine's margin (each logs one 'execution held' WARNING with what the "
+     "host was doing; scrape-time; TPU engine only)")
+_reg("engine_held_excess_seconds_total", "counter",
+     "seconds the executions counted in engine_executions_held_total took "
+     "past their shape's pace")
 _reg("inflight_windows_total", "counter",
      "takes by an idle slot loop that held the coalescing window open "
      "before a join (a take that was full or alone at once holds none)")
@@ -458,22 +490,30 @@ class ServeMetrics:
                 self.usage.observe_shed(tenant, n)
 
     def observe_batch(self, occupancy: int, engine_s: float,
-                      gen_tokens: int = 0) -> None:
+                      gen_tokens: int = 0, join: bool = False) -> None:
+        """One batch dispatch; ``join=True`` where it is a slot admission of
+        the in-flight loop (an oversized request's one-shot fallback is a
+        batch and no join)."""
         with self._lock:
             self._stats.batches += 1
             self._stats.batch_occupancy_sum += occupancy
             self._stats.engine_seconds += engine_s
+            if join:
+                self._stats.join_seconds += engine_s
+                self._stats.join_rows += occupancy
             self._hists["batch_occupancy"].observe(occupancy)
             self._rolling_tps.add(gen_tokens, engine_s)
 
     def observe_segment(self, live: int, seg_s: float,
-                        gen_tokens: int = 0) -> None:
+                        gen_tokens: int = 0, steps: int = 0) -> None:
         """One in-flight decode segment: slot occupancy, engine residency,
-        and the tokens it retired (feeds the rolling tokens/s gauge the way
-        observe_batch does for batch dispatches)."""
+        the decode steps it ran and the tokens it retired (feeds the rolling
+        tokens/s gauge the way observe_batch does for batch dispatches)."""
         with self._lock:
             self._stats.segments += 1
             self._stats.engine_seconds += seg_s
+            self._stats.segment_seconds += seg_s
+            self._stats.segment_steps += steps
             self._hists["slot_occupancy"].observe(live)
             self._rolling_tps.add(gen_tokens, seg_s)
 
@@ -756,6 +796,7 @@ class ServeMetrics:
     def render_prometheus(self, queue_depth: int | None = None,
                           queued_tokens: int | None = None,
                           cache_stats: dict | None = None,
+                          engine_counters: dict | None = None,
                           slot_state: tuple[int, int] | None = None,
                           degraded_rung: int | None = None,
                           journal_stats: dict | None = None,
@@ -769,6 +810,8 @@ class ServeMetrics:
         """``cache_stats`` is the backend's prefix_cache_stats() snapshot
         (evictions / blocks_used / blocks_total), read at scrape time like
         the queue gauges — the serving layer never mirrors pool state.
+        ``engine_counters`` is TpuBackend.engine_counters(), the same way
+        (absent on a backend without one: no ``engine_*`` family renders).
         ``mesh_state`` is ServeState.mesh_state() (devices / data / model,
         plus replica_occupancy when the in-flight loop is live).
         ``qos_state`` is TenantTable.stats() (per-tenant config + bucket
@@ -849,6 +892,10 @@ class ServeMetrics:
         simple("cache_hit_rate", round(s.cache_hit_rate, 6))
         simple("inflight_segments_total", s.segments)
         simple("inflight_refills_total", s.refills)
+        simple("inflight_join_seconds_total", round(s.join_seconds, 6))
+        simple("inflight_join_rows_total", s.join_rows)
+        simple("inflight_segment_seconds_total", round(s.segment_seconds, 6))
+        simple("inflight_segment_steps_total", s.segment_steps)
         simple("inflight_windows_total", s.windows)
         simple("inflight_window_joined_total", s.window_joined)
         simple("inflight_window_wait_seconds_total",
@@ -1095,6 +1142,10 @@ class ServeMetrics:
                 # soaks assert this returns to baseline after churn — a
                 # non-zero value with no batch in flight is a pin leak
                 simple("cache_pinned_blocks", cache_stats["pinned_blocks"])
+        if engine_counters is not None:
+            for name, value in engine_counters.items():
+                simple(f"engine_{name}_total",
+                       round(value, 6) if isinstance(value, float) else value)
         if queue_depth is not None:
             simple("queue_depth", queue_depth)
         if queued_tokens is not None:

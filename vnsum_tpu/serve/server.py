@@ -879,6 +879,9 @@ def make_handler(state: ServeState):
                 cache_stats = getattr(
                     state.backend, "prefix_cache_stats", lambda: None
                 )()
+                engine_counters = getattr(
+                    state.backend, "engine_counters", lambda: None
+                )()
                 slot_state = getattr(
                     state.scheduler, "slot_state", lambda: None
                 )()
@@ -901,6 +904,7 @@ def make_handler(state: ServeState):
                         queue_depth=state.scheduler.queue.depth,
                         queued_tokens=state.scheduler.queue.queued_tokens,
                         cache_stats=cache_stats,
+                        engine_counters=engine_counters,
                         slot_state=slot_state,
                         mesh_state=mesh_state,
                         degraded_rung=(

@@ -16,6 +16,10 @@ site                  fires
 ``fake.slot_admit``   FakeSlotLoop.admit entry (in-flight join)
 ``fake.slot_step``    FakeSlotLoop.step entry (in-flight decode segment)
 ``engine.dispatch``   TpuBackend.generate entry
+``engine.wait``       inside a one-shot dispatch's ``engine/wait`` span, ahead
+                      of the result fetch (a ``latency`` here is a dispatch
+                      held with the process awake: the execution account's
+                      test)
 ``engine.slot_admit`` TpuSlotLoop.admit entry
 ``engine.slot_step``  TpuSlotLoop.step entry
 ``journal.fsync``     RequestJournal group-commit fsync — fires INSIDE the
