@@ -192,6 +192,45 @@ def test_a_key_block_under_the_pad_expands_nothing():
             atol=2e-5)
 
 
+@pytest.mark.parametrize("H,S,offset,pads,bq,bk", _PREFILL_CASES)
+def test_prefill_kernel_reads_the_stacked_cache_in_place(H, S, offset, pads,
+                                                         bq, bk):
+    """PR 53: handed the stacked cache, a layer and the queries' first batch
+    row, the kernel gives bit for bit what it gives for those rows sliced
+    out — with NaN in every other layer, every other batch row and every
+    slot past the keys, so nothing but the rows' own T slots is read."""
+    from vnsum_tpu.ops.mla_attention import mla_prefill_attention
+
+    T = offset + S
+    qn, qr, lat, wk, wv, *_ = _prefill_operands(H, S, T)
+    pad = jnp.asarray(pads, jnp.int32)
+    kw = dict(scale=0.2, q_offset=offset, block_q=bq, block_k=bk,
+              interpret=True)
+    want = mla_prefill_attention(qn, qr, lat, wk, wv, pad, **kw)
+    # layer 1 of 3, the two rows at batch rows 2 and 3 of 5, eleven slots
+    # more than the keys
+    cache = np.full((3, 5, T + 11, lat.shape[-1]), np.nan, np.float32)
+    cache[1, 2:4, :T] = np.asarray(lat)
+    got = mla_prefill_attention(qn, qr, jnp.asarray(cache), wk, wv, pad,
+                                layer_idx=jnp.int32(1),
+                                row_offset=jnp.int32(2), **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def test_the_stacked_cache_has_to_hold_the_keys():
+    from vnsum_tpu.ops.mla_attention import mla_prefill_attention
+
+    qn, qr, lat, wk, wv, *_ = _prefill_operands(3, 64, 96)
+    pad = jnp.zeros((2,), jnp.int32)
+    with pytest.raises(ValueError, match="95 keys for queries at"):
+        mla_prefill_attention(qn, qr, lat[:, :95], wk, wv, pad, scale=0.2,
+                              q_offset=32, interpret=True)
+    with pytest.raises(ValueError, match="a cache of 95 slots"):
+        mla_prefill_attention(qn, qr, lat[None, :, :95], wk, wv, pad,
+                              scale=0.2, q_offset=32, layer_idx=0,
+                              interpret=True)
+
+
 def _brute_force_tiles(pads, S, T, offset, bq, bk):
     """Class counts of the (bq x bk) tiles from the mask itself."""
     from vnsum_tpu.ops.mla_attention import TILE_CLASSES
@@ -292,13 +331,20 @@ def test_a_piece_of_the_prefill_attention_is_four_rows_at_the_cells_shapes():
     assert ds._rows_a_piece(cfg, 24, 2048) == 2
 
 
+@pytest.mark.parametrize("rows_a_piece", [None, 1],
+                         ids=["one-piece", "a-row-a-piece"])
 @pytest.mark.parametrize("offset,pads", [(0, [0, 17]), (128, [0, 150])])
-def test_int8_leaves_scales_are_folded_outside_the_kernel(offset, pads):
+def test_int8_leaves_scales_are_folded_outside_the_kernel(
+        offset, pads, rows_a_piece, monkeypatch):
     """``prefill_attention`` with int8 ``wk_b`` / ``wv_b``: the kernel gets
     the leaves' integers in the latent's type, the scales multiply the
     queries and the output (``_expanded_attention``'s rule), and the result
-    is the dense path's over the same leaves."""
+    is the dense path's over the same leaves — the kernel reading layer 1
+    of the stacked cache in place, in one piece and a row a piece (each
+    piece's keys at its own batch row: PR 53)."""
     cfg = ds.tiny_deepseek()
+    if rows_a_piece:
+        monkeypatch.setattr(ds, "_rows_a_piece", lambda *_: rows_a_piece)
     params = quantize_params(ds.init_params(jax.random.key(3), cfg))
     lp = jax.tree.map(lambda w: w[0], params["layers"])
     assert isinstance(lp["wk_b"], dict) and isinstance(lp["wv_b"], dict)

@@ -64,6 +64,12 @@ the scan kernel's third prefetched vector (its body unchanged: one
 `ssd_prefill_scan` body still), the per-row writes of keys, values and
 convolution tail are in it.
 
+**PR 53 moved `deepseek-v2` on purpose**: the latent prefill kernel
+(`ops/mla_attention.py`) reads the stacked cache in place, so the slice of a
+layer's rows is gone from the latent family's program and the kernel's
+second prefetched vector holds the rows' place in the cache beside the chunk
+offset (its body unchanged); the other thirteen did not move.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
@@ -95,7 +101,7 @@ _PINNED = {
     "laguna": ("tiny-laguna", {}, "c8b4edca989eecec"),
     "granite-h": ("tiny-granite-h", {}, "3f9fcb64cac59b4a"),
     "nemotron-h": ("tiny-nemotron-h", {}, "c14ede11a5e68ce7"),
-    "deepseek-v2": (tiny_deepseek, {}, "90bc1e80a899c229"),
+    "deepseek-v2": (tiny_deepseek, {}, "17a317c39a880058"),
     "llama-row-pieces": ("tiny", {}, "87c61288db10c21a"),
     "llama-looped": ("tiny-ouro", {}, "05e5ea3227bd67de"),
     "granite-h-row-pieces": ("tiny-granite-h", {}, "8ece4d118fa328f3"),

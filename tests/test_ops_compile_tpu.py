@@ -75,6 +75,28 @@ def test_prefill_kernel_compiles_at_the_published_widths(one_chip, S, offset):
     assert "tpu_custom_call" in c.as_text()
 
 
+@pytest.mark.parametrize("R,S,offset", [
+    (4, 1024, 7168),   # the map dispatch's last chunk, a piece of four rows
+    (4, 1024, 0),      # its first
+    (1, 1024, 3072),   # parity's last chunk
+])
+def test_prefill_kernel_compiles_over_the_stacked_cache(one_chip, R, S,
+                                                        offset):
+    """As the model calls it since PR 53: the cell's latent cache whole, its
+    layer and the piece's first batch row by scalar prefetch."""
+    from vnsum_tpu.ops.mla_attention import mla_prefill_attention
+
+    H = 128
+    c = _compiled(
+        lambda qn, qr, cache, wk, wv, p, layer, row: mla_prefill_attention(
+            qn, qr, cache, wk, wv, p, scale=0.1147, q_offset=offset,
+            layer_idx=layer, row_offset=row),
+        one_chip, ((R, H, S, 128), BF16), ((R, H, S, 64), BF16),
+        ((8, 24, 8448, 576), BF16), ((H, 512, 128), BF16),
+        ((H, 512, 128), BF16), ((R,), I32), ((), I32), ((), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
 def test_absorbed_decode_kernel_compiles_over_the_576_wide_cache(one_chip):
     """C = 8448 leaves a partial last block of 256; the block's lane slices
     at 512 (latent | rope) have to be ones Mosaic takes."""

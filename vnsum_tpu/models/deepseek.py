@@ -417,9 +417,11 @@ def prefill_attention(cfg: DeepseekV2Config, pad_lens, q_offset: int, *,
     """The prefill attention of one chunk whose queries start at cache slot
     ``q_offset``: the layer's ``wk_b`` / ``wv_b`` are laid out a head once,
     then for a few batch rows at a time the queries are projected up and go
-    with the rows' latent slots through ``mla_prefill_attention``, which
-    expands a key block's keys and values in VMEM (whole in HBM, 24 rows of
-    8,192 would be 12.9 GB a layer), and the output is projected. An int8
+    through ``mla_prefill_attention``, which reads the rows' latent slots
+    from the stacked cache in place (a layer's rows sliced out for it were
+    226 MB at 24 rows of 8,192, a chunk and layer) and expands a key
+    block's keys and values in VMEM (whole in HBM they would be 12.9 GB a
+    layer), and the output is projected. An int8
     leaf's scale lies on a channel the attention keeps, so it multiplies
     the queries (keys) and the output (values): ``_expanded_attention``'s
     rule."""
@@ -427,31 +429,31 @@ def prefill_attention(cfg: DeepseekV2Config, pad_lens, q_offset: int, *,
 
     def attend(c_q, rope, cache, layer_idx, lp, aq):
         B, S, _ = c_q.shape
-        T = q_offset + S
-        lat = jax.lax.dynamic_slice(
-            cache["latent"], (layer_idx, 0, 0, 0),
-            (1, B, T, cfg.latent_width))[0]
+        latent = cache["latent"]
         (wk, sk), (wv, sv) = _leaf(lp["wk_b"]), _leaf(lp["wv_b"])
         with jax.named_scope("attn"):
             # [rank, H, k] -> a head's [rank, k] block, in the latent's type
-            wk, wv = (w.astype(lat.dtype).transpose(1, 0, 2) for w in (wk, wv))
+            wk, wv = (w.astype(latent.dtype).transpose(1, 0, 2)
+                      for w in (wk, wv))
         R = _rows_a_piece(cfg, B, S)
 
-        def piece(args):
-            c_q, cos, sin, lat, pads = args
+        def piece(args, first_row=0):
+            c_q, cos, sin, pads = args
             q_nope, q_rope = _queries(c_q, (cos, sin), lp, aq, cfg)
             with jax.named_scope("attn"):
                 attn = _scaled(mla_prefill_attention(
-                    _scaled(q_nope, sk), q_rope, lat, wk, wv, pads,
+                    _scaled(q_nope, sk), q_rope, latent, wk, wv, pads,
                     scale=cfg.softmax_scale, q_offset=q_offset,
+                    layer_idx=layer_idx, row_offset=first_row,
                     interpret=interpret), sv)
             return _project_out(attn, lp, aq)
 
-        args = (c_q, *rope, lat, pad_lens)
+        args = (c_q, *rope, pad_lens)
         if R == B:
             return piece(args)
-        out = jax.lax.map(piece, tuple(
-            a.reshape((B // R, R) + a.shape[1:]) for a in args))
+        pieces = tuple(a.reshape((B // R, R) + a.shape[1:]) for a in args)
+        out = jax.lax.map(lambda xs: piece(*xs),
+                          (pieces, jnp.arange(0, B, R)))
         return out.reshape((B,) + out.shape[2:])
 
     def real_tokens(S: int):

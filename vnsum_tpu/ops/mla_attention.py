@@ -10,21 +10,27 @@ per token (models/deepseek.py: 512 of normalised ``c_kv`` + 64 of rotated
   the inputs' type; ``k_rope`` is the block's last lanes, shared by all
   heads): nothing expanded is written to HBM. The query/key width (nope +
   rope = 192) differs from the value width (128), which the GQA kernels of
-  ops/flash_attention.py cannot express. Left-padded rows and chunked
-  prefill (``q_offset``) as there: a block above the diagonal or under a
-  row's pad is neither fetched nor expanded nor computed, a block that
-  needs no mask builds none (_tile_class; ``prefill_tile_classes`` counts a
-  call's tiles and the keys it expands on the host by the same rule). A
+  ops/flash_attention.py cannot express. It reads the stacked cache ``[L,
+  B, C, 576]`` in place, as the decode kernel does (layer index and the
+  queries' first batch row by scalar prefetch): no layer's rows are sliced
+  out of the cache for it. Left-padded rows and chunked prefill
+  (``q_offset``) as there: a block above the diagonal or under a row's pad
+  is neither fetched nor expanded nor computed, a block that needs no mask
+  builds none (_tile_class; ``prefill_tile_classes`` counts a call's tiles
+  and the keys it expands on the host by the same rule). A
   grid step holds a GROUP of heads (their queries and their blocks of
   ``W_kb`` / ``W_vb``) against one latent block, as a GQA group shares its
   K/V block there, and a head computes a (1024, 1024) tile of scores at a
   time. A chunked prefill calls it once a chunk, so a row's keys are
-  expanded once for every chunk that reads them. The kernel's pace is its
-  products' (a head and tile: 8 passes of 1,024 rows through the 128 x 128
-  units for the expansion, 8 + 8 for the scores — the 64-wide rope product
-  costs what a 128-wide one does — and 8 for the values); the softmax's
-  vector work runs beside them, for which a loop step is WRITTEN products
-  first (for_each_head).
+  expanded once for every chunk that reads them (4.6 times at the cell's
+  map dispatch) — and that costs nothing to speak of: a head and tile is
+  32 passes of 1,024 rows through the 128 x 128 units (8 for the expansion,
+  8 + 8 for the scores — the 64-wide rope product costs what a 128-wide one
+  does — and 8 for the values), but its pace is the handling of its (1024,
+  1024) float32 scores, beside which the products run, for which a loop
+  step is WRITTEN products first (for_each_head). A kernel that expanded a
+  block once for all of a call's query tiles ran a tile in the same 5.7 us
+  a head (PR 53, the comment over _BLOCK).
 - ``mla_decode_attention``: the ABSORBED decode step. The caller folds
   ``W_kvb``'s key half into the query (``q_lat = q_nope . W_k^T``, 512 wide)
   and the kernel is multi-query attention of all heads over the one latent
@@ -59,15 +65,30 @@ VMEM_LIMIT_BYTES = 48 * 1024 * 1024
 # queries all cost more. The heads of a group share the step's latent block,
 # mask and positions, which buys 1-3%; they go two at a time, which buys 3%
 # more where four at a time spill (+50%; +35-47% with the expansion inside,
-# PR 44, at a scoped limit raised to fit them). The pace itself is the matrix
-# units' — 32 passes of 1,024 rows a head and tile, 8 of them the expansion's
-# — so past this cell what counts is the order a step is written in
-# (for_each_head).
+# PR 44, at a scoped limit raised to fit them). Past this cell what counts
+# is the order a step is written in (for_each_head).
+#
+# The pace is NOT the matrix units', though a head and tile's 32 passes of
+# 1,024 rows (8 of them the expansion's) take 5.5 us at their peak and the
+# tile 5.7 (PR 53, the cell's device trace): a kernel whose step held all of
+# a call's query tiles — spans of 4,096 queries a call, the first tile
+# expanding the block into VMEM, the others taking it, 12 expansions a row
+# of 8,192 keys for 36 — ran a tile that took its keys from VMEM in 0.730 ms
+# a row and one that expanded them in 0.734, a full row in 26.98 ms for
+# 27.26. Since the products are written ahead of the softmax the expansion
+# hides under it, and what a tile waits for is the passes over its (1024,
+# 1024) float32 scores (PERF.md section 6, PR 53, has the probes). That
+# kernel and its engine rule are not in the tree: they bought no time.
 _BLOCK = 1024
 # the widest group whose step fits 48 MiB of scoped VMEM at that tile (16
 # heads do not compile)
 _GROUP = 8
 _HEADS_UNROLLED = 2
+# ... alone in a program. Reading the cache in place (PR 53), the same step
+# is booked 49.35 MiB inside the cell's (4, 2048) and (4, 4096) programs,
+# which then do not compile at 48 (the (24, 8192) program and the kernel
+# alone do): the limit is the expert product's, half of the chip's 128
+_PREFILL_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 
 def _prefill_geometry(H: int, S: int, T: int, block_q: int | None = None,
@@ -98,15 +119,16 @@ def _tile_class(q_start, k_start, pad, rows: int, cols: int):
     return above, under, interior
 
 
-def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, lat_ref, wk_ref, wv_ref,
+def _prefill_kernel(pad_ref, at_ref, qn_ref, qr_ref, lat_ref, wk_ref, wv_ref,
                     o_ref, acc_ref, m_ref, l_ref, *, group: int, block_q: int,
                     block_k: int, n_keys: int, rank: int, scale: float):
-    # qn [1,G,bq,dn] qr [1,G,bq,dr] lat [1,bk,rank+dr] wk/wv [G,rank,dn|dv];
+    # at: (q_offset, row 0's place among the latent's rows); qn [1,G,bq,dn]
+    # qr [1,G,bq,dr] lat [1,bk,rank+dr] wk/wv [G,rank,dn|dv];
     # acc [G*bq,dv], m/l [G*bq,LANES]: a head's state is a slice of rows
     b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     nj = pl.num_programs(3)
     pad = pad_ref[b]
-    q_start = off_ref[0] + i * block_q
+    q_start = at_ref[0] + i * block_q
     k_start = j * block_k
 
     def rows_of(g):
@@ -221,6 +243,7 @@ def _prefill_kernel(pad_ref, off_ref, qn_ref, qr_ref, lat_ref, wk_ref, wv_ref,
     "scale", "q_offset", "block_q", "block_k", "interpret"))
 def mla_prefill_attention(q_nope, q_rope, latent, wk, wv, pad_lens, *,
                           scale: float, q_offset: int = 0,
+                          layer_idx=None, row_offset=0,
                           block_q: int | None = None,
                           block_k: int | None = None,
                           interpret: bool = False):
@@ -229,16 +252,30 @@ def mla_prefill_attention(q_nope, q_rope, latent, wk, wv, pad_lens, *,
 
     q_nope [B, H, S, dn], q_rope [B, H, S, dr]; latent [B, T, rank + dr]
     is the cache's rows of those slots (``c_kv`` then the one rotated key
-    all heads share); wk [H, rank, dn] and wv [H, rank, dv] are the two
-    halves of ``W_kvb`` a head, in the latent's type: the kernel expands a
-    key block's ``k_nope = c_kv wk[h]`` and ``v = c_kv wv[h]`` itself;
-    pad_lens [B] left pads. Returns [B, H, S, dv]. The cell is chosen from
-    the shapes (_prefill_geometry); ``block_q``/``block_k`` are for tests."""
+    all heads share) — or, with ``layer_idx``, the stacked cache [L, Bc, C,
+    rank + dr] itself, read in place: that layer, query row b's keys at
+    batch row ``row_offset + b``, its first T slots; wk [H, rank, dn] and
+    wv [H, rank, dv] are the two halves of ``W_kvb`` a head, in the
+    latent's type: the kernel expands a key block's ``k_nope = c_kv wk[h]``
+    and ``v = c_kv wv[h]`` itself; pad_lens [B] left pads. Returns [B, H, S,
+    dv]. The cell is chosen from the shapes (_prefill_geometry);
+    ``block_q``/``block_k`` are for tests."""
     B, H, S, dn = q_nope.shape
     dr = q_rope.shape[-1]
-    T, rank, dv = latent.shape[1], wk.shape[1], wv.shape[2]
-    if T != q_offset + S:
-        raise ValueError(f"{T} keys for queries at [{q_offset}, {q_offset + S})")
+    rank, dv = wk.shape[1], wv.shape[2]
+    T = q_offset + S
+    if layer_idx is None:
+        if latent.shape[1] != T:
+            raise ValueError(f"{latent.shape[1]} keys for queries at "
+                             f"[{q_offset}, {T})")
+        first_row = 0
+    else:
+        # every layer's batch rows in one axis: a reshape that moves nothing
+        first_row = layer_idx * latent.shape[1] + row_offset
+        latent = latent.reshape((-1,) + latent.shape[2:])
+    if latent.shape[1] < T:
+        raise ValueError(f"a cache of {latent.shape[1]} slots for queries "
+                         f"at [{q_offset}, {T})")
     if latent.shape[2] != rank + dr:
         raise ValueError(f"latent rows of {latent.shape[2]} for rank {rank} "
                          f"and {dr} rotated lanes")
@@ -247,12 +284,12 @@ def mla_prefill_attention(q_nope, q_rope, latent, wk, wv, pad_lens, *,
     G, bq, bk = _prefill_geometry(H, S, T, block_q, block_k)
     off = q_offset
 
-    def visible_j(b, i, j, pad, _off):
+    def latent_block(b, h, i, j, pad, at):
         # clamp to the blocks this query block reads: a repeated index is
         # not fetched again, so dead blocks cost no DMA
         first = pad[b] // bk
         last = (off + i * bq + bq - 1) // bk
-        return jnp.clip(j, jnp.minimum(first, last), last)
+        return (at[1] + b, jnp.clip(j, jnp.minimum(first, last), last), 0)
 
     kernel = functools.partial(
         _prefill_kernel, group=G, block_q=bq, block_k=bk, n_keys=T,
@@ -267,9 +304,7 @@ def mla_prefill_attention(q_nope, q_rope, latent, wk, wv, pad_lens, *,
                              lambda b, h, i, j, pad, o: (b, h, i, 0)),
                 pl.BlockSpec((1, G, bq, dr),
                              lambda b, h, i, j, pad, o: (b, h, i, 0)),
-                pl.BlockSpec((1, bk, rank + dr),
-                             lambda b, h, i, j, pad, o:
-                             (b, visible_j(b, i, j, pad, o), 0)),
+                pl.BlockSpec((1, bk, rank + dr), latent_block),
                 # the group's weights: fetched when the group changes
                 pl.BlockSpec((G, rank, dn),
                              lambda b, h, i, j, pad, o: (h, 0, 0)),
@@ -288,12 +323,13 @@ def mla_prefill_attention(q_nope, q_rope, latent, wk, wv, pad_lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
-            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            vmem_limit_bytes=_PREFILL_VMEM_LIMIT_BYTES),
         interpret=interpret,
         # a contract: the device trace and the benchmark's metrics name this
         # kernel by it
         name="mla_prefill_attention",
-    )(pad_lens.astype(jnp.int32), jnp.full((1,), off, jnp.int32),
+    )(pad_lens.astype(jnp.int32),
+      jnp.stack([jnp.asarray(x, jnp.int32) for x in (off, first_row)]),
       q_nope, q_rope, latent, wk, wv)
 
 
