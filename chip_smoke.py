@@ -46,13 +46,13 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe",
-          "laguna", "nemotron_h", "ouro")
+          "laguna", "nemotron_h", "ouro", "lfm2")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
     "experts": 420, "moe": 420, "laguna": 480, "nemotron_h": 480,
-    "ouro": 360,
+    "ouro": 360, "lfm2": 420,
 }
 
 
@@ -91,6 +91,10 @@ def sizes(rehearsal: bool) -> dict:
             nemotron_h_prompt_bytes=250, nemotron_h_parity=(150, 256, 4),
             nemotron_h_tolerance=0.05, nemotron_h_tie_band=0.02,
             nemotron_h_state_tolerance=0.01,
+            lfm2_layers=10, lfm2_seq=328, lfm2_batch=4, lfm2_max_new=8,
+            lfm2_prefill_chunk=128, lfm2_prompt_bytes=250,
+            lfm2_parity=(150, 256, 4), lfm2_tolerance=0.15,
+            lfm2_tie_band=0.02, lfm2_state_tolerance=0.01,
             ouro_layers=2, ouro_seq=328, ouro_batch=4, ouro_max_new=8,
             ouro_prefill_chunk=128, ouro_prompt_bytes=250,
             ouro_parity=(150, 256, 4), ouro_tolerance=0.05,
@@ -138,6 +142,12 @@ def sizes(rehearsal: bool) -> dict:
         nemotron_h_prompt_bytes=1_900, nemotron_h_parity=(1500, 2048, 4),
         nemotron_h_tolerance=0.25, nemotron_h_tie_band=0.1,
         nemotron_h_state_tolerance=0.006,
+        # lfm2: the first seven layers c c A c c c A at the published widths
+        # (both dense layers, five sparse ones, both kinds of operator)
+        lfm2_layers=7, lfm2_seq=2304, lfm2_batch=4, lfm2_max_new=32,
+        lfm2_prefill_chunk=1024, lfm2_prompt_bytes=1_900,
+        lfm2_parity=(1500, 2048, 4), lfm2_tolerance=0.25,
+        lfm2_tie_band=0.1, lfm2_state_tolerance=0.012,
         # ouro: two layers at the published widths run FOUR times (8 cache
         # layers), 16 / 16 heads; prompts of two 2,048-token chunks in the
         # S=4096 bucket; the cell's own limits
@@ -785,7 +795,7 @@ def phase_experts(args) -> dict:
 def _expert_family(args, key: str, cfg, expert_layers: int, reference,
                    sizes_ref: dict, *, window: bool = True,
                    more=None) -> dict:
-    """What the phases ``moe``, ``laguna`` and ``nemotron_h`` share: a
+    """What the phases ``moe``, ``laguna``, ``nemotron_h`` and ``lfm2`` share: a
     family with every expert held, at the published widths and int8,
     through ``TpuBackend.generate`` (with ``window``: GQA window layers and
     prompts longer than the window); its counters; and its logits against
@@ -963,6 +973,56 @@ def phase_nemotron_h(args) -> dict:
                           sizes_ref, window=False, more=state_check)
 
 
+def phase_lfm2(args) -> dict:
+    """The LFM2-MoE family on the one-shot path: the first seven layers
+    ``c c A c c c A`` at the published widths — the gated short convolution
+    at three taps with its two-token tail, rotary QK-normed GQA at 32/8
+    heads of 64, the two dense layers and five layers of 32 gated experts
+    top-4 by sigmoid score + bias, all held — tails, expert counters and
+    keys and values in one carry (``_expert_family``), and the first
+    convolution layer's tail against the reference's after the prompt and
+    after each forced token."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks import reference_lfm2 as reference
+    from benchmarks.engine_setup_lfm2 import sizes_from
+    from vnsum_tpu.models.lfm2 import lfm2_8b_a1b, tiny_lfm2
+
+    sz = sizes(args.rehearsal)
+    make = tiny_lfm2 if args.rehearsal else lfm2_8b_a1b
+    cfg = make(n_layers=sz["lfm2_layers"], max_seq_len=sz["lfm2_seq"])
+    sizes_ref = sizes_from(cfg)
+
+    def tail_check(c, sz, backend, state, ids):
+        n_rows = sz["parity"][2] + 1
+        want = np.asarray(jax.jit(
+            lambda p, t: reference.forward(p, t, sizes_ref,
+                                           last=n_rows)["tail_rows"]
+        )(backend.params, jnp.asarray(ids, jnp.int32)), np.float64)
+        # [rows, first | last, 1, K - 1, D] against [first | last, rows, ..]
+        mine = np.asarray(state["rows"]["tail"].astype(np.float32),
+                          np.float64)[:, 0, 0]
+        errors = [float(np.linalg.norm(mine[r] - want[0, r])
+                        / np.linalg.norm(want[0, r])) for r in range(n_rows)]
+        c.check(f"the first convolution layer's tail within "
+                f"{sz['state_tolerance']} of the reference's, after the "
+                "prompt and after each forced token",
+                max(errors) <= sz["state_tolerance"], errors)
+        blocks = backend.stats.prefill_blocks
+        c.check("the convolution's tokens are counted beside the "
+                "attention's cells",
+                0 < blocks.get("conv_tokens_real", 0)
+                <= blocks.get("conv_tokens_computed", 0)
+                and blocks.get("edge", 0) > 0, blocks)
+        return {"tail_errors": errors,
+                "tail_dtype": str(state["cache"]["conv"].dtype)}
+
+    return _expert_family(args, "lfm2", cfg, cfg.n_sparse, reference,
+                          sizes_ref, window=False, more=tail_check)
+
+
 def phase_ouro(args) -> dict:
     """The dense family LOOPED over its weights (``LlamaConfig.loop_passes``,
     Ouro-2.6B) on the one-shot path: two layers at the published widths run
@@ -1066,7 +1126,8 @@ def _child(args) -> int:
                     "moe": phase_moe,
                     "laguna": phase_laguna,
                     "nemotron_h": phase_nemotron_h,
-                    "ouro": phase_ouro}[phase](args))
+                    "ouro": phase_ouro,
+                    "lfm2": phase_lfm2}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

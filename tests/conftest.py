@@ -12,6 +12,21 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 os.environ.setdefault("JAX_ENABLE_X64", "0")
+# Each xdist worker compiles into a directory of its own. JAX's file cache
+# writes an entry in place (no rename, no lock), so a sibling worker that
+# reads the same key mid-write deserializes a truncated executable: the
+# worker of the driver's six-worker run that held
+# test_model_nemotron_h.py::test_engine_generates_and_counts_scan_cells_and_experts
+# died of a segmentation fault in compilation_cache.get_executable_and_time
+# (the test passes alone and beside its file's others). With the variable
+# set, core/jax_cache.py sets no directory and JAX's own handling stands; a
+# child process a test starts inherits its worker's directory.
+_worker = os.environ.get("PYTEST_XDIST_WORKER")
+if _worker:
+    os.environ.setdefault(
+        "JAX_COMPILATION_CACHE_DIR",
+        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+            __file__))), ".jax_cache", _worker))
 
 import pytest  # noqa: E402
 

@@ -607,7 +607,32 @@ def test_engine_refuses_the_entries_at_construction(tiny, kw):
         _engine(cfg, params, **kw)
 
 
-def test_engine_generates_and_counts_scan_cells_and_experts(tiny):
+@pytest.fixture()
+def no_persistent_compile_cache():
+    """JAX's persistent compile cache off for one test, as
+    tests/test_ops_compile_tpu.py turns it off. In two whole six-worker runs
+    of the suite (the driver's on PR 53's tree, and PR 54's own with a
+    cache directory a worker) the worker that held the test below died of a
+    segmentation fault inside that cache — once reading this program's
+    entry (``compilation_cache.get_executable_and_time``), once writing it
+    (``put_executable_and_time``: ``executable.serialize()``) — while the
+    test passes alone, beside its file's others under six workers ten times
+    of ten, and after libtpu was loaded in its process. What a long-lived
+    worker holds by then that spoils the XLA:CPU executable's
+    (de)serialization was not found; the program is compiled here and kept
+    out of the cache."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def test_engine_generates_and_counts_scan_cells_and_experts(
+        tiny, no_persistent_compile_cache):
     """``TpuBackend.generate`` with every kernel interpreted: the prefill's
     attention cells counted over the ONE attention layer at 4 query heads a
     KV head, the scan's tokens over 4 Mamba layers beside them in

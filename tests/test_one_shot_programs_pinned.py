@@ -70,11 +70,23 @@ layer's rows is gone from the latent family's program and the kernel's
 second prefetched vector holds the rows' place in the cache beside the chunk
 offset (its body unchanged); the other thirteen did not move.
 
+**PR 54 moved none of the fourteen and added `lfm2` and `lfm2-row-pieces`**:
+`models/nemotron_h.py::route` became `models/experts.py::sigmoid_route` (its
+epsilon under the sum static-gated: none for Nemotron),
+`mamba_mixer.causal_conv` takes no bias and no activation for a seventh
+family (`models/lfm2.py`) and `expert_layer` keeps a row piece's picks at
+its own batch rows, and at the Mamba call sites and with no piece handed in
+each traces what it traced. The new pins are the tiny LFM2 program (ten
+layers as three scanned blocks: the gated short convolution, rotary
+QK-normed attention, a dense and a sparse feed-forward) and the same with the
+piece set to its 128-token chunk: the per-row writes of keys, values, tails
+and picks are in it.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
 (`assert_pinned`). `python tests/test_one_shot_programs_pinned.py` (from the
-repo's root, `PYTHONPATH=.`) traces all fourteen, prints both tables as they
+repo's root, `PYTHONPATH=.`) traces all sixteen, prints both tables as they
 would have to read and rewrites that file — the one place that regenerates
 them."""
 from __future__ import annotations
@@ -105,10 +117,13 @@ _PINNED = {
     "llama-row-pieces": ("tiny", {}, "87c61288db10c21a"),
     "llama-looped": ("tiny-ouro", {}, "05e5ea3227bd67de"),
     "granite-h-row-pieces": ("tiny-granite-h", {}, "8ece4d118fa328f3"),
+    "lfm2": ("tiny-lfm2", {}, "2ae2707f3d02f044"),
+    "lfm2-row-pieces": ("tiny-lfm2", {}, "10157522ffa94d1f"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)
-_PIECE_TOKENS = {"llama-row-pieces": 128, "granite-h-row-pieces": 128}
+_PIECE_TOKENS = {"llama-row-pieces": 128, "granite-h-row-pieces": 128,
+                 "lfm2-row-pieces": 128}
 
 # the slot loop's programs of the tiny llama family, "kind-rows" -> the same
 # hash: a join of 1 and of 2 rows, the segment of 4 slots, the adopt of a
@@ -252,7 +267,7 @@ def test_a_slot_program_traces_to_the_pinned_jaxpr(program):
 
 
 def regenerate() -> None:
-    """Trace all fourteen programs, print the two tables' hashes as they are now
+    """Trace all sixteen programs, print the two tables' hashes as they are now
     and rewrite the line ladders."""
     texts = [("_PINNED", family, want,
               one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw),

@@ -3,7 +3,9 @@ expert product at the SmallThinker family's geometry, the Granite-4.0-H
 family's two scan kernels and the GQA kernels at its 64-wide heads, and the
 Nemotron-H family's (the scan kernels at eight groups, the single-product
 expert form at experts stored 1,920 wide for 1,856, the GQA kernels at 16
-query heads on 2 KV heads), compiled
+query heads on 2 KV heads), and the LFM2-MoE family's (the GQA kernels at
+32/8 heads of 64 over 6 layers with a row piece of four rows, the expert
+product at 32 experts of 2048 x 1792), compiled
 for the chip at the published widths, with no chip: the TPU's compiler is installed here and
 compiles for a described v5e. Interpret mode cannot show what this does: a
 slice not aligned to the tiling, too much VMEM, an int8 product Mosaic
@@ -114,6 +116,8 @@ def test_absorbed_decode_kernel_compiles_over_the_576_wide_cache(one_chip):
 _DEEPSEEK = (7, 40, 5120, 1536, "silu", (512, 1280))
 _SMALLTHINKER = (16, 64, 2560, 768, "relu", (768, 2560))
 _LAGUNA = (4, 256, 3072, 1024, "silu", (512, 1536))
+# LFM2-8B-A1B whole: 22 sparse layers of 32 gated experts of 2048 x 1792
+_LFM2 = (22, 32, 2048, 1792, "silu", (896, 1024))
 
 
 @pytest.mark.parametrize("widths,tm,tiles", [
@@ -124,6 +128,9 @@ _LAGUNA = (4, 256, 3072, 1024, "silu", (512, 1536))
     # a piece of 8,192 tokens x 10 picks over 256 held experts; a decode
     # step of 12 rows: no more tiles than its 120 slots
     (_LAGUNA, 256, 577), (_LAGUNA, 32, 121),
+    # a piece of 8,192 tokens x 4 picks over 32 held experts (~1,024 rows
+    # an expert); a decode step of 24 rows
+    (_LFM2, 256, 161), (_LFM2, 32, 36),
 ])
 def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
                                                       tiles):
@@ -161,6 +168,7 @@ def test_grouped_expert_product_compiles_on_int8_rows(one_chip, widths, tm,
     (_DEEPSEEK, 6, 24, 45), (_DEEPSEEK, 6, 4, 25),
     (_SMALLTHINKER, 6, 24, 69), (_SMALLTHINKER, 6, 4, 25),
     (_LAGUNA, 10, 12, 121), (_LAGUNA, 10, 4, 41),
+    (_LFM2, 4, 24, 36), (_LFM2, 4, 4, 17),
 ])
 def test_a_decode_steps_expert_layer_compiles_with_its_dynamic_grid(
         one_chip, widths, k, rows, tiles):
@@ -373,6 +381,32 @@ def test_gqa_decode_kernel_compiles_at_64_wide_heads(one_chip):
         lambda q, cache, pads: flash_decode_attention(
             q, cache, 3, pads, 8200, 4),
         one_chip, ((24, 1, 32, 64), BF16), _int8_cache(4, 24, 8, 8448, 64),
+        ((24,), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("offset", [0, 6144])
+def test_gqa_prefill_kernel_compiles_for_lfm2s_row_piece(one_chip, offset):
+    """LFM2-8B-A1B's geometry: 32/8 rotary QK-normed heads of 64, a row
+    piece of FOUR rows of a 2,048-query chunk (``cache_rows``) over the
+    int8 cache of its 6 attention layers, 24 rows of 8,448 slots."""
+    from vnsum_tpu.ops import flash_attention
+
+    c = _compiled(
+        lambda q, cache, pads, rows: flash_attention.flash_prefill_attention(
+            q, cache, 5, pads, 4, None, offset, rows),
+        one_chip, ((4, 2048, 32, 64), BF16), _int8_cache(6, 24, 8, 8448, 64),
+        ((4,), I32), ((4,), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_gqa_decode_kernel_compiles_over_lfm2s_six_layers(one_chip):
+    from vnsum_tpu.ops.decode_attention import flash_decode_attention
+
+    c = _compiled(
+        lambda q, cache, pads: flash_decode_attention(
+            q, cache, 5, pads, 8200, 4),
+        one_chip, ((24, 1, 32, 64), BF16), _int8_cache(6, 24, 8, 8448, 64),
         ((24,), I32))
     assert "tpu_custom_call" in c.as_text()
 

@@ -190,6 +190,35 @@ def test_a_one_mixer_familys_program_carries_its_component_scopes():
     assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
 
 
+def test_a_short_convolution_familys_program_carries_its_component_scopes():
+    """Every scope ``models/lfm2.py`` adds, under both phases of its
+    one-shot program: the convolution operator's three (``shortconv_in``,
+    ``shortconv``, ``shortconv_out``), the attention layers', ``mlp`` on the
+    two dense layers, the router and the routed experts; and every
+    instruction the map places lies under a phase and a component — no
+    fusion of the layer stack falls beside the scopes."""
+    from vnsum_tpu.models.lfm2 import init_params, tiny_lfm2
+
+    cfg = tiny_lfm2(max_seq_len=128)
+    b = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
+                   max_new_tokens=NEW, seed=1, flash=False,
+                   params=init_params(jax.random.key(0), cfg))
+    b._get_fn(B, S, NEW, b.gen_cfg)
+    (m,) = b.scope_maps()
+    assert m["module"] == "jit_generate"
+    got = paths(m["scopes"])
+    components = ("shortconv_in", "shortconv", "shortconv_out", "qkv",
+                  "kv_write", "attn", "attn_out", "mlp", "router", "experts",
+                  "embed", "lm_head", "sample")
+    for phase in ("prefill", "decode"):
+        assert {f"{phase}/{c}" for c in components} <= got
+    assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
+    # what is under a phase is under one of the components, or is the
+    # phase's own glue (masks, positions, the loop): nothing else is named
+    named = {p.split("/")[1] for p in got if p.count("/") >= 1}
+    assert named <= set(components) | {"emit"}, named - set(components)
+
+
 def test_a_looped_stacks_program_carries_the_norm_between_passes():
     """A stack looped over its weights (``LlamaConfig.loop_passes``) adds
     ONE scope to the dense family's, under both phases: ``loop_norm``, the

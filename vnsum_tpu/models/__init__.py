@@ -85,6 +85,18 @@ def _tiny_nemotron_h(**kw):
     return tiny_nemotron_h(**kw)
 
 
+def _lfm2_8b_a1b(**kw):
+    from .lfm2 import lfm2_8b_a1b
+
+    return lfm2_8b_a1b(**kw)
+
+
+def _tiny_lfm2(**kw):
+    from .lfm2 import tiny_lfm2
+
+    return tiny_lfm2(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -126,6 +138,11 @@ MODEL_REGISTRY = {
     # or position-free GQA - state, counters and keys in one carry
     "nemotron-3-nano-30b-a3b": _nemotron_3_nano,
     "tiny-nemotron-h": _tiny_nemotron_h,
+    # a seventh (models/lfm2.py): gated short-convolution layers whose only
+    # state is a two-token tail beside a few rotary QK-normed GQA layers,
+    # two leading dense layers, then 32 gated experts top-4 by sigmoid + bias
+    "lfm2-8b-a1b": _lfm2_8b_a1b,
+    "tiny-lfm2": _tiny_lfm2,
 }
 
 __all__ = [

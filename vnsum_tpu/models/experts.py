@@ -85,6 +85,27 @@ def last_picks(cache: dict) -> jax.Array:
     return cache["picks"]
 
 
+def sigmoid_route(logits: jax.Array, bias: jax.Array, top_k: int,
+                  scaling: float, denominator_eps: float = 0.0):
+    """logits [T, E] float32, bias [E] -> (expert ids [T, k] int32, weights
+    [T, k]): sigmoid scores over ALL experts; the ``top_k`` largest of
+    score + bias; the weights are the picked experts' scores WITHOUT the
+    bias, renormalised to sum to one (``norm_topk_prob``; over the sum +
+    ``denominator_eps`` where a family's published code adds one), times
+    ``scaling``.
+
+    One rule, two families: ``models/nemotron_h.py`` (128 experts top-6,
+    scaling 2.5, no epsilon) and ``models/lfm2.py`` (32 experts top-4,
+    scaling 1, ``+ 1e-6``)."""
+    scores = jax.nn.sigmoid(logits)
+    _, ids = jax.lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, ids, axis=-1)
+    total = jnp.sum(picked, axis=-1, keepdims=True)
+    if denominator_eps:
+        total = total + denominator_eps
+    return ids.astype(jnp.int32), picked / total * scaling
+
+
 def _quantize_rows(x: jax.Array):
     """x [M, K] -> (int8, per-row float32 scale [M, 1])."""
     x32 = x.astype(jnp.float32)
@@ -284,12 +305,15 @@ def dense_experts(x, local, weights, experts, slot, cfg):
 
 
 def expert_layer(x, picks, real, experts, slot, cache, cfg, experts_fn,
-                 rows: int):
+                 rows: int, cache_rows=None):
     """One layer's routed experts over x [T, D] (``rows`` batch rows of
     T / rows tokens each): ``picks()`` is the family's routing rule and
     gives (expert ids [T, k] int32 over ALL routed experts, weights [T, k]);
     ``real`` (T booleans, any shape) says which tokens are not under a
-    row's left pad (such a token is routed nowhere and counted nowhere). Returns (the weighted
+    row's left pad (such a token is routed nowhere and counted nowhere).
+    ``cache_rows`` [rows] int32: x is a row piece of a batch whose state
+    holds more rows (the engine's prefill), and row b's picks are kept at
+    the state's batch row ``cache_rows[b]``. Returns (the weighted
     sum over each token's picks held here [T, D], the state with this
     layer's counts added)."""
     with jax.named_scope("router"):
@@ -301,13 +325,15 @@ def expert_layer(x, picks, real, experts, slot, cache, cfg, experts_fn,
         tokens = jnp.sum(
             local.reshape(-1, 1) == jnp.arange(cfg.n_held)[None, :],
             axis=0, dtype=jnp.int32)
+        picks_at = (cache["picks"].at[slot] if cache_rows is None
+                    else cache["picks"].at[slot, cache_rows])
         cache = dict(
             cache,
             expert_tokens=cache["expert_tokens"].at[slot].add(tokens),
             slots_routed=cache["slots_routed"]
             + jnp.sum(real, dtype=jnp.int32) * ids.shape[1],
             slots_held=cache["slots_held"] + jnp.sum(held, dtype=jnp.int32),
-            picks=cache["picks"].at[slot].set(
+            picks=picks_at.set(
                 ids.reshape(rows, x.shape[0] // rows, -1)[:, -1]),
         )
         if "decode_touched" in cache and x.shape[0] == rows:

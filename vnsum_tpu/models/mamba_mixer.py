@@ -1,8 +1,9 @@
 """The Mamba-2 mixer two families share (``models/granite_hybrid.py``,
 ``models/nemotron_h.py``): its float32 leaves and how a seed draws them,
-the causal convolution with its tail, the mixer itself over
-``ops/ssd_scan.py`` at any number of groups of B and C, and what the
-engine's seam reads of its state.
+the causal convolution with its tail (``causal_conv``, which
+``models/lfm2.py``'s convolution operator calls too, with no bias and no
+activation at three taps), the mixer itself over ``ops/ssd_scan.py`` at any
+number of groups of B and C, and what the engine's seam reads of its state.
 
 What a config has to say: ``dim``, ``mamba_n_heads``, ``mamba_d_head``,
 ``mamba_d_state``, ``mamba_n_groups``, ``mamba_d_conv``,
@@ -106,18 +107,25 @@ def init_mamba_state(cfg, batch: int) -> dict:
     }
 
 
-def causal_conv(xbc, tail, w, b):
-    """Depth-wise causal convolution and silu: xbc [B, S, C] after ``tail``
-    [B, K - 1, C], the K - 1 inputs before it; w [C, K], b [C]. Returns
-    (silu(conv) [B, S, C] float32, the new tail)."""
+def causal_conv(xbc, tail, w, b=None, act=jax.nn.silu):
+    """Depth-wise causal convolution at any number of taps: xbc [B, S, C]
+    after ``tail`` [B, K - 1, C], the K - 1 inputs before it; w [C, K];
+    ``b`` [C] or None for no bias; ``act`` the activation on the sum or
+    None for none. Returns (act(conv) [B, S, C] float32, the new tail).
+
+    Who calls it: ``mamba_mixer`` below (Granite's and Nemotron-H's Mamba
+    layers: four taps, a bias, silu, then the scan) and
+    ``models/lfm2.py``'s convolution operator (three taps over the gated
+    product, no bias, no activation: the convolution IS the mixer)."""
     K = w.shape[-1]
     S = xbc.shape[1]
     ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
-    acc = b.astype(jnp.float32)
+    acc = None if b is None else b.astype(jnp.float32)
     for j in range(K):
-        acc = acc + w[:, j].astype(jnp.float32) * ext[:, j:j + S].astype(
+        tap = w[:, j].astype(jnp.float32) * ext[:, j:j + S].astype(
             jnp.float32)
-    return jax.nn.silu(acc), ext[:, S:]
+        acc = tap if acc is None else acc + tap
+    return (acc if act is None else act(acc)), ext[:, S:]
 
 
 def mamba_mixer(h, lp, slot, valid, cache, cfg, scan_kernels: bool,

@@ -10,9 +10,11 @@ Mamba-2 mixer is ``models/mamba_mixer.py``'s (shared with
 layer ``models/experts.py``'s in the two-matrix form, its attention layers
 ``models/llama.py``'s — the ``[L, B, KV, C, hd]`` cache over those layers
 only, ``_write_kv``, ``_cache_attention`` and the two flash kernels at 16
-query heads a KV head. What it owns is the config, the parameters, its
-routing rule, the state and ``forward``. ``FAMILY`` at the end is what the
-engine's seam picks up for a ``NemotronHConfig``.
+query heads a KV head. What it owns is the config, the parameters, the
+state and ``forward``; its routing rule is ``models/experts.py``'s
+``sigmoid_route`` (``route`` here), which ``models/lfm2.py`` calls too.
+``FAMILY`` at the end is what the engine's seam picks up for a
+``NemotronHConfig``.
 
 The equations (``benchmarks/reference_nemotron_h.py`` is the same in plain
 float32, the recurrence token by token):
@@ -66,6 +68,7 @@ from .experts import (
     expert_layer,
     grouped_experts,
     init_expert_state,
+    sigmoid_route as route,   # the rule, shared with models/lfm2.py
 )
 from .llama import (
     _attention_supported,
@@ -297,22 +300,6 @@ def init_cache(cfg: NemotronHConfig, batch: int, cache_len: int, *,
         **init_expert_state(cfg.n_sparse, cfg.n_held, batch,
                             cfg.num_experts_per_tok, decode_touched=True),
     }
-
-
-# -- routing ------------------------------------------------------------------
-
-
-def route(logits: jax.Array, bias: jax.Array, top_k: int, scaling: float):
-    """logits [T, E] float32, bias [E] -> (expert ids [T, k] int32, weights
-    [T, k]): sigmoid scores over ALL experts; the ``top_k`` largest of
-    score + bias; the weights are the picked experts' scores WITHOUT the
-    bias, renormalised to sum to one (``norm_topk_prob``), times
-    ``scaling``."""
-    scores = jax.nn.sigmoid(logits)
-    _, ids = jax.lax.top_k(scores + bias, top_k)
-    picked = jnp.take_along_axis(scores, ids, axis=-1)
-    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
-    return ids.astype(jnp.int32), weights
 
 
 # -- the mixers and forward ---------------------------------------------------

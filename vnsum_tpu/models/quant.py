@@ -52,6 +52,10 @@ _ALL_CONTRACT_AXES = {
     # A_log, D and gated norm stay float32
     "in_z": (0,), "in_xbc": (0,), "in_dt": (0,),   # [D, inner | conv | H]
     "out_proj": (0,),                              # [inner, D]
+    # a gated short-convolution operator's products (models/lfm2.py: in_proj
+    # held as its parts B | C | x; its out_proj is "out_proj" above); the
+    # taps stay float32
+    "in_b": (0,), "in_c": (0,), "in_x": (0,),      # [D, D]
 }
 # the groups of stacked layers a params tree may hold: every family has
 # "layers"; one with leading dense layers keeps them under "dense"; one
@@ -61,8 +65,12 @@ _ALL_CONTRACT_AXES = {
 # feed-forward under "layers" (models/granite_hybrid.py); one whose layers
 # are each ONE mixer keeps the sparse-expert layers under "layers"
 # (models/nemotron_h.py: two matrices an expert, "we_up" and "we_down"; its
-# router and the router's correction bias stay float32)
-_LAYER_GROUPS = ("layers", "dense", "full", "sliding", "mamba", "attn")
+# router and the router's correction bias stay float32); one that mixes
+# convolution operators and attention keeps each kind's operator under
+# "conv" and "attn", its leading dense feed-forwards under "dense" and the
+# routers and experts under "layers" (models/lfm2.py)
+_LAYER_GROUPS = ("layers", "dense", "full", "sliding", "mamba", "attn",
+                 "conv")
 
 
 def _quantize(w: jax.Array, contract_axes: tuple[int, ...]) -> dict:
