@@ -10,7 +10,11 @@ group's first dispatch (four tails of 20 to 75% pad).
 
 ``--other LABEL=FILE`` (repeatable) also times another tree's
 ``ops/decode_attention.py`` (read from FILE, its relative import pointed at
-this tree's ``flash_attention``) at its own default geometry, under LABEL.
+its own tree's ``flash_attention`` beside it) at its own default geometry,
+under LABEL, over the same numbers one KV head a lane tile; this tree's
+kernel reads them as ``init_kv_cache`` lays them out (64-wide heads two a
+tile). ``cache_bytes`` is what the compiled program holds of each layout's
+cache in HBM (``memory_analysis().argument_size_in_bytes``).
 
     chiprun -- python3 scripts/profile_decode_blocks.py --other \
         parent=.chip_checkout/parent/vnsum_tpu/ops/decode_attention.py
@@ -30,7 +34,9 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from vnsum_tpu.models.llama import heads_to_tiles  # noqa: E402
 from vnsum_tpu.ops import decode_attention as ours  # noqa: E402
+from vnsum_tpu.ops.flash_attention import heads_per_lane_tile  # noqa: E402
 
 LAYERS = 6
 C_OFFLINE, C_SERVED, FILL = 8448, 8320, 8320
@@ -62,11 +68,30 @@ def _pads(name: str, rows: int, mix: bool) -> np.ndarray:
 
 
 def _load_other(label: str, path: str):
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        f"{label}_flash_attention", Path(path).with_name("flash_attention.py"))
+    flash = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = flash
+    spec.loader.exec_module(flash)
     text = Path(path).read_text().replace(
-        "from .flash_attention import", "from vnsum_tpu.ops.flash_attention import")
+        "from .flash_attention import", f"from {spec.name} import")
     mod = types.ModuleType(f"{label}_decode_attention")
     exec(compile(text, path, "exec"), mod.__dict__)
     return mod
+
+
+def _tiled(cache: dict, tile: int) -> dict:
+    """The cache as ``init_kv_cache`` lays it out: ``tile`` KV heads a lane
+    tile, the scales a head."""
+    return dict(cache, k=heads_to_tiles(cache["k"], tile),
+                v=heads_to_tiles(cache["v"], tile))
+
+
+def _cache_bytes(cache: dict) -> int:
+    compiled = jax.jit(lambda c: c["k"][0, 0, 0, 0, 0]).lower(cache).compile()
+    return int(compiled.memory_analysis().argument_size_in_bytes)
 
 
 def _inputs(rows, KV, G, hd, C):
@@ -135,15 +160,21 @@ def main() -> None:
             if wanted and name not in wanted:
                 continue
             q, cache = _inputs(rows, KV, G, hd, C)
+            tile = heads_per_lane_tile(KV, hd)
+            tiled = _tiled(cache, tile)
             for mix in (False, True):
                 row = {"shape": name, "rows": rows, "kv": KV, "g": G, "hd": hd,
                        "window": window, "pads": "mix" if mix else "all-live",
-                       "rule_block_k": ours.decode_block_k(KV, hd, 1, C)}
+                       "heads_a_tile": tile,
+                       "rule_block_k": ours.decode_block_k(
+                           KV // tile, hd * tile, 1, C),
+                       "cache_bytes": {"a head a tile": _cache_bytes(cache),
+                                       "tiled": _cache_bytes(tiled)}}
                 for label, mod, bk in geometries:
                     try:
                         row[label] = round(_time(
-                            mod, kind, q, cache, G, window,
-                            _pads(name, rows, mix), bk, args.calls,
+                            mod, kind, q, tiled if mod is ours else cache, G,
+                            window, _pads(name, rows, mix), bk, args.calls,
                             args.repeats), 4)
                     except Exception as e:  # a geometry Mosaic refuses
                         row[label] = f"{type(e).__name__}: {str(e)[:120]}"

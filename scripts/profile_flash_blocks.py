@@ -123,6 +123,15 @@ def main() -> int:
         "vs": jax.random.uniform(vs, (1, B, KV, C), jnp.float32, 0.01, 0.02),
     }
 
+    # this tree's kernel reads the cache as init_kv_cache lays it out
+    # (64-wide heads two a lane tile); a parent file reads the same numbers
+    # a head a tile
+    from vnsum_tpu.models.llama import heads_to_tiles
+
+    tile = flash_attention.heads_per_lane_tile(KV, hd)
+    tiled = dict(cache, k=heads_to_tiles(cache["k"], tile),
+                 v=heads_to_tiles(cache["v"], tile))
+
     def program(mod, bq, bk):
         @jax.jit
         def run(q, cache, pad, win, off, calls):
@@ -170,7 +179,8 @@ def main() -> int:
                             block_q=bq, block_k=bk)
                         computed = ((n["interior"] + n["edge"]) * KV
                                     * layers[win])
-                        a = (q, cache, pad, jnp.int32(win), jnp.int32(off))
+                        a = (q, cache if label == "parent" else tiled, pad,
+                             jnp.int32(win), jnp.int32(off))
                         one = run(*a, jnp.int32(1)).block_until_ready()
                         if label == "parent":
                             reference[d, win, off] = one

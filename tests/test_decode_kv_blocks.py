@@ -37,12 +37,14 @@ S = 8192
 
 
 def _counter(cfg, quantize_kv=True, kernels=True):
-    """The engine's counter on a bare object: a configuration, its family and
-    the statistics are all it reads (no parameters, no program)."""
+    """The engine's counter on a bare object: a configuration, its family,
+    the mesh (none) and the statistics are all it reads (no parameters, no
+    program)."""
     be = types.SimpleNamespace(
-        cfg=cfg, family=family_of(cfg), quantize_kv=quantize_kv,
+        cfg=cfg, family=family_of(cfg), quantize_kv=quantize_kv, mesh=None,
         stats=EngineStats(), _decode_settings=lambda S, C: (kernels, kernels))
     be.count = types.MethodType(TpuBackend._count_decode_kv_blocks, be)
+    be._model_shards = types.MethodType(TpuBackend._model_shards, be)
     return be
 
 
@@ -101,6 +103,27 @@ def test_an_offline_groups_first_dispatch_skips_its_tails_pad(cfg, rows):
     assert be.count(np.zeros(rows, np.int64), fills, S, S + 256) == (0, got[1])
     assert be.stats.decode_kv_blocks_skipped == got[0]
     assert be.stats.decode_kv_blocks_total == 2 * got[1]
+
+
+def test_heads_of_64_count_their_blocks_by_the_pairs_tile():
+    """Llama-3.2-1B's 8 KV heads of 64 sit two a lane tile: the kernels walk
+    4 tiles of 128 in blocks of 1,024 slots (9 a row at 8,448), and every
+    block counted is counted as paired; at heads of 128 none is."""
+    from vnsum_tpu.models.llama import llama32_1b
+
+    cfg = llama32_1b()
+    assert (cfg.n_kv_heads, cfg.head_dim) == (8, 64)
+    assert decode_block_k(4, 128, 1, S + 256) == 1024
+    be = _counter(cfg)
+    fills = S + np.arange(256)[:, None]
+    got = be.count(S - np.asarray(_OFFLINE), fills, S, S + 256)
+    assert got == _by_hand(_OFFLINE, 256, 1024, cfg.n_layers)
+    assert got[1] == 256 * 8 * 9 * cfg.n_layers
+    assert be.stats.decode_kv_blocks_paired == got[1]
+    wide = _counter(qwen3_8b())
+    wide.count(S - np.asarray(_OFFLINE), fills, S, S + 256)
+    assert wide.stats.decode_kv_blocks_total > 0
+    assert wide.stats.decode_kv_blocks_paired == 0
 
 
 def test_rows_that_ended_stay_where_they_were_and_a_free_slot_is_all_pad():

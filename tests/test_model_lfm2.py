@@ -257,7 +257,9 @@ def test_the_state_is_three_kinds_side_by_side():
         lfm2.lfm2_8b_a1b(), 24, 8448, quantized=True))
     assert full["conv"].shape == (18, 24, 2, 2048)
     assert full["conv"].dtype == jnp.bfloat16
-    assert full["k"].shape == (6, 24, 8, 8448, 64)
+    # 8 KV heads of 64, two a lane tile; the scales a head (PR 55)
+    assert full["k"].shape == full["v"].shape == (6, 24, 4, 8448, 128)
+    assert full["ks"].shape == full["vs"].shape == (6, 24, 8, 8448)
     assert full["expert_tokens"].shape == (22, 32)
 
 

@@ -4,7 +4,8 @@ family's two scan kernels and the GQA kernels at its 64-wide heads, and the
 Nemotron-H family's (the scan kernels at eight groups, the single-product
 expert form at experts stored 1,920 wide for 1,856, the GQA kernels at 16
 query heads on 2 KV heads), and the LFM2-MoE family's (the GQA kernels at
-32/8 heads of 64 over 6 layers with a row piece of four rows, the expert
+32/8 heads of 64, two KV heads a lane tile of the cache as ``init_kv_cache``
+lays it out, over 6 layers with a row piece of four rows, the expert
 product at 32 experts of 2048 x 1792), compiled
 for the chip at the published widths, with no chip: the TPU's compiler is installed here and
 compiles for a described v5e. Interpret mode cannot show what this does: a
@@ -223,8 +224,14 @@ def test_expert_combine_compiles_at_a_piece_of_the_cells(one_chip, widths, k,
     assert "expert_combine" in c.as_text()
 
 
-def _int8_cache(L, B, KV, C, hd):
-    return {"k": ((L, B, KV, C, hd), I8), "v": ((L, B, KV, C, hd), I8),
+def _int8_cache(L, B, KV, C, hd, tile=None):
+    """``init_kv_cache``'s shapes: ``tile`` KV heads a lane tile (None: the
+    rule's — two of 64, one of 128), the scales a head."""
+    from vnsum_tpu.ops.flash_attention import heads_per_lane_tile
+
+    tile = tile or heads_per_lane_tile(KV, hd)
+    kv = ((L, B, KV // tile, C, hd * tile), I8)
+    return {"k": kv, "v": kv,
             "ks": ((L, B, KV, C), F32), "vs": ((L, B, KV, C), F32)}
 
 
@@ -357,31 +364,54 @@ def test_gqa_decode_kernel_compiles_at_g7_under_a_window(one_chip):
 # -- the Granite-4.0-H family: the scan kernels, the GQA kernels at hd 64 ----
 
 
+@pytest.mark.parametrize("tile", [2, 1])
 @pytest.mark.parametrize("offset", [0, 6144])
-def test_gqa_prefill_kernel_compiles_at_64_wide_heads(one_chip, offset):
-    """32/8 heads of 64 as blocks of the array's own width (half of each
-    lane tile holds nothing), a 2,048-query chunk of the S=8192 bucket over
-    the int8 cache of the 4 attention layers, 24 rows."""
+def test_gqa_prefill_kernel_compiles_at_64_wide_heads(one_chip, offset, tile):
+    """32/8 heads of 64, a 2,048-query chunk of the S=8192 bucket over the
+    int8 cache of the 4 attention layers, 24 rows: the cache as
+    ``init_kv_cache`` lays it out, two KV heads a lane tile (a cell takes
+    its head's half of the pair's tile by a lane slice Mosaic has to take),
+    and one a tile, blocks of the array's own width (what an odd KV count
+    or a tensor axis that splits the pairs falls back to)."""
     from vnsum_tpu.ops import flash_attention
 
     assert flash_attention.head_dim_supported(64)
     assert not flash_attention.head_dim_supported(96)
+    cache = _int8_cache(4, 24, 8, 8448, 64, tile)
+    assert cache["k"][0] == (4, 24, 8 // tile, 8448, 64 * tile)
     c = _compiled(
         lambda q, cache, pads: flash_attention.flash_prefill_attention(
             q, cache, 1, pads, 4, None, offset),
-        one_chip, ((24, 2048, 32, 64), BF16), _int8_cache(4, 24, 8, 8448, 64),
-        ((24,), I32))
+        one_chip, ((24, 2048, 32, 64), BF16), cache, ((24,), I32))
     assert "tpu_custom_call" in c.as_text()
 
 
-def test_gqa_decode_kernel_compiles_at_64_wide_heads(one_chip):
+@pytest.mark.parametrize("tile", [2, 1])
+def test_gqa_decode_kernel_compiles_at_64_wide_heads(one_chip, tile):
+    """Two KV heads a tile: 4 tiles of 128 in blocks of 1,024 slots, a
+    tile's 8 rows two heads' groups; and the fallback, one a tile."""
     from vnsum_tpu.ops.decode_attention import flash_decode_attention
 
     c = _compiled(
         lambda q, cache, pads: flash_decode_attention(
             q, cache, 3, pads, 8200, 4),
-        one_chip, ((24, 1, 32, 64), BF16), _int8_cache(4, 24, 8, 8448, 64),
-        ((24,), I32))
+        one_chip, ((24, 1, 32, 64), BF16),
+        _int8_cache(4, 24, 8, 8448, 64, tile), ((24,), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("Sq", [1, 5])
+def test_gqa_verify_kernel_compiles_over_paired_heads(one_chip, Sq):
+    """llama3.2-1b's slot segment (one query a row) and speculative verify
+    (k = 4) over 8 KV heads of 64, two a tile: the limits' rows pair off as
+    the queries' do."""
+    from vnsum_tpu.ops.decode_attention import flash_spec_verify_attention
+
+    c = _compiled(
+        lambda q, cache, pads, fills: flash_spec_verify_attention(
+            q, cache, 3, pads, fills, 4),
+        one_chip, ((8, Sq, 32, 64), BF16), _int8_cache(16, 8, 8, 8320, 64),
+        ((8,), I32), ((8,), I32))
     assert "tpu_custom_call" in c.as_text()
 
 

@@ -236,6 +236,10 @@ class EngineStats:
     # pads
     decode_kv_blocks_total: int = 0
     decode_kv_blocks_skipped: int = 0
+    # those of ``decode_kv_blocks_total`` that held two KV heads a lane tile
+    # (64-wide heads paired in the cache, ``models.llama.init_kv_cache``):
+    # all of them or none, by the model's shapes
+    decode_kv_blocks_paired: int = 0
     # which attention each built program got, keyed "program[B=..,S=..]" →
     # {"prefill"|"decode": "kernel"|"dense"}: a dense fallback (unaligned
     # head dim, the slot/verify kernel under a mesh) is visible here and in
@@ -630,9 +634,8 @@ class TpuBackend:
             "state_bytes_per_row": {
                 name: leaf.size * leaf.dtype.itemsize
                 for name, leaf in jax.eval_shape(
-                    lambda: self.family.init_cache(
-                        self.cfg, 1, self.cfg.max_seq_len,
-                        quantized=self.quantize_kv)).items()},
+                    lambda: self._init_cache(
+                        1, self.cfg.max_seq_len)).items()},
             "memory": [
                 {k: int(v) for k, v in (d.memory_stats() or {}).items()
                  if k in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit")}
@@ -667,8 +670,7 @@ class TpuBackend:
         done = lambda n: jax.ShapeDtypeStruct((n,), jnp.bool_)  # noqa: E731
 
         def cache_of(B, C):
-            return jax.eval_shape(lambda: self.family.init_cache(
-                self.cfg, B, C, quantized=self.quantize_kv))
+            return jax.eval_shape(lambda: self._init_cache(B, C))
 
         programs = []   # (label, jitted function, arguments)
         for key in self._fns:
@@ -1008,11 +1010,24 @@ class TpuBackend:
 
         return layer_window
 
+    def _model_shards(self) -> int:
+        """The mesh's tensor axis, which the cache's KV heads divide over."""
+        return 1 if self.mesh is None else self.mesh.shape.get("model", 1)
+
+    def _init_cache(self, B: int, C: int):
+        """The family's state for ``B`` rows of ``C`` slots. Under a mesh
+        (the llama family alone) the cache is told the tensor axis its heads
+        divide over: heads stored two a lane tile must pair off inside a
+        shard (``models.llama.init_kv_cache``)."""
+        shards = ({} if self.mesh is None
+                  else {"model_shards": self._model_shards()})
+        return self.family.init_cache(
+            self.cfg, B, C, quantized=self.quantize_kv, **shards)
+
     def _init_prefill_cache(self, B: int, C: int):
         """Fresh KV cache with the mesh layout pinned (batch over data,
         heads over model) instead of left to GSPMD propagation."""
-        cache = self.family.init_cache(
-            self.cfg, B, C, quantized=self.quantize_kv)
+        cache = self._init_cache(B, C)
         if self.mesh is not None:
             from jax.sharding import NamedSharding
 
@@ -1173,12 +1188,16 @@ class TpuBackend:
                 and self._decode_settings(S, C)[1]):
             return 0, 0
         from ..ops.decode_attention import decode_block_k
+        from ..ops.flash_attention import heads_per_lane_tile
 
         cfg = self.cfg
         windows = (self.family.layer_windows(cfg)
                    or (0,) * self.family.attention_layers(cfg))
+        # the cache's own shape: KV heads two a lane tile where they pair
+        tile = heads_per_lane_tile(
+            cfg.n_kv_heads, cfg.head_dim, self._model_shards())
         bk = decode_block_k(
-            cfg.n_kv_heads, cfg.head_dim,
+            cfg.n_kv_heads // tile, cfg.head_dim * tile,
             1 if self.quantize_kv else jnp.dtype(cfg.dtype).itemsize, C)
         # a row that spent its budget sits one past the cache's last slot
         under_pad, walked = np.broadcast_arrays(
@@ -1189,6 +1208,8 @@ class TpuBackend:
         skipped = int(np.minimum(under_pad, walked).sum()) * layers
         self.stats.decode_kv_blocks_total += total
         self.stats.decode_kv_blocks_skipped += skipped
+        if tile > 1:
+            self.stats.decode_kv_blocks_paired += total
         return skipped, total
 
     def _prefill_spans(self, S: int, start: int = 0) -> list[tuple[int, int]]:
@@ -1977,6 +1998,7 @@ class TpuBackend:
             "prefill_row_chunks_dead": st.prefill_row_chunks_dead,
             "decode_kv_blocks": st.decode_kv_blocks_total,
             "decode_kv_blocks_skipped": st.decode_kv_blocks_skipped,
+            "decode_kv_blocks_paired": st.decode_kv_blocks_paired,
             "executions_held": st.executions_held,
             "held_excess_seconds": st.held_excess_seconds,
         }

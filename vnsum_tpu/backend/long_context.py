@@ -54,6 +54,7 @@ from ..models.llama import (
     _rmsnorm,
     _rope_cos_sin,
     cache_free_block,
+    dequantize_cache_layer,
     forward,
     init_kv_cache,
     prefill_positions,
@@ -327,12 +328,8 @@ def make_long_decode_attention(
         o1, m1, l1 = partial_fn(*args)
 
         # decode-cache partial (replicated math; C = max_new is small)
-        k_dec = jax.lax.dynamic_index_in_dim(
-            cache["k"], layer_idx, 0, keepdims=False
-        )  # [B, KV, C, hd]
-        v_dec = jax.lax.dynamic_index_in_dim(
-            cache["v"], layer_idx, 0, keepdims=False
-        )
+        # [B, KV, C, hd], a head apiece however the cache tiles them
+        k_dec, v_dec = dequantize_cache_layer(cache, layer_idx, hd)
         KV = k_dec.shape[1]
         C = k_dec.shape[2]
         qg = q1.reshape(B, KV, q_per_kv, hd)

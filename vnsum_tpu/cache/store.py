@@ -3,7 +3,9 @@
 The pool mirrors the stacked cache layout the attention kernels consume
 (models/llama.py init_kv_cache: [L, B, KV, C, hd], scales [L, B, KV, C]):
 one pool row per block, [L, KV, BLK, hd] (and [L, KV, BLK] for int8-KV
-scales), so extraction and gather are pure layout-preserving copies — no
+scales; 64-wide heads two a lane tile as the cache keeps them, [L, KV/2,
+BLK, 128] — the pool moves whole slots and never looks inside a tile), so
+extraction and gather are pure layout-preserving copies — no
 transpose ever materializes on device.
 
 Blocks are POSITION-CONTIGUOUS: a block holds the KV of BLK consecutive
@@ -75,7 +77,15 @@ class BlockStore:
         self.scratch_id = num_blocks
         self.mesh = mesh
         N = num_blocks + 1
-        shape = (N, n_layers, n_kv_heads, block_tokens, head_dim)
+        model_size = 1 if mesh is None else mesh.shape.get("model", 1)
+        # the batch cache's own tiling of its heads (models/llama.py
+        # init_kv_cache): 64-wide heads two a lane tile, the scales a head
+        from ..ops.flash_attention import heads_per_lane_tile
+
+        tile = heads_per_lane_tile(n_kv_heads, head_dim, model_size)
+        heads = (N, n_layers, n_kv_heads, block_tokens)
+        shape = (N, n_layers, n_kv_heads // tile, block_tokens,
+                 head_dim * tile)
         # [N, L, KV(, BLK, hd)]: KV heads over `model`, rest replicated —
         # allocated DIRECTLY into the sharding (a production pool is sized
         # against the mesh's combined HBM; materializing it on one chip
@@ -86,7 +96,6 @@ class BlockStore:
 
             from ..parallel.mesh import AXES
 
-            model_size = mesh.shape.get(AXES.model, 1)
             if n_kv_heads % max(model_size, 1):
                 raise ValueError(
                     f"n_kv_heads={n_kv_heads} is not divisible by mesh axis "
@@ -109,8 +118,8 @@ class BlockStore:
             self.pool = {
                 "k": zeros(shape, jnp.int8),
                 "v": zeros(shape, jnp.int8),
-                "ks": zeros(shape[:-1], jnp.float32),
-                "vs": zeros(shape[:-1], jnp.float32),
+                "ks": zeros(heads, jnp.float32),
+                "vs": zeros(heads, jnp.float32),
             }
         else:
             self.pool = {

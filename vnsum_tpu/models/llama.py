@@ -259,10 +259,22 @@ def init_params(key: jax.Array, cfg: LlamaConfig) -> dict:
 
 
 def init_kv_cache(
-    cfg: LlamaConfig, batch: int, cache_len: int, *, quantized: bool = False
+    cfg: LlamaConfig, batch: int, cache_len: int, *, quantized: bool = False,
+    model_shards: int = 1,
 ) -> dict:
     """Stacked cache [L, B, KV, C, hd] — KV heads BEFORE the sequence dim;
     L is ``cache_layers(cfg)``, a layer a (pass, layer) of a looped stack.
+
+    Heads half a lane tile wide (hd 64) are stored TWO A TILE where they
+    pair off (``ops.flash_attention.heads_per_lane_tile``; ``model_shards``
+    is the mesh's tensor axis, which the pairs must still divide over):
+    [L, B, KV/2, C, 128], heads 2p and 2p+1 in lanes 0-63 and 64-127 of
+    tile p, so that what streams the cache moves and multiplies full tiles.
+    The scales stay a head, [L, B, KV, C]. Shapes alone decide; what writes
+    and reads the cache takes the heads a tile off its last dim
+    (``_write_kv``, ``dequantize_cache_layer``, the kernels' wrappers), and
+    what moves whole slots of it (``_cache_write``, ``cache/store.py``)
+    never looks inside a tile.
 
     This is the layout the attention einsums consume directly ((b, kv) as
     batch dims, hd/c as the minor contraction dims). With the sequence dim
@@ -275,14 +287,19 @@ def init_kv_cache(
     scales ``ks``/``vs`` [L, B, KV, C] — decode attention streams the whole
     cache every step, so this halves its HBM traffic (decode attention is
     the largest decode-phase cost once weights are int8)."""
-    shape = (cache_layers(cfg), batch, cfg.n_kv_heads, cache_len, cfg.head_dim)
+    from ..ops.flash_attention import heads_per_lane_tile
+
+    heads = (cache_layers(cfg), batch, cfg.n_kv_heads, cache_len)
+    tile = heads_per_lane_tile(cfg.n_kv_heads, cfg.head_dim, model_shards)
+    shape = (*heads[:2], cfg.n_kv_heads // tile, cache_len,
+             cfg.head_dim * tile)
     if not quantized:
         return {"k": jnp.zeros(shape, cfg.dtype), "v": jnp.zeros(shape, cfg.dtype)}
     return {
         "k": jnp.zeros(shape, jnp.int8),
         "v": jnp.zeros(shape, jnp.int8),
-        "ks": jnp.zeros(shape[:-1], jnp.float32),
-        "vs": jnp.zeros(shape[:-1], jnp.float32),
+        "ks": jnp.zeros(heads, jnp.float32),
+        "vs": jnp.zeros(heads, jnp.float32),
     }
 
 
@@ -299,10 +316,35 @@ def _quantize_kv(x: jax.Array):
     return q, scale[..., 0]
 
 
-def dequantize_cache_layer(cache: dict, layer_idx) -> tuple[jax.Array, jax.Array]:
-    """Extract layer `layer_idx` as dense float K/V [B, KV, C, hd]."""
+def heads_to_tiles(x: jax.Array, tile: int) -> jax.Array:
+    """x [..., KV, S, hd] -> [..., KV/tile, S, tile * hd]: ``tile``
+    neighbouring heads side by side on the lanes (``init_kv_cache``)."""
+    if tile == 1:
+        return x
+    *lead, KV, S, hd = x.shape
+    x = x.reshape(*lead, KV // tile, tile, S, hd)
+    return jnp.moveaxis(x, -3, -2).reshape(*lead, KV // tile, S, tile * hd)
+
+
+def tiles_to_heads(x: jax.Array, tile: int) -> jax.Array:
+    """The way back: x [..., KV/tile, S, tile * hd] -> [..., KV, S, hd]."""
+    if tile == 1:
+        return x
+    *lead, T, S, width = x.shape
+    x = x.reshape(*lead, T, S, tile, width // tile)
+    return jnp.moveaxis(x, -2, -3).reshape(*lead, T * tile, S, width // tile)
+
+
+def dequantize_cache_layer(cache: dict, layer_idx,
+                           head_dim: int | None = None,
+                           ) -> tuple[jax.Array, jax.Array]:
+    """Extract layer `layer_idx` as dense float K/V [B, KV, C, hd], a head
+    of ``head_dim`` apiece where the cache holds several a lane tile (None:
+    the cache's last dim is the head)."""
     k = jax.lax.dynamic_index_in_dim(cache["k"], layer_idx, 0, keepdims=False)
     v = jax.lax.dynamic_index_in_dim(cache["v"], layer_idx, 0, keepdims=False)
+    tile = k.shape[-1] // (head_dim or k.shape[-1])
+    k, v = tiles_to_heads(k, tile), tiles_to_heads(v, tile)
     if not is_quantized_cache(cache):
         return k, v
     ks = jax.lax.dynamic_index_in_dim(cache["ks"], layer_idx, 0, keepdims=False)
@@ -553,6 +595,11 @@ def _write_kv(cache: dict, k, v, layer_idx, write_index, rows=None) -> dict:
         if is_quantized_cache(cache):
             new["k"], new["ks"] = _quantize_kv(new["k"])
             new["v"], new["vs"] = _quantize_kv(new["v"])
+        # the numbers stored are a head's whatever the tile holds: heads
+        # side by side where the cache keeps several a lane tile
+        tile = cache["k"].shape[-1] // k.shape[-1]
+        new["k"] = heads_to_tiles(new["k"], tile)
+        new["v"] = heads_to_tiles(new["v"], tile)
         return dict(cache, **{
             name: _cache_write(cache[name], val, layer_idx, write_index, rows)
             for name, val in new.items()})
@@ -571,7 +618,8 @@ def _cache_attention(q, cache: dict, layer_idx, mask, q_per_kv: int,
             # reads the stacked cache in place (Pallas kernels): no
             # per-layer extraction copy materializes
             return stacked_attention_fn(q, cache, layer_idx)
-        k_cache, v_cache = dequantize_cache_layer(cache, layer_idx)
+        k_cache, v_cache = dequantize_cache_layer(
+            cache, layer_idx, q.shape[-1])
         if rows is not None:
             k_cache, v_cache = k_cache[rows], v_cache[rows]
         k_cache = k_cache.astype(q.dtype)
@@ -958,9 +1006,12 @@ def _attention_supported(cfg: LlamaConfig, S: int, C: int):
 
 def _group_of(q, cache: dict) -> int:
     """Query heads a KV head, off the operands: q [B, S, H, hd] over the
-    stacked cache [L, B, KV, C, hd]. A family whose layers differ in their
-    query heads (models/laguna.py) hands each layer's own queries in."""
-    return q.shape[2] // cache["k"].shape[2]
+    stacked cache [L, B, KV, C, hd] (or its KV heads two a lane tile,
+    ``init_kv_cache``). A family whose layers differ in their query heads
+    (models/laguna.py) hands each layer's own queries in."""
+    # all the queries' lanes over all the keys', however the heads tile
+    return q.shape[2] * q.shape[3] // (
+        cache["k"].shape[2] * cache["k"].shape[4])
 
 
 def _prefill_attention(cfg: LlamaConfig, mesh, interpret: bool, pad_lens,

@@ -25,9 +25,10 @@ upcast, multiplied and masked away. Now a block carries all KV heads of ONE
 row over ``bk`` slots, ``bk`` chosen from the shapes so that the block's
 keys stay ~512 KiB as VMEM tiles them — a slot weighed as 8 heads of 128
 at most, so 1 MiB at 16 heads — (``decode_block_k``: 512 slots at KV=8 x hd=128, at Phi-4's KV=10 and at
-Ouro's KV=16, 1,024 at SmallThinker's KV=4, 512 at
-Granite-4.0-H's 64-wide heads, which fill half of each lane tile, 2,048 at
-Nemotron-H's KV=2; half the slots for a bfloat16 cache), and each row
+Ouro's KV=16, 1,024 at SmallThinker's KV=4 and at Granite-4.0-H's and
+LFM2's 8 heads of 64, which the cache holds as 4 tiles of 128 — "two KV
+heads a lane tile" below —, 2,048 at Nemotron-H's KV=2; half the slots for
+a bfloat16 cache), and each row
 walks its own blocks: grid step j of row b is block ``first_b + j``, from
 the block of the row's first real slot (under a sliding window, of its
 window's floor) to the block of its fill, and the grid takes as many steps
@@ -55,6 +56,8 @@ group's four tails of 20-75% pad):
   Laguna full   12 x 8 x 6         0.3427 / 0.3442     0.3050 / 0.2671  (512)
    ... sliding, G=9, window 512    0.0785 / 0.0771     0.0480 / 0.0465
   Granite-H     24 x 8 x 4, hd 64  0.6844 / 0.6867     0.6873 / 0.6503  (512)
+   ... two KV heads a tile (PR 55; LFM2's shape is the same)
+                                                       0.3264 / 0.3119  (1,024)
   Nemotron-H    12 x 2 x 16        0.1663 / 0.1679     0.0986 / 0.0922  (2,048)
 
 Off the rule: 256 slots at KV=8 read 0.286 (all-live Qwen3), 1,024 read
@@ -65,6 +68,31 @@ block a row; three buffers a block are refused by this Mosaic. The one
 shape that did not gain is the one whose old block was already 1 MiB of
 keys (8 rows x 8 KV heads): +0.9% all-live, inside the sweep's own repeat
 (0.2025-0.2054 over four readings of the old kernel).
+
+**Two KV heads a lane tile** (PR 55). A head of 64 fills half a lane tile,
+and until PR 55 the cache held one a tile: the kernel copied, upcast and
+multiplied tiles that were half empty, and Granite's row above read 1.5 x
+Qwen3's real bytes in 3.3 x its time (0.6873 ms for 0.2066; 311 GB/s where
+heads of 128 read 660-720). Now ``models.llama.init_kv_cache`` stores heads
+2p and 2p+1 side by side, ``[L, B, KV/2, C, 128]`` with the scales still a
+head, and ``_attend`` hands the kernel the tiles as its heads: a tile's rows
+are head a's R merged rows over head b's, each query in its own head's 64
+lanes and zeros in the other's (``place_in_own_lanes``, a few KB a call in
+XLA), so ONE product against the key tile is both heads' scores — the other
+head's lanes meet zeros, and adding exact zeros in float32 changes nothing
+—, ``P.V`` against the value tile is both heads' outputs, each in its own
+lanes (``take_own_lanes``), in the passes one padded head cost, and the
+block is what the rule gives 4 heads of 128. Inside the kernel only the
+scales know a tile is two heads (``scale_rows``); the mask, the running max
+and the sums go by row. The same sweep, this tree against its parent's
+kernel on the same numbers (all-live / the group's four tails): 0.6875 /
+0.6507 -> **0.3264 / 0.3119** at the rule's 1,024 slots; 512 read 0.3848 /
+0.3644, 2,048 0.3386 / 0.3245. The call's least time at 819 GB/s (217 MB of
+keys, values and scales) is 81% of 0.3264 ms: 666 GB/s, the pace heads of
+128 read at. The outputs equal the
+one-head-a-tile kernel's (bit for bit in interpret mode but where a tile's
+2R rows sum in another order than R: 1.9e-6 at R = 1,
+``tests/test_kv_head_pairs.py``).
 
 At 16 KV heads of one query head each (Ouro-2.6B; PR 50, the same script:
 8 rows, all-live / the group's four tails): 128 slots 0.5475 / 0.4509; 256
@@ -99,6 +127,7 @@ from .flash_attention import (
     _LANES,
     _NEG,
     VMEM_LIMIT_BYTES,
+    cache_heads_per_tile,
     head_dim_supported,
 )
 
@@ -128,7 +157,9 @@ def decode_block_k(n_kv: int, head_dim: int, itemsize: int,
                    cache_len: int) -> int:
     """Key slots of a K/V block, which holds ONE row's KV heads: the fewest
     whole lane tiles whose keys ``[KV, bk, hd]`` hold ``_BLOCK_KEY_BYTES``
-    as VMEM tiles them (a narrow head padded to whole lanes), a slot
+    as VMEM tiles them (a head narrower than a lane tile padded to whole
+    lanes: what an odd count of 64-wide heads costs; heads of 64 that pair
+    off arrive here as ``KV/2`` tiles of 128, ``_attend``), a slot
     weighed at ``_SLOT_KEYS_MOST`` key elements at most (many KV heads: 16
     of 128 weigh 512 KiB at 256 slots and read 4-6% faster at the 512 that
     8 heads get), no more than
@@ -140,6 +171,32 @@ def decode_block_k(n_kv: int, head_dim: int, itemsize: int,
     weighed = min(tiled, _SLOT_KEYS_MOST) * itemsize
     slots = min(-(-_BLOCK_KEY_BYTES // weighed), most)
     return min(max(-(-slots // _LANES) * _LANES, _LANES), cache_len)
+
+
+def _in_upper_lanes(x: jax.Array) -> jax.Array:
+    # x [B, KV, ...]: whether head kv's lanes are its tile's upper half
+    return (jnp.arange(x.shape[1]) % 2 == 1).reshape(
+        (1, -1) + (1,) * (x.ndim - 2))
+
+
+def place_in_own_lanes(x: jax.Array) -> jax.Array:
+    """Queries x [B, KV, ..., hd] of heads stored two a lane tile ->
+    [B, KV, ..., 2 * hd]: head kv's values in the lanes its keys hold of
+    their tile (the lower half for an even head, the upper for an odd one)
+    and zeros in its neighbour's, so that a product against the whole key
+    tile is the head's own scores: the other lanes meet zeros, and adding
+    exact zeros in float32 changes nothing."""
+    upper, zeros = _in_upper_lanes(x), jnp.zeros_like(x)
+    return jnp.concatenate(
+        [jnp.where(upper, zeros, x), jnp.where(upper, x, zeros)], axis=-1)
+
+
+def take_own_lanes(x: jax.Array) -> jax.Array:
+    """The way back on x [B, KV, ..., 2 * hd], a head's probabilities
+    against a whole value tile: the head's own lanes (the others hold its
+    probabilities against its neighbour's values)."""
+    hd = x.shape[-1] // 2
+    return jnp.where(_in_upper_lanes(x), x[..., hd:], x[..., :hd])
 
 
 def _row_blocks(pad, fill, n_q: int, win, block_k: int, cache_len: int):
@@ -171,6 +228,7 @@ def _kernel(
     quantized: bool,
     per_query: bool,
     return_partials: bool,
+    paired: bool,
 ):
     """One grid step of both kernels: row ``b``'s query positions (row
     s*G + g of the merged [KV, Sq*G] layout: position s, group head g)
@@ -178,12 +236,20 @@ def _kernel(
     attends pad_b <= slot <= fill_b + s. The single-token kernel's limit is
     the scalar fill; the verify kernel's per-(row, query) limit arrives as a
     lane-broadcast VMEM operand (``per_query``), because the merged rows
-    cannot be assembled from SMEM scalars in-kernel."""
+    cannot be assembled from SMEM scalars in-kernel.
+
+    ``paired``: a K/V tile holds two KV heads, 64 lanes each, and "KV"
+    below counts the tiles. A tile's R rows are then head a's merged rows
+    over head b's, each with its values in its own head's lanes and zeros
+    in the other's (``_attend``), so the one product a tile gives both
+    heads' scores; the mask, the running max and the sums go by row as
+    ever, and only the scales, a HEAD's, have to be told apart by row."""
     refs = list(refs)
     q_ref = refs.pop(0)                     # [1, KV, R, hd]
     lim_ref = refs.pop(0) if per_query else None  # [1, KV, R, LANES]
     k_ref, v_ref = refs.pop(0), refs.pop(0)       # [1, 1, KV, BK, hd]
-    ks_ref = refs.pop(0) if quantized else None   # [1, 1, KV, BK]
+    # [1, 1, KV, BK]; paired, a row a head: [1, 1, 2 KV, BK]
+    ks_ref = refs.pop(0) if quantized else None
     vs_ref = refs.pop(0) if quantized else None
     # outputs [1, KV, R, hd] (+ m, l [1, KV, R, LANES] with return_partials:
     # the UNNORMALIZED online-softmax state, the shard-local form the
@@ -210,6 +276,20 @@ def _kernel(
     # compute so the duplicate block isn't double-counted
     jb = first_ref[b] + j
 
+    def scale_rows(ref):
+        """A block's scales against the scores' rows: [KV, 1 or R, BK]."""
+        n_kv, n_rows = q_ref.shape[1:3]
+        scales = ref[0, 0]
+        if not paired:
+            return scales.reshape(n_kv, 1, block_k)
+        # the upper half of a tile's rows are its second head's
+        second = jax.lax.broadcasted_iota(
+            jnp.int32, (n_rows, 1), 0) >= n_rows // 2
+        return jnp.stack([
+            jnp.where(second, scales[2 * t + 1:2 * t + 2],
+                      scales[2 * t:2 * t + 1])
+            for t in range(n_kv)])
+
     @pl.when(jb <= last_ref[b])
     def _compute():
         n_kv = k_ref.shape[2]
@@ -225,7 +305,7 @@ def _kernel(
             preferred_element_type=jnp.float32,
         ) * scale  # [KV, R, BK]
         if quantized:
-            s = s * ks_ref[0, 0].reshape(n_kv, 1, block_k)
+            s = s * scale_rows(ks_ref)
 
         k_pos = jb * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (n_kv, 1, block_k), 2
@@ -251,9 +331,7 @@ def _kernel(
             l_ref.shape,
         )
         if quantized:
-            p = p * jnp.where(
-                in_cache, vs_ref[0, 0].reshape(n_kv, 1, block_k), 0.0
-            )
+            p = p * jnp.where(in_cache, scale_rows(vs_ref), 0.0)
         pv = jax.lax.dot_general(
             p, vb, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -289,10 +367,27 @@ def _attend(
     return_partials: bool = False,
 ):
     """The ``pallas_call`` both wrappers make: grid (row, key block), every
-    K/V block one row's KV heads over ``bk`` slots."""
+    K/V block one row's KV heads over ``bk`` slots. Returns the kernel's
+    outputs ``[B, KV, R, hd]`` (and m, l ``[B, KV, R, LANES]``).
+
+    Where the cache holds two KV heads a lane tile (``heads_per_lane_tile``)
+    the kernel is handed the tiles as its heads — ``KV/2`` of 128, the block
+    the rule gives that shape — and a tile's two heads' merged rows as one
+    tile's ``2R``, each query in its own head's lanes; the outputs' own
+    lanes are taken on the way back. The merged rows are head-major, so
+    both are plain reshapes around the lane placement."""
     k_all, v_all = cache["k"], cache["v"]
     quantized = "ks" in cache
-    B, KV, R, hd = qg.shape
+    heads = B, KV, R, hd = qg.shape
+    paired = cache_heads_per_tile(cache, hd) == 2
+    # 1/sqrt of the head's own size, whatever the tile's
+    scale = 1.0 / (hd ** 0.5)
+    if paired:
+        # from here on KV counts the tiles, R a tile's rows, hd its lanes
+        KV, R, hd = KV // 2, 2 * R, 2 * hd
+        qg = place_in_own_lanes(qg).reshape(B, KV, R, hd)
+        if limits is not None:
+            limits = limits.reshape(B, KV, R, _LANES)
     C = k_all.shape[3]
     bk = min(block_k, C) if block_k else decode_block_k(
         KV, hd, k_all.dtype.itemsize, C)
@@ -321,7 +416,8 @@ def _attend(
     in_specs += [pl.BlockSpec((1, 1, KV, bk, hd), kv_index)] * 2
     operands += [k_all, v_all]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, KV, bk), scale_index)] * 2
+        in_specs += [pl.BlockSpec(
+            (1, 1, cache["ks"].shape[2], bk), scale_index)] * 2
         operands += [cache["ks"], cache["vs"]]
 
     # the output, with ``return_partials`` in float32 and its m and l beside
@@ -329,11 +425,11 @@ def _attend(
     state = [(KV, R, hd), (KV, R, _LANES), (KV, R, _LANES)]
     outs = state if return_partials else state[:1]
     out_dtype = jnp.float32 if return_partials else qg.dtype
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _kernel, block_k=bk, cache_len=C,
-            scale=1.0 / (hd ** 0.5), quantized=quantized,
-            per_query=limits is not None, return_partials=return_partials,
+            _kernel, block_k=bk, cache_len=C, scale=scale,
+            quantized=quantized, per_query=limits is not None,
+            return_partials=return_partials, paired=paired,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=6,
@@ -353,6 +449,10 @@ def _attend(
         jnp.asarray(layer_idx, jnp.int32).reshape(1), first, last, fills, win,
         pads, *operands,
     )
+    if not paired:
+        return out
+    o, *sums = (x.reshape(*heads[:3], x.shape[-1]) for x in out)
+    return [take_own_lanes(o), *sums]
 
 
 def supports_decode(cache_len: int, head_dim: int) -> bool:
@@ -391,7 +491,7 @@ def flash_decode_attention(
     shard-local partial the long-context decode LSE-merges across the seq
     axis (same contract as backend.long_context._prefill_partial_local)."""
     B, S, H, hd = q.shape
-    KV = cache["k"].shape[2]
+    KV = cache["k"].shape[2] * cache_heads_per_tile(cache, hd)
     if S != 1:
         raise ValueError(f"decode kernel is single-token (S=1), got S={S}")
     if not (head_dim_supported(hd) or interpret):
@@ -444,7 +544,7 @@ def flash_spec_verify_attention(
     Each row reads from its own pad to its own last query; masking uses the
     exact per-(row, query) limit."""
     B, Sq, H, hd = q.shape
-    KV = cache["k"].shape[2]
+    KV = cache["k"].shape[2] * cache_heads_per_tile(cache, hd)
     if not (head_dim_supported(hd) or interpret):
         raise ValueError(f"unsupported verify head_dim={hd}")
     G = q_per_kv

@@ -113,6 +113,20 @@ VMEM holds only (BQ × BK) score tiles and HBM never sees a score tensor:
   (ops/decode_attention.py): the layer index arrives via scalar prefetch and
   steers the index_map, eliminating the per-layer 2×(B·C·hd·KV) extraction
   copies XLA otherwise materializes inside the layer scan;
+- **64-wide KV heads arrive two a lane tile** (PR 55): where the cache
+  holds heads 2p and 2p+1 side by side (``heads_per_lane_tile``,
+  ``models.llama.init_kv_cache``: ``[L, B, KV/2, C, 128]``, the scales a
+  head) the grid still walks the KV heads, a cell is handed its pair's
+  full-width K/V block (``kv_index``) and takes its own head's half of the
+  lanes (``_own_half``) before the cast; queries, outputs, the state and
+  the rest of the body are a head wide, as they were. What sets this
+  kernel's pace is the score tile's vector work, not its loads (below), so
+  nothing is gained here: kernel alone at Granite's and LFM2's map dispatch
+  (24 rows, 8 KV x 4, hd 64, int8, (512, 1024)) 9.35 us a cell for the
+  parent's 9.10 (0.694 s for 0.677 over 6 layers; the lane shift as a
+  32-bit roll of the cast block 0.687, the queries zero-padded to 128
+  lanes in XLA instead 0.765) — the decode kernel is where the pair pays
+  (ops/decode_attention.py: 0.6875 -> 0.3264 ms a call);
 - causal + left-pad masking fused (same semantics as
   models.llama.prefill_attention_mask: pad_b <= j <= i);
 - **each cell does its class's work** (_block_class, from the scalars the
@@ -182,6 +196,14 @@ def _block_class(q_start, k_start, pad, win, q_end, cache_len,
     return seen, padded, interior
 
 
+def _own_half(block, upper, hd: int):
+    """A head's own half of its pair's K/V block [BK, 2 hd], in the cache's
+    type: the upper lanes for an odd head (``upper``, a traced scalar: the
+    grid walks the heads). A lane shift a block, beside a head's [BQ, BK]
+    score tile next to nothing; the DMA moved full tiles."""
+    return jnp.where(upper, block[:, hd:], block[:, :hd])
+
+
 def _kernel(
     lidx_ref,  # [1] int32 (scalar prefetch, SMEM) — layer to read
     pad_ref,   # [B] int32 (scalar prefetch, SMEM)
@@ -197,13 +219,15 @@ def _kernel(
     q_per_kv: int,
     heads_per_step: int,
     heads_ahead: int,
+    paired: bool = False,
 ):
     if quantized:
         q_ref, k_ref, v_ref, ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = refs
     else:
         q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = refs
         ks_ref = vs_ref = None
-    # q_ref/o_ref [1, 1, G, BQ, hd]; k_ref/v_ref [1, 1, 1, BK, hd];
+    # q_ref/o_ref [1, 1, G, BQ, hd]; k_ref/v_ref [1, 1, 1, BK, hd] (``paired``:
+    # [1, 1, 1, BK, 2 hd], this head's tile, which it shares with its pair);
     # ks_ref/vs_ref [1, 1, KV, BK] (full KV axis — Mosaic requires the
     # second-minor block dim be 8-divisible or whole; the group's row is
     # selected in-kernel); scratch acc [G*BQ, hd] f32, m/l [G*BQ, LANES]
@@ -275,8 +299,13 @@ def _kernel(
         # loop over the group: one [BK, hd] conversion of each block, not G
         # (int8 cache values are exact in the query dtype — see the dot
         # comment below), the value scales, the mask
-        kb = k_ref[0, 0, 0].astype(q_ref.dtype)
-        vb = v_ref[0, 0, 0].astype(q_ref.dtype)
+        def block(ref):
+            x = ref[0, 0, 0]
+            if paired:
+                x = _own_half(x, kv % 2 == 1, q_ref.shape[-1])
+            return x.astype(q_ref.dtype)
+
+        kb, vb = block(k_ref), block(v_ref)
         v_scale = vs_ref[0, 0, kv][None, :] if quantized else None
         mask = None
         if masked:
@@ -474,10 +503,28 @@ def _block_geometry(S: int, C: int, G: int, hd: int,
 
 def head_dim_supported(head_dim: int) -> bool:
     """Head sizes the attention kernels take on the chip: whole lane tiles,
-    or half of one (64) as blocks of the array's own width — the DMA moves
-    64-wide rows and the products contract 64, on tiles whose other 64
-    lanes hold nothing."""
+    or half of one (64) — two heads a tile where the cache pairs them
+    (``heads_per_lane_tile``), else as blocks of the array's own width: the
+    DMA moves 64-wide rows and the products contract 64, on tiles whose
+    other 64 lanes hold nothing."""
     return head_dim % _LANES == 0 or head_dim == _LANES // 2
+
+
+def heads_per_lane_tile(n_kv: int, head_dim: int, model_shards: int = 1) -> int:
+    """KV heads the cache stores in one 128-lane tile (models.llama.
+    init_kv_cache): two where a head is half a tile wide and the KV heads
+    pair off — under a mesh, where the pairs still divide over the tensor
+    axis' ``model_shards`` — else one. Heads 2p and 2p+1 then share tile p,
+    lanes 0-63 and 64-127; the scales stay a head. What reads the cache
+    takes the number off its operands (``cache_heads_per_tile``)."""
+    pairs = head_dim * 2 == _LANES and n_kv % 2 == 0
+    return 2 if pairs and (n_kv // 2) % max(model_shards, 1) == 0 else 1
+
+
+def cache_heads_per_tile(cache: dict, head_dim: int) -> int:
+    """KV heads a lane tile of the stacked ``cache`` holds, for queries of
+    ``head_dim``: the cache's last dim over the head's."""
+    return cache["k"].shape[-1] // head_dim
 
 
 def supports_flash(seq_len: int, cache_len: int, head_dim: int) -> bool:
@@ -528,9 +575,15 @@ def flash_prefill_attention(
     k_all, v_all = cache["k"], cache["v"]
     quantized = "ks" in cache
     B, S, H, hd = q.shape
-    L, _, KV, C, _ = k_all.shape
+    C = k_all.shape[3]
     if not (head_dim_supported(hd) or interpret):
         raise ValueError(f"unsupported flash head_dim={hd}")
+    # KV heads a lane tile of the cache (heads_per_lane_tile): where there
+    # are two, the grid still walks the KV heads, a cell is handed its
+    # pair's K/V tile (kv_index) and takes its head's half of the lanes
+    # (_kernel, ``paired``); queries, outputs and the state stay a head wide
+    tile = cache_heads_per_tile(cache, hd)
+    KV = k_all.shape[2] * tile
     G = H // KV
     if q_per_kv != G:
         # the group-major grid derives G from the shapes; a mismatched
@@ -565,7 +618,9 @@ def flash_prefill_attention(
 
     def kv_index(b, kv, i, j, lidx, pad, win, off, *rows):
         row = rows[0][b] if rows else b
-        return (lidx[0], row, kv, visible_j(b, i, j, pad, win, off), 0)
+        # no division traced at one head a tile: the program it was
+        tile_of = kv // tile if tile > 1 else kv
+        return (lidx[0], row, tile_of, visible_j(b, i, j, pad, win, off), 0)
 
     def scale_index(b, kv, i, j, lidx, pad, win, off, *rows):
         row = rows[0][b] if rows else b
@@ -576,8 +631,8 @@ def flash_prefill_attention(
             (1, 1, G, bq, hd),
             lambda b, kv, i, j, *prefetched: (b, kv, 0, i, 0),
         ),
-        pl.BlockSpec((1, 1, 1, bk, hd), kv_index),
-        pl.BlockSpec((1, 1, 1, bk, hd), kv_index),
+        pl.BlockSpec((1, 1, 1, bk, hd * tile), kv_index),
+        pl.BlockSpec((1, 1, 1, bk, hd * tile), kv_index),
     ]
     operands = [qt, k_all, v_all]
     if quantized:
@@ -593,6 +648,7 @@ def flash_prefill_attention(
         block_q=bq, block_k=bk, seq_len=S, cache_len=C,
         scale=1.0 / (hd ** 0.5), quantized=quantized, q_per_kv=G,
         heads_per_step=_heads_per_step(G), heads_ahead=_heads_ahead(G),
+        paired=tile == 2,
     )
     out = pl.pallas_call(
         kernel,

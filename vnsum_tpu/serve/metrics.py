@@ -120,6 +120,10 @@ _reg("engine_decode_kv_blocks_total", "counter",
 _reg("engine_decode_kv_blocks_skipped_total", "counter",
      "blocks of engine_decode_kv_blocks_total the decode kernels left out "
      "for lying wholly under a row's left pad")
+_reg("engine_decode_kv_blocks_paired_total", "counter",
+     "blocks of engine_decode_kv_blocks_total that held two KV heads a lane "
+     "tile (64-wide heads paired in the cache): all of them or none, by the "
+     "model's shapes")
 _reg("engine_executions_held_total", "counter",
      "device executions that took more than their shape's pace by the "
      "engine's margin (each logs one 'execution held' WARNING with what the "
