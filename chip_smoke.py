@@ -5,9 +5,12 @@ Drives the system's main paths once, at the full width of Llama-3.2-3B with
 random weights made from a seed, through the entry points a user calls:
 
     device   jax.devices()[0].platform must be "tpu"; versions, cache dir
-    kernels  every Pallas kernel, compiled (never interpreted), at the head
-             geometry of every lane-aligned family, against the dense
-             reference attention of models/llama.py
+    kernels  the GQA Pallas kernels, compiled (never interpreted), at the
+             head geometries of 128 and 256 lanes, against the dense
+             reference attention of models/llama.py (NOT the paired 64-wide
+             heads, which the Granite and LFM2 cells' parity checks hold,
+             nor the latent, scan, delta-rule and expert kernels, which
+             their families' phases below run)
     offline  PipelineRunner (what `python -m vnsum_tpu.pipeline.cli
              --backend tpu` runs): VN-LongSum-length documents,
              mapreduce, a full-batch S=8192 dispatch, a reduce, evaluation
@@ -17,6 +20,10 @@ random weights made from a seed, through the entry points a user calls:
              SIGTERM drain
     mesh     the generate step under TpuBackend(mesh=) at model=4 and
              data=4 — only with >= 4 devices, otherwise reported as skipped
+    experts, moe, laguna, nemotron_h, ouro, lfm2, ling
+             one family each on the one-shot path at its published widths
+             and a few layers, against its plain reference
+             (``--phases device,<family>``; each phase's docstring)
 
 One process holds the chip at a time: this parent never imports JAX and runs
 each phase in its own child. It exits non-zero if any phase failed or ran on
@@ -46,13 +53,13 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe",
-          "laguna", "nemotron_h", "ouro", "lfm2")
+          "laguna", "nemotron_h", "ouro", "lfm2", "ling")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
     "experts": 420, "moe": 420, "laguna": 480, "nemotron_h": 480,
-    "ouro": 360, "lfm2": 420,
+    "ouro": 360, "lfm2": 420, "ling": 480,
 }
 
 
@@ -95,6 +102,9 @@ def sizes(rehearsal: bool) -> dict:
             lfm2_prefill_chunk=128, lfm2_prompt_bytes=250,
             lfm2_parity=(150, 256, 4), lfm2_tolerance=0.15,
             lfm2_tie_band=0.02, lfm2_state_tolerance=0.01,
+            ling_layers=6, ling_held=8, ling_seq=328, ling_batch=4,
+            ling_max_new=8, ling_prefill_chunk=128, ling_prompt_bytes=250,
+            ling_kernel=(2, 96, 4, 16), ling_kernel_tolerance=1e-4,
             ouro_layers=2, ouro_seq=328, ouro_batch=4, ouro_max_new=8,
             ouro_prefill_chunk=128, ouro_prompt_bytes=250,
             ouro_parity=(150, 256, 4), ouro_tolerance=0.05,
@@ -148,6 +158,12 @@ def sizes(rehearsal: bool) -> dict:
         lfm2_prefill_chunk=1024, lfm2_prompt_bytes=1_900,
         lfm2_parity=(1500, 2048, 4), lfm2_tolerance=0.25,
         lfm2_tie_band=0.1, lfm2_state_tolerance=0.012,
+        # ling: the first period K K K K K M of layers 0-5 at the published
+        # widths, 128 of 512 experts held; the two delta-rule kernels at a
+        # row piece's shape (rows, tokens, heads, head width) in bfloat16
+        ling_layers=6, ling_held=128, ling_seq=2304, ling_batch=4,
+        ling_max_new=32, ling_prefill_chunk=1024, ling_prompt_bytes=1_900,
+        ling_kernel=(4, 2048, 32, 128), ling_kernel_tolerance=3e-2,
         # ouro: two layers at the published widths run FOUR times (8 cache
         # layers), 16 / 16 heads; prompts of two 2,048-token chunks in the
         # S=4096 bucket; the cell's own limits
@@ -1023,6 +1039,136 @@ def phase_lfm2(args) -> dict:
                           sizes_ref, window=False, more=tail_check)
 
 
+def phase_ling(args) -> dict:
+    """The Ling-3.0-flash family on the one-shot path. First its two
+    delta-rule kernels (``ops/kda_scan.py``) compiled at the published
+    widths — a row piece of the prefill scan under ragged pads, in place at
+    rows of a larger state, and the one-token update — against their XLA
+    forms, each timed inside a jitted loop. Then one small generate: the
+    first period ``K K K K K M`` at the published widths (five KDA layers,
+    the latent attention at 32 heads with no compressed query, both dense
+    layers and four sparse layers of which this chip holds 128 of 512
+    experts), int8 and W8A8, twice through ``TpuBackend.generate``, with
+    its counters. The logits against ``benchmarks/reference_ling.py`` are
+    the cell's own set-up (``--workload
+    ling-3.0-flash-ep4-l12-int8.offline-mapreduce-8k-kda-ep``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models import jitted_init
+    from vnsum_tpu.models.ling import ling_3_0_flash, tiny_ling
+    from vnsum_tpu.models.quant import init_params_quantized
+    from vnsum_tpu.ops import kda_scan
+
+    sz = {k[5:]: v for k, v in sizes(args.rehearsal).items()
+          if k.startswith("ling_")}
+    c = Checks()
+    R, S, H, d = sz["kernel"]
+    dtype = jnp.float32 if args.rehearsal else jnp.bfloat16
+    chunk = 32 if args.rehearsal else 64
+    ks = jax.random.split(jax.random.key(5), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = (unit(jax.random.normal(ks[0], (R, S, H, d))) * d ** -0.5)
+    k = unit(jax.random.normal(ks[1], (R, S, H, d)))
+    v = jax.random.normal(ks[2], (R, S, H, d))
+    g = -5.0 * jax.nn.sigmoid(jax.random.uniform(ks[3], (R, S, H, d),
+                                                 minval=-8.0, maxval=3.0))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (R, S, H)))
+    pads = jnp.asarray([0, 1, S // 3, S - S // 4][:R], jnp.int32)
+    real = jnp.arange(S)[None, :] >= pads[:, None]
+    q, k, v = (jnp.where(real[:, :, None, None], a, 0).astype(dtype)
+               for a in (q, k, v))
+    beta = jnp.where(real[:, :, None], beta, 0.0)
+    rows = jnp.arange(R, dtype=jnp.int32)[::-1] * 2
+    state = jnp.zeros((2, 2 * R, H, d, d), jnp.float32)
+    interpret = bool(args.rehearsal)
+    o, new = jax.jit(lambda *a: kda_scan.kda_prefill_scan(
+        *a, chunk=chunk, interpret=interpret))(
+        q, k, v, g, beta, state, 1, pads, rows)
+    want_o, want = jax.jit(lambda *a: kda_scan.kda_chunked_xla(
+        *a, chunk))(q, k, v, g, beta, state[1, rows])
+
+    def rel(a, b):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    errs = {"prefill_out": rel(o, want_o), "prefill_state": rel(new[1, rows],
+                                                                want)}
+    c.check("kda_prefill_scan is its XLA form (a row piece under ragged "
+            "pads, in place)", max(errs.values()) <= sz["kernel_tolerance"]
+            and not np.asarray(new[0]).any()
+            and not np.asarray(new[1, rows + 1]).any(), errs)
+    step = (q[:, -1], k[:, -1], v[:, -1], g[:, -1], beta[:, -1])
+    o1, new1 = jax.jit(lambda *a: kda_scan.kda_decode_update(
+        *a, interpret=interpret))(*step, new[:, rows], 1)
+    want_o1, want1 = jax.jit(kda_scan.kda_step_xla)(*step, new[1, rows])
+    errs.update(decode_out=rel(o1, want_o1), decode_state=rel(new1[1], want1))
+    c.check("kda_decode_update is the one-token step",
+            max(errs["decode_out"], errs["decode_state"]) <= 1e-5, errs)
+
+    def timed(fn, *a, n=8):
+        """Seconds a call inside a jitted loop of ``n`` (a lone call costs
+        the host as much as a small kernel)."""
+        loop = jax.jit(lambda *a: jax.lax.fori_loop(
+            0, n, lambda _, carry: fn(*a[:-1], carry)[1], a[-1]))
+        jax.block_until_ready(loop(*a))
+        t0 = time.time()
+        jax.block_until_ready(loop(*a))
+        return (time.time() - t0) / n
+
+    times = {
+        "kda_prefill_scan_s": timed(
+            lambda q, k, v, g, b, st: kda_scan.kda_prefill_scan(
+                q, k, v, g, b, st, 1, pads * 0, rows, chunk=chunk,
+                interpret=interpret), q, k, v, g, beta, state),
+        "kda_decode_update_s": timed(
+            lambda *a: kda_scan.kda_decode_update(
+                *a[:5], a[5], 1, interpret=interpret),
+            *step, new[:, rows])}
+
+    make = tiny_ling if args.rehearsal else ling_3_0_flash
+    cfg = make(n_layers=sz["layers"], experts_held=sz["held"],
+               max_seq_len=sz["seq"])
+    backend = TpuBackend(
+        model_config=cfg, tokenizer="byte",
+        params=jitted_init(init_params_quantized, cfg, 3),
+        batch_size=sz["batch"], max_new_tokens=sz["max_new"], quantize=True,
+        quantize_act=True, quantize_kv=False,
+        prefill_chunk_tokens=sz["prefill_chunk"],
+        generation=GenerationConfig(temperature=1.0, seed=3),
+        interpret=args.rehearsal)
+    prompts = [_vn_text(sz["prompt_bytes"] - 300 * i // 4, f"g{i}")
+               for i in range(sz["batch"])]
+    _outs, first_s, second_s = _generate_twice(backend, prompts, c)
+    st = backend.stats
+    layers, top = cfg.n_sparse, cfg.num_experts_per_tok
+    c.check("slots routed cover the prompts' tokens, a share of them held",
+            st.expert_slots_routed >= st.prompt_tokens * top * layers
+            and 0 < st.expert_slots_held < st.expert_slots_routed,
+            (st.expert_slots_routed, st.expert_slots_held))
+    tokens = np.asarray(st.expert_tokens)
+    c.check("expert_tokens add up to slots held",
+            tokens.shape == (layers, cfg.n_held)
+            and int(tokens.sum()) == st.expert_slots_held, tokens.shape)
+    blocks = st.prefill_blocks
+    c.check("the scan's tokens and the latent kernel's keys are counted",
+            0 < blocks.get("kda_tokens_real", 0)
+            <= blocks.get("kda_tokens_computed", 0)
+            and 0 < blocks.get("latent_keys_real", 0)
+            <= blocks.get("latent_keys_expanded", 0), blocks)
+    rep = c.report()
+    rep.update(kernel_errors=errs, kernel_seconds=times,
+               first_call_s=round(first_s, 2),
+               second_call_s=round(second_s, 2), prefill_blocks=blocks,
+               held_share=st.expert_slots_held / max(st.expert_slots_routed,
+                                                     1),
+               engine=backend.describe())
+    return rep
+
+
 def phase_ouro(args) -> dict:
     """The dense family LOOPED over its weights (``LlamaConfig.loop_passes``,
     Ouro-2.6B) on the one-shot path: two layers at the published widths run
@@ -1127,7 +1273,8 @@ def _child(args) -> int:
                     "laguna": phase_laguna,
                     "nemotron_h": phase_nemotron_h,
                     "ouro": phase_ouro,
-                    "lfm2": phase_lfm2}[phase](args))
+                    "lfm2": phase_lfm2,
+                    "ling": phase_ling}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

@@ -82,11 +82,19 @@ QK-normed attention, a dense and a sparse feed-forward) and the same with the
 piece set to its 128-token chunk: the per-row writes of keys, values, tails
 and picks are in it.
 
+**PR 56 moved none of the sixteen and added `ling` and `ling-row-pieces`**:
+the eighth family (`models/ling.py`: the two delta-rule kernels of
+`ops/kda_scan.py`, the latent kernels at 4 heads with no compressed query,
+the grouped expert product) whole, and with a row piece set to its 128-token
+chunk: a piece's gathered latent rows and the per-row writes of latent rows,
+tails and picks are in it. `deepseek-v2`, whose attention functions took a
+`gate` argument this family passes, hashes as it did.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
 (`assert_pinned`). `python tests/test_one_shot_programs_pinned.py` (from the
-repo's root, `PYTHONPATH=.`) traces all sixteen, prints both tables as they
+repo's root, `PYTHONPATH=.`) traces all eighteen, prints both tables as they
 would have to read and rewrites that file — the one place that regenerates
 them."""
 from __future__ import annotations
@@ -119,11 +127,13 @@ _PINNED = {
     "granite-h-row-pieces": ("tiny-granite-h", {}, "8ece4d118fa328f3"),
     "lfm2": ("tiny-lfm2", {}, "2ae2707f3d02f044"),
     "lfm2-row-pieces": ("tiny-lfm2", {}, "10157522ffa94d1f"),
+    "ling": ("tiny-ling", {}, "c8779ab54ff08e62"),
+    "ling-row-pieces": ("tiny-ling", {}, "0550cb2fa46f8c9a"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)
 _PIECE_TOKENS = {"llama-row-pieces": 128, "granite-h-row-pieces": 128,
-                 "lfm2-row-pieces": 128}
+                 "lfm2-row-pieces": 128, "ling-row-pieces": 128}
 
 # the slot loop's programs of the tiny llama family, "kind-rows" -> the same
 # hash: a join of 1 and of 2 rows, the segment of 4 slots, the adopt of a
@@ -267,7 +277,7 @@ def test_a_slot_program_traces_to_the_pinned_jaxpr(program):
 
 
 def regenerate() -> None:
-    """Trace all sixteen programs, print the two tables' hashes as they are now
+    """Trace all eighteen programs, print the two tables' hashes as they are now
     and rewrite the line ladders."""
     texts = [("_PINNED", family, want,
               one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw),

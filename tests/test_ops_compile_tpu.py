@@ -655,3 +655,90 @@ def test_gqa_decode_kernel_compiles_at_one_query_head_a_kv_head(one_chip):
         one_chip, ((8, 1, 16, 128), BF16),
         _int8_cache(48, 8, 16, 8448, 128), ((8,), I32), ((), I32))
     assert "tpu_custom_call" in c.as_text()
+
+
+# -- the Ling-3.0-flash family (ops/kda_scan.py; the latent kernels at 32
+# heads with no compressed query) ------------------------------------------
+
+
+@pytest.mark.parametrize("R,S,rows", [
+    (4, 2048, True),    # the map dispatch's row piece: four rows of a chunk
+    (1, 2048, False),   # parity's chunk: one row, the whole state
+    (4, 1024, True),    # the smoke phase's chunk
+])
+def test_kda_prefill_scan_compiles_at_the_published_widths(one_chip, R, S,
+                                                           rows):
+    """A head a lane tile of the [rows, tokens, 32 x 128] arrays, scan
+    chunks of 64 in sub-blocks of 16, token blocks of 1,024, the head's
+    [128, 128] float32 state in VMEM, the cell's stacked state of 10 layers
+    x 24 rows written in place at a piece's own rows: the float32 products
+    of the 64 x 64 inverse and the transposed state update are what Mosaic
+    might refuse."""
+    from vnsum_tpu.ops import kda_scan
+
+    head = ((R, S, 32, 128), BF16)
+    args = [head, head, head, ((R, S, 32, 128), F32), ((R, S, 32), F32),
+            ((10, 24, 32, 128, 128), F32), ((R,), I32)]
+    if rows:
+        c = _compiled(
+            lambda q, k, v, g, b, st, pads, rows: kda_scan.kda_prefill_scan(
+                q, k, v, g, b, st, 7, pads, rows, chunk=64),
+            one_chip, *args, ((R,), I32))
+    else:
+        c = _compiled(
+            lambda q, k, v, g, b, st, pads: kda_scan.kda_prefill_scan(
+                q, k, v, g, b, st[:, :R], 7, pads, chunk=64),
+            one_chip, *args)
+    assert "tpu_custom_call" in c.as_text()
+    assert kda_scan.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("B", [24, 4, 1])
+def test_kda_decode_update_compiles_in_place_at_the_cells_shapes(one_chip, B):
+    """A row's 32 states of one layer a grid step (2 MiB in, 2 MiB out,
+    double-buffered), the heads unrolled, a head's beta v and output as
+    [128, 1] columns of [128, 32] blocks."""
+    from vnsum_tpu.ops import kda_scan
+
+    row = ((B, 32, 128), BF16)
+    c = _compiled(
+        lambda q, k, v, g, b, st: kda_scan.kda_decode_update(
+            q, k, v, g, b, st, 3),
+        one_chip, row, row, row, ((B, 32, 128), F32), ((B, 32), F32),
+        ((10, B, 32, 128, 128), F32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+@pytest.mark.parametrize("R,S,offset", [
+    (4, 2048, 6144),   # the map dispatch's last chunk, a piece of four rows
+    (4, 2048, 0),      # its first
+    (1, 2048, 6144),   # parity's last chunk
+])
+def test_latent_prefill_kernel_compiles_at_32_heads(one_chip, R, S, offset):
+    """As ``models/ling.py`` calls it: 32 heads in groups of 8, the queries
+    of a 2,048-token chunk, a piece's latent rows gathered into a one-layer
+    stack of their own (layer 0, first row 0) of the cell's 8,448 slots."""
+    from vnsum_tpu.ops.mla_attention import mla_prefill_attention
+
+    H = 32
+    c = _compiled(
+        lambda qn, qr, lat, wk, wv, p: mla_prefill_attention(
+            qn, qr, lat, wk, wv, p, scale=192 ** -0.5, q_offset=offset,
+            layer_idx=0, row_offset=0),
+        one_chip, ((R, H, S, 128), BF16), ((R, H, S, 64), BF16),
+        ((1, R, 8448, 576), BF16), ((H, 512, 128), BF16),
+        ((H, 512, 128), BF16), ((R,), I32))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_absorbed_decode_kernel_compiles_at_32_heads_over_two_layers(
+        one_chip):
+    from vnsum_tpu.ops.mla_attention import mla_decode_attention
+
+    B, H = 24, 32
+    c = _compiled(
+        lambda ql, qr, cache, pads: mla_decode_attention(
+            ql, qr, cache, 1, pads, 8200, scale=192 ** -0.5, rank=512),
+        one_chip, ((B, H, 512), BF16), ((B, H, 64), BF16),
+        ((2, B, 8448, 576), BF16), ((B,), I32))
+    assert "tpu_custom_call" in c.as_text()

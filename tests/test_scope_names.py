@@ -219,6 +219,40 @@ def test_a_short_convolution_familys_program_carries_its_component_scopes():
     assert named <= set(components) | {"emit"}, named - set(components)
 
 
+def test_a_delta_rule_familys_program_carries_its_component_scopes():
+    """Every scope ``models/ling.py`` adds, under both phases of its
+    one-shot program: the KDA mixer's four (``kda_in``, ``kda_conv``,
+    ``kda_scan``, ``kda_out``), the MLA layers' (``kv_latent``,
+    ``kv_write``, ``attn_gate``, ``q_lora`` — the name
+    ``models/deepseek.py`` projects its queries under, here the whole query
+    projection —, ``attn``, ``attn_out``), ``mlp`` on the dense layer, the
+    router, the routed experts and the shared expert; and every instruction
+    the map places lies under a phase and a component."""
+    from vnsum_tpu.models.ling import init_params, tiny_ling
+
+    cfg = tiny_ling(max_seq_len=128)
+    b = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
+                   max_new_tokens=NEW, seed=1, flash=False,
+                   params=init_params(jax.random.key(0), cfg))
+    b._get_fn(B, S, NEW, b.gen_cfg)
+    (m,) = b.scope_maps()
+    assert m["module"] == "jit_generate"
+    got = paths(m["scopes"])
+    components = ("kda_in", "kda_conv", "kda_scan", "kda_out", "kv_latent",
+                  "kv_write", "attn_gate", "q_lora", "attn", "attn_out",
+                  "mlp", "router", "experts", "shared_experts", "embed",
+                  "lm_head", "sample")
+    for phase in ("prefill", "decode"):
+        assert {f"{phase}/{c}" for c in components} <= got
+    assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
+    # ... or the phase's own glue: ``emit``, and the decode phase's scans of
+    # the layer stack themselves (``add;while``: a run's loop with its
+    # residual add, the compiler's name for a call it closed over)
+    named = {p.split("/")[1] for p in got if p.count("/") >= 1}
+    assert named <= set(components) | {"emit", "add;while"}, \
+        named - set(components)
+
+
 def test_a_looped_stacks_program_carries_the_norm_between_passes():
     """A stack looped over its weights (``LlamaConfig.loop_passes``) adds
     ONE scope to the dense family's, under both phases: ``loop_norm``, the

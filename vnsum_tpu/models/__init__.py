@@ -97,6 +97,18 @@ def _tiny_lfm2(**kw):
     return tiny_lfm2(**kw)
 
 
+def _ling_3_0_flash(**kw):
+    from .ling import ling_3_0_flash
+
+    return ling_3_0_flash(**kw)
+
+
+def _tiny_ling(**kw):
+    from .ling import tiny_ling
+
+    return tiny_ling(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -143,6 +155,12 @@ MODEL_REGISTRY = {
     # two leading dense layers, then 32 gated experts top-4 by sigmoid + bias
     "lfm2-8b-a1b": _lfm2_8b_a1b,
     "tiny-lfm2": _tiny_lfm2,
+    # an eighth (models/ling.py): Kimi-Delta-Attention layers - a float32
+    # matrix state a head under a gated delta rule - beside latent-attention
+    # layers with no compressed query 5:1, two leading dense layers, then
+    # 512 experts top-8 by group-limited sigmoid score + bias and a shared one
+    "ling-3.0-flash": _ling_3_0_flash,
+    "tiny-ling": _tiny_ling,
 }
 
 __all__ = [

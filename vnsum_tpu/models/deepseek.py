@@ -317,7 +317,7 @@ def _expert_ffn(h, lp, experts, slot, valid, cache, cfg: DeepseekV2Config,
 #
 # An attention function takes a layer's compressed queries and gives the
 # layer's attention output, projected: ``attend(c_q, rope, cache, layer_idx,
-# lp, aq) -> [B, S, D]``. It owns the up-projection of the queries, the
+# lp, aq, gate=None) -> [B, S, D]`` (``gate``: ``_project_out``). It owns the up-projection of the queries, the
 # attention and the output projection, so that the prefill can do all three
 # for a few batch rows at a time: a chunk's queries (24 x 1024 x 128 x 192)
 # and its output would otherwise be whole arrays of a gigabyte each. The
@@ -345,10 +345,17 @@ def _queries(c_q, rope, lp, aq: bool, cfg: DeepseekV2Config):
         return q[..., :dn].transpose(0, 2, 1, 3), q_rope.transpose(0, 2, 1, 3)
 
 
-def _project_out(attn, lp, aq: bool):
-    """attn [B, H, S, dv] -> [B, S, D] through ``wo``."""
+def _project_out(attn, lp, aq: bool, gate=None):
+    """attn [B, H, S, dv] -> [B, S, D] through ``wo``; ``gate`` [B, S, H]
+    float32 or None: a factor a token and head on the attention's output
+    before the projection (``models/ling.py``'s head-wise gate; this
+    family has none)."""
     with jax.named_scope("attn_out"):
-        return _proj("bshk,hkd->bsd", attn.transpose(0, 2, 1, 3), lp["wo"], aq)
+        attn = attn.transpose(0, 2, 1, 3)
+        if gate is not None:
+            attn = (attn.astype(jnp.float32) * gate[..., None]
+                    ).astype(attn.dtype)
+        return _proj("bshk,hkd->bsd", attn, lp["wo"], aq)
 
 
 def _scaled(x, s):
@@ -386,13 +393,13 @@ def dense_attention(cfg: DeepseekV2Config, mask) -> LatentAttention:
         return jnp.einsum("bhst,bhtk->bhsk",
                           jax.nn.softmax(s, axis=-1).astype(v.dtype), v)
 
-    def attend(c_q, rope, cache, layer_idx, lp, aq):
+    def attend(c_q, rope, cache, layer_idx, lp, aq, gate=None):
         q_nope, q_rope = _queries(c_q, rope, lp, aq, cfg)
         with jax.named_scope("attn"):
             lat = jax.lax.dynamic_index_in_dim(
                 cache["latent"], layer_idx, 0, keepdims=False)
             attn = _expanded_attention(q_nope, q_rope, lat, lp, cfg, attention)
-        return _project_out(attn, lp, aq)
+        return _project_out(attn, lp, aq, gate)
 
     return LatentAttention(attend)
 
@@ -427,7 +434,7 @@ def prefill_attention(cfg: DeepseekV2Config, pad_lens, q_offset: int, *,
     rule."""
     from ..ops.mla_attention import mla_prefill_attention
 
-    def attend(c_q, rope, cache, layer_idx, lp, aq):
+    def attend(c_q, rope, cache, layer_idx, lp, aq, gate=None):
         B, S, _ = c_q.shape
         latent = cache["latent"]
         (wk, sk), (wv, sv) = _leaf(lp["wk_b"]), _leaf(lp["wv_b"])
@@ -438,7 +445,7 @@ def prefill_attention(cfg: DeepseekV2Config, pad_lens, q_offset: int, *,
         R = _rows_a_piece(cfg, B, S)
 
         def piece(args, first_row=0):
-            c_q, cos, sin, pads = args
+            c_q, cos, sin, pads, *gated = args
             q_nope, q_rope = _queries(c_q, (cos, sin), lp, aq, cfg)
             with jax.named_scope("attn"):
                 attn = _scaled(mla_prefill_attention(
@@ -446,9 +453,9 @@ def prefill_attention(cfg: DeepseekV2Config, pad_lens, q_offset: int, *,
                     scale=cfg.softmax_scale, q_offset=q_offset,
                     layer_idx=layer_idx, row_offset=first_row,
                     interpret=interpret), sv)
-            return _project_out(attn, lp, aq)
+            return _project_out(attn, lp, aq, *gated)
 
-        args = (c_q, *rope, pad_lens)
+        args = (c_q, *rope, pad_lens) + (() if gate is None else (gate,))
         if R == B:
             return piece(args)
         pieces = tuple(a.reshape((B // R, R) + a.shape[1:]) for a in args)
@@ -470,7 +477,7 @@ def decode_attention(cfg: DeepseekV2Config, pad_lens, S: int, t, *,
     through ``wv_b``. Keys and values are never expanded."""
     from ..ops.mla_attention import mla_decode_attention
 
-    def attend(c_q, rope, cache, layer_idx, lp, aq):
+    def attend(c_q, rope, cache, layer_idx, lp, aq, gate=None):
         q_nope, q_rope = _queries(c_q, rope, lp, aq, cfg)   # [B, H, 1, *]
         with jax.named_scope("attn"):
             (wk, sk), (wv, sv) = _leaf(lp["wk_b"]), _leaf(lp["wv_b"])
@@ -485,7 +492,7 @@ def decode_attention(cfg: DeepseekV2Config, pad_lens, S: int, t, *,
             attn = jnp.einsum("bhc,chk->bhk", o_lat, wv.astype(o_lat.dtype))
             if sv is not None:
                 attn = (attn.astype(jnp.float32) * sv[None]).astype(attn.dtype)
-        return _project_out(attn[:, :, None], lp, aq)
+        return _project_out(attn[:, :, None], lp, aq, gate)
 
     def real_tokens(n: int):
         return jnp.broadcast_to((S + t >= pad_lens)[:, None],

@@ -106,6 +106,34 @@ def sigmoid_route(logits: jax.Array, bias: jax.Array, top_k: int,
     return ids.astype(jnp.int32), picked / total * scaling
 
 
+def sigmoid_group_route(logits: jax.Array, bias: jax.Array, top_k: int,
+                        n_group: int, topk_group: int, scaling: float,
+                        group_top: int = 2):
+    """``sigmoid_route`` under a GROUP LIMIT (DeepSeek-V3's ``noaux_tc``
+    rule, which ``models/ling.py`` routes 512 experts by): logits [T, E]
+    float32, bias [E] -> (expert ids [T, k] int32, weights [T, k]). With
+    ``c = sigmoid(logits) + bias``: the experts fall into ``n_group`` runs of
+    ``E / n_group``; a group scores the sum of its ``group_top`` largest
+    ``c``; the ``topk_group`` best groups are kept and the ``top_k`` largest
+    ``c`` among THEIR experts picked; the weights are the picked experts'
+    scores WITHOUT the bias, renormalised to sum to one, times ``scaling``.
+    (``models/deepseek.py::route`` is the softmax rule, a group scored by
+    its largest member alone.)"""
+    scores = jax.nn.sigmoid(logits)
+    ranked = scores + bias
+    T, E = ranked.shape
+    best, _ = jax.lax.top_k(ranked.reshape(T, n_group, E // n_group),
+                            group_top)
+    _, keep = jax.lax.top_k(jnp.sum(best, axis=-1), topk_group)   # [T, kg]
+    group_kept = jnp.any(
+        keep[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+    kept = jnp.repeat(group_kept, E // n_group, axis=1)
+    _, ids = jax.lax.top_k(jnp.where(kept, ranked, -jnp.inf), top_k)
+    picked = jnp.take_along_axis(scores, ids, axis=-1)
+    weights = picked / jnp.sum(picked, axis=-1, keepdims=True) * scaling
+    return ids.astype(jnp.int32), weights
+
+
 def _quantize_rows(x: jax.Array):
     """x [M, K] -> (int8, per-row float32 scale [M, 1])."""
     x32 = x.astype(jnp.float32)

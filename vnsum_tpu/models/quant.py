@@ -56,6 +56,11 @@ _ALL_CONTRACT_AXES = {
     # held as its parts B | C | x; its out_proj is "out_proj" above); the
     # taps stay float32
     "in_b": (0,), "in_c": (0,), "in_x": (0,),      # [D, D]
+    # a Kimi-Delta-Attention mixer's products beside wq, wk, wv and wo
+    # (models/ling.py: the decay gate's one matrix, beta's and the head-wise
+    # output gate's, which its MLA layers have too); the taps, A_log and
+    # dt_bias stay float32
+    "wa": (0,), "w_beta": (0,), "wg_head": (0,),   # [D, H, hd], [D, H] x 2
 }
 # the groups of stacked layers a params tree may hold: every family has
 # "layers"; one with leading dense layers keeps them under "dense"; one
@@ -68,9 +73,11 @@ _ALL_CONTRACT_AXES = {
 # router and the router's correction bias stay float32); one that mixes
 # convolution operators and attention keeps each kind's operator under
 # "conv" and "attn", its leading dense feed-forwards under "dense" and the
-# routers and experts under "layers" (models/lfm2.py)
+# routers and experts under "layers" (models/lfm2.py); one that mixes
+# delta-rule and latent-attention layers keeps each kind's mixer under "kda"
+# and "mla" (models/ling.py)
 _LAYER_GROUPS = ("layers", "dense", "full", "sliding", "mamba", "attn",
-                 "conv")
+                 "conv", "kda", "mla")
 
 
 def _quantize(w: jax.Array, contract_axes: tuple[int, ...]) -> dict:

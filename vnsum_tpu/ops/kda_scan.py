@@ -1,0 +1,473 @@
+"""The gated delta rule of Kimi Delta Attention (KDA) for the one-shot
+program: a chunked prefill scan and a one-token state update, each as its XLA
+form and as a Pallas TPU kernel.
+
+The recurrence, per row and head (``d_k`` key channels, ``d_v`` value
+channels, a MATRIX state ``S [d_k, d_v]`` in float32):
+
+    S_t = (I - beta_t k_t k_t^T) diag(exp(g_t)) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t
+
+with ``g_t [d_k] <= 0`` a log-decay a key CHANNEL (not a scalar a head: what
+``ops/ssd_scan.py`` scans), ``beta_t`` in (0, 1) a head, ``k_t`` of unit
+length and ``q_t`` scaled by the caller. Every token the state is decayed
+channel by channel, the value it would read at ``k_t`` is erased by
+``beta_t`` and ``beta_t v_t`` written there: the delta rule. With ``u_t =
+beta_t (v_t - (diag(exp(g_t)) S_{t-1})^T k_t)`` the same step reads ``S_t =
+diag(exp(g_t)) S_{t-1} + k_t u_t^T``. A position whose ``k``, ``v`` and
+``beta`` are zero leaves a zero state zero whatever ``g`` reads there, so a
+state stays exactly zero through a row's left pad (``models/ling.py`` zeroes
+them under the pad).
+
+**The state's layout** is ``[L, B, H, d_v, d_k]`` — ``S`` TRANSPOSED, the
+value channel on the sublanes and the key channel on the lanes. Everything a
+step scales the state by (the decay, ``k``, ``beta k``, ``q``) varies along
+the key channel, so it is a lane row of the ``[B, H, d_k]`` arrays the layer
+already has; ``beta v`` alone varies along the sublanes and comes in as a
+``[d_v, 1]`` column, and a head's output leaves as one. The decode update is
+then element-wise products and two lane sums a head, in float32 with no
+matrix product, and the prefill kernel's state products are the plain forms
+the matrix unit takes (``x S`` contracts the lanes of both; the update
+``U^T K`` the sublanes of both). Both kernels take the whole stacked state
+with the layer's index as a prefetched scalar and write the layer's block
+back **in place** (``input_output_aliases``): the decode loop's carry does
+not copy it. The prefill kernel also takes a ROW PIECE: ``rows`` names, for
+each row of its inputs, the batch row of the state it continues (a third
+prefetched vector, which steers the state's index_map alone).
+
+**The chunked form** (``kda_prefill_scan``, ``kda_chunked_xla``; ``chunk`` =
+the config's ``kda_chunk_size``) is how the recurrence is computed, not
+another model. With ``G_t`` the running sum of ``g`` inside a chunk
+(``Gamma_t = exp(G_t)``), ``S_0`` the state entering it, and row ``t`` of
+``Q, K, V, U`` the token's vectors:
+
+    A_ti = sum_c beta_t k_t[c] k_i[c] exp(G_t[c] - G_i[c])      i <  t
+    B_ti = sum_c      q_t[c] k_i[c] exp(G_t[c] - G_i[c])        i <= t
+    T    = (I + A)^-1                    unit lower triangular (the WY form)
+    U    = T (beta V) - T (beta K * Gamma) S_0
+    O    = (Q * Gamma) S_0 + B U
+    S_C  = diag(Gamma_C) S_0 + (K * exp(G_C - G))^T U
+
+**Decays only ever as differences.** At the gate's bound of -5 a token,
+``exp(-G)`` alone overflows float32 within 18 tokens, so no factor
+``exp(-G_i)`` is ever formed: the kernel splits a chunk into sub-blocks of
+16 tokens and, for the rows of sub-block I, measures every exponent from
+``G`` at the sub-block's first row — ``exp(G_t - ref_I) <= 1`` on the row
+side, ``exp(ref_I - G_i)`` on the column side, which is ``<= 1`` for every
+earlier sub-block and at most ``exp(75)`` inside the sub-block itself
+(columns after the row's own are masked, their exponent clamped). ``T`` is
+built from the sub-blocks' own inverses (a product of ``I + N^(2^j)``, ``N``
+nilpotent of order 16) merged pair by pair (``[[P, 0], [C, Q]]^-1 = [[P^-1,
+0], [-Q^-1 C P^-1, Q^-1]]``), in float32 at the highest precision: it is an
+inverse, and rounding it to the inputs' type would be rounding every token's
+erase. The other products run in the inputs' type with float32 sums. The
+XLA form computes the same sums directly (every difference before its
+exponential, a triangular solve).
+
+Grid (rows, heads, token blocks), the blocks in sequence with the head's
+state in VMEM scratch; a head is one lane tile of the ``[B, S, H * 128]``
+arrays the layer has, so nothing is transposed for the kernel. The running
+sum ``G`` is taken outside, in float32 by XLA (a product on the matrix unit
+would round it). A token block wholly under the row's left pad is not
+fetched, a chunk wholly under it not computed: its output is written as
+zeros and the state passes (``kda_tokens_computed`` counts the rest on the
+host).
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# [B, S, ...] arrays padded with zeros at the END of S to whole chunks: a
+# position with k, v, beta and g zero neither decays nor writes
+from .ssd_scan import _whole_chunks
+
+VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+# tokens of a sub-block: exponents inside one reach 15 tokens x 5 = 75, and
+# exp(75) = 3.7e32 is a float32 (and a bfloat16)
+_SUB = 16
+# what a masked column's exponent is clamped to (exp(80) = 5.5e34: 128 of
+# them still sum inside float32)
+_CAP = 80.0
+# tokens one grid step of the prefill kernel holds (whole chunks)
+_BLOCK_TOKENS = 1024
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _sub_block(chunk: int) -> int:
+    sub = min(_SUB, chunk)
+    n = chunk // sub
+    if chunk % sub or n & (n - 1) or sub & (sub - 1):
+        raise ValueError(
+            f"a chunk of {chunk} tokens is no power-of-two count of "
+            f"sub-blocks of {sub} (itself a power of two)")
+    return sub
+
+
+# -- XLA forms ----------------------------------------------------------------
+
+
+def kda_step_xla(q, k, v, g, beta, state):
+    """One token of the recurrence: q, k, g [B, H, dk] (g float32), v
+    [B, H, dv], beta [B, H], state [B, H, dv, dk] float32 (S transposed) ->
+    (o [B, H, dv] float32, state). Sums, not products on the matrix unit:
+    float32 whatever the platform's default precision."""
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    beta = beta.astype(f32)[..., None]
+    decayed = state * jnp.exp(g.astype(f32))[:, :, None, :]
+    u = beta * v - jnp.sum(decayed * (beta * k)[:, :, None, :], axis=-1)
+    state = decayed + u[..., None] * k[:, :, None, :]
+    return jnp.sum(state * q[:, :, None, :], axis=-1), state
+
+
+def kda_recurrent_xla(q, k, v, g, beta, state):
+    """The recurrence token by token (``kda_step_xla`` under a scan): q, k,
+    g [B, S, H, dk], v [B, S, H, dv], beta [B, S, H], state [B, H, dv, dk]
+    -> (o [B, S, H, dv] float32, state). What the chunked forms compute."""
+    def step(state, xs):
+        o, state = kda_step_xla(*xs, state)
+        return state, o
+
+    state, o = jax.lax.scan(
+        step, state, tuple(a.swapaxes(0, 1) for a in (q, k, v, g, beta)))
+    return o.swapaxes(0, 1), state
+
+
+def kda_chunked_xla(q, k, v, g, beta, state, chunk: int, rows=None):
+    """The chunked scan in plain XLA: q, k [B, S, H, dk], v [B, S, H, dv],
+    g [B, S, H, dk] float32, beta [B, S, H], state [B, H, dv, dk] float32 ->
+    (o [B, S, H, dv] in v's type, the state after the S tokens). S is
+    padded at its END to whole chunks. ``rows`` [B] int32: the rows are a
+    piece of a ``state`` that holds more of them, row b continuing
+    ``state[rows[b]]``; those rows of the state are returned rewritten, its
+    others as they came."""
+    if rows is not None:
+        o, piece = kda_chunked_xla(q, k, v, g, beta, state[rows], chunk)
+        return o, state.at[rows].set(piece)
+    Bt, S, H, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    q, k, v, g, beta = _whole_chunks(
+        chunk, q.astype(f32), k.astype(f32), v.astype(f32), g.astype(f32),
+        beta.astype(f32))
+    nc = q.shape[1] // chunk
+
+    def chunks(a):   # [B, nc * C, H, ...] -> [nc, B, H, C, ...]
+        a = a.reshape((Bt, nc, chunk) + a.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(a, 3, 2), 1, 0)
+
+    qc, kc, vc, gc = chunks(q), chunks(k), chunks(v), chunks(g)
+    bc = chunks(beta[..., None])
+    G = jnp.cumsum(gc, axis=3)                             # [nc, B, H, C, dk]
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+    eye = jnp.eye(chunk, dtype=f32)
+
+    def step(St, xs):
+        q, k, v, G, beta = xs                              # [B, H, C, *]
+        kb, vb = k * beta, v * beta
+        # every difference before its exponential: [B, H, t, i, dk]
+        decay = jnp.exp(jnp.where(
+            tri[:, :, None], G[:, :, :, None, :] - G[:, :, None, :, :],
+            -jnp.inf))
+        A = jnp.sum(kb[:, :, :, None, :] * k[:, :, None, :, :] * decay, -1)
+        Bm = jnp.sum(q[:, :, :, None, :] * k[:, :, None, :, :] * decay, -1)
+        A = jnp.where(jnp.tril(tri, -1), A, 0.0)
+        gam = jnp.exp(G)
+        S0 = St.swapaxes(-1, -2)                           # [B, H, dk, dv]
+        rhs = vb - jnp.einsum("bhtc,bhcv->bhtv", kb * gam, S0,
+                              precision=_HIGHEST)
+        U = jax.scipy.linalg.solve_triangular(
+            eye + A, rhs, lower=True, unit_diagonal=True)
+        o = jnp.einsum("bhtc,bhcv->bhtv", q * gam, S0, precision=_HIGHEST) \
+            + jnp.einsum("bhti,bhiv->bhtv", Bm, U, precision=_HIGHEST)
+        last = G[:, :, -1:, :]
+        St = St * jnp.exp(last) + jnp.einsum(
+            "bhtv,bhtc->bhvc", U, k * jnp.exp(last - G), precision=_HIGHEST)
+        return St, o
+
+    state, o = jax.lax.scan(step, state.astype(f32), (qc, kc, vc, G, bc))
+    # [nc, B, H, C, dv] -> [B, S, H, dv]
+    o = jnp.moveaxis(o, 0, 1).swapaxes(2, 3).reshape(Bt, nc * chunk, H, dv)
+    return o[:, :S].astype(v.dtype), state
+
+
+# -- the prefill kernel -------------------------------------------------------
+
+
+def _prefill_kernel(lidx_ref, pad_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
+                    sin_ref, o_ref, sout_ref, s_scr, *, chunk: int, sub: int,
+                    block: int):
+    # q/k/kb/vb/o [1, block, d] (one head's lanes), g [1, block, dk] float32
+    # (the running sum inside each chunk); the state [1, 1, 1, dv, dk]
+    b, t = pl.program_id(0), pl.program_id(2)
+    nt = pl.num_programs(2)
+    C, n_sub = chunk, chunk // sub
+    f32 = jnp.float32
+
+    @pl.when(t == 0)
+    def _load():
+        s_scr[...] = sin_ref[0, 0, 0]
+
+    row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
+    eye = (row == col).astype(f32)
+
+    def same(size: int):
+        return (row // size) == (col // size)
+
+    def rhs_t(a, b):     # a [m, d] . b [n, d]^T
+        return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=f32)
+
+    def stacked(blocks):  # the sub-blocks' rows, one under the other
+        return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, 0)
+
+    def exact(a, b):     # float32 a @ b, not rounded to the inputs' type
+        return jnp.dot(a, b, preferred_element_type=f32, precision=_HIGHEST)
+
+    def one_chunk(c, _):
+        at = pl.ds(pl.multiple_of(c * C, C), C)
+        # a chunk wholly under the row's left pad: nothing enters the state
+        live = t * block + (c + 1) * C > pad_ref[b]
+
+        @pl.when(jnp.logical_not(live))
+        def _skip():
+            o_ref[0, at, :] = jnp.zeros((C, o_ref.shape[-1]), o_ref.dtype)
+
+        @pl.when(live)
+        def _compute():
+            dtype = q_ref.dtype
+            G = g_ref[0, at, :]                                  # [C, dk]
+            q = q_ref[0, at, :].astype(f32)
+            k = k_ref[0, at, :].astype(f32)
+            kb = kb_ref[0, at, :].astype(f32)
+            firsts = [G[i * sub:i * sub + 1] for i in range(n_sub)]
+            ref = stacked([jnp.broadcast_to(r, (sub, G.shape[1]))
+                           for r in firsts])
+            to_ref = jnp.exp(G - ref)                            # <= 1
+            kb_rows = (kb * to_ref).astype(dtype)
+            q_rows = (q * to_ref).astype(dtype)
+            a_rows, b_rows = [], []
+            for i, first in enumerate(firsts):
+                # the columns as sub-block i's rows see them: exponents from
+                # the sub-block's first row
+                cols = (k * jnp.exp(jnp.minimum(first - G, _CAP))
+                        ).astype(dtype)
+                mine = slice(i * sub, (i + 1) * sub)
+                a_rows.append(rhs_t(kb_rows[mine], cols))        # [sub, C]
+                b_rows.append(rhs_t(q_rows[mine], cols))
+            A = jnp.where(row > col, stacked(a_rows), 0.0)
+            Bm = jnp.where(row >= col, stacked(b_rows), 0.0)
+            # T = (I + A)^-1: the sub-blocks' own inverses, (I + N)(I + N^2)
+            # (I + N^4) ... with N = -A inside a sub-block, nilpotent
+            N = -jnp.where(same(sub), A, 0.0)
+            T, P = eye + N, N
+            for _ in range(max(sub.bit_length() - 2, 0)):
+                P = exact(P, P)
+                T = T + exact(T, P)
+            # ... merged pair by pair
+            size = sub
+            while size < C:
+                below = jnp.where(same(2 * size) & ~same(size), A, 0.0)
+                T = T - exact(exact(T, below), T)
+                size *= 2
+            Tm = T.astype(dtype)
+            gam = jnp.exp(G)
+            U = jnp.dot(Tm, vb_ref[0, at, :], preferred_element_type=f32)
+            W = jnp.dot(Tm, (kb * gam).astype(dtype),
+                        preferred_element_type=f32)
+            St = s_scr[...]                                      # [dv, dk]
+            Sd = St.astype(dtype)
+            U = U - rhs_t(W.astype(dtype), Sd)                   # [C, dv]
+            Ud = U.astype(dtype)
+            o = rhs_t((q * gam).astype(dtype), Sd) + jnp.dot(
+                Bm.astype(dtype), Ud, preferred_element_type=f32)
+            o_ref[0, at, :] = o.astype(o_ref.dtype)
+            last = G[C - 1:C]                                    # [1, dk]
+            to_end = (k * jnp.exp(last - G)).astype(dtype)
+            s_scr[...] = St * jnp.exp(last) + jax.lax.dot_general(
+                Ud, to_end, (((0,), (0,)), ((), ())),
+                preferred_element_type=f32)
+
+    jax.lax.fori_loop(0, block // C, one_chunk, None)
+
+    @pl.when(t == nt - 1)
+    def _store():
+        sout_ref[0, 0, 0] = s_scr[...]
+
+
+def _prefill_kernel_of_rows(lidx_ref, pad_ref, rows_ref, *refs, **geometry):
+    """``_prefill_kernel`` under a third prefetched vector (``rows``), which
+    only the state's index_map reads."""
+    del rows_ref
+    _prefill_kernel(lidx_ref, pad_ref, *refs, **geometry)
+
+
+def _block_tokens(n_chunks: int, chunk: int) -> int:
+    """Tokens a grid step holds: as many whole chunks as divide the call's
+    and stay within ``_BLOCK_TOKENS``."""
+    m = max(d for d in range(1, n_chunks + 1)
+            if n_chunks % d == 0 and d * chunk <= max(_BLOCK_TOKENS, chunk))
+    return m * chunk
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def kda_prefill_scan(q, k, v, g, beta, state, layer_idx, pad_lens, rows=None,
+                     *, chunk: int, interpret: bool = False):
+    """The chunked scan over S tokens from layer ``layer_idx``'s state of
+    the stacked ``state`` [L, B, H, dv, dk] float32; q, k [B, S, H, dk], v
+    [B, S, H, dv], g [B, S, H, dk] float32, beta [B, S, H], ``pad_lens`` [B]
+    the left-pad slots among these S (whole chunks of them are skipped).
+    Returns (o [B, S, H, dv] in v's type, the stacked state with the layer's
+    block overwritten in place). Semantics: ``kda_chunked_xla``.
+
+    ``rows`` [B] int32 (distinct): the rows are a piece of a state that
+    holds more of them, and row b continues — and overwrites, in place —
+    the state's batch row ``rows[b]``; no other row is fetched or written."""
+    Bt, S, H, dk = q.shape
+    dv = v.shape[-1]
+    sub = _sub_block(chunk)
+    f32 = jnp.float32
+    beta = beta.astype(f32)[..., None]
+    kb = (k.astype(f32) * beta).astype(k.dtype)
+    vb = (v.astype(f32) * beta).astype(v.dtype)
+    q, k, kb, vb, g = _whole_chunks(chunk, q, k, kb, vb, g.astype(f32))
+    Sp = q.shape[1]
+    nc = Sp // chunk
+    block = _block_tokens(nc, chunk)
+    nt = Sp // block
+    # the running sum of g inside each chunk, in float32
+    G = jnp.cumsum(g.reshape(Bt, nc, chunk, H * dk), axis=2).reshape(
+        Bt, Sp, H * dk)
+    prefetch = 2 if rows is None else 3
+
+    def first_live(b, t, pad):
+        # a pad block parks on the row's first live one: no fetch of its own
+        return jnp.minimum(jnp.maximum(t, pad[b] // block), nt - 1)
+
+    head_block = lambda width: pl.BlockSpec(  # noqa: E731
+        (1, block, width),
+        lambda b, h, t, lidx, pad, *rows: (b, first_live(b, t, pad), h))
+    state_block = pl.BlockSpec(
+        (1, 1, 1, dv, dk), lambda b, h, t, lidx, pad, *rows: (
+            lidx[0], rows[0][b] if rows else b, h, 0, 0))
+    kernel = functools.partial(
+        _prefill_kernel if rows is None else _prefill_kernel_of_rows,
+        chunk=chunk, sub=sub, block=block)
+    o, state = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=prefetch,
+            grid=(Bt, H, nt),
+            in_specs=[
+                head_block(dk), head_block(dk), head_block(dk),   # q, k, kb
+                head_block(dv),                                   # beta v
+                head_block(dk),                                   # G
+                state_block,
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block, dv),
+                             lambda b, h, t, *prefetched: (b, t, h)),
+                state_block,
+            ],
+            scratch_shapes=[pltpu.VMEM((dv, dk), f32)],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((Bt, Sp, H * dv), v.dtype),
+            jax.ShapeDtypeStruct(state.shape, f32),
+        ],
+        # the call's last operand, after the prefetched scalars and the five
+        # blocks before it, is the state
+        input_output_aliases={prefetch + 5: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        # a contract: the device trace and the benchmark's metrics name this
+        # kernel by it
+        name="kda_prefill_scan",
+    )(
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
+        pad_lens.astype(jnp.int32),
+        *(() if rows is None else (rows.astype(jnp.int32),)),
+        q.reshape(Bt, Sp, H * dk), k.reshape(Bt, Sp, H * dk),
+        kb.reshape(Bt, Sp, H * dk), vb.reshape(Bt, Sp, H * dv), G, state,
+    )
+    return o[:, :S].reshape(Bt, S, H, dv), state
+
+
+def kda_tokens_computed(pad_lens, S: int, chunk: int) -> int:
+    """Tokens of the chunks ``kda_prefill_scan`` does not skip, summed over
+    rows, for one call over S tokens with ``pad_lens`` left-pad slots among
+    them. Host arithmetic, the kernel's rule."""
+    import numpy as np
+
+    pads = np.minimum(np.asarray(pad_lens, np.int64), S)
+    chunks = -(-S // chunk)
+    return int(((chunks - pads // chunk) * chunk).sum())
+
+
+# -- the decode kernel --------------------------------------------------------
+
+
+def _decode_kernel(lidx_ref, q_ref, k_ref, kb_ref, decay_ref, vbt_ref,
+                   sin_ref, ot_ref, sout_ref, *, n_heads: int):
+    # q/k/kb/decay [1, H, dk] float32 rows; vbt/ot [1, dv, H]: a head's
+    # beta v and its output as columns; the state [1, 1, H, dv, dk]
+    for h in range(n_heads):
+        row = slice(h, h + 1)
+        decayed = sin_ref[0, 0, h] * decay_ref[0, row, :]        # [dv, dk]
+        u = vbt_ref[0, :, row] - jnp.sum(
+            decayed * kb_ref[0, row, :], axis=1, keepdims=True)  # [dv, 1]
+        new = decayed + u * k_ref[0, row, :]
+        sout_ref[0, 0, h] = new
+        ot_ref[0, :, row] = jnp.sum(new * q_ref[0, row, :], axis=1,
+                                    keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kda_decode_update(q, k, v, g, beta, state, layer_idx, *,
+                      interpret: bool = False):
+    """One token for every row: q, k, g [B, H, dk], v [B, H, dv], beta
+    [B, H], the stacked ``state`` [L, B, H, dv, dk] float32, whose layer
+    ``layer_idx`` is read and overwritten in place. Returns (o [B, H, dv]
+    float32, the stacked state). Semantics: ``kda_step_xla``."""
+    Bt, H, dk = q.shape
+    dv = v.shape[-1]
+    f32 = jnp.float32
+    beta = beta.astype(f32)[..., None]
+    k = k.astype(f32)
+    row = pl.BlockSpec((1, H, dk), lambda b, lidx: (b, 0, 0))
+    col = pl.BlockSpec((1, dv, H), lambda b, lidx: (b, 0, 0))
+    state_block = pl.BlockSpec(
+        (1, 1, H, dv, dk), lambda b, lidx: (lidx[0], b, 0, 0, 0))
+    ot, state = pl.pallas_call(
+        functools.partial(_decode_kernel, n_heads=H),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Bt,),
+            in_specs=[row, row, row, row, col, state_block],
+            out_specs=[col, state_block],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((Bt, dv, H), f32),
+            jax.ShapeDtypeStruct(state.shape, f32),
+        ],
+        # operand 6 of the call (the prefetched scalar first) is the state
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="kda_decode_update",
+    )(
+        jnp.asarray(layer_idx, jnp.int32).reshape(1),
+        q.astype(f32), k, k * beta, jnp.exp(g.astype(f32)),
+        (v.astype(f32) * beta).swapaxes(1, 2), state,
+    )
+    return ot.swapaxes(1, 2), state
