@@ -1044,7 +1044,9 @@ def phase_ling(args) -> dict:
     delta-rule kernels (``ops/kda_scan.py``) compiled at the published
     widths — a row piece of the prefill scan under ragged pads, in place at
     rows of a larger state, and the one-token update — against their XLA
-    forms, each timed inside a jitted loop. Then one small generate: the
+    forms, each timed inside a jitted loop (the scan also at the cell's
+    call: four rows of a 2,048-token chunk into the map dispatch's stacked
+    state of 10 layers x 24 rows). Then one small generate: the
     first period ``K K K K K M`` at the published widths (five KDA layers,
     the latent attention at 32 heads with no compressed query, both dense
     layers and four sparse layers of which this chip holds 128 of 512
@@ -1119,11 +1121,19 @@ def phase_ling(args) -> dict:
         jax.block_until_ready(loop(*a))
         return (time.time() - t0) / n
 
+    # as the cell calls it: a piece's rows of the map dispatch's stacked
+    # state (10 KDA layers x 24 rows), every token live
+    cell_rows = jnp.arange(R, dtype=jnp.int32) + R
     times = {
         "kda_prefill_scan_s": timed(
             lambda q, k, v, g, b, st: kda_scan.kda_prefill_scan(
                 q, k, v, g, b, st, 1, pads * 0, rows, chunk=chunk,
                 interpret=interpret), q, k, v, g, beta, state),
+        "kda_prefill_scan_cell_s": timed(
+            lambda q, k, v, g, b, st: kda_scan.kda_prefill_scan(
+                q, k, v, g, b, st, 7, pads * 0, cell_rows, chunk=chunk,
+                interpret=interpret), q, k, v, g, beta,
+            jnp.zeros((10, 24, H, d, d), jnp.float32)),
         "kda_decode_update_s": timed(
             lambda *a: kda_scan.kda_decode_update(
                 *a[:5], a[5], 1, interpret=interpret),

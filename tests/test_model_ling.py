@@ -629,15 +629,16 @@ def test_engine_refuses_the_slot_loop_and_the_ring(tiny):
 
 def test_prefill_counts_by_hand():
     """Four rows of a 256 bucket in two prefill chunks of 128, scan chunks
-    of 32: pads 6 and 0 skip nothing, 200 skips the first chunk whole and
-    two scan chunks of the second, 130 the first and none of the second."""
+    of 32 — four a prefill chunk, one group of the scan kernel's state-free
+    phase, computed whole or not at all: pads 6 and 0 skip nothing, 200 and
+    130 the first prefill chunk and none of the second."""
     from vnsum_tpu.ops.mla_attention import prefill_tile_classes
 
     cfg = ling.tiny_ling()
     pads = [6, 200, 0, 130]
     got = ling.prefill_counts(cfg, pads, [(0, 128), (128, 256)])
     real = 250 + 56 + 256 + 126
-    computed = (128 + 0 + 128 + 0) + (128 + 64 + 128 + 128)
+    computed = (128 + 0 + 128 + 0) + (128 + 128 + 128 + 128)
     keys = sum(prefill_tile_classes(pads, 128, hi, lo)["keys_expanded"]
                for lo, hi in [(0, 128), (128, 256)])
     assert got == {"kda_tokens_real": real * 4,

@@ -90,6 +90,15 @@ chunk: a piece's gathered latent rows and the per-row writes of latent rows,
 tails and picks are in it. `deepseek-v2`, whose attention functions took a
 `gate` argument this family passes, hashes as it did.
 
+**PR 57 moved `ling` and `ling-row-pieces` on purpose**: the body of
+`kda_prefill_scan` (`ops/kda_scan.py`) runs a token block in two phases —
+what a chunk needs of `q, k, beta, G` alone (the triangles, the float32
+inverse with its block-diagonal factors folded to a block's rows, `T [beta V
+| beta K * Gamma]`) for a group of four chunks at a time into VMEM scratch,
+then the four products with the state chunk by chunk — and that body is in
+both Ling programs and in no other family's; the other sixteen did not move
+(the file's own `__main__` printed them as they stand).
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
@@ -127,8 +136,8 @@ _PINNED = {
     "granite-h-row-pieces": ("tiny-granite-h", {}, "8ece4d118fa328f3"),
     "lfm2": ("tiny-lfm2", {}, "2ae2707f3d02f044"),
     "lfm2-row-pieces": ("tiny-lfm2", {}, "10157522ffa94d1f"),
-    "ling": ("tiny-ling", {}, "c8779ab54ff08e62"),
-    "ling-row-pieces": ("tiny-ling", {}, "0550cb2fa46f8c9a"),
+    "ling": ("tiny-ling", {}, "0a57afbbdab8c02c"),
+    "ling-row-pieces": ("tiny-ling", {}, "11a5121278f56bcb"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)

@@ -28,6 +28,15 @@ def _draw(seed, B, S, H, dk, dv, bound=-5.0, spread=3.0, dtype=jnp.float32):
             state)
 
 
+def _under_pads(q, k, v, beta, st, pads, S):
+    """A row's left pad as ``models/ling.py`` hands it over: q, k, v and
+    beta zero under it, and a padded row's state zero when it starts."""
+    real = jnp.arange(S)[None, :] >= pads[:, None]
+    q, k, v = (a * real[:, :, None, None] for a in (q, k, v))
+    return (q, k, v, beta * real[:, :, None],
+            st * (pads == 0)[:, None, None, None])
+
+
 def _err(a, b) -> float:
     return float(jnp.abs(jnp.asarray(a, jnp.float32)
                          - jnp.asarray(b, jnp.float32)).max())
@@ -78,6 +87,103 @@ def test_prefill_kernel_hands_its_state_from_block_to_block(monkeypatch):
         q, k, v, g, beta, st[None], 0, jnp.zeros((2,), jnp.int32), chunk=16,
         interpret=True)
     assert _err(ok, o) < 5e-6 and _err(sk[0], s) < 5e-6
+
+
+GROUPED = {  # S, chunk, tokens a block, pads, rows of a state of five
+    # two blocks of five chunks a call: a group of four and one of one a
+    # block, as a piece of a larger state
+    "odd-chunks-a-block": (160, 16, 80, [0, 90], [3, 1]),
+    # row 1's pad ends inside the second chunk: the first chunk of the
+    # group is dead, the others live
+    "pad-ends-inside-a-group": (64, 16, 1024, [0, 20], [4, 0]),
+    # a first group wholly under row 1's pad, half of the second (computed,
+    # and nothing of it enters the state)
+    "a-group-under-the-pad": (128, 16, 1024, [0, 100], None),
+    # a chunk a block, three blocks: every group is one chunk
+    "a-chunk-a-block": (48, 16, 16, [0, 17], [2, 4]),
+    # chunks of four sub-blocks, five of them: 256 rows an operand
+    "five-chunks-of-64": (320, 64, 1024, [70, 0], [1, 0]),
+}
+
+
+@pytest.mark.parametrize("case", GROUPED)
+def test_prefill_kernel_groups_its_chunks_as_the_block_has_them(
+        case, monkeypatch):
+    """The state-free phase takes the chunks of a block a group at a time:
+    a group the block does not fill, a group a row's left pad ends inside,
+    with and without ``rows`` — against the recurrence and the XLA form."""
+    S, chunk, block, pads, rows = GROUPED[case]
+    monkeypatch.setattr(kda_scan, "_BLOCK_TOKENS", block)
+    B, H, dk, dv = 2, 2, 16, 16
+    q, k, v, g, beta, st = _draw(10, B, S, H, dk, dv)
+    pads = jnp.array(pads)
+    q, k, v, beta, st = _under_pads(q, k, v, beta, st, pads, S)
+    if rows is None:
+        big, mine = jnp.stack([st, st + 1.0]), slice(None)
+        ok, sk = kda_scan.kda_prefill_scan(
+            q, k, v, g, beta, big, 0, pads, chunk=chunk, interpret=True)
+    else:
+        mine = jnp.array(rows)
+        big = jnp.full((2, 5, H, dv, dk), 7.0).at[0, mine].set(st)
+        ok, sk = kda_scan.kda_prefill_scan(
+            q, k, v, g, beta, big, 0, pads, mine, chunk=chunk,
+            interpret=True)
+        others = jnp.array(sorted(set(range(5)) - set(rows)))
+        assert (np.asarray(sk[0][others]) == 7.0).all()
+    o, s = kda_scan.kda_recurrent_xla(q, k, v, g, beta, st)
+    oc, sc = kda_scan.kda_chunked_xla(q, k, v, g, beta, st, chunk)
+    assert float(jnp.abs(o).max()) > 0.1
+    assert _err(ok, o) < 5e-6 and _err(sk[0][mine], s) < 5e-6
+    assert _err(ok, oc) < 5e-6 and _err(sk[0][mine], sc) < 5e-6
+    assert _err(sk[1], big[1]) == 0.0            # the other layer untouched
+    for b, pad in enumerate(np.asarray(pads)):   # whole pad chunks: zeros
+        assert not np.asarray(ok[b, :pad // chunk * chunk]).any()
+
+
+@pytest.mark.parametrize("group", [1, 2, 3, 8])
+def test_the_size_of_a_group_changes_no_sum(group, monkeypatch):
+    """However many chunks share an operand, the result is the
+    recurrence's: seven chunks under a pad that ends inside the third."""
+    monkeypatch.setattr(kda_scan, "_GROUP_CHUNKS", group)
+    q, k, v, g, beta, st = _draw(12, 2, 112, 1, 16, 16)
+    pads = jnp.array([0, 37])
+    q, k, v, beta, st = _under_pads(q, k, v, beta, st, pads, 112)
+    o, s = kda_scan.kda_recurrent_xla(q, k, v, g, beta, st)
+    # not the jitted entry: jit caches by arguments, not by the constant
+    ok, sk = kda_scan.kda_prefill_scan.__wrapped__(
+        q, k, v, g, beta, st[None], 0, pads, chunk=16, interpret=True)
+    assert _err(ok, o) < 5e-6 and _err(sk[0], s) < 5e-6
+    assert not np.asarray(ok[1, :32]).any()
+    assert kda_scan.kda_tokens_computed(pads, 112, 16) == {
+        1: 7 + 5, 2: 7 + 5, 3: 7 + 7, 8: 7 + 7}[group] * 16
+
+
+def test_float32_inputs_give_what_the_one_phase_kernel_gave():
+    """The same sums in another order: outputs and state of the kernel as
+    PR 56 left it (a chunk at a time, every product inside the state's
+    loop), interpreted at float32 on this draw, to 1e-5. Three chunks of
+    64, one group, row 1's pad ending inside the second."""
+    q, k, v, g, beta, st = _draw(11, 2, 192, 2, 32, 32)
+    pads = jnp.array([0, 70])
+    q, k, v, beta, st = _under_pads(q, k, v, beta, st, pads, 192)
+    o, s = kda_scan.kda_prefill_scan(
+        q, k, v, g, beta, st[None], 0, pads, chunk=64, interpret=True)
+    o, s = np.asarray(o), np.asarray(s[0])
+    was_o0 = [[0.12740950, -0.01662224, -0.03305814],      # tokens 0, 63,
+              [-0.00143864, 0.00904303, 0.00151281],       # 64, 191 of row
+              [-0.01936263, -0.01867227, -0.00054826],     # 0, head 1
+              [0.01797232, -0.00652484, -0.01111810]]
+    was_o1 = [[0.0, 0.0, 0.0],                             # tokens 69, 70,
+              [-0.00681758, -0.01911932, -0.03725274],     # 127, 128, 191
+              [-0.00607630, -0.00490933, -0.00834571],     # of row 1, head 0
+              [0.01151496, 0.00416203, -0.01943647],
+              [0.02050495, 0.01968795, -0.01952249]]
+    was_s = [[[0.02519188, 0.05876053], [-0.01498567, -0.03466559]],
+             [[-0.13889401, -0.08171223], [0.01867666, 0.04982152]]]
+    assert np.abs(o[0, [0, 63, 64, 191], 1, :3] - was_o0).max() < 1e-5
+    assert np.abs(o[1, [69, 70, 127, 128, 191], 0, :3] - was_o1).max() < 1e-5
+    assert np.abs(s[:, :, 5, [0, 31]] - was_s).max() < 1e-5
+    assert not o[1, :64].any()
 
 
 @pytest.mark.parametrize("form", ["kernel", "xla"])
@@ -183,11 +289,17 @@ def test_decode_kernel_is_the_one_token_step(B, H, dk, dv):
     assert np.abs(np.asarray(s).swapaxes(-1, -2) - new).max() < 1e-5
 
 
-def test_tokens_computed_by_hand():
-    # chunk 64 over 256 tokens: pads 0, 63, 64, 200, 256 skip 0, 0, 1, 3, 4
-    assert kda_scan.kda_tokens_computed([0, 63, 64, 200, 256], 256, 64) \
-        == (4 + 4 + 3 + 1 + 0) * 64
+def test_tokens_computed_by_hand(monkeypatch):
+    # chunk 64 over 512 tokens, groups of four chunks: pads 0, 255, 256,
+    # 300 and 512 skip 0, 0, 1, 1 and 2 groups
+    assert kda_scan.kda_tokens_computed([0, 255, 256, 300, 512], 512, 64) \
+        == (2 + 2 + 1 + 1 + 0) * 256
     assert kda_scan.kda_tokens_computed([10], 100, 64) == 128
+    # blocks of five chunks: a group of four and one of one a block; the
+    # pad of 330 ends inside the second block's first chunk
+    monkeypatch.setattr(kda_scan, "_BLOCK_TOKENS", 320)
+    assert kda_scan.kda_tokens_computed([0, 256, 330], 640, 64) \
+        == (10 + 6 + 5) * 64
 
 
 @pytest.mark.parametrize("chunk", [48, 24])
