@@ -12,30 +12,30 @@ if "xla_force_host_platform_device_count" not in flags:
         flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 os.environ.setdefault("JAX_ENABLE_X64", "0")
-# Each xdist worker compiles into a directory of its own. JAX's file cache
-# writes an entry in place (no rename, no lock), so a sibling worker that
-# reads the same key mid-write deserializes a truncated executable: the
-# worker of the driver's six-worker run that held
-# test_model_nemotron_h.py::test_engine_generates_and_counts_scan_cells_and_experts
-# died of a segmentation fault in compilation_cache.get_executable_and_time
-# (the test passes alone and beside its file's others). With the variable
-# set, core/jax_cache.py sets no directory and JAX's own handling stands; a
-# child process a test starts inherits its worker's directory.
-_worker = os.environ.get("PYTEST_XDIST_WORKER")
-if _worker:
-    os.environ.setdefault(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
-            __file__))), ".jax_cache", _worker))
+
+import gc  # noqa: E402
+import sys  # noqa: E402
 
 import pytest  # noqa: E402
 
-# Heavy JAX-compile modules: every test in these files traces + compiles real
-# model programs, which dominates wall-clock on a 1-core host (full suite
-# >10 min there). The remaining files are the FAST tier — host logic plus
-# tiny-encoder compiles — and finish in ~2.5 min:
-#   python -m pytest -m "not slow"
-# The full hermetic suite stays the CI default (plain `pytest`).
+# The C++ host core builds itself on first use (``make`` into one file). In
+# a fresh checkout six workers importing tests/test_native.py at once each
+# start that build, and a worker that loads the file while another's linker
+# is still writing it ("file too short") reports no library for good: its
+# seven tests skip. The session's first process builds it before any worker
+# starts; a worker's own ``make`` is then a no-op.
+if not os.environ.get("PYTEST_XDIST_WORKER"):
+    from vnsum_tpu import native as _native  # noqa: E402
+
+    _native.available()
+
+# ``slow`` today means "outside tier-1": the driver's command deselects the
+# marker (``-m 'not slow'``), so nothing runs these fourteen modules — the
+# dense path's own tests, 141 test functions — unless somebody names them.
+# They were set apart forty PRs ago for a one-core host; what each costs and
+# whether it still passes, run alone, is ROADMAP.md D19 (PR 58: thirteen
+# pass, one has rotted). Bringing them in is a count and a clock that a
+# later issue decides with those numbers.
 _SLOW_MODULES = {
     "test_backend_engine",
     "test_backend_long_context",
@@ -58,6 +58,61 @@ def pytest_collection_modifyitems(config, items):
     for item in items:
         if item.module.__name__ in _SLOW_MODULES:
             item.add_marker(pytest.mark.slow)
+
+
+# What ends a long-lived worker is its count of memory mappings (ROADMAP.md
+# D18): every executable XLA:CPU loads is a few hundred of them, JAX's
+# in-process caches keep every one, and at ``vm.max_map_count`` the loader's
+# next ``mmap`` fails inside a compile — a segmentation fault or an abort in
+# whatever test happens to run then. So a worker gives them back: at every
+# module's end, and inside a module once it holds a quarter of the limit.
+
+
+def _mappings() -> int:
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            return sum(1 for _ in f)
+    except OSError:          # no /proc: nothing to count, nothing to shed
+        return 0
+
+
+def _mappings_allowed() -> int:
+    try:
+        with open("/proc/sys/vm/max_map_count") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return 65530         # the kernel's default
+
+
+_SHED_ABOVE = _mappings_allowed() // 4
+
+
+def shed_compiled_programs() -> None:
+    """Give back every executable this process loaded: JAX's in-process
+    caches, then the cycles that keep an executable alive. What survives —
+    a jitted function, an engine, an array — compiles again when called."""
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        jax.clear_caches()
+    gc.collect()
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _shed_at_a_modules_end():
+    """Set up before a module's own fixtures and so torn down after them;
+    the family harness's engines go first: nothing else holds them."""
+    yield
+    harness = sys.modules.get("family_harness")
+    if harness is not None:
+        harness.forget_engines()
+    shed_compiled_programs()
+
+
+@pytest.fixture(autouse=True)
+def _shed_at_a_quarter_of_the_limit():
+    yield
+    if _mappings() > _SHED_ABOVE:
+        shed_compiled_programs()
 
 
 @pytest.fixture(scope="session")

@@ -13,8 +13,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from family_harness import engine
 from vnsum_tpu.backend.engine import TpuBackend
-from vnsum_tpu.models import MODEL_REGISTRY
+from vnsum_tpu.models import MODEL_REGISTRY, jitted_init
 from vnsum_tpu.models import deepseek as ds
 from vnsum_tpu.models.family import family_of
 from vnsum_tpu.models.quant import (
@@ -28,7 +29,7 @@ from vnsum_tpu.models.quant import (
 @pytest.fixture(scope="module")
 def tiny():
     cfg = ds.tiny_deepseek()
-    return cfg, ds.init_params(jax.random.key(0), cfg)
+    return cfg, jitted_init(ds.init_params, cfg, 0)
 
 
 # -- the config and the registry ---------------------------------------------
@@ -345,7 +346,7 @@ def test_int8_leaves_scales_are_folded_outside_the_kernel(
     cfg = ds.tiny_deepseek()
     if rows_a_piece:
         monkeypatch.setattr(ds, "_rows_a_piece", lambda *_: rows_a_piece)
-    params = quantize_params(ds.init_params(jax.random.key(3), cfg))
+    params = quantize_params(jitted_init(ds.init_params, cfg, 3))
     lp = jax.tree.map(lambda w: w[0], params["layers"])
     assert isinstance(lp["wk_b"], dict) and isinstance(lp["wv_b"], dict)
     B, S, C = 2, 128, 256                # offset + S <= C
@@ -430,7 +431,7 @@ def _expert_inputs(cfg, T, seed=0):
 def test_grouped_experts_match_the_dense_sum(quantized, held, offset):
     cfg = ds.tiny_deepseek(experts_held=held, expert_offset=offset,
                            w8a8_prefill=quantized)
-    params = ds.init_params(jax.random.key(3), cfg)
+    params = jitted_init(ds.init_params, cfg, 3)
     if quantized:
         params = quantize_params(params)
     experts = {n: params["layers"][n] for n in ds._EXPERTS}
@@ -448,7 +449,7 @@ def test_no_token_is_dropped_when_every_token_picks_one_expert():
     """Every pick of every token on expert 2: the worst case for a scheme
     with a capacity. The grouped product has none."""
     cfg = ds.tiny_deepseek()
-    params = ds.init_params(jax.random.key(4), cfg)
+    params = jitted_init(ds.init_params, cfg, 4)
     experts = {n: params["layers"][n] for n in ds._EXPERTS}
     T, k = 300, cfg.num_experts_per_tok
     x = jax.random.normal(jax.random.key(5), (T, cfg.dim))
@@ -696,15 +697,18 @@ def test_the_products_grid_is_bounded_by_the_tiles_used(case):
 # -- the program through the engine ------------------------------------------
 
 
-def _engine(cfg, params, **kw):
-    kw.setdefault("interpret", True)
-    return TpuBackend(model_config=cfg, tokenizer="byte", batch_size=2,
-                      max_new_tokens=8, params=params, **kw)
+def _two_rows(cfg, params, **kw):
+    """The harness's engine at this file's own defaults (two rows, whole
+    prompts, the cache's type left to the engine), one a test: most of the
+    tests below count what their own calls added to ``stats``."""
+    return engine(cfg, params, fresh=True, **{
+        "batch_size": 2, "prefill_chunk_tokens": 0, "quantize_kv": "auto",
+        **kw})
 
 
 def test_generate_runs_the_kernels_and_counts_the_experts(tiny):
     cfg, params = tiny
-    be = _engine(cfg, params, prefill_chunk_tokens=128)
+    be = _two_rows(cfg, params, prefill_chunk_tokens=128)
     outs = be.generate(["xin chao " * 30, "hello"], max_new_tokens=8)
     assert len(outs) == 2
     assert be.stats.attention_paths == {
@@ -728,7 +732,7 @@ def test_a_dispatch_counts_the_keys_its_prefill_expanded(tiny):
     import logging
 
     cfg, params = tiny
-    be = _engine(cfg, params, prefill_chunk_tokens=128)
+    be = _two_rows(cfg, params, prefill_chunk_tokens=128)
     lines = []
     handler = logging.Handler()
     handler.emit = lambda record: lines.append(record.getMessage())
@@ -754,7 +758,7 @@ def test_a_dispatch_counts_the_keys_its_prefill_expanded(tiny):
 
 def test_a_share_counts_only_the_experts_it_holds():
     cfg = ds.tiny_deepseek(experts_held=4, expert_offset=4)
-    be = _engine(cfg, ds.init_params(jax.random.key(0), cfg))
+    be = _two_rows(cfg, jitted_init(ds.init_params, cfg, 0))
     be.generate(["xin chao " * 20], max_new_tokens=8)
     st = be.stats
     assert 0 < st.expert_slots_held < st.expert_slots_routed
@@ -768,8 +772,8 @@ def test_kernel_path_and_dense_path_agree(tiny, quantize):
     cfg, params = tiny
     ids = list(range(5, 155))
     forced = [7, 8, 9]
-    a = _engine(cfg, params, prefill_chunk_tokens=128, quantize=quantize)
-    b = _engine(cfg, params, flash=False, interpret=False, quantize=quantize)
+    a = _two_rows(cfg, params, prefill_chunk_tokens=128, quantize=quantize)
+    b = _two_rows(cfg, params, flash=False, interpret=False, quantize=quantize)
     got = a.prefill_then_decode_logits(ids, forced, bucket=256)
     want = b.prefill_then_decode_logits(ids, forced, bucket=256)
     assert got.shape == (4, cfg.vocab_size)
@@ -802,12 +806,12 @@ def test_the_dense_families_logits_entry_matches_their_forward():
 def test_slot_loop_prefix_cache_mesh_and_speculation_refuse_the_family(tiny):
     cfg, params = tiny
     with pytest.raises(NotImplementedError, match="prefix cache.*no KV heads"):
-        _engine(cfg, params, cache_blocks=8)
+        _two_rows(cfg, params, cache_blocks=8)
     with pytest.raises(NotImplementedError, match="mesh.*no expert axis"):
-        _engine(cfg, params, mesh=object())
+        _two_rows(cfg, params, mesh=object())
     with pytest.raises(ValueError, match="cache has no int8 form"):
-        _engine(cfg, params, quantize_kv=True)
-    be = _engine(cfg, params)
+        _two_rows(cfg, params, quantize_kv=True)
+    be = _two_rows(cfg, params)
     assert be.quantize_kv is False
     with pytest.raises(NotImplementedError, match="slot loop.*latent cache"):
         be.start_slot_loop(slots=2)
@@ -867,7 +871,7 @@ def test_quantizing_a_dense_family_is_unchanged_by_the_new_groups():
 
 def test_w8a8_is_switched_on_by_the_engine_as_for_the_dense_families(tiny):
     cfg, params = tiny
-    be = _engine(cfg, params, quantize=True, quantize_act=True)
+    be = _two_rows(cfg, params, quantize=True, quantize_act=True)
     assert be.cfg.w8a8_prefill and not cfg.w8a8_prefill
     assert dataclasses.replace(cfg, w8a8_prefill=True) == be.cfg
 

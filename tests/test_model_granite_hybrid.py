@@ -4,32 +4,32 @@ query heads of 16 over 2 KV heads, 8 Mamba heads of 16, a state of 16, scan
 chunks of 8."""
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import engine_setup_granite_h as setup
 from benchmarks import reference_granite_h as reference
-from vnsum_tpu.models import MODEL_REGISTRY, llama
+from family_harness import (
+    alone_and_in_a_batch,
+    engine as _engine,
+    reference as jitted,
+    reference_of,
+    rel as _rel,
+    sizes,
+    through_the_engine as _through_the_engine,
+    tokens as _tokens,
+)
+from vnsum_tpu.models import MODEL_REGISTRY, jitted_init, llama
 from vnsum_tpu.models import granite_hybrid as gh
 from vnsum_tpu.models.family import family_of
 from vnsum_tpu.ops import ssd_scan
 
 
-def _tokens(n=60, rows=2, seed=1):
-    return jax.random.randint(jax.random.key(seed), (rows, n), 0, 384)
-
-
-def _sizes(cfg) -> dict:
-    """The published keys the reference reads, off a program config."""
-    from benchmarks.engine_setup_granite_h import sizes_from
-
-    return sizes_from(cfg)
-
-
-def _rel(got, want) -> float:
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+_sizes = functools.partial(sizes, setup)
 
 
 @pytest.fixture(scope="module")
@@ -39,20 +39,10 @@ def tiny():
     scores of a 0.02-normal draw are flat to 1e-3 and no fault of the
     attention (a rotary, another scale) would show in the logits."""
     cfg = gh.tiny_granite_h()
-    params = gh.init_params(jax.random.key(0), cfg)
+    params = jitted_init(gh.init_params, cfg, 0)
     attn = dict(params["attn"], wq=params["attn"]["wq"] * 30.0,
                 wk=params["attn"]["wk"] * 30.0)
     return cfg, dict(params, attn=attn)
-
-
-def _engine(cfg, params, **kw):
-    from vnsum_tpu.backend.engine import TpuBackend
-
-    # a float cache unless a test asks: int8 keys and values are a rounding
-    # of their own (1e-4 of the logits here), beside what is compared
-    kw = {"batch_size": 1, "max_new_tokens": 8, "interpret": True,
-          "prefill_chunk_tokens": 128, "quantize_kv": False, **kw}
-    return TpuBackend(model_config=cfg, tokenizer="byte", params=params, **kw)
 
 
 # -- the config and the parameters ---------------------------------------------
@@ -123,7 +113,7 @@ def test_int8_keeps_the_scans_vectors_in_float32_and_ties_the_head():
     )
 
     cfg = gh.tiny_granite_h()
-    params = gh.init_params(jax.random.key(0), cfg)
+    params = jitted_init(gh.init_params, cfg, 0)
     q = quantize_params(params)
     assert q["mamba"]["in_xbc"]["q"].dtype == jnp.int8
     assert q["mamba"]["in_xbc"]["s"].shape == (18, 160)
@@ -362,7 +352,7 @@ def test_cache_free_forward_equals_the_reference(tiny):
     toks = _tokens(37)
     with jax.default_matmul_precision("highest"):
         got = gh.forward_dense(params, cfg, toks)
-        want = jnp.stack([reference.logits(params, t, _sizes(cfg))
+        want = jnp.stack([jitted(reference, _sizes(cfg))(params, t)["logits"]
                           for t in toks])
     assert got.shape == (2, 37, cfg.vocab_size)
     assert _rel(got, want) < 1e-5
@@ -373,8 +363,9 @@ def test_every_departure_of_the_reference_shows_in_the_logits(tiny, fault):
     cfg, params = tiny
     toks = _tokens(37)[0]
     with jax.default_matmul_precision("highest"):
-        want = reference.logits(params, toks, _sizes(cfg))
-        other = reference.logits(params, toks, _sizes(cfg), faults=(fault,))
+        want = jitted(reference, _sizes(cfg))(params, toks)["logits"]
+        other = jitted(reference, _sizes(cfg), faults=(fault,))(
+            params, toks)["logits"]
     assert _rel(other, want) > 1e-3
 
 
@@ -382,13 +373,6 @@ def test_reference_refuses_an_unknown_fault(tiny):
     cfg, params = tiny
     with pytest.raises(ValueError, match="unknown faults"):
         reference.logits(params, _tokens(5)[0], _sizes(cfg), faults=("x",))
-
-
-def _through_the_engine(cfg, params, ids, n, bucket, **kw):
-    be = _engine(cfg, params, **kw)
-    logits, state = be.prefill_then_decode_logits(
-        ids[:n], ids[n:], bucket=bucket, return_state=True)
-    return be, logits, state
 
 
 @pytest.mark.parametrize("flash", [True, False])
@@ -407,7 +391,7 @@ def test_engine_prefill_and_decode_agree_with_the_reference(tiny, flash):
     with jax.default_matmul_precision("highest"):
         be, got, state = _through_the_engine(cfg, params, ids, 150, 256, **kw)
         sizes = _sizes(cfg)
-        want = reference.forward(params, jnp.asarray(ids), sizes, last=6)
+        want = reference_of(reference, sizes, params, ids, last=6)
         assert _rel(got, want["logits"]) < 1e-5
         assert got.shape == (6, cfg.vocab_size)
         # the first and the last Mamba layer's state after the prompt and
@@ -418,7 +402,7 @@ def test_engine_prefill_and_decode_agree_with_the_reference(tiny, flash):
                 assert _rel(state["rows"][row, which, 0],
                             lay(want["ssm_rows"][which, row])) < 1e-5
         # ... which are the states of shorter sequences
-        short = reference.forward(params, jnp.asarray(ids[:152]), sizes)
+        short = reference_of(reference, sizes, params, ids[:152])
         assert _rel(short["ssm_rows"][:, 0], want["ssm_rows"][:, 2]) < 1e-6
     assert _rel(state["cache"]["ssm"][:, 0],
                 reference.state_as_the_program_lays_it(want["ssm"])) < 1e-5
@@ -436,8 +420,7 @@ def unpadded(tiny):
     _, params = tiny
     ids = np.asarray(_tokens(60, 1, seed=4))[0].tolist()
     with jax.default_matmul_precision("highest"):
-        want = reference.forward(params, jnp.asarray(ids), _sizes(cfg),
-                                 last=5)
+        want = reference_of(reference, _sizes(cfg), params, ids, last=5)
         _, got, state = _through_the_engine(cfg, params, ids, 56, 56)
     return cfg, ids, want, got, state["cache"]
 
@@ -472,8 +455,7 @@ def test_a_bf16_state_fails_the_states_tolerance(tiny):
     ids = np.asarray(_tokens(155, 1, seed=8))[0].tolist()
     with jax.default_matmul_precision("highest"):
         _, got, state = _through_the_engine(cfg, params, ids, 150, 256)
-        want = reference.forward(params, jnp.asarray(ids), _sizes(cfg),
-                                 last=6)
+        want = reference_of(reference, _sizes(cfg), params, ids, last=6)
     err = _rel(np.asarray(state["cache"]["ssm"][:, 0], np.float32),
                reference.state_as_the_program_lays_it(want["ssm"]))
     assert err > 1e-4, err
@@ -542,7 +524,7 @@ def test_engine_generates_and_counts_its_scan_and_its_attention_cells(tiny):
 
     cfg, params = tiny
     be = _engine(cfg, params, batch_size=2, max_new_tokens=6,
-                 quantize_kv=True)
+                 quantize_kv=True, fresh=True)
     packed = []
     pack = be._pack_group
     be._pack_group = lambda *a: packed.append(pack(*a)) or packed[-1]
@@ -573,16 +555,7 @@ def test_engine_generates_and_counts_its_scan_and_its_attention_cells(tiny):
 def test_generate_gives_the_same_rows_alone_and_in_a_batch(tiny):
     """A row's tokens do not hang on its neighbours or its pad: the state
     of one row never reaches another's (greedy, kernels interpreted)."""
-    from vnsum_tpu.core.config import GenerationConfig
-
-    cfg, params = tiny
-    gen = GenerationConfig(temperature=0.0)
-    prompts = ["xin chào " * 22, "một hai ba"]
-    both = _engine(cfg, params, batch_size=2, max_new_tokens=6,
-                   generation=gen).generate(prompts, max_new_tokens=6)
-    alone = [_engine(cfg, params, batch_size=1, max_new_tokens=6,
-                     generation=gen).generate([p], max_new_tokens=6)[0]
-             for p in prompts]
+    both, alone = alone_and_in_a_batch(*tiny)
     assert both == alone
 
 
@@ -592,7 +565,7 @@ def test_the_one_shot_program_names_the_familys_scopes(tiny):
     ``scripts/trace_by_scope.py`` books this family by."""
     cfg, params = tiny
     be = _engine(cfg, params, batch_size=2, max_new_tokens=4, flash=False,
-                 interpret=False)
+                 interpret=False, fresh=True)
     be._get_fn(2, 64, 4, be.gen_cfg)
     (m,) = be.scope_maps()
     got = {"/".join(p.split("/")[:2]) for p in m["scopes"].values()}

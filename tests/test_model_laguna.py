@@ -11,19 +11,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from vnsum_tpu.models import MODEL_REGISTRY, llama
+from family_harness import engine as _engine, tokens as _tokens
+from vnsum_tpu.models import MODEL_REGISTRY, jitted_init, llama
 from vnsum_tpu.models import laguna as lg
 from vnsum_tpu.models.family import family_of
-
-
-def _tokens(n=60, rows=2, seed=1):
-    return jax.random.randint(jax.random.key(seed), (rows, n), 0, 384)
 
 
 @pytest.fixture(scope="module")
 def tiny():
     cfg = lg.tiny_laguna()
-    return cfg, lg.init_params(jax.random.key(0), cfg)
+    return cfg, jitted_init(lg.init_params, cfg, 0)
 
 
 # -- the config ----------------------------------------------------------------
@@ -86,7 +83,7 @@ def test_int8_keeps_the_gate_the_router_and_the_norms_in_full():
     from vnsum_tpu.models.quant import init_params_quantized, quantize_params
 
     cfg = lg.tiny_laguna()
-    for tree in (quantize_params(lg.init_params(jax.random.key(0), cfg)),
+    for tree in (quantize_params(jitted_init(lg.init_params, cfg, 0)),
                  init_params_quantized(jax.random.key(0), cfg)):
         for group in ("dense", "full", "sliding"):
             assert set(tree[group]["wq"]) == {"q", "s"}
@@ -395,13 +392,9 @@ def test_engine_prefill_and_decode_agree_with_forward_dense(tiny, quantize_kv):
     teacher-forced decode steps through the cache, against the family's
     cache-free forward over the whole sequence, with a prompt six windows
     long: float weights, so what is left is the cache's own rounding."""
-    from vnsum_tpu.backend.engine import TpuBackend
-
     cfg = lg.tiny_laguna(max_seq_len=400)
     _, params = tiny
-    be = TpuBackend(model_config=cfg, tokenizer="byte", params=params,
-                    batch_size=1, max_new_tokens=8, interpret=True,
-                    quantize_kv=quantize_kv, prefill_chunk_tokens=128)
+    be = _engine(cfg, params, quantize_kv=quantize_kv)
     ids = np.asarray(_tokens(155, 1, seed=8))[0].tolist()
     assert 150 > 6 * cfg.sliding_window
     got = be.prefill_then_decode_logits(ids[:150], ids[150:], bucket=256)
@@ -422,13 +415,11 @@ def test_engine_generates_and_counts_its_cells_by_layer_kind(tiny):
     returned with the output, the prefill's cells counted by layer kind
     (each kind's own group), and beside the classes the scores the sliding
     layers computed and needed."""
-    from vnsum_tpu.backend.engine import TpuBackend
     from vnsum_tpu.ops.flash_attention import prefill_block_classes
 
     cfg, params = tiny
-    be = TpuBackend(model_config=cfg, tokenizer="byte", params=params,
-                    batch_size=2, max_new_tokens=6, interpret=True,
-                    quantize_kv=True, prefill_chunk_tokens=128)
+    be = _engine(cfg, params, batch_size=2, max_new_tokens=6,
+                 quantize_kv=True, fresh=True)
     packed = []
     pack = be._pack_group
     be._pack_group = lambda *a: packed.append(pack(*a)) or packed[-1]

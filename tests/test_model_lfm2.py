@@ -7,31 +7,31 @@ of 16 over 2 KV heads, 8 gated experts of 32 top-2, vocabulary 384 (the byte tok
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import engine_setup_lfm2 as setup
 from benchmarks import reference_lfm2 as reference
-from vnsum_tpu.models import MODEL_REGISTRY, experts, lfm2, mamba_mixer
+from family_harness import (
+    alone_and_in_a_batch,
+    engine as _engine,
+    picks_agree as _picks_agree,
+    reference as jitted,
+    reference_of,
+    rel as _rel,
+    sizes,
+    through_the_engine as _through_the_engine,
+    tokens as _tokens,
+)
+from vnsum_tpu.models import MODEL_REGISTRY, experts, jitted_init, lfm2, mamba_mixer
 from vnsum_tpu.models.family import family_of
 
 
-def _tokens(n=60, rows=2, seed=1):
-    return jax.random.randint(jax.random.key(seed), (rows, n), 0, 384)
-
-
-def _sizes(cfg) -> dict:
-    """The published keys the reference reads, off a program config."""
-    from benchmarks.engine_setup_lfm2 import sizes_from
-
-    return sizes_from(cfg)
-
-
-def _rel(got, want) -> float:
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+_sizes = functools.partial(sizes, setup)
 
 
 @pytest.fixture(scope="module")
@@ -41,25 +41,11 @@ def tiny():
     rotary would show) and the router ten times (so that its scores spread
     as the published widths' do: 0.02 x sqrt(2048) = 0.9 a logit there)."""
     cfg = lfm2.tiny_lfm2()
-    params = lfm2.init_params(jax.random.key(0), cfg)
+    params = jitted_init(lfm2.init_params, cfg, 0)
     attn = dict(params["attn"], wq=params["attn"]["wq"] * 30.0,
                 wk=params["attn"]["wk"] * 30.0)
     layers = dict(params["layers"], router=params["layers"]["router"] * 10.0)
     return cfg, dict(params, attn=attn, layers=layers)
-
-
-def _engine(cfg, params, piece_tokens=None, **kw):
-    from vnsum_tpu.backend.engine import TpuBackend
-
-    # a float cache unless a test asks: int8 keys and values are a rounding
-    # of their own, beside what is compared
-    kw = {"batch_size": 1, "max_new_tokens": 8, "interpret": True,
-          "prefill_chunk_tokens": 128, "quantize_kv": False, **kw}
-    be = TpuBackend(model_config=cfg, tokenizer="byte", params=params, **kw)
-    if piece_tokens is not None:
-        be.family = dataclasses.replace(be.family,
-                                        prefill_piece_tokens=piece_tokens)
-    return be
 
 
 # -- the config and the parameters ---------------------------------------------
@@ -320,8 +306,8 @@ def test_cache_free_forward_equals_the_reference(tiny, int8):
     toks = _tokens(37)
     with jax.default_matmul_precision("highest"):
         got = lfm2.forward_dense(params, cfg, toks)
-        want = jnp.stack([reference.logits(params, t, _sizes(cfg))
-                          for t in toks])
+    want = jnp.stack([jitted(reference, _sizes(cfg))(params, t)["logits"]
+                      for t in toks])
     assert got.shape == (2, 37, cfg.vocab_size)
     assert float(jnp.abs(want).max()) > 0.1
     assert _rel(got, want) < 1e-5
@@ -330,17 +316,15 @@ def test_cache_free_forward_equals_the_reference(tiny, int8):
 @pytest.fixture(scope="module")
 def clean_logits(tiny):
     cfg, params = tiny
-    with jax.default_matmul_precision("highest"):
-        return reference.logits(params, _tokens(37)[0], _sizes(cfg))
+    return jitted(reference, _sizes(cfg))(params, _tokens(37)[0])["logits"]
 
 
 @pytest.mark.parametrize("fault", reference.FAULTS)
 def test_every_departure_of_the_reference_shows_in_the_logits(
         tiny, clean_logits, fault):
     cfg, params = tiny
-    with jax.default_matmul_precision("highest"):
-        other = reference.logits(params, _tokens(37)[0], _sizes(cfg),
-                                 faults=(fault,))
+    other = jitted(reference, _sizes(cfg), faults=(fault,))(
+        params, _tokens(37)[0])["logits"]
     assert _rel(other, clean_logits) > 1e-3
 
 
@@ -377,21 +361,6 @@ def test_reference_takes_rightful_picks_inside_the_band_alone():
     assert not reference.ties_broken_their_way(ranked, theirs, 0.0)[0]
 
 
-def _through_the_engine(cfg, params, ids, n, bucket, **kw):
-    be = _engine(cfg, params, **kw)
-    logits, state = be.prefill_then_decode_logits(
-        ids[:n], ids[n:], bucket=bucket, return_state=True)
-    return be, logits, state
-
-
-def _picks_agree(state, want, rows: int) -> bool:
-    """The routers' picks of the scored rows, every sparse layer, are the
-    reference's own (float32 against float32: no tie to break)."""
-    mine = np.sort(np.asarray(state["rows"]["picks"])[:, :, 0], -1)
-    theirs = np.sort(np.asarray(want["ids"])[:, -rows:], -1).swapaxes(0, 1)
-    return bool((mine == theirs).all())
-
-
 def _agrees_with_the_reference(cfg, params, ids, n, bucket, rows, **kw):
     """Logits, the first and last convolution layer's tail position by
     position, every layer's final tail, keys, picks and every expert's
@@ -400,8 +369,7 @@ def _agrees_with_the_reference(cfg, params, ids, n, bucket, rows, **kw):
     with jax.default_matmul_precision("highest"):
         be, got, state = _through_the_engine(cfg, params, ids, n, bucket,
                                              **kw)
-        want = reference.forward(params, jnp.asarray(ids), _sizes(cfg),
-                                 last=rows)
+    want = reference_of(reference, _sizes(cfg), params, ids, last=rows)
     assert got.shape == (rows, cfg.vocab_size)
     assert _rel(got, want["logits"]) < 1e-5
     for row in range(rows):
@@ -515,8 +483,7 @@ def test_a_tail_kept_a_precision_below_fails_the_tails_tolerance(tiny):
     ids = np.asarray(_tokens(155, 1, seed=8))[0].tolist()
     with jax.default_matmul_precision("highest"):
         _, got, state = _through_the_engine(cfg, params, ids, 150, 256)
-        want = reference.forward(params, jnp.asarray(ids), _sizes(cfg),
-                                 last=6)
+    want = reference_of(reference, _sizes(cfg), params, ids, last=6)
     assert state["cache"]["conv"].dtype.name == "bfloat16"
     err = _rel(np.asarray(state["cache"]["conv"][:, 0], np.float32),
                want["conv"])
@@ -641,7 +608,7 @@ def test_engine_generates_and_counts_conv_tokens_blocks_and_experts(tiny):
 
     cfg, params = tiny
     be = _engine(cfg, params, batch_size=2, max_new_tokens=6,
-                 quantize_kv=True)
+                 quantize_kv=True, fresh=True)
     packed = []
     pack = be._pack_group
     be._pack_group = lambda *a: packed.append(pack(*a)) or packed[-1]
@@ -680,14 +647,5 @@ def test_generate_gives_the_same_rows_alone_and_in_a_batch(tiny):
     """A row's tokens do not hang on its neighbours or its pad: neither the
     tail nor an expert's rows of one row reach another's (greedy, kernels
     interpreted)."""
-    from vnsum_tpu.core.config import GenerationConfig
-
-    cfg, params = tiny
-    gen = GenerationConfig(temperature=0.0)
-    prompts = ["xin chào " * 22, "một hai ba"]
-    both = _engine(cfg, params, batch_size=2, max_new_tokens=6,
-                   generation=gen).generate(prompts, max_new_tokens=6)
-    alone = [_engine(cfg, params, batch_size=1, max_new_tokens=6,
-                     generation=gen).generate([p], max_new_tokens=6)[0]
-             for p in prompts]
+    both, alone = alone_and_in_a_batch(*tiny)
     assert both == alone

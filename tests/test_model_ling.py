@@ -15,53 +15,32 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import engine_setup_ling as setup
 from benchmarks import reference_ling as reference
-from vnsum_tpu.models import MODEL_REGISTRY, experts, ling
+from family_harness import (
+    alone_and_in_a_batch,
+    engine as _engine,
+    reference as jitted,
+    reference_of,
+    rel as _rel,
+    sizes,
+    through_the_engine as _through_the_engine,
+    tokens as _tokens,
+)
+from vnsum_tpu.models import MODEL_REGISTRY, experts, jitted_init, ling
 from vnsum_tpu.models.family import family_of
 
 
-def _tokens(n=60, rows=2, seed=1):
-    return jax.random.randint(jax.random.key(seed), (rows, n), 0, 384)
+_sizes = functools.partial(sizes, setup)
 
 
-def _sizes(cfg) -> dict:
-    """The published keys the reference reads, off a program config."""
-    from benchmarks.engine_setup_ling import sizes_from
-
-    return sizes_from(cfg)
-
-
-def _rel(got, want) -> float:
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
-
-
-@functools.lru_cache(maxsize=None)
 def _reference(cfg, last=None, faults=()):
-    """The reference's forward for ``cfg``'s sizes, jitted: one compile a
-    sequence length, where op by op it is minutes of a test file."""
-    sizes = _sizes(cfg)
-
-    def forward(params, ids):
-        with jax.default_matmul_precision("highest"):
-            return reference.forward(params, ids, sizes, last=last,
-                                     faults=faults)
-
-    return jax.jit(forward)
-
-
-_KEPT = {}
+    return jitted(reference, _sizes(cfg), last=last, faults=faults)
 
 
 def _reference_of(cfg, params, ids, last):
-    """... of one sequence, computed once a session: several tests run the
-    same prompt through the engine another way."""
-    key = (id(params), tuple(ids), last)
-    if key not in _KEPT:
-        plain = dataclasses.replace(
-            cfg, max_seq_len=0, state_dtype=jnp.float32, latent_int8=False)
-        _KEPT[key] = _reference(plain, last)(params, jnp.asarray(ids))
-    return _KEPT[key]
+    return reference_of(reference, _sizes(cfg), params, ids, last=last,
+                        faults=())
 
 
 @pytest.fixture(scope="module")
@@ -72,23 +51,11 @@ def tiny():
     (so that its scores spread as the published widths' do: 0.02 x
     sqrt(2560) = 1.0 a logit there)."""
     cfg = ling.tiny_ling()
-    params = ling.init_params(jax.random.key(0), cfg)
+    params = jitted_init(ling.init_params, cfg, 0)
     mla = dict(params["mla"], wq_b=params["mla"]["wq_b"] * 30.0,
                wkv_a=params["mla"]["wkv_a"] * 30.0)
     layers = dict(params["layers"], router=params["layers"]["router"] * 10.0)
     return cfg, dict(params, mla=mla, layers=layers)
-
-
-def _engine(cfg, params, piece_tokens=None, **kw):
-    from vnsum_tpu.backend.engine import TpuBackend
-
-    kw = {"batch_size": 1, "max_new_tokens": 8, "interpret": True,
-          "prefill_chunk_tokens": 128, **kw}
-    be = TpuBackend(model_config=cfg, tokenizer="byte", params=params, **kw)
-    if piece_tokens is not None:
-        be.family = dataclasses.replace(be.family,
-                                        prefill_piece_tokens=piece_tokens)
-    return be
 
 
 # -- the config and the parameters ---------------------------------------------
@@ -377,13 +344,6 @@ def test_reference_is_plain_float32_and_reads_nothing_of_the_program():
     assert len(reference.FAULTS) == 17
 
 
-def _through_the_engine(cfg, params, ids, n, bucket, **kw):
-    be = _engine(cfg, params, **kw)
-    logits, state = be.prefill_then_decode_logits(
-        ids[:n], ids[n:], bucket=bucket, return_state=True)
-    return be, logits, state
-
-
 def _agrees_with_the_reference(cfg, params, ids, n, bucket, rows, **kw):
     """Logits, the first and last KDA layer's state position by position,
     every layer's final state and tail, the latent rows, picks and every
@@ -652,7 +612,7 @@ def test_engine_generates_and_counts_tokens_keys_and_experts(tiny):
     tokens over 4 KDA layers and the latent kernel's keys over 2 MLA layers
     in ``prefill_blocks``, the expert counters on ``EngineStats``."""
     cfg, params = tiny
-    be = _engine(cfg, params, batch_size=2, max_new_tokens=6)
+    be = _engine(cfg, params, batch_size=2, max_new_tokens=6, fresh=True)
     packed = []
     pack = be._pack_group
     be._pack_group = lambda *a: packed.append(pack(*a)) or packed[-1]
@@ -699,14 +659,5 @@ def test_generate_gives_the_same_rows_alone_and_in_a_batch(tiny):
     """A row's tokens do not hang on its neighbours or its pad: neither the
     state, the tail, the latent rows nor an expert's rows of one row reach
     another's (greedy, kernels interpreted)."""
-    from vnsum_tpu.core.config import GenerationConfig
-
-    cfg, params = tiny
-    gen = GenerationConfig(temperature=0.0)
-    prompts = ["xin chào " * 22, "một hai ba"]
-    both = _engine(cfg, params, batch_size=2, max_new_tokens=6,
-                   generation=gen).generate(prompts, max_new_tokens=6)
-    alone = [_engine(cfg, params, batch_size=1, max_new_tokens=6,
-                     generation=gen).generate([p], max_new_tokens=6)[0]
-             for p in prompts]
+    both, alone = alone_and_in_a_batch(*tiny)
     assert both == alone

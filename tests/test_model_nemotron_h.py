@@ -7,33 +7,33 @@ experts of 24 (not whole lanes of anything) top-4 with a shared one of 48."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmarks import engine_setup_nemotron_h as setup
 from benchmarks import reference_nemotron_h as reference
-from vnsum_tpu.models import MODEL_REGISTRY, experts
+from family_harness import (
+    alone_and_in_a_batch,
+    engine as _engine,
+    picks_agree as _picks_agree,
+    reference as jitted,
+    reference_of,
+    rel as _rel,
+    sizes,
+    through_the_engine as _through_the_engine,
+    tokens as _tokens,
+)
+from vnsum_tpu.models import MODEL_REGISTRY, experts, jitted_init
 from vnsum_tpu.models import nemotron_h as nh
 from vnsum_tpu.models.family import family_of
 from vnsum_tpu.ops import ssd_scan
 
 
-def _tokens(n=60, rows=2, seed=1):
-    return jax.random.randint(jax.random.key(seed), (rows, n), 0, 384)
-
-
-def _sizes(cfg) -> dict:
-    """The published keys the reference reads, off a program config."""
-    from benchmarks.engine_setup_nemotron_h import sizes_from
-
-    return sizes_from(cfg)
-
-
-def _rel(got, want) -> float:
-    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
-    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+_sizes = functools.partial(sizes, setup)
 
 
 @pytest.fixture(scope="module")
@@ -43,21 +43,11 @@ def tiny():
     rotary would show) and the router ten times (so that its scores spread
     as the published widths' do: 0.02 x sqrt(2688) = 1.0 a logit there)."""
     cfg = nh.tiny_nemotron_h()
-    params = nh.init_params(jax.random.key(0), cfg)
+    params = jitted_init(nh.init_params, cfg, 0)
     attn = dict(params["attn"], wq=params["attn"]["wq"] * 30.0,
                 wk=params["attn"]["wk"] * 30.0)
     layers = dict(params["layers"], router=params["layers"]["router"] * 10.0)
     return cfg, dict(params, attn=attn, layers=layers)
-
-
-def _engine(cfg, params, **kw):
-    from vnsum_tpu.backend.engine import TpuBackend
-
-    # a float cache unless a test asks: int8 keys and values are a rounding
-    # of their own, beside what is compared
-    kw = {"batch_size": 1, "max_new_tokens": 8, "interpret": True,
-          "prefill_chunk_tokens": 128, "quantize_kv": False, **kw}
-    return TpuBackend(model_config=cfg, tokenizer="byte", params=params, **kw)
 
 
 # -- the config and the parameters ---------------------------------------------
@@ -398,7 +388,7 @@ def test_cache_free_forward_equals_the_reference(tiny):
     toks = _tokens(37)
     with jax.default_matmul_precision("highest"):
         got = nh.forward_dense(params, cfg, toks)
-        want = jnp.stack([reference.logits(params, t, _sizes(cfg))
+        want = jnp.stack([jitted(reference, _sizes(cfg))(params, t)["logits"]
                           for t in toks])
     assert got.shape == (2, 37, cfg.vocab_size)
     assert _rel(got, want) < 1e-5
@@ -408,7 +398,8 @@ def test_cache_free_forward_equals_the_reference(tiny):
 def clean_logits(tiny):
     cfg, params = tiny
     with jax.default_matmul_precision("highest"):
-        return reference.logits(params, _tokens(37)[0], _sizes(cfg))
+        return jitted(reference, _sizes(cfg))(
+            params, _tokens(37)[0])["logits"]
 
 
 @pytest.mark.parametrize("fault", reference.FAULTS)
@@ -416,8 +407,8 @@ def test_every_departure_of_the_reference_shows_in_the_logits(
         tiny, clean_logits, fault):
     cfg, params = tiny
     with jax.default_matmul_precision("highest"):
-        other = reference.logits(params, _tokens(37)[0], _sizes(cfg),
-                                 faults=(fault,))
+        other = jitted(reference, _sizes(cfg), faults=(fault,))(
+            params, _tokens(37)[0])["logits"]
     assert _rel(other, clean_logits) > 1e-3
 
 
@@ -433,21 +424,6 @@ def test_reference_takes_rightful_picks_inside_the_band_alone():
     took = reference.ties_broken_their_way(ranked, theirs, 0.01)
     assert np.asarray(took).tolist() == [True, False, False]
     assert not reference.ties_broken_their_way(ranked, theirs, 0.0)[0]
-
-
-def _through_the_engine(cfg, params, ids, n, bucket, **kw):
-    be = _engine(cfg, params, **kw)
-    logits, state = be.prefill_then_decode_logits(
-        ids[:n], ids[n:], bucket=bucket, return_state=True)
-    return be, logits, state
-
-
-def _picks_agree(state, want, rows: int) -> bool:
-    """The routers' picks of the scored rows, every sparse layer, are the
-    reference's own (float32 against float32: no tie to break)."""
-    mine = np.sort(np.asarray(state["rows"]["picks"])[:, :, 0], -1)
-    theirs = np.sort(np.asarray(want["ids"])[:, -rows:], -1).swapaxes(0, 1)
-    return bool((mine == theirs).all())
 
 
 @pytest.mark.parametrize("flash", [True, False])
@@ -467,7 +443,7 @@ def test_engine_prefill_and_decode_agree_with_the_reference(tiny, flash):
     with jax.default_matmul_precision("highest"):
         be, got, state = _through_the_engine(cfg, params, ids, 150, 256, **kw)
         sizes = _sizes(cfg)
-        want = reference.forward(params, jnp.asarray(ids), sizes, last=6)
+        want = reference_of(reference, sizes, params, ids, last=6)
         assert _rel(got, want["logits"]) < 1e-5
         assert got.shape == (6, cfg.vocab_size)
         lay = reference.state_as_the_program_lays_it
@@ -504,8 +480,7 @@ def unpadded(tiny):
     _, params = tiny
     ids = np.asarray(_tokens(60, 1, seed=4))[0].tolist()
     with jax.default_matmul_precision("highest"):
-        want = reference.forward(params, jnp.asarray(ids), _sizes(cfg),
-                                 last=5)
+        want = reference_of(reference, _sizes(cfg), params, ids, last=5)
         _, got, state = _through_the_engine(cfg, params, ids, 56, 56)
     return cfg, ids, want, got, state["cache"]
 
@@ -543,8 +518,7 @@ def test_a_bf16_state_fails_the_states_tolerance(tiny):
     ids = np.asarray(_tokens(155, 1, seed=8))[0].tolist()
     with jax.default_matmul_precision("highest"):
         _, got, state = _through_the_engine(cfg, params, ids, 150, 256)
-        want = reference.forward(params, jnp.asarray(ids), _sizes(cfg),
-                                 last=6)
+        want = reference_of(reference, _sizes(cfg), params, ids, last=6)
     err = _rel(np.asarray(state["cache"]["ssm"][:, 0], np.float32),
                reference.state_as_the_program_lays_it(want["ssm"]))
     assert err > 1e-4, err
@@ -607,32 +581,7 @@ def test_engine_refuses_the_entries_at_construction(tiny, kw):
         _engine(cfg, params, **kw)
 
 
-@pytest.fixture()
-def no_persistent_compile_cache():
-    """JAX's persistent compile cache off for one test, as
-    tests/test_ops_compile_tpu.py turns it off. In two whole six-worker runs
-    of the suite (the driver's on PR 53's tree, and PR 54's own with a
-    cache directory a worker) the worker that held the test below died of a
-    segmentation fault inside that cache — once reading this program's
-    entry (``compilation_cache.get_executable_and_time``), once writing it
-    (``put_executable_and_time``: ``executable.serialize()``) — while the
-    test passes alone, beside its file's others under six workers ten times
-    of ten, and after libtpu was loaded in its process. What a long-lived
-    worker holds by then that spoils the XLA:CPU executable's
-    (de)serialization was not found; the program is compiled here and kept
-    out of the cache."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was_on)
-    compilation_cache.reset_cache()
-
-
-def test_engine_generates_and_counts_scan_cells_and_experts(
-        tiny, no_persistent_compile_cache):
+def test_engine_generates_and_counts_scan_cells_and_experts(tiny):
     """``TpuBackend.generate`` with every kernel interpreted: the prefill's
     attention cells counted over the ONE attention layer at 4 query heads a
     KV head, the scan's tokens over 4 Mamba layers beside them in
@@ -641,7 +590,7 @@ def test_engine_generates_and_counts_scan_cells_and_experts(
 
     cfg, params = tiny
     be = _engine(cfg, params, batch_size=2, max_new_tokens=6,
-                 quantize_kv=True)
+                 quantize_kv=True, fresh=True)
     packed = []
     pack = be._pack_group
     be._pack_group = lambda *a: packed.append(pack(*a)) or packed[-1]
@@ -679,14 +628,5 @@ def test_generate_gives_the_same_rows_alone_and_in_a_batch(tiny):
     """A row's tokens do not hang on its neighbours or its pad: neither the
     state nor an expert's rows of one row reach another's (greedy, kernels
     interpreted)."""
-    from vnsum_tpu.core.config import GenerationConfig
-
-    cfg, params = tiny
-    gen = GenerationConfig(temperature=0.0)
-    prompts = ["xin chào " * 22, "một hai ba"]
-    both = _engine(cfg, params, batch_size=2, max_new_tokens=6,
-                   generation=gen).generate(prompts, max_new_tokens=6)
-    alone = [_engine(cfg, params, batch_size=1, max_new_tokens=6,
-                     generation=gen).generate([p], max_new_tokens=6)[0]
-             for p in prompts]
+    both, alone = alone_and_in_a_batch(*tiny)
     assert both == alone

@@ -13,6 +13,12 @@ import numpy as np
 import pytest
 
 from benchmarks import reference_ouro as ref
+from family_harness import (
+    reference as jitted,
+    reference_of,
+    rel as _distance,
+    through_the_engine,
+)
 from vnsum_tpu.backend.engine import TpuBackend, trim_to_eos
 from vnsum_tpu.core.config import GenerationConfig
 from vnsum_tpu.models import MODEL_REGISTRY, llama, quant
@@ -49,11 +55,6 @@ def tiny():
     cfg = llama.tiny_ouro(max_seq_len=512)
     assert (cfg.n_layers, cfg.loop_passes) == (L, T)
     return cfg, _varied(llama.init_params(jax.random.key(1), cfg))
-
-
-def _distance(mine, theirs) -> float:
-    mine, theirs = np.asarray(mine, np.float64), np.asarray(theirs, np.float64)
-    return float(np.linalg.norm(mine - theirs) / np.linalg.norm(theirs))
 
 
 def _ids(n: int, seed: int = 0) -> np.ndarray:
@@ -142,7 +143,7 @@ def test_forward_agrees_with_the_reference(tiny):
     cfg, params = tiny
     n = 40
     toks = jnp.asarray(_ids(n))[None]
-    want = ref.forward(params, toks[0], SIZES)
+    want = jitted(ref, SIZES)(params, toks[0])
     cache = llama.init_kv_cache(cfg, 1, n)
     mask = llama.prefill_attention_mask(jnp.zeros((1,), jnp.int32), n, n)
     with jax.default_matmul_precision("highest"):
@@ -161,22 +162,23 @@ def test_forward_agrees_with_the_reference(tiny):
     assert _distance(want["k"][0], want["k"][(T - 1) * L]) > 0.1
 
 
-def _through_the_engine(cfg, params, n: int, **kw):
+def _logits_and_keys(cfg, params, n: int, **kw):
     """A prompt of ``n`` tokens through the engine's chunked prefill (two
     chunks of 128 in the 256 bucket, left pad 256 - n) and ``NEW`` forced
     decode steps: (logits [NEW + 1, V], the cache's dequantized keys
-    {cache layer: [n + NEW, KV, hd]}), beside the reference's."""
+    {cache layer: [n + NEW, KV, hd]}), beside the reference's. The pad is an
+    argument of the program: one engine and one compile a path, whatever
+    ``n`` (``fresh=True``: an engine of the test's own)."""
     ids = _ids(n + NEW)
-    if kw.get("interpret"):
+    if kw.setdefault("interpret", False):
         kw.setdefault("quantize_kv", False)   # "auto" follows the kernels
     else:
         kw.setdefault("flash", False)         # the dense path, by name
-    be = TpuBackend(model_config=cfg, params=params, tokenizer="byte",
-                    batch_size=2, max_new_tokens=NEW,
-                    prefill_chunk_tokens=CHUNK, **kw)
+        kw.setdefault("quantize_kv", "auto")
     with jax.default_matmul_precision("highest"):
-        got, state = be.prefill_then_decode_logits(
-            ids[:n].tolist(), ids[n:].tolist(), bucket=S, return_state=True)
+        be, got, state = through_the_engine(
+            cfg, params, ids.tolist(), n, S, batch_size=2,
+            max_new_tokens=NEW, prefill_chunk_tokens=CHUNK, **kw)
     cache = state["cache"]
     assert cache["k"].shape[0] == T * L
 
@@ -205,10 +207,9 @@ def test_chunked_prefill_and_decode_agree_with_the_reference(tiny, n, path):
     right inside one chunk and wrong from the second. ``kernels`` runs
     both GQA kernels interpreted at one query head a KV head."""
     cfg, params = tiny
-    be, ids, got, keys = _through_the_engine(
+    be, ids, got, keys = _logits_and_keys(
         cfg, params, n, **({"interpret": True} if path == "kernels" else {}))
-    want = ref.forward(params, jnp.asarray(ids), SIZES, last=NEW + 1,
-                       keep=_SEEN)
+    want = reference_of(ref, SIZES, params, ids, last=NEW + 1, keep=_SEEN)
     for row in range(NEW + 1):
         assert _distance(got[row], want["logits"][row]) < 1e-5
     for layer in _SEEN:
@@ -222,10 +223,9 @@ def test_chunked_prefill_and_decode_agree_with_the_reference(tiny, n, path):
 def test_the_int8_cache_is_within_its_own_tolerance(tiny):
     cfg, params = tiny
     n = S - 28
-    _, ids, got, keys = _through_the_engine(
+    _, ids, got, keys = _logits_and_keys(
         cfg, params, n, interpret=True, quantize_kv=True)
-    want = ref.forward(params, jnp.asarray(ids), SIZES, last=NEW + 1,
-                       keep=_SEEN)
+    want = reference_of(ref, SIZES, params, ids, last=NEW + 1, keep=_SEEN)
     errors = [_distance(got[r], want["logits"][r]) for r in range(NEW + 1)]
     assert 1e-5 < max(errors) < 0.05
     for layer in _SEEN:   # a value in 127 steps of its row's largest
@@ -240,9 +240,9 @@ def test_every_fault_is_another_model(tiny, fault):
     every q . k): only the LATER passes' cached keys show it."""
     cfg, params = tiny
     n = S - 28
-    _, ids, got, keys = _through_the_engine(cfg, params, n)
-    wrong = ref.forward(params, jnp.asarray(ids), SIZES, last=NEW + 1,
-                        keep=(0, (T - 1) * L), faults=(fault,))
+    _, ids, got, keys = _logits_and_keys(cfg, params, n)
+    wrong = jitted(ref, SIZES, last=NEW + 1, keep=(0, (T - 1) * L),
+                   faults=(fault,))(params, jnp.asarray(ids))
     logits = max(_distance(got[r], wrong["logits"][r]) for r in range(NEW + 1))
     first = _distance(keys(0), wrong["k"][0])
     last = (_distance(keys((T - 1) * L), wrong["k"][(T - 1) * L])
@@ -269,8 +269,8 @@ def test_decode_steps_that_read_one_passes_keys_are_another_model(
 
     monkeypatch.setattr(llama, "_cache_attention", last_pass_for_all)
     n = S - 28
-    _, ids, got, _ = _through_the_engine(cfg, params, n)
-    want = ref.forward(params, jnp.asarray(ids), SIZES, last=NEW + 1, keep=())
+    _, ids, got, _ = _logits_and_keys(cfg, params, n, fresh=True)
+    want = reference_of(ref, SIZES, params, ids, last=NEW + 1, keep=())
     errors = [_distance(got[r], want["logits"][r]) for r in range(NEW + 1)]
     assert errors[0] < 1e-5
     assert min(errors[1:]) > 1e-3
@@ -364,12 +364,17 @@ def greedy(tiny):
     a silent single pass."""
     cfg, params = tiny
     tok = _backend(tiny).tok
+    # one length for every forward, the longest sequence's: the reference is
+    # causal, so tokens after a position change nothing at it
+    room = max(len(tok.encode(p, add_bos=True)) for p in PROMPTS) + N_OUT
 
     def continuation(prompt, faults=()):
         ids, out = tok.encode(prompt, add_bos=True), []
+        forward = jitted(ref, SIZES, faults=faults)
         for _ in range(N_OUT):
-            row = np.array(ref.logits(params, jnp.asarray(ids + out), SIZES,
-                                      last=1, faults=faults)[0])
+            seq = ids + out
+            padded = jnp.asarray(seq + [tok.pad_id] * (room - len(seq)))
+            row = np.array(forward(params, padded)["logits"][len(seq) - 1])
             row[[i for i in range(len(row))
                  if i >= 256 and i != tok.eos_id]] = -np.inf
             out.append(int(row.argmax()))
@@ -465,7 +470,8 @@ def test_the_trainer_runs_every_pass(tiny):
     with jax.default_matmul_precision("highest"):
         got = llama.forward_train(params, cfg, toks, remat=True)
     for row in range(2):
-        assert _distance(got[row], ref.logits(params, toks[row], SIZES)) < 1e-5
+        assert _distance(got[row], jitted(ref, SIZES)(
+            params, toks[row])["logits"]) < 1e-5
     n = min(len(jax.devices()), 4)
     mesh = make_mesh({"data": n, "model": 1, "seq": 1}, platform="cpu")
     trainer = Trainer(cfg, mesh, TrainConfig(learning_rate=5e-3, remat=False))

@@ -13,19 +13,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from vnsum_tpu.models import MODEL_REGISTRY, experts, llama
+from family_harness import engine as _engine, tokens as _tokens
+from vnsum_tpu.models import MODEL_REGISTRY, experts, jitted_init, llama
 from vnsum_tpu.models import smallthinker as st
 from vnsum_tpu.models.family import family_of
-
-
-def _tokens(n=60, rows=2, seed=1):
-    return jax.random.randint(jax.random.key(seed), (rows, n), 0, 384)
 
 
 @pytest.fixture(scope="module")
 def tiny():
     cfg = st.tiny_smallthinker()
-    return cfg, st.init_params(jax.random.key(0), cfg)
+    return cfg, jitted_init(st.init_params, cfg, 0)
 
 
 # -- the config ----------------------------------------------------------------
@@ -491,12 +488,9 @@ def test_engine_generates_through_the_kernels_with_a_window(tiny, quantize_kv):
     """``TpuBackend.generate`` with the kernels interpreted: chunked
     prefill, the window a per-layer scalar, counters returned with the
     output, the prefill's cells counted by class with the window."""
-    from vnsum_tpu.backend.engine import TpuBackend
-
     cfg, params = tiny
-    be = TpuBackend(model_config=cfg, tokenizer="byte", params=params,
-                    batch_size=2, max_new_tokens=6, interpret=True,
-                    quantize_kv=quantize_kv, prefill_chunk_tokens=128)
+    be = _engine(cfg, params, batch_size=2, max_new_tokens=6,
+                 quantize_kv=quantize_kv, fresh=True)
     # the engine's logger does not propagate: listen on it directly
     log, heard = logging.getLogger("vnsum.engine"), []
     listener = logging.Handler()

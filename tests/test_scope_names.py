@@ -353,6 +353,10 @@ def test_scope_maps_ignore_a_stale_compile_cache(tmp_path, monkeypatch):
         "jax_compilation_cache_dir",
         "jax_persistent_cache_min_compile_time_secs",
         "jax_persistent_cache_min_entry_size_bytes")}
+    # the variable set too: ``core.jax_cache`` then leaves the directory
+    # alone when the backends below are built, and the checkout's own
+    # cache, which holds this program WITH its scopes, answers nothing
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
     jax.config.update("jax_compilation_cache_dir", str(tmp_path))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

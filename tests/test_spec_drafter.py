@@ -103,6 +103,11 @@ def test_longest_match_beats_shorter_and_later_position_breaks_ties():
 
 
 def test_jnp_and_host_drafters_agree_on_random_cases():
+    import jax
+
+    # one program a trial's shapes, as the verify step runs it (under jit),
+    # where op by op every trial compiles each of its primitives anew
+    propose_jitted = jax.jit(propose_drafts, static_argnums=3)
     rng = np.random.default_rng(7)
     for trial in range(20):
         B = int(rng.integers(1, 5))
@@ -116,7 +121,7 @@ def test_jnp_and_host_drafters_agree_on_random_cases():
         for b in range(B):
             cut = int(rng.integers(0, N))
             tail[b, :cut] = NO_TOKEN
-        dj, nj = propose_drafts(ref, lens, tail, k)
+        dj, nj = propose_jitted(ref, lens, tail, k)
         dh, nh = propose_drafts_host(ref, lens, tail, k)
         np.testing.assert_array_equal(np.asarray(nj), nh, err_msg=f"trial {trial}")
         np.testing.assert_array_equal(np.asarray(dj), dh, err_msg=f"trial {trial}")
