@@ -1044,9 +1044,11 @@ def phase_ling(args) -> dict:
     delta-rule kernels (``ops/kda_scan.py``) compiled at the published
     widths — a row piece of the prefill scan under ragged pads, in place at
     rows of a larger state, and the one-token update — against their XLA
-    forms, each timed inside a jitted loop (the scan also at the cell's
-    call: four rows of a 2,048-token chunk into the map dispatch's stacked
-    state of 10 layers x 24 rows). Then one small generate: the
+    forms, each timed inside a jitted loop (the scan, as the layer calls it
+    — the gate's projection, ``A_log`` and ``dt_bias`` in, no prologue
+    around the kernel —, also at the cell's call: four rows of a
+    2,048-token chunk into the map dispatch's stacked state of 10 layers x
+    24 rows). Then one small generate: the
     first period ``K K K K K M`` at the published widths (five KDA layers,
     the latent attention at 32 heads with no compressed query, both dense
     layers and four sparse layers of which this chip holds 128 of 512
@@ -1071,25 +1073,33 @@ def phase_ling(args) -> dict:
     R, S, H, d = sz["kernel"]
     dtype = jnp.float32 if args.rehearsal else jnp.bfloat16
     chunk = 32 if args.rehearsal else 64
-    ks = jax.random.split(jax.random.key(5), 6)
+    ks = jax.random.split(jax.random.key(5), 8)
     unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
     q = (unit(jax.random.normal(ks[0], (R, S, H, d))) * d ** -0.5)
     k = unit(jax.random.normal(ks[1], (R, S, H, d)))
     v = jax.random.normal(ks[2], (R, S, H, d))
-    g = -5.0 * jax.nn.sigmoid(jax.random.uniform(ks[3], (R, S, H, d),
-                                                 minval=-8.0, maxval=3.0))
+    # a layer's gate: the projection in the inputs' type, A_log and dt_bias
+    # as ``models/ling.py::float_leaves`` draws them, and a projection wide
+    # enough that the log-decays run from the bound of -5 to none
+    a = jax.random.uniform(ks[3], (R, S, H, d), minval=-4.0,
+                           maxval=10.0).astype(dtype)
+    gate = dict(
+        A_log=jnp.log(jax.random.uniform(ks[6], (H,), minval=0.5, maxval=2.0)),
+        dt_bias=jax.random.uniform(ks[7], (H, d), minval=-8.0, maxval=-1.0),
+        lower_bound=-5.0)
+    g = kda_scan.kda_gate(a, **gate)
     beta = jax.nn.sigmoid(jax.random.normal(ks[4], (R, S, H)))
     pads = jnp.asarray([0, 1, S // 3, S - S // 4][:R], jnp.int32)
     real = jnp.arange(S)[None, :] >= pads[:, None]
-    q, k, v = (jnp.where(real[:, :, None, None], a, 0).astype(dtype)
-               for a in (q, k, v))
+    q, k, v = (jnp.where(real[:, :, None, None], x, 0).astype(dtype)
+               for x in (q, k, v))
     beta = jnp.where(real[:, :, None], beta, 0.0)
     rows = jnp.arange(R, dtype=jnp.int32)[::-1] * 2
     state = jnp.zeros((2, 2 * R, H, d, d), jnp.float32)
     interpret = bool(args.rehearsal)
-    o, new = jax.jit(lambda *a: kda_scan.kda_prefill_scan(
-        *a, chunk=chunk, interpret=interpret))(
-        q, k, v, g, beta, state, 1, pads, rows)
+    o, new = jax.jit(lambda *x: kda_scan.kda_prefill_scan(
+        *x, **gate, chunk=chunk, interpret=interpret))(
+        q, k, v, a, beta, state, 1, pads, rows)
     want_o, want = jax.jit(lambda *a: kda_scan.kda_chunked_xla(
         *a, chunk))(q, k, v, g, beta, state[1, rows])
 
@@ -1126,13 +1136,13 @@ def phase_ling(args) -> dict:
     cell_rows = jnp.arange(R, dtype=jnp.int32) + R
     times = {
         "kda_prefill_scan_s": timed(
-            lambda q, k, v, g, b, st: kda_scan.kda_prefill_scan(
-                q, k, v, g, b, st, 1, pads * 0, rows, chunk=chunk,
-                interpret=interpret), q, k, v, g, beta, state),
+            lambda q, k, v, a, b, st: kda_scan.kda_prefill_scan(
+                q, k, v, a, b, st, 1, pads * 0, rows, **gate, chunk=chunk,
+                interpret=interpret), q, k, v, a, beta, state),
         "kda_prefill_scan_cell_s": timed(
-            lambda q, k, v, g, b, st: kda_scan.kda_prefill_scan(
-                q, k, v, g, b, st, 7, pads * 0, cell_rows, chunk=chunk,
-                interpret=interpret), q, k, v, g, beta,
+            lambda q, k, v, a, b, st: kda_scan.kda_prefill_scan(
+                q, k, v, a, b, st, 7, pads * 0, cell_rows, **gate,
+                chunk=chunk, interpret=interpret), q, k, v, a, beta,
             jnp.zeros((10, 24, H, d, d), jnp.float32)),
         "kda_decode_update_s": timed(
             lambda *a: kda_scan.kda_decode_update(

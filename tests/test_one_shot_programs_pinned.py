@@ -99,6 +99,17 @@ then the four products with the state chunk by chunk — and that body is in
 both Ling programs and in no other family's; the other sixteen did not move
 (the file's own `__main__` printed them as they stand).
 
+**PR 59 moved `ling` and `ling-row-pieces` on purpose**: `kda_prefill_scan`
+takes the gate's projection `a` with `A_log`, `dt_bias` and the bound, `k`,
+`v` and `beta` as the layer has them, and its body makes the float32
+log-decay, its running sum inside each chunk (rolled float32 additions),
+`beta k` and `beta v` a head's tile at a time — so the cumulative sum, the
+two products with beta and the prefill's gate are gone from the program
+around the kernel (`models/ling.py::_kda_mixer` makes `g` for a decode step
+and for the XLA path alone) and the kernel has two more operands and one
+more scratch; the other sixteen did not move (printed by the file's own
+`__main__` as they stand).
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
@@ -136,8 +147,8 @@ _PINNED = {
     "granite-h-row-pieces": ("tiny-granite-h", {}, "8ece4d118fa328f3"),
     "lfm2": ("tiny-lfm2", {}, "2ae2707f3d02f044"),
     "lfm2-row-pieces": ("tiny-lfm2", {}, "10157522ffa94d1f"),
-    "ling": ("tiny-ling", {}, "0a57afbbdab8c02c"),
-    "ling-row-pieces": ("tiny-ling", {}, "11a5121278f56bcb"),
+    "ling": ("tiny-ling", {}, "8514e5805097be1c"),
+    "ling-row-pieces": ("tiny-ling", {}, "c3645d3dd83eed7a"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)

@@ -673,21 +673,29 @@ def test_kda_prefill_scan_compiles_at_the_published_widths(one_chip, R, S,
     [128, 128] float32 state in VMEM, the cell's stacked state of 10 layers
     x 24 rows written in place at a piece's own rows: the float32 products
     of the 64 x 64 inverse and the transposed state update are what Mosaic
-    might refuse."""
+    might refuse — and, of what the kernel makes for itself, the gate from
+    a bfloat16 tile and a [2, 128] block of ``exp(A_log)`` over ``dt_bias``,
+    the running sum's rolls along the sublanes, a head's column of the
+    [1,024, 32] float32 block of beta."""
     from vnsum_tpu.ops import kda_scan
 
     head = ((R, S, 32, 128), BF16)
-    args = [head, head, head, ((R, S, 32, 128), F32), ((R, S, 32), F32),
-            ((10, 24, 32, 128, 128), F32), ((R,), I32)]
+    args = [head, head, head, head, ((R, S, 32), F32),
+            ((10, 24, 32, 128, 128), F32), ((R,), I32), ((32,), F32),
+            ((32, 128), F32)]
+    static = dict(lower_bound=-5.0, chunk=64)
     if rows:
         c = _compiled(
-            lambda q, k, v, g, b, st, pads, rows: kda_scan.kda_prefill_scan(
-                q, k, v, g, b, st, 7, pads, rows, chunk=64),
+            lambda q, k, v, a, b, st, pads, A, dt, rows:
+            kda_scan.kda_prefill_scan(
+                q, k, v, a, b, st, 7, pads, rows, A_log=A, dt_bias=dt,
+                **static),
             one_chip, *args, ((R,), I32))
     else:
         c = _compiled(
-            lambda q, k, v, g, b, st, pads: kda_scan.kda_prefill_scan(
-                q, k, v, g, b, st[:, :R], 7, pads, chunk=64),
+            lambda q, k, v, a, b, st, pads, A, dt: kda_scan.kda_prefill_scan(
+                q, k, v, a, b, st[:, :R], 7, pads, A_log=A, dt_bias=dt,
+                **static),
             one_chip, *args)
     assert "tpu_custom_call" in c.as_text()
     assert kda_scan.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024

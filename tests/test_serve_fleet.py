@@ -292,8 +292,14 @@ def test_failover_preserves_trace_identity_on_survivor(tmp_path):
             time.sleep(0.02)
         assert "trace-keep-1" in survivor_ids
         # and the router's own ring joined the same id, so the two halves
-        # stitch into one merged trace
-        router_ids = {t.trace_id for t in state.obs.snapshot()[0]}
+        # stitch into one merged trace (its trace, too, finishes after the
+        # response bytes: the same brief poll)
+        router_ids: set = set()
+        while time.monotonic() < deadline:
+            router_ids = {t.trace_id for t in state.obs.snapshot()[0]}
+            if "trace-keep-1" in router_ids:
+                break
+            time.sleep(0.02)
         assert "trace-keep-1" in router_ids
     finally:
         server.shutdown()

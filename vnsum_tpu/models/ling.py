@@ -365,10 +365,17 @@ def _kda_mixer(u, lp, slot, valid, cache, cfg: LingConfig, scan_kernels: bool,
     ``cache_rows[b]``, read and written there in place. The
     ``jax.named_scope`` names are metadata a device trace is read by (README
     "Device time by layer")."""
+    # imported on use, as llama's kernels: the other families' paths never
+    # load it
+    from ..ops import kda_scan
+
     B, S, _ = u.shape
     H, hd, W = cfg.n_heads, cfg.head_dim, cfg.kda_width
     aq = cfg.w8a8_prefill and S > 1
     f32 = jnp.float32
+    kernels = scan_kernels and cache["kda"].dtype == f32
+    decay = dict(A_log=lp["A_log"], dt_bias=lp["dt_bias"],
+                 lower_bound=cfg.kda_lower_bound)
     with jax.named_scope("kda_in"):
         parts = [_proj("bsd,dhk->bshk", u, lp[n], aq).reshape(B, S, W)
                  for n in ("wq", "wk", "wv")]
@@ -395,18 +402,16 @@ def _kda_mixer(u, lp, slot, valid, cache, cfg: LingConfig, scan_kernels: bool,
         q = (unit(q) * hd ** -0.5).astype(u.dtype)
         k = unit(k).astype(u.dtype)
         v = v.astype(u.dtype)
-        g = cfg.kda_lower_bound * jax.nn.sigmoid(
-            jnp.exp(lp["A_log"].astype(f32))[:, None]
-            * (a.astype(f32) + lp["dt_bias"]))
+        # the prefill kernel takes the gate's projection as it is and makes
+        # the log-decay, its running sum, beta k and beta v itself, a head's
+        # tile at a time in VMEM: no float32 [B, S, W] array lies between
+        # the convolution and the kernel
+        g = None if kernels and S > 1 else kda_scan.kda_gate(a, **decay)
         # a pad's beta would be sigmoid(0): nothing is erased or written there
         beta = jnp.where(valid[..., None], jax.nn.sigmoid(b.astype(f32)), 0.0)
     with jax.named_scope("kda_scan"):
-        # imported on use, as llama's kernels: the other families' paths
-        # never load it
-        from ..ops import kda_scan
-
         state = cache["kda"]
-        if scan_kernels and state.dtype == f32:
+        if kernels:
             if S == 1:
                 o, state = kda_scan.kda_decode_update(
                     q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state,
@@ -416,7 +421,7 @@ def _kda_mixer(u, lp, slot, valid, cache, cfg: LingConfig, scan_kernels: bool,
                 # left padding: a row's pads are its first positions
                 pads = S - jnp.sum(valid, axis=-1, dtype=jnp.int32)
                 o, state = kda_scan.kda_prefill_scan(
-                    q, k, v, g, beta, state, slot, pads, cache_rows,
+                    q, k, v, a, beta, state, slot, pads, cache_rows, **decay,
                     chunk=cfg.kda_chunk_size, interpret=interpret)
         else:
             mine = jax.lax.dynamic_index_in_dim(state, slot, 0, False).astype(

@@ -66,14 +66,27 @@ exponential, a triangular solve).
 
 Grid (rows, heads, token blocks), the blocks in sequence with the head's
 state in VMEM scratch; a head is one lane tile of the ``[B, S, H * 128]``
-arrays the layer has, so nothing is transposed for the kernel. The running
-sum ``G`` is taken outside, in float32 by XLA (a product on the matrix unit
-would round it). A grid step runs its block in two phases. The first reads
-no state: ``A``, ``B``, ``T``, ``T (beta V)``, ``T (beta K * Gamma)``, ``Q *
-Gamma`` and ``K * exp(G_C - G)`` hang on ``q, k, beta, G`` alone, so
-``_GROUP_CHUNKS`` chunks are computed at a time, as the diagonal blocks of
-one operand — a chunk is one more level of the masks the inverse already
-has — and left in VMEM scratch. The inverse's factors are block-diagonal
+arrays the layer has, so nothing is transposed for the kernel — and
+nothing is computed for it either: the kernel takes ``q, k, v`` and the
+gate's projection ``a`` as the projections and the convolution leave them
+(the inputs' type), ``beta`` as ``[B, S, H]`` float32 (a block holds every
+head's column and the kernel picks its own) and a head's ``exp(A_log)`` and
+``dt_bias`` as a ``[2, 128]`` block, and makes in VMEM, a group of chunks at
+a time, what XLA once wrote to HBM as whole ``[B, S, H * 128]`` arrays: the
+log-decay ``g = lower_bound * sigmoid(exp(A_log) * (a + dt_bias))``
+(``kda_gate``) in float32, its running sum ``G`` inside each chunk in
+float32 ADDITIONS (a product with a triangle on the matrix unit would round
+it: ``log2(chunk)`` steps of rows rolled down the sublanes by 1, 2, 4, ...
+and added where the shift stays inside the chunk; the sum nearest the
+float64 one of the three orders tried), and ``beta k``, ``beta v`` as
+float32 products rounded to the inputs' type. A grid step runs its block in
+two phases. The first reads no state: those, and ``A``, ``B``, ``T``, ``T
+(beta V)``, ``T (beta K * Gamma)``, ``Q * Gamma`` and ``K * exp(G_C - G)``
+hang on ``q, k, v, beta, a`` alone, so ``_GROUP_CHUNKS`` chunks are computed
+at a time, as the diagonal blocks of one operand — a chunk is one more
+level of the masks the inverse already has — and left in VMEM scratch
+(with each chunk's last row of ``G`` for the state's decay). The inverse's
+factors are block-diagonal
 (sub-blocks, then pairs of them): as a LEFT factor such a matrix is folded
 to one block's rows, every block in lanes of its own, so a product pushes 16
 or 32 rows through the matrix unit where the operand has 256 — the same
@@ -214,19 +227,22 @@ def kda_chunked_xla(q, k, v, g, beta, state, chunk: int, rows=None):
 # -- the prefill kernel -------------------------------------------------------
 
 
-def _prefill_kernel(lidx_ref, pad_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
-                    sin_ref, o_ref, sout_ref, s_scr, u_scr, w_scr, b_scr,
-                    qg_scr, ke_scr, *, chunk: int, sub: int, block: int):
-    # q/k/kb/vb/o [1, block, d] (one head's lanes), g [1, block, dk] float32
-    # (the running sum inside each chunk); the state [1, 1, 1, dv, dk]; the
-    # scratch between the phases, a row a token of the block: U0 = T (beta
-    # V) [block, dv] float32, and in the inputs' type W = T (beta K * Gamma)
-    # and Q * Gamma and K * exp(G_C - G) [block, dk], B [block, chunk]
-    b, t = pl.program_id(0), pl.program_id(2)
+def _prefill_kernel(lidx_ref, pad_ref, q_ref, k_ref, v_ref, a_ref, beta_ref,
+                    gate_ref, sin_ref, o_ref, sout_ref, s_scr, u_scr, w_scr,
+                    b_scr, qg_scr, ke_scr, gc_scr, *, chunk: int, sub: int,
+                    block: int, seq: int, lower_bound: float):
+    # q/k/v/a/o [1, block, d] (one head's lanes; a the gate's projection),
+    # beta [1, block, H] float32 (a column a head), gate [2, dk] float32
+    # (the head's exp(A_log) on every lane, over its dt_bias); the state
+    # [1, 1, 1, dv, dk]; the scratch between the phases, a row a token of
+    # the block: U0 = T (beta V) [block, dv] float32, and in the inputs'
+    # type W = T (beta K * Gamma) and Q * Gamma and K * exp(G_C - G)
+    # [block, dk], B [block, chunk]; and a row a chunk, G_C [.., dk] float32
+    b, h, t = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     nt = pl.num_programs(2)
     C, per = chunk, chunk // sub
     f32, dtype = jnp.float32, q_ref.dtype
-    dv = vb_ref.shape[-1]
+    dk, dv = q_ref.shape[-1], v_ref.shape[-1]
 
     @pl.when(t == 0)
     def _load():
@@ -248,10 +264,10 @@ def _prefill_kernel(lidx_ref, pad_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
         return t * block + (first + size) * C > pad_ref[b]
 
     def free_of_state(first, size: int):
-        """What chunks [first, first + size) need of q, k, beta and G alone,
-        into scratch. The chunks are the diagonal blocks of ONE operand of
-        ``n = size * C`` rows: a chunk is one more level of ``same``, and
-        each product below is one for all of them."""
+        """What chunks [first, first + size) need of q, k, v, beta and the
+        gate alone, into scratch. The chunks are the diagonal blocks of ONE
+        operand of ``n = size * C`` rows: a chunk is one more level of
+        ``same``, and each product below is one for all of them."""
         n = size * C
         at = pl.ds(pl.multiple_of(first * C, C), n)
         row = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
@@ -280,10 +296,32 @@ def _prefill_kernel(lidx_ref, pad_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
             return jnp.where(
                 keep, stacked([rows] * (n // rows.shape[0])), 0.0)
 
-        G = g_ref[0, at, :]                                      # [n, dk]
+        token = jax.lax.broadcasted_iota(jnp.int32, (n, dk), 0)
+        # the log-decay, in float32: lower_bound sigmoid(exp(A_log) (a +
+        # dt_bias)), and none at the zeros a ragged call ends in (they must
+        # not decay the state)
+        g = lower_bound * jax.nn.sigmoid(gate_ref[0:1, :] * (
+            a_ref[0, at, :].astype(f32) + gate_ref[1:2, :]))
+        if seq % C:
+            g = jnp.where(t * block + first * C + token < seq, g, 0.0)
+        # G, its running sum inside each chunk: float32 additions (a product
+        # with a triangle on the matrix unit would round them), log2(C)
+        # steps of the rows shifted down by 1, 2, 4, ... and added where the
+        # shift stays inside the chunk
+        G, shift = g, 1
+        while shift < C:
+            G = G + jnp.where(token % C >= shift, pltpu.roll(G, shift, 0),
+                              0.0)
+            shift *= 2
         q = q_ref[0, at, :].astype(f32)
         k = k_ref[0, at, :].astype(f32)
-        kb = kb_ref[0, at, :].astype(f32)
+        # the head's column of beta, then beta k and beta v as the layer's
+        # XLA made them: a float32 product rounded to the inputs' type
+        lane = jax.lax.broadcasted_iota(jnp.int32, (n, beta_ref.shape[-1]), 1)
+        beta = jnp.sum(jnp.where(lane == h, beta_ref[0, at, :], 0.0),
+                       axis=1, keepdims=True)                        # [n, 1]
+        kb = (k * beta).astype(dtype).astype(f32)
+        vb = (v_ref[0, at, :].astype(f32) * beta).astype(dtype)
         firsts = [G[i * sub:i * sub + 1] for i in range(size * per)]
         to_ref = jnp.exp(G - of_each(firsts, sub))               # <= 1
         kb_rows = (kb * to_ref).astype(dtype)
@@ -335,7 +373,7 @@ def _prefill_kernel(lidx_ref, pad_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
             width *= 2
         gam = jnp.exp(G)
         UW = jnp.dot(T.astype(dtype), jnp.concatenate(
-            [vb_ref[0, at, :], (kb * gam).astype(dtype)], axis=1),
+            [vb, (kb * gam).astype(dtype)], axis=1),
             preferred_element_type=f32)                     # [n, dv + dk]
         u_scr[at, :] = UW[:, :dv]
         w_scr[at, :] = UW[:, dv:].astype(dtype)
@@ -346,6 +384,8 @@ def _prefill_kernel(lidx_ref, pad_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
         qg_scr[at, :] = (q * gam).astype(dtype)
         lasts = [G[(j + 1) * C - 1:(j + 1) * C] for j in range(size)]
         ke_scr[at, :] = (k * jnp.exp(of_each(lasts, C) - G)).astype(dtype)
+        for j in range(size):
+            gc_scr[pl.ds(first + j, 1), :] = lasts[j]
 
     def one_group(first, size: int):
         pl.when(live(first, size))(lambda: free_of_state(first, size))
@@ -367,7 +407,7 @@ def _prefill_kernel(lidx_ref, pad_ref, q_ref, k_ref, kb_ref, vb_ref, g_ref,
             o = rhs_t(qg_scr[at, :], Sd) + jnp.dot(
                 b_scr[at, :], Ud, preferred_element_type=f32)
             o_ref[0, at, :] = o.astype(o_ref.dtype)
-            last = g_ref[0, pl.ds(c * C + C - 1, 1), :]          # [1, dk]
+            last = gc_scr[pl.ds(c, 1), :]                        # [1, dk]
             s_scr[...] = St * jnp.exp(last) + jax.lax.dot_general(
                 Ud, ke_scr[at, :], (((0,), (0,)), ((), ())),
                 preferred_element_type=f32)
@@ -403,15 +443,37 @@ def _block_tokens(n_chunks: int, chunk: int) -> int:
     return m * chunk
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def kda_prefill_scan(q, k, v, g, beta, state, layer_idx, pad_lens, rows=None,
-                     *, chunk: int, interpret: bool = False):
+def kda_gate(a, A_log, dt_bias, lower_bound: float):
+    """The log-decay ``g`` [..., H, dk] float32 of the gate's projection
+    ``a`` [..., H, dk], a head's ``A_log`` [H] and a channel's ``dt_bias``
+    [H, dk]: ``lower_bound * sigmoid(exp(A_log) * (a + dt_bias))``, in
+    (lower_bound, 0). The XLA form of what ``kda_prefill_scan`` computes in
+    its kernel."""
+    f32 = jnp.float32
+    return lower_bound * jax.nn.sigmoid(
+        jnp.exp(A_log.astype(f32))[:, None] * (a.astype(f32) + dt_bias))
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "lower_bound", "chunk", "interpret"))
+def kda_prefill_scan(q, k, v, a, beta, state, layer_idx, pad_lens, rows=None,
+                     *, A_log, dt_bias, lower_bound: float, chunk: int,
+                     interpret: bool = False):
     """The chunked scan over S tokens from layer ``layer_idx``'s state of
-    the stacked ``state`` [L, B, H, dv, dk] float32; q, k [B, S, H, dk], v
-    [B, S, H, dv], g [B, S, H, dk] float32, beta [B, S, H], ``pad_lens`` [B]
-    the left-pad slots among these S (whole chunks of them are skipped).
-    Returns (o [B, S, H, dv] in v's type, the stacked state with the layer's
-    block overwritten in place). Semantics: ``kda_chunked_xla``.
+    the stacked ``state`` [L, B, H, dv, dk] float32, on what the layer's
+    projections and convolution hand over: q, k [B, S, H, dk], v [B, S, H,
+    dv], beta [B, S, H], and in ``g``'s place the gate's projection ``a``
+    [B, S, H, dk] (in the inputs' type) with ``A_log`` [H], ``dt_bias`` [H,
+    dk] and the bound — ``g = kda_gate(a, A_log, dt_bias, lower_bound)``,
+    which the kernel computes a head's tile at a time in float32, as it
+    does ``g``'s running sum inside each chunk (float32 additions), ``beta
+    k`` and ``beta v`` (float32 products rounded to the inputs' type).
+    Around the kernel the arrays are reshaped and padded to whole chunks:
+    nothing of their size is computed or written outside it. ``pad_lens``
+    [B]: the left-pad slots among these S (whole chunks of them are
+    skipped). Returns (o [B, S, H, dv] in v's type, the stacked state with
+    the layer's block overwritten in place). Semantics: ``kda_chunked_xla``
+    on ``kda_gate``'s ``g``.
 
     ``rows`` [B] int32 (distinct): the rows are a piece of a state that
     holds more of them, and row b continues — and overwrites, in place —
@@ -420,17 +482,15 @@ def kda_prefill_scan(q, k, v, g, beta, state, layer_idx, pad_lens, rows=None,
     dv = v.shape[-1]
     sub = _sub_block(chunk)
     f32 = jnp.float32
-    beta = beta.astype(f32)[..., None]
-    kb = (k.astype(f32) * beta).astype(k.dtype)
-    vb = (v.astype(f32) * beta).astype(v.dtype)
-    q, k, kb, vb, g = _whole_chunks(chunk, q, k, kb, vb, g.astype(f32))
+    q, k, v, a, beta = _whole_chunks(
+        chunk, q.reshape(Bt, S, H * dk), k.reshape(Bt, S, H * dk),
+        v.reshape(Bt, S, H * dv), a.reshape(Bt, S, H * dk), beta.astype(f32))
     Sp = q.shape[1]
-    nc = Sp // chunk
-    block = _block_tokens(nc, chunk)
+    block = _block_tokens(Sp // chunk, chunk)
     nt = Sp // block
-    # the running sum of g inside each chunk, in float32
-    G = jnp.cumsum(g.reshape(Bt, nc, chunk, H * dk), axis=2).reshape(
-        Bt, Sp, H * dk)
+    # exp(A_log) a head on its channels' lanes, over dt_bias: [2, H * dk]
+    gate = jnp.stack([jnp.repeat(jnp.exp(A_log.astype(f32)), dk),
+                      dt_bias.astype(f32).reshape(H * dk)])
     prefetch = 2 if rows is None else 3
 
     def first_live(b, t, pad):
@@ -445,16 +505,20 @@ def kda_prefill_scan(q, k, v, g, beta, state, layer_idx, pad_lens, rows=None,
             lidx[0], rows[0][b] if rows else b, h, 0, 0))
     kernel = functools.partial(
         _prefill_kernel if rows is None else _prefill_kernel_of_rows,
-        chunk=chunk, sub=sub, block=block)
+        chunk=chunk, sub=sub, block=block, seq=S, lower_bound=lower_bound)
     o, state = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=prefetch,
             grid=(Bt, H, nt),
             in_specs=[
-                head_block(dk), head_block(dk), head_block(dk),   # q, k, kb
-                head_block(dv),                                   # beta v
-                head_block(dk),                                   # G
+                head_block(dk), head_block(dk), head_block(dv),   # q, k, v
+                head_block(dk),                                   # a
+                # beta: every head's column of the block (a head's alone
+                # would be a block one lane wide)
+                pl.BlockSpec((1, block, H), lambda b, h, t, lidx, pad, *rows:
+                             (b, first_live(b, t, pad), 0)),
+                pl.BlockSpec((2, dk), lambda b, h, t, *prefetched: (0, h)),
                 state_block,
             ],
             out_specs=[
@@ -469,15 +533,16 @@ def kda_prefill_scan(q, k, v, g, beta, state, layer_idx, pad_lens, rows=None,
                 pltpu.VMEM((block, chunk), q.dtype),  # B
                 pltpu.VMEM((block, dk), q.dtype),   # Q * Gamma
                 pltpu.VMEM((block, dk), q.dtype),   # K * exp(G_C - G)
+                pltpu.VMEM((block // chunk, dk), f32),  # G_C
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((Bt, Sp, H * dv), v.dtype),
             jax.ShapeDtypeStruct(state.shape, f32),
         ],
-        # the call's last operand, after the prefetched scalars and the five
+        # the call's last operand, after the prefetched scalars and the six
         # blocks before it, is the state
-        input_output_aliases={prefetch + 5: 1},
+        input_output_aliases={prefetch + 6: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
@@ -489,8 +554,7 @@ def kda_prefill_scan(q, k, v, g, beta, state, layer_idx, pad_lens, rows=None,
         jnp.asarray(layer_idx, jnp.int32).reshape(1),
         pad_lens.astype(jnp.int32),
         *(() if rows is None else (rows.astype(jnp.int32),)),
-        q.reshape(Bt, Sp, H * dk), k.reshape(Bt, Sp, H * dk),
-        kb.reshape(Bt, Sp, H * dk), vb.reshape(Bt, Sp, H * dv), G, state,
+        q, k, v, a, beta, gate, state,
     )
     return o[:, :S].reshape(Bt, S, H, dv), state
 
