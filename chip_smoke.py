@@ -20,7 +20,7 @@ random weights made from a seed, through the entry points a user calls:
              SIGTERM drain
     mesh     the generate step under TpuBackend(mesh=) at model=4 and
              data=4 — only with >= 4 devices, otherwise reported as skipped
-    experts, moe, laguna, nemotron_h, ouro, lfm2, ling
+    experts, moe, laguna, nemotron_h, ouro, lfm2, ling, brumby
              one family each on the one-shot path at its published widths
              and a few layers, against its plain reference
              (``--phases device,<family>``; each phase's docstring)
@@ -53,13 +53,13 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe",
-          "laguna", "nemotron_h", "ouro", "lfm2", "ling")
+          "laguna", "nemotron_h", "ouro", "lfm2", "ling", "brumby")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
     "experts": 420, "moe": 420, "laguna": 480, "nemotron_h": 480,
-    "ouro": 360, "lfm2": 420, "ling": 480,
+    "ouro": 360, "lfm2": 420, "ling": 480, "brumby": 480,
 }
 
 
@@ -105,6 +105,10 @@ def sizes(rehearsal: bool) -> dict:
             ling_layers=6, ling_held=8, ling_seq=328, ling_batch=4,
             ling_max_new=8, ling_prefill_chunk=128, ling_prompt_bytes=250,
             ling_kernel=(2, 96, 4, 16), ling_kernel_tolerance=1e-4,
+            brumby_layers=3, brumby_seq=328, brumby_batch=4,
+            brumby_max_new=8, brumby_prefill_chunk=128,
+            brumby_prompt_bytes=250, brumby_kernel=(2, 40, 4, 2, 16),
+            brumby_kernel_tolerance=1e-4,
             ouro_layers=2, ouro_seq=328, ouro_batch=4, ouro_max_new=8,
             ouro_prefill_chunk=128, ouro_prompt_bytes=250,
             ouro_parity=(150, 256, 4), ouro_tolerance=0.05,
@@ -164,6 +168,12 @@ def sizes(rehearsal: bool) -> dict:
         ling_layers=6, ling_held=128, ling_seq=2304, ling_batch=4,
         ling_max_new=32, ling_prefill_chunk=1024, ling_prompt_bytes=1_900,
         ling_kernel=(4, 2048, 32, 128), ling_kernel_tolerance=3e-2,
+        # brumby: two power-retention layers at the published widths; the
+        # two retention kernels at a row piece's shape (rows, tokens, query
+        # heads, KV heads, head width) in bfloat16
+        brumby_layers=2, brumby_seq=2304, brumby_batch=4, brumby_max_new=32,
+        brumby_prefill_chunk=1024, brumby_prompt_bytes=1_900,
+        brumby_kernel=(2, 2048, 40, 8, 128), brumby_kernel_tolerance=3e-2,
         # ouro: two layers at the published widths run FOUR times (8 cache
         # layers), 16 / 16 heads; prompts of two 2,048-token chunks in the
         # S=4096 bucket; the cell's own limits
@@ -1189,6 +1199,161 @@ def phase_ling(args) -> dict:
     return rep
 
 
+def phase_brumby(args) -> dict:
+    """The Brumby family on the one-shot path. First its two power-retention
+    kernels (``ops/power_retention.py``) compiled at the published widths —
+    a row piece of the chunked scan under ragged pads, in place at rows of a
+    larger state, and the one-token update — against their XLA forms, each
+    timed inside a jitted loop (the scan also at the cell's call: one row of
+    a 2,048-token chunk into the map dispatch's stacked state of 10 layers x
+    12 rows; the update at the cell's 12 rows). Then one small generate: two
+    layers at the published widths, int8 and W8A8, twice through
+    ``TpuBackend.generate``, with its counters. The logits, the state and
+    the normaliser against ``benchmarks/reference_brumby.py`` are the cell's
+    own set-up (``--workload
+    brumby-14b-l10-int8.offline-mapreduce-8k-retention``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models import jitted_init
+    from vnsum_tpu.models.brumby import brumby_14b, tiny_brumby
+    from vnsum_tpu.models.quant import init_params_quantized
+    from vnsum_tpu.ops import power_retention as pr
+
+    sz = {k[7:]: v for k, v in sizes(args.rehearsal).items()
+          if k.startswith("brumby_")}
+    c = Checks()
+    R, S, H, KV, d = sz["kernel"]
+    dtype = jnp.float32 if args.rehearsal else jnp.bfloat16
+    chunk = 8 if args.rehearsal else 256
+    how = dict(scale=d ** -0.5, eps=1e-6)
+    interpret = bool(args.rehearsal)
+    ks = jax.random.split(jax.random.key(5), 5)
+    # q and k as a QK-norm leaves them (unit mean square), a layer's gates
+    # from a head that forgets in ~8 tokens to one that keeps ~8,000
+    q = jax.random.normal(ks[0], (R, S, H, d))
+    k = jax.random.normal(ks[1], (R, S, KV, d))
+    v = jax.random.normal(ks[2], (R, S, KV, d))
+    gamma = jax.nn.log_sigmoid(
+        jnp.linspace(2.0, 9.0, KV) + jax.random.normal(ks[3], (R, S, KV)))
+    pads = jnp.asarray([1, S - S // 4, 0, S // 3][:R], jnp.int32)
+    real = jnp.arange(S)[None, :] >= pads[:, None]
+    q = q.astype(dtype)
+    k, v = (jnp.where(real[:, :, None, None], x, 0).astype(dtype)
+            for x in (k, v))
+    T = pr.n_tiles(d)
+    rows = jnp.arange(R, dtype=jnp.int32)[::-1] * 2
+    state = jnp.zeros((2, 2 * R, KV, T, d, d), jnp.float32)
+    norm = jnp.zeros((2, 2 * R, KV, d, d), jnp.float32)
+    o, new, new_z = jax.jit(lambda *x: pr.retention_prefill_scan(
+        *x, chunk=chunk, interpret=interpret, **how))(
+        q, k, v, gamma, state, norm, 1, pads, rows)
+    want_o, want, want_z = jax.jit(lambda *a: pr.retention_chunked_xla(
+        *a, chunk, **how))(q, k, v, gamma, state[1, rows], norm[1, rows])
+
+    def rel(a, b):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    errs = {"prefill_out": rel(o, want_o),
+            "prefill_state": rel(new[1, rows], want),
+            "prefill_normaliser": rel(new_z[1, rows], want_z)}
+    c.check("retention_prefill_scan is its XLA form (a row piece under "
+            "ragged pads, in place)",
+            max(errs.values()) <= sz["kernel_tolerance"]
+            and not np.asarray(new[0]).any()
+            and not np.asarray(new[1, rows + 1]).any()
+            and not np.asarray(o)[1, :int(pads[1])].any(), errs)
+    step = (q[:, -1], k[:, -1], v[:, -1], gamma[:, -1])
+    o1, new1, new_z1 = jax.jit(lambda *a: pr.retention_decode_update(
+        *a, interpret=interpret, **how))(*step, new[:, rows],
+                                         new_z[:, rows], 1)
+    want_o1, want1, want_z1 = jax.jit(lambda *a: pr.retention_step_xla(
+        *a, **how))(*step, new[1, rows], new_z[1, rows])
+    errs.update(decode_out=rel(o1, want_o1), decode_state=rel(new1[1], want1),
+                decode_normaliser=rel(new_z1[1], want_z1))
+    # the write is float32 on the vector unit; the read's products run in
+    # the inputs' type, as the scan's
+    c.check("retention_decode_update is the one-token step",
+            max(errs["decode_state"], errs["decode_normaliser"]) <= 1e-5
+            and errs["decode_out"] <= sz["kernel_tolerance"], errs)
+
+    def timed(fn, *a, n=8):
+        """Seconds a call of ``n`` chained in ONE jitted program (a lone call
+        costs the host as much as a small kernel); the last two arguments
+        are the state and the normaliser (their shapes: zeros are made
+        here and donated), handed from call to call. The
+        calls are written out, not a ``fori_loop``: carried through a loop
+        the stacked state cost every call one layer's copy beside the
+        kernel (3.05 ms a 12-row update where the cell's trace reads 1.5)."""
+        def chain(*a):
+            carry = a[-2:]
+            for _ in range(n):
+                carry = fn(*a[:-2], *carry)[1:]
+            return carry
+
+        chained = jax.jit(chain, donate_argnums=(len(a) - 2, len(a) - 1))
+        fresh = lambda: tuple(jnp.zeros(x.shape, x.dtype)  # noqa: E731
+                              for x in a[-2:])
+        jax.block_until_ready(chained(*a[:-2], *fresh()))
+        carry = fresh()
+        t0 = time.time()
+        jax.block_until_ready(chained(*a[:-2], *carry))
+        return (time.time() - t0) / n
+
+    # as the cell calls it: one row of the map dispatch's stacked state (10
+    # layers x 12 rows), every token live; the update at all 12 rows
+    B, L = (4, 3) if args.rehearsal else (12, 10)
+    big = (jax.ShapeDtypeStruct((L, B, KV, T, d, d), jnp.float32),
+           jax.ShapeDtypeStruct((L, B, KV, d, d), jnp.float32))
+    wide = lambda x: jnp.concatenate([x] * (B // R), 0)  # noqa: E731
+    times = {
+        "retention_prefill_scan_s": timed(
+            lambda q, k, v, g, st, z: pr.retention_prefill_scan(
+                q, k, v, g, st, z, 1, pads * 0, rows, chunk=chunk,
+                interpret=interpret, **how), q, k, v, gamma, state, norm),
+        "retention_prefill_scan_cell_s": timed(
+            lambda q, k, v, g, st, z: pr.retention_prefill_scan(
+                q, k, v, g, st, z, L - 1, pads[:1] * 0,
+                jnp.asarray([B - 1], jnp.int32), chunk=chunk,
+                interpret=interpret, **how),
+            q[:1], k[:1], v[:1], gamma[:1], *big),
+        "retention_decode_update_cell_s": timed(
+            lambda q, k, v, g, st, z: pr.retention_decode_update(
+                q, k, v, g, st, z, L - 1, interpret=interpret, **how),
+            *(wide(x) for x in step), *big)}
+
+    make = tiny_brumby if args.rehearsal else brumby_14b
+    cfg = make(n_layers=sz["layers"], max_seq_len=sz["seq"])
+    backend = TpuBackend(
+        model_config=cfg, tokenizer="byte",
+        params=jitted_init(init_params_quantized, cfg, 3),
+        batch_size=sz["batch"], max_new_tokens=sz["max_new"], quantize=True,
+        quantize_act=True, quantize_kv=False,
+        prefill_chunk_tokens=sz["prefill_chunk"],
+        generation=GenerationConfig(temperature=1.0, seed=3),
+        interpret=args.rehearsal)
+    prompts = [_vn_text(sz["prompt_bytes"] - 300 * i // 4, f"g{i}")
+               for i in range(sz["batch"])]
+    _outs, first_s, second_s = _generate_twice(backend, prompts, c)
+    blocks = backend.stats.prefill_blocks
+    c.check("the scan's tokens are counted",
+            0 < blocks.get("retention_tokens_real", 0)
+            <= blocks.get("retention_tokens_computed", 0), blocks)
+    state_leaves = backend.describe()["state_bytes_per_row"]
+    c.check("the program carries a state and no keys and values",
+            sorted(state_leaves) == ["norm", "ret"], state_leaves)
+    rep = c.report()
+    rep.update(kernel_errors=errs, kernel_seconds=times,
+               first_call_s=round(first_s, 2),
+               second_call_s=round(second_s, 2), prefill_blocks=blocks,
+               engine=backend.describe())
+    return rep
+
+
 def phase_ouro(args) -> dict:
     """The dense family LOOPED over its weights (``LlamaConfig.loop_passes``,
     Ouro-2.6B) on the one-shot path: two layers at the published widths run
@@ -1294,7 +1459,8 @@ def _child(args) -> int:
                     "nemotron_h": phase_nemotron_h,
                     "ouro": phase_ouro,
                     "lfm2": phase_lfm2,
-                    "ling": phase_ling}[phase](args))
+                    "ling": phase_ling,
+                    "brumby": phase_brumby}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

@@ -110,11 +110,20 @@ and for the XLA path alone) and the kernel has two more operands and one
 more scratch; the other sixteen did not move (printed by the file's own
 `__main__` as they stand).
 
+**PR 60 moved none of the eighteen and added `brumby` and
+`brumby-row-pieces`**: the ninth family (`models/brumby.py`: the two
+power-retention kernels of `ops/power_retention.py`, no attention function
+in either phase, a state and a normaliser for a cache) whole, and with a row
+piece set to its 128-token chunk: the scan kernel's `rows` prefetch is in
+it. The engine builds no attention function where a family has no attention
+layer (`TpuBackend._attends`), which is no line of any other family's
+program.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
 (`assert_pinned`). `python tests/test_one_shot_programs_pinned.py` (from the
-repo's root, `PYTHONPATH=.`) traces all eighteen, prints both tables as they
+repo's root, `PYTHONPATH=.`) traces all twenty, prints both tables as they
 would have to read and rewrites that file — the one place that regenerates
 them."""
 from __future__ import annotations
@@ -149,11 +158,14 @@ _PINNED = {
     "lfm2-row-pieces": ("tiny-lfm2", {}, "10157522ffa94d1f"),
     "ling": ("tiny-ling", {}, "8514e5805097be1c"),
     "ling-row-pieces": ("tiny-ling", {}, "c3645d3dd83eed7a"),
+    "brumby": ("tiny-brumby", {}, "9f6665e8269e1743"),
+    "brumby-row-pieces": ("tiny-brumby", {}, "f1de891b2a247bef"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)
 _PIECE_TOKENS = {"llama-row-pieces": 128, "granite-h-row-pieces": 128,
-                 "lfm2-row-pieces": 128, "ling-row-pieces": 128}
+                 "lfm2-row-pieces": 128, "ling-row-pieces": 128,
+                 "brumby-row-pieces": 128}
 
 # the slot loop's programs of the tiny llama family, "kind-rows" -> the same
 # hash: a join of 1 and of 2 rows, the segment of 4 slots, the adopt of a
@@ -297,7 +309,7 @@ def test_a_slot_program_traces_to_the_pinned_jaxpr(program):
 
 
 def regenerate() -> None:
-    """Trace all eighteen programs, print the two tables' hashes as they are now
+    """Trace all twenty programs, print the two tables' hashes as they are now
     and rewrite the line ladders."""
     texts = [("_PINNED", family, want,
               one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw),

@@ -6,7 +6,8 @@ expert form at experts stored 1,920 wide for 1,856, the GQA kernels at 16
 query heads on 2 KV heads), and the LFM2-MoE family's (the GQA kernels at
 32/8 heads of 64, two KV heads a lane tile of the cache as ``init_kv_cache``
 lays it out, over 6 layers with a row piece of four rows, the expert
-product at 32 experts of 2048 x 1792), compiled
+product at 32 experts of 2048 x 1792), and the Brumby family's two
+power-retention kernels and its whole (12, 8192, 256) program, compiled
 for the chip at the published widths, with no chip: the TPU's compiler is installed here and
 compiles for a described v5e. Interpret mode cannot show what this does: a
 slice not aligned to the tiling, too much VMEM, an int8 product Mosaic
@@ -750,3 +751,119 @@ def test_absorbed_decode_kernel_compiles_at_32_heads_over_two_layers(
         one_chip, ((B, H, 512), BF16), ((B, H, 64), BF16),
         ((2, B, 8448, 576), BF16), ((B,), I32))
     assert "tpu_custom_call" in c.as_text()
+
+
+# -- the Brumby family (ops/power_retention.py; no attention kernel) ----------
+
+
+@pytest.mark.parametrize("R,S,rows", [
+    (1, 2048, True),    # the map dispatch's row piece: one row of a chunk
+    (1, 2048, False),   # parity's chunk: one row, the whole state
+    (2, 1000, True),    # a ragged call: not a whole number of chunks
+])
+def test_retention_prefill_scan_compiles_at_the_published_widths(one_chip, R,
+                                                                 S, rows):
+    """A KV head's five query heads as five lane tiles of the [rows, tokens,
+    40 x 128] array stacked in VMEM, retention chunks of 256, the KV head's
+    [65, 128, 128] float32 state and [128, 128] normaliser in VMEM scratch,
+    the cell's stacked state of 10 layers x 12 rows written in place at a
+    piece's own rows: a lane rotation by a traced amount, a scratch tile
+    taken by a traced index, seven tiles' phi side by side in one product
+    and the transposed decayed values are what Mosaic might refuse."""
+    from vnsum_tpu.ops import power_retention as pr
+
+    args = [((R, S, 40, 128), BF16), ((R, S, 8, 128), BF16),
+            ((R, S, 8, 128), BF16), ((R, S, 8), F32),
+            ((10, 12, 8, 65, 128, 128), F32), ((10, 12, 8, 128, 128), F32),
+            ((R,), I32)]
+    how = dict(chunk=256, scale=128 ** -0.5, eps=1e-6)
+    if rows:
+        c = _compiled(
+            lambda q, k, v, g, st, z, pads, rows: pr.retention_prefill_scan(
+                q, k, v, g, st, z, 7, pads, rows, **how),
+            one_chip, *args, ((R,), I32))
+    else:
+        c = _compiled(
+            lambda q, k, v, g, st, z, pads: pr.retention_prefill_scan(
+                q, k, v, g, st[:, :R], z[:, :R], 7, pads, **how),
+            one_chip, *args)
+    assert "tpu_custom_call" in c.as_text()
+    assert pr.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024
+    # nothing of phi's size lies around the kernel (phi(k) alone would be
+    # 133 MB a thousand tokens and row): its temporaries are the running
+    # gate sum by columns and by rows, a ragged call's padded copies of q, k
+    # and v and, without rows, the state's slice
+    if rows:
+        assert c.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("B", [12, 4, 1])
+def test_retention_decode_update_compiles_in_place_at_the_cells_shapes(
+        one_chip, B):
+    """A row's and KV head's [65, 128, 128] state of one layer a grid step
+    (4.3 MB in, 4.3 MB out, double-buffered), the five query heads' read of
+    a tile one [8, 128] x [128, 128]^T product."""
+    from vnsum_tpu.ops import power_retention as pr
+
+    c = _compiled(
+        lambda q, k, v, g, st, z: pr.retention_decode_update(
+            q, k, v, g, st, z, 3, scale=128 ** -0.5, eps=1e-6),
+        one_chip, ((B, 40, 128), BF16), ((B, 8, 128), BF16),
+        ((B, 8, 128), BF16), ((B, 8), F32),
+        ((10, B, 8, 65, 128, 128), F32), ((10, B, 8, 128, 128), F32))
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 8 * 1024 * 1024
+
+
+def test_the_cells_map_program_holds_no_phi_and_no_keys_and_values(one_chip):
+    """The (12, 8192, 256) one-shot program of the cell's configuration,
+    compiled for the chip from shapes alone (the weights a tree of shapes):
+    its arguments are the int8 weights, its temporaries the float32 state
+    of 10 layers x 12 rows and a 2,048-token row piece's activations — no
+    array of ``phi(k)``'s or ``phi(q)``'s size (13 GB and 66 GB a layer at
+    this dispatch) and no keys and values. The two numbers stand in the
+    configuration's ``engine_notes``."""
+    import functools
+    import json
+    import types
+    from pathlib import Path
+
+    from benchmarks import engine_setup, engine_setup_brumby
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models.quant import init_params_quantized
+
+    root = Path(__file__).resolve().parents[1]
+    config = json.loads((root / "benchmarks" / "configs"
+                         / "brumby-14b-l10-int8.json").read_text())
+    cfg = engine_setup_brumby.model_config(config, False)
+    params = jax.eval_shape(
+        functools.partial(init_params_quantized, cfg=cfg), jax.random.key(0))
+    B, S, new = config["engine"]["batch"], 8192, 256
+
+    class OnTheChip(TpuBackend):
+        def _devices(self):
+            return [types.SimpleNamespace(platform="tpu")]
+
+    be = OnTheChip(model_config=cfg, tokenizer="byte", params=params,
+                   batch_size=B, max_new_tokens=new,
+                   generation=GenerationConfig(temperature=1.0, seed=1),
+                   **engine_setup.backend_kwargs(config, False))
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    c = be._make_fn(B, S, new, be.gen_cfg).lower(
+        jax.tree.map(lambda x: spec(x.shape, x.dtype), params),
+        spec((B, S), I32), spec((B,), I32), spec((), jnp.uint32)).compile()
+    m = c.memory_analysis()
+    state = 12 * config["bytes"]["state_and_normaliser_a_row_and_layer"] * 10
+    assert config["bytes"]["weights"] <= m.argument_size_in_bytes \
+        < config["bytes"]["weights"] + 4 * 1024 * 1024
+    # the state, and under a GiB of a row piece's activations beside it
+    assert state < m.temp_size_in_bytes < state + 1024 ** 3
+    for n in (m.temp_size_in_bytes, m.argument_size_in_bytes):
+        assert f"{n:,}" in config["engine_notes"], n
+    text = c.as_text()
+    assert "retention_prefill_scan" in text
+    assert "retention_decode_update" in text
+    assert "flash" not in text and "decode_attention" not in text
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 0.62 * 16 * 1024 ** 3

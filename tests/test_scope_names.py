@@ -253,6 +253,33 @@ def test_a_delta_rule_familys_program_carries_its_component_scopes():
         named - set(components)
 
 
+def test_a_retention_familys_program_carries_its_component_scopes():
+    """Every scope ``models/brumby.py`` adds, under the phase it belongs to:
+    ``ret_in`` (the four projections, QK-norm, rotary, the gate) and
+    ``ret_out`` under both, ``ret_scan`` under the prefill and
+    ``ret_update`` under the decode loop, beside the skeleton's names for
+    the feed-forward and the head; NO attention scope in either phase; and
+    every instruction the map places lies under a phase and a component."""
+    from vnsum_tpu.models.brumby import init_params, tiny_brumby
+
+    cfg = tiny_brumby(max_seq_len=128)
+    b = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
+                   max_new_tokens=NEW, seed=1, flash=False,
+                   params=init_params(jax.random.key(0), cfg))
+    b._get_fn(B, S, NEW, b.gen_cfg)
+    (m,) = b.scope_maps()
+    assert m["module"] == "jit_generate"
+    got = paths(m["scopes"])
+    both = ("ret_in", "ret_out", "mlp", "embed", "lm_head", "sample")
+    assert {f"prefill/{c}" for c in both + ("ret_scan",)} <= got
+    assert {f"decode/{c}" for c in both + ("ret_update",)} <= got
+    assert "prefill/ret_update" not in got and "decode/ret_scan" not in got
+    assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
+    named = {p.split("/")[1] for p in got if p.count("/") >= 1}
+    assert named <= set(both) | {"ret_scan", "ret_update", "emit"}, named
+    assert not named & {"qkv", "kv_write", "attn", "attn_out"}
+
+
 def test_a_looped_stacks_program_carries_the_norm_between_passes():
     """A stack looped over its weights (``LlamaConfig.loop_passes``) adds
     ONE scope to the dense family's, under both phases: ``loop_norm``, the

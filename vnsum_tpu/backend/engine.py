@@ -411,6 +411,14 @@ class TpuBackend:
             self.family.refuse("mesh", self.cfg)
         if cache_blocks:
             self.family.refuse("prefix cache", self.cfg)
+        # whether any layer keeps keys and values: where none does the
+        # program carries the family's state alone and no attention
+        # function over a cache is ever built, for either phase
+        self._attends = self.family.attention_layers(self.cfg) > 0
+        if cache_blocks and not self._attends:
+            raise ValueError(
+                "a prefix cache holds keys and values by block, and no "
+                "layer of this configuration keeps any")
         if quantize_act:
             # W8A8 prefill (models.llama._proj): double-rate s8xs8 MXU
             # dots on multi-token forwards. LOSSY (per-token activation
@@ -832,6 +840,7 @@ class TpuBackend:
         mesh = self.mesh
         interpret = self.interpret
         family, forward_kw = self.family, self._forward_kw
+        attends = self._attends
         layer_window = self._layer_window_fn()
         prefill_part = self._make_prefill_part(B, S, max_new, gen, resume_from)
 
@@ -857,7 +866,7 @@ class TpuBackend:
                 pos = (S - pad_lens) + t
                 mask_t = decode_attention_mask(pad_lens, S + t, C)
                 stacked_fn = None
-                if use_flash_decode:
+                if use_flash_decode and attends:
                     stacked_fn = family.decode_attention(
                         cfg, mesh, interpret, pad_lens, S, t, layer_window)
                 logits, cache = family.forward(
@@ -1050,7 +1059,7 @@ class TpuBackend:
         chunked prefill passes each chunk's start), with a row piece's
         ``cache_rows`` where the forward runs one. None when the dense
         path is in effect."""
-        if not use_flash:
+        if not (use_flash and self._attends):
             return None
         return self.family.prefill_attention(
             self.cfg, self.mesh, self.interpret, pad_lens, layer_window,
@@ -2381,7 +2390,7 @@ class TpuBackend:
                 with jax.named_scope("decode"):
                     for t in range(steps):
                         stacked_fn = None
-                        if use_flash_decode:
+                        if use_flash_decode and self._attends:
                             stacked_fn = family.decode_attention(
                                 cfg, self.mesh, self.interpret, pad_lens,
                                 S, t, layer_window)

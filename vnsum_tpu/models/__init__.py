@@ -109,6 +109,18 @@ def _tiny_ling(**kw):
     return tiny_ling(**kw)
 
 
+def _brumby_14b(**kw):
+    from .brumby import brumby_14b
+
+    return brumby_14b(**kw)
+
+
+def _tiny_brumby(**kw):
+    from .brumby import tiny_brumby
+
+    return tiny_brumby(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -161,6 +173,12 @@ MODEL_REGISTRY = {
     # 512 experts top-8 by group-limited sigmoid score + bias and a shared one
     "ling-3.0-flash": _ling_3_0_flash,
     "tiny-ling": _tiny_ling,
+    # a ninth (models/brumby.py): the dense QK-normed rotary skeleton with
+    # every attention layer a power-retention layer of degree 2 - a float32
+    # matrix state a KV head, expanded from q and k inside the kernels of
+    # ops/power_retention.py - and no keys and values at all
+    "brumby-14b": _brumby_14b,
+    "tiny-brumby": _tiny_brumby,
 }
 
 __all__ = [
