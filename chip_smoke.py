@@ -1204,9 +1204,18 @@ def phase_brumby(args) -> dict:
     kernels (``ops/power_retention.py``) compiled at the published widths —
     a row piece of the chunked scan under ragged pads, in place at rows of a
     larger state, and the one-token update — against their XLA forms, each
-    timed inside a jitted loop (the scan also at the cell's call: one row of
-    a 2,048-token chunk into the map dispatch's stacked state of 10 layers x
-    12 rows; the update at the cell's 12 rows). Then one small generate: two
+    timed in one jitted program of eight calls (the scan also at the cell's
+    call: one row of a 2,048-token chunk into the map dispatch's stacked
+    state of 10 layers x 12 rows). The update ALONE at the cell's 12 and 4
+    rows (``retention_decode_update_alone``): ms a call and GB/s of the
+    bytes the kernel moves, by the host's clock, the state donated; that no
+    copy stands beside it was checked twice — the compiled program's
+    temporaries are under one layer's block (a check of this phase), and
+    the device trace of the same eight calls shows the eight kernel events,
+    the operands' pads, casts and transposes (a few us a call) and nothing
+    of the state's size: the host's clock reads ~0.13 ms a call over the
+    kernel's own events (1.36 for 1.22 ms at 12 rows; ``PERF.md`` section
+    5, PR 61). Then one small generate: two
     layers at the published widths, int8 and W8A8, twice through
     ``TpuBackend.generate``, with its counters. The logits, the state and
     the normaliser against ``benchmarks/reference_brumby.py`` are the cell's
@@ -1282,49 +1291,73 @@ def phase_brumby(args) -> dict:
             and errs["decode_out"] <= sz["kernel_tolerance"], errs)
 
     def timed(fn, *a, n=8):
-        """Seconds a call of ``n`` chained in ONE jitted program (a lone call
-        costs the host as much as a small kernel); the last two arguments
-        are the state and the normaliser (their shapes: zeros are made
-        here and donated), handed from call to call. The
-        calls are written out, not a ``fori_loop``: carried through a loop
-        the stacked state cost every call one layer's copy beside the
-        kernel (3.05 ms a 12-row update where the cell's trace reads 1.5)."""
+        """(seconds a call, the program's temporary bytes) of ``n`` calls
+        chained in ONE jitted program (a lone call costs the host as much
+        as a small kernel); the last two arguments are the state and the
+        normaliser (their shapes: zeros are made here and donated), handed
+        from call to call. The calls are written out, not a ``fori_loop``:
+        carried through a loop the stacked state cost every call one
+        layer's copy beside the kernel (3.05 ms a 12-row update where the
+        cell's trace reads 1.3). The zeros are on the device BEFORE the
+        clock starts: filling 4.15 GB takes ~5 ms, which the clock used to
+        count (1.92 ms a call for the trace's 1.27)."""
         def chain(*a):
             carry = a[-2:]
             for _ in range(n):
                 carry = fn(*a[:-2], *carry)[1:]
             return carry
 
-        chained = jax.jit(chain, donate_argnums=(len(a) - 2, len(a) - 1))
-        fresh = lambda: tuple(jnp.zeros(x.shape, x.dtype)  # noqa: E731
-                              for x in a[-2:])
+        chained = jax.jit(
+            chain, donate_argnums=(len(a) - 2, len(a) - 1)).lower(*a).compile()
+        fresh = lambda: jax.block_until_ready(tuple(  # noqa: E731
+            jnp.zeros(x.shape, x.dtype) for x in a[-2:]))
         jax.block_until_ready(chained(*a[:-2], *fresh()))
         carry = fresh()
         t0 = time.time()
         jax.block_until_ready(chained(*a[:-2], *carry))
-        return (time.time() - t0) / n
+        return ((time.time() - t0) / n,
+                chained.memory_analysis().temp_size_in_bytes)
 
     # as the cell calls it: one row of the map dispatch's stacked state (10
     # layers x 12 rows), every token live; the update at all 12 rows
     B, L = (4, 3) if args.rehearsal else (12, 10)
     big = (jax.ShapeDtypeStruct((L, B, KV, T, d, d), jnp.float32),
            jax.ShapeDtypeStruct((L, B, KV, d, d), jnp.float32))
-    wide = lambda x: jnp.concatenate([x] * (B // R), 0)  # noqa: E731
     times = {
         "retention_prefill_scan_s": timed(
             lambda q, k, v, g, st, z: pr.retention_prefill_scan(
                 q, k, v, g, st, z, 1, pads * 0, rows, chunk=chunk,
-                interpret=interpret, **how), q, k, v, gamma, state, norm),
+                interpret=interpret, **how), q, k, v, gamma, state, norm)[0],
         "retention_prefill_scan_cell_s": timed(
             lambda q, k, v, g, st, z: pr.retention_prefill_scan(
                 q, k, v, g, st, z, L - 1, pads[:1] * 0,
                 jnp.asarray([B - 1], jnp.int32), chunk=chunk,
                 interpret=interpret, **how),
-            q[:1], k[:1], v[:1], gamma[:1], *big),
-        "retention_decode_update_cell_s": timed(
+            q[:1], k[:1], v[:1], gamma[:1], *big)[0]}
+
+    def update_alone(n_rows: int) -> dict:
+        """The update ALONE at ``n_rows`` rows of a stacked state of L
+        layers: ms a call, GB/s of the bytes the kernel moves (a row's and
+        layer's state and normaliser read and written once), and the
+        program's temporary bytes — under one layer's block means no copy
+        of the state stands beside the kernel."""
+        stacked = tuple(jax.ShapeDtypeStruct((L, n_rows) + x.shape[2:],
+                                             x.dtype) for x in big)
+        s, temp = timed(
             lambda q, k, v, g, st, z: pr.retention_decode_update(
                 q, k, v, g, st, z, L - 1, interpret=interpret, **how),
-            *(wide(x) for x in step), *big)}
+            *(jnp.concatenate([x] * (n_rows // R), 0) for x in step),
+            *stacked)
+        moved = 2 * 4 * n_rows * KV * (T * d * d + d * d)
+        layer = 4 * n_rows * KV * T * d * d
+        c.check(f"the update at {n_rows} rows runs in place: no copy of a "
+                "layer's state beside it (a kernel interpreted keeps some)",
+                interpret or temp < layer, (temp, layer))
+        return {"ms_a_call": s * 1e3, "gb_s": moved / s / 1e9,
+                "temp_bytes": temp}
+
+    alone = {f"rows_{n}": update_alone(n)
+             for n in ((4, 2) if args.rehearsal else (12, 4))}
 
     make = tiny_brumby if args.rehearsal else brumby_14b
     cfg = make(n_layers=sz["layers"], max_seq_len=sz["seq"])
@@ -1348,6 +1381,7 @@ def phase_brumby(args) -> dict:
             sorted(state_leaves) == ["norm", "ret"], state_leaves)
     rep = c.report()
     rep.update(kernel_errors=errs, kernel_seconds=times,
+               retention_decode_update_alone=alone,
                first_call_s=round(first_s, 2),
                second_call_s=round(second_s, 2), prefill_blocks=blocks,
                engine=backend.describe())
@@ -1885,6 +1919,12 @@ def main(argv: list[str] | None = None) -> int:
         tag = ("skipped: " + rep["skipped"] if rep.get("skipped")
                else "ok" if rep.get("ok") else "FAILED")
         print(f"{phase}: {tag} in {time.time() - t0:.0f}s", flush=True)
+        # (a rehearsal's times are the CPU's: never shown as a pace)
+        for rows, alone in ({} if args.rehearsal else rep.get(
+                "retention_decode_update_alone", {})).items():
+            print(f"  retention_decode_update alone, {rows}: "
+                  f"{alone['ms_a_call']:.4f} ms a call, "
+                  f"{alone['gb_s']:.1f} GB/s", flush=True)
         if not rep.get("ok"):
             bad = [k for k, v in rep.get("checks", {}).items() if not v]
             print(f"  failed checks: {bad}\n  {rep.get('error', '')[-1500:]}",

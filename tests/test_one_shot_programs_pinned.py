@@ -119,6 +119,12 @@ it. The engine builds no attention function where a family has no attention
 layer (`TpuBackend._attends`), which is no line of any other family's
 program.
 
+**PR 61 moved `brumby` and `brumby-row-pieces` on purpose**:
+`retention_decode_update` leaves the stacked state in HBM and copies a grid
+step's block itself (two blocks of scratch and two semaphore pairs, both grid
+axes in sequence; `ops/power_retention.py`); the other eighteen did not move
+(printed by the file's own `__main__` as they stand).
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
@@ -158,8 +164,8 @@ _PINNED = {
     "lfm2-row-pieces": ("tiny-lfm2", {}, "10157522ffa94d1f"),
     "ling": ("tiny-ling", {}, "8514e5805097be1c"),
     "ling-row-pieces": ("tiny-ling", {}, "c3645d3dd83eed7a"),
-    "brumby": ("tiny-brumby", {}, "9f6665e8269e1743"),
-    "brumby-row-pieces": ("tiny-brumby", {}, "f1de891b2a247bef"),
+    "brumby": ("tiny-brumby", {}, "a73eee8579c6a8ba"),
+    "brumby-row-pieces": ("tiny-brumby", {}, "3e0f670ea7efbf83"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)

@@ -20,6 +20,7 @@ process may hold the TPU library, and every xdist worker imports this file
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -800,9 +801,12 @@ def test_retention_prefill_scan_compiles_at_the_published_widths(one_chip, R,
 @pytest.mark.parametrize("B", [12, 4, 1])
 def test_retention_decode_update_compiles_in_place_at_the_cells_shapes(
         one_chip, B):
-    """A row's and KV head's [65, 128, 128] state of one layer a grid step
-    (4.3 MB in, 4.3 MB out, double-buffered), the five query heads' read of
-    a tile one [8, 128] x [128, 128]^T product."""
+    """The stacked state stays in HBM and a grid step (a row, a KV head)
+    holds its [65, 128, 128] block of one layer in one of two VMEM buffers
+    (4.3 MB a transfer, 8.5 MB of scratch) by the kernel's own copies:
+    slices of a 4.15 GB operand by three traced indices, a buffer taken by a
+    traced index; the five query heads' read of 13 new tiles is one
+    [8, 1664] x [128, 1664]^T product."""
     from vnsum_tpu.ops import power_retention as pr
 
     c = _compiled(
@@ -821,8 +825,9 @@ def test_the_cells_map_program_holds_no_phi_and_no_keys_and_values(one_chip):
     its arguments are the int8 weights, its temporaries the float32 state
     of 10 layers x 12 rows and a 2,048-token row piece's activations — no
     array of ``phi(k)``'s or ``phi(q)``'s size (13 GB and 66 GB a layer at
-    this dispatch) and no keys and values. The two numbers stand in the
-    configuration's ``engine_notes``."""
+    this dispatch) and no keys and values. The compiler's two byte counts
+    are held to bands: to the digit they move with any kernel's scratch and
+    with the compiler (``engine_notes`` quotes one machine's)."""
     import functools
     import json
     import types
@@ -860,10 +865,10 @@ def test_the_cells_map_program_holds_no_phi_and_no_keys_and_values(one_chip):
         < config["bytes"]["weights"] + 4 * 1024 * 1024
     # the state, and under a GiB of a row piece's activations beside it
     assert state < m.temp_size_in_bytes < state + 1024 ** 3
-    for n in (m.temp_size_in_bytes, m.argument_size_in_bytes):
-        assert f"{n:,}" in config["engine_notes"], n
-    text = c.as_text()
-    assert "retention_prefill_scan" in text
-    assert "retention_decode_update" in text
-    assert "flash" not in text and "decode_attention" not in text
+    # the kernels by their calls' own names: the text ends in tables of
+    # every function the PROCESS has traced, other tests' kernels among
+    # them (a worker that had compiled ``mla_decode_attention`` failed a
+    # search of the whole text: ROADMAP D20)
+    kernels = set(re.findall(r"/(\w+)/pallas_call", c.as_text()))
+    assert kernels == {"retention_prefill_scan", "retention_decode_update"}
     assert m.temp_size_in_bytes + m.argument_size_in_bytes < 0.62 * 16 * 1024 ** 3

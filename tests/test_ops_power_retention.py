@@ -346,18 +346,51 @@ def test_the_state_and_the_normaliser_are_written_in_place():
     assert "input_output_aliases=((7, 1), (8, 2))" in step
 
 
-@pytest.mark.parametrize("H,KV", [(4, 2), (10, 2), (2, 2)])
-def test_decode_kernel_equals_its_xla_form_and_writes_one_layer(H, KV):
-    q, k, v, gamma, state, norm = _case(H=H, KV=KV)
+@pytest.mark.parametrize("rows,H,KV,d", [
+    (3, 4, 2, 16), (3, 10, 2, 16), (3, 2, 2, 16),
+    # the cell's rows (map, reduce, one) and query heads a KV head 5 / 4 / 1
+    (12, 10, 2, 16), (4, 8, 2, 16), (1, 2, 2, 16),
+    # T = 7 tiles, a prime; T = 15, a product of 13 tiles and a rest of 2,
+    # on an odd count of KV heads
+    (3, 4, 2, 12), (2, 6, 3, 28)])
+def test_decode_kernel_equals_its_xla_form_and_writes_one_layer(
+        rows, H, KV, d):
+    q, k, v, gamma, state, norm = _case(rows=rows, H=H, KV=KV, d=d)
     args = (q[:, 5], k[:, 5], v[:, 5], gamma[:, 5])
-    want = pr.retention_step_xla(*args, state[1], norm[1], **_how())
+    want = pr.retention_step_xla(*args, state[1], norm[1], **_how(d))
     o, S, Z = pr.retention_decode_update(*args, state, norm, 1,
-                                         interpret=True, **_how())
-    assert o.shape == (3, H, 16)
+                                         interpret=True, **_how(d))
+    assert o.shape == (rows, H, d)
     assert _rel(o, want[0]) < 1e-5 and _rel(S[1], want[1]) < 1e-6
     assert _rel(Z[1], want[2]) < 1e-6
     np.testing.assert_array_equal(np.asarray(S[0]), np.asarray(state[0]))
     np.testing.assert_array_equal(np.asarray(Z[0]), np.asarray(norm[0]))
+
+
+def test_two_layers_updates_in_a_row_leave_every_other_layer_as_it_was():
+    """The stacked state of four layers through the update of layer 2 and
+    then of layer 0, as the model's scan over layers hands it on: each
+    layer's block is what its own call wrote, layers 1 and 3 are bit-equal
+    to what came in, and the second call read nothing of the first's."""
+    q, k, v, gamma, state, norm = _case(rows=2)
+    state = jnp.concatenate([state, state[::-1] * 0.5], axis=0)
+    norm = jnp.concatenate([norm, norm[::-1] * 0.5], axis=0)
+    first = (q[:, 3], k[:, 3], v[:, 3], gamma[:, 3])
+    second = (q[:, 4], k[:, 4], v[:, 4], gamma[:, 4])
+    run = dict(interpret=True, **_how())
+    o2, S, Z = pr.retention_decode_update(*first, state, norm, 2, **run)
+    o0, S, Z = pr.retention_decode_update(*second, S, Z, 0, **run)
+    for layer in (1, 3):
+        np.testing.assert_array_equal(np.asarray(S[layer]),
+                                      np.asarray(state[layer]))
+        np.testing.assert_array_equal(np.asarray(Z[layer]),
+                                      np.asarray(norm[layer]))
+    for layer, args, o in ((2, first, o2), (0, second, o0)):
+        want = pr.retention_step_xla(*args, state[layer], norm[layer],
+                                     **_how())
+        assert _rel(o, want[0]) < 1e-5
+        assert _rel(S[layer], want[1]) < 1e-6
+        assert _rel(Z[layer], want[2]) < 1e-6
 
 
 def test_decode_steps_continue_a_prefill_as_one_longer_prefill():

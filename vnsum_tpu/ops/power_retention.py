@@ -464,16 +464,54 @@ def retention_tokens_computed(pad_lens, S: int, chunk: int) -> int:
 
 
 def _decode_kernel(lidx_ref, q_ref, qcol_ref, k_ref, kcol_ref, vcol_ref,
-                   g_ref, sin_ref, zin_ref, o_ref, sout_ref, zout_ref, *,
-                   group: int, scale: float, eps: float, dtype):
+                   g_ref, s_hbm, zin_ref, o_ref, s_out, zout_ref, held,
+                   read_sem, write_sem, *, steps: int, group: int,
+                   scale: float, eps: float, dtype):
     # q [1, 1, Gp, d] float32 rows (the KV head's G query heads, padded to
     # whole sublanes) and [1, 1, d, Gp] columns; k [1, 1, 1, d] a row and,
-    # as v, [1, 1, d, 1] a column; g [1, 1, 1, 1] the decay; the state
-    # [1, 1, 1, T, dv, d], the normaliser [1, 1, 1, d, d]; the output
-    # [1, 1, Gp, dv], a head a row. ``dtype``: the inputs' type, in which
-    # the read's products run (float32 sums), as the prefill kernel's
-    T, dv, d = sin_ref.shape[3:]
+    # as v, [1, 1, d, 1] a column; g [1, 1, 1, 1] the decay; the normaliser
+    # [1, 1, 1, d, d]; the output [1, 1, Gp, dv], a head a row. The stacked
+    # state stays in HBM (``s_hbm`` and ``s_out`` are one buffer) and a grid
+    # step's [T, dv, d] block of it stands in ``held`` [2, T, dv, d].
+    # ``dtype``: the inputs' type, in which the read's products run
+    # (float32 sums), as the prefill kernel's
+    _, T, dv, d = held.shape
     f32 = jnp.float32
+    KV = pl.num_programs(1)
+    step = pl.program_id(0) * KV + pl.program_id(1)
+    layer = lidx_ref[0]
+    me = step % 2
+
+    # The kernel moves the state itself, and NEVER a read beside a write: on
+    # this chip a read alone runs at 743 GB/s and a write alone at 645, but
+    # a block read while another is written — what the pipeline of
+    # BlockSpecs does, at any block size, depth or order — moves 658 GB/s
+    # of the two together: 13.1 us a block where the two in turn take 5.8 +
+    # 6.7 (``PERF.md`` section 6, PR 61). So the transfers of a call are
+    # one sequence, read(0) read(1) write(0) read(2) write(1) ..., each
+    # started when the one before has ended; a step's arithmetic runs
+    # beside the write of the step before it.
+    def read(n):
+        return pltpu.make_async_copy(
+            s_hbm.at[layer, n // KV, n % KV], held.at[n % 2],
+            read_sem.at[n % 2])
+
+    def write(n):
+        return pltpu.make_async_copy(
+            held.at[n % 2], s_out.at[layer, n // KV, n % KV],
+            write_sem.at[n % 2])
+
+    @pl.when(step == 0)
+    def _first():
+        for n in range(min(2, steps)):   # no write to wait for yet
+            read(n).start()
+
+    read(step).wait()
+
+    @pl.when(step >= 1)
+    def _write_the_last():
+        write(step - 1).start()
+
     g = g_ref[0, 0]                                              # [1, 1]
     vcol = vcol_ref[0, 0]                                        # [dv, 1]
     rows = q_ref.shape[2]
@@ -482,19 +520,19 @@ def _decode_kernel(lidx_ref, q_ref, qcol_ref, k_ref, kcol_ref, vcol_ref,
 
     vfull = jnp.broadcast_to(vcol, (dv, d))
     # every tile unrolled, ``_DECODE_GROUP_TILES`` of them a product: decay
-    # and rank-one write of each tile on the vector unit, float32; then the
-    # query heads' read of the NEW tiles, all heads and the group's tiles
-    # ONE product — phi_q's tiles [Gp, n d] side by side against the tiles'
-    # lanes side by side. (A product a tile made the loop wait out the
-    # matrix unit's latency 65 times a grid step: three times the state's
-    # transfer.)
+    # and rank-one write of each tile on the vector unit, float32, where it
+    # stands; then the query heads' read of the NEW tiles, all heads and
+    # the group's tiles ONE product — phi_q's tiles [Gp, n d] side by side
+    # against the tiles' lanes side by side. (A product a tile made the
+    # loop wait out the matrix unit's latency 65 times a grid step: three
+    # times the state's transfer.)
     num = jnp.zeros((rows, dv), f32)
     for first in range(0, T, _DECODE_GROUP_TILES):
         pqs, news = [], []
         for r in range(first, min(first + _DECODE_GROUP_TILES, T)):
             pk = (K * pltpu.roll(K, r, 1))[0:1, :] if r else K[0:1] * K[0:1]
-            new = sin_ref[0, 0, 0, r] * g + vfull * pk           # [dv, d]
-            sout_ref[0, 0, 0, r] = new
+            new = held[me, r] * g + vfull * pk                   # [dv, d]
+            held[me, r] = new
             pq = Qs * (pltpu.roll(Qs, r, 1) if r else Qs)
             if r in (0, T - 1):
                 pq = pq * 0.5
@@ -511,16 +549,31 @@ def _decode_kernel(lidx_ref, q_ref, qcol_ref, k_ref, kcol_ref, vcol_ref,
         for a in range(group)] + [jnp.ones((rows - group, 1), f32)], axis=0)
     o_ref[0, 0] = num / ((scale * scale) * den + eps)
 
+    @pl.when(step >= 1)
+    def _read_the_next():
+        write(step - 1).wait()
+
+        @pl.when(step + 1 < steps)
+        def _():
+            read(step + 1).start()       # into the block just written
+
+    @pl.when(step == steps - 1)
+    def _write_mine():
+        write(step).start()
+        write(step).wait()
+
 
 @functools.partial(jax.jit, static_argnames=("scale", "eps", "interpret"))
 def retention_decode_update(q, k, v, gamma, S, Z, layer_idx, *, scale: float,
                             eps: float, interpret: bool = False):
     """One token for every row: q [B, H, d], k, v [B, KV, d], gamma [B, KV]
     float32, the stacked ``S`` [L, B, KV, T, dv, d] and ``Z`` [L, B, KV, d,
-    d] float32, whose layer ``layer_idx`` is read and overwritten in place:
-    decay and the rank-one write in float32 on the vector unit, then the KV
-    head's query heads' read of the new state, once, tile by tile on the
-    matrix unit in the inputs' type with float32 sums. Returns (o [B, H, dv] float32, S, Z). Semantics:
+    d] float32, whose layer ``layer_idx`` is read and overwritten in place,
+    once, by the kernel's own copies (a row's and KV head's block a grid
+    step, two blocks in VMEM): decay and the rank-one write in float32 on
+    the vector unit, then the KV head's query heads' read of the new state,
+    once, tile by tile on the matrix unit in the inputs' type with float32
+    sums. Returns (o [B, H, dv] float32, S, Z). Semantics:
     ``retention_step_xla``."""
     Bt, H, d = q.shape
     KV, dv = k.shape[1], v.shape[-1]
@@ -532,20 +585,21 @@ def retention_decode_update(q, k, v, gamma, S, Z, layer_idx, *, scale: float,
     k = k.astype(f32)
     at = lambda *tail: pl.BlockSpec(  # noqa: E731
         (1, 1) + tail, lambda b, h, lidx: (b, h) + (0,) * len(tail))
-    state_block = lambda *tail: pl.BlockSpec(  # noqa: E731
-        (1, 1, 1) + tail,
-        lambda b, h, lidx: (lidx[0], b, h) + (0,) * len(tail))
+    stays_in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    norm_block = pl.BlockSpec(
+        (1, 1, 1) + Z.shape[3:], lambda b, h, lidx: (lidx[0], b, h, 0, 0))
     o, S, Z = pl.pallas_call(
-        functools.partial(_decode_kernel, group=G, scale=scale, eps=eps,
-                          dtype=dtype),
+        functools.partial(_decode_kernel, steps=Bt * KV, group=G,
+                          scale=scale, eps=eps, dtype=dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(Bt, KV),
             in_specs=[at(Gp, d), at(d, Gp), at(1, d), at(d, 1), at(dv, 1),
-                      at(1, 1), state_block(*S.shape[3:]),
-                      state_block(*Z.shape[3:])],
-            out_specs=[at(Gp, dv), state_block(*S.shape[3:]),
-                       state_block(*Z.shape[3:])],
+                      at(1, 1), stays_in_hbm, norm_block],
+            out_specs=[at(Gp, dv), stays_in_hbm, norm_block],
+            scratch_shapes=[pltpu.VMEM((2,) + S.shape[3:], f32),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SemaphoreType.DMA((2,))],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((Bt, KV, Gp, dv), f32),
@@ -555,8 +609,10 @@ def retention_decode_update(q, k, v, gamma, S, Z, layer_idx, *, scale: float,
         # operands 7 and 8 of the call (the prefetched scalar first) are the
         # state and the normaliser
         input_output_aliases={7: 1, 8: 2},
+        # the transfers are one sequence over the steps: no step may run
+        # beside another
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="retention_decode_update",
