@@ -117,6 +117,8 @@ def test_parameters_are_stacked_by_kind(tiny):
     assert set(kda) == {"mixer_norm", "wq", "wk", "wv", "wa", "w_beta",
                         "wg_head", "o_norm", "wo", "conv_w", "A_log",
                         "dt_bias"}
+    # [.., D, H, hd] as stored, whatever view the mixer takes of them:
+    # ``benchmarks/reference_ling.py`` reads them ``sd,dhk->shk``
     assert kda["wq"].shape == kda["wa"].shape == (4, 64, 4, 16)
     assert kda["w_beta"].shape == kda["wg_head"].shape == (4, 64, 4)
     assert kda["conv_w"].shape == (4, 3 * 64, 4)
@@ -501,6 +503,143 @@ def test_a_latent_rounded_to_int8_sits_on_the_grid_and_off_the_reference(
     assert grid_distance(rows) < 0.01
     # the reference's own rows, float32, lie between the grid's points
     assert grid_distance(want["latent"][0]) > 0.2
+
+
+# -- the mixer's views (PR 62) -------------------------------------------------------
+
+
+def _mixer_as_stored(u, lp, slot, valid, cache, cfg, cache_rows=None):
+    """``_kda_mixer``'s arithmetic as it was written before PR 62, XLA
+    path: every projection ``bsd,dhk->bshk`` on the leaf as it is stored,
+    the unit norms and the head norm on the [B, S, H, hd] reshape."""
+    from vnsum_tpu.models.llama import _cache_write, _proj
+    from vnsum_tpu.models.mamba_mixer import causal_conv
+    from vnsum_tpu.ops import kda_scan
+
+    B, S, _ = u.shape
+    H, hd, W = cfg.n_heads, cfg.head_dim, cfg.kda_width
+    aq = cfg.w8a8_prefill and S > 1
+    f32 = jnp.float32
+    parts = [_proj("bsd,dhk->bshk", u, lp[n], aq).reshape(B, S, W)
+             for n in ("wq", "wk", "wv")]
+    a = _proj("bsd,dhk->bshk", u, lp["wa"], aq)
+    b = _proj("bsd,dh->bsh", u, lp["w_beta"], aq)
+    gate = _proj("bsd,dh->bsh", u, lp["wg_head"], aq)
+    tail = cache["conv"][slot]
+    if cache_rows is not None:
+        tail = tail[cache_rows]
+    out = [causal_conv(x, tail[..., i * W:(i + 1) * W],
+                       lp["conv_w"][i * W:(i + 1) * W])
+           for i, x in enumerate(parts)]
+    conv = _cache_write(
+        cache["conv"], jnp.concatenate([t for _, t in out], -1).astype(
+            cache["conv"].dtype), slot, 0, cache_rows)
+    q, k, v = (x.reshape(B, S, H, hd) for x, _ in out)
+
+    def unit(x):
+        return x / (jnp.sqrt(jnp.sum(x * x, -1, keepdims=True)) + ling.L2_EPS)
+
+    q = (unit(q) * hd ** -0.5).astype(u.dtype)
+    k = unit(k).astype(u.dtype)
+    v = v.astype(u.dtype)
+    g = kda_scan.kda_gate(a, A_log=lp["A_log"], dt_bias=lp["dt_bias"],
+                          lower_bound=cfg.kda_lower_bound)
+    beta = jnp.where(valid[..., None], jax.nn.sigmoid(b.astype(f32)), 0.0)
+    mine = cache["kda"][slot].astype(f32)
+    if S == 1:
+        o, mine = kda_scan.kda_step_xla(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], mine)
+        o = o[:, None]
+    else:
+        o, mine = kda_scan.kda_chunked_xla(
+            q, k, v, g, beta, mine, cfg.kda_chunk_size, cache_rows)
+    state = cache["kda"].at[slot].set(mine.astype(cache["kda"].dtype))
+    o = o.astype(f32)
+    o = o * jax.lax.rsqrt(
+        jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
+    y = (o * lp["o_norm"].astype(f32)
+         * jax.nn.sigmoid(gate.astype(f32))[..., None]).astype(u.dtype)
+    return _proj("bshk,hkd->bsd", y, lp["wo"], aq), dict(
+        cache, conv=conv, kda=state)
+
+
+@pytest.mark.parametrize("int8", [False, True])
+@pytest.mark.parametrize("S, pads, rows", [
+    (1, (0, 0), None),            # a decode step
+    (12, (0, 5), None),           # no whole tile of eight tokens
+    (64, (3, 40), (4, 1)),        # two chunks under left pads, a row piece
+])
+def test_the_mixers_views_change_no_value(S, pads, rows, int8):
+    """The projections against ``_lane_views``' [D, H * hd], the unit norms
+    and the head norm in ``_head_tiles``' order where S is whole tiles of
+    eight tokens (as it is, elsewhere): output, tails and matrix state
+    BIT-EQUAL to the arithmetic on the stored shapes — both are views, no
+    sum changes its terms or their order — in bfloat16 on int8 weights
+    under W8A8 as in float32."""
+    from vnsum_tpu.models.quant import init_params_quantized
+
+    cfg = ling.tiny_ling(w8a8_prefill=int8,
+                         dtype=jnp.bfloat16 if int8 else jnp.float32)
+    if int8:
+        kda = init_params_quantized(jax.random.key(5), cfg)["kda"]
+    else:
+        kda = jitted_init(ling.init_params, cfg, 5)["kda"]
+    slot, batch = 2, 5 if rows else 2
+    keys = jax.random.split(jax.random.key(S), 3)
+    cache = ling.init_cache(cfg, batch, 8)
+    cache = dict(
+        cache,
+        kda=jax.random.normal(keys[0], cache["kda"].shape, jnp.float32) * 0.1,
+        conv=jax.random.normal(keys[1], cache["conv"].shape).astype(cfg.dtype))
+    valid = jnp.arange(S)[None, :] >= jnp.asarray(pads)[:, None]
+    u = jax.random.normal(keys[2], (2, S, cfg.dim)).astype(cfg.dtype)
+    u = jnp.where(valid[..., None], u, jnp.zeros_like(u))
+    cache_rows = None if rows is None else jnp.asarray(rows, jnp.int32)
+    layer = lambda tree: jax.tree.map(lambda w: w[slot], tree)  # noqa: E731
+
+    # operation by operation: a jitted program's fusions round in their own
+    # ways (the two forms sit a last bit apart there, as any two programs)
+    want, kept = _mixer_as_stored(
+        u, layer(kda), slot, valid, cache, cfg, cache_rows)
+    got, mine = ling._kda_mixer(
+        u, layer(ling._lane_views(kda)), slot, valid, cache, cfg, False,
+        False, cache_rows)
+    assert got.dtype == want.dtype == cfg.dtype
+    assert (np.asarray(got, np.float32) == np.asarray(want, np.float32)).all()
+    for name in ("conv", "kda"):
+        assert (np.asarray(mine[name], np.float32)
+                == np.asarray(kept[name], np.float32)).all(), name
+    touched = list(rows or range(batch))
+    assert not (np.asarray(mine["kda"][slot, touched])
+                == np.asarray(cache["kda"][slot, touched])).all()
+
+
+def test_lane_views_turn_the_four_projections_alone():
+    """[L, D, H, hd] -> [L, D, H * hd], an int8 leaf's scales with it; ``wo``
+    [L, H, hd, D] -> [L, H * hd, D], its scales as they are; every other
+    leaf is the stored array itself."""
+    from vnsum_tpu.models.quant import init_params_quantized
+
+    cfg = ling.tiny_ling()
+    kda = init_params_quantized(jax.random.key(0), cfg)["kda"]
+    views = ling._lane_views(kda)
+    assert set(views) == set(kda)
+    for n in ling.LANE_LEAVES:
+        assert views[n]["q"].shape == (4, 64, 64)
+        assert views[n]["s"].shape == (4, 64)
+        assert (np.asarray(views[n]["q"]).reshape(kda[n]["q"].shape)
+                == np.asarray(kda[n]["q"])).all()
+    assert views["wo"]["q"].shape == (4, 64, 64)
+    assert views["wo"]["s"] is kda["wo"]["s"]
+    for n in set(kda) - set(ling.LANE_LEAVES) - {"wo"}:
+        assert all(a is b for a, b in zip(jax.tree.leaves(views[n]),
+                                          jax.tree.leaves(kda[n])))
+    x = jnp.arange(2 * 16 * 3 * 5.0).reshape(2, 16, 3, 5)
+    tiles = ling._head_tiles(x)
+    assert tiles.shape == (2, 2, 3, 8, 5)
+    assert (tiles[1, 1, 2, 3] == x[1, 11, 2]).all()
+    assert (ling._head_rows(tiles, 16) == x).all()
+    assert ling._head_tiles(x[:, :12]).shape == (2, 12, 3, 5)
 
 
 # -- row pieces --------------------------------------------------------------------

@@ -125,6 +125,15 @@ step's block itself (two blocks of scratch and two semaphore pairs, both grid
 axes in sequence; `ops/power_retention.py`); the other eighteen did not move
 (printed by the file's own `__main__` as they stand).
 
+**PR 62 moved `ling` and `ling-row-pieces` on purpose**: `models/ling.py`
+views the KDA group's four lane projections `[L, D, H * hd]` and `wo`
+`[L, H * hd, D]` before the layers' scans (`_lane_views`; the products read
+`bsd,dw->bsw` and `bsw,wd->bsd`), takes the unit norms and the head norm in
+the order the `[B, S, H * hd]` arrays are tiled in where S is whole tiles of
+eight tokens (`_head_tiles`, a pinned layout each way) and keeps the gate's
+reshape out of its product by a barrier; the other eighteen did not move
+(printed by the file's own `__main__` as they stand).
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
@@ -162,8 +171,8 @@ _PINNED = {
     "granite-h-row-pieces": ("tiny-granite-h", {}, "8ece4d118fa328f3"),
     "lfm2": ("tiny-lfm2", {}, "2ae2707f3d02f044"),
     "lfm2-row-pieces": ("tiny-lfm2", {}, "10157522ffa94d1f"),
-    "ling": ("tiny-ling", {}, "8514e5805097be1c"),
-    "ling-row-pieces": ("tiny-ling", {}, "c3645d3dd83eed7a"),
+    "ling": ("tiny-ling", {}, "fc8717d4a6edbcee"),
+    "ling-row-pieces": ("tiny-ling", {}, "a7f94ff7d071bafa"),
     "brumby": ("tiny-brumby", {}, "a73eee8579c6a8ba"),
     "brumby-row-pieces": ("tiny-brumby", {}, "3e0f670ea7efbf83"),
 }

@@ -19,6 +19,7 @@ process may hold the TPU library, and every xdist worker imports this file
 """
 from __future__ import annotations
 
+import math
 import os
 import re
 
@@ -719,6 +720,131 @@ def test_kda_decode_update_compiles_in_place_at_the_cells_shapes(one_chip, B):
     assert "tpu_custom_call" in c.as_text()
 
 
+def _copies(text, dtype):
+    """Element counts of the ``copy`` instructions of ``dtype`` results."""
+    return [math.prod(int(d) for d in dims.split(","))
+            for dims in re.findall(
+                rf" = {dtype}\[([\d,]+)\]\S* copy\(", text)]
+
+
+def _root_slices(text, result):
+    """ROOT ``dynamic-slice`` instructions whose result starts ``result``:
+    fusions that do nothing but copy a slice out of their operand."""
+    return re.findall(
+        rf"ROOT \S+ = {re.escape(result)}\S* dynamic-slice\(", text)
+
+
+def _kda_layers(decode_steps=None):
+    """Ling's ten KDA mixers at the cell's widths, scanned over the stacked
+    int8 group as ``models/ling.py::forward`` hands it (the stored 4-D
+    parameters in, ``_lane_views`` once before the scans, a layer's slice
+    taken where it is used); a token loop of ``decode_steps`` around the
+    scan, or a row piece with ``cache_rows``. Returns (the function, the
+    group's shapes, the state's)."""
+    import functools
+
+    from vnsum_tpu.models import ling
+    from vnsum_tpu.models.quant import init_params_quantized
+
+    cfg = ling.LingConfig(n_layers=12, experts_held=128, w8a8_prefill=True)
+    kda = jax.eval_shape(functools.partial(init_params_quantized, cfg=cfg),
+                         jax.random.key(0))["kda"]
+    group = jax.tree.map(lambda x: (x.shape, x.dtype), kda)
+    state = {"kda": ((cfg.n_kda, 24, 32, 128, 128), F32),
+             "conv": ((cfg.n_kda, 24, 3, 3 * 4096), BF16)}
+
+    def layers(kda, x, valid, cache, rows=None):
+        kda = ling._lane_views(kda)
+
+        def step(carry, i):
+            x, cache = carry
+            lp = jax.tree.map(lambda w: jax.lax.dynamic_index_in_dim(
+                w, i, 0, keepdims=False), kda)
+            u = ling._rmsnorm(x, lp["mixer_norm"], cfg.norm_eps)
+            out, cache = ling._kda_mixer(u, lp, i, valid, cache, cfg, True,
+                                         False, rows)
+            return (x + out.astype(x.dtype), cache), None
+
+        def token(t, carry):
+            return jax.lax.scan(step, carry, jnp.arange(cfg.n_kda))[0]
+
+        if decode_steps is None:
+            return token(0, (x, cache))
+        return jax.lax.fori_loop(0, decode_steps, token, (x, cache))
+
+    return layers, group, state
+
+
+def test_a_kda_row_piece_re_tiles_no_float32_array(one_chip):
+    """The row piece [4, 2048, 2560] with ``cache_rows``: the compiled text
+    holds no ``copy`` of a float32 array of 4 x 2048 x 4096 elements. On the
+    tree before PR 62 it holds three a layer body, ``copy
+    f32[1024,8,32,128]{3,2,1,0:T(8,128)}`` of a bitcast of the
+    ``f32[4,2048,4096]{2,1,0:T(8,128)}`` convolution outputs (q's and k's
+    unit norms) and of the scan's output (the head norm): a reduction over a
+    head's channels on the ``[B, S, H, hd]`` reshape draws that shape's own
+    tiling (8 heads x 128 lanes) where the array lies (8 tokens x 128
+    lanes). Necessary, not sufficient: XLA undid the same views inside the
+    WHOLE program until ``_head_tiles`` pinned their layout
+    (``test_lings_map_program_...`` below is the one that decides)."""
+    layers, group, state = _kda_layers()
+    c = _compiled(layers, one_chip, group, ((4, 2048, 2560), BF16),
+                  ((4, 2048), jnp.bool_), state, ((4,), I32))
+    text = c.as_text()
+    assert "kda_prefill_scan" in text
+    assert 4 * 2048 * 4096 not in _copies(text, "f32")
+    # nor do the four projections' outputs turn (parent: four
+    # ``copy bf16[4,32,256,8,128]`` a body) or a layer's weight leave its
+    # stack (parent: four root slices + ``copy s8[1,2560,32,128]``)
+    assert 4 * 2048 * 4096 not in _copies(text, "bf16")
+    assert not _root_slices(text, "s8[1,2560,")
+
+
+def test_a_kda_decode_step_reads_its_projections_in_place(one_chip):
+    """The [24, 1, 2560] step inside a token loop: no fusion's ROOT is a
+    ``dynamic-slice`` with an ``s8[1,2560,`` result and no ``copy`` of an
+    int8 array of 2560 x 4096 elements. On the tree before PR 62 the loop's
+    body holds three of each, ``ROOT dynamic-slice
+    s8[1,2560,32,128]{...S(1)}`` (``wq``, ``wk``, ``wv``: 10.5 MB out of
+    the stack a projection, layer and step) and ``copy
+    s8[320,8,32,128]{3,1,2,0}`` at the head of the product's fusion (the
+    stored (32 heads x 128) tile turned to (32 rows of D x 128 lanes))."""
+    layers, group, state = _kda_layers(decode_steps=256)
+    c = _compiled(layers, one_chip, group, ((24, 1, 2560), BF16),
+                  ((24, 1), jnp.bool_), state)
+    text = c.as_text()
+    assert "kda_decode_update" in text
+    assert not _root_slices(text, "s8[1,2560,")
+    assert 2560 * 4096 not in _copies(text, "s8")
+
+
+def test_lings_map_program_re_tiles_nothing_of_the_kda_mixers(one_chip):
+    """The cell's (24, 8192, 256) one-shot program whole, from shapes alone:
+    what the two tests above hold a scanned mixer to, held where it counts.
+    Before PR 62 this text has 36 ``copy f32[1024,8,32,128]`` (three a KDA
+    body, twelve bodies: four prefill chunks of three scanned blocks), 48
+    ``copy bf16[4,32,256,8,128]`` and nine root slices of an
+    ``s8[1,2560,32,128]`` with as many copies; a tree with the views but
+    without their layout pinned kept all 36 (XLA's simplifier folds the two
+    transposes around the element-wise work back into the 4-D reshape).
+    What is left to move is the four stacks' views, once a call of
+    ``forward`` outside every loop (``reshape s8[10,2560,4096]``)."""
+    config, c = _map_program(one_chip, "ling-3.0-flash-ep4-l12-int8.json",
+                             "engine_setup_ling")
+    text = c.as_text()
+    kernels = set(re.findall(r"/(\w+)/pallas_call", text))
+    assert {"kda_prefill_scan", "kda_decode_update"} <= kernels
+    assert 4 * 2048 * 4096 not in _copies(text, "f32")
+    assert 4 * 2048 * 4096 not in _copies(text, "bf16")
+    assert not _root_slices(text, "s8[1,2560,")
+    assert 2560 * 4096 not in _copies(text, "s8")
+    m = c.memory_analysis()
+    # 9.18 GB of weights; 3.60 GB of temporaries as before the views (the
+    # four stacks' are made and dropped a call of ``forward``)
+    assert m.temp_size_in_bytes < 3.75e9
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 0.80 * 16 * 1024 ** 3
+
+
 @pytest.mark.parametrize("R,S,offset", [
     (4, 2048, 6144),   # the map dispatch's last chunk, a piece of four rows
     (4, 2048, 0),      # its first
@@ -819,29 +945,25 @@ def test_retention_decode_update_compiles_in_place_at_the_cells_shapes(
     assert c.memory_analysis().temp_size_in_bytes < 8 * 1024 * 1024
 
 
-def test_the_cells_map_program_holds_no_phi_and_no_keys_and_values(one_chip):
-    """The (12, 8192, 256) one-shot program of the cell's configuration,
-    compiled for the chip from shapes alone (the weights a tree of shapes):
-    its arguments are the int8 weights, its temporaries the float32 state
-    of 10 layers x 12 rows and a 2,048-token row piece's activations — no
-    array of ``phi(k)``'s or ``phi(q)``'s size (13 GB and 66 GB a layer at
-    this dispatch) and no keys and values. The compiler's two byte counts
-    are held to bands: to the digit they move with any kernel's scratch and
-    with the compiler (``engine_notes`` quotes one machine's)."""
+def _map_program(one_chip, config_file, setup_module):
+    """(the configuration file, its (batch, 8192, 256) one-shot program
+    compiled for the chip from shapes alone: the weights a tree of shapes)."""
     import functools
+    import importlib
     import json
     import types
     from pathlib import Path
 
-    from benchmarks import engine_setup, engine_setup_brumby
+    from benchmarks import engine_setup
     from vnsum_tpu.backend.engine import TpuBackend
     from vnsum_tpu.core.config import GenerationConfig
     from vnsum_tpu.models.quant import init_params_quantized
 
     root = Path(__file__).resolve().parents[1]
-    config = json.loads((root / "benchmarks" / "configs"
-                         / "brumby-14b-l10-int8.json").read_text())
-    cfg = engine_setup_brumby.model_config(config, False)
+    config = json.loads(
+        (root / "benchmarks" / "configs" / config_file).read_text())
+    cfg = importlib.import_module(
+        f"benchmarks.{setup_module}").model_config(config, False)
     params = jax.eval_shape(
         functools.partial(init_params_quantized, cfg=cfg), jax.random.key(0))
     B, S, new = config["engine"]["batch"], 8192, 256
@@ -856,9 +978,22 @@ def test_the_cells_map_program_holds_no_phi_and_no_keys_and_values(one_chip):
                    **engine_setup.backend_kwargs(config, False))
     spec = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dt, sharding=one_chip)
-    c = be._make_fn(B, S, new, be.gen_cfg).lower(
+    return config, be._make_fn(B, S, new, be.gen_cfg).lower(
         jax.tree.map(lambda x: spec(x.shape, x.dtype), params),
         spec((B, S), I32), spec((B,), I32), spec((), jnp.uint32)).compile()
+
+
+def test_the_cells_map_program_holds_no_phi_and_no_keys_and_values(one_chip):
+    """The (12, 8192, 256) one-shot program of the cell's configuration,
+    compiled for the chip from shapes alone (the weights a tree of shapes):
+    its arguments are the int8 weights, its temporaries the float32 state
+    of 10 layers x 12 rows and a 2,048-token row piece's activations — no
+    array of ``phi(k)``'s or ``phi(q)``'s size (13 GB and 66 GB a layer at
+    this dispatch) and no keys and values. The compiler's two byte counts
+    are held to bands: to the digit they move with any kernel's scratch and
+    with the compiler (``engine_notes`` quotes one machine's)."""
+    config, c = _map_program(one_chip, "brumby-14b-l10-int8.json",
+                             "engine_setup_brumby")
     m = c.memory_analysis()
     state = 12 * config["bytes"]["state_and_normaliser_a_row_and_layer"] * 10
     assert config["bytes"]["weights"] <= m.argument_size_in_bytes \

@@ -356,11 +356,73 @@ def init_cache(cfg: LingConfig, batch: int, cache_len: int, *,
 
 # -- the mixers, the feed-forwards and forward --------------------------------
 
+# the KDA projections whose columns are a head's channels on the lanes
+LANE_LEAVES = ("wq", "wk", "wv", "wa")
+# tokens a tile of a [B, S, H * hd] activation holds: (8 x 128 lanes)
+TILE_TOKENS = 8
+
+
+def _lane_views(kda: dict) -> dict:
+    """The stacked ``kda`` group as ``_kda_mixer`` takes it: ``LANE_LEAVES``
+    viewed [L, D, H * hd] (an int8 leaf's scales [L, H * hd]) and ``wo``
+    [L, H * hd, D]. Stored [L, D, H, hd], a stack is tiled (heads x lanes),
+    and a product that reads a layer of it ``bsd,dhk->bshk`` first copies
+    that layer out of the stack and turns it to (rows of D x lanes) — 10.5
+    MB a projection, layer and decode step at the published widths — and
+    hands a row piece's result over head-major, to be turned again. Viewed
+    in ``forward`` before the layers' scans, the turn is the whole stack's
+    once a call (XLA lifts it out of the call's loops: five a one-shot
+    dispatch) and a layer's slice fuses into its product. ``wo``'s view
+    moves nothing; it keeps the head norm's result [B, S, H * hd]. The
+    stored tree keeps its shapes: ``benchmarks/reference_ling.py`` reads
+    them ``sd,dhk->shk``."""
+    def lanes(x):      # [L, D, H, hd] and its scales [L, H, hd]
+        return x.reshape(x.shape[:-2] + (-1,))
+
+    def rows(x):       # [L, H, hd, D]; its scales [L, D] as they are
+        return x.reshape(x.shape[0], -1, x.shape[-1]) if x.ndim == 4 else x
+
+    views = {n: jax.tree.map(lanes, kda[n]) for n in LANE_LEAVES}
+    return {**kda, **views, "wo": jax.tree.map(rows, kda["wo"])}
+
+
+def _tile_order(x):
+    # pinned: in the whole program XLA's simplifier else cancels the two
+    # turns around the element-wise work between them, and the 4-D reshape
+    # with its copy is back
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+def _head_tiles(x):
+    """x [B, S, H, ...] in the order the [B, S, H * hd] array it is a
+    reshape of is tiled on the chip — (8 tokens x 128 lanes), a head of 128
+    a lane tile —: [B, S / 8, H, 8, ...], where S is whole tiles; else x as
+    it is. A float32 reduction over a head's channels on the [B, S, H, hd]
+    reshape draws that shape's own tiling (8 heads x 128 lanes) and with it
+    a copy of the whole array, 134 MB a layer and row piece at the published
+    widths; on this view it reads the array where it lies. Value for value
+    the same: ``_head_rows`` turns back."""
+    B, S, H = x.shape[:3]
+    if S % TILE_TOKENS:
+        return x
+    return _tile_order(x.reshape((B, S // TILE_TOKENS, TILE_TOKENS, H)
+                                 + x.shape[3:]).swapaxes(2, 3))
+
+
+def _head_rows(x, S: int):
+    """``_head_tiles``' way back, to [B, S, H, ...]."""
+    if S % TILE_TOKENS:
+        return x
+    x = _tile_order(x).swapaxes(2, 3)
+    return x.reshape((x.shape[0], S) + x.shape[3:])
+
 
 def _kda_mixer(u, lp, slot, valid, cache, cfg: LingConfig, scan_kernels: bool,
                interpret: bool, cache_rows=None):
     """Kimi Delta Attention over u [B, S, D] (normed, zero under the pad) at
-    KDA slot ``slot`` of the state. ``cache_rows`` [B]: u is a row piece and
+    KDA slot ``slot`` of the state, ``lp`` a layer of ``_lane_views``' group.
+    ``cache_rows`` [B]: u is a row piece and
     row b's tail and matrix state live at the state's batch row
     ``cache_rows[b]``, read and written there in place. The
     ``jax.named_scope`` names are metadata a device trace is read by (README
@@ -377,9 +439,7 @@ def _kda_mixer(u, lp, slot, valid, cache, cfg: LingConfig, scan_kernels: bool,
     decay = dict(A_log=lp["A_log"], dt_bias=lp["dt_bias"],
                  lower_bound=cfg.kda_lower_bound)
     with jax.named_scope("kda_in"):
-        parts = [_proj("bsd,dhk->bshk", u, lp[n], aq).reshape(B, S, W)
-                 for n in ("wq", "wk", "wv")]
-        a = _proj("bsd,dhk->bshk", u, lp["wa"], aq)
+        *parts, a = (_proj("bsd,dw->bsw", u, lp[n], aq) for n in LANE_LEAVES)
         b = _proj("bsd,dh->bsh", u, lp["w_beta"], aq)
         gate = _proj("bsd,dh->bsh", u, lp["wg_head"], aq)
     with jax.named_scope("kda_conv"):
@@ -396,17 +456,26 @@ def _kda_mixer(u, lp, slot, valid, cache, cfg: LingConfig, scan_kernels: bool,
                 cache["conv"].dtype), slot, 0, cache_rows)
         q, k, v = (x.reshape(B, S, H, hd) for x, _ in out)      # float32
 
-        def unit(x):
-            return x / (jnp.sqrt(jnp.sum(x * x, -1, keepdims=True)) + L2_EPS)
+        def unit(x, scale=1.0):
+            x = _head_tiles(x)
+            x = x / (jnp.sqrt(jnp.sum(x * x, -1, keepdims=True)) + L2_EPS)
+            return _head_rows((x * scale).astype(u.dtype), S)
 
-        q = (unit(q) * hd ** -0.5).astype(u.dtype)
-        k = unit(k).astype(u.dtype)
+        q = unit(q, hd ** -0.5)
+        k = unit(k)
         v = v.astype(u.dtype)
         # the prefill kernel takes the gate's projection as it is and makes
         # the log-decay, its running sum, beta k and beta v itself, a head's
         # tile at a time in VMEM: no float32 [B, S, W] array lies between
         # the convolution and the kernel
-        g = None if kernels and S > 1 else kda_scan.kda_gate(a, **decay)
+        if kernels and S > 1:
+            g = None
+        else:
+            # the barrier keeps the reshape out of the product: folded into
+            # it, a decode step's product wants ``wa`` with D on the lanes
+            # and copies the layer out of the stack to turn it
+            g = kda_scan.kda_gate(
+                jax.lax.optimization_barrier(a).reshape(B, S, H, hd), **decay)
         # a pad's beta would be sigmoid(0): nothing is erased or written there
         beta = jnp.where(valid[..., None], jax.nn.sigmoid(b.astype(f32)), 0.0)
     with jax.named_scope("kda_scan"):
@@ -421,7 +490,8 @@ def _kda_mixer(u, lp, slot, valid, cache, cfg: LingConfig, scan_kernels: bool,
                 # left padding: a row's pads are its first positions
                 pads = S - jnp.sum(valid, axis=-1, dtype=jnp.int32)
                 o, state = kda_scan.kda_prefill_scan(
-                    q, k, v, a, beta, state, slot, pads, cache_rows, **decay,
+                    q, k, v, a.reshape(B, S, H, hd), beta, state, slot, pads,
+                    cache_rows, **decay,
                     chunk=cfg.kda_chunk_size, interpret=interpret)
         else:
             mine = jax.lax.dynamic_index_in_dim(state, slot, 0, False).astype(
@@ -437,12 +507,13 @@ def _kda_mixer(u, lp, slot, valid, cache, cfg: LingConfig, scan_kernels: bool,
                 state, mine.astype(state.dtype)[None], (slot, 0, 0, 0, 0))
     with jax.named_scope("kda_out"):
         # a norm a head (group_norm_size 1), then ONE gate a head
-        o = o.astype(f32)
+        o = _head_tiles(o.astype(f32))
         o = o * jax.lax.rsqrt(
             jnp.mean(o * o, axis=-1, keepdims=True) + cfg.norm_eps)
-        y = (o * lp["o_norm"].astype(f32)
-             * jax.nn.sigmoid(gate.astype(f32))[..., None]).astype(u.dtype)
-        out = _proj("bshk,hkd->bsd", y, lp["wo"], aq)
+        gate = _head_tiles(jax.nn.sigmoid(gate.astype(f32)))[..., None]
+        y = _head_rows(
+            (o * lp["o_norm"].astype(f32) * gate).astype(u.dtype), S)
+        out = _proj("bsw,wd->bsd", y.reshape(B, S, W), lp["wo"], aq)
     return out, dict(cache, conv=conv, kda=state)
 
 
@@ -579,6 +650,9 @@ def forward(params: dict, cfg: LingConfig, tokens, positions, cache,
     sparse = {n: w for n, w in params["layers"].items()
               if n not in EXPERT_LEAVES}
     Ld = cfg.first_k_dense_replace
+    # outside the scans: a layer's slice then fuses into the product that
+    # reads it
+    mixers = {"kda": _lane_views(params["kda"]), "mla": params["mla"]}
 
     def one(tree, i):
         """Layer i of a stacked group, read where it is used (the slice
@@ -592,7 +666,7 @@ def forward(params: dict, cfg: LingConfig, tokens, positions, cache,
         its mixer's group; ``l`` its index in the stack."""
         x, cache = carry
         mixer, dense = kind
-        lp = one(params[mixer], mixer_slot)
+        lp = one(mixers[mixer], mixer_slot)
         u = _rmsnorm(x, lp["mixer_norm"], cfg.norm_eps)
         if mixer == "kda":
             u = jnp.where(valid[..., None], u, jnp.zeros_like(u))
