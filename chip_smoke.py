@@ -20,7 +20,7 @@ random weights made from a seed, through the entry points a user calls:
              SIGTERM drain
     mesh     the generate step under TpuBackend(mesh=) at model=4 and
              data=4 — only with >= 4 devices, otherwise reported as skipped
-    experts, moe, laguna, nemotron_h, ouro, lfm2, ling, brumby
+    experts, moe, laguna, nemotron_h, ouro, lfm2, ling, brumby, keye
              one family each on the one-shot path at its published widths
              and a few layers, against its plain reference
              (``--phases device,<family>``; each phase's docstring)
@@ -53,13 +53,13 @@ from pathlib import Path
 
 HERE = Path(__file__).resolve().parent
 PHASES = ("device", "kernels", "offline", "serve", "mesh", "experts", "moe",
-          "laguna", "nemotron_h", "ouro", "lfm2", "ling", "brumby")
+          "laguna", "nemotron_h", "ouro", "lfm2", "ling", "brumby", "keye")
 # the whole run, compilation included, must end inside 1200 s
 TOTAL_BUDGET_S = 1140
 PHASE_TIMEOUT_S = {
     "device": 120, "kernels": 360, "offline": 600, "serve": 600, "mesh": 600,
     "experts": 420, "moe": 420, "laguna": 480, "nemotron_h": 480,
-    "ouro": 360, "lfm2": 420, "ling": 480, "brumby": 480,
+    "ouro": 360, "lfm2": 420, "ling": 480, "brumby": 480, "keye": 600,
 }
 
 
@@ -109,6 +109,10 @@ def sizes(rehearsal: bool) -> dict:
             brumby_max_new=8, brumby_prefill_chunk=128,
             brumby_prompt_bytes=250, brumby_kernel=(2, 40, 4, 2, 16),
             brumby_kernel_tolerance=1e-4,
+            keye_layers=3, keye_seq=328, keye_batch=4, keye_max_new=8,
+            keye_prefill_chunk=128, keye_prompt_bytes=250,
+            keye_kernel=(32, 64, 200, 4, 2, 16, 4, 8, 24, 16, 32),
+            keye_kernel_tolerance=1e-4,
             ouro_layers=2, ouro_seq=328, ouro_batch=4, ouro_max_new=8,
             ouro_prefill_chunk=128, ouro_prompt_bytes=250,
             ouro_parity=(150, 256, 4), ouro_tolerance=0.05,
@@ -174,6 +178,15 @@ def sizes(rehearsal: bool) -> dict:
         brumby_layers=2, brumby_seq=2304, brumby_batch=4, brumby_max_new=32,
         brumby_prefill_chunk=1024, brumby_prompt_bytes=1_900,
         brumby_kernel=(2, 2048, 40, 8, 128), brumby_kernel_tolerance=3e-2,
+        # keye: two layers at the published widths (all 128 experts), prompts
+        # past the top-k of 2,048; the sparse-attention kernels at (queries
+        # compared with the XLA forms, queries timed, cache slots, query
+        # heads, KV heads, head width, indexer heads, indexer width, top-k,
+        # the blocks of the tests: none here)
+        keye_layers=2, keye_seq=4352, keye_batch=4, keye_max_new=32,
+        keye_prefill_chunk=1024, keye_prompt_bytes=2_900,
+        keye_kernel=(512, 2048, 16640, 32, 4, 128, 16, 64, 2048, None, None),
+        keye_kernel_tolerance=3e-2,
         # ouro: two layers at the published widths run FOUR times (8 cache
         # layers), 16 / 16 heads; prompts of two 2,048-token chunks in the
         # S=4096 bucket; the cell's own limits
@@ -1388,6 +1401,299 @@ def phase_brumby(args) -> dict:
     return rep
 
 
+def phase_keye(args) -> dict:
+    """The Keye family on the one-shot path. First the kernels of
+    ``ops/sparse_attention.py`` compiled at the published widths over a
+    stacked int8 cache of 16,640 slots — a row piece's selection
+    (``dsa_index_select``) and masked attention (``dsa_prefill_attention``)
+    under a left pad at a chunk's offset, in place at a row of a larger
+    state, and a decode step's selection and masked walk
+    (``dsa_decode_attention``) in the form the cell's dispatch runs them,
+    EIGHT rows under ragged pads — against their XLA forms: every query
+    keeps exactly min(visible, top-k) slots, the slots the two forms do not
+    share are a few per mille (two roundings of one bfloat16 product), the
+    attention agrees on the SAME set. The eight-row decode step also meets
+    ``benchmarks/reference_keye.py`` (the cell's parity check runs ONE
+    row): its sets against ``top_by_sort`` of the equations' scores in
+    float32, its walk against a plain softmax over the kept slots of the
+    multiplied-out cache. Each is timed in one jitted program
+    of eight calls, the decode attention in BOTH its forms (the masked walk
+    and ``decode_attention_gathered``: PERF.md keeps both readings). Then
+    one small generate: two layers at the published widths, all 128
+    experts, int8 and W8A8, prompts past the top-k, twice through
+    ``TpuBackend.generate``, with its counters. The logits, the caches'
+    rows and the selection against ``benchmarks/reference_keye.py`` are the
+    cell's own set-up (``--workload
+    keye-vl-2.0-l12-int8.offline-mapreduce-12k-dsa``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models import jitted_init
+    from vnsum_tpu.models.keye import keye_vl_2_0_30b_a3b, tiny_keye
+    from vnsum_tpu.models.llama import _attention, dequantize_cache_layer
+    from vnsum_tpu.models.quant import init_params_quantized
+    from vnsum_tpu.ops import sparse_attention as sa
+
+    sz = {k[5:]: v for k, v in sizes(args.rehearsal).items()
+          if k.startswith("keye_")}
+    c = Checks()
+    S, S_timed, C, H, KV, hd, Hi, di, topk, bq, bk = sz["kernel"]
+    interpret = bool(args.rehearsal)
+    dtype = jnp.float32 if args.rehearsal else jnp.bfloat16
+    blocks = dict(block_k=bk, interpret=interpret)
+    L, B = 2, 8
+    ks = iter(jax.random.split(jax.random.key(63), 12))
+    cache = {
+        "k": jax.random.randint(next(ks), (L, B, KV, C, hd), -127, 128,
+                                jnp.int8),
+        "v": jax.random.randint(next(ks), (L, B, KV, C, hd), -127, 128,
+                                jnp.int8),
+        "ks": jax.random.uniform(next(ks), (L, B, KV, C), jnp.float32,
+                                 0.005, 0.02),
+        "vs": jax.random.uniform(next(ks), (L, B, KV, C), jnp.float32,
+                                 0.005, 0.02),
+        "ki": jax.random.normal(next(ks), (L, B, di, C)).astype(dtype),
+    }
+    # a row piece: one row of the state (row 2) whose pad ends inside the
+    # cache, its chunk's queries ending at the cache's last prompt slot
+    off = (C - S_timed) // 128 * 128 if not interpret else C - 2 * S_timed
+    pad = jnp.asarray([off // 3 + 5], jnp.int32)
+    rows = jnp.asarray([2], jnp.int32)
+
+    def queries(n):
+        kq = jax.random.split(jax.random.key(n), 3)
+        return (jax.random.normal(kq[0], (1, n, H, hd)).astype(dtype),
+                jax.random.normal(kq[1], (1, n, Hi, di)).astype(dtype),
+                jax.random.normal(kq[2], (1, n, Hi), jnp.float32) * 0.03)
+
+    def rel(a, b):
+        a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+        return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+    q, q_idx, w_idx = queries(S)
+    mask, last = jax.jit(lambda q, w, cache: sa.dsa_index_select(
+        q, w, cache, 1, pad, off, rows, topk=topk, block_q=bq, **blocks))(
+        q_idx, w_idx, cache)
+    Cp = mask.shape[2]
+    slot = jnp.arange(C)[None, None, :]
+    visible = (slot >= pad[:, None, None]) & (
+        slot <= off + jnp.arange(S)[None, :, None])
+    scores = jax.jit(sa.index_scores_xla)(q_idx, w_idx, cache["ki"][1, rows])
+    want_sel = jax.jit(lambda s, v: sa.select_xla(s, v, topk))(scores, visible)
+    got_sel = np.asarray(mask[:, :, :C]) != 0
+    kept = got_sel.sum(-1)
+    want_kept = np.minimum(np.asarray(visible).sum(-1), topk)
+    unshared = int((got_sel != np.asarray(want_sel)).sum())
+    errs = {"select_unshared_share": unshared / max(int(want_kept.sum()), 1),
+            "select_last_scores": rel(
+                np.where(np.isfinite(last[:, :C]), last[:, :C], 0.0),
+                np.where(np.asarray(visible)[:, -1], scores[:, -1], 0.0))}
+    c.check("dsa_index_select keeps exactly min(visible, top-k) slots a "
+            "query, all visible, and its set is the XLA form's but for "
+            "near-ties (a row piece under a left pad, in place)",
+            (kept == want_kept).all() and not (got_sel & ~np.asarray(
+                visible)).any() and not np.asarray(mask[:, :, C:]).any()
+            and errs["select_unshared_share"] <= 0.01
+            and errs["select_last_scores"] <= sz["kernel_tolerance"], errs)
+
+    def dense(q, sel, fill_mask):
+        k, v = dequantize_cache_layer(cache, 1, hd)
+        return _attention(q, k[rows].astype(dtype), v[rows].astype(dtype),
+                          sel & fill_mask, H // KV)
+
+    out = jax.jit(lambda q, cache, m: sa.dsa_prefill_attention(
+        q, cache, 1, m, pad, off, rows, block_q=bq, **blocks))(q, cache, mask)
+    want = jax.jit(dense)(q, jnp.asarray(got_sel), visible)
+    real = np.asarray(visible).any(-1)[0]
+    errs["prefill_attention"] = rel(np.asarray(out)[0][real],
+                                    np.asarray(want)[0][real])
+    c.check("dsa_prefill_attention is dense attention over the same sets",
+            errs["prefill_attention"] <= sz["kernel_tolerance"]
+            and not np.asarray(out)[0][~real].any(), errs)
+
+    # a decode step: every row of the state (the cell's eight), ragged
+    # pads — rows that see more slots than the top-k and rows that see
+    # fewer —, one fill
+    fill = C - 3
+    pads = jnp.asarray([0, C // 2, 130, C - 40, 1, C // 3, C - 5 - topk,
+                        C - 4], jnp.int32)
+    kq = jax.random.split(jax.random.key(7), 3)
+    q1 = jax.random.normal(kq[0], (B, H, hd)).astype(dtype)
+    q1_idx = jax.random.normal(kq[1], (B, Hi, di)).astype(dtype)
+    w1_idx = jax.random.normal(kq[2], (B, Hi), jnp.float32) * 0.03
+    mask1, scores1 = jax.jit(lambda q, w, cache: sa.dsa_index_select_decode(
+        q, w, cache, 1, pads, fill, topk=topk, **blocks))(
+        q1_idx, w1_idx, cache)
+    visible1 = (slot[0] >= pads[:, None]) & (slot[0] <= fill)
+    want_scores1 = jax.jit(sa.index_scores_xla)(
+        q1_idx[:, None], w1_idx[:, None], cache["ki"][1])[:, 0]
+    want_sel1 = jax.jit(lambda s, v: sa.select_xla(s[:, None], v[:, None],
+                                                   topk))(
+        want_scores1, visible1)[:, 0]
+    got_sel1 = np.asarray(mask1[:, :C]) != 0
+    errs["decode_select_unshared"] = int(
+        (got_sel1 != np.asarray(want_sel1)).sum())
+    errs["decode_select_scores"] = rel(
+        np.where(np.asarray(visible1), scores1[:, :C], 0.0),
+        np.where(np.asarray(visible1), want_scores1, 0.0))
+    c.check("the decode step's selection keeps exactly min(visible, top-k) "
+            "slots a row and is the XLA form's but for near-ties",
+            (got_sel1.sum(-1) == np.minimum(
+                np.asarray(visible1).sum(-1), topk)).all()
+            and errs["decode_select_unshared"] <= 0.01 * B * topk
+            and errs["decode_select_scores"] <= sz["kernel_tolerance"], errs)
+    walk = jax.jit(lambda q, cache, m: sa.dsa_decode_attention(
+        q, cache, 1, m, pads, fill, **blocks))
+    got1 = walk(q1, cache, mask1)
+    k_of = min(topk, C)
+    order = jnp.argsort(~jnp.asarray(got_sel1), axis=-1, stable=True)[:, :k_of]
+    valid = jnp.take_along_axis(jnp.asarray(got_sel1), order, axis=-1)
+    gathered = jax.jit(lambda q, cache, i, v: sa.decode_attention_gathered(
+        q, cache, 1, i, v))
+    want1 = gathered(q1, cache, order, valid)
+    errs["decode_attention_walk_vs_gathered"] = rel(got1, want1)
+    c.check("dsa_decode_attention (the masked walk) and the gathered form "
+            "give the same rows",
+            errs["decode_attention_walk_vs_gathered"]
+            <= sz["kernel_tolerance"], errs)
+
+    # the same eight rows against the reference: the equations in float32
+    # at the highest precision, the top-k by a full sort
+    from benchmarks import reference_keye as reference
+
+    def plain(q_idx, w_idx, q, cache, kept):
+        f32 = jnp.float32
+        with jax.default_matmul_precision("highest"):
+            scores = jnp.einsum("bh,bhc->bc", w_idx, jnp.maximum(jnp.einsum(
+                "bhd,bdc->bhc", q_idx.astype(f32),
+                cache["ki"][1].astype(f32)), 0.0))
+            own = reference.top_by_sort(scores, visible1, topk)
+            k = cache["k"][1].astype(f32) * cache["ks"][1][..., None]
+            v = cache["v"][1].astype(f32) * cache["vs"][1][..., None]
+            qg = q.astype(f32).reshape(B, KV, H // KV, hd)
+            s = jnp.einsum("bkgd,bkcd->bkgc", qg, k) / jnp.sqrt(f32(hd))
+            p = jax.nn.softmax(
+                jnp.where(kept[:, None, None, :], s, -jnp.inf), -1)
+            return scores, own, jnp.einsum(
+                "bkgc,bkcd->bkgd", p, v).reshape(B, H, hd)
+
+    ref_scores, ref_sel, ref_rows = jax.jit(plain)(
+        q1_idx, w1_idx, q1, cache, jnp.asarray(got_sel1))
+    ref_sel, seen1 = np.asarray(ref_sel), np.asarray(visible1)
+    # a slot the two sets do not share: how far, in the reference's
+    # scores, from the reference's cut, over the visible scores' spread
+    far = [float(np.abs(s[u] - s[own].min()).max() / s[v].std())
+           if u.any() else 0.0
+           for s, own, v, u in zip(np.asarray(ref_scores, np.float64),
+                                   ref_sel, seen1, got_sel1 != ref_sel)]
+    errs["decode_select_vs_reference_unshared"] = int(
+        (got_sel1 != ref_sel).sum())
+    errs["decode_select_vs_reference_from_cut"] = max(far)
+    errs["decode_scores_vs_reference"] = rel(
+        np.where(seen1, scores1[:, :C], 0.0),
+        np.where(seen1, ref_scores, 0.0))
+    errs["decode_attention_vs_reference"] = rel(got1, ref_rows)
+    c.check("the eight-row decode step meets the reference: its sets are "
+            "top_by_sort's but for slots at the cut, its walk a plain "
+            "softmax over the kept slots",
+            (got_sel1.sum(-1) == ref_sel.sum(-1)).all()
+            and errs["decode_select_vs_reference_unshared"]
+            <= 0.01 * B * topk
+            and errs["decode_select_vs_reference_from_cut"] <= 0.05
+            and errs["decode_scores_vs_reference"] <= sz["kernel_tolerance"]
+            and errs["decode_attention_vs_reference"]
+            <= sz["kernel_tolerance"], errs)
+
+    def timed(fn, *a, n=8):
+        """Seconds a call of ``n`` calls in ONE jitted program, each
+        call's first argument nudged by the call before (so that none is
+        merged with another)."""
+        def chain(x, *rest):
+            for _ in range(n):
+                y = fn(x, *rest)
+                y = y[0] if isinstance(y, tuple) else y
+                x = x + (jnp.sum(y.astype(jnp.float32)) * 0).astype(x.dtype)
+            return x
+
+        chained = jax.jit(chain)
+        jax.block_until_ready(chained(*a))
+        t0 = time.time()
+        jax.block_until_ready(chained(*a))
+        return (time.time() - t0) / n
+
+    qt, qt_idx, wt_idx = queries(S_timed)
+    mask_t = jax.jit(lambda q, w, cache: sa.dsa_index_select(
+        q, w, cache, 1, pad, off, rows, topk=topk, block_q=bq, **blocks)[0])(
+        qt_idx, wt_idx, cache)
+    times = {
+        "dsa_index_select_s": timed(
+            lambda q, w, cache: sa.dsa_index_select(
+                q, w, cache, 1, pad, off, rows, topk=topk, block_q=bq,
+                **blocks), qt_idx, wt_idx, cache),
+        "dsa_prefill_attention_s": timed(
+            lambda q, cache, m: sa.dsa_prefill_attention(
+                q, cache, 1, m, pad, off, rows, block_q=bq, **blocks),
+            qt, cache, mask_t),
+        "dsa_index_select_decode_s": timed(
+            lambda q, w, cache: sa.dsa_index_select_decode(
+                q, w, cache, 1, pads, fill, topk=topk, **blocks),
+            q1_idx, w1_idx, cache),
+        "dsa_decode_attention_walk_s": timed(
+            lambda q, cache, m: sa.dsa_decode_attention(
+                q, cache, 1, m, pads, fill, **blocks), q1, cache, mask1),
+        "dsa_decode_attention_gathered_s": timed(
+            lambda q, cache, i, v: sa.decode_attention_gathered(
+                q, cache, 1, i, v), q1, cache, order, valid),
+        # what the gathered form needs first: the index list from the scores
+        "decode_top_k_indices_s": timed(
+            lambda s: jax.lax.top_k(s, k_of)[1].astype(jnp.float32),
+            jnp.where(jnp.asarray(visible1), want_scores1, -jnp.inf)),
+    }
+    times["shape"] = {"prefill_queries": S_timed, "slots": C,
+                      "visible_to_last_query": int(off + S_timed - pad[0]),
+                      "decode_rows": B, "decode_fill": fill}
+
+    make = tiny_keye if args.rehearsal else keye_vl_2_0_30b_a3b
+    cfg = make(n_layers=sz["layers"], max_seq_len=sz["seq"])
+    backend = TpuBackend(
+        model_config=cfg, tokenizer="byte",
+        params=jitted_init(init_params_quantized, cfg, 63),
+        batch_size=sz["batch"], max_new_tokens=sz["max_new"], quantize=True,
+        quantize_act=True, prefill_chunk_tokens=sz["prefill_chunk"],
+        generation=GenerationConfig(temperature=1.0, seed=63),
+        interpret=args.rehearsal)
+    prompts = [_vn_text(sz["prompt_bytes"] - 300 * i // 4, f"y{i}")
+               for i in range(sz["batch"])]
+    _outs, first_s, second_s = _generate_twice(backend, prompts, c)
+    counted = backend.stats.prefill_blocks
+    c.check("the selection's scores are counted: computed >= needed, "
+            "selected < visible x heads (the prompts pass the top-k)",
+            0 < counted.get("dsa_index_scores_needed", 0)
+            <= counted.get("dsa_index_scores_computed", 0)
+            and 0 < counted.get("dsa_attention_scores_selected", 0)
+            < counted.get("dsa_keys_visible", 0) * cfg.n_heads
+            and counted["dsa_attention_scores_selected"]
+            <= counted.get("dsa_attention_scores_computed", 0), counted)
+    c.check("experts were routed and held",
+            backend.stats.expert_slots_routed > 0
+            and backend.stats.expert_slots_held
+            == backend.stats.expert_slots_routed,
+            (backend.stats.expert_slots_routed,
+             backend.stats.expert_slots_held))
+    state_leaves = backend.describe()["state_bytes_per_row"]
+    c.check("the program carries indexer keys beside keys and values",
+            {"k", "v", "ks", "vs", "ki"} <= set(state_leaves), state_leaves)
+    rep = c.report()
+    rep.update(kernel_errors=errs, kernel_seconds=times,
+               first_call_s=round(first_s, 2),
+               second_call_s=round(second_s, 2), prefill_blocks=counted,
+               engine=backend.describe())
+    return rep
+
+
 def phase_ouro(args) -> dict:
     """The dense family LOOPED over its weights (``LlamaConfig.loop_passes``,
     Ouro-2.6B) on the one-shot path: two layers at the published widths run
@@ -1494,7 +1800,8 @@ def _child(args) -> int:
                     "ouro": phase_ouro,
                     "lfm2": phase_lfm2,
                     "ling": phase_ling,
-                    "brumby": phase_brumby}[phase](args))
+                    "brumby": phase_brumby,
+                    "keye": phase_keye}[phase](args))
         rep["device"] = _device_report()
         rep["memory"] = _memory()
         rep["compile"] = {k: round(v, 2) if isinstance(v, float) else v

@@ -134,11 +134,19 @@ eight tokens (`_head_tiles`, a pinned layout each way) and keeps the gate's
 reshape out of its product by a barrier; the other eighteen did not move
 (printed by the file's own `__main__` as they stand).
 
+**PR 63 moved none of the twenty and added `keye` and `keye-row-pieces`**:
+the tenth family (`models/keye.py`: the selection and the masked attention
+of `ops/sparse_attention.py` in both phases, the grouped expert product, an
+indexer-key cache and the last position's selection in the carry) whole,
+and with a row piece set to its 128-token chunk: the kernels' `rows`
+prefetch is in it. `models/quant.py` learned the indexer's two projections'
+names, which is no line of any other family's program.
+
 A hash says that a program moved, not what moved. `program_pins.json` beside
 this file keeps, for every pinned program, one hex digit a line of the
 running hash of its text: a failing pin prints the first line that differs
 (`assert_pinned`). `python tests/test_one_shot_programs_pinned.py` (from the
-repo's root, `PYTHONPATH=.`) traces all twenty, prints both tables as they
+repo's root, `PYTHONPATH=.`) traces all twenty-two, prints both tables as they
 would have to read and rewrites that file — the one place that regenerates
 them."""
 from __future__ import annotations
@@ -175,12 +183,14 @@ _PINNED = {
     "ling-row-pieces": ("tiny-ling", {}, "a7f94ff7d071bafa"),
     "brumby": ("tiny-brumby", {}, "a73eee8579c6a8ba"),
     "brumby-row-pieces": ("tiny-brumby", {}, "3e0f670ea7efbf83"),
+    "keye": ("tiny-keye", {}, "b14c2362e521e912"),
+    "keye-row-pieces": ("tiny-keye", {}, "a5d46e91257a11f8"),
 }
 # family -> the tokens a row piece of its prefill holds, where the pinned
 # program is not the family's own (`Family.prefill_piece_tokens`)
 _PIECE_TOKENS = {"llama-row-pieces": 128, "granite-h-row-pieces": 128,
                  "lfm2-row-pieces": 128, "ling-row-pieces": 128,
-                 "brumby-row-pieces": 128}
+                 "brumby-row-pieces": 128, "keye-row-pieces": 128}
 
 # the slot loop's programs of the tiny llama family, "kind-rows" -> the same
 # hash: a join of 1 and of 2 rows, the segment of 4 slots, the adopt of a
@@ -324,7 +334,7 @@ def test_a_slot_program_traces_to_the_pinned_jaxpr(program):
 
 
 def regenerate() -> None:
-    """Trace all twenty programs, print the two tables' hashes as they are now
+    """Trace all twenty-two programs, print the two tables' hashes as they are now
     and rewrite the line ladders."""
     texts = [("_PINNED", family, want,
               one_shot_jaxpr(MODEL_REGISTRY.get(config, config)(**kw),

@@ -7,7 +7,9 @@ query heads on 2 KV heads), and the LFM2-MoE family's (the GQA kernels at
 32/8 heads of 64, two KV heads a lane tile of the cache as ``init_kv_cache``
 lays it out, over 6 layers with a row piece of four rows, the expert
 product at 32 experts of 2048 x 1792), and the Brumby family's two
-power-retention kernels and its whole (12, 8192, 256) program, compiled
+power-retention kernels and its whole (12, 8192, 256) program, and the Keye
+family's sparse-attention kernels (selection and masked attention, prefill
+and decode, over 16,640 slots) and its whole (8, 16384, 256) program, compiled
 for the chip at the published widths, with no chip: the TPU's compiler is installed here and
 compiles for a described v5e. Interpret mode cannot show what this does: a
 slice not aligned to the tiling, too much VMEM, an int8 product Mosaic
@@ -1007,3 +1009,130 @@ def test_the_cells_map_program_holds_no_phi_and_no_keys_and_values(one_chip):
     kernels = set(re.findall(r"/(\w+)/pallas_call", c.as_text()))
     assert kernels == {"retention_prefill_scan", "retention_decode_update"}
     assert m.temp_size_in_bytes + m.argument_size_in_bytes < 0.62 * 16 * 1024 ** 3
+
+
+# -- the Keye family: selection and attention over a selection -------------------
+
+
+def _keye_caches(L=12, B=8, C=16640):
+    return {"k": ((L, B, 4, C, 128), I8), "v": ((L, B, 4, C, 128), I8),
+            "ks": ((L, B, 4, C), F32), "vs": ((L, B, 4, C), F32),
+            "ki": ((L, B, 64, C), BF16)}
+
+
+@pytest.mark.parametrize("S,offset,C", [
+    (2048, 14336, 16640),   # the map dispatch's last chunk: a row piece
+    (2048, 0, 16640),       # its first
+    (1024, 10240, 16392),   # parity's cache: blocks end past it
+])
+def test_dsa_prefill_kernels_compile_at_the_published_widths(one_chip, S,
+                                                             offset, C):
+    """A row piece's selection — 16 indexer heads of 64 against the
+    transposed indexer cache, a 256-query tile's int32 keys over every slot
+    in VMEM scratch (17.8 MB), the bisection's dynamic trip counts and its
+    conditional second bisection, an int8 mask written by dynamic lane
+    slices — and the masked attention at 8 query heads a KV head over the
+    int8 cache, in place at a row of the batch's state."""
+    from vnsum_tpu.ops import sparse_attention as sa
+
+    cache = _keye_caches(C=C)
+    Cp = -(-C // sa._KEY_BLOCK) * sa._KEY_BLOCK
+    c = _compiled(
+        lambda q, w, cache, pads, rows: sa.dsa_index_select(
+            q, w, cache, 7, pads, offset, rows, topk=2048),
+        one_chip, ((1, S, 16, 64), BF16), ((1, S, 16), F32), cache,
+        ((1,), I32), ((1,), I32))
+    assert "tpu_custom_call" in c.as_text()
+    # no copy of a cache stands beside the kernel: the indexer cache alone
+    # is 204 MB, and [L, B, C, 64] cost a 409 MB re-tiling a call
+    assert c.memory_analysis().temp_size_in_bytes < 16 * 1024 * 1024
+    c = _compiled(
+        lambda q, cache, m, pads, rows: sa.dsa_prefill_attention(
+            q, cache, 7, m, pads, offset, rows),
+        one_chip, ((1, S, 32, 128), BF16), cache, ((1, S, Cp), I8),
+        ((1,), I32), ((1,), I32))
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+    assert sa.VMEM_LIMIT_BYTES <= 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("B,C", [(8, 16640), (4, 2304), (1, 16392)])
+def test_dsa_decode_kernels_compile_at_the_cells_shapes(one_chip, B, C):
+    """A decode step's selection (the rows on the sublanes of one tile, a
+    fill that is a traced scalar) and the masked walk over every KV head of
+    a block in one step."""
+    from vnsum_tpu.ops import sparse_attention as sa
+
+    cache = _keye_caches(B=B, C=C)
+    Cp = -(-C // sa._DECODE_KEY_BLOCK) * sa._DECODE_KEY_BLOCK
+    for fn, shapes in (
+            (lambda q, w, cache, pads, fill: sa.dsa_index_select_decode(
+                q, w, cache, 3, pads, fill, topk=2048),
+             (((B, 16, 64), BF16), ((B, 16), F32))),
+            (lambda q, m, cache, pads, fill: sa.dsa_decode_attention(
+                q, cache, 3, m, pads, fill),
+             (((B, 32, 128), BF16), ((B, Cp), I32)))):
+        c = _compiled(fn, one_chip, *shapes, cache, ((B,), I32), ((), I32))
+        assert "tpu_custom_call" in c.as_text()
+        assert c.memory_analysis().temp_size_in_bytes < 8 * 1024 * 1024
+
+
+def test_keyes_map_program_carries_three_caches_and_fits_the_chip(one_chip):
+    """The (8, 16384, 256) one-shot program of the cell's configuration,
+    compiled for the chip from shapes alone: its arguments are the int8
+    weights, its temporaries hold the KV cache, the indexer-key cache and a
+    2,048-token row piece's activations (the selection's int8 mask 35 MB
+    among them); the kernels it calls are the family's three and the expert
+    product's two."""
+    import functools
+    import importlib
+    import json
+    import types
+    from pathlib import Path
+
+    from benchmarks import engine_setup
+    from vnsum_tpu.backend.engine import TpuBackend
+    from vnsum_tpu.core.config import GenerationConfig
+    from vnsum_tpu.models.quant import init_params_quantized
+
+    root = Path(__file__).resolve().parents[1]
+    config = json.loads((root / "benchmarks" / "configs"
+                         / "keye-vl-2.0-l12-int8.json").read_text())
+    cfg = importlib.import_module(
+        "benchmarks.engine_setup_keye").model_config(config, False)
+    params = jax.eval_shape(
+        functools.partial(init_params_quantized, cfg=cfg), jax.random.key(0))
+    B, S, new = config["engine"]["batch"], 16384, 256
+
+    class OnTheChip(TpuBackend):
+        def _devices(self):
+            return [types.SimpleNamespace(platform="tpu")]
+
+    be = OnTheChip(model_config=cfg, tokenizer="byte", params=params,
+                   batch_size=B, max_new_tokens=new,
+                   generation=GenerationConfig(temperature=1.0, seed=1),
+                   **engine_setup.backend_kwargs(config, False))
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dt, sharding=one_chip)
+    c = be._make_fn(B, S, new, be.gen_cfg).lower(
+        jax.tree.map(lambda x: spec(x.shape, x.dtype), params),
+        spec((B, S), I32), spec((B,), I32), spec((), jnp.uint32)).compile()
+    m = c.memory_analysis()
+    b = config["bytes"]
+    assert b["weights"] <= m.argument_size_in_bytes \
+        < b["weights"] + 4 * 1024 * 1024
+    caches = b["kv_cache"] + b["indexer_cache"]
+    # the caches, and under a GiB of a row piece's activations beside them
+    assert caches < m.temp_size_in_bytes < caches + 1024 ** 3
+    text = c.as_text()
+    kernels = set(re.findall(r"/(\w+)/pallas_call", text))
+    assert kernels == {"dsa_index_select", "dsa_prefill_attention",
+                       "dsa_decode_attention", "expert_grouped_matmul",
+                       "expert_combine"}
+    # the four leaves a parity check alone reads (models/keye.py
+    # ``row_record``) are dead in this program: the compiler carries none
+    for dead in ("s8[12,8,16640]", "f32[12,8,16640]", "bf16[12,8,16,64]",
+                 "f32[12,8,16]{"):
+        assert dead not in text, dead
+    assert "bf16[12,8,64,16640]" in text          # the indexer keys are
+    assert m.temp_size_in_bytes + m.argument_size_in_bytes < 0.7 * 16 * 1024 ** 3

@@ -280,6 +280,34 @@ def test_a_retention_familys_program_carries_its_component_scopes():
     assert not named & {"qkv", "kv_write", "attn", "attn_out"}
 
 
+def test_a_sparse_attention_familys_program_carries_its_component_scopes():
+    """Every scope ``models/keye.py`` adds, under BOTH phases: ``dsa_in``
+    (the indexer's three projections, its LayerNorm and rotary, the write
+    of the indexer-key cache) and ``dsa_select`` (index scores and the exact
+    top-k), beside the skeleton's ``qkv``, ``kv_write``, ``attn`` (here the
+    attention over the selection), ``attn_out``, and the expert layer's
+    ``router`` and ``experts``; and every instruction the map places lies
+    under a phase and a component."""
+    from vnsum_tpu.models.keye import init_params, tiny_keye
+
+    cfg = tiny_keye(max_seq_len=128)
+    b = TpuBackend(model_config=cfg, tokenizer="byte", batch_size=B,
+                   max_new_tokens=NEW, seed=1, flash=False,
+                   params=init_params(jax.random.key(0), cfg))
+    b._get_fn(B, S, NEW, b.gen_cfg)
+    (m,) = b.scope_maps()
+    assert m["module"] == "jit_generate"
+    got = paths(m["scopes"])
+    both = ("dsa_in", "dsa_select", "attn", "experts", "router", "qkv",
+            "kv_write", "attn_out", "embed", "lm_head", "sample")
+    for phase in ("prefill", "decode"):
+        assert {f"{phase}/{c}" for c in both} <= got, phase
+    assert not [p for p in got if p and any(ch.isdigit() for ch in p)]
+    named = {p.split("/")[1] for p in got if p.count("/") >= 1}
+    assert named <= set(both) | {"emit"}, named
+    assert "mlp" not in named and "shared_experts" not in named
+
+
 def test_a_looped_stacks_program_carries_the_norm_between_passes():
     """A stack looped over its weights (``LlamaConfig.loop_passes``) adds
     ONE scope to the dense family's, under both phases: ``loop_norm``, the

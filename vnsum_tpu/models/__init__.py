@@ -121,6 +121,18 @@ def _tiny_brumby(**kw):
     return tiny_brumby(**kw)
 
 
+def _keye_vl_2_0(**kw):
+    from .keye import keye_vl_2_0_30b_a3b
+
+    return keye_vl_2_0_30b_a3b(**kw)
+
+
+def _tiny_keye(**kw):
+    from .keye import tiny_keye
+
+    return tiny_keye(**kw)
+
+
 # model name -> config factory (names match the reference's Ollama tags where
 # an equivalent open-weights architecture exists)
 MODEL_REGISTRY = {
@@ -179,6 +191,13 @@ MODEL_REGISTRY = {
     # ops/power_retention.py - and no keys and values at all
     "brumby-14b": _brumby_14b,
     "tiny-brumby": _tiny_brumby,
+    # a tenth (models/keye.py): the Qwen3-MoE skeleton (QK-normed rotary GQA
+    # by three position components, 128 experts top-8) whose every layer
+    # attends the 2,048 keys a learned indexer scores highest
+    # (ops/sparse_attention.py), indexer keys in the carry beside keys and
+    # values
+    "keye-vl-2.0-30b-a3b": _keye_vl_2_0,
+    "tiny-keye": _tiny_keye,
 }
 
 __all__ = [

@@ -61,6 +61,9 @@ _ALL_CONTRACT_AXES = {
     # output gate's, which its MLA layers have too); the taps, A_log and
     # dt_bias stay float32
     "wa": (0,), "w_beta": (0,), "wg_head": (0,),   # [D, H, hd], [D, H] x 2
+    # a sparse-attention indexer's two projections (models/keye.py); the
+    # heads' weights, its LayerNorm and the QK-norms stay full precision
+    "wq_idx": (0,), "wk_idx": (0,),                # [D, Hi, di], [D, di]
 }
 # the groups of stacked layers a params tree may hold: every family has
 # "layers"; one with leading dense layers keeps them under "dense"; one
